@@ -216,8 +216,10 @@ def main() -> None:
         prepare_image,
     )
     from can_tpu.serve.quant import param_bytes
-    from can_tpu.utils import enable_compilation_cache
+    from can_tpu.utils import bench_device, enable_compilation_cache
 
+    # no TPU and the CPU not requested -> exit 2, never a silent CPU run
+    device = bench_device()
     enable_compilation_cache(None)  # no-op on CPU, warm restarts on TPU
     # serving cost is weight-independent: random init serves the same
     # FLOPs a trained checkpoint would (swap in cli/serve.py for accuracy)
@@ -283,7 +285,7 @@ def main() -> None:
                    "serve_dtype": serve_dtype,
                    "sizes": [f"{h}x{w}" for h, w in sizes],
                    "buckets": [f"{h}x{w}" for h, w in buckets],
-                   "platform": jax.devices()[0].platform},
+                   **device},
         "warmup": warm,
         "compile_count": engine.compile_count,
         "bucket_count": len(buckets),

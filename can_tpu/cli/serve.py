@@ -186,7 +186,8 @@ def parse_args(argv=None):
                    choices=["default", "cpu", "tpu"])
     p.add_argument("--compile-cache", type=str, default="auto",
                    help="persistent XLA compilation-cache dir ('auto' = "
-                        "~/.cache/can_tpu/xla, 'off' disables) — makes "
+                        "where JAX_COMPILATION_CACHE_DIR says, else "
+                        "<repo>/.jax_cache; 'off' disables) — makes "
                         "warm restarts deserialise the bucket programs "
                         "instead of recompiling")
     p.add_argument("--telemetry-dir", type=str, default="",
@@ -430,8 +431,9 @@ def main(argv=None) -> int:
 
     validate_incident_args(args)
     apply_platform(args)
-    init_runtime()
+    topo = init_runtime()
     apply_compile_cache(args, announce=True)
+    print(f"[runtime] {topo}")
     telemetry, heartbeat, exporter = build_telemetry(
         args, host_id=process_index(), trace_window=None)
     try:

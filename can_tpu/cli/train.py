@@ -253,12 +253,11 @@ def parse_args(argv=None):
     p.add_argument("--launch-cost-mpx", type=parse_launch_cost, default=2.0,
                    help="fixed cost of one extra step launch, in "
                         "megapixel-equivalents, for the remnant planner's "
-                        "pixels-vs-launches trade. The conservative "
-                        "default (~50 ms at the chip's measured rate) "
-                        "suits high-dispatch-latency links; 'auto' "
-                        "measures this host's dispatch overhead at "
-                        "startup (sub-ms dispatch unlocks exact "
-                        "straggler splits)")
+                        "pixels-vs-launches trade. The default is a "
+                        "conservative constant, not measured on the "
+                        "current machine; 'auto' measures this host's "
+                        "dispatch overhead at startup (sub-ms dispatch "
+                        "unlocks exact straggler splits)")
     p.add_argument("--bn-impl", choices=("twopass", "onepass", "pallas"),
                    default="onepass",
                    help="SyncBN batch-moments path (only meaningful with "
@@ -270,8 +269,9 @@ def parse_args(argv=None):
                         "mean-then-variance math, kept bit-compatible for "
                         "A/B, mirroring --plan-mode legacy); 'pallas' "
                         "additionally fuses the mask multiply into a TPU "
-                        "kernel (ops/pallas_bn.py; jnp fallback off-TPU / "
-                        "unsupported shapes)")
+                        "kernel (ops/pallas_bn.py; interpreted when the "
+                        "CPU is requested, jnp twin for shapes the kernel "
+                        "cannot tile — both printed at step build)")
     p.add_argument("--plan-mode", choices=("cost", "legacy"), default="cost",
                    help="batch-plan search: 'cost' (default) plans bucket "
                         "boundaries, per-cell batch sizes, and remnant "
@@ -299,7 +299,8 @@ def parse_args(argv=None):
                         "reacts faster, larger costs less)")
     p.add_argument("--compile-cache", type=str, default="auto",
                    help="persistent XLA compilation-cache dir ('auto' = "
-                        "~/.cache/can_tpu/xla, 'off' disables): warm "
+                        "where JAX_COMPILATION_CACHE_DIR says, else "
+                        "<repo>/.jax_cache; 'off' disables): warm "
                         "restarts skip the per-bucket-shape compile bill")
     return p.parse_args(argv)
 
@@ -467,7 +468,10 @@ def apply_compile_cache(args, *, announce: bool = False) -> None:
     from can_tpu.utils import enable_compilation_cache
 
     spec = getattr(args, "compile_cache", "auto")
-    cache_dir = enable_compilation_cache(None if spec == "auto" else spec)
+    try:
+        cache_dir = enable_compilation_cache(None if spec == "auto" else spec)
+    except ValueError as e:
+        raise SystemExit(f"--compile-cache: {e}")
     if announce and cache_dir:
         print(f"[xla] persistent compilation cache at {cache_dir}")
 
@@ -670,8 +674,9 @@ def _run_elastic_generations(args, run_cfg, topo, *, supervisor,
                         "runs per-device inside shard_map) or a single "
                         "device; use onepass for the GSPMD data-parallel "
                         "step")
-                bn_ops = make_bn_ops("pallas",
-                                     interpret=jax.default_backend() != "tpu")
+                from can_tpu.utils import pallas_interpret
+
+                bn_ops = make_bn_ops("pallas", interpret=pallas_interpret())
             else:
                 bn_ops = make_bn_ops(args.bn_impl)
         if args.sp > 1 and main_proc and first_gen and pad_multiple != "auto":
@@ -697,9 +702,16 @@ def _run_elastic_generations(args, run_cfg, topo, *, supervisor,
         # HBM agreed across hosts (min) ONCE PER GENERATION: both the
         # launch cap and the remat policy must be identical on every host
         # or the lockstep schedule deadlocks (ADVICE r4)
-        from can_tpu.cli.common import agreed_device_memory_bytes
+        from can_tpu.cli.common import (
+            agreed_device_memory_bytes,
+            device_memory_sources,
+        )
 
         hbm = agreed_device_memory_bytes()
+        if main_proc and first_gen:
+            limit, spec = device_memory_sources()
+            print(f"[hbm] per-device cap {hbm} bytes (memory_stats "
+                  f"bytes_limit={limit}, spec table={spec})")
         ndev = dp * args.sp  # devices per launch
         if not args.no_remnant_batches:
             # HBM cap per launch: bucket cells too big for the full
@@ -738,6 +750,23 @@ def _run_elastic_generations(args, run_cfg, topo, *, supervisor,
         # identical init on every host by construction: same seed/key
         params = cannet_init(jax.random.key(args.seed),
                              batch_norm=args.syncBN)
+        if bn_ops is not None and bn_ops.impl == "pallas" and main_proc \
+                and first_gen:
+            # the kernel's two silent exits, said out loud at step build:
+            # which mode it runs in, and per planned bucket how many BN
+            # layers its shape gate lets through
+            from can_tpu.cli.common import bn_kernel_routing
+
+            mode = ("INTERPRETED" if bn_ops.interpret
+                    else "compiled (not interpreted)")
+            print(f"[model] pallas BN-moments kernel: {mode}, platform "
+                  f"{jax.devices()[0].platform}")
+            for hw in sorted({k for k, _ in train_batcher.global_schedule(0)}):
+                k, t = bn_kernel_routing(params, hw, sp=args.sp,
+                                         interpret=bn_ops.interpret)
+                print(f"[model] bucket {hw[0]}x{hw[1]}: {k} BN layers -> "
+                      f"pallas kernel, {t} -> jnp onepass twin "
+                      f"(C % 128 / W % 8 gate)")
         if args.vgg16_npz:
             params = load_vgg16_frontend(params, args.vgg16_npz)
             if main_proc and first_gen:

@@ -68,8 +68,12 @@ _native_checked = False
 
 
 def _load_native():
-    """ctypes handle to the C++ stamping loop (tools/build_native.py), or
-    None — everything works without it, just slower on dense annotations."""
+    """ctypes handle to the C++ stamping loop, or None — output is
+    identical either way (tested), numpy is just slower on dense
+    annotations.  The library is a BUILD PRODUCT (git-ignored; a checkout
+    has none until ``python tools/build_native.py`` runs there), so which
+    path a process took is printed once, at load: two copies of one
+    commit must not differ silently."""
     global _native_lib, _native_checked
     if _native_checked:
         return _native_lib
@@ -79,6 +83,7 @@ def _load_native():
 
     so = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                       "native", "libdensity_stamp.so")
+    why = "not built — python tools/build_native.py"
     if os.path.exists(so):
         try:
             lib = ctypes.CDLL(so)
@@ -88,8 +93,10 @@ def _load_native():
                                             ctypes.c_double]
             lib.stamp_gaussians.restype = None
             _native_lib = lib
-        except OSError:
-            _native_lib = None
+        except OSError as e:
+            why = f"{so} failed to load: {e}"
+    print(f"[density] stamping path: native ({so})" if _native_lib is not None
+          else f"[density] stamping path: numpy ({why})", flush=True)
     return _native_lib
 
 

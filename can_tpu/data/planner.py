@@ -6,7 +6,7 @@ remnant-menu size that fit HBM, ``_partial_plan`` greedily merged straggler
 groups pairwise and dropped the smallest menu size when over the compile
 budget, and ``_decompose`` ran a per-cell DP — each locally sensible, none
 sharing an objective, and the measured result was a 30.7% schedule
-overhead for b16 varres vs 21.7% at b8 (BENCH_SUITE_r05, VERDICT r5
+overhead for b16 varres vs 21.7% at b8 (r5 chip sweep, VERDICT r5
 item 7).  This module replaces them with ONE explicit objective,
 
     plan_cost = area * padded_slots + launch_cost_px * n_launches

@@ -20,7 +20,11 @@ the ``--syncBN`` model) are the per-layer reduction ``(B, h, w, C) -> (C,)``
 * ``pallas`` — the same one-pass contract with the local reduction done by
   the TPU kernel in ``ops/pallas_bn.py`` (mask-multiply fused into the
   moment accumulation, tiles resident in VMEM); the packing/psum stays
-  out here, and unsupported shapes/backends fall back to the jnp onepass.
+  out here.  Layers whose shape the compiled kernel cannot tile (C not a
+  multiple of 128 — the C=64 stem — or W not a multiple of 8) take the
+  jnp onepass twin; ``masked_moments_pallas(routed=[...])`` tallies each
+  such decision at trace time, which is how the step builder can SAY how
+  many layers took which (``cli.common.bn_kernel_routing``).
 
 The f32 accumulator dtype is pinned across every implementation: callers
 hand in ``yf = y.astype(float32)`` and masks are f32, so bf16 compute
@@ -96,10 +100,16 @@ def masked_moments_onepass(yf, m, axes) -> Tuple:
     return _finish_onepass(*masked_moment_sums(yf, m), axes)
 
 
-def masked_moments_pallas(yf, m, axes, *, interpret: bool = False) -> Tuple:
+def masked_moments_pallas(yf, m, axes, *, interpret: bool = False,
+                          routed: Optional[list] = None) -> Tuple:
+    """``routed`` (a list, appended to at TRACE time): one
+    ``(took_kernel, yf.shape)`` per call — the shape gate made visible."""
     from can_tpu.ops import pallas_bn
 
-    if not pallas_bn.supports(yf.shape, interpret=interpret):
+    took_kernel = pallas_bn.supports(yf.shape, interpret=interpret)
+    if routed is not None:
+        routed.append((took_kernel, tuple(yf.shape)))
+    if not took_kernel:
         return masked_moments_onepass(yf, m, axes)
     s1, s2, s0 = pallas_bn.moment_sums(yf, m, interpret=interpret)
     return _finish_onepass(s1, s2, s0, axes)

@@ -38,24 +38,13 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 ROW_TILE = 8
 MAX_COL_TILE = 128
 
-try:  # import guard: pallas TPU lowering is unavailable on some backends
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as _pltpu  # noqa: F401 probe —
-    # importing the TPU lowering is the availability check (same rationale
-    # as ops/pallas_context.py)
-
-    _PALLAS_OK = True
-except ImportError:  # pragma: no cover
-    _PALLAS_OK = False
-
 
 def supports(y_shape, *, interpret: bool = False) -> bool:
-    if not _PALLAS_OK:
-        return False
     if len(y_shape) != 4:
         return False
     if interpret:

@@ -49,6 +49,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
 
 from can_tpu.ops.resize import upsample_matrix
 
@@ -99,19 +100,6 @@ def _kernel(fv_ref, *rest):
         num = num + gate * sm
         den = den + gate
     out_ref[0] = (num / (den + EPS)).astype(out_ref.dtype)
-
-
-try:  # import guard: pallas TPU lowering is unavailable on some backends
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as _pltpu  # noqa: F401 probe —
-    # importing the TPU lowering is the availability check itself; without
-    # it _PALLAS_OK would be True on builds where pallas imports but TPU
-    # lowering doesn't, and pallas_call would raise at trace time instead
-    # of supports() steering callers to the fallback
-
-    _PALLAS_OK = True
-except ImportError:  # pragma: no cover
-    _PALLAS_OK = False
 
 
 def _pick_col_tile(w: int, max_tw: int) -> int:
@@ -192,8 +180,6 @@ _fused.defvjp(_fused_fwd, _fused_bwd)
 
 
 def supports(fv_shape) -> bool:
-    if not _PALLAS_OK:
-        return False
     b, h, w, c = fv_shape
     return w % 16 == 0 and c % 128 == 0
 

@@ -33,20 +33,8 @@ from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # older jax: experimental home, check_vma spelled
-    from functools import wraps as _wraps
-
-    from jax.experimental.shard_map import shard_map as _shard_map_compat
-
-    @_wraps(_shard_map_compat)
-    def shard_map(*args, **kwargs):
-        if "check_vma" in kwargs:  # renamed from check_rep in jax 0.6
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map_compat(*args, **kwargs)
 
 from can_tpu.models.cannet import LocalOps, cannet_apply
 from can_tpu.ops.pooling import adaptive_pool_matrix, max_pool2d

@@ -438,7 +438,7 @@ def evaluate(eval_step: Callable, params, batches: Iterable, *,
     try:
         for dev_batch in it:
             # don't fetch per step: each device_get is a host<->device
-            # round trip (expensive on pods/tunnels) and drains the
+            # round trip (expensive on pods) and drains the
             # dispatch queue.  Windowed instead (like train_one_epoch):
             # one sync per ``check_every`` batches.  The window (plus
             # prefetch depth) also caps how many in-flight INPUT batches

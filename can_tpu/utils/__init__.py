@@ -14,9 +14,12 @@ from .viz import save_density_visualization
 from .profiling import (
     StepTimer,
     await_devices,
+    bench_device,
     device_watchdog,
     emit_null_result,
+    pallas_interpret,
     profile_trace,
+    requested_platform,
 )
 
 __all__ = [
@@ -35,6 +38,9 @@ __all__ = [
     "enable_compilation_cache",
     "default_cache_dir",
     "await_devices",
+    "bench_device",
     "device_watchdog",
     "emit_null_result",
+    "pallas_interpret",
+    "requested_platform",
 ]

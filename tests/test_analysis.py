@@ -312,7 +312,7 @@ class TestProgramContracts:
             pred = cannet_apply(params, image, **kw)
             return (pred.astype(jnp.float64) * 1.0).astype(jnp.float32)
 
-        with jax.experimental.enable_x64(True):
+        with jax.enable_x64(True):
             low = jax.jit(make_train_step(apply_f64, opt)).lower(
                 state, ha._audit_batch(1))
             facts = ha.facts_from_text("train_step_default",

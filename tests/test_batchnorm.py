@@ -529,15 +529,38 @@ class TestBNMomentsImpls:
 
     def test_pallas_unsupported_shape_falls_back(self):
         """Compiled-mode supports(): C % 128 / W % 8 gates; interpret
-        accepts anything; the bn_moments wrapper silently falls back."""
+        accepts anything; the bn_moments wrapper takes the jnp twin for a
+        gated shape and TALLIES the decision (``routed``) — the gate is
+        legitimate, its silence was not."""
         from can_tpu.ops import pallas_bn
+        from can_tpu.ops.bn_moments import masked_moments_pallas
 
-        if not pallas_bn._PALLAS_OK:
-            pytest.skip("pallas unavailable")
         assert pallas_bn.supports((2, 16, 24, 128))
         assert not pallas_bn.supports((2, 16, 24, 64))   # C not 128-mult
         assert not pallas_bn.supports((2, 16, 20, 128))  # W not 8-mult
         assert pallas_bn.supports((2, 16, 20, 64), interpret=True)
+        routed = []
+        y = jax.ShapeDtypeStruct((2, 16, 24, 64), jnp.float32)
+        m = jax.ShapeDtypeStruct((2, 16, 24, 1), jnp.float32)
+        jax.eval_shape(lambda y, m: masked_moments_pallas(
+            y, m, None, interpret=False, routed=routed), y, m)
+        assert routed == [(False, (2, 16, 24, 64))]
+
+    def test_cli_routing_line_counts_kernel_and_twin_layers(self):
+        """cli.common.bn_kernel_routing asks the model itself: compiled
+        mode at a real bucket routes the C >= 128 layers to the kernel
+        and the C=64 stem to the twin; interpret mode takes everything."""
+        from can_tpu.cli.common import bn_kernel_routing
+
+        params = cannet_init(jax.random.key(0), batch_norm=True)
+        kernel, twin = bn_kernel_routing(params, (576, 768),
+                                         interpret=False)
+        assert kernel > 0 and twin > 0
+        assert bn_kernel_routing(params, (576, 768), interpret=True) \
+            == (kernel + twin, 0)
+        # W/8 = 100 -> 100 % 8 != 0 at the deepest stage: more twins
+        k2, t2 = bn_kernel_routing(params, (576, 800), interpret=False)
+        assert k2 + t2 == kernel + twin and t2 > twin
 
 
 class TestSyncBNOnePassSpatial:

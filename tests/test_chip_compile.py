@@ -1,0 +1,171 @@
+"""Ask the chip's compiler before the chip: AOT-compile the main path's
+kernels and programs, at their real widths, for a DESCRIBED v5e:2x2.
+
+The TPU compiler is installed in the CPU-only sandbox and compiles for a
+topology that is described, not attached (on-chip-measurement guide §2,
+rehearsal 3).  It refuses what interpret mode and the CPU backend cannot
+see — a slice not aligned to the tiling, a kernel over its VMEM budget, a
+program over HBM — so these guard every later PR at no chip time.  Nothing
+runs: a compile that passes is not a chip run and says nothing about
+results or speed (``chip_smoke.py`` is the chip run).
+
+``jax.default_backend()`` is still ``cpu`` during such a compile, so code
+that branches on it would take its CPU branch: the kernels / jitted steps
+are compiled directly, with ``interpret=False`` spelled out.
+"""
+
+import functools
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from can_tpu.models import cannet_apply, cannet_init, init_batch_stats
+from can_tpu.parallel.mesh import DATA_AXIS, SPATIAL_AXIS
+from can_tpu.train import create_train_state, make_lr_schedule, make_optimizer
+
+V5E_HBM_BYTES = 16 * 2**30
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four described devices of a v5e:2x2 — skipped where the
+    topology cannot be described.  The persistent compile cache is off
+    around these compiles: an entry written for a described chip cannot be
+    read back without one, and the next run would warn and recompile."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield list(topo.devices)
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _mesh(devices, dp, sp):
+    return Mesh(np.asarray(devices).reshape(dp, sp), (DATA_AXIS, SPATIAL_AXIS))
+
+
+def _batch(mesh, gb, h, w, *, spatial=False):
+    big = NamedSharding(mesh, P(DATA_AXIS, SPATIAL_AXIS, None, None)
+                        if spatial else P(DATA_AXIS))
+    row = NamedSharding(mesh, P(DATA_AXIS))
+    f32 = jnp.float32
+    return {
+        "image": jax.ShapeDtypeStruct((gb, h, w, 3), f32, sharding=big),
+        "dmap": jax.ShapeDtypeStruct((gb, h // 8, w // 8, 1), f32,
+                                     sharding=big),
+        "pixel_mask": jax.ShapeDtypeStruct((gb, h // 8, w // 8, 1), f32,
+                                           sharding=big),
+        "sample_mask": jax.ShapeDtypeStruct((gb,), f32, sharding=row),
+    }
+
+
+def _state(mesh, opt, *, batch_norm=False):
+    """Shapes only: ``device_put`` to a described device fails."""
+    def make():
+        params = cannet_init(jax.random.key(0), batch_norm=batch_norm)
+        return create_train_state(
+            params, opt, init_batch_stats(params) if batch_norm else None)
+
+    repl = NamedSharding(mesh, P())
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=repl),
+        jax.eval_shape(make))
+
+
+def _fits_hbm(compiled):
+    ma = compiled.memory_analysis()
+    used = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
+            + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+    assert used < V5E_HBM_BYTES, f"{used / 2**30:.2f} GiB per device"
+    return used
+
+
+# the three real BN shapes the kernel's shape gate admits at b16 x 576x768
+@pytest.mark.parametrize("shape", [(16, 288, 384, 128), (16, 144, 192, 256),
+                                   (16, 72, 96, 512)])
+def test_pallas_bn_moment_sums_compiles_to_a_tpu_kernel(v5e, shape):
+    from can_tpu.ops import pallas_bn
+
+    assert pallas_bn.supports(shape)
+    one = SingleDeviceSharding(v5e[0])
+    y = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+    m = jax.ShapeDtypeStruct(shape[:3] + (1,), jnp.float32, sharding=one)
+    compiled = jax.jit(functools.partial(
+        pallas_bn.moment_sums, interpret=False)).lower(y, m).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits_hbm(compiled)
+
+
+def test_bf16_serve_predict_compiles_for_one_device(v5e):
+    """The program ``cli.serve --serve-dtype bf16`` warms for a 576x768
+    bucket at --max-batch 4 — the engine's own jitted predict, lowered for
+    one described device."""
+    from can_tpu.data.batching import pad_batch
+    from can_tpu.obs.costs import resolve_jit
+    from can_tpu.serve import ServeEngine
+    from can_tpu.serve.engine import _batch_dict
+
+    engine = ServeEngine(cannet_init(jax.random.key(0)), serve_dtype="bf16")
+    batch = _batch_dict(pad_batch(
+        [(np.zeros((576, 768, 3), np.float32),
+          np.zeros((72, 96, 1), np.float32))], (576, 768), 4, [False], 8))
+    one = SingleDeviceSharding(v5e[0])
+
+    def described(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one), tree)
+
+    args = (engine.params, batch, None)
+    compiled = resolve_jit(engine._predict, args).lower(
+        described(engine.params), described(batch), None).compile()
+    assert "bf16" in compiled.as_text()
+    _fits_hbm(compiled)
+
+
+@pytest.mark.slow
+def test_bf16_train_step_b16_fits_one_chip(v5e):
+    """The driver's cell: ``make_dp_train_step`` b16 x 576x768 bf16 on one
+    device (~45 s).  ~11 GB of temporaries on a 16 GB chip: it fits, and
+    whatever else is resident matters."""
+    from can_tpu.parallel import make_dp_train_step
+
+    mesh = _mesh(v5e[:1], 1, 1)
+    opt = make_optimizer(make_lr_schedule(1e-7))
+    step = make_dp_train_step(cannet_apply, opt, mesh,
+                              compute_dtype=jnp.bfloat16)
+    compiled = step.lower(_state(mesh, opt),
+                          _batch(mesh, 16, 576, 768)).compile()
+    assert _fits_hbm(compiled) > 8 * 2**30  # not a toy program
+
+
+@pytest.mark.slow
+def test_dp2_sp2_train_step_compiles_on_the_2x2_mesh(v5e):
+    """``--sp 2`` on four chips: the shard_map step with halo ``ppermute``
+    and psum'd pooling, dp=2 x sp=2, global b16 x 576x768 bf16."""
+    from can_tpu.parallel.spatial import make_sp_train_step
+
+    mesh = _mesh(v5e, 2, 2)
+    opt = make_optimizer(make_lr_schedule(1e-7, world_size=2))
+    step = make_sp_train_step(opt, mesh, (576, 768),
+                              compute_dtype=jnp.bfloat16)
+    compiled = step.lower(_state(mesh, opt),
+                          _batch(mesh, 16, 576, 768, spatial=True)).compile()
+    text = compiled.as_text()
+    assert "collective-permute" in text  # the halo exchange
+    assert "all-reduce" in text          # gradient / pooling psums
+    _fits_hbm(compiled)

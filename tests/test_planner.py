@@ -30,7 +30,7 @@ from can_tpu.data.planner import (
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # the r5 chip configuration's per-launch pixel cap (v5e spec HBM via the
-# device-kind fallback, bf16, single chip) — what BENCH_SUITE_r05 ran under
+# device-kind fallback, bf16, single chip) — what the r5 sweep ran under
 V5E_CAP = 0.92 * (16 * 2**30 * 0.97) / 1100.0
 
 
@@ -254,7 +254,7 @@ class TestAcceptanceHeadline:
         assert b.padding_overhead() <= 0.0961 + 5e-4  # no padding regression
         assert b.program_count(1) <= 24
 
-    def test_cost_planner_improves_even_at_tunnel_pricing(self):
+    def test_cost_planner_improves_even_at_2mpx_pricing(self):
         b = mk(bench_shapes(), 16, launch_cost_px=2e6, max_launch_px=V5E_CAP)
         assert b.schedule_overhead(1) < 0.3067 - 1e-3
 
@@ -315,12 +315,12 @@ class TestPlanSpaceTier:
         path = os.path.join(REPO, "PLAN_ABLATION_r08.json")
         doc = json.load(open(path))
         head = doc["headline"]
-        assert head["baseline_legacy_tunnel_pricing"]["schedule_overhead"] \
+        assert head["baseline_legacy_2mpx_pricing"]["schedule_overhead"] \
             == pytest.approx(0.3067, abs=5e-4)
         assert head["cost_planner_device_pricing"]["schedule_overhead"] \
             <= 0.24
         assert (head["cost_planner_device_pricing"]["padding_overhead"]
-                <= head["baseline_legacy_tunnel_pricing"]["padding_overhead"]
+                <= head["baseline_legacy_2mpx_pricing"]["padding_overhead"]
                 + 5e-4)
 
 

@@ -39,10 +39,9 @@ class TestHaloExchange:
         mesh = make_mesh(jax.devices()[:4], dp=1, sp=4)
         x = np.arange(4 * 8 * 2 * 1, dtype=np.float32).reshape(1, 32, 2, 1)
 
-        # the library's version-compat shim (top-level on jax >= 0.6,
-        # experimental + check_rep spelling on older jax)
-        from can_tpu.parallel.spatial import shard_map
         from functools import partial
+
+        from jax import shard_map
 
         @partial(shard_map, mesh=mesh,
                  in_specs=P(None, SPATIAL_AXIS, None, None),

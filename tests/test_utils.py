@@ -7,7 +7,10 @@ std typo, utils/train_eval_utils.py:92-95, is exactly the bug this would
 catch), file outputs, and logger gating.
 """
 
+import os
+
 import numpy as np
+import pytest
 
 from can_tpu.data import normalize_host
 from can_tpu.utils import MetricLogger, save_density_visualization
@@ -83,32 +86,82 @@ class TestMetricLogger:
 
 
 class TestCompileCache:
-    def test_enable_creates_dir_and_sets_config(self, tmp_path):
+    """Where the cache lives is decided outside the program:
+    JAX_COMPILATION_CACHE_DIR when set (and then no code sets a
+    directory), else one fixed path inside the checkout."""
+
+    @pytest.fixture(autouse=True)
+    def _restore_jax_cache_config(self):
+        import jax
+
+        names = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
+                 "jax_persistent_cache_min_compile_time_secs",
+                 "jax_persistent_cache_min_entry_size_bytes")
+        prev = {n: getattr(jax.config, n) for n in names}
+        yield
+        for n, v in prev.items():
+            jax.config.update(n, v)
+
+    def test_enable_creates_dir_and_sets_config(self, tmp_path, monkeypatch):
         import jax
 
         from can_tpu.utils import enable_compilation_cache
 
-        prev = jax.config.jax_compilation_cache_dir
-        try:
-            d = tmp_path / "xla_cache"
-            got = enable_compilation_cache(str(d))
-            assert got == str(d)
-            assert d.is_dir()
-            assert jax.config.jax_compilation_cache_dir == str(d)
-        finally:
-            jax.config.update("jax_compilation_cache_dir", prev)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        d = tmp_path / "xla_cache"
+        got = enable_compilation_cache(str(d))
+        assert got == str(d)
+        assert d.is_dir()
+        assert jax.config.jax_compilation_cache_dir == str(d)
 
-    def test_off_disables(self):
+    def test_off_disables(self, monkeypatch):
         from can_tpu.utils import enable_compilation_cache
 
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         assert enable_compilation_cache("off") is None
         assert enable_compilation_cache("none") is None
 
-    def test_default_dir_env_override(self, monkeypatch, tmp_path):
+    def test_default_dir_is_fixed_inside_checkout(self, monkeypatch,
+                                                  tmp_path):
+        """Unset: <repo>/.jax_cache, derived from the package location —
+        not from ~, the cwd, a pid or the time (the path is part of the
+        cache key)."""
+        import can_tpu
         from can_tpu.utils import default_cache_dir
 
-        monkeypatch.setenv("CAN_TPU_COMPILE_CACHE", str(tmp_path))
-        assert default_cache_dir() == str(tmp_path)
+        repo = os.path.dirname(os.path.dirname(
+            os.path.abspath(can_tpu.__file__)))
+        monkeypatch.setenv("HOME", str(tmp_path))
+        monkeypatch.chdir(tmp_path)
+        assert default_cache_dir() == os.path.join(repo, ".jax_cache")
+        assert default_cache_dir() == default_cache_dir()
+
+    def test_env_var_set_means_code_sets_no_dir(self, monkeypatch, tmp_path):
+        """JAX_COMPILATION_CACHE_DIR set: the directory is JAX's to read;
+        every jax.config.update the call makes is recorded, and none of
+        them may name a cache dir.  An explicit directory is refused;
+        "off" still means cold."""
+        import jax
+
+        from can_tpu.utils import enable_compilation_cache
+
+        env_dir = str(tmp_path / "from_env")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        # JAX snapshots the variable at import; stand in for that here
+        jax.config.update("jax_compilation_cache_dir", env_dir)
+        updates = []
+        real_update = jax.config.update
+        monkeypatch.setattr(
+            jax.config, "update",
+            lambda name, val: (updates.append(name), real_update(name, val)))
+        assert enable_compilation_cache() == env_dir
+        assert "jax_compilation_cache_dir" not in updates
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+        with pytest.raises(ValueError, match="JAX_COMPILATION_CACHE_DIR"):
+            enable_compilation_cache(str(tmp_path / "elsewhere"))
+        assert enable_compilation_cache("off") is None
+        assert jax.config.jax_enable_compilation_cache is False
+        assert "jax_compilation_cache_dir" not in updates
 
 
 class TestStepTimer:

@@ -1,8 +1,11 @@
 """Build the native density-stamping library (can_tpu/native/).
 
 Usage: python tools/build_native.py
-Produces can_tpu/native/libdensity_stamp.so; can_tpu/data/density.py picks it
-up automatically (and falls back to numpy when absent).
+Produces can_tpu/native/libdensity_stamp.so from density_stamp.cpp (g++).
+The library is git-ignored — a fresh checkout has none — and
+can_tpu/data/density.py uses it when present, numpy when not (identical
+output); it prints ``[density] stamping path: native|numpy`` once at load,
+so a run always says which one it took.
 """
 
 from __future__ import annotations

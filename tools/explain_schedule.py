@@ -65,7 +65,7 @@ def main() -> int:
                          "'--launch-cost-mpx auto needs no correction' "
                          "(CHANGES.md r5): plans are typically flat below "
                          "0.05 Mpx (sub-ms hosts) and above ~1 Mpx "
-                         "(tunnels), so only 2.5-25 ms dispatch costs are "
+                         "(slow-dispatch hosts), so only 2.5-25 ms dispatch costs are "
                          "decision-sensitive")
     args = ap.parse_args()
 

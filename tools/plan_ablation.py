@@ -5,7 +5,7 @@ Runs ``bench_suite.bench_plan_space`` — the SIMULATED sweep over the
 batch planner's candidate space (plan mode x launch pricing x batch) on
 the suite's varres distribution under the v5e HBM cap — and writes one
 JSON document with the per-candidate records plus a headline block
-comparing the r5 shipped plan (legacy mode, tunnel launch pricing:
+comparing the r5 shipped plan (legacy mode, 2.0 Mpx launch pricing:
 30.67% schedule overhead at b16) against the round-8 cost-model planner
 at device-regime pricing, which is the configuration the suite's quoted
 steady-state compute number actually runs in.
@@ -39,13 +39,13 @@ def headline(records: list) -> dict:
 
     from can_tpu.cli.common import DEVICE_LAUNCH_COST_MPX
 
-    baseline = find("legacy", 2.0)   # == BENCH_SUITE_r05's shipped plan
+    baseline = find("legacy", 2.0)   # == the r5 sweep's shipped plan
     tuned = find("cost", DEVICE_LAUNCH_COST_MPX)
     same_l = find("cost", 2.0)       # search contribution, pricing held
     return {
         "config": "b16 varres, max_buckets=24, v5e HBM cap "
                   f"({baseline['max_launch_mpx']} Mpx/launch)",
-        "baseline_legacy_tunnel_pricing": {
+        "baseline_legacy_2mpx_pricing": {
             "schedule_overhead": baseline["value"],
             "padding_overhead": baseline["padding_overhead"],
             "programs": baseline["programs"],
@@ -56,7 +56,7 @@ def headline(records: list) -> dict:
             "programs": same_l["programs"],
             "note": "search contribution alone: boundary placement + "
                     "exact menus + packing, launch price held at the "
-                    "tunnel's 2.0 Mpx — the model still trades pixels "
+                    "baseline's 2.0 Mpx — the model still trades pixels "
                     "for launches at that price",
         },
         "cost_planner_device_pricing": {
@@ -87,7 +87,7 @@ def main(argv=None) -> int:
                 "distribution under the v5e per-launch HBM cap, legacy "
                 "vs cost-model planner across launch pricings. "
                 "Overheads are exact properties of the emitted schedule; "
-                "the b16 legacy L=2.0 row reproduces BENCH_SUITE_r05's "
+                "the b16 legacy L=2.0 row reproduces the r5 sweep's "
                 "0.3067 bit-for-bit. plan_s fields are this host's plan "
                 "build time (median of repeats, spread recorded).",
         "headline": headline(records),
