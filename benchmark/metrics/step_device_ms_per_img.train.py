@@ -1,0 +1,5 @@
+"""Chip-milliseconds of the train-step programs per image, from the XLA Modules line of the traced launches."""
+
+
+def read(ctx):
+    return ctx["trace"].get("device_ms_per_img")
