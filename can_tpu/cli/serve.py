@@ -194,6 +194,14 @@ def parse_args(argv=None):
                    help="write serve.request/serve.batch/serve.reject "
                         "JSONL here (tools/telemetry_report.py summarises)")
     p.add_argument("--telemetry-heartbeat-s", type=float, default=60.0)
+    p.add_argument("--profile-dir", type=str, default="",
+                   help="jax.profiler trace output dir (with --trace-steps)")
+    p.add_argument("--trace-steps", type=str, default="",
+                   help="profile only launched batches START:STOP (python "
+                        "slice semantics, counted from the first batch "
+                        "after warmup) into --profile-dir (the device "
+                        "alone; tools/trace_export.py --profile draws the "
+                        "serve.* spans beside it)")
     p.add_argument("--metrics-port", type=int, default=None,
                    help="serve Prometheus-text /metrics + /healthz on this "
                         "port (0 = ephemeral): the service's /stats "
@@ -425,17 +433,19 @@ def main(argv=None) -> int:
         apply_platform,
         build_telemetry,
         validate_incident_args,
+        validate_trace_args,
     )
     from can_tpu.parallel import init_runtime, process_index, shutdown_runtime
     from can_tpu.serve import serve_http
 
+    trace_window = validate_trace_args(args)
     validate_incident_args(args)
     apply_platform(args)
     topo = init_runtime()
     apply_compile_cache(args, announce=True)
     print(f"[runtime] {topo}")
     telemetry, heartbeat, exporter = build_telemetry(
-        args, host_id=process_index(), trace_window=None)
+        args, host_id=process_index(), trace_window=trace_window)
     try:
         service = build_service(args, telemetry=telemetry)
         if exporter is not None:

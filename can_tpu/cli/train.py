@@ -428,6 +428,8 @@ def build_telemetry(args, *, host_id: int, trace_window, logger=None,
         tel.ledger = obs.ProgramCostLedger(
             compute="bf16" if getattr(args, "bf16", False) else "f32")
         tel.spans = obs.SpanTracer(tel)
+        if trace is not None:
+            trace.spans = tel.spans
     run_config = {k: v for k, v in vars(args).items()
                   if isinstance(v, (str, int, float, bool, type(None)))}
     if slo_spec_path:
@@ -1054,7 +1056,8 @@ def _run_elastic_generations(args, run_cfg, topo, *, supervisor,
         world_closed = False  # elastic branch closes early, pre-reform
         try:
             with profile_trace(None if trace_window
-                               else (args.profile_dir or None)):
+                               else (args.profile_dir or None),
+                               spans=telemetry.spans):
                 for epoch in range(start_epoch, args.epochs):
                     inc = include if epoch == start_epoch else None
                     total = (steps_per_epoch if inc is None else

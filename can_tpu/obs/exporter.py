@@ -447,7 +447,8 @@ def render_stats(stats: dict, *, prefix: str = "can_tpu_serve",
     the fleet's ``"replicas"`` sub-dicts, whose numeric entries become
     per-replica LABELLED lines (``can_tpu_serve_batches_total{replica=
     "k"}``), so one scrape shows which replica is serving, quarantined,
-    or lagging a rollout generation."""
+    or lagging a rollout generation, and ``"flush_reasons"``, whose counts
+    become ``can_tpu_serve_flushes_total{reason="full"}`` lines."""
     gauges: Dict[str, float] = {}
     counters: Dict[Tuple[str, tuple], float] = {}
     labelled_gauges: Dict[Tuple[str, tuple], float] = {}
@@ -465,6 +466,12 @@ def render_stats(stats: dict, *, prefix: str = "can_tpu_serve",
                         counters[(f"{name}_total", label)] = sv
                     else:  # quarantined/generation: state gauges
                         labelled_gauges[(name, label)] = sv
+            continue
+        if k == "flush_reasons" and isinstance(v, dict):
+            # launched batches by why their group was flushed
+            for reason, n in v.items():
+                counters[(f"{prefix}_flushes_total",
+                          (("reason", str(reason)),))] = n
             continue
         if v is None or not isinstance(v, (int, float, bool)):
             continue

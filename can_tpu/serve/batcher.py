@@ -56,12 +56,18 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from can_tpu.data.batching import Batch, pad_batch, snap_to_bucket
+from can_tpu.obs.spans import active
 from can_tpu.serve.queue import (
     REJECT_DEADLINE,
     REJECT_ERROR,
     BoundedRequestQueue,
     ServeRequest,
 )
+
+# why a group was flushed, named where it is decided: ``intake`` saw it
+# reach the top launch size, ``poll`` saw its priced deadline (or the
+# legacy timer) arrive, ``flush_all`` drained it at shutdown
+FLUSH_FULL, FLUSH_DUE, FLUSH_DRAIN = "full", "due", "drain"
 
 # (bucket H, bucket W, image dtype): dtype is part of the jit signature, so
 # u8 and f32 requests must not share a batch buffer (pad_batch keeps the
@@ -130,6 +136,12 @@ class MicroBatcher:
         self._clock = clock
         self._idle_wait_s = float(idle_wait_s)
         self._pending: Dict[GroupKey, _Group] = {}
+        # launched batches by flush reason (batcher thread writes; the
+        # service's stats() copies)
+        self.flush_reasons = {FLUSH_FULL: 0, FLUSH_DUE: 0, FLUSH_DRAIN: 0}
+        # the trace the thread's own cycle (wait / intake / poll) is
+        # recorded under; minted on the first traced cycle
+        self._lane: Optional[str] = None
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
 
@@ -168,14 +180,35 @@ class MicroBatcher:
         pending flush deadline), intake, flush what's due.  Returns the
         number of batches dispatched."""
         wait = self.next_wake_s() if wait_s is None else wait_s
-        self.queue.wait_nonempty(wait)
+        tr = active(self.telemetry)
+        if tr is None:
+            self.queue.wait_nonempty(wait)
+        else:
+            with self._cycle_span(tr, "serve.wait"):
+                self.queue.wait_nonempty(wait)
         n = self.intake()
         return n + self.poll(self._clock())
+
+    def _cycle_span(self, tr, name: str):
+        """A span of the batcher thread's own cycle, on the thread's
+        lane (one trace for the batcher's life)."""
+        if self._lane is None:
+            self._lane = tr.new_trace_id("batcher")
+        return tr.span(name, trace_id=self._lane)
 
     def intake(self) -> int:
         """Drain the queue into per-bucket pending groups; reject already
         expired requests; flush any group that reaches the top launch
         size.  Returns batches dispatched."""
+        tr = active(self.telemetry)
+        if tr is None:
+            return self._intake()[0]
+        with self._cycle_span(tr, "serve.intake") as sp:
+            flushed, sp.attrs["taken"] = self._intake()
+        return flushed
+
+    def _intake(self) -> Tuple[int, int]:
+        """-> (batches dispatched, requests taken off the queue)."""
         live, expired = self.queue.drain()
         for r in expired:
             self._reject_expired(r)
@@ -192,13 +225,20 @@ class MicroBatcher:
                 self.sched.observe_arrival(key, r.t_submit)
             if len(group.requests) >= self.max_batch:
                 del self._pending[key]
-                flushed += self._flush(key, group.requests)
-        return flushed
+                flushed += self._flush(key, group.requests, FLUSH_FULL)
+        return flushed, len(live) + len(expired)
 
     def poll(self, now: float) -> int:
         """Reject expired pending requests; flush groups whose priced
         deadline (or legacy timer) has arrived.  Returns batches
         dispatched."""
+        tr = active(self.telemetry)
+        if tr is None:
+            return self._poll(now)
+        with self._cycle_span(tr, "serve.poll"):
+            return self._poll(now)
+
+    def _poll(self, now: float) -> int:
         flushed = 0
         for key in sorted(self._pending):
             group = self._pending[key]
@@ -214,23 +254,31 @@ class MicroBatcher:
             group.requests = kept
             if now >= self._flush_at(key, group, now):
                 del self._pending[key]
-                flushed += self._flush(key, kept)
+                flushed += self._flush(key, kept, FLUSH_DUE)
         return flushed
 
     def flush_all(self) -> int:
         """Dispatch every pending group (shutdown path: an admitted request
         resolves even when the service is closing)."""
+        tr = active(self.telemetry)
+        if tr is None or not self._pending:
+            return self._flush_all()
+        with self._cycle_span(tr, "serve.drain"):
+            return self._flush_all()
+
+    def _flush_all(self) -> int:
         n = 0
         for key in sorted(self._pending):
             group = self._pending.pop(key)
-            n += self._flush(key, group.requests)
+            n += self._flush(key, group.requests, FLUSH_DRAIN)
         return n
 
     def pending_count(self) -> int:
         return sum(len(g.requests) for g in self._pending.values())
 
     # -- assembly + dispatch --------------------------------------------
-    def _flush(self, key: GroupKey, group: List[ServeRequest]) -> int:
+    def _flush(self, key: GroupKey, group: List[ServeRequest],
+               reason: str) -> int:
         """Cover the group with menu-size launches (one launch padded to
         ``max_batch`` without a core) and dispatch each.  Returns the
         number of batches dispatched."""
@@ -248,17 +296,29 @@ class MicroBatcher:
             pos += size
             if not take:
                 break
-            self._flush_part(key, take, size)
+            self.flush_reasons[reason] += 1
+            tr = active(self.telemetry)
+            if tr is None:
+                self._flush_part(key, take, size)
+            else:
+                # the root of the batch's own trace; on the thread's lane
+                # a child of the cycle span that launched it
+                with tr.span("serve.batch", trace_id=tr.new_trace_id("batch"),
+                             bucket=[key[0], key[1]], slots=size,
+                             valid=len(take), flush_reason=reason) as sp:
+                    for r in take:
+                        r.batch_span = sp
+                    self._flush_part(key, take, size, tr)
             n += 1
         return n
 
     def _flush_part(self, key: GroupKey, group: List[ServeRequest],
-                    size: int) -> None:
+                    size: int, tr=None) -> None:
         bh, bw = key[0], key[1]
         try:
             # assembly window stamped on every request (service clock):
             # queue-wait ends where assembly starts, and the service turns
-            # the pair into the serve.request breakdown + request spans
+            # the pair into the serve.request breakdown
             t_asm = self._clock()
             # zero per-item density targets: serve batches reuse the
             # offline Batch layout (image/dmap/pixel_mask/sample_mask) so
@@ -268,8 +328,14 @@ class MicroBatcher:
                       np.zeros((r.shape[0] // self.ds,
                                 r.shape[1] // self.ds, 1), np.float32))
                      for r in group]
-            batch = pad_batch(items, (bh, bw), size,
-                              [True] * len(group), self.ds)
+            if tr is None:
+                batch = pad_batch(items, (bh, bw), size,
+                                  [True] * len(group), self.ds)
+            else:
+                with tr.span("serve.pad") as sp:
+                    batch = pad_batch(items, (bh, bw), size,
+                                      [True] * len(group), self.ds)
+                    sp.attrs["bytes"] = int(batch.image.nbytes)
             t_ready = self._clock()
             for r in group:
                 r.t_assembly = t_asm

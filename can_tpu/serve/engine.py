@@ -40,6 +40,7 @@ import numpy as np
 from can_tpu.data.batching import Batch, pad_batch
 from can_tpu.models import cannet_apply
 from can_tpu.obs import RecompileTracker, Telemetry
+from can_tpu.obs.spans import active
 from can_tpu.serve.quant import (
     compute_dtype_for,
     dequantize_tree,
@@ -189,6 +190,21 @@ class ServeEngine:
         prog = (self._aot.get((tuple(batch.image.shape),
                                str(batch.image.dtype)))
                 if self._aot else None)
+        tr = active(self.telemetry)
+        if tr is None:
+            counts, density = self._launch(prog, batch)
+            return self._fetch(counts, density, want_density)
+        # dispatch: the call of the program up to its return, which is
+        # the enqueue (the runtime changes the batch's layout and copies
+        # it to the device on threads of its own); fetch: the wait for
+        # those, for the program, and the D2H
+        with tr.span("serve.dispatch", aot=prog is not None) as sp:
+            counts, density = self._launch(prog, batch)
+            sp.attrs["compiled"] = self._last_compiled
+        with tr.span("serve.fetch", density=bool(want_density)):
+            return self._fetch(counts, density, want_density)
+
+    def _launch(self, prog, batch: Batch):
         if prog is not None:
             counts, density = prog(self.params, _batch_dict(batch),
                                    self.batch_stats)
@@ -199,6 +215,10 @@ class ServeEngine:
                                             _batch_dict(batch),
                                             self.batch_stats)
             self._last_compiled = self._predict.last_first_call
+        return counts, density
+
+    @staticmethod
+    def _fetch(counts, density, want_density: bool):
         # can-tpu-lint: disable=HOSTSYNC(the fetch IS the product: callers resolve waiting requests with it)
         return (np.asarray(counts),
                 # can-tpu-lint: disable=HOSTSYNC(fetched only when a request asked for the density tensor)

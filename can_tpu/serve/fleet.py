@@ -76,6 +76,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from can_tpu.obs import Telemetry
+from can_tpu.obs.spans import active
 from can_tpu.serve.aot import bake_aot_bundle, load_aot_bundle, signature_sha
 from can_tpu.serve.engine import ServeEngine, tree_signature
 from can_tpu.serve.quant import host_tree, quantize_tree
@@ -1127,7 +1128,7 @@ class FleetEngine:
         with self._rollout_lock:
             t0 = time.perf_counter()
             gen = self.generation + 1
-            spans = getattr(self.telemetry, "spans", None)
+            spans = active(self.telemetry)
             trace_id = (spans.new_trace_id(f"rollout-g{gen}")
                         if spans is not None else None)
 

@@ -115,12 +115,17 @@ class ServeRequest:
         # stream registry tracks per-stream backlog (two independent
         # observers, so a single slot would drop one)
         self._done_hooks: List = []
-        # span plumbing (all in the request's own clock): trace_id is
+        # breakdown plumbing (in the request's own clock): trace_id is
         # minted by CountService.submit; the batcher stamps the assembly
         # window so the service can price queue-wait vs device time
         self.trace_id: Optional[str] = None
         self.t_assembly: Optional[float] = None  # batch assembly began
         self.t_ready: Optional[float] = None     # padded batch handed off
+        # span plumbing (obs/spans.py, perf_counter; both None with
+        # tracing off): the submit stamp the request / queue_wait spans
+        # are drawn from, and the open serve.batch span that launched it
+        self.t_trace: Optional[float] = None
+        self.batch_span = None
 
     def expired(self, now: float) -> bool:
         return self.deadline_ts is not None and now >= self.deadline_ts

@@ -44,6 +44,7 @@ from typing import Optional, Sequence, Tuple
 import numpy as np
 
 from can_tpu.data.dataset import normalize_host
+from can_tpu.obs.spans import active
 from can_tpu.serve.batcher import MicroBatcher
 from can_tpu.serve.engine import ServeEngine
 from can_tpu.serve.queue import (
@@ -328,15 +329,19 @@ class CountService:
         req = ServeRequest(np.asarray(image), deadline_s=deadline_s,
                            want_density=want_density, clock=self._clock,
                            stream_id=stream_id, frame_seq=frame_seq)
-        # the trace is born at the front door: every span of this
-        # request's life (queue wait -> assembly -> device -> respond)
-        # keys on this id, and HTTP clients get it back in the response.
+        # the trace is born at the front door: the request's spans
+        # (request, queue_wait; its batch's phases link by ``batch``)
+        # key on this id, and HTTP clients get it back in the response.
         # A caller-provided id (the X-CanTpu-Trace-Id request header, or
         # an upstream service propagating its own) wins over minting —
         # that is what stitches one trace ACROSS hosts: every hop's
         # spans key on the same id, and the fleet collector's snapshot
         # exports them as one skew-corrected timeline
         req.trace_id = trace_id or f"{self._trace_prefix}-{req.id}"
+        if active(self.telemetry) is not None:
+            # the spans' clock, not the service's injectable one (that
+            # one stays for deadlines and fake-clock tests)
+            req.t_trace = time.perf_counter()
         if req.shape[0] % self.engine.ds or req.shape[1] % self.engine.ds:
             raise ValueError(
                 f"image shape {req.shape} is not snapped to the /"
@@ -473,6 +478,9 @@ class CountService:
             # per-stream sessions (serve/streams.py): the operator's
             # view of the degradation ladder and sticky routing
             "streams": self.streams.stats(),
+            # launched batches by why their group was flushed (full /
+            # due / drain; serve/batcher.py)
+            "flush_reasons": dict(self.batcher.flush_reasons),
         }
         if self._fleet is not None:
             # per-replica rows: service-side work counters joined with the
@@ -525,8 +533,7 @@ class CountService:
         counts, density = self.engine.predict_batch(
             batch, want_density=any(r.want_density for r in requests))
         # execute_s stays on perf_counter (honest wall time even under
-        # the fake clocks the tests drive); the CLOCK stamps below anchor
-        # the spans in the same timeline as t_submit/deadlines
+        # the fake clocks the tests drive)
         execute_s = time.perf_counter() - t0
         compiled = self.engine.last_batch_compiled
         self._complete(bucket_hw, batch, requests, counts, density,
@@ -536,21 +543,28 @@ class CountService:
     def _complete(self, bucket_hw, batch, requests, counts, density,
                   execute_s, compiled, replica=None,
                   program: str = "serve_predict", t_exec0=None) -> None:
-        t_exec1 = self._clock()
+        if getattr(self.telemetry, "trace", None) is not None:
+            # an operator's --trace-steps window counts launched batches
+            self.telemetry.step_tick()
+        done = (bucket_hw, batch, requests, counts, density, execute_s,
+                compiled, replica, program, t_exec0)
+        tr = active(self.telemetry)
+        if tr is None:
+            self._resolve_batch(*done, None)
+        else:
+            with tr.span("serve.complete", resolved=len(requests)):
+                self._resolve_batch(*done, tr)
+
+    def _resolve_batch(self, bucket_hw, batch, requests, counts, density,
+                       execute_s, compiled, replica, program, t_exec0,
+                       tr) -> None:
         if t_exec0 is None:
             # fleet path: the worker measured execute_s on perf_counter;
-            # anchor the device span by subtracting it on the service
-            # clock (exact for the default monotonic clock, and merely a
-            # display anchor under test fake clocks)
-            t_exec0 = t_exec1 - execute_s
+            # place the execute window by subtracting it on the service
+            # clock (exact for the default monotonic clock)
+            t_exec0 = self._clock() - execute_s
         fill = len(requests) / batch.image.shape[0]
         now = self._clock()
-        spans = getattr(self.telemetry, "spans", None)
-        # per-slot respond spans tile [t_exec1, ...] back to back: each
-        # slot's span starts where the previous slot finished, so a late
-        # slot's respond shows ITS OWN density fetch/resolve cost, not
-        # the sum of every sibling processed before it in this loop
-        t_resp0 = t_exec1
         for slot, req in enumerate(requests):
             h, w = req.shape
             dens = (np.asarray(density[slot, : h // self.engine.ds,
@@ -590,25 +604,20 @@ class CountService:
                                queue_wait_s=round(queue_wait, 6),
                                assembly_s=round(max(t_ready - t_asm, 0.0), 6),
                                device_s=round(execute_s, 6))
-            if spans is not None:
-                # the submit->respond tree the Chrome export renders: one
-                # request-root with the four phases as children (device
-                # start anchored on the service clock, width = the real
-                # execute wall time)
-                t_done = self._clock()
-                root = spans.emit(trace_id=req.trace_id, name="request",
-                                  start=req.t_submit, end=t_done,
-                                  bucket=list(bucket_hw), ok=True)
-                spans.emit(trace_id=req.trace_id, name="queue_wait",
-                           start=req.t_submit, end=t_asm, parent_id=root)
-                spans.emit(trace_id=req.trace_id, name="batch_assembly",
-                           start=t_asm, end=t_ready, parent_id=root)
-                spans.emit(trace_id=req.trace_id, name="device",
-                           start=t_exec0, end=t_exec0 + execute_s,
-                           parent_id=root, compiled=compiled)
-                spans.emit(trace_id=req.trace_id, name="respond",
-                           start=t_resp0, end=t_done, parent_id=root)
-                t_resp0 = t_done
+            if tr is not None and req.t_trace is not None:
+                # the request's own two spans, from the perf_counter
+                # stamp it took at submit; the phases of its batch are
+                # recorded once, on the batch (``batch`` links to it)
+                sp = req.batch_span
+                t_done = time.perf_counter()
+                root = tr.emit(trace_id=req.trace_id, name="request",
+                               start=req.t_trace, end=t_done,
+                               bucket=list(bucket_hw), ok=True,
+                               batch=None if sp is None else sp.span_id)
+                if sp is not None:
+                    tr.emit(trace_id=req.trace_id, name="queue_wait",
+                            start=req.t_trace, end=sp.start,
+                            parent_id=root)
         with self._lock:
             self._stats["completed"] += len(requests)
             self._stats["batches"] += 1

@@ -20,12 +20,14 @@ Differences from the reference, by design:
 from __future__ import annotations
 
 import math
+import threading
 import time
 from typing import Callable, Iterable, NamedTuple, Optional
 
 import jax
 import numpy as np
 
+from can_tpu.obs.spans import active
 from can_tpu.parallel.elastic import ElasticInterrupt
 from can_tpu.train.steps import NonFiniteLossError
 
@@ -201,16 +203,18 @@ def train_one_epoch(train_step: Callable, state, batches: Iterable, *,
         health = None
     train_step, timer, stall = _arm_telemetry(telemetry, train_step,
                                               name="train_step")
-    # span tracing (obs/spans.py): one trace per epoch, a child span pair
-    # per metric-flush window (steps / metric_flush) plus a synthesized
-    # fetch_stall span — the step-scoped timeline the ISSUE's "where did
-    # the milliseconds go" question needs.  None on default runs.
-    spans = (getattr(telemetry, "spans", None)
-             if telemetry is not None else None)
-    trace_id = root_id = None
+    # span tracing (obs/spans.py): one trace per call, rooted at
+    # train_epoch; under it a span pair per metric-flush window (steps /
+    # metric_flush), one train.dispatch per step, train.turnover up to
+    # the first batch, and the prefetcher's input.load / input.put /
+    # input.wait.  None on default runs.
+    spans = active(telemetry)
+    trace_id = root_id = lane = None
     if spans is not None:
         trace_id = spans.new_trace_id(f"train.e{epoch}")
         root_id = spans.new_span_id()  # root emitted at epoch end
+        # spans stamped after the fact say whose time they are too
+        lane = threading.current_thread().name
     loss_sum = 0.0
     img_sum = 0.0
     flushed_img = 0.0  # img_sum at the last window flush (per-window delta)
@@ -220,8 +224,37 @@ def train_one_epoch(train_step: Callable, state, batches: Iterable, *,
     pending = []  # still-async metrics awaiting a windowed flush
     t0 = time.perf_counter()
     t_window = t0
+    timed = telemetry is not None or spans is not None
+
+    def _close_window(w0: float, t_flush: float, win: dict) -> float:
+        """A metric-flush window has just been fetched: its step_window
+        event and its steps / metric_flush span pair.  Returns the next
+        window's start."""
+        nonlocal flushed_img, flushed_steps
+        n = steps - flushed_steps
+        if telemetry is not None:
+            samples = timer.drain_window()
+            if health is not None:
+                health.on_window(samples, epoch=epoch, phase="train")
+            t_end = _emit_step_window(
+                telemetry, samples, steps=n, phase="train", epoch=epoch,
+                t_window=w0, images=img_sum - flushed_img, **win)
+        else:
+            t_end = time.perf_counter()
+        if spans is not None:
+            spans.emit(trace_id=trace_id, name="steps", start=w0,
+                       end=t_flush, parent_id=root_id, step=steps, steps=n,
+                       thread=lane)
+            spans.emit(trace_id=trace_id, name="metric_flush",
+                       start=t_flush, end=t_end, parent_id=root_id,
+                       step=steps, thread=lane)
+        flushed_img = img_sum
+        flushed_steps = steps
+        return t_end
+
     it = _progress(prefetch_to_device(batches, put_fn, depth=prefetch,
-                                      stall=stall),
+                                      stall=stall, tracer=spans,
+                                      trace_id=trace_id, parent_id=root_id),
                    enabled=show_progress, desc=f"epoch {epoch}", total=total)
     try:
         for dev_batch in it:
@@ -230,7 +263,20 @@ def train_one_epoch(train_step: Callable, state, batches: Iterable, *,
             if telemetry is not None:
                 telemetry.step_tick()
                 timer.start()
-            state, metrics = train_step(state, dev_batch)
+            if spans is None:
+                state, metrics = train_step(state, dev_batch)
+            else:
+                if not steps:
+                    # a new prefetcher filled from nothing: what an epoch
+                    # boundary costs before its first step
+                    spans.emit(trace_id=trace_id, name="train.turnover",
+                               start=t0, end=time.perf_counter(),
+                               parent_id=root_id, epoch=epoch,
+                               thread=lane)
+                with spans.span("train.dispatch", trace_id=trace_id,
+                                parent_id=root_id,
+                                program="x".join(map(str, shape[:3]))):
+                    state, metrics = train_step(state, dev_batch)
             if telemetry is not None:
                 # a first-call compile is attributed by its own compile
                 # event; recording it here too would poison the step
@@ -242,36 +288,16 @@ def train_one_epoch(train_step: Callable, state, batches: Iterable, *,
             if on_step is not None:
                 on_step(steps)
             if len(pending) >= max(check_every, 1):
-                t_flush = (time.perf_counter()
-                           if telemetry is not None else 0.0)
+                t_flush = (time.perf_counter() if timed else 0.0)
                 loss_sum, img_sum, win = _flush(
                     pending, loss_sum, img_sum, check_finite, epoch, steps,
                     health=health, collect=telemetry is not None)
                 pending = []
-                if telemetry is not None:
-                    win_samples = timer.drain_window()
-                    if health is not None:
-                        health.on_window(win_samples, epoch=epoch,
-                                         phase="train")
-                    w0 = t_window
-                    t_window = _emit_step_window(
-                        telemetry, win_samples,
-                        steps=steps - flushed_steps, phase="train",
-                        epoch=epoch, t_window=t_window,
-                        images=img_sum - flushed_img, **win)
-                    if spans is not None:
-                        spans.emit(trace_id=trace_id, name="steps",
-                                   start=w0, end=t_flush,
-                                   parent_id=root_id, step=steps,
-                                   steps=steps - flushed_steps)
-                        spans.emit(trace_id=trace_id, name="metric_flush",
-                                   start=t_flush, end=t_window,
-                                   parent_id=root_id, step=steps)
-                    flushed_img = img_sum
-                    flushed_steps = steps
+                if timed:
+                    t_window = _close_window(t_window, t_flush, win)
                 if show_progress and hasattr(it, "set_postfix") and img_sum:
                     it.set_postfix(loss=f"{loss_sum / img_sum:.4f}")
-        t_flush = (time.perf_counter() if telemetry is not None else 0.0)
+        t_flush = (time.perf_counter() if timed else 0.0)
         loss_sum, img_sum, win = _flush(pending, loss_sum, img_sum,
                                         check_finite, epoch, steps,
                                         health=health,
@@ -290,38 +316,17 @@ def train_one_epoch(train_step: Callable, state, batches: Iterable, *,
                          step=steps)
         raise
     seconds = time.perf_counter() - t0
+    if timed and steps > flushed_steps:  # partial trailing window
+        _close_window(t_window, t_flush, win)
     if telemetry is not None:
-        tail = timer.drain_window()
-        if tail or steps > flushed_steps:  # partial trailing window
-            if health is not None:
-                health.on_window(tail, epoch=epoch, phase="train")
-            w0 = t_window
-            t_end = _emit_step_window(
-                telemetry, tail, steps=steps - flushed_steps,
-                phase="train", epoch=epoch, t_window=t_window,
-                images=img_sum - flushed_img, **win)
-            if spans is not None:
-                spans.emit(trace_id=trace_id, name="steps", start=w0,
-                           end=t_flush, parent_id=root_id, step=steps,
-                           steps=steps - flushed_steps)
-                spans.emit(trace_id=trace_id, name="metric_flush",
-                           start=t_flush, end=t_end, parent_id=root_id,
-                           step=steps)
         _emit_epoch_telemetry(telemetry, timer, stall, phase="train",
                               epoch=epoch, seconds=seconds, health=health)
         if health is not None:
             health.epoch_summary(epoch)
-        if spans is not None:
-            # fetch_stall is SYNTHESIZED (start anchored at epoch start,
-            # duration = the StallClock's accumulated input starvation) —
-            # the stall events carry the exact accounting; the span gives
-            # the exported timeline a fetch lane to eyeball against steps
-            spans.emit(trace_id=trace_id, name="fetch_stall", start=t0,
-                       end=t0 + stall.seconds, parent_id=root_id,
-                       synthesized=True, count=stall.count)
-            spans.emit(trace_id=trace_id, name="train_epoch", start=t0,
-                       end=time.perf_counter(), span_id=root_id,
-                       epoch=epoch, steps=steps, images=img_sum)
+    if spans is not None:
+        spans.emit(trace_id=trace_id, name="train_epoch", start=t0,
+                   end=time.perf_counter(), span_id=root_id,
+                   epoch=epoch, steps=steps, images=img_sum, thread=lane)
     stats = EpochStats(loss_sum / max(img_sum, 1.0), seconds=seconds,
                        images=img_sum, steps=steps,
                        distinct_shapes=len(shapes))
@@ -414,7 +419,8 @@ def evaluate(eval_step: Callable, params, batches: Iterable, *,
     t0 = time.perf_counter()
     t_window = t0
     it = _progress(prefetch_to_device(batches, put_fn, depth=prefetch,
-                                      stall=stall),
+                                      stall=stall,
+                                      tracer=active(telemetry)),
                    enabled=show_progress, desc="eval", total=total)
 
     def flush():

@@ -149,13 +149,27 @@ def pallas_interpret() -> bool:
 
 
 @contextlib.contextmanager
-def profile_trace(log_dir: Optional[str]):
-    """Capture a jax.profiler trace into ``log_dir`` (no-op if None)."""
+def profile_trace(log_dir: Optional[str], spans=None):
+    """Capture a jax.profiler trace into ``log_dir`` (no-op if None), at
+    the operator's options (``obs/trace.py``: the device alone).
+    ``spans``: the run's ``SpanTracer``, for the ``profile.window`` span
+    that lets ``tools/trace_export.py --profile`` place the host's spans
+    beside the device plane."""
     if not log_dir:
         yield
         return
-    with jax.profiler.trace(log_dir):
-        yield
+    from can_tpu.obs.trace import (
+        operator_profile_options,
+        record_profile_window,
+    )
+
+    t0 = time.perf_counter()
+    with jax.profiler.trace(log_dir,
+                            profiler_options=operator_profile_options()):
+        try:
+            yield
+        finally:
+            record_profile_window(spans, log_dir, t0, time.perf_counter())
 
 
 class StepTimer:

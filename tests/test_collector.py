@@ -676,12 +676,19 @@ class TestServeTraceStitching:
         tel.close()
         events = obs.read_events(
             os.path.join(str(tmp_path), "telemetry.host0.jsonl"))
-        tree = [e["payload"] for e in events
-                if e["kind"] == "trace.span"
-                and e["payload"]["trace_id"] == "xhop-42"]
-        assert {s["name"] for s in tree} == {
-            "request", "queue_wait", "batch_assembly", "device",
-            "respond"}
+        spans = [e["payload"] for e in events if e["kind"] == "trace.span"]
+        tree = [s for s in spans if s["trace_id"] == "xhop-42"]
+        assert {s["name"] for s in tree} == {"request", "queue_wait"}
+        # the batch's phases are recorded once, on the batch the request
+        # links to, not per request under the propagated id
+        (root,) = [s for s in tree if s["name"] == "request"]
+        (batch,) = [s for s in spans if s["span_id"] == root["batch"]]
+        assert batch["name"] == "serve.batch"
+        # (the fixture's engine reports to a bus of its own, so its
+        # dispatch and fetch are not on this one)
+        assert {s["name"] for s in spans
+                if s["parent_id"] == batch["span_id"]} == {
+            "serve.pad", "serve.complete"}
 
     def test_cross_host_timeline_is_skew_corrected(self, tmp_path,
                                                    trace_engine):

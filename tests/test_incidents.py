@@ -828,7 +828,7 @@ class TestTraceExportBundle:
         root = spans.new_span_id()
         spans.emit(trace_id="t1", name="request", start=1.0, end=2.0,
                    span_id=root)
-        spans.emit(trace_id="t1", name="device", start=1.2, end=1.8,
+        spans.emit(trace_id="t1", name="queue_wait", start=1.2, end=1.8,
                    parent_id=root)
         tel.emit("fleet.replica", replica=0, state="quarantined")
         bundle = bundles_of(mgr)[0]
@@ -840,7 +840,7 @@ class TestTraceExportBundle:
         assert r.returncode == 0, r.stderr
         doc = json.load(open(out))
         names = [e["name"] for e in doc["traceEvents"] if e["ph"] == "X"]
-        assert sorted(names) == ["device", "request"]
+        assert sorted(names) == ["queue_wait", "request"]
 
     def test_bundle_without_ring_is_an_error(self, tmp_path):
         d = tmp_path / "incident-1-h0-x"

@@ -211,7 +211,11 @@ class TestTraceWindow:
         calls = []
 
         class FakeProfiler:
-            def start_trace(self, d):
+            def start_trace(self, d, profiler_options=None):
+                # the operator's options, never the profiler's defaults
+                # (the host tracer's "Transpose" flood)
+                assert profiler_options.host_tracer_level == 0
+                assert profiler_options.python_tracer_level == 0
                 calls.append(("start", d))
 
             def stop_trace(self):
@@ -227,7 +231,7 @@ class TestTraceWindow:
         calls = []
 
         class FakeProfiler:
-            def start_trace(self, d):
+            def start_trace(self, d, profiler_options=None):
                 calls.append("start")
 
             def stop_trace(self):
@@ -242,7 +246,7 @@ class TestTraceWindow:
         calls = []
 
         class FakeProfiler:
-            def start_trace(self, d):
+            def start_trace(self, d, profiler_options=None):
                 calls.append("start")
 
             def stop_trace(self):

@@ -276,7 +276,7 @@ class TestTraceExport:
         return [
             _span_event("t1", "r1", "request", 10.0, 1.0),
             _span_event("t1", "c1", "queue_wait", 10.0, 0.25, parent="r1"),
-            _span_event("t1", "c2", "device", 10.5, 0.5, parent="r1"),
+            _span_event("t1", "c2", "serve.fetch", 10.5, 0.5, parent="r1"),
             _span_event("t2", "r2", "request", 10.2, 0.3, host=1),
         ]
 
@@ -476,8 +476,10 @@ class TestLoopPerfTelemetry:
         by_name = {}
         for s in spans:
             by_name.setdefault(s["name"], []).append(s)
-        assert set(by_name) == {"steps", "metric_flush", "fetch_stall",
-                                "train_epoch"}
+        assert set(by_name) == {"steps", "metric_flush", "train.dispatch",
+                                "train.turnover", "input.load",
+                                "input.put", "train_epoch"}
+        assert len(by_name["train.dispatch"]) == 12
         root = by_name["train_epoch"][0]
         assert all(s["parent_id"] == root["span_id"]
                    for name, ss in by_name.items() if name != "train_epoch"

@@ -626,14 +626,18 @@ class TestServeTelemetryReport:
 
 class TestServeSpansAndPerf:
     """Performance-attribution layer on the serve path: the serve.request
-    queue-wait/device breakdown, the submit->respond span tree, and the
-    cost ledger's per-bucket MFU/roofline rows (the r9 tentpole's serve
+    queue-wait/device breakdown, the request's spans and its batch's,
+    and the cost ledger's per-bucket MFU/roofline rows (the r9 tentpole's serve
     acceptance)."""
 
     def test_request_breakdown_span_tree_and_ledger(self, tmp_path,
-                                                    small_engine):
+                                                    small_engine,
+                                                    monkeypatch):
         tel = obs.open_host_telemetry(str(tmp_path), host_id=0)
         tel.spans = obs.SpanTracer(tel, prefix="t")
+        # engine and service on one bus, as the CLI builds them: the
+        # engine's dispatch / fetch spans land beside the batcher's
+        monkeypatch.setattr(small_engine, "telemetry", tel)
         tel.ledger = obs.ProgramCostLedger(compute="f32")
         # the ENGINE's tracker attributes compiles on its own (module
         # fixture) bus, where (64,64) is already warm — register the
@@ -680,29 +684,41 @@ class TestServeSpansAndPerf:
             # the breakdown is consistent: queue wait never exceeds the
             # whole latency
             assert p["queue_wait_s"] <= p["latency_s"] + 1e-6
-        # acceptance: the exported trace of one request shows the FULL
-        # submit->respond tree
+        # acceptance: the exported trace of one request shows the
+        # request and the batch it rode in, phase by phase
         spans = [e["payload"] for e in events if e["kind"] == "trace.span"]
         tree = [s for s in spans if s["trace_id"] == results[0].trace_id]
-        assert {s["name"] for s in tree} == {
-            "request", "queue_wait", "batch_assembly", "device", "respond"}
+        assert {s["name"] for s in tree} == {"request", "queue_wait"}
         root = next(s for s in tree if s["name"] == "request")
         assert all(s["parent_id"] == root["span_id"]
                    for s in tree if s["name"] != "request")
-        # respond spans tile back to back (dispatch is single-threaded):
-        # a late slot's respond covers ITS OWN resolve cost, not the sum
-        # of every sibling processed before it in the batch loop
-        resp = sorted((s for s in spans if s["name"] == "respond"),
-                      key=lambda s: s["start_s"])
-        assert len(resp) == 4
-        for a, b in zip(resp, resp[1:]):
+        batch = next(s for s in spans if s["span_id"] == root["batch"])
+        phases = [s for s in spans if s["parent_id"] == batch["span_id"]]
+        assert sorted(s["name"] for s in phases) == [
+            "serve.complete", "serve.dispatch", "serve.fetch", "serve.pad"]
+        # the phases follow one another inside the batch (dispatch is
+        # single-threaded), and the wait ends where the batch begins
+        phases.sort(key=lambda s: s["start_s"])
+        assert [s["name"] for s in phases] == [
+            "serve.pad", "serve.dispatch", "serve.fetch", "serve.complete"]
+        for a, b in zip(phases, phases[1:]):
             assert b["start_s"] >= a["start_s"] + a["duration_s"] - 1e-6
+        wait = next(s for s in tree if s["name"] == "queue_wait")
+        assert wait["start_s"] + wait["duration_s"] == pytest.approx(
+            batch["start_s"], abs=2e-6)
+        assert len([s for s in spans if s["name"] == "request"]) == 4
         from tools.trace_export import spans_to_trace_events
 
         doc = spans_to_trace_events(events, trace_id=results[0].trace_id)
         xs = [e for e in doc["traceEvents"] if e["ph"] == "X"]
-        assert {e["name"] for e in xs} == {s["name"] for s in tree}
+        assert {e["name"] for e in xs} == {
+            "request", "queue_wait", "serve.batch",
+            *(s["name"] for s in phases)}
         assert all(e["ts"] >= 0 and e["dur"] >= 0 for e in xs)
+        # the request on its trace's lane, its batch on the thread's
+        lane = {e["name"]: e["tid"] for e in xs}
+        assert lane["request"] == lane["queue_wait"] != lane["serve.batch"]
+        assert lane["serve.batch"] == lane["serve.pad"]
         # the ledger priced the warmed bucket: roofline known, and MFU
         # joined in from the (fenced) execute times of real batches
         perf = [e["payload"] for e in events if e["kind"] == "perf.summary"]

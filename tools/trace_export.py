@@ -1,9 +1,11 @@
 #!/usr/bin/env python
 """Convert ``trace.span`` telemetry JSONL into Chrome trace-event JSON.
 
-The span tree a run emits (``can_tpu/obs/spans.py`` — serve requests'
-submit→queue→assembly→device→respond, the train loop's per-window
-steps/metric_flush lanes) is viewable in ``chrome://tracing`` or Perfetto
+The spans a run emits (``can_tpu/obs/spans.py`` — the serve batcher
+thread's wait/intake/poll cycle and each batch's pad/dispatch/fetch/
+complete, every request's request/queue_wait, the train loop's
+steps/metric_flush/train.dispatch and the prefetch worker's
+input.load/input.put) are viewable in ``chrome://tracing`` or Perfetto
 once converted to the trace-event format::
 
     python tools/trace_export.py runs/exp1/telemetry.host0.jsonl
@@ -15,12 +17,17 @@ once converted to the trace-event format::
 
 Mapping: every span becomes one complete event (``ph: "X"``) with
 microsecond ``ts``/``dur`` normalised to each HOST's earliest span (spans
-carry the emitter's own clock — service-monotonic for serve,
-``perf_counter`` for the train loop — whose epoch is process-local, so a
+carry ``perf_counter`` stamps, whose epoch is process-local, so a
 cross-host export re-anchors hosts against each other via the bus
-wall-clock ``ts``); ``pid`` is the telemetry ``host_id`` and each trace_id gets
-its own ``tid`` lane plus a ``thread_name`` metadata event, so one
-request/epoch reads as one horizontal track.  Span/parent ids ride in
+wall-clock ``ts``); ``pid`` is the telemetry ``host_id``.  Lanes
+(``tid``, with a ``thread_name`` metadata event): a span opened on a
+thread, or stamped as that thread's time (it carries ``thread``: the
+batcher thread, the prefetch worker, the train loop), lies on that
+thread's lane, where nesting reads as a flame; a request's own spans
+(request, queue_wait) lie on its trace's lane, so one request reads as
+one horizontal track.  ``--trace-id`` of a
+request draws it with its batch: the ``serve.batch`` trace its
+``batch`` attribute names comes along.  Span/parent ids ride in
 ``args`` for tooling that wants to rebuild the tree.
 
 Cross-host stitching: serve hops propagate one trace_id over HTTP
@@ -32,13 +39,25 @@ when the target is one, else the first-heartbeat estimate — without
 this, a host running 2 minutes fast would shove its segment of the
 request 2 minutes off every other host's.
 
-Pure host-side file reading — no JAX import, safe anywhere the artifact
-was copied to (same contract as tools/telemetry_report.py).
+Beside the device: ``--profile DIR`` (the ``--profile-dir`` of the same
+run) adds the profiler's device planes — ``XLA Modules`` and ``XLA Ops`` —
+as one more process in the document.  An operator's profile holds the
+device alone (the host tracer floods the serving path; PERF.md section
+6), so the host's spans reach the device's clock through one anchor, the
+benchmark's: the end of the last ``serve.fetch`` / ``metric_flush`` inside
+the run's ``profile.window`` span — the host has just seen a program
+complete — against the end of the last program on the device (a few
+milliseconds of error; gaps are tens of milliseconds).
+
+Pure host-side file reading — no JAX import unless ``--profile`` is given
+(reading ``.xplane.pb`` takes ``jax.profiler.ProfileData``), safe anywhere
+the artifact was copied to (same contract as tools/telemetry_report.py).
 """
 
 from __future__ import annotations
 
 import argparse
+import glob
 import json
 import os
 import sys
@@ -51,29 +70,105 @@ from can_tpu.obs.join import (  # noqa: E402
     resolve_telemetry_source,
 )
 
-_SPAN_KEYS = ("trace_id", "span_id", "parent_id", "name",
-              "start_s", "duration_s")
+# spans at whose end the host has just seen a device program complete
+_SEEN_COMPLETE = ("serve.fetch", "metric_flush")
+_DEVICE_LINES = ("XLA Modules", "XLA Ops")
+_DEVICE_PID = 1000  # + the plane's index: clear of the host ids
+
+
+def load_device_planes(profile_dir: str) -> dict:
+    """The newest ``.xplane.pb`` under a ``--profile-dir`` ->
+    ``{plane: {line: [(name, start_ns, duration_ns)]}}`` for the device
+    planes' ``XLA Modules`` / ``XLA Ops`` lines."""
+    from jax.profiler import ProfileData
+
+    paths = sorted(glob.glob(os.path.join(
+        profile_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    if not paths:
+        raise FileNotFoundError(f"no .xplane.pb under {profile_dir}")
+    planes: dict = {}
+    for plane in ProfileData.from_file(paths[-1]).planes:
+        if not plane.name.startswith("/device:"):
+            continue
+        lines = {line.name: [(e.name, float(e.start_ns), float(e.duration_ns))
+                             for e in line.events]
+                 for line in plane.lines if line.name in _DEVICE_LINES}
+        if any(lines.values()):
+            planes[plane.name] = lines
+    return planes
+
+
+def _device_events(planes: dict, spans, to_ts) -> list:
+    """The device planes as trace events, anchored (module docstring).
+    ``to_ts(host, seconds)``: the document's microseconds for a host
+    ``perf_counter`` time."""
+    windows = [e for e in spans
+               if e["payload"].get("name") == "profile.window"]
+    if not windows:
+        raise ValueError("no profile.window span: the run recorded no "
+                         "profile beside these spans (--trace-steps with "
+                         "--telemetry-dir records one)")
+    host = int(windows[-1].get("host_id", 0))
+    w = windows[-1]["payload"]
+    lo, hi = float(w["start_s"]), float(w["start_s"]) + float(w["duration_s"])
+    seen = [float(p["start_s"]) + float(p["duration_s"])
+            for p in (e["payload"] for e in spans
+                      if int(e.get("host_id", 0)) == host)
+            if p.get("name") in _SEEN_COMPLETE]
+    seen = [t for t in seen if lo <= t <= hi]
+    last_end = max((s + d for lines in planes.values()
+                    for _, s, d in lines.get("XLA Modules", [])), default=None)
+    if not seen or last_end is None:
+        raise ValueError("nothing to anchor the device plane by: no "
+                         "serve.fetch / metric_flush span ends inside the "
+                         "profile.window, or the profile holds no program")
+    anchor = max(seen)
+    out = []
+    for i, (plane, lines) in enumerate(sorted(planes.items())):
+        pid = _DEVICE_PID + i
+        out.append({"ph": "M", "name": "process_name", "pid": pid,
+                    "args": {"name": plane + " (anchored to the host by "
+                                     "its last program's end)"}})
+        for tid, line in enumerate(_DEVICE_LINES, start=1):
+            out.append({"ph": "M", "name": "thread_name", "pid": pid,
+                        "tid": tid, "args": {"name": line}})
+            for name, start, dur in lines.get(line, []):
+                out.append({
+                    "name": name[:120], "cat": "device", "ph": "X",
+                    "ts": round(to_ts(host, anchor + (start - last_end) * 1e-9),
+                                3),
+                    "dur": round(dur * 1e-3, 3), "pid": pid, "tid": tid,
+                    "args": {}})
+    return out
 
 
 def spans_to_trace_events(events, *, trace_id: Optional[str] = None,
-                          offsets: Optional[dict] = None) -> dict:
+                          offsets: Optional[dict] = None,
+                          device_planes: Optional[dict] = None) -> dict:
     """``trace.span`` events -> a Chrome trace-event document
     (``{"traceEvents": [...], "displayTimeUnit": "ms"}``).
 
-    Lanes (``tid``) are assigned per trace_id in order of first
-    appearance — deterministic for a given artifact.  ``trace_id``
-    filters to one request/epoch tree.  ``offsets`` (host_id -> seconds
+    Lanes (``tid``) are assigned per thread (spans that carry one) or
+    trace_id, in order of first appearance — deterministic for a given
+    artifact.  ``trace_id`` filters to one request/epoch tree, and the
+    batch a request rode in.  ``offsets`` (host_id -> seconds
     fast, obs/join.py convention) skew-corrects the per-host wall
     anchors for RAW event streams; events already corrected upstream
-    (``load_joined_events``) must not pass it again."""
-    spans = [e for e in events if e.get("kind") == "trace.span"]
+    (``load_joined_events``) must not pass it again.  ``device_planes``
+    (``load_device_planes``) adds the profiler's device planes."""
+    spans = every = [e for e in events if e.get("kind") == "trace.span"]
     if trace_id is not None:
+        mine = [e["payload"] for e in spans
+                if e.get("payload", {}).get("trace_id") == trace_id]
+        batches = {p["batch"] for p in mine if p.get("batch")}
+        keep = {trace_id} | {e["payload"].get("trace_id") for e in spans
+                             if e.get("payload", {}).get("span_id") in batches}
         spans = [e for e in spans
-                 if e.get("payload", {}).get("trace_id") == trace_id]
+                 if e.get("payload", {}).get("trace_id") in keep]
     out: List[dict] = []
     lanes: dict = {}
-    # span start_s is the EMITTER's clock (perf_counter / service
-    # monotonic), whose epoch is process-local — a global min across
+    # span start_s is the emitter's perf_counter, whose epoch is
+    # process-local — a global min across
     # hosts would offset lanes by arbitrary inter-host clock deltas.
     # Normalise per host, then re-anchor hosts against each other with
     # the bus wall-clock ``ts`` each event also carries (cross-host skew
@@ -95,7 +190,7 @@ def spans_to_trace_events(events, *, trace_id: Optional[str] = None,
         p = e.get("payload", {})
         if "start_s" not in p or "duration_s" not in p:
             continue  # malformed span: skip, exactly like a torn line
-        tid_key = str(p.get("trace_id", "?"))
+        tid_key = str(p.get("thread") or p.get("trace_id", "?"))
         pid = int(e.get("host_id", 0))
         if (pid, tid_key) not in lanes:
             lanes[(pid, tid_key)] = len(lanes) + 1
@@ -115,6 +210,10 @@ def spans_to_trace_events(events, *, trace_id: Optional[str] = None,
             "tid": lanes[(pid, tid_key)],
             "args": args,
         })
+    if device_planes:
+        out.extend(_device_events(
+            device_planes, every,
+            lambda h, t: ((t - base[h]) + (wall0[h] - global_wall0)) * 1e6))
     return {"traceEvents": out, "displayTimeUnit": "ms"}
 
 
@@ -135,14 +234,25 @@ def main(argv=None) -> int:
     p.add_argument("--trace-id", default=None,
                    help="export only this trace's span tree (the id a "
                         "serve response returns)")
+    p.add_argument("--profile", default="",
+                   help="the run's --profile-dir: draw the device's XLA "
+                        "Modules / XLA Ops beside the spans")
     args = p.parse_args(argv)
     # estimate=True: a flame view exists to compare timing across hosts,
     # so skew correction is always on (measured snapshot offsets win;
     # plain run dirs get the first-heartbeat estimate).  The events come
     # back already corrected — no offsets passed below.
     events, _, _ = load_joined_events(args.target, estimate=True)
-    doc = spans_to_trace_events(events, trace_id=args.trace_id)
-    n = sum(1 for e in doc["traceEvents"] if e["ph"] == "X")
+    try:
+        doc = spans_to_trace_events(
+            events, trace_id=args.trace_id,
+            device_planes=(load_device_planes(args.profile)
+                           if args.profile else None))
+    except (FileNotFoundError, ValueError) as e:
+        print(f"trace_export: {e}", file=sys.stderr)
+        return 1
+    n = sum(1 for e in doc["traceEvents"]
+            if e["ph"] == "X" and e["cat"] == "can_tpu")
     if not n:
         print("no trace.span events found"
               + (f" for trace_id {args.trace_id}" if args.trace_id else "")
