@@ -1,6 +1,12 @@
 from .density import gaussian_density_map, generate_density_maps
 from .dataset import CrowdDataset, IMAGENET_MEAN, IMAGENET_STD, normalize_host
-from .batching import ShardedBatcher, Batch, pad_batch, snap_to_bucket
+from .batching import (
+    Batch,
+    ShardedBatcher,
+    StagingBatch,
+    pad_batch,
+    snap_to_bucket,
+)
 from .synthetic import make_synthetic_dataset
 from .prefetch import PrefetchPutError, prefetch_to_device
 from .prepared import ItemCache, PreparedStore, StaleStoreError, write_store
@@ -14,6 +20,7 @@ __all__ = [
     "normalize_host",
     "ShardedBatcher",
     "Batch",
+    "StagingBatch",
     "pad_batch",
     "snap_to_bucket",
     "make_synthetic_dataset",

@@ -150,14 +150,69 @@ def _merge_partial_groups(partials, gbs: int):
     return full + partials
 
 
+class StagingBatch:
+    """A ``Batch``'s four arrays at one bucket's top launch size, kept
+    across launches: ``pad_batch(out=...)`` assembles into a leading view
+    of them and writes only what changed since the last launch.
+
+    Owned by whoever allocates it (``serve.MicroBatcher``: one per bucket
+    and image dtype).  A ``Batch`` assembled into it is a VIEW: it is good
+    until the next ``pad_batch`` into the same buffer, so the owner hands
+    the buffer on only once nothing reads the previous batch any more.
+    Nothing but ``pad_batch`` may write the arrays: ``extent`` is what
+    lets it skip the zeroing."""
+
+    def __init__(self, bucket_hw: Tuple[int, int], slots: int, ds: int,
+                 img_dtype):
+        bh, bw = bucket_hw
+        gh, gw = bh // ds, bw // ds
+        self.image = np.zeros((slots, bh, bw, 3), img_dtype)
+        self.dmap = np.zeros((slots, gh, gw, 1), np.float32)
+        self.pixel_mask = np.zeros((slots, gh, gw, 1), np.float32)
+        self.sample_mask = np.zeros((slots,), np.float32)
+        # per slot, the (h, w) of the item last written there: the slot is
+        # zero outside [:h, :w] of image and outside [:h // ds, :w // ds]
+        # of dmap and pixel_mask
+        self.extent: List[Tuple[int, int]] = [(0, 0)] * slots
+
+    @property
+    def nbytes(self) -> int:
+        return (self.image.nbytes + self.dmap.nbytes
+                + self.pixel_mask.nbytes + self.sample_mask.nbytes)
+
+
+def _zero_stale(a: np.ndarray, prev: Tuple[int, int],
+                new: Tuple[int, int]) -> None:
+    """Zero the part of ``a[:prev[0], :prev[1]]`` that ``a[:new[0],
+    :new[1]]`` does not cover (the new item overwrites the rest)."""
+    (ph, pw), (h, w) = prev, new
+    if ph > h:
+        a[h:ph, :pw] = 0
+    if pw > w:
+        a[:min(h, ph), w:pw] = 0
+
+
 def pad_batch(items, bucket_hw: Tuple[int, int], batch_size: int,
-              valid_flags, ds: int) -> Batch:
+              valid_flags, ds: int,
+              out: Optional[StagingBatch] = None) -> Batch:
     """Assemble variable-size (img, dmap) numpy pairs into one padded Batch.
 
     The image buffer keeps the items' dtype: float32 for the normalised
     host path, uint8 for the device-normalised transfer path (where the
     step zeroes padded pixels in normalised space via the upsampled
-    pixel_mask, so both paths see identical zero padding)."""
+    pixel_mask, so both paths see identical zero padding).
+
+    out: None allocates the four arrays fresh and the caller owns them
+    (training, eval, warmup, the fleet).  A ``StagingBatch`` of this
+    bucket and dtype with at least ``batch_size`` slots is filled in place
+    instead, and the returned Batch is its leading ``[:batch_size]`` view
+    holding the same bytes a fresh call would: the items are copied in and
+    only what is stale is zeroed (what a slot's previous item covered and
+    the new one does not; dead slots an earlier launch filled).  The view
+    is free for the next assembly when its reader is done with it, which
+    is the buffer's owner's to know."""
+    if out is not None:
+        return _pad_into(out, items, bucket_hw, batch_size, valid_flags, ds)
     bh, bw = bucket_hw
     gh, gw = bh // ds, bw // ds
     img_dtype = items[0][0].dtype if items else np.float32
@@ -172,6 +227,45 @@ def pad_batch(items, bucket_hw: Tuple[int, int], batch_size: int,
         pixel_mask[slot, : h // ds, : w // ds] = 1.0
         sample_mask[slot] = float(valid)
     return Batch(image, dmap, pixel_mask, sample_mask)
+
+
+def _pad_into(out: StagingBatch, items, bucket_hw: Tuple[int, int],
+              batch_size: int, valid_flags, ds: int) -> Batch:
+    bh, bw = bucket_hw
+    batch = Batch(out.image[:batch_size], out.dmap[:batch_size],
+                  out.pixel_mask[:batch_size], out.sample_mask[:batch_size])
+    if (batch.image.shape != (batch_size, bh, bw, 3)
+            or batch.dmap.shape != (batch_size, bh // ds, bw // ds, 1)
+            or (items and items[0][0].dtype != out.image.dtype)):
+        raise ValueError(
+            f"staging buffer {out.image.shape} {out.image.dtype} cannot "
+            f"hold {batch_size} slots of bucket {bh}x{bw} (ds {ds})"
+            + (f" {items[0][0].dtype}" if items else ""))
+
+    def restage(slot: int, h: int, w: int) -> None:
+        # zero first, then record, then (the caller) write: whatever
+        # raises in between, the slot is zero outside the recorded extent
+        prev, grid = out.extent[slot], (h // ds, w // ds)
+        _zero_stale(batch.image[slot], prev, (h, w))
+        prev = (prev[0] // ds, prev[1] // ds)
+        _zero_stale(batch.dmap[slot], prev, grid)
+        _zero_stale(batch.pixel_mask[slot], prev, grid)
+        out.extent[slot] = (h, w)
+
+    batch.sample_mask[:] = 0.0
+    filled = 0
+    for slot, ((img, dm), valid) in enumerate(zip(items, valid_flags)):
+        h, w = img.shape[:2]
+        restage(slot, h, w)
+        batch.image[slot, :h, :w] = img
+        batch.dmap[slot, : h // ds, : w // ds] = dm
+        batch.pixel_mask[slot, : h // ds, : w // ds] = 1.0
+        batch.sample_mask[slot] = float(valid)
+        filled = slot + 1
+    for slot in range(filled, batch_size):
+        if out.extent[slot] != (0, 0):
+            restage(slot, 0, 0)
+    return batch
 
 
 class ShardedBatcher:

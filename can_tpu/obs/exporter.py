@@ -447,8 +447,10 @@ def render_stats(stats: dict, *, prefix: str = "can_tpu_serve",
     the fleet's ``"replicas"`` sub-dicts, whose numeric entries become
     per-replica LABELLED lines (``can_tpu_serve_batches_total{replica=
     "k"}``), so one scrape shows which replica is serving, quarantined,
-    or lagging a rollout generation, and ``"flush_reasons"``, whose counts
-    become ``can_tpu_serve_flushes_total{reason="full"}`` lines."""
+    or lagging a rollout generation, ``"flush_reasons"``, whose counts
+    become ``can_tpu_serve_flushes_total{reason="full"}`` lines, and
+    ``"staging"``: ``can_tpu_serve_staging_launches_total{assembled=
+    "reused"}`` lines and the ``can_tpu_serve_staging_bytes_held`` gauge."""
     gauges: Dict[str, float] = {}
     counters: Dict[Tuple[str, tuple], float] = {}
     labelled_gauges: Dict[Tuple[str, tuple], float] = {}
@@ -472,6 +474,16 @@ def render_stats(stats: dict, *, prefix: str = "can_tpu_serve",
             for reason, n in v.items():
                 counters[(f"{prefix}_flushes_total",
                           (("reason", str(reason)),))] = n
+            continue
+        if k == "staging" and isinstance(v, dict):
+            # launches by how the batcher assembled their batch; the bytes
+            # its staging pool holds now
+            for how, n in v.items():
+                if how == "bytes_held":
+                    gauges[f"{prefix}_staging_bytes_held"] = n
+                else:
+                    counters[(f"{prefix}_staging_launches_total",
+                              (("assembled", str(how)),))] = n
             continue
         if v is None or not isinstance(v, (int, float, bool)):
             continue

@@ -8,6 +8,23 @@ mapping is ``data.batching.snap_to_bucket`` and batch assembly is
 ``data.batching.pad_batch`` — it only swaps the epoch schedule for an
 arrival-driven flush policy.
 
+Where ``dispatch`` is done with a batch when it returns (the in-process
+service: ``predict_batch`` has fetched the answers and the requests are
+resolved), the batcher assembles into staging buffers it OWNS: one
+``data.batching.StagingBatch`` per (bucket, dtype), sized for the top
+launch size at the key's first flush and kept until ``close()``; a
+smaller menu size is its leading view.  ``pad_batch(out=...)`` then
+copies the items in and zeroes only what the previous launch left
+stale, instead of mapping and zeroing the whole batch anew per launch
+(151 MB at b16 768x1024 f32: 9.2 ms per image on the v5e's host against
+1.0 for the copy alone; PERF.md, PR 25).  The batch handed to
+``dispatch`` is a view of that buffer, free again when ``dispatch``
+returns or raises.  Where ``dispatch`` only enqueues (the fleet: a
+``_WorkItem`` keeps the batch until a replica completes it, may be
+redispatched, and a wedged replica may still be reading it) every launch
+is assembled fresh, and the batch is its receiver's.  ``staging`` counts
+launches by which of the two it was.
+
 Since round 14 the flush policy and launch sizes come from the shared
 scheduling core (``can_tpu/sched``) when a ``ServeSched`` is given:
 
@@ -44,7 +61,9 @@ the live requests need.
 
 Single consumer thread; dispatch runs ON that thread — the device executes
 serially anyway, and one thread means the pending-group state needs no
-locking beyond the queue's own.
+locking beyond the queue's own, and that one staging buffer per key is
+enough: the thread assembles the next launch only after the previous
+``dispatch`` has returned.
 """
 
 from __future__ import annotations
@@ -55,7 +74,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from can_tpu.data.batching import Batch, pad_batch, snap_to_bucket
+from can_tpu.data.batching import StagingBatch, pad_batch, snap_to_bucket
 from can_tpu.obs.spans import active
 from can_tpu.serve.queue import (
     REJECT_DEADLINE,
@@ -95,6 +114,12 @@ class MicroBatcher:
     dispatch that raises rejects its requests with ``error`` and the
     batcher keeps running: one poison batch must not kill the service.
 
+    batch_free_on_return: what the owner knows of ITS ``dispatch`` — True
+    when nothing reads the batch's arrays once ``dispatch`` has returned
+    or raised.  The batcher then assembles every launch into a staging
+    buffer it reuses (module docstring); False (a dispatch that hands the
+    batch on, or keeps it) assembles each launch fresh.
+
     sched: optional ``can_tpu.sched.ServeSched`` — the shared scheduling
     core (priced sub-batch menu + priced flush deadlines).  None keeps
     the pre-r14 pad-to-``max_batch`` / fixed-timer behaviour exactly.
@@ -110,7 +135,7 @@ class MicroBatcher:
                  telemetry=None, clock=time.monotonic,
                  idle_wait_s: float = 0.05,
                  on_reject: Optional[Callable] = None,
-                 sched=None):
+                 sched=None, batch_free_on_return: bool = False):
         if max_batch < 1:
             raise ValueError(f"max_batch must be >= 1, got {max_batch}")
         if sched is not None and sched.max_batch != int(max_batch):
@@ -139,6 +164,12 @@ class MicroBatcher:
         # launched batches by flush reason (batcher thread writes; the
         # service's stats() copies)
         self.flush_reasons = {FLUSH_FULL: 0, FLUSH_DUE: 0, FLUSH_DRAIN: 0}
+        # the staging pool (None: every launch fresh), and launches by how
+        # they were assembled + the bytes the pool holds now (same
+        # writer, same reader as flush_reasons)
+        self._staging_pool: Optional[Dict[GroupKey, StagingBatch]] = (
+            {} if batch_free_on_return else None)
+        self.staging = {"reused": 0, "fresh": 0, "bytes_held": 0}
         # the trace the thread's own cycle (wait / intake / poll) is
         # recorded under; minted on the first traced cycle
         self._lane: Optional[str] = None
@@ -329,12 +360,11 @@ class MicroBatcher:
                                 r.shape[1] // self.ds, 1), np.float32))
                      for r in group]
             if tr is None:
-                batch = pad_batch(items, (bh, bw), size,
-                                  [True] * len(group), self.ds)
+                batch, _ = self._assemble(key, items, size)
             else:
                 with tr.span("serve.pad") as sp:
-                    batch = pad_batch(items, (bh, bw), size,
-                                      [True] * len(group), self.ds)
+                    batch, sp.attrs["reused"] = self._assemble(key, items,
+                                                               size)
                     sp.attrs["bytes"] = int(batch.image.nbytes)
             t_ready = self._clock()
             for r in group:
@@ -353,6 +383,21 @@ class MicroBatcher:
                 self.telemetry.emit("serve.reject", reason=REJECT_ERROR,
                                     count=n,
                                     detail=f"{type(e).__name__}: {e}")
+
+    def _assemble(self, key: GroupKey, items, size: int):
+        """-> (the launch's Batch, whether it was assembled into a buffer
+        that already existed)."""
+        out, reused = None, False
+        if self._staging_pool is not None:
+            out = self._staging_pool.get(key)
+            reused = out is not None
+            if out is None:
+                out = self._staging_pool[key] = StagingBatch(
+                    key[:2], self.max_batch, self.ds, np.dtype(key[2]))
+                self.staging["bytes_held"] += out.nbytes
+        self.staging["reused" if reused else "fresh"] += 1
+        return pad_batch(items, key[:2], size, [True] * len(items),
+                         self.ds, out=out), reused
 
     def _reject_expired(self, r: ServeRequest) -> None:
         r.reject(REJECT_DEADLINE, "deadline expired before dispatch")
@@ -389,3 +434,6 @@ class MicroBatcher:
         else:
             self.intake()
             self.flush_all()
+        if self._staging_pool:
+            self._staging_pool.clear()
+            self.staging["bytes_held"] = 0

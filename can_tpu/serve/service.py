@@ -185,7 +185,11 @@ class CountService:
                                     ds=engine.ds, telemetry=self.telemetry,
                                     clock=clock,
                                     on_reject=self._note_reject,
-                                    sched=self.sched)
+                                    sched=self.sched,
+                                    # in process, _dispatch returns with
+                                    # the answers fetched and the requests
+                                    # resolved; the fleet's only enqueues
+                                    batch_free_on_return=self._fleet is None)
         # request latency reservoir: p50/p95/max over recent requests,
         # tagged by bucket shape (skip_first=0 — warmup() already keeps
         # compiles off the request path, so every sample is steady-state).
@@ -481,6 +485,10 @@ class CountService:
             # launched batches by why their group was flushed (full /
             # due / drain; serve/batcher.py)
             "flush_reasons": dict(self.batcher.flush_reasons),
+            # launches by how their batch was assembled (into a staging
+            # buffer that already existed / fresh) and the bytes the
+            # batcher's staging pool holds now
+            "staging": dict(self.batcher.staging),
         }
         if self._fleet is not None:
             # per-replica rows: service-side work counters joined with the

@@ -8,8 +8,10 @@ from scipy.spatial import cKDTree
 from can_tpu.data import (
     CrowdDataset,
     ShardedBatcher,
+    StagingBatch,
     gaussian_density_map,
     make_synthetic_dataset,
+    pad_batch,
 )
 from can_tpu.data.dataset import IMAGENET_MEAN, IMAGENET_STD
 
@@ -247,6 +249,117 @@ class TestPreparedParity:
                 np.testing.assert_array_equal(s.sample_mask, p.sample_mask)
         finally:
             b1.close()
+
+
+BATCH_ARRAYS = ("image", "dmap", "pixel_mask", "sample_mask")
+
+
+def assert_same_bytes(got, want):
+    for name in BATCH_ARRAYS:
+        a, b = getattr(got, name), getattr(want, name)
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        assert a.tobytes() == b.tobytes(), name
+
+
+def random_items(rng, n, bucket_hw, ds, dtype):
+    """n (image, density) pairs, sides from ``ds`` up to the bucket's;
+    no zero among the values, so a stale cell cannot pass for padding."""
+    items = []
+    for _ in range(n):
+        h = ds * int(rng.integers(1, bucket_hw[0] // ds + 1))
+        w = ds * int(rng.integers(1, bucket_hw[1] // ds + 1))
+        img = rng.integers(1, 255, (h, w, 3)).astype(dtype)
+        items.append((img, rng.uniform(0.5, 1.5, (h // ds, w // ds, 1))
+                      .astype(np.float32)))
+    return items
+
+
+class TestStagingBatch:
+    """``pad_batch(out=...)``: one buffer through many launches holds, at
+    every launch, the bytes a fresh ``pad_batch`` makes."""
+
+    BUCKET, DS, TOP = (48, 64), 8, 16
+
+    @pytest.mark.parametrize("dtype", [np.float32, np.uint8])
+    @pytest.mark.parametrize("seed", [0, 1, 2, 3])
+    def test_random_launches_equal_fresh_bit_for_bit(self, seed, dtype):
+        rng = np.random.default_rng((seed, np.dtype(dtype).itemsize))
+        staging = StagingBatch(self.BUCKET, self.TOP, self.DS, dtype)
+        for step in range(60):
+            slots = int(rng.choice([16, 4, 1]))
+            n = int(rng.integers(0, slots + 1))
+            if step % 7 == 0:
+                n = slots  # full launches, the benchmark cell's case
+            items = random_items(rng, n, self.BUCKET, self.DS, dtype)
+            if step % 11 == 0:  # every item fills its bucket
+                items = [(np.full(self.BUCKET + (3,), 7, dtype), dm[:1, :1])
+                         for _, dm in items]
+            valid = [bool(v) for v in rng.integers(0, 2, n)]
+            got = pad_batch(items, self.BUCKET, slots, valid, self.DS,
+                            out=staging)
+            want = pad_batch(items, self.BUCKET, slots, valid, self.DS)
+            if not items:
+                want.image = want.image.astype(dtype)  # fresh guesses f32
+            assert_same_bytes(got, want)
+            for name in BATCH_ARRAYS:  # a leading view, no memory of its own
+                assert np.shares_memory(getattr(got, name),
+                                        getattr(staging, name))
+
+    def test_a_full_launch_zeroes_nothing_and_margins_only_the_stale(self):
+        """The write set is the items plus what is stale: poison outside
+        the recorded extents must survive (nothing is zeroed to be safe)."""
+        staging = StagingBatch((16, 16), 2, 8, np.float32)
+        full = (np.ones((16, 16, 3), np.float32),
+                np.ones((2, 2, 1), np.float32))
+        small = (np.ones((8, 8, 3), np.float32),
+                 np.ones((1, 1, 1), np.float32))
+        pad_batch([small, small], (16, 16), 2, [True, True], 8, out=staging)
+        staging.image[:, 8:, 8:] = 5.0  # outside every recorded extent
+        pad_batch([small], (16, 16), 2, [True], 8, out=staging)
+        assert (staging.image[:, 8:, 8:] == 5.0).all()
+        assert staging.extent == [(8, 8), (0, 0)]
+        assert not staging.image[1, :8, :8].any()  # the dead slot's stale part
+        got = pad_batch([full, small], (16, 16), 2, [True, False], 8,
+                        out=staging)
+        assert (staging.image[1, 8:, 8:] == 5.0).all()
+        assert (got.image[0] == 1.0).all() and got.pixel_mask[0].all()
+        assert got.sample_mask.tolist() == [1.0, 0.0]
+
+    def test_an_assembly_that_raises_leaves_the_buffer_sound(self):
+        rng = np.random.default_rng(5)
+        staging = StagingBatch(self.BUCKET, 4, self.DS, np.float32)
+        first = random_items(rng, 4, self.BUCKET, self.DS, np.float32)
+        pad_batch(first, self.BUCKET, 4, [True] * 4, self.DS, out=staging)
+        bad = random_items(rng, 3, self.BUCKET, self.DS, np.float32)
+        bad[1] = (np.ones((56, 64, 3), np.float32), bad[1][1])  # too tall
+        with pytest.raises(ValueError):
+            pad_batch(bad, self.BUCKET, 4, [True] * 3, self.DS, out=staging)
+        with pytest.raises(ValueError):
+            pad_batch(bad, self.BUCKET, 4, [True] * 3, self.DS)  # as fresh
+        bad[1] = (bad[1][0][:8, :8], np.ones((3, 3, 1), np.float32))
+        with pytest.raises(ValueError):  # the density write, after the image's
+            pad_batch(bad, self.BUCKET, 4, [True] * 3, self.DS, out=staging)
+        nxt = random_items(rng, 2, self.BUCKET, self.DS, np.float32)
+        assert_same_bytes(
+            pad_batch(nxt, self.BUCKET, 4, [True] * 2, self.DS, out=staging),
+            pad_batch(nxt, self.BUCKET, 4, [True] * 2, self.DS))
+
+    @pytest.mark.parametrize("bucket,slots,dtype", [
+        ((48, 72), 4, np.float32),   # another bucket
+        ((48, 64), 17, np.float32),  # more slots than the buffer has
+        ((48, 64), 4, np.uint8),     # another image dtype
+    ])
+    def test_a_buffer_of_another_shape_or_dtype_is_refused(self, bucket,
+                                                           slots, dtype):
+        staging = StagingBatch(self.BUCKET, self.TOP, self.DS, np.float32)
+        item = (np.ones((8, 8, 3), dtype), np.ones((1, 1, 1), np.float32))
+        with pytest.raises(ValueError, match="staging buffer"):
+            pad_batch([item], bucket, slots, [True], self.DS, out=staging)
+
+    def test_nbytes_is_the_four_arrays(self):
+        staging = StagingBatch((768, 1024), 16, 8, np.float32)
+        assert staging.nbytes == (16 * 768 * 1024 * 3 * 4
+                                  + 2 * 16 * 96 * 128 * 4 + 16 * 4)
 
 
 class TestShardedBatcher:

@@ -501,6 +501,219 @@ class TestEngineAndService:
                 httpd.server_close()
 
 
+class StubFleet:
+    """The least of a fleet engine the service needs: ``submit_work``
+    marks the path on which the batch outlives ``dispatch``."""
+
+    ds, compile_count = 8, 0
+
+    def __init__(self):
+        self.telemetry = obs.Telemetry()
+        self.work = []
+
+    def bind(self, *, on_complete, **_):
+        self.on_complete = on_complete
+
+    def start(self):
+        pass
+
+    def close(self):
+        pass
+
+    def healthz(self):
+        return {"ok": True, "replicas": [], "live": 0, "generation": 0}
+
+    def submit_work(self, bucket_hw, batch, requests, *, pin=None):
+        self.work.append((bucket_hw, batch, requests))
+
+    def complete_all(self):
+        for bucket_hw, batch, requests in self.work:
+            b, h, w, _ = batch.image.shape
+            self.on_complete(bucket_hw, batch, requests,
+                             np.zeros((b,), np.float32),
+                             np.zeros((b, h // 8, w // 8, 1), np.float32),
+                             0.0, False, 0, "stub")
+
+
+class TestStagingReuse:
+    """The in-process service assembles each launch into the batcher's
+    staging buffer; what the engine is handed, and what the clients get,
+    is what fresh ``pad_batch`` batches give, bit for bit."""
+
+    LADDER = ((96,), (96,))
+    KEY = (96, 96, "float32")
+
+    def service(self, engine, **kw):
+        kw.setdefault("max_batch", 4)
+        svc = CountService(engine, max_wait_ms=2.0, bucket_ladder=self.LADDER,
+                           queue_capacity=64, **kw)
+        svc.warmup([(96, 96)])
+        return svc
+
+    def checked_dispatch(self, svc, reference):
+        """Wrap the service's dispatch: the batch it is handed equals a
+        fresh ``pad_batch`` of the same requests, whose answers (computed
+        here, from the fresh batch) go to ``reference`` by request id."""
+        from can_tpu.data import pad_batch
+
+        inner = svc.batcher.dispatch
+
+        def dispatch(bucket_hw, batch, requests):
+            items = [(r.image, np.zeros((r.shape[0] // 8, r.shape[1] // 8, 1),
+                                        np.float32)) for r in requests]
+            fresh = pad_batch(items, bucket_hw, batch.image.shape[0],
+                              [True] * len(items), 8)
+            for name in ("image", "dmap", "pixel_mask", "sample_mask"):
+                got, want = getattr(batch, name), getattr(fresh, name)
+                assert got.dtype == want.dtype, name
+                np.testing.assert_array_equal(got, want, err_msg=name)
+            counts, density = svc.engine.predict_batch(fresh,
+                                                       want_density=True)
+            for slot, r in enumerate(requests):
+                h, w = r.shape
+                reference[r.id] = (float(counts[slot]),
+                                   density[slot, :h // 8, :w // 8].copy())
+            inner(bucket_hw, batch, requests)
+
+        svc.batcher.dispatch = dispatch
+
+    def rounds(self, rng):
+        """Launches of 4, 2 + 1, 1 and 4 slots, the items first large and
+        then small (margins to zero), then large again."""
+        sides = [[(96, 96)] * 4, [(32, 40), (96, 64), (8, 8)], [(64, 96)],
+                 [(48, 48), (96, 96), (16, 88), (72, 24)]]
+        return [[rng.standard_normal((h, w, 3)).astype(np.float32)
+                 for h, w in group] for group in sides]
+
+    def test_served_answers_equal_fresh_batches_and_outlive_the_buffer(
+            self, small_engine):
+        svc = self.service(small_engine)
+        reference = {}
+        self.checked_dispatch(svc, reference)
+        tickets = []
+        for group in self.rounds(np.random.default_rng(11)):
+            tickets += [svc.submit(img, want_density=True) for img in group]
+            svc.batcher.intake()
+            svc.batcher.flush_all()  # hand-driven: one group, known launches
+        results = [(t._request.id, t.result(0)) for t in tickets]
+        assert len(results) == 12 and len(reference) == 12
+        for rid, res in results:
+            count, dens = reference[rid]
+            assert res.count == count  # bit for bit
+            np.testing.assert_array_equal(res.density, dens)
+        # launches of 4, 2, 1, 1, 4 slots: the first made the buffer
+        staging = svc.stats()["staging"]
+        buf = svc.batcher._staging_pool[self.KEY]
+        assert staging == {"reused": 4, "fresh": 1, "bytes_held": buf.nbytes}
+        assert buf.image.shape == (4, 96, 96, 3)
+        # the buffer is the batcher's again: scribbling on it reaches no
+        # answer a client already holds
+        for a in (buf.image, buf.dmap, buf.pixel_mask, buf.sample_mask):
+            a[...] = np.nan
+        for rid, res in results:
+            count, dens = reference[rid]
+            assert res.count == count
+            np.testing.assert_array_equal(res.density, dens)
+        svc.close()
+        assert svc.stats()["staging"] == {"reused": 4, "fresh": 1,
+                                          "bytes_held": 0}
+
+    def test_a_dispatch_that_raises_leaves_the_buffer_usable(
+            self, small_engine, monkeypatch):
+        svc = self.service(small_engine)
+        reference = {}
+        self.checked_dispatch(svc, reference)
+        rng = np.random.default_rng(12)
+        big, small, mid = self.rounds(rng)[0], self.rounds(rng)[1], \
+            self.rounds(rng)[3]
+
+        def launch(group):
+            tickets = [svc.submit(img, want_density=True) for img in group]
+            svc.batcher.intake()
+            svc.batcher.flush_all()
+            return tickets
+
+        for t in launch(big):
+            t.result(0)
+        real = small_engine.predict_batch
+        calls = {"n": 0}
+
+        def boom(batch, *, want_density=False):
+            calls["n"] += 1
+            if calls["n"] % 2 == 0:  # the service's call, not the check's
+                raise RuntimeError("device fell over")
+            return real(batch, want_density=want_density)
+
+        monkeypatch.setattr(small_engine, "predict_batch", boom)
+        failed = launch(small[:1])
+        with pytest.raises(RejectedError) as e:
+            failed[0].result(0)
+        assert e.value.reason == REJECT_ERROR
+        monkeypatch.setattr(small_engine, "predict_batch", real)
+        for t in launch(mid):  # checked against fresh inside the dispatch
+            res = t.result(0)
+            count, dens = reference[t._request.id]
+            assert res.count == count
+            np.testing.assert_array_equal(res.density, dens)
+        assert svc.stats()["staging"]["reused"] == 2
+        svc.close()
+
+    def test_uint8_and_float32_stage_apart(self, small_engine):
+        svc = self.service(small_engine)
+        svc.warmup([(96, 96)], dtypes=(np.uint8,))
+        reference = {}
+        self.checked_dispatch(svc, reference)
+        rng = np.random.default_rng(13)
+        for _ in range(2):
+            ts = [svc.submit(rng.integers(0, 255, (40, 96, 3)).astype(np.uint8)),
+                  svc.submit(rng.standard_normal((96, 40, 3))
+                             .astype(np.float32))]
+            svc.batcher.intake()
+            svc.batcher.flush_all()
+            for t in ts:
+                assert t.result(0).count == reference[t._request.id][0]
+        pool = svc.batcher._staging_pool
+        assert sorted(pool) == [(96, 96, "float32"), (96, 96, "uint8")]
+        assert pool[(96, 96, "uint8")].image.dtype == np.uint8
+        assert svc.stats()["staging"] == {
+            "reused": 2, "fresh": 2,
+            "bytes_held": sum(b.nbytes for b in pool.values())}
+        svc.close()
+
+    def test_the_fleet_path_assembles_every_launch_fresh(self):
+        fleet = StubFleet()
+        svc = CountService(fleet, max_batch=4, max_wait_ms=2.0,
+                           bucket_ladder=self.LADDER)
+        assert svc.batcher._staging_pool is None
+        tickets = []
+        for group in self.rounds(np.random.default_rng(14)):
+            tickets += [svc.submit(img) for img in group]
+            svc.batcher.intake()
+            svc.batcher.flush_all()
+        assert len(fleet.work) == 5
+        for _, batch, _ in fleet.work:  # each batch owns its arrays
+            assert batch.image.base is None and batch.image.flags.owndata
+        images = [b.image for _, b, _ in fleet.work]
+        assert not any(np.shares_memory(a, b) for i, a in enumerate(images)
+                       for b in images[i + 1:])
+        assert svc.stats()["staging"] == {"reused": 0, "fresh": 5,
+                                          "bytes_held": 0}
+        fleet.complete_all()
+        assert all(t.result(0).count == 0.0 for t in tickets)
+        svc.close()
+
+    def test_staging_reaches_the_scrape(self):
+        from can_tpu.obs.exporter import render_stats
+
+        text = render_stats({"batches": 3, "staging": {
+            "reused": 2, "fresh": 1, "bytes_held": 4096}})
+        assert ('can_tpu_serve_staging_launches_total{assembled="reused"} 2'
+                in text)
+        assert ('can_tpu_serve_staging_launches_total{assembled="fresh"} 1'
+                in text)
+        assert "can_tpu_serve_staging_bytes_held 4096" in text
+
+
 class TestOfflineOnlineParity:
     """Acceptance: a served count is bit-for-bit evaluate()'s per-image
     output for the same image and params."""

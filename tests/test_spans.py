@@ -218,9 +218,13 @@ class TestServeSpans:
             assert k["serve.fetch"]["density"] is False
             assert k["serve.complete"]["resolved"] == b["valid"]
             assert k["serve.dispatch"]["duration_s"] >= 0.004
-        # the counter at the same boundary, always on
+        # the counters at the same boundary, always on
         counted = collections.Counter(b["flush_reason"] for b in batches)
         assert svc.stats()["flush_reasons"] == dict(counted)
+        # the first launch of the key made its staging buffer
+        assert [s["reused"] for s in names["serve.pad"]] == [False, True, True]
+        assert svc.stats()["staging"] == {"reused": 2, "fresh": 1,
+                                          "bytes_held": 0}  # closed: released
         # the thread's own cycle lies on one lane
         cycle = names["serve.wait"] + names["serve.intake"] + names["serve.poll"]
         assert len({s["trace_id"] for s in cycle}) == 1
