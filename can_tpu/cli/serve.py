@@ -58,6 +58,11 @@ def parse_args(argv=None):
     p.add_argument("--syncBN", action="store_true",
                    help="checkpoint is the BatchNorm model variant")
     p.add_argument("--seed", type=int, default=0)
+    p.add_argument("--model-config", type=str, default="",
+                   help="serve the model this configuration file describes "
+                        "(benchmark/configs/*.json's format; a language "
+                        "model: POST /generate) instead of CANNet from a "
+                        "checkpoint; its weights are made from --seed")
     # serving policy
     p.add_argument("--bucket-shapes", type=parse_bucket_shapes,
                    default=parse_bucket_shapes("384x512,512x768,768x1024"),
@@ -297,6 +302,8 @@ def build_service(args, telemetry=None):
     from can_tpu.cli.test import load_params
     from can_tpu.serve import CountService, FleetEngine, ServeEngine
 
+    if args.model_config:
+        return _build_configured(args, telemetry)
     if args.bf16 and args.serve_dtype != "f32":
         raise SystemExit("--bf16 is the legacy f32-params/bf16-compute "
                          "mode; with --serve-dtype use the mode itself "
@@ -423,11 +430,36 @@ def build_service(args, telemetry=None):
     return service
 
 
+def _build_configured(args, telemetry):
+    """The service of ``--model-config``: ``serve.build_model_service``,
+    the construction the benchmark's driver uses, then its warm-up."""
+    import json
+
+    from can_tpu.serve import build_model_service
+
+    if args.replicas != 1:
+        raise SystemExit("--model-config serves one engine in process "
+                         "(drop --replicas)")
+    with open(args.model_config) as f:
+        config = json.load(f)
+    try:
+        service = build_model_service(config, seed=args.seed,
+                                      telemetry=telemetry)
+    except ValueError as e:
+        raise SystemExit(f"--model-config refused: {e}")
+    report = service.warmup()
+    print(f"[serve] warmup: {report['compiles']} programs over "
+          f"{report['shapes']} length buckets x {report['sizes']} launch "
+          f"sizes in {report['seconds']:.1f}s")
+    return service
+
+
 def main(argv=None) -> int:
     args = parse_args(argv)
     from can_tpu.cli.test import validate_params_source
 
-    validate_params_source(args)  # the corrected sentinel logic, shared
+    if not args.model_config:
+        validate_params_source(args)  # the corrected sentinel logic, shared
     from can_tpu.cli.train import (
         apply_compile_cache,
         apply_platform,
@@ -453,7 +485,8 @@ def main(argv=None) -> int:
             exporter.add_stats_source("serve", service.stats)
         with service:
             httpd = serve_http(service, host=args.host, port=args.port)
-            endpoints = "POST /predict, GET /healthz, GET /stats"
+            endpoints = ("POST /generate" if args.model_config
+                         else "POST /predict") + ", GET /healthz, GET /stats"
             if args.replicas > 1:
                 endpoints += ", POST /rollout"
             print(f"[serve] listening on http://{args.host}:{args.port} "

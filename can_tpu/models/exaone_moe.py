@@ -1,0 +1,398 @@
+"""K-EXAONE (``model_type`` ``exaone_moe``): a decoder with window and
+full attention layers mixed, a dense first layer, sparse experts with one
+shared expert in the others, and a multi-token-prediction (MTP) module.
+
+Pure functions over a parameter tree made from a seed:
+
+* ``prefill(params, tokens, lengths, cfg)`` -> (logits at each sequence's
+  last position, cache);
+* ``decode_step(params, cache, tokens, positions, cfg)`` -> (logits, cache);
+* ``mtp_logits(params, hidden, next_tokens, cfg)`` -> logits for ``t + 2``.
+
+One chip's share: ``cfg.share`` says which experts of each layer live here
+(``ops/moe.py``) and ``cfg.vocab`` which slice of the vocabulary; ids,
+logits and the argmax are over the slice.  Weights and activations follow
+the parameter tree's dtype (bfloat16 as served; the CPU tests also run
+float32); the router, the softmax and the norms' statistics are float32.
+
+The block: ``h = x + Attn(RMSNorm(x))``, ``y = h + F(RMSNorm(h))``, a final
+RMSNorm and an untied head.  Three conventions are the family's (EXAONE
+4.0, arXiv:2507.11407, and the K-EXAONE model card) and not keys of the
+published ``config.json``; each sits behind one field of the config:
+``qk_norm`` (q and k RMS-normalised per head), ``rope_layers`` (rotary
+embedding on window layers only) and ``pre_norm`` (norms before the
+sublayers).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import json
+import math
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from can_tpu.ops import attention as attn_ops
+from can_tpu.ops import moe as moe_ops
+from can_tpu.ops.moe import ExpertShare
+
+WINDOW, FULL = "sliding_attention", "full_attention"
+
+
+class VocabSlice(ExpertShare):
+    """Rows ``first .. first + held - 1`` of the ``total`` vocabulary."""
+
+    __slots__ = ()
+
+
+@dataclasses.dataclass(frozen=True)
+class ExaoneMoeConfig:
+    hidden_size: int
+    num_attention_heads: int
+    num_key_value_heads: int
+    head_dim: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    num_experts_per_tok: int
+    num_shared_experts: int
+    routed_scaling_factor: float
+    norm_topk_prob: bool
+    rms_norm_eps: float
+    rope_theta: float
+    sliding_window: int
+    layer_types: Tuple[str, ...]        # of the layers held
+    mlp_layer_types: Tuple[str, ...]
+    share: ExpertShare
+    vocab: VocabSlice
+    mtp_layers: int = 0
+    # the family's conventions (module docstring)
+    qk_norm: bool = True
+    rope_layers: Tuple[str, ...] = (WINDOW,)
+    pre_norm: bool = True
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def groups(self) -> int:
+        return self.num_attention_heads // self.num_key_value_heads
+
+    @classmethod
+    def from_dict(cls, d: dict) -> "ExaoneMoeConfig":
+        """From a configuration file: the published ``config.json`` keys
+        with the cut applied (``num_hidden_layers`` kept, ``num_experts``
+        and ``vocab_size`` HELD), ``published`` for the uncut counts,
+        ``deployment`` for the rank, ``assumed`` for the conventions."""
+        pub = d.get("published", {})
+        dep = d.get("deployment", {})
+        ass = d.get("assumed", {})
+        n = int(d["num_hidden_layers"])
+        rank = int(dep.get("rank", 0))
+        held_e, tot_e = int(d["num_experts"]), int(pub.get("num_experts", d["num_experts"]))
+        held_v, tot_v = int(d["vocab_size"]), int(pub.get("vocab_size", d["vocab_size"]))
+        if int(d.get("n_group", 1)) != 1 or int(d.get("topk_group", 1)) != 1:
+            raise ValueError("group-limited routing is not implemented "
+                             "(n_group and topk_group must be 1)")
+        if d.get("scoring_func", "sigmoid") != "sigmoid":
+            raise ValueError("only sigmoid router scores are implemented")
+        return cls(
+            hidden_size=int(d["hidden_size"]),
+            num_attention_heads=int(d["num_attention_heads"]),
+            num_key_value_heads=int(d["num_key_value_heads"]),
+            head_dim=int(d["head_dim"]),
+            intermediate_size=int(d["intermediate_size"]),
+            moe_intermediate_size=int(d["moe_intermediate_size"]),
+            num_experts_per_tok=int(d["num_experts_per_tok"]),
+            num_shared_experts=int(d["num_shared_experts"]),
+            routed_scaling_factor=float(d["routed_scaling_factor"]),
+            norm_topk_prob=bool(d["norm_topk_prob"]),
+            rms_norm_eps=float(d["rms_norm_eps"]),
+            rope_theta=float(d["rope_parameters"]["rope_theta"]),
+            sliding_window=int(d["sliding_window"]),
+            layer_types=tuple(d["layer_types"][:n]),
+            mlp_layer_types=tuple(d["mlp_layer_types"][:n]),
+            share=ExpertShare(rank * held_e, held_e, tot_e),
+            vocab=VocabSlice(rank * held_v, held_v, tot_v),
+            mtp_layers=int(d.get("num_nextn_predict_layers", 0)),
+            qk_norm=bool(ass.get("qk_norm", True)),
+            rope_layers=tuple(ass.get("rope_layers", (WINDOW,))),
+            pre_norm=bool(ass.get("pre_norm", True)),
+        )
+
+    @classmethod
+    def from_file(cls, path: str) -> "ExaoneMoeConfig":
+        with open(path) as f:
+            return cls.from_dict(json.load(f))
+
+
+# -- parameters ---------------------------------------------------------
+def param_shapes(cfg: ExaoneMoeConfig) -> dict:
+    """The tree of shapes (tuples); ``bias`` leaves are float32 buffers."""
+    d, hd = cfg.hidden_size, cfg.head_dim
+    qd, kd = cfg.num_attention_heads * hd, cfg.num_key_value_heads * hd
+
+    def mlp(width):
+        return {"gate": (d, width), "up": (d, width), "down": (width, d)}
+
+    def block(mlp_type):
+        out = {"ln_in": (d,), "ln_post": (d,),
+               "attn": {"wq": (d, qd), "wk": (d, kd), "wv": (d, kd),
+                        "wo": (qd, d), "q_norm": (hd,), "k_norm": (hd,)}}
+        if mlp_type == "dense":
+            out["mlp"] = mlp(cfg.intermediate_size)
+        else:
+            f, e = cfg.moe_intermediate_size, cfg.share.held
+            out["moe"] = {"router": (d, cfg.share.total),
+                          "bias": (cfg.share.total,),
+                          "experts": {"gate": (e, d, f), "up": (e, d, f),
+                                      "down": (e, f, d)},
+                          "shared": mlp(f * cfg.num_shared_experts)}
+        return out
+
+    tree = {"embed": (cfg.vocab.held, d),
+            "layers": [block(t) for t in cfg.mlp_layer_types],
+            "final_norm": (d,), "head": (d, cfg.vocab.held)}
+    if cfg.mtp_layers:
+        tree["mtp"] = {"ln_hidden": (d,), "ln_embed": (d,), "proj": (2 * d, d),
+                       "block": block("sparse"), "final_norm": (d,)}
+    return tree
+
+
+def param_count(cfg: ExaoneMoeConfig) -> int:
+    return sum(math.prod(s) for s in
+               jax.tree.leaves(param_shapes(cfg),
+                               is_leaf=lambda x: isinstance(x, tuple)))
+
+
+def _leaf(key, name: str, shape, dtype):
+    if name in ("ln_in", "ln_post", "final_norm", "q_norm", "k_norm",
+                "ln_hidden", "ln_embed"):
+        return (1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
+    if name == "bias":      # the router's correction bias: a float32 buffer
+        return 0.05 * jax.random.normal(key, shape, jnp.float32)
+    if name == "embed":
+        return jax.random.normal(key, shape, dtype)
+    fan_in = shape[-2]
+    return jax.random.normal(key, shape, dtype) * jnp.asarray(fan_in ** -0.5, dtype)
+
+
+def init_params(key, cfg: ExaoneMoeConfig, dtype=jnp.bfloat16):
+    """Parameters from a key, leaf by leaf on the device (one jitted call a
+    leaf: no float32 copy of the whole tree is ever alive).  Projections
+    N(0, 1 / fan_in) so that activations stay of order one, norms near one,
+    embedding N(0, 1)."""
+    shapes = param_shapes(cfg)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(
+        shapes, is_leaf=lambda x: isinstance(x, tuple))
+    make = jax.jit(_leaf, static_argnums=(1, 2, 3))
+    leaves = []
+    for i, (path, shape) in enumerate(flat):
+        leaves.append(make(jax.random.fold_in(key, i), str(path[-1].key), shape,
+                           dtype))
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+# -- layers -------------------------------------------------------------
+def rms_norm(x, g, eps: float):
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (y * g.astype(jnp.float32)).astype(x.dtype)
+
+
+def swiglu(x, p):
+    return jnp.dot(jax.nn.silu(jnp.dot(x, p["gate"])) * jnp.dot(x, p["up"]),
+                   p["down"])
+
+
+def _qkv(p, x, positions, layer_type, cfg: ExaoneMoeConfig):
+    """``x`` (B, L, d) -> q (B, L, KV, G, D), k, v (B, L, KV, D)."""
+    b, l, _ = x.shape
+    kv, g, hd = cfg.num_key_value_heads, cfg.groups, cfg.head_dim
+    q = jnp.dot(x, p["wq"]).reshape(b, l, kv, g, hd)
+    k = jnp.dot(x, p["wk"]).reshape(b, l, kv, hd)
+    v = jnp.dot(x, p["wv"]).reshape(b, l, kv, hd)
+    if cfg.qk_norm:
+        q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
+        k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
+    if layer_type in cfg.rope_layers:
+        q = attn_ops.rope(q, positions, cfg.rope_theta)
+        k = attn_ops.rope(k, positions, cfg.rope_theta)
+    return q, k, v
+
+
+def expert_layer(p, x, cfg: ExaoneMoeConfig):
+    """``x`` (T, d) -> (this chip's part of the routed sum + the shared
+    expert (T, d), the experts each token chose (T, k))."""
+    with jax.named_scope("moe"):
+        idx, w = moe_ops.route(x, p["router"], p["bias"],
+                               top_k=cfg.num_experts_per_tok,
+                               scale=cfg.routed_scaling_factor,
+                               normalize=cfg.norm_topk_prob)
+        routed = moe_ops.share_apply(x, idx, w, p["experts"], cfg.share)
+        return routed + swiglu(x, p["shared"]), idx
+
+
+def _ffn(layer, h, cfg: ExaoneMoeConfig):
+    """The feed-forward half of a block on (B, L, d): -> (y, the experts
+    each token chose (B, L, k), or None for the dense layer)."""
+    b, l, d = h.shape
+    x = rms_norm(h, layer["ln_post"], cfg.rms_norm_eps) if cfg.pre_norm else h
+    if "mlp" in layer:
+        with jax.named_scope("dense_mlp"):
+            return h + swiglu(x, layer["mlp"]), None
+    y, idx = expert_layer(layer["moe"], x.reshape(b * l, d), cfg)
+    return h + y.reshape(b, l, d), idx.reshape(b, l, -1)
+
+
+def _routing(chosen, mask, pick, cfg: ExaoneMoeConfig) -> dict:
+    """What a program reports of its routing: ``counts`` (expert layers,
+    held) assignments of the tokens ``mask`` (B, L) marks that landed on
+    each held expert, and ``choices`` (expert layers, B, k) the experts
+    chosen at position ``pick`` (B,) of each sequence."""
+    chosen = [c for c in chosen if c is not None]
+    if not chosen:
+        return {"counts": jnp.zeros((0, cfg.share.held), jnp.int32),
+                "choices": jnp.zeros((0, pick.shape[0], cfg.num_experts_per_tok),
+                                     jnp.int32)}
+    counts = [moe_ops.held_counts(jnp.where(mask[..., None], c, -1), cfg.share)
+              for c in chosen]
+    at = [jnp.take_along_axis(c, pick[:, None, None], axis=1)[:, 0]
+          for c in chosen]
+    return {"counts": jnp.stack(counts), "choices": jnp.stack(at)}
+
+
+def _embed(params, tokens):
+    return params["embed"][tokens]
+
+
+def _head(params, h, cfg: ExaoneMoeConfig):
+    with jax.named_scope("head"):
+        x = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
+        return jnp.dot(x, params["head"],
+                       preferred_element_type=jnp.float32)
+
+
+# -- prefill ------------------------------------------------------------
+def _prefill_block(layer, layer_type, x, positions, cfg,
+                   cache_len: Optional[int], lengths):
+    """One block over whole prompts; -> (y, cache entry or None, chosen)."""
+    with jax.named_scope("attn"):
+        xn = rms_norm(x, layer["ln_in"], cfg.rms_norm_eps) if cfg.pre_norm else x
+        q, k, v = _qkv(layer["attn"], xn, positions, layer_type, cfg)
+        if layer_type == WINDOW:
+            o = attn_ops.prefill_window(q, k, v, window=cfg.sliding_window)
+        else:
+            o = attn_ops.prefill_full(q, k, v)
+        b, l = x.shape[:2]
+        h = x + jnp.dot(o.reshape(b, l, -1), layer["attn"]["wo"])
+        entry = None
+        if cache_len is not None:
+            kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+            if layer_type == WINDOW:
+                # slot r <- the newest position p < length with p % W == r
+                held = attn_ops.ring_positions(lengths - 1, cfg.sliding_window)
+                take = jnp.clip(held, 0, l - 1)[:, None, :, None]
+                entry = {"k": jnp.take_along_axis(kt, take, axis=2),
+                         "v": jnp.take_along_axis(vt, take, axis=2)}
+            else:
+                pad = ((0, 0), (0, 0), (0, cache_len - l), (0, 0))
+                entry = {"k": jnp.pad(kt, pad), "v": jnp.pad(vt, pad)}
+    y, chosen = _ffn(layer, h, cfg)
+    return y, entry, chosen
+
+
+def prefill_hidden(params, tokens, lengths, cfg: ExaoneMoeConfig,
+                   cache_len: Optional[int] = None, active=None):
+    """Whole prompts through the blocks: -> (hidden (B, L, d) before the
+    final norm, cache or None, ``_routing`` of the valid tokens).
+    ``tokens`` (B, L) right-padded, ``lengths`` (B,); ``active`` (B,) marks
+    the sequences whose routing is counted (all when None).  Padded
+    positions compute garbage no valid position ever sees (attention is
+    causal)."""
+    b, l = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(l)[None], (b, l))
+    mask = positions < lengths[:, None]
+    if active is not None:
+        mask &= active[:, None]
+    x = _embed(params, tokens)
+    entries, chosen = [], []
+    for layer, lt in zip(params["layers"], cfg.layer_types):
+        x, entry, c = _prefill_block(layer, lt, x, positions, cfg, cache_len,
+                                     lengths)
+        entries.append(entry)
+        chosen.append(c)
+    cache = None if cache_len is None else {"layers": entries}
+    return x, cache, _routing(chosen, mask, lengths - 1, cfg)
+
+
+def prefill(params, tokens, lengths, cfg: ExaoneMoeConfig, cache_len: int,
+            active=None):
+    """-> (float32 logits (B, V) at each sequence's last position, cache,
+    routing).  The cache holds ``cache_len`` positions in full
+    layers and ``cfg.sliding_window`` in window layers."""
+    h, cache, routing = prefill_hidden(params, tokens, lengths, cfg, cache_len,
+                                       active)
+    last = jnp.take_along_axis(h, (lengths - 1)[:, None, None], axis=1)[:, 0]
+    return _head(params, last, cfg), cache, routing
+
+
+# -- decode -------------------------------------------------------------
+def decode_step(params, cache, tokens, positions, cfg: ExaoneMoeConfig,
+                active=None):
+    """One token per sequence: ``tokens`` (B,) at ``positions`` (B,) ->
+    (float32 logits (B, V) for the next position, cache, routing).  The
+    token's key and value are written at its position (its ring slot in
+    window layers) before it attends.  ``active`` (B,) marks the slots
+    whose routing is counted (all when None)."""
+    b = tokens.shape[0]
+    pos2 = positions[:, None]
+    x = _embed(params, tokens)[:, None]                       # (B, 1, d)
+    entries, chosen = [], []
+    for layer, lt, entry in zip(params["layers"], cfg.layer_types,
+                                cache["layers"]):
+        with jax.named_scope("attn"):
+            xn = (rms_norm(x, layer["ln_in"], cfg.rms_norm_eps)
+                  if cfg.pre_norm else x)
+            q, k, v = _qkv(layer["attn"], xn, pos2, lt, cfg)
+            if lt == WINDOW:
+                slot = jnp.mod(positions, cfg.sliding_window)
+                valid = attn_ops.ring_positions(positions,
+                                                cfg.sliding_window) >= 0
+            else:
+                slot = positions
+                valid = jnp.arange(entry["k"].shape[2])[None, :] <= pos2
+            kc = attn_ops.write_slot(entry["k"], k[:, 0], slot)
+            vc = attn_ops.write_slot(entry["v"], v[:, 0], slot)
+            o = attn_ops.decode(q[:, 0], kc, vc, valid)
+            h = x + jnp.dot(o.reshape(b, 1, -1), layer["attn"]["wo"])
+        entries.append({"k": kc, "v": vc})
+        x, c = _ffn(layer, h, cfg)
+        chosen.append(c)
+    mask = jnp.ones((b, 1), bool) if active is None else active[:, None]
+    return (_head(params, x[:, 0], cfg), {"layers": entries},
+            _routing(chosen, mask, jnp.zeros((b,), jnp.int32), cfg))
+
+
+# -- multi-token prediction -----------------------------------------------
+def mtp_logits(params, hidden, next_tokens, cfg: ExaoneMoeConfig):
+    """The MTP module in DeepSeek-V3's form, over whole sequences:
+    ``h' = W_p [RMSNorm(h_t); RMSNorm(Emb(x_{t+1}))]``, one full-attention
+    block with an expert layer, the module's norm and the SHARED head:
+    float32 logits (B, L, V) for position ``t + 2``.  ``hidden`` (B, L, d)
+    is ``prefill_hidden``'s, ``next_tokens`` (B, L) the ids at ``t + 1``."""
+    m = params["mtp"]
+    b, l, _ = hidden.shape
+    with jax.named_scope("mtp"):
+        x = jnp.concatenate(
+            [rms_norm(hidden, m["ln_hidden"], cfg.rms_norm_eps),
+             rms_norm(_embed(params, next_tokens), m["ln_embed"],
+                      cfg.rms_norm_eps)], axis=-1)
+        x = jnp.dot(x, m["proj"])
+        positions = jnp.broadcast_to(jnp.arange(l)[None], (b, l))
+        x, _, _ = _prefill_block(m["block"], FULL, x, positions, cfg, None,
+                                 None)
+        x = rms_norm(x, m["final_norm"], cfg.rms_norm_eps)
+        return jnp.dot(x, params["head"], preferred_element_type=jnp.float32)

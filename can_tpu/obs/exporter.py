@@ -450,7 +450,9 @@ def render_stats(stats: dict, *, prefix: str = "can_tpu_serve",
     or lagging a rollout generation, ``"flush_reasons"``, whose counts
     become ``can_tpu_serve_flushes_total{reason="full"}`` lines, and
     ``"staging"``: ``can_tpu_serve_staging_launches_total{assembled=
-    "reused"}`` lines and the ``can_tpu_serve_staging_bytes_held`` gauge."""
+    "reused"}`` lines and the ``can_tpu_serve_staging_bytes_held`` gauge, and
+    ``"lm"`` (a language model's engine): ``can_tpu_serve_lm_*_total`` counters
+    and ``can_tpu_serve_lm_cache_bytes{kind=}``."""
     gauges: Dict[str, float] = {}
     counters: Dict[Tuple[str, tuple], float] = {}
     labelled_gauges: Dict[Tuple[str, tuple], float] = {}
@@ -484,6 +486,19 @@ def render_stats(stats: dict, *, prefix: str = "can_tpu_serve",
                 else:
                     counters[(f"{prefix}_staging_launches_total",
                               (("assembled", str(how)),))] = n
+            continue
+        if k == "lm" and isinstance(v, dict):
+            # the language model's engine: generated tokens, assignments
+            # that landed on held experts against all, cache bytes by kind
+            for name, n in v.items():
+                if name == "cache_bytes":
+                    for kind, b in n.items():
+                        labelled_gauges[(f"{prefix}_lm_cache_bytes",
+                                         (("kind", str(kind)),))] = b
+                elif name == "expert_tokens_max":
+                    gauges[f"{prefix}_lm_{name}"] = n
+                else:
+                    counters[(f"{prefix}_lm_{name}_total", ())] = n
             continue
         if v is None or not isinstance(v, (int, float, bool)):
             continue

@@ -47,14 +47,17 @@ class RecompileTracker:
     would let one 10 s compile masquerade as the step p95/max)."""
 
     def __init__(self, fn: Callable, telemetry, *, name: str = "step",
-                 batch_arg: int = 1):
+                 batch_arg: int = 1, signature_of: Optional[Callable] = None):
         from can_tpu.train.steps import batch_signature
 
         self._fn = fn
         self._tel = telemetry
         self._name = name
-        self._batch_arg = batch_arg
-        self._signature = batch_signature
+        # signature_of(args) -> hashable: for a callable whose program
+        # depends on more than one dict of arrays (a decode step: its
+        # state AND its cache); None keys on args[batch_arg] alone
+        self._signature = (signature_of if signature_of is not None else
+                           lambda args: batch_signature(args[batch_arg]))
         self._seen = telemetry.signature_registry.setdefault(name, {})
         self.last_first_call = False
 
@@ -68,7 +71,7 @@ class RecompileTracker:
         return inner(*args) if inner is not None else self._fn
 
     def __call__(self, *args):
-        sig = self._signature(args[self._batch_arg])
+        sig = self._signature(args)
         if sig in self._seen:
             self.last_first_call = False
             return self._fn(*args)

@@ -1,0 +1,119 @@
+"""Grouped-query attention for a decoder: prefill over a whole prompt in
+blocks, and one-token decode over a cache.
+
+Shapes: ``q`` (B, L, KV, G, D) with G query heads to each of the KV
+key/value heads; ``k``, ``v`` (B, L, KV, D).  Scores and the softmax are
+float32 whatever the inputs are; the output has ``q``'s dtype.
+
+Two kinds of layer: *full* (every earlier position) and *window*
+(``i - j < window``).  Prefill never forms an L x L score matrix for a window
+layer (a query block of ``window`` rows sees its own block and the one
+before), and for a full layer forms it a block of queries at a time, each
+against the keys up to its own end.  Decode reads a cache laid out
+(B, KV, S, D): the whole context for a full layer, a ring of ``window``
+slots for a window layer, in which slot ``r`` holds the newest position
+``p <= pos`` with ``p % window == r``.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+NEG = -1e30  # finite: a fully masked row (a padded slot) must not make NaN
+
+
+def rope(x, positions, theta: float):
+    """Rotary embedding, rotate-half over the whole last dimension.
+    ``x`` (B, L, ..., D), ``positions`` (B, L)."""
+    d = x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = positions.astype(jnp.float32)[..., None] * inv      # (B, L, D/2)
+    ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + (d // 2,))
+    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)
+    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., : d // 2], x32[..., d // 2:]
+    rot = jnp.concatenate([-x2, x1], -1)
+    return (x32 * cos + rot * sin).astype(x.dtype)
+
+
+def _softmax_av(scores, mask, v, eq, dtype):
+    scores = jnp.where(mask, scores, NEG)
+    p = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum(eq, p.astype(dtype), v,
+                      preferred_element_type=jnp.float32).astype(dtype)
+
+
+def prefill_full(q, k, v, *, block: int = 256):
+    """Causal attention over the whole prompt, a block of queries at a time."""
+    b, l, kv, g, d = q.shape
+    block = min(block, l)
+    if l % block:
+        raise ValueError(f"prompt bucket {l} is not a multiple of the query "
+                         f"block {block}")
+    scale = d ** -0.5
+    outs = []
+    for i in range(l // block):
+        hi = (i + 1) * block
+        qb = q[:, i * block:hi]
+        s = jnp.einsum("bqkgd,bskd->bkgqs", qb, k[:, :hi],
+                       preferred_element_type=jnp.float32) * scale
+        mask = (jnp.arange(hi)[None, :]
+                <= (i * block + jnp.arange(block))[:, None])
+        outs.append(_softmax_av(s, mask, v[:, :hi], "bkgqs,bskd->bqkgd",
+                                q.dtype))
+    return jnp.concatenate(outs, axis=1)
+
+
+def prefill_window(q, k, v, *, window: int):
+    """Causal attention with ``i - j < window``: blocks of ``window``
+    queries, each against its own block of keys and the one before."""
+    b, l, kv, g, d = q.shape
+    if l % window:
+        raise ValueError(f"prompt bucket {l} is not a multiple of the "
+                         f"window {window}")
+    nb = l // window
+    qb = q.reshape(b, nb, window, kv, g, d)
+
+    def two_blocks(x):
+        xb = x.reshape(b, nb, window, kv, d)
+        prev = jnp.pad(xb[:, :-1], ((0, 0), (1, 0), (0, 0), (0, 0), (0, 0)))
+        return jnp.concatenate([prev, xb], axis=2)   # (B, nb, 2W, KV, D)
+
+    kk, vv = two_blocks(k), two_blocks(v)
+    s = jnp.einsum("bnqkgd,bnskd->bnkgqs", qb, kk,
+                   preferred_element_type=jnp.float32) * d ** -0.5
+    qi = jnp.arange(window)[:, None] + window         # in two-block frame
+    kj = jnp.arange(2 * window)[None, :]
+    mask = (kj <= qi) & (qi - kj < window)
+    first = mask & (kj >= window)                     # block 0 has no "before"
+    mask = jnp.where((jnp.arange(nb) == 0)[:, None, None], first, mask)
+    out = _softmax_av(s, mask[None, :, None, None], vv,
+                      "bnkgqs,bnskd->bnqkgd", q.dtype)
+    return out.reshape(b, l, kv, g, d)
+
+
+def decode(q, k_cache, v_cache, valid):
+    """One query per sequence against its cache.  ``q`` (B, KV, G, D);
+    caches (B, KV, S, D); ``valid`` (B, S): which slots hold a position
+    this query may see."""
+    d = q.shape[-1]
+    s = jnp.einsum("bkgd,bksd->bkgs", q, k_cache,
+                   preferred_element_type=jnp.float32) * d ** -0.5
+    return _softmax_av(s, valid[:, None, None, :], v_cache,
+                       "bkgs,bksd->bkgd", q.dtype)
+
+
+def ring_positions(pos, window: int):
+    """(B, W): the position each ring slot holds once the token at ``pos``
+    (B,) has been written; negative where the slot is still empty."""
+    r = jnp.arange(window)[None, :]
+    return pos[:, None] - jnp.mod(pos[:, None] - r, window)
+
+
+def write_slot(cache, new, slot):
+    """``cache`` (B, KV, S, D) with ``new`` (B, KV, D) written at ``slot``
+    (B,), one slot per sequence."""
+    b = cache.shape[0]
+    return cache.at[jnp.arange(b), :, slot].set(new.astype(cache.dtype))

@@ -3,6 +3,7 @@ formation engines consume (offline ShardedBatcher, serve MicroBatcher,
 eval prefetch, fleet work queue).  See sched/core.py."""
 
 from .core import (
+    COST_UNIT,
     DEFAULT_LAUNCH_COST_SLOTS,
     DEFAULT_MENU_BUDGET,
     ServeSched,
@@ -17,6 +18,7 @@ from .core import (
 )
 
 __all__ = [
+    "COST_UNIT",
     "DEFAULT_LAUNCH_COST_SLOTS",
     "DEFAULT_MENU_BUDGET",
     "ServeSched",

@@ -79,6 +79,13 @@ GAP_EWMA_ALPHA = 0.25
 DEFAULT_WAIT_GAP_FACTOR = 2.0
 
 
+# What the pricing function prices: a launch costs its padded slots times
+# the bucket's pixel area, plus a fixed launch cost in the same unit.  A
+# request kind (serve/kinds.py) states the unit its launches cost in;
+# ``ServeSched`` refuses one that is not this.
+COST_UNIT = "px"
+
+
 # -- priced sub-batch menu -------------------------------------------------
 def cover_cost(n: int, menu: Tuple[int, ...],
                launch_cost_slots: float) -> float:
@@ -173,15 +180,28 @@ class ServeSched:
     (the old timer's only surviving role); ``priced_flush=False`` keeps
     the timer as the flush trigger while the menu still prices sizes
     (the legacy escape hatch the CLI's ``--flush-policy timer`` wires).
+
+    kinds: the request kinds whose launches this instance is to price;
+    one whose ``cost_unit`` is not ``COST_UNIT`` is refused (its menu and
+    its flush deadlines would be priced as if a launch cost slots x
+    pixels: groups of 5 and 8 flushed alone where a launch of any size
+    costs the same seconds).
     """
 
-    def __init__(self, max_batch: int, *, max_wait_s: float,
+    def __init__(self, max_batch: int, *, max_wait_s: float, kinds=(),
                  menu: Optional[Tuple[int, ...]] = None,
                  menu_budget: int = DEFAULT_MENU_BUDGET,
                  launch_cost_slots: float = DEFAULT_LAUNCH_COST_SLOTS,
                  priced_flush: bool = True,
                  wait_gap_factor: float = DEFAULT_WAIT_GAP_FACTOR,
                  min_gap_intervals: int = MIN_GAP_INTERVALS):
+        unpriced = sorted(k.name for k in kinds if k.cost_unit != COST_UNIT)
+        if unpriced:
+            raise ValueError(
+                f"the scheduling core prices a launch in {COST_UNIT!r} "
+                f"(slots x pixels + a launch cost) and cannot price request "
+                f"kind(s) {unpriced}: serve them with one launch size and "
+                f"the timer (menu_budget=1, flush_policy='timer')")
         self.max_batch = int(max_batch)
         self.menu = (tuple(sorted(menu, reverse=True)) if menu is not None
                      else default_serve_menu(max_batch, budget=menu_budget))

@@ -28,7 +28,7 @@ and params at the same launch size.
 from .aot import AotBundle, AotStaleError, load_aot_bundle
 from .autoscale import Autoscaler, AutoscalePolicy
 from .batcher import MicroBatcher
-from .engine import ServeEngine, tree_signature
+from .engine import LMEngine, ServeEngine, lm_probe_steps, tree_signature
 from .fleet import (
     REPLICA_ACTIVE,
     REPLICA_DRAINING,
@@ -55,13 +55,17 @@ from .queue import (
     REJECT_STALE_FRAME,
     REJECT_STREAM_OVERLOAD,
     BoundedRequestQueue,
+    GenerateResult,
     RejectedError,
     ServeRequest,
     ServeResult,
+    TokenRequest,
 )
 from .service import (
     CountService,
+    GenerateService,
     ServeTicket,
+    build_model_service,
     make_http_handler,
     prepare_image,
     serve_http,
@@ -82,6 +86,12 @@ __all__ = [
     "AutoscalePolicy",
     "BoundedRequestQueue",
     "CountService",
+    "GenerateResult",
+    "GenerateService",
+    "LMEngine",
+    "TokenRequest",
+    "build_model_service",
+    "lm_probe_steps",
     "FleetClosedError",
     "FleetEngine",
     "MicroBatcher",

@@ -268,8 +268,13 @@ class AotBundle:
                 continue
             with open(os.path.join(self.path, p["file"]), "rb") as f:
                 ser, in_tree, out_tree = pickle.loads(f.read())
+            # execution_devices: the one device the program was compiled
+            # for.  Left out, this JAX loads the executable for EVERY
+            # device of the backend and then refuses the call ("expected
+            # args ... to have 8 shards" on a host with 8 devices)
             table[(tuple(p["shape"]), str(p["dtype"]))] = \
-                se.deserialize_and_load(ser, in_tree, out_tree)
+                se.deserialize_and_load(ser, in_tree, out_tree,
+                                        execution_devices=[device])
         self._loaded[did] = table
         return table
 

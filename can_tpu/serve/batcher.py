@@ -3,10 +3,17 @@ static-shape batch.
 
 The offline ``ShardedBatcher`` solves variable-resolution-under-XLA with
 shape buckets + masked padding; online serving has the same constraint at
-request granularity, so this batcher reuses the SAME math — the bucket
-mapping is ``data.batching.snap_to_bucket`` and batch assembly is
+request granularity, so this batcher reuses the SAME math — for images the
+bucket mapping is ``data.batching.snap_to_bucket`` and batch assembly is
 ``data.batching.pad_batch`` — it only swaps the epoch schedule for an
 arrival-driven flush policy.
+
+What a request carries is its KIND's business (``serve/kinds.py``): the
+request names its kind, and the batcher asks that kind for the group key
+and for the assembly.  Images (``ImageKind``, built from this class's own
+bucket arguments) make the two calls above; prompts of token ids
+(``TokenKind``) bucket on a length ladder.  Intake, pricing, the menu, the
+spans and the staging pool below are one code for every kind.
 
 Where ``dispatch`` is done with a batch when it returns (the in-process
 service: ``predict_batch`` has fetched the answers and the requests are
@@ -63,7 +70,11 @@ Single consumer thread; dispatch runs ON that thread — the device executes
 serially anyway, and one thread means the pending-group state needs no
 locking beyond the queue's own, and that one staging buffer per key is
 enough: the thread assembles the next launch only after the previous
-``dispatch`` has returned.
+``dispatch`` has returned.  A launch therefore blocks the pump for as
+long as it runs, and its view of the queue is stale when it returns:
+``intake`` sorts what arrived meanwhile into its groups before ``poll``
+judges them, so a group's rest is never launched alone while its
+companions sit in the queue.
 """
 
 from __future__ import annotations
@@ -74,8 +85,8 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from can_tpu.data.batching import StagingBatch, pad_batch, snap_to_bucket
 from can_tpu.obs.spans import active
+from can_tpu.serve.kinds import IMAGE, GroupKey, ImageKind
 from can_tpu.serve.queue import (
     REJECT_DEADLINE,
     REJECT_ERROR,
@@ -87,11 +98,6 @@ from can_tpu.serve.queue import (
 # reach the top launch size, ``poll`` saw its priced deadline (or the
 # legacy timer) arrive, ``flush_all`` drained it at shutdown
 FLUSH_FULL, FLUSH_DUE, FLUSH_DRAIN = "full", "due", "drain"
-
-# (bucket H, bucket W, image dtype): dtype is part of the jit signature, so
-# u8 and f32 requests must not share a batch buffer (pad_batch keeps the
-# items' dtype)
-GroupKey = Tuple[int, int, str]
 
 
 class _Group:
@@ -124,8 +130,11 @@ class MicroBatcher:
     core (priced sub-batch menu + priced flush deadlines).  None keeps
     the pre-r14 pad-to-``max_batch`` / fixed-timer behaviour exactly.
 
-    bucket_ladder / pad_multiple / min_bucket_h: forwarded to
-    ``snap_to_bucket`` (same semantics as the offline batcher).
+    bucket_ladder / pad_multiple / min_bucket_h: the image kind's
+    ``snap_to_bucket`` arguments (same semantics as the offline batcher).
+
+    kinds: further request kinds by name (``serve/kinds.py``); the image
+    kind is always there, built from the arguments above.
     """
 
     def __init__(self, queue: BoundedRequestQueue, dispatch: Callable,
@@ -135,7 +144,8 @@ class MicroBatcher:
                  telemetry=None, clock=time.monotonic,
                  idle_wait_s: float = 0.05,
                  on_reject: Optional[Callable] = None,
-                 sched=None, batch_free_on_return: bool = False):
+                 sched=None, batch_free_on_return: bool = False,
+                 kinds=None):
         if max_batch < 1:
             raise ValueError(f"max_batch must be >= 1, got {max_batch}")
         if sched is not None and sched.max_batch != int(max_batch):
@@ -147,12 +157,10 @@ class MicroBatcher:
         self.max_batch = int(max_batch)
         self.max_wait_s = float(max_wait_ms) / 1e3
         self.sched = sched
-        if isinstance(pad_multiple, int):
-            pad_multiple = (pad_multiple, pad_multiple)
-        self.bucket_ladder = bucket_ladder
-        self.pad_multiple = pad_multiple
-        self.min_bucket_h = min_bucket_h
-        self.ds = int(ds)
+        self.kinds = {IMAGE: ImageKind(bucket_ladder=bucket_ladder,
+                                       pad_multiple=pad_multiple,
+                                       min_bucket_h=min_bucket_h, ds=ds)}
+        self.kinds.update(kinds or {})
         self.telemetry = telemetry
         # on_reject(reason, count): batcher-side rejections (deadline
         # expiry, poison batch) happen past the admission gate, so the
@@ -167,7 +175,7 @@ class MicroBatcher:
         # the staging pool (None: every launch fresh), and launches by how
         # they were assembled + the bytes the pool holds now (same
         # writer, same reader as flush_reasons)
-        self._staging_pool: Optional[Dict[GroupKey, StagingBatch]] = (
+        self._staging_pool: Optional[Dict[GroupKey, object]] = (
             {} if batch_free_on_return else None)
         self.staging = {"reused": 0, "fresh": 0, "bytes_held": 0}
         # the trace the thread's own cycle (wait / intake / poll) is
@@ -178,9 +186,8 @@ class MicroBatcher:
 
     # -- bucket mapping -------------------------------------------------
     def bucket_of(self, hw: Tuple[int, int]) -> Tuple[int, int]:
-        return snap_to_bucket(hw, ladder=self.bucket_ladder,
-                              pad_multiple=self.pad_multiple,
-                              min_bucket_h=self.min_bucket_h)
+        """The image kind's bucket for an (H, W)."""
+        return self.kinds[IMAGE].bucket_of(hw)
 
     # -- flush pricing ---------------------------------------------------
     def _flush_at(self, key: GroupKey, group: _Group, now: float) -> float:
@@ -217,8 +224,7 @@ class MicroBatcher:
         else:
             with self._cycle_span(tr, "serve.wait"):
                 self.queue.wait_nonempty(wait)
-        n = self.intake()
-        return n + self.poll(self._clock())
+        return self.intake() + self.poll(self._clock())
 
     def _cycle_span(self, tr, name: str):
         """A span of the batcher thread's own cycle, on the thread's
@@ -229,8 +235,8 @@ class MicroBatcher:
 
     def intake(self) -> int:
         """Drain the queue into per-bucket pending groups; reject already
-        expired requests; flush any group that reaches the top launch
-        size.  Returns batches dispatched."""
+        expired requests; launch every group that reached the top launch
+        size (the one place that does).  Returns batches dispatched."""
         tr = active(self.telemetry)
         if tr is None:
             return self._intake()[0]
@@ -240,13 +246,35 @@ class MicroBatcher:
 
     def _intake(self) -> Tuple[int, int]:
         """-> (batches dispatched, requests taken off the queue)."""
+        taken = self._sort_arrivals()
+        flushed = 0
+        for key in list(self._pending):
+            group = self._pending[key]
+            while len(group.requests) >= self.max_batch:
+                full = group.requests[:self.max_batch]
+                group.requests = group.requests[self.max_batch:]
+                flushed += self._flush(key, full, FLUSH_FULL)
+            if not group.requests:
+                del self._pending[key]
+            else:
+                group.t0 = group.requests[0].t_submit
+        if flushed:
+            # a launch blocked this thread for as long as it ran: what
+            # arrived meanwhile joins its group before ``poll`` judges the
+            # groups.  Else the rest of a drain is flushed as "due" while
+            # its companions sit in the queue: launches of 60 and 4 for a
+            # group of 64
+            taken += self._sort_arrivals()
+        return flushed, taken
+
+    def _sort_arrivals(self) -> int:
+        """The queue's requests into their groups (nothing is launched);
+        -> how many were taken off the queue."""
         live, expired = self.queue.drain()
         for r in expired:
             self._reject_expired(r)
-        flushed = 0
         for r in live:
-            bh, bw = self.bucket_of(r.shape)
-            key = (bh, bw, str(r.image.dtype))
+            key = self.kinds[r.kind].group_key(r)
             group = self._pending.get(key)
             if group is None:
                 group = self._pending[key] = _Group(r.t_submit)
@@ -254,10 +282,7 @@ class MicroBatcher:
             group.t_last = r.t_submit
             if self.sched is not None:
                 self.sched.observe_arrival(key, r.t_submit)
-            if len(group.requests) >= self.max_batch:
-                del self._pending[key]
-                flushed += self._flush(key, group.requests, FLUSH_FULL)
-        return flushed, len(live) + len(expired)
+        return len(live) + len(expired)
 
     def poll(self, now: float) -> int:
         """Reject expired pending requests; flush groups whose priced
@@ -283,6 +308,11 @@ class MicroBatcher:
                 del self._pending[key]
                 continue
             group.requests = kept
+            if len(kept) >= self.max_batch:
+                # filled while a launch blocked the thread: a whole launch
+                # is the next intake's (``next_wake_s`` is 0 for it), and
+                # what it leaves over is judged then, by its own oldest
+                continue
             if now >= self._flush_at(key, group, now):
                 del self._pending[key]
                 flushed += self._flush(key, kept, FLUSH_DUE)
@@ -351,21 +381,14 @@ class MicroBatcher:
             # queue-wait ends where assembly starts, and the service turns
             # the pair into the serve.request breakdown
             t_asm = self._clock()
-            # zero per-item density targets: serve batches reuse the
-            # offline Batch layout (image/dmap/pixel_mask/sample_mask) so
-            # the engine can run the exact eval-step math; dmap is unused
-            # by prediction
-            items = [(r.image,
-                      np.zeros((r.shape[0] // self.ds,
-                                r.shape[1] // self.ds, 1), np.float32))
-                     for r in group]
+            kind = self.kinds[group[0].kind]
             if tr is None:
-                batch, _ = self._assemble(key, items, size)
+                batch, _ = self._assemble(kind, key, group, size)
             else:
                 with tr.span("serve.pad") as sp:
-                    batch, sp.attrs["reused"] = self._assemble(key, items,
-                                                               size)
-                    sp.attrs["bytes"] = int(batch.image.nbytes)
+                    batch, sp.attrs["reused"] = self._assemble(kind, key,
+                                                               group, size)
+                    sp.attrs["bytes"] = int(kind.payload(batch).nbytes)
             t_ready = self._clock()
             for r in group:
                 r.t_assembly = t_asm
@@ -384,20 +407,20 @@ class MicroBatcher:
                                     count=n,
                                     detail=f"{type(e).__name__}: {e}")
 
-    def _assemble(self, key: GroupKey, items, size: int):
-        """-> (the launch's Batch, whether it was assembled into a buffer
+    def _assemble(self, kind, key: GroupKey, group: List[ServeRequest],
+                  size: int):
+        """-> (the launch's batch, whether it was assembled into a buffer
         that already existed)."""
         out, reused = None, False
         if self._staging_pool is not None:
             out = self._staging_pool.get(key)
             reused = out is not None
             if out is None:
-                out = self._staging_pool[key] = StagingBatch(
-                    key[:2], self.max_batch, self.ds, np.dtype(key[2]))
+                out = self._staging_pool[key] = kind.new_staging(
+                    key, self.max_batch)
                 self.staging["bytes_held"] += out.nbytes
         self.staging["reused" if reused else "fresh"] += 1
-        return pad_batch(items, key[:2], size, [True] * len(items),
-                         self.ds, out=out), reused
+        return kind.assemble(key, group, size, out), reused
 
     def _reject_expired(self, r: ServeRequest) -> None:
         r.reject(REJECT_DEADLINE, "deadline expired before dispatch")

@@ -1,6 +1,11 @@
-"""ServeEngine: params + one jitted predict program per bucket signature.
+"""The engines: device-resident params + the jitted programs of one model,
+one program per bucket signature.  An engine is GIVEN its programs
+(``serve/programs.py``, the model seam); it imports no network.
+``ServeEngine`` runs CANNet's one predict program per launch; ``LMEngine``
+(below) a language model's prefill and then a loop of decode steps over a
+cache that stays on the device.
 
-The prediction math is EXACTLY the offline eval step's (``train/steps.py
+ServeEngine's prediction math is EXACTLY the offline eval step's (``train/steps.py
 make_eval_step``): normalise-on-device for u8 batches, ``cannet_apply``
 forward, masked per-image count reduction via ``train.loss.density_counts``
 — so a count served online is bit-for-bit the count ``evaluate()`` would
@@ -38,16 +43,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from can_tpu.data.batching import Batch, pad_batch
-from can_tpu.models import cannet_apply
 from can_tpu.obs import RecompileTracker, Telemetry
 from can_tpu.obs.spans import active
-from can_tpu.serve.quant import (
-    compute_dtype_for,
-    dequantize_tree,
-    quantize_tree,
-)
-from can_tpu.train.loss import density_counts
-from can_tpu.train.steps import _batch_image
+from can_tpu.serve import cache as kv_cache
+from can_tpu.serve.quant import compute_dtype_for, quantize_tree
+from can_tpu.train.steps import batch_signature
 
 
 def _batch_dict(batch: Batch) -> dict:
@@ -82,12 +82,15 @@ class ServeEngine:
     (the fleet quantizes once and replicates, instead of per replica).
     telemetry: optional bus for ``compile`` events; the engine works (and
     still counts compiles) without one.
+    predict: the program, ``fn(params, batch dict, batch_stats) -> (counts,
+    masked density)``; None builds CANNet's (``programs.cannet_predict``).
     """
 
     def __init__(self, params, batch_stats=None, *, compute_dtype=None,
                  serve_dtype: str = "f32", ds: int = 8, device=None,
                  quantized: bool = False, telemetry=None,
-                 name: str = "serve_predict", aot_programs=None):
+                 name: str = "serve_predict", aot_programs=None,
+                 predict=None):
         self.ds = int(ds)
         self.serve_dtype = serve_dtype
         self.device = device
@@ -109,22 +112,10 @@ class ServeEngine:
         if compute_dtype is None:
             compute_dtype = compute_dtype_for(serve_dtype)
 
-        def predict(params, batch, batch_stats):
-            # int8 mode: in-program dequant (fused multiply; HBM holds
-            # int8) -> f32 weights -> f32 arithmetic ("f32 accumulation")
-            params = dequantize_tree(params, serve_dtype)
-            image = _batch_image(batch)  # u8 -> normalised f32, f32 passthru
-            if batch_stats is not None:
-                pred = cannet_apply(params, image,
-                                    compute_dtype=compute_dtype,
-                                    batch_stats=batch_stats, train=False)
-            else:
-                pred = cannet_apply(params, image,
-                                    compute_dtype=compute_dtype)
-            counts, _ = density_counts(pred, batch)
-            mask = (batch["pixel_mask"]
-                    * batch["sample_mask"][:, None, None, None])
-            return counts, pred.astype(jnp.float32) * mask
+        if predict is None:
+            from can_tpu.serve.programs import cannet_predict
+
+            predict = cannet_predict(serve_dtype, compute_dtype)
 
         # RecompileTracker attributes each new (shape, dtype) signature —
         # bucket warmup and any mid-traffic compile both land as `compile`
@@ -233,8 +224,6 @@ class ServeEngine:
         if (self._aot and (tuple(batch.image.shape),
                            str(batch.image.dtype)) in self._aot):
             return True
-        from can_tpu.train.steps import batch_signature
-
         return batch_signature(_batch_dict(batch)) in self._signatures
 
     @property
@@ -339,3 +328,222 @@ class ServeEngine:
                   "seconds": round(dt_s, 3)}
         self.telemetry.emit("serve.warmup", **report)
         return report
+
+
+# -- the language model's engine ------------------------------------------
+def lm_probe_steps(steps: int) -> Tuple[int, ...]:
+    """The decode steps whose logits a launch keeps for a request that
+    asked for them: the first, the middle one and the last."""
+    return tuple(sorted({1, max(1, steps // 2), steps}))
+
+
+class LMEngine:
+    """Executes token launches on one device: one flush is a prefill (in
+    slices of the batch, so that its temporaries fit beside the weights)
+    and then greedy decode steps over the same slots, dispatched one by one
+    (a later change can let slots join and leave between steps).  The cache
+    and the step's state are donated to every program and never leave the
+    device; the answers are fetched once, at the end.  Whether a request
+    asked for its logits changes that fetch only, never a program.
+
+    programs: ``serve.programs.LMPrograms``.  prefill_slice: how many of a
+    launch's prompts one prefill program takes.
+    """
+
+    ds = 1  # a token batch has no density grid (CountService reads it)
+
+    def __init__(self, params, programs, *, prefill_slice: int = 8,
+                 device=None, telemetry=None, name: str = "lm"):
+        self.programs = programs
+        self.prefill_slice = int(prefill_slice)
+        self.device = device
+        self.name = name
+        self.released = False
+        self.params = (jax.device_put(params) if device is None
+                       else jax.device_put(params, device))
+        self.telemetry = telemetry if telemetry is not None else Telemetry()
+        tel = self.telemetry
+        self._prefill = RecompileTracker(
+            jax.jit(programs.prefill_slice, donate_argnums=(2,)), tel,
+            name=f"{name}_prefill", signature_of=_with_cache_signature)
+        self._decode = RecompileTracker(
+            jax.jit(programs.decode, donate_argnums=(1, 2)), tel,
+            name=f"{name}_decode", signature_of=_with_cache_signature)
+        self._new_cache = jax.jit(programs.new_cache, static_argnums=(0, 1))
+        self._new_state = jax.jit(programs.new_state)
+        self._signatures = (tel.signature_registry[f"{name}_prefill"],
+                            tel.signature_registry[f"{name}_decode"])
+        self._warm: set = set()   # (slots, bucket) launched to the end once
+        self._last_compiled = False
+        # counters (the batcher thread writes, stats() reads a copy)
+        self.counters = {"launches": 0, "generated_tokens": 0,
+                         "prompt_tokens": 0, "decode_steps": 0,
+                         # assignments_held: tokens over all held experts
+                         "expert_tokens_max": 0,
+                         "assignments_held": 0, "assignments_all": 0,
+                         "cache_bytes": {}}
+        self.last_launch: dict = {}
+
+    @property
+    def compile_count(self) -> int:
+        """Distinct prefill and decode signatures compiled so far."""
+        return sum(len(s) for s in self._signatures)
+
+    @property
+    def last_batch_compiled(self) -> bool:
+        return self._last_compiled
+
+    def is_warm(self, batch) -> bool:
+        return tuple(batch.tokens.shape) in self._warm
+
+    def release_buffers(self) -> None:
+        self.params = None
+        self.released = True
+        import gc
+
+        gc.collect()
+
+    def _slices(self, slots: int):
+        s = min(self.prefill_slice, slots)
+        return [(lo, min(s, slots - lo)) for lo in range(0, slots, s)]
+
+    def generate_batch(self, batch, *, steps: Optional[int] = None,
+                       want_logits: bool = False):
+        """Run one padded launch (``serve.kinds.TokenBatch``) to the end:
+        -> (ids (slots, steps) int32, probes or None).  ``ids[:, 0]`` is
+        prefill's token, ``ids[:, s]`` decode step ``s``'s; all ``steps``
+        decode steps run (the last writes the last token's cache row and
+        yields the last probe).  ``probes``: {"prefill" | "step<s>":
+        {"logits" (slots, V) float32, "choices" (expert layers, slots, k)}}
+        for ``lm_probe_steps(steps)``."""
+        if self.released:
+            raise RuntimeError(f"engine {self.name}: buffers released")
+        steps = self.programs.max_new_tokens if steps is None else int(steps)
+        if not 1 <= steps <= self.programs.max_new_tokens:
+            raise ValueError(f"steps {steps} outside 1..{self.programs.max_new_tokens}")
+        slots, bucket = batch.tokens.shape
+        tr = active(self.telemetry)
+        span = tr.span if tr is not None else _NoSpan
+        live = batch.sample_mask > 0
+        valid = int(live.sum())
+        valid_tokens = int(batch.lengths[live].sum())
+        slices = self._slices(slots)
+        compiled = False
+        # the engine's two phases under ``serve.batch`` carry the names
+        # they have for every model (``ServeEngine.predict_batch``):
+        # ``serve.dispatch`` from the first program's call to the last
+        # one's return, ``serve.fetch`` the wait for the answers and their
+        # copy back; the model's own spans nest in the first
+        with span("serve.dispatch") as launch:
+            with span("lm.prefill", bucket=bucket, slots=slots, valid=valid,
+                      tokens=slots * bucket, valid_tokens=valid_tokens,
+                      slices=len(slices)) as sp:
+                cache = self._new_cache(slots, bucket)
+                outs = []
+                for lo, n in slices:
+                    part = {"tokens": batch.tokens[lo:lo + n],
+                            "lengths": batch.lengths[lo:lo + n],
+                            "active": live[lo:lo + n]}
+                    out, cache = self._prefill(self.params, part, cache,
+                                               np.int32(lo))
+                    compiled |= self._prefill.last_first_call
+                    outs.append(out)
+                state, pre = self._new_state(outs, batch.lengths, live)
+                sp.attrs["compiled"] = compiled
+            probes = {"prefill": {"logits": pre["logits"],
+                                  "choices": pre["choices"]}}
+            keep = set(lm_probe_steps(steps))
+            with span("lm.decode", steps=steps, slots=slots) as sp:
+                for step in range(1, steps + 1):
+                    with span("lm.decode.dispatch", decode_step=step):
+                        state, cache, out = self._decode(self.params, state,
+                                                         cache)
+                    compiled |= self._decode.last_first_call
+                    if step in keep:
+                        probes[f"step{step}"] = out
+                sp.attrs["compiled"] = compiled
+            launch.attrs["compiled"] = compiled
+        self._last_compiled = compiled
+        with span("serve.fetch", logits=bool(want_logits)):
+            # can-tpu-lint: disable=HOSTSYNC(the fetch IS the product: the generated ids resolve the waiting requests)
+            ids = np.asarray(state["ids"])[:, :steps]
+            # can-tpu-lint: disable=HOSTSYNC(the launch's routing counters, reduced on the device, fetched with the answers)
+            pre_counts, dec_counts = np.asarray(pre["counts"]), np.asarray(state["counts"])
+            fetched = None
+            if want_logits:
+                # can-tpu-lint: disable=HOSTSYNC(fetched only when a request asked for its logits)
+                fetched = jax.tree.map(np.asarray, probes)
+        self._warm.add((slots, bucket))
+        self._count(cache, valid, valid_tokens, steps, pre_counts, dec_counts)
+        return ids, fetched
+
+    def _count(self, cache, valid, valid_tokens, steps, pre_counts,
+               dec_counts) -> None:
+        p = self.programs
+        k = p.cfg.num_experts_per_tok
+        held = int(pre_counts.sum() + dec_counts.sum())
+        every = (valid_tokens + valid * steps) * k * p.expert_layers
+        launch = {"valid": valid, "steps": steps,
+                  "prefill_expert_tokens": pre_counts.tolist(),
+                  "decode_expert_tokens": dec_counts.tolist(),
+                  "assignments_held": held, "assignments_all": every}
+        c = self.counters
+        c["launches"] += 1
+        c["generated_tokens"] += valid * steps
+        c["prompt_tokens"] += valid_tokens
+        c["decode_steps"] += steps
+        c["expert_tokens_max"] = max(c["expert_tokens_max"],
+                                     int((pre_counts + dec_counts).max(initial=0)))
+        c["assignments_held"] += held
+        c["assignments_all"] += every
+        c["cache_bytes"] = kv_cache.nbytes_by_kind(cache, p.layer_kinds)
+        self.last_launch = launch
+
+    def warmup(self, buckets, max_batch: int, *, sizes=None) -> dict:
+        """Compile every (bucket, menu size) launch before traffic: one
+        launch of one decode step each, through ``generate_batch`` itself,
+        so that every program and every small op of the path is built."""
+        from can_tpu.sched import normalize_sizes
+        from can_tpu.serve.kinds import TokenBatch
+
+        t0 = time.perf_counter()
+        before = self.compile_count
+        buckets = sorted(set(int(b) for b in buckets))
+        sizes = normalize_sizes(max_batch, sizes)
+        counters = dict(self.counters)
+        for bucket in buckets:
+            for size in sizes:
+                batch = TokenBatch(np.zeros((size, bucket), np.int32),
+                                   np.ones((size,), np.int32),
+                                   np.zeros((size,), np.float32))
+                self.generate_batch(batch, steps=1, want_logits=True)
+        self.counters = counters   # warm-up launches are not traffic
+        report = {"shapes": len(buckets), "sizes": len(sizes),
+                  "compiles": self.compile_count - before,
+                  "seconds": round(time.perf_counter() - t0, 3)}
+        self.telemetry.emit("serve.warmup", **report)
+        return report
+
+
+def _with_cache_signature(args) -> tuple:
+    """(params, dict of arrays, cache, ...) -> the signature of the dict
+    AND of the cache: the launch size and the bucket both choose the
+    program."""
+    flat = dict(args[1])
+    for i, entry in enumerate(args[2]["layers"]):
+        flat[f"cache{i}"] = entry["k"]
+    return batch_signature(flat)
+
+
+class _NoSpan:
+    """``tracer.span(...)`` with tracing off: takes the attributes, keeps
+    nothing."""
+
+    def __init__(self, name, **attrs):
+        self.attrs = {}
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False

@@ -1,0 +1,192 @@
+"""The model seam of the serving path: the programs an engine runs.
+
+An engine (``serve/engine.py``) owns device-resident parameters, compile
+counting, warm-up and the fetch; WHAT it runs it is given.  This module
+builds those programs from the networks:
+
+* ``cannet_predict``: CANNet's one program, a padded image batch ->
+  (counts, masked density): the offline eval step's math;
+* ``LMPrograms``: the language model's two, over one launch's cache:
+  ``prefill_slice`` (some of the launch's prompts -> their first generated
+  token, their cache rows written at their slots) and ``decode`` (one greedy
+  token for every slot, the cache and the step's state donated).
+
+``MODEL_TYPES`` is the table ``serve.build_model_service`` reads: for each
+``model_type`` a configuration file may name, what is the model's own on
+the serving path.  This module is the only one under ``serve/`` that
+imports a network.
+"""
+
+from __future__ import annotations
+
+from typing import Callable, NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from can_tpu.serve import cache as kv_cache
+
+
+def cannet_predict(serve_dtype: str, compute_dtype):
+    """``predict(params, batch, batch_stats) -> (counts, masked density)``."""
+    from can_tpu.models import cannet_apply
+    from can_tpu.serve.quant import dequantize_tree
+    from can_tpu.train.loss import density_counts
+    from can_tpu.train.steps import _batch_image
+
+    def predict(params, batch, batch_stats):
+        # int8 mode: in-program dequant (fused multiply; HBM holds
+        # int8) -> f32 weights -> f32 arithmetic ("f32 accumulation")
+        params = dequantize_tree(params, serve_dtype)
+        image = _batch_image(batch)  # u8 -> normalised f32, f32 passthru
+        if batch_stats is not None:
+            pred = cannet_apply(params, image,
+                                compute_dtype=compute_dtype,
+                                batch_stats=batch_stats, train=False)
+        else:
+            pred = cannet_apply(params, image,
+                                compute_dtype=compute_dtype)
+        counts, _ = density_counts(pred, batch)
+        # the counts are reduced BEFORE the density is masked, as a program
+        # of their own would reduce them: fused into one loop with the
+        # masked density XLA:CPU sums in another order, and a served count
+        # then differs from evaluate()'s in the last bit
+        pred, counts = jax.lax.optimization_barrier((pred, counts))
+        mask = (batch["pixel_mask"]
+                * batch["sample_mask"][:, None, None, None])
+        return counts, pred.astype(jnp.float32) * mask
+
+    return predict
+
+
+class LMPrograms:
+    """``models/exaone_moe.py``'s pure functions as serving programs.
+
+    State between the programs of a launch, all on the device: the cache
+    (``serve/cache.py``) and ``state``: ``tokens`` (slots,) the token each
+    slot feeds next, ``positions`` (slots,) where it stands, ``active``
+    (slots,) which slots hold a request, ``ids`` (slots, max_new_tokens + 1)
+    what each slot has generated (column 0 from prefill, column s from
+    decode step s), ``step`` the next decode step (from 1), ``counts``
+    (expert layers, held) decode assignments that landed on each held
+    expert.  Greedy: the next token is the argmax over the vocabulary slice.
+    """
+
+    def __init__(self, cfg, *, max_new_tokens: int, dtype=jnp.bfloat16):
+        from can_tpu.models import exaone_moe
+
+        self._m = exaone_moe
+        self.cfg = cfg
+        self.max_new_tokens = int(max_new_tokens)
+        self.dtype = dtype   # of the cache: the parameters' own
+        self.layer_kinds = tuple(
+            kv_cache.RING if t == exaone_moe.WINDOW else kv_cache.FULL
+            for t in cfg.layer_types)
+        self.expert_layers = sum(t != "dense" for t in cfg.mlp_layer_types)
+        self.vocab_size = cfg.vocab.held   # ids and logits are over the slice
+
+    def new_cache(self, slots: int, bucket: int):
+        cfg = self.cfg
+        return kv_cache.allocate(
+            self.layer_kinds, slots=slots, kv_heads=cfg.num_key_value_heads,
+            head_dim=cfg.head_dim, positions=bucket + self.max_new_tokens,
+            window=cfg.sliding_window, dtype=self.dtype)
+
+    def new_state(self, outs, lengths, active):
+        """The slices' outputs put together and the decode state after
+        prefill: -> (state, {"logits" (slots, V), "choices", "counts"} of
+        the whole launch's prefill)."""
+        cat = lambda name, axis=0: jnp.concatenate([o[name] for o in outs], axis)
+        first = cat("first")
+        pre = {"logits": cat("logits"), "choices": cat("choices", 1),
+               "counts": sum(o["counts"] for o in outs)}
+        ids = jnp.zeros((first.shape[0], self.max_new_tokens + 1), jnp.int32)
+        state = {"tokens": first, "positions": lengths.astype(jnp.int32),
+                 "active": active, "ids": ids.at[:, 0].set(first),
+                 "step": jnp.ones((), jnp.int32),
+                 "counts": jnp.zeros((self.expert_layers, self.cfg.share.held),
+                                     jnp.int32)}
+        return state, pre
+
+    def prefill_slice(self, params, batch, cache, start):
+        """``batch``: {"tokens" (s, L), "lengths" (s,), "active" (s,)} for
+        slots ``start .. start + s - 1`` -> ({"first", "logits", "choices",
+        "counts"}, the cache with those slots' rows written)."""
+        cache_len = max(e["k"].shape[2] for e in cache["layers"])
+        logits, part, routing = self._m.prefill(
+            params, batch["tokens"], batch["lengths"], self.cfg, cache_len,
+            active=batch["active"])
+        cache = jax.tree.map(
+            lambda c, p: jax.lax.dynamic_update_slice_in_dim(
+                c, p.astype(c.dtype), start, axis=0), cache, part)
+        out = {"first": jnp.argmax(logits, -1).astype(jnp.int32),
+               "logits": logits, "choices": routing["choices"],
+               "counts": routing["counts"]}
+        return out, cache
+
+    def decode(self, params, state, cache):
+        """One greedy step for every slot -> (state, cache, {"logits",
+        "choices"} of this step)."""
+        logits, cache, routing = self._m.decode_step(
+            params, cache, state["tokens"], state["positions"], self.cfg,
+            active=state["active"])
+        nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+        ids = jax.lax.dynamic_update_slice(
+            state["ids"], nxt[:, None], (jnp.zeros((), jnp.int32),
+                                         state["step"]))
+        state = {"tokens": nxt, "positions": state["positions"] + 1,
+                 "active": state["active"], "ids": ids,
+                 "step": state["step"] + 1,
+                 "counts": state["counts"] + routing["counts"]}
+        return state, cache, {"logits": logits, "choices": routing["choices"]}
+
+
+# -- the table of served models -------------------------------------------
+class ServingModel(NamedTuple):
+    """What the serving path has to be told about one ``model_type``."""
+
+    # (config dict, params or None, seed) -> (programs, params): the
+    # model's config read from the file, its programs, and its parameters
+    # (made from the seed where none were given)
+    programs: Callable
+    # (params, programs, config dict, telemetry) -> the engine that runs them
+    engine: Callable
+    # (engine, config dict, **queue and batcher arguments) -> the service
+    service: Callable
+
+
+def _exaone_moe_programs(config: dict, params, seed: int):
+    from can_tpu.models import exaone_moe
+
+    cfg = exaone_moe.ExaoneMoeConfig.from_dict(config)
+    if params is None:
+        params = exaone_moe.init_params(jax.random.key(seed), cfg)
+    return LMPrograms(cfg, max_new_tokens=int(config["max_new_tokens"]),
+                      dtype=params["embed"].dtype), params
+
+
+def _lm_engine(params, programs, config: dict, telemetry):
+    from can_tpu.serve.engine import LMEngine
+
+    return LMEngine(params, programs, telemetry=telemetry,
+                    prefill_slice=int(config["prefill_slice"]))
+
+
+def _generate_service(engine, config: dict, **kw):
+    from can_tpu.serve.service import GenerateService
+
+    return GenerateService(engine, length_ladder=config["length_ladder"], **kw)
+
+
+MODEL_TYPES = {
+    "exaone_moe": ServingModel(_exaone_moe_programs, _lm_engine,
+                               _generate_service),
+}
+
+
+def serving_model(model_type) -> ServingModel:
+    if model_type not in MODEL_TYPES:
+        raise ValueError(f"no serving programs for model_type {model_type!r} "
+                         f"(served from a configuration file: "
+                         f"{sorted(MODEL_TYPES)})")
+    return MODEL_TYPES[model_type]

@@ -34,6 +34,8 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from can_tpu.serve.kinds import IMAGE, TOKENS
+
 # the typed rejection reasons; payload["reason"] of serve.reject events
 REJECT_QUEUE_FULL = "queue_full"      # hard capacity bound hit
 REJECT_BACKPRESSURE = "backpressure"  # load shedding above high_water
@@ -87,8 +89,12 @@ class ServeRequest:
     ``image``: HWC numpy, float32 (host-normalised) or uint8 (device
     normalisation, exactly the offline pipeline's two transfer modes); H, W
     already snapped to the density grid (see ``service.prepare_image``).
+
+    ``kind`` names what the request carries (``serve/kinds.py``): the
+    batcher asks that kind for the request's group and for the assembly.
     """
 
+    kind = IMAGE
     _ids = itertools.count()
 
     def __init__(self, image: np.ndarray, *, deadline_s: Optional[float],
@@ -167,6 +173,43 @@ class ServeRequest:
         if self._reject is not None:
             raise self._reject
         return self._result
+
+
+@dataclasses.dataclass
+class GenerateResult:
+    """One completed generation."""
+
+    tokens: np.ndarray                   # (max_new_tokens,) generated ids
+    # float32 logits by probe (``LM_PROBES`` names; serve/engine.py), for a
+    # request that asked for them
+    logits: Optional[dict]
+    routing: Optional[dict]              # the experts chosen at each probe
+    bucket_hw: Tuple[int, int]           # (1, L_bucket) the launch ran at
+    batch_fill: float
+    latency_s: float
+    queue_wait_s: Optional[float] = None
+    device_s: Optional[float] = None
+    trace_id: Optional[str] = None
+
+
+class TokenRequest(ServeRequest):
+    """A prompt of token ids and how many tokens to generate after it.
+    Queued, grouped and resolved as any request; its ``shape`` is
+    ``(1, n)``, so that a prompt buckets as a one-row image of n columns."""
+
+    kind = TOKENS
+
+    def __init__(self, tokens: np.ndarray, *, max_new_tokens: int,
+                 deadline_s: Optional[float], want_logits: bool = False,
+                 clock=time.monotonic):
+        tokens = np.asarray(tokens, np.int32)
+        if tokens.ndim != 1 or tokens.size < 1:
+            raise ValueError(f"a prompt is a 1-D array of at least one "
+                             f"token id, got shape {tokens.shape}")
+        super().__init__(tokens[None, :], deadline_s=deadline_s, clock=clock)
+        self.tokens = tokens
+        self.max_new_tokens = int(max_new_tokens)
+        self.want_logits = bool(want_logits)
 
 
 class BoundedRequestQueue:

@@ -53,9 +53,11 @@ from can_tpu.serve.queue import (
     REJECT_STALE_FRAME,
     REJECT_STREAM_OVERLOAD,
     BoundedRequestQueue,
+    GenerateResult,
     RejectedError,
     ServeRequest,
     ServeResult,
+    TokenRequest,
 )
 from can_tpu.serve.streams import StreamSessionRegistry
 from can_tpu.utils.profiling import StepTimer
@@ -135,11 +137,11 @@ class CountService:
                  telemetry=None, clock=time.monotonic,
                  perf_summary_every: int = 32,
                  menu_budget: Optional[int] = None,
-                 flush_policy: str = "priced",
+                 flush_policy: Optional[str] = None,
                  stream_ttl_s: float = 300.0,
                  degrade_policy: str = "priced",
-                 max_body_mb: float = 64.0):
-        if flush_policy not in ("priced", "timer"):
+                 max_body_mb: float = 64.0, kinds=None):
+        if flush_policy not in (None, "priced", "timer"):
             raise ValueError(f"unknown flush_policy {flush_policy!r} "
                              f"(priced | timer)")
         self.engine = engine
@@ -147,14 +149,23 @@ class CountService:
         # priced flush deadlines.  menu_budget=1 keeps the single
         # max_batch-slot program; menu_budget=1 AND flush_policy="timer"
         # is the bit-compatible pre-r14 service (sched=None entirely).
-        from can_tpu.sched import DEFAULT_MENU_BUDGET, ServeSched
+        # Left to the service (None), both follow from the request kinds
+        # it serves: the core where it can price every one of them, else
+        # one launch size under the timer.  Asked for a kind it cannot
+        # price, the core refuses (ValueError).
+        from can_tpu.sched import COST_UNIT, DEFAULT_MENU_BUDGET, ServeSched
+        from can_tpu.serve.kinds import ImageKind
 
-        budget = DEFAULT_MENU_BUDGET if menu_budget is None \
-            else int(menu_budget)
+        served = tuple(kinds.values()) if kinds else (ImageKind,)
+        priced = all(k.cost_unit == COST_UNIT for k in served)
+        if flush_policy is None:
+            flush_policy = "priced" if priced else "timer"
+        budget = ((DEFAULT_MENU_BUDGET if priced else 1)
+                  if menu_budget is None else int(menu_budget))
         if budget == 1 and flush_policy == "timer":
             self.sched = None
         else:
-            self.sched = ServeSched(int(max_batch),
+            self.sched = ServeSched(int(max_batch), kinds=served,
                                     max_wait_s=float(max_wait_ms) / 1e3,
                                     menu_budget=budget,
                                     priced_flush=flush_policy == "priced")
@@ -189,7 +200,10 @@ class CountService:
                                     # in process, _dispatch returns with
                                     # the answers fetched and the requests
                                     # resolved; the fleet's only enqueues
-                                    batch_free_on_return=self._fleet is None)
+                                    batch_free_on_return=self._fleet is None,
+                                    # further request kinds by name
+                                    # (serve/kinds.py); images always
+                                    kinds=kinds)
         # request latency reservoir: p50/p95/max over recent requests,
         # tagged by bucket shape (skip_first=0 — warmup() already keeps
         # compiles off the request path, so every sample is steady-state).
@@ -341,11 +355,7 @@ class CountService:
         # that is what stitches one trace ACROSS hosts: every hop's
         # spans key on the same id, and the fleet collector's snapshot
         # exports them as one skew-corrected timeline
-        req.trace_id = trace_id or f"{self._trace_prefix}-{req.id}"
-        if active(self.telemetry) is not None:
-            # the spans' clock, not the service's injectable one (that
-            # one stays for deadlines and fake-clock tests)
-            req.t_trace = time.perf_counter()
+        self._stamp(req, trace_id)
         if req.shape[0] % self.engine.ds or req.shape[1] % self.engine.ds:
             raise ValueError(
                 f"image shape {req.shape} is not snapped to the /"
@@ -359,18 +369,38 @@ class CountService:
                 f"image {req.shape[0]}x{req.shape[1]} exceeds the largest "
                 f"bucket {bucket[0]}x{bucket[1]} — resize it or serve with "
                 f"a bigger bucket ladder")
+        if not self._count_in(req):
+            return ServeTicket(req, self)
+        if stream_id is None:
+            return self._offer(req)
+        return self._submit_stream(req, bucket)
+
+    def _stamp(self, req: ServeRequest, trace_id: Optional[str]) -> None:
+        """The request's trace id (the caller's, else minted) and, with a
+        tracer active, its submit stamp on the spans' clock (not the
+        service's injectable one: that stays for deadlines and fake-clock
+        tests)."""
+        req.trace_id = trace_id or f"{self._trace_prefix}-{req.id}"
+        if active(self.telemetry) is not None:
+            req.t_trace = time.perf_counter()
+
+    def _count_in(self, req: ServeRequest) -> bool:
+        """Count the submission; False (the request rejected) when the
+        service has closed."""
         with self._lock:
             self._stats["submitted"] += 1
         if self._closed:
             req.reject(REJECT_SHUTDOWN, "service closed")
             self._count_reject(REJECT_SHUTDOWN)
-            return ServeTicket(req, self)
-        if stream_id is None:
-            reason = self.queue.offer(req)
-            if reason is not None:
-                self._count_reject(reason)
-            return ServeTicket(req, self)
-        return self._submit_stream(req, bucket)
+            return False
+        return True
+
+    def _offer(self, req: ServeRequest) -> ServeTicket:
+        """The stateless admission: the queue admits or rejects."""
+        reason = self.queue.offer(req)
+        if reason is not None:
+            self._count_reject(reason)
+        return ServeTicket(req, self)
 
     def _submit_stream(self, req: ServeRequest,
                        bucket) -> ServeTicket:
@@ -571,13 +601,10 @@ class CountService:
             # place the execute window by subtracting it on the service
             # clock (exact for the default monotonic clock)
             t_exec0 = self._clock() - execute_s
-        fill = len(requests) / batch.image.shape[0]
+        slots = batch.sample_mask.shape[0]
+        fill = len(requests) / slots
         now = self._clock()
         for slot, req in enumerate(requests):
-            h, w = req.shape
-            dens = (np.asarray(density[slot, : h // self.engine.ds,
-                                       : w // self.engine.ds])
-                    if req.want_density else None)
             latency = now - req.t_submit
             # assembly stamps come from the batcher; a request dispatched
             # through a path that skipped them (flush_all on a hand-driven
@@ -585,6 +612,10 @@ class CountService:
             t_asm = req.t_assembly if req.t_assembly is not None else t_exec0
             t_ready = req.t_ready if req.t_ready is not None else t_exec0
             queue_wait = max(t_asm - req.t_submit, 0.0)
+            res = self._result_for(slot, req, counts, density, dict(
+                bucket_hw=tuple(bucket_hw), batch_fill=fill,
+                latency_s=latency, queue_wait_s=round(queue_wait, 6),
+                device_s=round(execute_s, 6), trace_id=req.trace_id))
             if req.stream_id is not None:
                 # fold the fresh count (and density, when fetched) into
                 # the stream's session BEFORE resolving: a degraded
@@ -593,16 +624,10 @@ class CountService:
                 # (first completion only; pins move via re-pin, not
                 # work stealing).
                 self.streams.note_completed(
-                    req.stream_id, float(counts[slot]), dens, bucket_hw,
+                    req.stream_id, res.count, res.density, bucket_hw,
                     now=now, replica=replica,
                     token=None if replica is None else program)
-            req.resolve(ServeResult(count=float(counts[slot]), density=dens,
-                                    bucket_hw=tuple(bucket_hw),
-                                    batch_fill=fill, latency_s=latency,
-                                    queue_wait_s=round(queue_wait, 6),
-                                    device_s=round(execute_s, 6),
-                                    trace_id=req.trace_id,
-                                    stream_id=req.stream_id))
+            req.resolve(res)
             with self._lock:
                 self.latency.record(latency, shape=tuple(bucket_hw))
             self.telemetry.emit("serve.request", request_id=req.id,
@@ -629,7 +654,7 @@ class CountService:
         with self._lock:
             self._stats["completed"] += len(requests)
             self._stats["batches"] += 1
-            self._stats["batch_slots"] += batch.image.shape[0]
+            self._stats["batch_slots"] += slots
             self._stats["batch_valid"] += len(requests)
             if replica is not None:
                 rs = self._replica_stats.setdefault(
@@ -645,7 +670,6 @@ class CountService:
         # bug the can_tpu_sched_* gauges must surface, not noise.  The
         # legacy no-core service predicts its own contract: every launch
         # pads to max_batch.
-        slots = batch.image.shape[0]
         # drain pricing for the stream degradation ladder: every
         # completed batch (stream or not) feeds the bucket's measured
         # seconds-per-slot, so the pricing is warm before the first
@@ -675,8 +699,9 @@ class CountService:
                 # compiles are the compile event's bill, same exclusion
                 # rule as the step reservoirs); fleet batches bill their
                 # replica's own program name
-                ledger.observe(program, tuple(batch.image.shape),
-                               execute_s, dtype=str(batch.image.dtype))
+                payload = self.batcher.kinds[requests[0].kind].payload(batch)
+                ledger.observe(program, tuple(payload.shape),
+                               execute_s, dtype=str(payload.dtype))
             # under _lock: fleet replica workers call _complete
             # concurrently, and an unlocked += here can lose counts or
             # double-emit the periodic summary (lint: LOCKHELD)
@@ -687,6 +712,16 @@ class CountService:
                     self._perf_batches = 0
             if due:
                 ledger.emit_summary(self.telemetry, phase="serve")
+
+    def _result_for(self, slot: int, req, counts, density, common: dict):
+        """What slot ``slot`` of a completed launch answers its request
+        with; ``common``: the fields every kind of result carries."""
+        h, w = req.shape
+        dens = (np.asarray(density[slot, : h // self.engine.ds,
+                                   : w // self.engine.ds])
+                if req.want_density else None)
+        return ServeResult(count=float(counts[slot]), density=dens,
+                           stream_id=req.stream_id, **common)
 
     def _note_reject(self, reason: str, count: int = 1) -> None:
         """Count a rejection that already emitted its own telemetry
@@ -735,6 +770,113 @@ class CountService:
         return self._fleet.rollout(params, batch_stats,
                                    run_config=run_config,
                                    allow_config_change=allow_config_change)
+
+
+class GenerateService(CountService):
+    """The same front door for a language model: prompts of token ids in,
+    generated ids out.  Queue, batcher, scheduler, spans, staging,
+    lifecycle, rejection and statistics are ``CountService``'s; what
+    differs is the request kind (``serve.kinds.TokenKind``: prompts bucket
+    on a length ladder), the engine's call (one flush is a prefill and then
+    decode steps, ``LMEngine.generate_batch``) and the result.  Static
+    batches: a slot that has finished waits for its launch."""
+
+    def __init__(self, engine, *, length_ladder, **kw):
+        from can_tpu.serve.kinds import TOKENS, TokenKind
+
+        self.token_kind = TokenKind(length_ladder)
+        super().__init__(engine, kinds={TOKENS: self.token_kind}, **kw)
+        if self._fleet is not None:
+            raise ValueError("GenerateService runs one engine in process")
+
+    def warmup(self, buckets=None) -> dict:
+        """Compile every (bucket, menu size) launch before traffic."""
+        return self.engine.warmup(
+            self.token_kind.ladder if buckets is None else buckets,
+            self.max_batch,
+            sizes=self.sched.menu if self.sched is not None else None)
+
+    def submit(self, tokens, *, max_new_tokens: Optional[int] = None,
+               want_logits: bool = False,
+               deadline_ms: Optional[float] = None,
+               trace_id: Optional[str] = None) -> ServeTicket:
+        """Enqueue one prompt; the ticket's ``result()`` is a
+        ``GenerateResult`` with ``max_new_tokens`` generated ids (and the
+        probe logits, if asked for).  A prompt past the length ladder or
+        ids outside the vocabulary held here are refused at the door."""
+        cap = self.engine.programs.max_new_tokens
+        n_new = cap if max_new_tokens is None else int(max_new_tokens)
+        if not 1 <= n_new <= cap:
+            raise ValueError(f"max_new_tokens {n_new} outside 1..{cap}")
+        deadline_s = (float(deadline_ms) / 1e3 if deadline_ms is not None
+                      else self.default_deadline_s)
+        req = TokenRequest(tokens, max_new_tokens=n_new,
+                           want_logits=want_logits, deadline_s=deadline_s,
+                           clock=self._clock)
+        self.token_kind.bucket_of(req.shape[1])  # ValueError past the ladder
+        held = self.engine.programs.vocab_size
+        if req.tokens.min() < 0 or req.tokens.max() >= held:
+            raise ValueError(f"token ids must lie in 0..{held - 1}, the "
+                             f"vocabulary slice held here")
+        self._stamp(req, trace_id)
+        if not self._count_in(req):
+            return ServeTicket(req, self)
+        return self._offer(req)
+
+    def generate(self, tokens, *, timeout: Optional[float] = None, **kw):
+        """submit + result in one call."""
+        return self.submit(tokens, **kw).result(timeout)
+
+    def stats(self) -> dict:
+        out = super().stats()
+        # the engine's counters (the batcher thread writes them between
+        # launches; a copy of plain numbers)
+        out["lm"] = dict(self.engine.counters)
+        return out
+
+    def _dispatch(self, bucket_hw, batch, requests) -> None:
+        t_exec0 = self._clock()
+        t0 = time.perf_counter()
+        ids, probes = self.engine.generate_batch(
+            batch, steps=max(r.max_new_tokens for r in requests),
+            want_logits=any(r.want_logits for r in requests))
+        execute_s = time.perf_counter() - t0
+        self._complete(bucket_hw, batch, requests, ids, probes, execute_s,
+                       self.engine.last_batch_compiled,
+                       program=self.engine.name, t_exec0=t_exec0)
+
+    def _result_for(self, slot: int, req, ids, probes, common: dict):
+        logits = routing = None
+        if req.want_logits:
+            logits = {k: v["logits"][slot] for k, v in probes.items()}
+            routing = {k: v["choices"][:, slot] for k, v in probes.items()}
+        return GenerateResult(tokens=ids[slot, :req.max_new_tokens].copy(),
+                              logits=logits, routing=routing, **common)
+
+
+def build_model_service(config: dict, *, params=None, seed: int = 0,
+                        telemetry=None, break_programs=None):
+    """Queue, batcher, engine and service for the model a configuration
+    file describes (``benchmark/configs/*.json``'s format): the ONE
+    construction ``can_tpu.cli.serve --model-config`` and the benchmark's
+    driver both use.  What is the model's own (its programs, the engine
+    that runs them, the service in front) comes from the table in
+    ``serve/programs.py`` under the file's ``model_type``; no network is
+    named here.  ``params``: the model's parameter tree; made from ``seed``
+    where there is no checkpoint.  ``break_programs``: tests and
+    calibration only, ``fn(programs)`` before the engine takes them."""
+    from can_tpu.serve.programs import serving_model
+
+    model = serving_model(config.get("model_type"))
+    programs, params = model.programs(config, params, seed)
+    if break_programs is not None:
+        break_programs(programs)
+    engine = model.engine(params, programs, config, telemetry)
+    capacity = int(config["queue_capacity"])
+    return model.service(
+        engine, config, max_batch=int(config["max_batch"]),
+        max_wait_ms=float(config["max_wait_ms"]), queue_capacity=capacity,
+        high_water=max(1, (3 * capacity) // 4), telemetry=telemetry)
 
 
 # -- HTTP front end -----------------------------------------------------
@@ -874,13 +1016,48 @@ def make_http_handler(service: CountService):
                 return
             self._send(200, report)
 
+        def _do_generate(self):
+            """POST /generate {"tokens": [...], "max_new_tokens": n}
+            -> {"tokens": [...], "latency_ms"} (a GenerateService only)."""
+            n = self._body_capped()
+            if n is None:
+                return
+            if not hasattr(service, "generate"):
+                self._send(501, {"error": "this server serves images "
+                                          "(POST /predict)"})
+                return
+            try:
+                spec = json.loads(self.rfile.read(n) or b"{}")
+                res = service.generate(
+                    np.asarray(spec["tokens"], np.int32),
+                    max_new_tokens=spec.get("max_new_tokens"),
+                    deadline_ms=spec.get("deadline_ms"))
+            except (KeyError, TypeError, ValueError) as e:
+                self._send(400, {"error": f"bad request: {e}"})
+                return
+            except RejectedError as e:
+                self._send(status_of.get(e.reason, 503),
+                           {"error": str(e), "reason": e.reason})
+                return
+            self._send(200, {"tokens": res.tokens.tolist(),
+                             "latency_ms": round(res.latency_s * 1e3, 3),
+                             "bucket": list(res.bucket_hw),
+                             "batch_fill": res.batch_fill})
+
         def do_POST(self):
             url = urlparse(self.path)
             if url.path == "/rollout":
                 self._do_rollout()
                 return
+            if url.path == "/generate":
+                self._do_generate()
+                return
             if url.path != "/predict":
                 self._send(404, {"error": f"no such path: {url.path}"})
+                return
+            if hasattr(service, "generate"):
+                self._send(501, {"error": "this server serves prompts of "
+                                          "token ids (POST /generate)"})
                 return
             n = self._body_capped()
             if n is None:

@@ -169,3 +169,67 @@ def test_dp2_sp2_train_step_compiles_on_the_2x2_mesh(v5e):
     assert "collective-permute" in text  # the halo exchange
     assert "all-reduce" in text          # gradient / pooling psums
     _fits_hbm(compiled)
+
+
+# -- the language model's serving programs at the published widths --------
+def _lm_programs_and_shapes(v5e, slots, part):
+    """``serve/programs.py::LMPrograms`` of the benchmark's configuration,
+    its parameters, one launch's cache of ``slots`` and a prefill slice of
+    ``part`` prompts, as shapes on one described device."""
+    import json
+
+    from can_tpu.models import exaone_moe as em
+    from can_tpu.serve.programs import LMPrograms
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "k-exaone-ep8-serve-bf16.json")) as f:
+        config = json.load(f)
+    cfg = em.ExaoneMoeConfig.from_dict(config)
+    programs = LMPrograms(cfg, max_new_tokens=int(config["max_new_tokens"]))
+    one = SingleDeviceSharding(v5e[0])
+    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
+    flat, treedef = jax.tree_util.tree_flatten_with_path(
+        em.param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
+    params = jax.tree_util.tree_unflatten(treedef, [
+        shape(s, jnp.float32 if path[-1].key == "bias" else jnp.bfloat16)
+        for path, s in flat])
+    bucket = int(config["length_ladder"][-1])
+    cache = jax.tree.map(lambda a: shape(a.shape, a.dtype),
+                         jax.eval_shape(lambda: programs.new_cache(slots, bucket)))
+    batch = {"tokens": shape((part, bucket), jnp.int32),
+             "lengths": shape((part,), jnp.int32),
+             "active": shape((part,), jnp.bool_)}
+    return programs, params, cache, batch, shape
+
+
+def test_lm_decode_step_compiles_for_one_device(v5e):
+    """One greedy step of 64 slots at K-EXAONE's published widths (16 of 128
+    experts held): the few-token form of the grouped product (every held
+    expert on every token, no grouped kernel), the cache updated in place
+    (donated), and weights + cache + temporaries fit."""
+    programs, params, cache, _, shape = _lm_programs_and_shapes(v5e, 64, 8)
+    state = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(lambda: programs.new_state(
+            [{"first": jnp.zeros((64,), jnp.int32),
+              "logits": jnp.zeros((64, 8), jnp.float32),
+              "choices": jnp.zeros((4, 64, 8), jnp.int32),
+              "counts": jnp.zeros((4, 16), jnp.int32)}],
+            jnp.ones((64,), jnp.int32), jnp.ones((64,), bool))[0]))
+    compiled = jax.jit(programs.decode, donate_argnums=(1, 2)).lower(
+        params, state, cache).compile()
+    assert "ragged-dot" not in compiled.as_text()
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 64 * 8 * (1280 + 4 * 128) * 128 * 2 * 2
+    assert _fits_hbm(compiled) > 7 * 2**30   # the weights alone are 7.4 GB
+
+
+def test_lm_prefill_slice_compiles_for_one_device(v5e):
+    """8 prompts of 1,024 tokens into a 64-slot cache: the sorted buffer's
+    worst case (8 x tokens rows) fits beside the weights."""
+    programs, params, cache, batch, shape = _lm_programs_and_shapes(v5e, 64, 8)
+    compiled = jax.jit(programs.prefill_slice, donate_argnums=(2,)).lower(
+        params, batch, cache, shape((), jnp.int32)).compile()
+    assert "ragged-dot" in compiled.as_text()
+    assert _fits_hbm(compiled) > 9 * 2**30
