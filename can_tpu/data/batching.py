@@ -155,10 +155,14 @@ class StagingBatch:
     across launches: ``pad_batch(out=...)`` assembles into a leading view
     of them and writes only what changed since the last launch.
 
-    Owned by whoever allocates it (``serve.MicroBatcher``: one per bucket
-    and image dtype).  A ``Batch`` assembled into it is a VIEW: it is good
-    until the next ``pad_batch`` into the same buffer, so the owner hands
-    the buffer on only once nothing reads the previous batch any more.
+    Owned by whoever allocates it (``serve.MicroBatcher``: per bucket and
+    image dtype a ring of as many as launches may be in flight).  A
+    ``Batch`` assembled into it is a VIEW: it is good until the next
+    ``pad_batch`` into the same buffer, so the owner assembles into a
+    buffer again only once nothing reads its previous batch any more (the
+    batcher: when the ``dispatch`` it handed that batch to has returned
+    or raised; until then the launch holds the buffer and the next one is
+    assembled into another of the ring).
     Nothing but ``pad_batch`` may write the arrays: ``extent`` is what
     lets it skip the zeroing."""
 

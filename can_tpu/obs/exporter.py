@@ -37,7 +37,7 @@ _PROM_CONTENT_TYPE = "text/plain; version=0.0.4; charset=utf-8"
 # the Prometheus ``_total`` suffix); the rest of the dict is gauges
 _SERVE_COUNTER_KEYS = frozenset(
     {"submitted", "completed", "rejected", "batches", "batch_slots",
-     "batch_valid", "compile_count", "failures"})
+     "batch_valid", "compile_count", "failures", "launches_overlapped"})
 
 
 def _fmt_value(v) -> str:

@@ -44,7 +44,9 @@ class RecompileTracker:
     signature — callers timing steps around this wrapper use it to keep
     compile wall time OUT of their steady-state step distribution (it is
     already fully accounted by the ``compile`` event; recording it twice
-    would let one 10 s compile masquerade as the step p95/max)."""
+    would let one 10 s compile masquerade as the step p95/max).  It is the
+    CALLING THREAD's last call: two serve launches in flight on two
+    threads each read their own."""
 
     def __init__(self, fn: Callable, telemetry, *, name: str = "step",
                  batch_arg: int = 1, signature_of: Optional[Callable] = None):
@@ -59,7 +61,11 @@ class RecompileTracker:
         self._signature = (signature_of if signature_of is not None else
                            lambda args: batch_signature(args[batch_arg]))
         self._seen = telemetry.signature_registry.setdefault(name, {})
-        self.last_first_call = False
+        self._call = threading.local()
+
+    @property
+    def last_first_call(self) -> bool:
+        return getattr(self._call, "first", False)
 
     def jit_for(self, *args):
         """The underlying jitted callable for these args — the same hook
@@ -73,9 +79,9 @@ class RecompileTracker:
     def __call__(self, *args):
         sig = self._signature(args)
         if sig in self._seen:
-            self.last_first_call = False
+            self._call.first = False
             return self._fn(*args)
-        self.last_first_call = True
+        self._call.first = True
         t0 = time.perf_counter()
         out = self._fn(*args)
         dt = time.perf_counter() - t0

@@ -31,6 +31,7 @@ and takes its unchanged code path on None.
 from __future__ import annotations
 
 import collections
+import contextlib
 import itertools
 import os
 import threading
@@ -81,7 +82,7 @@ class Span:
     may be filled while it is open (a count known only at the end)."""
 
     __slots__ = ("_tracer", "_annotation", "_outer", "name", "trace_id",
-                 "span_id", "parent_id", "start", "attrs")
+                 "span_id", "parent_id", "start", "thread", "attrs")
 
     def __init__(self, tracer, name, trace_id, parent_id, attrs):
         self._tracer = tracer
@@ -91,16 +92,22 @@ class Span:
         self.span_id = tracer.new_span_id()
         self.attrs = attrs
 
+    def _adopt(self, outer: Optional["Span"]) -> None:
+        """Parent and trace default to ``outer``'s, the span open on the
+        opening thread (a new trace when there is none)."""
+        if outer is not None:
+            if self.parent_id is None:
+                self.parent_id = outer.span_id
+            if self.trace_id is None:
+                self.trace_id = outer.trace_id
+        if self.trace_id is None:
+            self.trace_id = self._tracer.new_trace_id(self.name)
+        self.thread = threading.current_thread().name
+
     def __enter__(self) -> "Span":
         tr = self._tracer
         self._outer = getattr(tr._local, "span", None)
-        if self._outer is not None:
-            if self.parent_id is None:
-                self.parent_id = self._outer.span_id
-            if self.trace_id is None:
-                self.trace_id = self._outer.trace_id
-        if self.trace_id is None:
-            self.trace_id = tr.new_trace_id(self.name)
+        self._adopt(self._outer)
         tr._local.span = self
         self._annotation = tr._annotate(self.name)
         self._annotation.__enter__()
@@ -110,14 +117,42 @@ class Span:
     def __exit__(self, exc_type, exc, tb) -> bool:
         end = time.perf_counter()
         self._annotation.__exit__(exc_type, exc, tb)
-        tr = self._tracer
-        tr._local.span = self._outer
+        self._tracer._local.span = self._outer
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
-        tr.emit(trace_id=self.trace_id, name=self.name, start=self.start,
-                end=end, span_id=self.span_id, parent_id=self.parent_id,
-                thread=threading.current_thread().name, **self.attrs)
+        self._record(end)
         return False
+
+    def begin(self) -> "Span":
+        """Stamp the start of a span that may END ON ANOTHER THREAD
+        (``finish()`` there): a launch that the batcher thread assembles
+        and a lane runs.  It does not become this thread's open span, and
+        enters no profiler annotation (those cannot change threads);
+        whoever works on it makes it the open one with ``span.under()``.
+        It is recorded on the lane of the thread that began it."""
+        self._adopt(self._tracer.current())
+        self.start = time.perf_counter()
+        return self
+
+    @contextlib.contextmanager
+    def under(self):
+        """Make this begun span the calling thread's open one for the
+        block: spans opened inside are its children, on whichever thread."""
+        local, outer = self._tracer._local, self._tracer.current()
+        local.span = self
+        try:
+            yield self
+        finally:
+            local.span = outer
+
+    def finish(self) -> None:
+        self._record(time.perf_counter())
+
+    def _record(self, end: float) -> None:
+        self._tracer.emit(trace_id=self.trace_id, name=self.name,
+                          start=self.start, end=end, span_id=self.span_id,
+                          parent_id=self.parent_id, thread=self.thread,
+                          **self.attrs)
 
 
 class SpanTracer:

@@ -17,20 +17,21 @@ spans and the staging pool below are one code for every kind.
 
 Where ``dispatch`` is done with a batch when it returns (the in-process
 service: ``predict_batch`` has fetched the answers and the requests are
-resolved), the batcher assembles into staging buffers it OWNS: one
-``data.batching.StagingBatch`` per (bucket, dtype), sized for the top
-launch size at the key's first flush and kept until ``close()``; a
-smaller menu size is its leading view.  ``pad_batch(out=...)`` then
-copies the items in and zeroes only what the previous launch left
-stale, instead of mapping and zeroing the whole batch anew per launch
-(151 MB at b16 768x1024 f32: 9.2 ms per image on the v5e's host against
-1.0 for the copy alone; PERF.md, PR 25).  The batch handed to
-``dispatch`` is a view of that buffer, free again when ``dispatch``
-returns or raises.  Where ``dispatch`` only enqueues (the fleet: a
-``_WorkItem`` keeps the batch until a replica completes it, may be
-redispatched, and a wedged replica may still be reading it) every launch
-is assembled fresh, and the batch is its receiver's.  ``staging`` counts
-launches by which of the two it was.
+resolved), the batcher assembles into staging buffers it OWNS: a ring of
+``data.batching.StagingBatch`` per (bucket, dtype), as many as launches
+may be in flight (below), each sized for the top launch size when it is
+first needed and kept until ``close()``; a smaller menu size is its
+leading view.  ``pad_batch(out=...)`` then copies the items in and zeroes
+only what the buffer's previous launch left stale, instead of mapping and
+zeroing the whole batch anew per launch (151 MB at b16 768x1024 f32: 9.2
+ms per image on the v5e's host against 1.0 for the copy alone; PERF.md,
+PR 25).  The batch handed to ``dispatch`` is a view of its buffer, and the
+buffer goes back to the ring when that ``dispatch`` has returned or
+raised, never earlier: the runtime reads its bytes until then.  Where
+``dispatch`` only enqueues (the fleet: a ``_WorkItem`` keeps the batch
+until a replica completes it, may be redispatched, and a wedged replica
+may still be reading it) every launch is assembled fresh, and the batch is
+its receiver's.  ``staging`` counts launches by which of the two it was.
 
 Since round 14 the flush policy and launch sizes come from the shared
 scheduling core (``can_tpu/sched``) when a ``ServeSched`` is given:
@@ -66,22 +67,41 @@ launched: a result the client has already given up on still costs a full
 batch slot, and under overload those zombie slots are exactly the capacity
 the live requests need.
 
-Single consumer thread; dispatch runs ON that thread — the device executes
-serially anyway, and one thread means the pending-group state needs no
-locking beyond the queue's own, and that one staging buffer per key is
-enough: the thread assembles the next launch only after the previous
-``dispatch`` has returned.  A launch therefore blocks the pump for as
-long as it runs, and its view of the queue is stale when it returns:
-``intake`` sorts what arrived meanwhile into its groups before ``poll``
-judges them, so a group's rest is never launched alone while its
-companions sit in the queue.
+Single consumer thread: it alone reads the queue, owns the pending-group
+state (which therefore needs no locking beyond the queue's own), decides
+the flushes and assembles the launches.  WHO RUNS a launch depends on how
+many the owner's engine can hold in flight (``launches_in_flight``, which
+the service reads off the engine; nobody sets it):
+
+* 1 (a language model's engine, a hand-driven batcher, the fleet, whose
+  ``dispatch`` only enqueues): ``dispatch`` runs ON the batcher thread.
+  A launch blocks the pump for as long as it runs, and one staging buffer
+  per key is enough.
+* N > 1 (``ServeEngine``: 2), once ``start()`` has made the threads: the
+  batcher thread hands each assembled launch to one of N LAUNCH LANES and
+  returns to the queue; the lane runs ``dispatch`` (through the fetched
+  answers and the requests' resolution) and gives the buffer back.  While
+  lane A waits for program n, the batcher thread assembles batch n+1 into
+  the ring's other buffer and lane B dispatches it: its layout change and
+  H2D run while n computes, and its program is queued behind n (PERF.md,
+  PR 27).  With N launches in flight the batcher thread waits for one to
+  return before it assembles the next (``serve.launch_wait``): that is
+  the backpressure, and what bounds the buffers.  The flush decision is
+  the same in both: a group is judged by its size, its timer and its
+  arrival rate, not by what is in flight.
+
+Either way the thread's view of the queue is stale after a launch it had
+to wait for: ``intake`` sorts what arrived meanwhile into its groups
+before ``poll`` judges them, so a group's rest is never launched alone
+while its companions sit in the queue.
 """
 
 from __future__ import annotations
 
+import collections
 import threading
 import time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -112,6 +132,86 @@ class _Group:
         self.t_last = t0  # newest arrival (the wait-for-next anchor)
 
 
+class _Launch(NamedTuple):
+    """One assembled launch on its way to ``dispatch``: what whoever runs
+    it needs to run it, to give its staging buffer back and to close its
+    ``serve.batch`` span."""
+
+    key: GroupKey
+    batch: object
+    requests: List[ServeRequest]
+    staging: object            # the ring's buffer it views; None: fresh
+    span: object               # its begun serve.batch span; None: untraced
+
+
+class _LaunchLanes:
+    """N threads that run launches, and the count of launches in flight.
+
+    The batcher thread ``acquire()``s a slot BEFORE it assembles (it waits
+    while N launches are in flight), ``hand_over()``s the assembled launch,
+    and a lane ``run``s it and frees the slot.  ``run`` gives the launch's
+    staging buffer back before the slot is freed: whoever holds a slot
+    finds a buffer of its key free or not made yet."""
+
+    def __init__(self, depth: int, run: Callable[[_Launch], None]):
+        self.depth = int(depth)
+        self.lock = threading.Condition()
+        self._run = run
+        self._in_flight = 0
+        self._work: collections.deque = collections.deque()
+        self._stop = False
+        self._threads = [threading.Thread(target=self._lane, daemon=True,
+                                          name=f"can-tpu-serve-lane_{i}")
+                         for i in range(self.depth)]
+        for t in self._threads:
+            t.start()
+
+    def in_flight(self) -> int:
+        return self._in_flight
+
+    def acquire(self) -> int:
+        """Take a slot, waiting while all are taken; -> launches in flight
+        without this one."""
+        with self.lock:
+            while self._in_flight >= self.depth:
+                self.lock.wait()
+            self._in_flight += 1
+            return self._in_flight - 1
+
+    def release(self) -> None:
+        with self.lock:
+            self._in_flight -= 1
+            self.lock.notify_all()
+
+    def hand_over(self, launch: _Launch) -> None:
+        with self.lock:
+            self._work.append(launch)
+            self.lock.notify_all()
+
+    def _lane(self) -> None:
+        while True:
+            with self.lock:
+                while not self._work and not self._stop:
+                    self.lock.wait()
+                if not self._work:
+                    return
+                launch = self._work.popleft()
+            try:
+                self._run(launch)
+            finally:
+                self.release()
+
+    def close(self, timeout: float = 30.0) -> None:
+        """Wait for every launch in flight (no longer than ``timeout``: a
+        hung engine must not hang shutdown), then stop the threads."""
+        with self.lock:
+            self.lock.wait_for(lambda: not self._in_flight, timeout)
+            self._stop = True
+            self.lock.notify_all()
+        for t in self._threads:
+            t.join(timeout=timeout)
+
+
 class MicroBatcher:
     """Pulls from a ``BoundedRequestQueue``, emits padded ``Batch``es.
 
@@ -125,6 +225,12 @@ class MicroBatcher:
     or raised.  The batcher then assembles every launch into a staging
     buffer it reuses (module docstring); False (a dispatch that hands the
     batch on, or keeps it) assembles each launch fresh.
+
+    launches_in_flight: how many ``dispatch`` calls may be in progress at
+    once (what the owner's engine states; needs ``batch_free_on_return``).
+    Above 1, ``start()`` makes that many launch lanes and ``dispatch`` runs
+    on them (module docstring); it must then be safe to call from several
+    threads.  1: ``dispatch`` runs on the batcher thread.
 
     sched: optional ``can_tpu.sched.ServeSched`` — the shared scheduling
     core (priced sub-batch menu + priced flush deadlines).  None keeps
@@ -145,9 +251,15 @@ class MicroBatcher:
                  idle_wait_s: float = 0.05,
                  on_reject: Optional[Callable] = None,
                  sched=None, batch_free_on_return: bool = False,
-                 kinds=None):
+                 launches_in_flight: int = 1, kinds=None):
         if max_batch < 1:
             raise ValueError(f"max_batch must be >= 1, got {max_batch}")
+        if launches_in_flight < 1 or (launches_in_flight > 1
+                                      and not batch_free_on_return):
+            raise ValueError(
+                f"launches_in_flight {launches_in_flight}: at least 1, and "
+                f"more than 1 only for a dispatch that is done with its "
+                f"batch when it returns (batch_free_on_return)")
         if sched is not None and sched.max_batch != int(max_batch):
             raise ValueError(
                 f"sched menu tops out at {sched.max_batch}, batcher "
@@ -172,12 +284,23 @@ class MicroBatcher:
         # launched batches by flush reason (batcher thread writes; the
         # service's stats() copies)
         self.flush_reasons = {FLUSH_FULL: 0, FLUSH_DUE: 0, FLUSH_DRAIN: 0}
-        # the staging pool (None: every launch fresh), and launches by how
-        # they were assembled + the bytes the pool holds now (same
-        # writer, same reader as flush_reasons)
-        self._staging_pool: Optional[Dict[GroupKey, object]] = (
+        # the staging pool (None: every launch fresh): per key the ring's
+        # buffers, all of them and those no launch holds; and launches by
+        # how they were assembled + the bytes the pool holds now (same
+        # writer, same reader as flush_reasons).  A free list is appended
+        # to by whoever ran the launch and popped by the batcher thread
+        # alone (``list.append`` / ``pop`` are atomic under CPython)
+        self._staging_pool: Optional[Dict[GroupKey, List[object]]] = (
             {} if batch_free_on_return else None)
+        self._staging_free: Dict[GroupKey, List[object]] = {}
         self.staging = {"reused": 0, "fresh": 0, "bytes_held": 0}
+        # launch lanes: made by start() where dispatch may run on more than
+        # one thread; None runs every launch on the batcher thread
+        self.launches_in_flight = int(launches_in_flight)
+        self._lanes: Optional[_LaunchLanes] = None
+        # launches handed over while another was in flight (batcher thread
+        # writes; stats() copies)
+        self.launches_overlapped = 0
         # the trace the thread's own cycle (wait / intake / poll) is
         # recorded under; minted on the first traced cycle
         self._lane: Optional[str] = None
@@ -341,8 +464,8 @@ class MicroBatcher:
     def _flush(self, key: GroupKey, group: List[ServeRequest],
                reason: str) -> int:
         """Cover the group with menu-size launches (one launch padded to
-        ``max_batch`` without a core) and dispatch each.  Returns the
-        number of batches dispatched."""
+        ``max_batch`` without a core), assemble each and run it: here, or
+        on a launch lane.  Returns the number of batches dispatched."""
         if self.sched is None:
             # one padded launch per max_batch-full slice (legacy; a group
             # never exceeds max_batch in practice — intake flushes full)
@@ -357,70 +480,141 @@ class MicroBatcher:
             pos += size
             if not take:
                 break
-            self.flush_reasons[reason] += 1
             tr = active(self.telemetry)
-            if tr is None:
-                self._flush_part(key, take, size)
-            else:
+            lanes, in_flight = self._lanes, 0
+            if lanes is not None:
+                in_flight, take = self._launch_slot(lanes, take, tr)
+                if not take:
+                    lanes.release()
+                    continue
+            self.flush_reasons[reason] += 1
+            if in_flight:
+                self.launches_overlapped += 1
+            sp = None
+            if tr is not None:
                 # the root of the batch's own trace; on the thread's lane
-                # a child of the cycle span that launched it
-                with tr.span("serve.batch", trace_id=tr.new_trace_id("batch"),
+                # a child of the cycle span that launched it.  Begun here,
+                # finished by whoever runs the launch
+                sp = tr.span("serve.batch", trace_id=tr.new_trace_id("batch"),
                              bucket=[key[0], key[1]], slots=size,
-                             valid=len(take), flush_reason=reason) as sp:
-                    for r in take:
-                        r.batch_span = sp
-                    self._flush_part(key, take, size, tr)
+                             valid=len(take), flush_reason=reason,
+                             in_flight=in_flight).begin()
+                for r in take:
+                    r.batch_span = sp
+            launch = self._stage(key, take, size, tr, sp)
+            if launch is None:
+                if lanes is not None:
+                    lanes.release()
+            elif lanes is None:
+                self._run_launch(launch)
+            else:
+                lanes.hand_over(launch)
             n += 1
         return n
 
-    def _flush_part(self, key: GroupKey, group: List[ServeRequest],
-                    size: int, tr=None) -> None:
-        bh, bw = key[0], key[1]
+    def _launch_slot(self, lanes: _LaunchLanes, take: List[ServeRequest],
+                     tr) -> Tuple[int, List[ServeRequest]]:
+        """Take a launch slot, waiting for a launch to return while all are
+        taken (the backpressure) -> (launches in flight without this one,
+        the requests still worth launching: those a wait saw expire are
+        rejected here, as ``poll`` would have)."""
+        if lanes.in_flight() < lanes.depth:
+            return lanes.acquire(), take
+        if tr is None:
+            in_flight = lanes.acquire()
+        else:
+            with tr.span("serve.launch_wait"):
+                in_flight = lanes.acquire()
+        now = self._clock()
+        live = []
+        for r in take:
+            if r.expired(now):
+                self._reject_expired(r)
+            else:
+                live.append(r)
+        return in_flight, live
+
+    def _stage(self, key: GroupKey, group: List[ServeRequest], size: int,
+               tr, sp) -> Optional[_Launch]:
+        """Assemble one launch (``serve.pad``); None when assembly raised
+        and the requests were rejected."""
+        out = None
         try:
             # assembly window stamped on every request (service clock):
             # queue-wait ends where assembly starts, and the service turns
             # the pair into the serve.request breakdown
             t_asm = self._clock()
             kind = self.kinds[group[0].kind]
+            out, reused = self._staging_for(kind, key)
             if tr is None:
-                batch, _ = self._assemble(kind, key, group, size)
+                batch = kind.assemble(key, group, size, out)
             else:
-                with tr.span("serve.pad") as sp:
-                    batch, sp.attrs["reused"] = self._assemble(kind, key,
-                                                               group, size)
-                    sp.attrs["bytes"] = int(kind.payload(batch).nbytes)
+                with sp.under(), tr.span("serve.pad", reused=reused) as pad:
+                    batch = kind.assemble(key, group, size, out)
+                    pad.attrs["bytes"] = int(kind.payload(batch).nbytes)
             t_ready = self._clock()
             for r in group:
                 r.t_assembly = t_asm
                 r.t_ready = t_ready
-            self.dispatch((bh, bw), batch, group)
+            return _Launch(key, batch, group, out, sp)
         except Exception as e:  # noqa: BLE001 — poison batch, keep serving
-            n = 0
-            for r in group:
-                if not r.done:
-                    r.reject(REJECT_ERROR, f"{type(e).__name__}: {e}")
-                    n += 1
-            if self.on_reject is not None and n:
-                self.on_reject(REJECT_ERROR, n)
-            if self.telemetry is not None:
-                self.telemetry.emit("serve.reject", reason=REJECT_ERROR,
-                                    count=n,
-                                    detail=f"{type(e).__name__}: {e}")
+            self._reject_poison(group, e)
+            self._launch_done(key, out, sp)
+            return None
 
-    def _assemble(self, kind, key: GroupKey, group: List[ServeRequest],
-                  size: int):
-        """-> (the launch's batch, whether it was assembled into a buffer
-        that already existed)."""
+    def _run_launch(self, launch: _Launch) -> None:
+        """``dispatch`` one assembled launch through to its end: the
+        batcher thread, or a launch lane.  Never raises."""
+        key, sp = launch.key, launch.span
+        try:
+            if sp is None:
+                self.dispatch(key[:2], launch.batch, launch.requests)
+            else:
+                with sp.under():
+                    self.dispatch(key[:2], launch.batch, launch.requests)
+        except Exception as e:  # noqa: BLE001 — poison batch, keep serving
+            self._reject_poison(launch.requests, e)
+        finally:
+            self._launch_done(key, launch.staging, sp)
+
+    def _launch_done(self, key: GroupKey, staging, sp) -> None:
+        """``dispatch`` has returned or raised: nothing reads the launch's
+        staging buffer any more, and its span ends."""
+        if staging is not None:
+            self._staging_free[key].append(staging)
+        if sp is not None:
+            sp.finish()
+
+    def _reject_poison(self, group: List[ServeRequest], e: Exception) -> None:
+        n = 0
+        for r in group:
+            if not r.done:
+                r.reject(REJECT_ERROR, f"{type(e).__name__}: {e}")
+                n += 1
+        if self.on_reject is not None and n:
+            self.on_reject(REJECT_ERROR, n)
+        if self.telemetry is not None:
+            self.telemetry.emit("serve.reject", reason=REJECT_ERROR,
+                                count=n, detail=f"{type(e).__name__}: {e}")
+
+    def _staging_for(self, kind, key: GroupKey):
+        """-> (a buffer of ``key``'s ring that no launch holds, whether it
+        existed already); (None, False) where every launch is assembled
+        fresh.  Whoever asks runs launches in line or holds a launch slot,
+        so fewer than ``launches_in_flight`` of the ring's buffers are out:
+        one is free, or the ring has room for one more."""
         out, reused = None, False
         if self._staging_pool is not None:
-            out = self._staging_pool.get(key)
-            reused = out is not None
-            if out is None:
-                out = self._staging_pool[key] = kind.new_staging(
-                    key, self.max_batch)
+            free = self._staging_free.setdefault(key, [])
+            reused = bool(free)
+            if reused:
+                out = free.pop()
+            else:
+                out = kind.new_staging(key, self.max_batch)
+                self._staging_pool.setdefault(key, []).append(out)
                 self.staging["bytes_held"] += out.nbytes
         self.staging["reused" if reused else "fresh"] += 1
-        return kind.assemble(key, group, size, out), reused
+        return out, reused
 
     def _reject_expired(self, r: ServeRequest) -> None:
         r.reject(REJECT_DEADLINE, "deadline expired before dispatch")
@@ -435,6 +629,9 @@ class MicroBatcher:
         if self._thread is not None:
             raise RuntimeError("batcher already started")
         self._stop.clear()
+        if self.launches_in_flight > 1:
+            self._lanes = _LaunchLanes(self.launches_in_flight,
+                                       self._run_launch)
         self._thread = threading.Thread(target=self._run, daemon=True,
                                         name="can-tpu-serve-batcher")
         self._thread.start()
@@ -449,7 +646,8 @@ class MicroBatcher:
         self.flush_all()
 
     def close(self) -> None:
-        """Stop the pump thread and flush everything pending (idempotent)."""
+        """Stop the pump thread, flush everything pending and wait for the
+        launches in flight (idempotent)."""
         self._stop.set()
         if self._thread is not None:
             self._thread.join(timeout=30.0)
@@ -457,6 +655,10 @@ class MicroBatcher:
         else:
             self.intake()
             self.flush_all()
+        if self._lanes is not None:
+            self._lanes.close()
+            self._lanes = None
         if self._staging_pool:
             self._staging_pool.clear()
+            self._staging_free.clear()
             self.staging["bytes_held"] = 0

@@ -35,6 +35,7 @@ tests pin).  The engine adds only what serving needs around that math:
 
 from __future__ import annotations
 
+import threading
 import time
 from typing import Optional, Tuple
 
@@ -86,6 +87,15 @@ class ServeEngine:
     masked density)``; None builds CANNet's (``programs.cannet_predict``).
     """
 
+    # How many ``predict_batch`` calls the engine can usefully hold in
+    # progress at once, on as many threads (the batcher reads it and runs
+    # that many launch lanes): a launch is ONE stateless program, so while
+    # program n runs the runtime changes the layout of batch n+1, copies it
+    # to the device and queues its program behind n.  The device runs one
+    # program at a time: a third launch in flight would only add latency
+    # and a third input batch.
+    launches_in_flight = 2
+
     def __init__(self, params, batch_stats=None, *, compute_dtype=None,
                  serve_dtype: str = "f32", ds: int = 8, device=None,
                  quantized: bool = False, telemetry=None,
@@ -123,7 +133,8 @@ class ServeEngine:
         self._predict = RecompileTracker(jax.jit(predict), self.telemetry,
                                          name=name, batch_arg=1)
         self._signatures = self.telemetry.signature_registry[name]
-        self._last_compiled = False
+        # per calling thread: whether its last predict_batch compiled
+        self._call = threading.local()
 
     def _put(self, tree):
         if self.device is None:
@@ -188,10 +199,11 @@ class ServeEngine:
         # dispatch: the call of the program up to its return, which is
         # the enqueue (the runtime changes the batch's layout and copies
         # it to the device on threads of its own); fetch: the wait for
-        # those, for the program, and the D2H
+        # those, for the program (and, with a second call in progress on
+        # another thread, for the program queued ahead of it), and the D2H
         with tr.span("serve.dispatch", aot=prog is not None) as sp:
             counts, density = self._launch(prog, batch)
-            sp.attrs["compiled"] = self._last_compiled
+            sp.attrs["compiled"] = self._call.compiled
         with tr.span("serve.fetch", density=bool(want_density)):
             return self._fetch(counts, density, want_density)
 
@@ -200,12 +212,12 @@ class ServeEngine:
             counts, density = prog(self.params, _batch_dict(batch),
                                    self.batch_stats)
             self.aot_hits += 1
-            self._last_compiled = False
+            self._call.compiled = False
         else:
             counts, density = self._predict(self.params,
                                             _batch_dict(batch),
                                             self.batch_stats)
-            self._last_compiled = self._predict.last_first_call
+            self._call.compiled = self._predict.last_first_call
         return counts, density
 
     @staticmethod
@@ -228,11 +240,13 @@ class ServeEngine:
 
     @property
     def last_batch_compiled(self) -> bool:
-        """True when the most recent ``predict_batch`` hit a new signature
-        (its wall time is compile, not steady-state — keep it out of
-        latency reservoirs, exactly like the offline loops do).  AOT hits
-        are never compiles."""
-        return self._last_compiled
+        """True when the CALLING THREAD's most recent ``predict_batch`` hit
+        a new signature (its wall time is compile, not steady-state — keep
+        it out of latency reservoirs, exactly like the offline loops do).
+        Per thread, because two calls may be in progress at once
+        (``launches_in_flight``) and each caller asks about its own.  AOT
+        hits are never compiles."""
+        return getattr(self._call, "compiled", False)
 
     def release_buffers(self) -> None:
         """Drop every reference to the device-resident param/batch-stats
@@ -351,6 +365,11 @@ class LMEngine:
     """
 
     ds = 1  # a token batch has no density grid (CountService reads it)
+
+    # One launch is hundreds of executions of two programs over a cache the
+    # engine owns: a second ``generate_batch`` in progress would interleave
+    # decode steps on a device that is busy already, and hold a second cache.
+    launches_in_flight = 1
 
     def __init__(self, params, programs, *, prefill_slice: int = 8,
                  device=None, telemetry=None, name: str = "lm"):

@@ -3,7 +3,8 @@
 Wires the pieces into one lifecycle::
 
     client -> submit() -> BoundedRequestQueue -> MicroBatcher(thread)
-                                                   -> ServeEngine.predict_batch
+                                                   -> (launch lane ->)
+                                                      ServeEngine.predict_batch
                                                    -> resolve ServeRequests
 
 ``submit()/result()`` is the primary API — tests and the bench drive the
@@ -11,11 +12,15 @@ full stack through it with zero networking.  The HTTP front end
 (``serve_http``) is a thin stdlib adapter over the same calls: one process,
 one device owner, many client connections.
 
-The engine may be a single ``ServeEngine`` (dispatch executes inline on
-the batcher thread, the original topology) or a ``FleetEngine``
-(serve/fleet.py): then dispatch ENQUEUES the assembled batch and returns,
-replica worker threads execute on their own devices and call back into
-``_complete`` — same resolution/telemetry code either way, so every
+The engine may be a single engine in process or a ``FleetEngine``.  In
+process, dispatch executes the batch through to the resolved requests: on
+the batcher thread (the original topology) where the engine holds one
+launch in flight (``LMEngine``), on one of the batcher's launch lanes
+where it states more (``ServeEngine``: 2, so that batch n+1 is assembled
+and transferred while batch n's program runs; serve/batcher.py).  Behind a
+``FleetEngine`` (serve/fleet.py) dispatch ENQUEUES the assembled batch and
+returns, replica worker threads execute on their own devices and call back
+into ``_complete`` — same resolution/telemetry code every way, so every
 guarantee (typed rejection, parity, bounded compiles) holds per replica.
 
 Telemetry (same bus/schema as train/eval, summarised by
@@ -121,7 +126,8 @@ class ServeTicket:
 
 
 class CountService:
-    """Owns the queue, the batcher thread, and the engine.
+    """Owns the queue, the batcher thread (with its launch lanes), and the
+    engine.
 
     bucket_ladder / pad_multiple: the bucket policy (same semantics as the
     offline batcher; pick the ladder from the deployment's expected shape
@@ -201,6 +207,14 @@ class CountService:
                                     # the answers fetched and the requests
                                     # resolved; the fleet's only enqueues
                                     batch_free_on_return=self._fleet is None,
+                                    # ... and may run as many launches at
+                                    # once as the engine says it can hold
+                                    # in flight (launch lanes); the fleet
+                                    # has its replicas' workers for that
+                                    launches_in_flight=(
+                                        1 if self._fleet is not None else
+                                        getattr(engine, "launches_in_flight",
+                                                1)),
                                     # further request kinds by name
                                     # (serve/kinds.py); images always
                                     kinds=kinds)
@@ -519,6 +533,9 @@ class CountService:
             # buffer that already existed / fresh) and the bytes the
             # batcher's staging pool holds now
             "staging": dict(self.batcher.staging),
+            # launches handed to a launch lane while another was in flight
+            # (of ``batches``): how often the overlap engages
+            "launches_overlapped": self.batcher.launches_overlapped,
         }
         if self._fleet is not None:
             # per-replica rows: service-side work counters joined with the
@@ -550,7 +567,7 @@ class CountService:
         with self._lock:
             return self.latency.percentile(q)
 
-    # -- batcher dispatch (runs on the batcher thread) -------------------
+    # -- batcher dispatch (the batcher thread, or one of its launch lanes) -
     def _dispatch(self, bucket_hw, batch, requests) -> None:
         if self._fleet is not None:
             # hand the assembled batch to whichever replica frees up
@@ -577,7 +594,7 @@ class CountService:
         self._complete(bucket_hw, batch, requests, counts, density,
                        execute_s, compiled, t_exec0=t_exec0)
 
-    # -- batch completion (batcher thread, or a fleet replica worker) ----
+    # -- batch completion (whoever dispatched, or a fleet replica worker) -
     def _complete(self, bucket_hw, batch, requests, counts, density,
                   execute_s, compiled, replica=None,
                   program: str = "serve_predict", t_exec0=None) -> None:
@@ -1138,7 +1155,7 @@ def serve_http(service: CountService, *, host: str = "127.0.0.1",
                port: int = 8000):
     """Build a ``ThreadingHTTPServer`` for ``service`` (caller runs
     ``serve_forever()``; threads give one blocked client per connection
-    while the single batcher thread owns the device)."""
+    while the batcher thread and its launch lanes own the device)."""
     from http.server import ThreadingHTTPServer
 
     return ThreadingHTTPServer((host, port), make_http_handler(service))

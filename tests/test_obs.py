@@ -129,6 +129,31 @@ class TestRecompileTracker:
         assert compiles[1]["payload"]["n_signatures"] == 2
         assert compiles[0]["payload"]["seconds"] >= 0
 
+    def test_last_first_call_is_the_calling_threads_own(self):
+        """Two serve launches may be in progress on two threads: each asks
+        whether ITS call compiled, whatever the other's did since."""
+        import threading
+
+        tel = obs.Telemetry()
+        step = obs.RecompileTracker(fake_train_step, tel, name="per-thread")
+        small, tall = make_batches(2, tall_from=1)
+        step(None, small)
+        assert step.last_first_call is True
+        seen = {}
+
+        def other():
+            seen["before"] = step.last_first_call   # made no call yet
+            step(None, small)
+            seen["warm"] = step.last_first_call
+            step(None, tall)
+            seen["new"] = step.last_first_call
+
+        t = threading.Thread(target=other)
+        t.start()
+        t.join(10.0)
+        assert seen == {"before": False, "warm": False, "new": True}
+        assert step.last_first_call is True    # this thread's, still
+
     def test_dtype_change_is_a_new_signature(self):
         from can_tpu.train import batch_signature
 

@@ -601,9 +601,12 @@ class TestStagingReuse:
             count, dens = reference[rid]
             assert res.count == count  # bit for bit
             np.testing.assert_array_equal(res.density, dens)
-        # launches of 4, 2, 1, 1, 4 slots: the first made the buffer
+        # launches of 4, 2, 1, 1, 4 slots: the first made the buffer, and
+        # a hand-driven batcher (no lanes: each launch runs to its end
+        # before the next is assembled) never needs the ring's second
         staging = svc.stats()["staging"]
-        buf = svc.batcher._staging_pool[self.KEY]
+        buf, = svc.batcher._staging_pool[self.KEY]
+        assert svc.batcher._staging_free[self.KEY] == [buf]
         assert staging == {"reused": 4, "fresh": 1, "bytes_held": buf.nbytes}
         assert buf.image.shape == (4, 96, 96, 3)
         # the buffer is the batcher's again: scribbling on it reaches no
@@ -656,6 +659,9 @@ class TestStagingReuse:
             assert res.count == count
             np.testing.assert_array_equal(res.density, dens)
         assert svc.stats()["staging"]["reused"] == 2
+        # the launch that raised gave its buffer back like the others
+        assert (svc.batcher._staging_free[self.KEY]
+                == svc.batcher._staging_pool[self.KEY])
         svc.close()
 
     def test_uint8_and_float32_stage_apart(self, small_engine):
@@ -674,10 +680,12 @@ class TestStagingReuse:
                 assert t.result(0).count == reference[t._request.id][0]
         pool = svc.batcher._staging_pool
         assert sorted(pool) == [(96, 96, "float32"), (96, 96, "uint8")]
-        assert pool[(96, 96, "uint8")].image.dtype == np.uint8
+        assert [len(ring) for ring in pool.values()] == [1, 1]
+        assert pool[(96, 96, "uint8")][0].image.dtype == np.uint8
         assert svc.stats()["staging"] == {
             "reused": 2, "fresh": 2,
-            "bytes_held": sum(b.nbytes for b in pool.values())}
+            "bytes_held": sum(b.nbytes for ring in pool.values()
+                              for b in ring)}
         svc.close()
 
     def test_the_fleet_path_assembles_every_launch_fresh(self):
@@ -685,6 +693,10 @@ class TestStagingReuse:
         svc = CountService(fleet, max_batch=4, max_wait_ms=2.0,
                            bucket_ladder=self.LADDER)
         assert svc.batcher._staging_pool is None
+        # how many launches a fleet holds in flight is its replicas'
+        # business: the batcher runs no lanes for it, whatever it states
+        fleet.launches_in_flight = 2
+        assert CountService(fleet, max_batch=4).batcher.launches_in_flight == 1
         tickets = []
         for group in self.rounds(np.random.default_rng(14)):
             tickets += [svc.submit(img) for img in group]
@@ -712,6 +724,289 @@ class TestStagingReuse:
         assert ('can_tpu_serve_staging_launches_total{assembled="fresh"} 1'
                 in text)
         assert "can_tpu_serve_staging_bytes_held 4096" in text
+
+
+class GatedEngine:
+    """As much of an engine as ``CountService`` needs, with a
+    ``predict_batch`` that waits: call ``i`` blocks until ``gates[i]`` is
+    set, so a test decides which launches are in flight and nothing depends
+    on timing.  A request's "count" is its image's first pixel."""
+
+    ds, compile_count, last_batch_compiled = 8, 0, False
+
+    def __init__(self, launches_in_flight, *, raises=()):
+        if launches_in_flight is not None:
+            self.launches_in_flight = launches_in_flight
+        self.telemetry = obs.Telemetry()
+        self.gates = [threading.Event() for _ in range(8)]
+        self.raises = set(raises)
+        self.entered = threading.Semaphore(0)
+        self.calls = []   # per call: thread, the batch, its bytes on entry
+        self.running = self.most_running = 0
+        self._lock = threading.Lock()
+
+    def wait_entered(self, n=1):
+        for _ in range(n):
+            assert self.entered.acquire(timeout=30), "a launch never started"
+
+    def predict_batch(self, batch, *, want_density=False):
+        with self._lock:
+            i = len(self.calls)
+            self.calls.append({"thread": threading.current_thread().name,
+                               "batch": batch, "bytes": batch.image.copy()})
+            self.running += 1
+            self.most_running = max(self.most_running, self.running)
+        self.entered.release()
+        try:
+            assert self.gates[i].wait(30), f"gate {i} never opened"
+            if i in self.raises:
+                raise RuntimeError(f"launch {i} fell over")
+            return batch.image[:, 0, 0, 0].copy(), None
+        finally:
+            with self._lock:
+                self.running -= 1
+
+
+def marked(value, side=16):
+    """An image whose first pixel (its "count" on a GatedEngine) is
+    ``value``."""
+    return np.full((side, side, 3), float(value), np.float32)
+
+
+class TestLaunchLanes:
+    """Two launches in flight (PR 27): the batcher thread assembles and a
+    lane dispatches batch n+1 while batch n's ``predict_batch`` is still in
+    progress on the other lane; never three; a staging buffer is nobody
+    else's while its launch is in flight."""
+
+    def service(self, engine, **kw):
+        return CountService(engine, max_batch=2, max_wait_ms=2.0,
+                            bucket_ladder=((16,), (16,)), queue_capacity=64,
+                            **kw)
+
+    def submit(self, svc, values):
+        return [svc.submit(marked(v)) for v in values]
+
+    @staticmethod
+    def buffer_of(call):
+        return call["batch"].image.base
+
+    def test_depth_is_two_never_three(self):
+        eng = GatedEngine(2)
+        svc = self.service(eng)
+        tickets = self.submit(svc, range(1, 7))  # three launches' worth
+        svc.start()
+        eng.wait_entered(2)
+        # two launches in progress, on the two lanes; the third is not
+        # assembled (there is no buffer for it) until one returns
+        assert sorted(c["thread"] for c in eng.calls) == [
+            "can-tpu-serve-lane_0", "can-tpu-serve-lane_1"]
+        assert svc.batcher._lanes.in_flight() == 2
+        assert not any(t.done for t in tickets)
+        eng.gates[0].set()
+        eng.wait_entered(1)
+        assert len(eng.calls) == 3
+        for g in eng.gates:
+            g.set()
+        assert [t.result(30).count for t in tickets] == [1, 2, 3, 4, 5, 6]
+        assert eng.most_running == 2
+        stats = svc.stats()
+        assert stats["batches"] == 3 and stats["launches_overlapped"] == 2
+        svc.close()
+
+    @pytest.mark.parametrize("first_raises", [False, True],
+                             ids=["returns", "raises"])
+    def test_a_buffer_is_not_written_while_its_launch_is_in_flight(
+            self, first_raises):
+        eng = GatedEngine(2, raises={0} if first_raises else ())
+        svc = self.service(eng)
+        tickets = self.submit(svc, range(1, 7))
+        svc.start()
+        eng.wait_entered(2)
+        first, second = eng.calls
+        assert self.buffer_of(first) is not self.buffer_of(second)
+        ring = svc.batcher._staging_pool[(16, 16, "float32")]
+        assert {id(b.image) for b in ring} == {id(self.buffer_of(first)),
+                                               id(self.buffer_of(second))}
+        assert svc.stats()["staging"] == {
+            "reused": 0, "fresh": 2, "bytes_held": sum(b.nbytes for b in ring)}
+        # both launches are blocked and the third group waits: neither
+        # buffer has been touched since its launch was handed over
+        for call in (first, second):
+            np.testing.assert_array_equal(call["batch"].image, call["bytes"])
+        eng.gates[0].set()
+        eng.wait_entered(1)
+        third = eng.calls[2]
+        # the first launch returned (or raised): ITS buffer was reused
+        assert self.buffer_of(third) is self.buffer_of(first)
+        np.testing.assert_array_equal(second["batch"].image, second["bytes"])
+        assert third["bytes"][:, 0, 0, 0].tolist() == [5.0, 6.0]
+        for g in eng.gates:
+            g.set()
+        if first_raises:
+            # a poison batch on a lane: its own requests only
+            for t in tickets[:2]:
+                with pytest.raises(RejectedError) as e:
+                    t.result(30)
+                assert e.value.reason == REJECT_ERROR
+            assert svc.stats()["rejected"] == 2
+        else:
+            assert [t.result(30).count for t in tickets[:2]] == [1, 2]
+        assert [t.result(30).count for t in tickets[2:]] == [3, 4, 5, 6]
+        assert svc.stats()["staging"]["reused"] == 1
+        svc.close()
+        assert svc.stats()["staging"]["bytes_held"] == 0
+
+    def test_overlapping_launches_keep_each_request_with_its_own_count(self):
+        eng = GatedEngine(2)
+        svc = self.service(eng)
+        tickets = self.submit(svc, [10, 20, 30, 40])
+        svc.start()
+        eng.wait_entered(2)
+        eng.gates[1].set()   # the second launch completes first
+        assert [t.result(30).count for t in tickets[2:]] == [30, 40]
+        assert not tickets[0].done and not tickets[1].done
+        eng.gates[0].set()
+        assert [t.result(30).count for t in tickets[:2]] == [10, 20]
+        svc.close()
+
+    def test_close_resolves_everything_with_launches_in_flight(self):
+        eng = GatedEngine(2)
+        svc = self.service(eng)
+        tickets = self.submit(svc, range(1, 8))  # 3 launches and a rest of 1
+        svc.start()
+        eng.wait_entered(2)
+        closer = threading.Thread(target=svc.close)
+        closer.start()
+        closer.join(0.05)
+        assert closer.is_alive()  # close() waits for the launches in flight
+        for g in eng.gates:
+            g.set()
+        closer.join(30)
+        assert not closer.is_alive()
+        assert [t.result(0).count for t in tickets] == [1, 2, 3, 4, 5, 6, 7]
+        assert svc.batcher._lanes is None and eng.running == 0
+        late = svc.submit(marked(9))
+        with pytest.raises(RejectedError) as e:
+            late.result(0)
+        assert e.value.reason == REJECT_SHUTDOWN
+
+    @pytest.mark.parametrize("stated, lanes", [(1, 0), (2, 2), (None, 0)],
+                             ids=["one", "two", "unstated"])
+    def test_the_engine_states_how_many_launches_fly(self, stated, lanes):
+        eng = GatedEngine(stated)
+        for g in eng.gates:
+            g.set()
+        svc = self.service(eng)
+        assert svc.batcher.launches_in_flight == (stated or 1)
+        tickets = self.submit(svc, range(1, 5))
+        svc.start()
+        assert [t.result(30).count for t in tickets] == [1, 2, 3, 4]
+        threads = {c["thread"] for c in eng.calls}
+        if lanes:
+            assert threads <= {"can-tpu-serve-lane_0", "can-tpu-serve-lane_1"}
+            assert len(svc.batcher._lanes._threads) == lanes
+        else:
+            # depth 1 is the in-line path: dispatch on the batcher thread,
+            # no lane, one buffer, never a launch beside another
+            assert threads == {"can-tpu-serve-batcher"}
+            assert svc.batcher._lanes is None
+            assert eng.most_running == 1
+            assert len(svc.batcher._staging_pool[(16, 16, "float32")]) == 1
+            assert svc.stats()["launches_overlapped"] == 0
+        svc.close()
+
+    def test_more_than_one_launch_needs_a_dispatch_that_frees_its_batch(self):
+        q = BoundedRequestQueue(4)
+        with pytest.raises(ValueError, match="batch_free_on_return"):
+            MicroBatcher(q, CollectDispatch(), launches_in_flight=2)
+        with pytest.raises(ValueError, match="at least 1"):
+            MicroBatcher(q, CollectDispatch(), launches_in_flight=0,
+                         batch_free_on_return=True)
+
+    def test_span_tree_with_two_launches_in_flight(self, small_engine,
+                                                   monkeypatch):
+        """What the benchmark's readers read stays a tree: ``serve.batch``
+        under the batcher thread's cycle span, pad / dispatch / fetch /
+        complete its direct children, though the batch begins on the
+        batcher thread and ends on a lane."""
+        tel = obs.Telemetry()
+        tel.spans = obs.SpanTracer(tel, prefix="l")
+        monkeypatch.setattr(small_engine, "telemetry", tel)
+        svc = CountService(small_engine, max_batch=2, max_wait_ms=2.0,
+                           bucket_ladder=((64,), (64,)), telemetry=tel)
+        svc.warmup([(64, 64)])
+        real = small_engine.predict_batch
+        gate, entered = threading.Event(), threading.Semaphore(0)
+
+        def gated(batch, *, want_density=False):
+            entered.release()
+            assert gate.wait(30)
+            return real(batch, want_density=want_density)
+
+        monkeypatch.setattr(small_engine, "predict_batch", gated)
+        tickets = [svc.submit(np.zeros((64, 64, 3), np.float32))
+                   for _ in range(4)]
+        svc.start()
+        for _ in range(2):   # both launches in progress before either runs
+            assert entered.acquire(timeout=30)
+        gate.set()
+        for t in tickets:
+            t.result(60)
+        assert svc.stats()["launches_overlapped"] == 1
+        svc.close()   # every serve.batch span is recorded by now
+        ring = tel.spans.snapshot()
+        by_id = {s["span_id"]: s for s in ring}
+        requests = [s for s in ring if s["name"] == "request"]
+        assert len(requests) == 4
+        batches = sorted({s["batch"] for s in requests},
+                         key=lambda i: by_id[i]["start_s"])
+        assert len(batches) == 2
+        assert [by_id[i]["in_flight"] for i in batches] == [0, 1]
+        lanes = set()
+        for i in batches:
+            batch = by_id[i]
+            assert batch["name"] == "serve.batch" and batch["valid"] == 2
+            assert batch["thread"] == "can-tpu-serve-batcher"
+            cycle = by_id[batch["parent_id"]]
+            assert cycle["name"] in ("serve.intake", "serve.poll")
+            assert cycle["thread"] == "can-tpu-serve-batcher"
+            assert cycle["trace_id"].startswith("batcher-")
+            kids = {s["name"]: s for s in ring if s.get("parent_id") == i}
+            assert sorted(kids) == ["serve.complete", "serve.dispatch",
+                                    "serve.fetch", "serve.pad"]
+            assert all(k["trace_id"] == batch["trace_id"]
+                       for k in kids.values())
+            assert kids["serve.dispatch"]["compiled"] is False
+            assert kids["serve.pad"]["thread"] == "can-tpu-serve-batcher"
+            lane = {kids[n]["thread"] for n in ("serve.dispatch",
+                                                "serve.fetch",
+                                                "serve.complete")}
+            assert len(lane) == 1
+            lanes |= lane
+            # the phases follow one another inside the batch, and the
+            # batch ends with the last of them
+            order = [kids[n] for n in ("serve.pad", "serve.dispatch",
+                                       "serve.fetch", "serve.complete")]
+            for a, b in zip(order, order[1:]):
+                assert b["start_s"] >= a["start_s"] + a["duration_s"] - 1e-6
+            end = batch["start_s"] + batch["duration_s"]
+            done = order[-1]["start_s"] + order[-1]["duration_s"]
+            assert done - 1e-6 <= end
+        assert lanes == {"can-tpu-serve-lane_0", "can-tpu-serve-lane_1"}
+        first, second = (by_id[i] for i in batches)
+        assert second["start_s"] < first["start_s"] + first["duration_s"]
+        for s in requests:   # a request's wait ends where its batch begins
+            wait = next(w for w in ring if w["name"] == "queue_wait"
+                        and w["parent_id"] == s["span_id"])
+            assert wait["start_s"] + wait["duration_s"] == pytest.approx(
+                by_id[s["batch"]]["start_s"], abs=2e-6)
+
+    def test_overlap_reaches_the_scrape(self):
+        from can_tpu.obs.exporter import render_stats
+
+        text = render_stats({"batches": 5, "launches_overlapped": 4})
+        assert "can_tpu_serve_launches_overlapped_total 4" in text
 
 
 class TestOfflineOnlineParity:

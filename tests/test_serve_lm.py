@@ -322,19 +322,32 @@ class TestGenerateService:
         svc, _, tracer = service
         import time
 
-        before = {s["span_id"] for s in tracer.snapshot()
-                  if s["name"] == "serve.batch"}
-        svc.submit(_prompt(12, 5)).result(120)
-        # the request resolves inside its serve.batch span: wait for the
-        # span to close on the batcher's thread
+        ticket = svc.submit(_prompt(12, 5))
+        ticket.result(120)
+        # the request resolves inside serve.complete, before its
+        # serve.batch span is recorded (and an earlier test's batch may
+        # still be on its way into the ring): find the batch through the
+        # request's own link and wait for that id
+        want = ticket._request.batch_span.span_id
         for _ in range(500):
             ring = tracer.snapshot()
-            new = [s for s in ring if s["name"] == "serve.batch"
-                   and s["span_id"] not in before]
+            new = [s for s in ring if s["span_id"] == want]
             if new:
                 break
             time.sleep(0.01)
         batch, = new
+        assert batch["name"] == "serve.batch" and batch["valid"] == 1
+        # the engine states one launch in flight: in line, on the
+        # batcher's thread, nothing beside it
+        assert batch["in_flight"] == 0
+        assert batch["thread"] == "can-tpu-serve-batcher"
+        assert {s["thread"] for s in ring
+                if s.get("parent_id") == want} == {"can-tpu-serve-batcher"}
+        cycle = next(s for s in ring if s["span_id"] == batch["parent_id"])
+        assert cycle["name"] in ("serve.intake", "serve.poll")
+        request = next(s for s in ring if s["name"] == "request"
+                       and s["trace_id"] == ticket._request.trace_id)
+        assert request["batch"] == want
         kids = {}
         for s in ring:
             if s.get("parent_id") == batch["span_id"]:

@@ -102,6 +102,42 @@ class TestRecorder:
         assert s["outer"]["thread"] == threading.current_thread().name
         assert outer.span_id != inner.span_id != rooted.span_id
 
+    def test_a_begun_span_ends_on_another_thread(self):
+        """``begin()`` / ``under()`` / ``finish()``: the span of a launch
+        that one thread assembles and another runs."""
+        tr = SpanTracer(prefix="t")
+        with tr.span("cycle") as cycle:
+            batch = tr.span("batch", trace_id="its-own", valid=2).begin()
+            assert tr.current() is cycle   # begun, not opened here
+            with batch.under():
+                with tr.span("pad"):
+                    pass
+            assert tr.current() is cycle
+
+        def lane():
+            with batch.under(), tr.span("dispatch"):
+                pass
+            assert tr.current() is None
+            batch.finish()
+
+        t = threading.Thread(target=lane, name="a-lane")
+        t.start()
+        t.join(10.0)
+        s = {x["name"]: x for x in tr.snapshot()}
+        assert s["batch"]["parent_id"] == s["cycle"]["span_id"]
+        assert s["batch"]["trace_id"] == "its-own" and s["batch"]["valid"] == 2
+        for phase in ("pad", "dispatch"):
+            assert s[phase]["parent_id"] == s["batch"]["span_id"]
+            assert s[phase]["trace_id"] == "its-own"
+        # recorded on the lane of the thread that began it; its children
+        # on theirs; it ends after the last of them
+        assert s["batch"]["thread"] == threading.current_thread().name
+        assert s["dispatch"]["thread"] == "a-lane"
+        end = lambda x: x["start_s"] + x["duration_s"]  # noqa: E731
+        assert s["batch"]["start_s"] <= s["pad"]["start_s"]
+        assert end(s["batch"]) >= end(s["dispatch"]) - 1e-6
+        assert self_time(s["batch"], [s["pad"], s["dispatch"]]) >= 0.0
+
     def test_both_ends_are_perf_counter(self):
         tr = SpanTracer(prefix="t")
         t0 = time.perf_counter()
