@@ -23,8 +23,7 @@ Two passes, two failure classes:
 
 Entry points: ``tools/can_tpu_lint.py`` (lint CLI),
 ``python -m can_tpu.analysis.hlo_audit`` (audit CLI), ``tools/ci_lint.sh``
-(both, as a CI gate beside ``ci_bench_gate.sh``), and
-``tests/test_analysis.py`` (tier-1).
+(both, as a CI gate), and ``tests/test_analysis.py`` (tier-1).
 """
 
 from can_tpu.analysis.source_lint import (  # noqa: F401

@@ -25,9 +25,8 @@ committed ``PROGRAM_CONTRACTS.json``:
   (dequant INSIDE the program, HBM holds int8; a hoisted dequant would
   quietly quadruple parameter traffic);
 * **flop/byte budgets** — XLA ``cost_analysis()`` within a per-program
-  noise band of the contract (bench_compare discipline: cost is
-  deterministic, so both directions trip — up is bloat, down is lost
-  work).
+  noise band of the contract (cost is deterministic, so both
+  directions trip — up is bloat, down is lost work).
 
 Facts come from text because text is what XLA was actually given: the
 byte-identity pin (tests/test_perf.py) already proves lowering is

@@ -440,14 +440,13 @@ def declared_event_kinds(root: str) -> Tuple[List[str], int]:
 
 
 def default_paths(root: str) -> List[str]:
-    """The lint scope: the library, the bench entry points, the tools —
-    same universe the EVENT_KINDS drift test always scanned."""
+    """The lint scope: the library and the tools — the universe the
+    EVENT_KINDS drift test scans."""
     import glob
 
     paths = sorted(
         glob.glob(os.path.join(root, "can_tpu", "**", "*.py"),
                   recursive=True)
-        + glob.glob(os.path.join(root, "bench*.py"))
         + glob.glob(os.path.join(root, "tools", "*.py")))
     return paths
 

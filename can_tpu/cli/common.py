@@ -459,18 +459,15 @@ MODEL_MPX_PER_S = 42.0  # CANNet bf16 train-step device rate (v5e measured:
 
 # Per-launch cost in the DEVICE regime: what one extra launch costs when
 # dispatch is overlapped with compute (steps enqueued back-to-back, the
-# loop's windowed fetch amortising the sync) — the regime the bench
-# suite's steady-state compute numbers and a healthy prefetching train
-# loop run in.  The pixel-independent device work per launch is chiefly
-# the optimizer update (~300 MB param/momentum traffic ≈ 0.4 ms ≈ 0.017
-# Mpx on v5e, r5 calibration note above) plus executable switch + infeed
+# loop's windowed fetch amortising the sync) — the regime a healthy
+# prefetching train loop runs in.  The pixel-independent device work per
+# launch is chiefly the optimizer update (~300 MB param/momentum traffic
+# ≈ 0.4 ms ≈ 0.017 Mpx on v5e) plus executable switch + infeed
 # bookkeeping; 0.05 Mpx (~1.2 ms) is that with ~3x slack.  This is NOT
 # the dispatch-bound number: a host whose launches serialize on a slow
 # dispatch path must price with --launch-cost-mpx auto / the CLI default
-# instead.  The distinction matters: the r5 bench planned its varres
-# schedule at the CLI default (2.0) and then quoted the steady-state
-# compute rate — paying 30.7% pixel overhead (b16) to economise launches
-# that regime gets nearly free (VERDICT r5 item 7).
+# instead.  No CLI prices from this constant: the planner's golden-plan
+# tests (tests/test_planner.py) do.
 # Both constants — this one and the CLIs' --launch-cost-mpx default of
 # 2.0 — are NOT MEASURED on the current machine (ROADMAP Design 4).
 DEVICE_LAUNCH_COST_MPX = 0.05
@@ -492,14 +489,14 @@ def measure_launch_cost_mpx(*, probes: int = 30,
     the bound is tight, and elsewhere both numbers sit in the planner's
     flat region.
 
-    Calibration status (r5, tools/launch_cost_probe.py + the plan-space
-    sweep in CHANGES.md): the probe measures DISPATCH only; a real train
-    step also pays pixel-independent device work each launch — chiefly
+    Calibration status (r5, tools/launch_cost_probe.py): the probe
+    measures DISPATCH only; a real train step also pays
+    pixel-independent device work each launch — chiefly
     the optimizer update (~300 MB of param/momentum traffic ≈ 0.4 ms ≈
     0.015 Mpx-equivalents on v5e) plus argument marshaling.  That
     omission cannot change a plan: the remnant planner's decisions are
-    flat across [0, 0.05] Mpx and across [1, 4] Mpx on the bench
-    distribution; the sensitive band (0.1-1 Mpx ≈ 2.5-25 ms) is exactly
+    flat across [0, 0.05] Mpx and across [1, 4] Mpx on a Part-A-like
+    shape histogram; the sensitive band (0.1-1 Mpx ≈ 2.5-25 ms) is exactly
     where dispatch dominates and the probe measures the dominant term
     directly.  So: no correction applied, by measurement rather than
     hope.  Costs one trivial compile at startup.
@@ -566,9 +563,10 @@ def make_bucketed_train_step(apply_fn, optimizer, mesh, *, compute_dtype,
     """Data-parallel train step with per-bucket remat dispatch: two jitted
     step objects (remat on/off); jit caches per batch shape under each, so
     every bucket runs the cheapest variant the ``policy`` (make_remat_policy)
-    allows.  Shared by the train CLI and bench_suite so the bench measures
-    exactly the CLI's dispatch.  health_metrics: in-program grad/update
-    norms for the run-health layer (default off — identical programs)."""
+    allows.  Shared by the train CLI and the benchmark's train driver, so
+    the benchmark measures exactly the CLI's dispatch.  health_metrics:
+    in-program grad/update norms for the run-health layer (default off —
+    identical programs)."""
     from can_tpu.parallel import make_dp_train_step
 
     steps = {flag: make_dp_train_step(apply_fn, optimizer, mesh,

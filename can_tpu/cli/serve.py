@@ -75,28 +75,9 @@ def parse_args(argv=None):
     p.add_argument("--max-wait-ms", type=float, default=5.0,
                    help="latency CAP on batching: the priced flush "
                         "deadline (can_tpu/sched) never waits past this; "
-                        "with --flush-policy timer it is the fixed flush "
-                        "deadline itself (pre-r14 behaviour)")
-    p.add_argument("--menu-budget", type=int, default=None,
-                   help="launch sizes per (bucket, dtype) in the priced "
-                        "sub-batch menu (can_tpu/sched.select_menu; "
-                        "default 3): a 2-request flush launches a 2-slot "
-                        "program instead of padding to --max-batch; all "
-                        "menu sizes are compiled at warmup.  1 = the "
-                        "single max-batch program")
-    p.add_argument("--flush-policy", type=str, default="priced",
-                   choices=["priced", "timer"],
-                   help="priced: a group flushes the moment waiting "
-                        "longer cannot beat launch-cost amortization "
-                        "given its arrival rate and deadline slack; "
-                        "timer: the fixed --max-wait-ms deadline "
-                        "(pre-r14)")
-    p.add_argument("--dispatch-order", type=str, default="priced",
-                   choices=["priced", "fifo"],
-                   help="fleet work-queue order: priced = cheapest-"
-                        "feasible-first under deadline pressure with a "
-                        "starvation age bound (can_tpu/sched.pick_work); "
-                        "fifo = pre-r14 pure FIFO")
+                        "for a model the scheduling core cannot price "
+                        "(--model-config: a language model) it is the "
+                        "fixed flush deadline itself")
     p.add_argument("--queue-capacity", type=int, default=64,
                    help="hard bound on queued requests (beyond: queue_full)")
     p.add_argument("--high-water", type=int, default=None,
@@ -295,7 +276,7 @@ def make_rollout_loader(base_args):
 
 def build_service(args, telemetry=None):
     """Engine + service from parsed args (no networking) — the seam the
-    tests and bench drive; ``main`` adds HTTP around it."""
+    tests drive; ``main`` adds HTTP around it."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -310,12 +291,6 @@ def build_service(args, telemetry=None):
                          "(drop --bf16)")
     if args.replicas < 1:
         raise SystemExit(f"--replicas must be >= 1, got {args.replicas}")
-    if args.menu_budget is not None and not 1 <= args.menu_budget <= 8:
-        # the exact menu search is combinatorial in the budget: bound it
-        # HERE (and cleanly), before the checkpoint load — past ~8 sizes
-        # the expected-cost curve is flat and the search is just heat
-        raise SystemExit(f"--menu-budget must be in [1, 8], got "
-                         f"{args.menu_budget}")
     if args.stream_ttl_s <= 0:
         raise SystemExit(f"--stream-ttl-s must be positive, got "
                          f"{args.stream_ttl_s}")
@@ -353,8 +328,7 @@ def build_service(args, telemetry=None):
                 aot_bundle=args.aot_bundle or None,
                 probe_cooldown_s=args.probe_cooldown_s,
                 watchdog_slack=args.watchdog_slack,
-                watchdog_default_s=args.watchdog_default_s,
-                dispatch_order=args.dispatch_order)
+                watchdog_default_s=args.watchdog_default_s)
         except AotStaleError as e:
             # a stale bundle silently falling back to minutes of
             # compiles defeats the flag's whole point: refuse, name the
@@ -376,8 +350,6 @@ def build_service(args, telemetry=None):
                            high_water=high_water,
                            default_deadline_ms=args.deadline_ms,
                            bucket_ladder=ladder, telemetry=telemetry,
-                           menu_budget=args.menu_budget,
-                           flush_policy=args.flush_policy,
                            stream_ttl_s=args.stream_ttl_s,
                            degrade_policy=args.degrade_policy,
                            max_body_mb=args.max_body_mb)

@@ -232,9 +232,7 @@ def parse_args(argv=None):
                         "less padding (straggler merging keeps the number "
                         "of shapes actually compiled well under the "
                         "budget), and the persistent compilation cache "
-                        "makes the one-time bill cheap. Measured on the "
-                        "bench distribution: 8 -> 41.5, 16 -> 50.4, "
-                        "24 -> 56.3 img/s")
+                        "makes the one-time bill cheap")
     p.add_argument("--s2d-stem", action="store_true",
                    help="space-to-depth the VGG stem: fold the 3-channel "
                         "first conv into (H/2, W/2, 12) packed space so its "

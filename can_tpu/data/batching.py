@@ -302,7 +302,8 @@ class ShardedBatcher:
         # remnant menus, full-cell batch-size pricing under the HBM cap,
         # merge + local-search packing, and plan-cost-scored ladder grids.
         # "legacy": the pre-r8 heuristics, kept bit-compatible as the
-        # ablation baseline (tools/plan_ablation.py) and escape hatch.
+        # baseline of tests/fixtures/PLAN_ABLATION_r08.json and escape
+        # hatch.
         self.plan_mode = plan_mode
         # remnant sub-batches (ladder mode only): emit partial groups at a
         # small menu of sub-batch sizes instead of padding every straggler
@@ -311,7 +312,7 @@ class ShardedBatcher:
         # can't see: every emitted global batch must divide by the mesh's
         # dp axis AND by process_count, which is what ``batch_quantum``
         # (global-batch units; callers pass lcm(dp, process_count))
-        # promises.  The CLIs/bench enable it with the right quantum.
+        # promises.  The CLIs enable it with the right quantum.
         self.remnant_sizes = bool(remnant_sizes)
         self.batch_quantum = int(batch_quantum or process_count or 1)
         # fixed cost of one extra step launch, in pixel-equivalents, for
@@ -724,9 +725,9 @@ class ShardedBatcher:
     def planner_stats(self, epoch: int = 0) -> Dict[str, object]:
         """One flat dict of planner decisions + realized schedule
         economics for this epoch — the payload of the ``data.planner``
-        telemetry event (live gauges on the /metrics exporter) and the
-        plan-ablation bench tier.  Predicted numbers come from the cost
-        model; realized ones are re-derived from the emitted schedule, so
+        telemetry event (live gauges on the /metrics exporter).  Predicted
+        numbers come from the cost model; realized ones are re-derived
+        from the emitted schedule, so
         a divergence between the two is a planner bug, not noise (pinned
         by test)."""
         sched = self.global_schedule(epoch)

@@ -40,8 +40,8 @@ permutes which items fill the slots).
 
 ``mode="legacy"`` preserves the round-5 behaviour exactly (max-fitting
 full size, power-of-two menu, pairwise merge + drop-smallest) — it is the
-baseline arm of ``tools/plan_ablation.py`` and the escape hatch if a
-regression ever points here.
+baseline arm of the golden plan ``tests/fixtures/PLAN_ABLATION_r08.json``
+and the escape hatch if a regression ever points here.
 """
 
 from __future__ import annotations

@@ -6,7 +6,7 @@ mirrored that.  A production pod needs a machine-readable record of where
 each step's time and memory went — recompiles, input stalls, HBM pressure —
 that survives the process and is diffable across runs and hosts.
 
-Schema: one JSON object per line, identical across train / eval / bench so
+Schema: one JSON object per line, identical across train / eval / serve so
 artifacts are directly comparable::
 
     {"ts": <unix seconds>, "kind": <str>, "step": <int|null>,
@@ -17,8 +17,8 @@ a jitted step, with elapsed first-call time), ``step_window`` (a windowed
 batch of per-step wall times), ``stall`` (seconds the consumer spent
 blocked on the input pipeline), ``memory`` (device/host memory snapshot),
 ``heartbeat`` (liveness timestamp from a daemon thread), ``epoch``
-(per-epoch scalars — the row wandb used to get directly), ``bench``
-(benchmark result records), ``run`` (run-level config, emitted once).
+(per-epoch scalars — the row wandb used to get directly), ``run``
+(run-level config, emitted once).
 Sinks must tolerate kinds they don't know: the set is open.
 
 Multi-host: every host writes its OWN file (``telemetry.host{k}.jsonl``,
@@ -97,7 +97,7 @@ import numpy as np
 # for one host (tail or push transport, event + torn-line counts —
 # can_tpu_collector_events_total{host}).
 EVENT_KINDS = ("compile", "step_window", "stall", "memory", "heartbeat",
-               "epoch", "bench", "run",
+               "epoch", "run",
                "serve.request", "serve.batch", "serve.reject",
                "serve.warmup",
                "fleet.replica", "fleet.rollout",

@@ -39,9 +39,8 @@ Everything surfaces as ``perf.summary`` events (per-epoch in the loops,
 periodic in serve): numeric payload keys become ``can_tpu_mfu_*`` /
 ``can_tpu_roofline_*`` / ``can_tpu_launch_cost_*`` gauges via the
 exporter's ``GaugeSink``, and the ``detail`` rows feed
-``tools/telemetry_report.py`` and the bench suite's perf tier.  A run
-without telemetry constructs no ledger — the default hot path is
-untouched.
+``tools/telemetry_report.py``.  A run without telemetry constructs no
+ledger — the default hot path is untouched.
 """
 
 from __future__ import annotations
@@ -414,7 +413,7 @@ class ProgramCostLedger:
     def emit_summary(self, telemetry, *, step: Optional[int] = None,
                      phase: str = "") -> dict:
         """One ``perf.summary`` event: the aggregate payload (gauge feed)
-        plus the per-program ``detail`` rows (report/bench feed), both —
+        plus the per-program ``detail`` rows (the report's feed), both —
         including the launch-cost fit — from the same snapshot."""
         snap = self._snapshot()
         rows = self.rows(snap)

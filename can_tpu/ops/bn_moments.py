@@ -146,7 +146,7 @@ class BNOps:
     ``masked_moments(yf, m, axes) -> (mean, biased_var, global_s0)`` and
     ``global_moments(yf, axes) -> (mean, biased_var)`` — both f32 in/out.
     ``impl`` is the CLI-facing name; ``interpret`` runs the Pallas kernel
-    in interpreter mode (CPU tests / benches).
+    in interpreter mode (CPU tests).
     """
 
     impl: str = "twopass"

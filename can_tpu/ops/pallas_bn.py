@@ -20,16 +20,16 @@ per-device; pallas_call runs on each device's local block).
 Normalize-scale-shift(+ReLU) is deliberately NOT in the kernel: it is a
 per-element affine of the SAME activation the next conv consumes, and XLA
 already fuses that chain into the consumer (verified per-program via the
-PR-6 cost ledger — see the bn bench tier, bytes do not move when the
-affine is pulled in by hand).  Gradients come from a custom VJP that
-re-differentiates the jnp twin (``masked_moment_sums``) — the residuals
+PR-6 cost ledger: bytes do not move when the affine is pulled in by
+hand).  Gradients come from a custom VJP that re-differentiates the
+jnp twin (``masked_moment_sums``) — the residuals
 are just the kernel inputs, no extra HBM, exactly the
 ``ops/pallas_context.py`` fallback discipline.
 
 Constraints (else callers fall back to the jnp one-pass): C a multiple of
 128 lanes (the C=128+ frontend/backend layers; the C=64 stem layers fall
 back), W a multiple of 8.  ``interpret=True`` runs anywhere (CPU
-parity tests and the bench tier's pallas-interpret variant).
+parity tests).
 """
 
 from __future__ import annotations

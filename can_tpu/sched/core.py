@@ -112,9 +112,9 @@ def _cover_costs(max_n: int, menu: Tuple[int, ...],
 
 def costs_match(predicted, realized, *, tol: float = 1e-6) -> bool:
     """THE predicted==realized comparison, owned by the module that owns
-    the invariant: the gauge sink, the report, and the bench receipt all
-    call this — three hand-rolled epsilon checks could silently disagree
-    about whether the invariant held."""
+    the invariant: the gauge sink and the report both call this — two
+    hand-rolled epsilon checks could silently disagree about whether the
+    invariant held."""
     if predicted is None or realized is None:
         return True  # pre-r14 events carry no cost pair: nothing to judge
     return abs(float(predicted) - float(realized)) <= tol
@@ -177,9 +177,9 @@ class ServeSched:
     on every ``serve.batch`` event).
 
     max_wait_s is the latency CAP the priced deadline can never exceed
-    (the old timer's only surviving role); ``priced_flush=False`` keeps
-    the timer as the flush trigger while the menu still prices sizes
-    (the legacy escape hatch the CLI's ``--flush-policy timer`` wires).
+    (the timer's only role here); ``priced_flush=False`` keeps the timer
+    as the flush trigger while the menu still prices sizes (tests only:
+    no CLI reaches it).
 
     kinds: the request kinds whose launches this instance is to price;
     one whose ``cost_unit`` is not ``COST_UNIT`` is refused (its menu and
@@ -384,7 +384,8 @@ def offline_planner(model: PlanCostModel, *, max_buckets: int,
     """The offline engine's entry into the core: exactly the r8
     ``GlobalPlanner`` over the shared cost model — plans are BIT-
     identical to constructing it directly (pinned by the legacy
-    comparator in tests/test_sched.py), so PLAN_ABLATION_r08 reproduces.
+    comparator in tests/test_sched.py, which reproduces the golden plan
+    tests/fixtures/PLAN_ABLATION_r08.json).
     Routing construction through the core is what lets the audit and the
     gauges treat 'the planner every consumer uses' as one object."""
     return GlobalPlanner(model, max_buckets=max_buckets, mode=mode,
@@ -398,9 +399,8 @@ def prefetch_depth(launch_px: float, launch_cost_px: float, *,
     device compute.  A launch whose fixed cost is a large fraction of
     its compute (tiny batches) needs deeper pipelining; big launches
     need only the classic double buffer.  ``1 + ceil(launch_cost /
-    launch_compute)`` clamped to [lo, hi] — at the bench pricing
-    (0.05 Mpx launch, ~1 Mpx batches) this is exactly the historical
-    depth=2, so default behaviour is unchanged."""
+    launch_compute)`` clamped to [lo, hi] — at a 0.05 Mpx launch and
+    ~1 Mpx batches this is the classic depth=2."""
     px = max(float(launch_px), 1.0)
     depth = 1 + int(-(-float(launch_cost_px) // px))
     return max(int(lo), min(int(hi), depth))

@@ -21,7 +21,7 @@ Bounds are hard: never below ``min_replicas`` (and never below 1 live),
 never above ``max_replicas`` or the fleet's device universe.
 
 With an AOT bundle loaded on the fleet, a scale-up is executables
-deserialised, not compiled — the seconds-to-ready the bench tier records
+deserialised, not compiled — the seconds-to-ready ``fleet.scale`` reports
 as ``time_to_first_ready_s``.
 """
 

@@ -33,8 +33,10 @@ until a replica completes it, may be redispatched, and a wedged replica
 may still be reading it) every launch is assembled fresh, and the batch is
 its receiver's.  ``staging`` counts launches by which of the two it was.
 
-Since round 14 the flush policy and launch sizes come from the shared
-scheduling core (``can_tpu/sched``) when a ``ServeSched`` is given:
+The flush policy and launch sizes come from the shared scheduling core
+(``can_tpu/sched``) when a ``ServeSched`` is given, which is whenever the
+core can price every kind the service serves (``CountService`` decides;
+images: the benchmark's ``serve-shb-closed``):
 
 * a bucket's group flushes the moment it holds the TOP menu size (the
   batch is full — waiting longer buys nothing);
@@ -43,8 +45,8 @@ scheduling core (``can_tpu/sched``) when a ``ServeSched`` is given:
   request cannot beat launch-cost amortization or when the bucket's
   observed arrival rate says no request is expected inside the window;
   at the latency cap (``max_wait_ms``) or the group's deadline slack
-  otherwise — with no rate estimate yet the priced deadline IS the old
-  timer, so cold behaviour is unchanged;
+  otherwise — with no rate estimate yet the priced deadline is the
+  ``max_wait_ms`` timer;
 * a flush is covered by the core's menu parts (the planner's exact
   ``decompose`` DP): a 2-request flush launches a 2-slot program
   instead of padding to ``max_batch`` (fill slots remain
@@ -52,15 +54,17 @@ scheduling core (``can_tpu/sched``) when a ``ServeSched`` is given:
   emitted size is a menu size — the XLA compile count is
   ``buckets x dtypes x menu sizes``, static and warmed up front.
 
-Without a ``sched`` the pre-r14 behaviour is preserved exactly: pad
-every flush to ``max_batch``, flush on the ``max_wait_ms`` timer (the
-bit-compatible baseline the tests and the bench's legacy arm drive).
+Without a ``sched`` (a service with a kind the core cannot price: a
+launch of prompts costs decode steps, not slots x pixels) there is one
+launch size and the timer: every flush pads to ``max_batch``, and a group
+that is not full flushes ``max_wait_ms`` after its first request (the
+benchmark's ``serve-exaone-chat-closed`` runs this side).
 
 The pump wakes EXACTLY at the earliest pending flush deadline (or on
 arrival, via the queue's condition) — never on a fixed poll grain: with
-priced deadlines that can be "now", a 50 ms idle poll would have eaten
-the entire low-load latency win, and even under the timer policy a poll
-interval above a short ``max_wait_ms`` silently inflated the tail.
+priced deadlines that can be "now", a 50 ms idle poll would eat the
+entire low-load latency win, and under the timer a poll interval above a
+short ``max_wait_ms`` would inflate the tail.
 
 Requests whose deadline expires before dispatch are rejected, never
 launched: a result the client has already given up on still costs a full

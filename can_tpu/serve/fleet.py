@@ -239,12 +239,8 @@ class FleetEngine:
                  watchdog_slack: float = 10.0,
                  watchdog_floor_s: float = 1.0,
                  watchdog_default_s: float = 30.0,
-                 dispatch_order: str = "priced",
                  starvation_age_s: float = 2.0,
                  deadline_pressure_s: float = 0.5):
-        if dispatch_order not in ("priced", "fifo"):
-            raise ValueError(f"unknown dispatch_order {dispatch_order!r} "
-                             f"(priced | fifo)")
         if replicas < 1:
             raise ValueError(f"replicas must be >= 1, got {replicas}")
         devices = list(devices if devices is not None else jax.devices())
@@ -290,9 +286,8 @@ class FleetEngine:
         # jitter is seeded per fleet: chaos tests reproduce bit-exactly
         self._rng = random.Random(0xC0FFEE)
         # shared-queue dispatch ordering (can_tpu/sched.pick_work):
-        # "priced" = cheapest-feasible-first under deadline pressure with
-        # the starvation age bound; "fifo" = the pre-r14 pure FIFO
-        self.dispatch_order = dispatch_order
+        # cheapest-feasible-first under deadline pressure with the
+        # starvation age bound
         self.starvation_age_s = float(starvation_age_s)
         self.deadline_pressure_s = float(deadline_pressure_s)
         self._work_seq = 0
@@ -514,10 +509,10 @@ class FleetEngine:
         """Next work item under ``_cond``: the scheduling core's priced
         order (urgent deadline-pressured work EDF-first, the rest
         cheapest-first, age-promoted against starvation, stream pins as
-        an affinity preference for the pulling replica) — or plain FIFO
-        when configured.  A redispatched batch sits at the queue FRONT
-        and is also urgent-class, so both orders serve it first."""
-        if self.dispatch_order == "fifo" or len(self._queue) == 1:
+        an affinity preference for the pulling replica).  A redispatched
+        batch sits at the queue FRONT and is also urgent-class, so it is
+        served first."""
+        if len(self._queue) == 1:
             return self._queue.popleft()
         from can_tpu.sched import pick_work
 
@@ -958,8 +953,7 @@ class FleetEngine:
         current generation, warmed before it joins dispatch — zero-drop
         by construction (the shared queue never assigned it work until
         its worker starts pulling).  Returns the scale report (also
-        emitted as ``fleet.scale``, with ``time_to_first_ready_s`` the
-        bench tier records).
+        emitted as ``fleet.scale``, with ``time_to_first_ready_s``).
 
         The staging warmup — device work that can hang on a sick spare
         device — runs under ``_scale_lock`` only: probes, rollout, and

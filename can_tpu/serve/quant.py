@@ -30,8 +30,8 @@ three modes and the jit signature differs only via the stored leaves.
 
 The parity cost of each mode is measured, not assumed: ``parity_report``
 runs the same images through a quantized engine and the f32 reference and
-grades the worst count delta against ``PARITY_LADDER`` — the graded rung
-is committed with every ``BENCH_SERVE_FLEET_*`` artifact.
+grades the worst count delta against ``PARITY_LADDER`` (tests/test_fleet.py
+holds every mode to its rung).
 """
 
 from __future__ import annotations
@@ -160,7 +160,7 @@ def host_tree(tree):
 
 def param_bytes(tree) -> int:
     """Device-resident parameter bytes of a storage tree (the HBM the
-    mode actually holds — the artifact's compression receipt)."""
+    mode actually holds)."""
     return sum(int(np.prod(x.shape)) * jnp.asarray(x).dtype.itemsize
                for x in jax.tree.leaves(tree))
 

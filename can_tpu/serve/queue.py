@@ -68,8 +68,8 @@ class ServeResult:
     bucket_hw: Tuple[int, int]           # static shape the batch ran at
     batch_fill: float                    # valid / total slots of its batch
     latency_s: float                     # submit -> resolve wall time
-    # latency breakdown (from the span timestamps; the bench's
-    # queue_wait_p95 and the HTTP trace_id ride these)
+    # latency breakdown (from the span timestamps; the HTTP response's
+    # queue_wait_ms and trace_id ride these)
     queue_wait_s: Optional[float] = None  # submit -> batch assembly start
     device_s: Optional[float] = None      # engine execute wall time
     trace_id: Optional[str] = None        # the request's span-tree id

@@ -7,8 +7,8 @@ Wires the pieces into one lifecycle::
                                                       ServeEngine.predict_batch
                                                    -> resolve ServeRequests
 
-``submit()/result()`` is the primary API — tests and the bench drive the
-full stack through it with zero networking.  The HTTP front end
+``submit()/result()`` is the primary API — tests and the benchmark drive
+the full stack through it with zero networking.  The HTTP front end
 (``serve_http``) is a thin stdlib adapter over the same calls: one process,
 one device owner, many client connections.
 
@@ -152,12 +152,13 @@ class CountService:
                              f"(priced | timer)")
         self.engine = engine
         # the scheduling core (can_tpu/sched): priced sub-batch menu +
-        # priced flush deadlines.  menu_budget=1 keeps the single
-        # max_batch-slot program; menu_budget=1 AND flush_policy="timer"
-        # is the bit-compatible pre-r14 service (sched=None entirely).
-        # Left to the service (None), both follow from the request kinds
-        # it serves: the core where it can price every one of them, else
-        # one launch size under the timer.  Asked for a kind it cannot
+        # priced flush deadlines.  Which side runs follows from the
+        # request kinds the service serves: the core where it can price
+        # every one of them (images), else no core at all (sched=None):
+        # one launch size of max_batch slots, flushed by the max_wait_ms
+        # timer (a language model; serve-exaone-chat-closed).  The two
+        # keywords are for tests that want a core-less service or one
+        # menu size; no CLI reaches them.  Asked for a kind it cannot
         # price, the core refuses (ValueError).
         from can_tpu.sched import COST_UNIT, DEFAULT_MENU_BUDGET, ServeSched
         from can_tpu.serve.kinds import ImageKind

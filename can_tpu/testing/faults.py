@@ -64,10 +64,10 @@ point every worker at it).  Schema::
   gate (duplicate/out-of-order rejection, never double-serve) runs for
   real.  Fires once.
 
-The stream kinds are directives to the DRIVER (the chaos test's and
-bench tier's stream load generators call ``on_stream_frame`` before
-each submit and perturb their own traffic), because arrival timing and
-frame ordering belong to the client side of the protocol — the serving
+The stream kinds are directives to the DRIVER (the chaos test's stream
+load generator calls ``on_stream_frame`` before each submit and
+perturbs its own traffic), because arrival timing and frame ordering
+belong to the client side of the protocol — the serving
 stack under test must see them arrive exactly as a misbehaving camera
 would send them.
 

@@ -1,4 +1,4 @@
-"""Persistent XLA compilation cache (on by default in the CLIs/benches).
+"""Persistent XLA compilation cache (on by default in the CLIs).
 
 The bucketed variable-resolution configs compile one program per bucket
 shape, and without a persistent cache that bill is repaid on EVERY fresh

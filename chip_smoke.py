@@ -334,9 +334,8 @@ def phase_serve(name: str, device: dict, *, data: str, ckpt: str,
             "50", "--port", str(port), "--seed", str(SEED),
             "--telemetry-dir", os.path.join(WORK, f"tel_{name}")]
     if replicas > 1:
-        # one launch size per replica: every program compiles once PER
-        # DEVICE, and the one-chip leg already covers the priced menu
-        argv += ["--replicas", str(replicas), "--menu-budget", "1"]
+        # every program of the priced menu compiles once PER DEVICE
+        argv += ["--replicas", str(replicas)]
     t0 = time.monotonic()
     log = open(log_path, "wb")
     proc = subprocess.Popen(argv, cwd=ROOT, env=child_env(), stdout=log,

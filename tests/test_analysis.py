@@ -585,7 +585,7 @@ class TestBaseline:
 
 class TestTreeIsClean:
     def test_real_tree_zero_unbaselined_findings(self):
-        """THE acceptance pin: the library + bench + tools lint clean
+        """THE acceptance pin: the library + tools lint clean
         (in-source pragmas carry their reasons; the committed baseline
         covers the rest — currently nothing)."""
         findings, suppressed = sl.lint_paths(REPO)

@@ -22,7 +22,6 @@ The contract under test (acceptance criteria):
 import gc
 import json
 import os
-import subprocess
 import threading
 import time
 
@@ -47,8 +46,6 @@ from can_tpu.serve import (
 )
 from can_tpu.serve.autoscale import decide
 from can_tpu.testing import faults
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
@@ -1202,53 +1199,3 @@ class TestCLI:
                 build_service(parse_args(["--replicas", "2",
                                           "--autoscale-max", "3",
                                           "--autoscale-min", bad]))
-
-
-# --- committed bench artifact + CI gate ---------------------------------
-class TestArtifactsAndGate:
-    TIER = os.path.join(REPO, "BENCH_AUTOSCALE_cpu_r13.json")
-
-    def test_autoscale_tier_artifact_schema(self):
-        doc = json.load(open(self.TIER))
-        assert doc["metric"] == "serve_autoscale"
-        metrics = {r["metric"]: r for r in doc["results"]}
-        cold = metrics["serve_autoscale_ttfr_cold"]
-        aot = metrics["serve_autoscale_ttfr_aot"]
-        p99 = metrics["serve_autoscale_p99_scaleup"]
-        assert cold["unit"] == "s" and aot["unit"] == "s"
-        assert p99["unit"] == "ms" and p99["value"] > 0
-        # THE acceptance receipts: AOT reaches ready faster than cold,
-        # with zero new compiles; the scale-up dropped nothing
-        assert aot["value"] < cold["value"]
-        assert aot["compiles"] == 0 and cold["compiles"] > 0
-        assert p99["rejects"] == 0
-        assert all(c == 0 for c in p99["scale_compiles"])
-        assert all(s > 0 for s in p99["scale_ttfr_s"])
-        for r in (cold, aot, p99):
-            assert r["spread_pct"] is not None  # the gate's noise floor
-
-    def test_ci_gate_compare_only_self_compare_passes(self):
-        gate = os.path.join(REPO, "tools", "ci_bench_gate.sh")
-        r = subprocess.run(
-            ["sh", gate, self.TIER],
-            capture_output=True, text=True, cwd=REPO,
-            env=dict(os.environ, CI_BENCH_SKIP_RUN="1",
-                     CI_BENCH_OUT=self.TIER, CI_BENCH_ONLY="autoscale",
-                     CI_MIN_OVERLAP="3", JAX_PLATFORMS="cpu"))
-        assert r.returncode == 0, r.stdout + r.stderr
-        assert "no regressions" in r.stdout
-
-    def test_seconds_unit_gates_as_duration(self):
-        """time_to_first_ready_s regresses UP (unit s is a duration in
-        bench_compare's direction table): slower recovery trips, faster
-        never does."""
-        from tools.bench_compare import compare
-
-        old = {"m": {"metric": "m", "value": 1.0, "unit": "s",
-                     "spread_pct": 10.0}}
-        up = {"m": {"metric": "m", "value": 2.0, "unit": "s",
-                    "spread_pct": 10.0}}
-        down = {"m": {"metric": "m", "value": 0.2, "unit": "s",
-                      "spread_pct": 10.0}}
-        assert compare(old, up)[0]["verdict"] == "regression"
-        assert compare(old, down)[0]["verdict"] == "improved"

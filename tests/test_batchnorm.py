@@ -682,82 +682,6 @@ class TestBNImplDefaultByteIdentity:
         assert base == hooked
 
 
-class TestBNBenchArtifact:
-    """The committed bn-tier artifact (BENCH_BN_cpu_r10.json) and its
-    gate: the acceptance pin is per-program cost_analysis bytes STRICTLY
-    lower for onepass than the two-pass baseline."""
-
-    ARTIFACT = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "BENCH_BN_cpu_r10.json")
-
-    def _doc(self):
-        import json
-
-        with open(self.ARTIFACT) as f:
-            return json.load(f)
-
-    def test_artifact_schema(self):
-        doc = self._doc()
-        assert doc["metric"] == "bench_bn"
-        variants = {r.get("variant") for r in doc["results"]
-                    if r["unit"] == "gbytes"}
-        assert {"plain", "syncbn_twopass", "syncbn_onepass",
-                "syncbn_pallas"} <= variants
-        for r in doc["results"]:
-            assert r["unit"] in ("gflops", "gbytes") and r["value"] > 0
-
-    def test_onepass_strictly_fewer_bytes_than_twopass(self):
-        """ISSUE 7 acceptance: the ledger artifact shows strictly fewer
-        HBM bytes per syncbn train-step program than the committed
-        two-pass baseline."""
-        doc = self._doc()
-        by_variant = {r["variant"]: r["value"] for r in doc["results"]
-                      if r["unit"] == "gbytes"}
-        assert by_variant["syncbn_onepass"] < by_variant["syncbn_twopass"]
-        # and the flops must be ~the same work (the path sheds bytes,
-        # not layers): within 1%
-        one = next(r["value"] for r in doc["results"]
-                   if r["unit"] == "gflops" and "onepass" in r["metric"])
-        two = next(r["value"] for r in doc["results"]
-                   if r["unit"] == "gflops" and "twopass" in r["metric"])
-        assert abs(one - two) / two < 0.01
-
-    def test_gbytes_unit_gates_upward_only(self):
-        """bench_compare direction rule for the new unit: bytes growing
-        beyond the floor = regression (lost fusion); shrinking = the
-        improvement this tier exists to bank.  The floor is the
-        DETERMINISTIC one (0.1%, not the 10% timing default): the
-        onepass-vs-twopass delta this gate holds is ~2%, so a lost
-        fusion of that size must trip."""
-        from tools.bench_compare import compare
-
-        old = {"m": {"metric": "m", "value": 1.5, "unit": "gbytes"}}
-        up = {"m": {"metric": "m", "value": 2.0, "unit": "gbytes"}}
-        down = {"m": {"metric": "m", "value": 1.0, "unit": "gbytes"}}
-        assert compare(old, up)[0]["verdict"] == "regression"
-        assert compare(old, down)[0]["verdict"] == "improved"
-        # a 2% creep — exactly a lost onepass fusion — is NOT noise
-        creep = {"m": {"metric": "m", "value": 1.53, "unit": "gbytes"}}
-        assert compare(old, creep)[0]["verdict"] == "regression"
-        same = {"m": {"metric": "m", "value": 1.5, "unit": "gbytes"}}
-        assert compare(old, same)[0]["verdict"] == "ok"
-
-    def test_ci_gate_compare_only_self_compare_passes(self):
-        import subprocess
-
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        gate = os.path.join(repo, "tools", "ci_bench_gate.sh")
-        r = subprocess.run(
-            ["sh", gate, self.ARTIFACT],
-            capture_output=True, text=True, cwd=repo,
-            env=dict(os.environ, CI_BENCH_SKIP_RUN="1",
-                     CI_BENCH_OUT=self.ARTIFACT, CI_BENCH_ONLY="bn",
-                     CI_MIN_OVERLAP="4", JAX_PLATFORMS="cpu"))
-        assert r.returncode == 0, r.stdout + r.stderr
-        assert "no regressions" in r.stdout
-
-
 class TestSyncBN:
     def test_sharded_train_step_is_syncbn(self):
         """BN stats from the dp=8-sharded batch equal full-batch stats: the

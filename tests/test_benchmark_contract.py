@@ -1,0 +1,232 @@
+"""What ``benchmark/`` takes from the package, checked on the CPU.
+
+The benchmark (``BENCHMARK.json``, ``python3 -m benchmark.run``) is run on the
+chip by the driver after a PR is handed in, and most PRs may not edit it.  It
+imports names from ``can_tpu``, passes fixed keywords to constructors and
+functions, and calls methods on the objects it is handed.  A refactor that
+renames one of those is otherwise found on the chip, as a refused PR.
+
+Everything here is read from the benchmark's own source with ``ast`` at
+import time (``benchmark/tests/`` left out: those are its tests, not what the
+driver runs) and resolved against the package with ``importlib`` /
+``inspect``: one case per imported name, per keyword of a call to an imported
+name, per attribute used on a service or an engine, per key read from
+``stats()``.  Nothing of the benchmark is imported or run.
+"""
+
+import ast
+import importlib
+import inspect
+import os
+import re
+import types
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(REPO, "benchmark")
+SERVE_DRIVER = "harness/drive_serve.py"
+LM_DRIVER = "harness/drive_lm_serve.py"
+
+
+def _sources():
+    """{path relative to benchmark/: tree} of every file the driver can run."""
+    out = {}
+    for root, dirs, files in os.walk(BENCH):
+        dirs[:] = sorted(d for d in dirs if d != "__pycache__" and not (
+            root == BENCH and d == "tests"))
+        for f in sorted(files):
+            if f.endswith(".py"):
+                path = os.path.join(root, f)
+                with open(path) as fh:
+                    out[os.path.relpath(path, BENCH)] = ast.parse(fh.read(),
+                                                                  path)
+    return out
+
+
+SOURCES = _sources()
+
+
+def _package_imports(tree):
+    """{local name: (module, name)} of a file's ``from can_tpu... import``."""
+    return {a.asname or a.name: (n.module, a.name)
+            for n in ast.walk(tree) if isinstance(n, ast.ImportFrom)
+            and n.module and n.module.split(".")[0] == "can_tpu"
+            for a in n.names}
+
+
+def _imported():
+    return sorted({pair for tree in SOURCES.values()
+                   for pair in _package_imports(tree).values()})
+
+
+def _call_keywords():
+    """(module, name, keyword) of every keyword a benchmark file passes to a
+    name it imported from the package."""
+    out = set()
+    for tree in SOURCES.values():
+        names = _package_imports(tree)
+        for n in ast.walk(tree):
+            if (isinstance(n, ast.Call) and isinstance(n.func, ast.Name)
+                    and n.func.id in names):
+                out.update((*names[n.func.id], k.arg)
+                           for k in n.keywords if k.arg)
+    return sorted(out)
+
+
+def _uses(rel, holder):
+    """Attribute names a driver reads or calls on ``holder`` (a dotted
+    expression such as ``service`` or ``self._engine``)."""
+    return {n.attr for n in ast.walk(SOURCES[rel])
+            if isinstance(n, ast.Attribute) and ast.unparse(n.value) == holder}
+
+
+def _probe_own(rel):
+    """What a driver's ``EngineProbe`` defines itself (``engine.launches``
+    and the like are the probe's, not the engine's)."""
+    cls = next(n for n in ast.walk(SOURCES[rel])
+               if isinstance(n, ast.ClassDef) and n.name == "EngineProbe")
+    own = {m.name for m in cls.body if isinstance(m, ast.FunctionDef)}
+    own |= {c.args[1].value for c in ast.walk(cls)
+            if isinstance(c, ast.Call)
+            and ast.unparse(c.func) == "object.__setattr__"
+            and isinstance(c.args[1], ast.Constant)}
+    return own
+
+
+def _method_keywords(rel, holder):
+    return {(n.func.attr, k.arg) for n in ast.walk(SOURCES[rel])
+            if isinstance(n, ast.Call) and isinstance(n.func, ast.Attribute)
+            and ast.unparse(n.func.value) == holder
+            for k in n.keywords if k.arg}
+
+
+def _stats_keys(rel):
+    return {n.slice.value for n in ast.walk(SOURCES[rel])
+            if isinstance(n, ast.Subscript) and isinstance(n.value, ast.Name)
+            and n.value.id in ("stats0", "stats1")
+            and isinstance(n.slice, ast.Constant)}
+
+
+# which classes a driver's ``service`` and ``engine`` are: the CANNet driver
+# names its own; the language-model driver is handed what
+# ``build_model_service`` builds for ``exaone_moe``
+ROLES = {SERVE_DRIVER: ("CountService", "ServeEngine"),
+         LM_DRIVER: ("GenerateService", "LMEngine")}
+# read THROUGH the probe by the service (``EngineProbe.__getattr__`` forwards
+# to the engine), so no benchmark file spells them
+FORWARDED = ("last_batch_compiled", "launches_in_flight")
+
+
+def _attribute_cases():
+    out = set()
+    for rel, (service, engine) in ROLES.items():
+        out |= {(service, a) for a in _uses(rel, "service")}
+        on_engine = _uses(rel, "self._engine") | (
+            _uses(rel, "engine") - _probe_own(rel))
+        out |= {(engine, a) for a in on_engine | set(FORWARDED)}
+    return sorted(out)
+
+
+def _method_keyword_cases():
+    return sorted({(ROLES[rel][0], m, k) for rel in ROLES
+                   for m, k in _method_keywords(rel, "service")})
+
+
+def _stats_cases():
+    return sorted({(ROLES[rel][0], k) for rel in ROLES
+                   for k in _stats_keys(rel)})
+
+
+def _resolve(module, name):
+    mod = importlib.import_module(module)
+    if hasattr(mod, name):
+        return getattr(mod, name)
+    return importlib.import_module(f"{module}.{name}")
+
+
+def _serve_class(name):
+    from can_tpu.serve import engine, service
+
+    return getattr(service if name.endswith("Service") else engine, name)
+
+
+def _has(cls, attr):
+    """A method, property or class attribute, or an attribute an
+    ``__init__`` of the class sets."""
+    if hasattr(cls, attr):
+        return True
+    assigns = re.compile(rf"\bself\.{re.escape(attr)}\b[^=\n]*=(?!=)")
+    return any(assigns.search(inspect.getsource(c))
+               for c in cls.__mro__ if c is not object)
+
+
+def _accepts(fn, keyword):
+    return keyword in inspect.signature(fn).parameters
+
+
+def test_the_collection_found_the_benchmark():
+    # an empty collection would pass vacuously: the drivers are there and
+    # each list holds what the drivers are known to use
+    assert {SERVE_DRIVER, LM_DRIVER, "harness/drive_train.py",
+            "run.py"} <= set(SOURCES)
+    assert ("can_tpu.serve", "RejectedError") in _imported()
+    assert ("can_tpu.serve", "CountService", "max_batch") in _call_keywords()
+    assert ("CountService", "warmup") in _attribute_cases()
+    assert ("ServeEngine", "predict_batch") in _attribute_cases()
+    assert ("CountService", "batch_slots") in _stats_cases()
+    assert not any(rel.startswith("tests") for rel in SOURCES)
+
+
+@pytest.mark.parametrize("module,name", _imported(),
+                         ids=lambda v: v.replace("can_tpu.", ""))
+def test_an_imported_name_resolves(module, name):
+    assert _resolve(module, name) is not None
+
+
+@pytest.mark.parametrize("module,name,keyword", _call_keywords(),
+                         ids=lambda v: v.replace("can_tpu.", ""))
+def test_a_call_s_keyword_is_accepted(module, name, keyword):
+    assert _accepts(_resolve(module, name), keyword), (
+        f"benchmark/ calls {module}.{name}({keyword}=...)")
+
+
+@pytest.mark.parametrize("cls,attr", _attribute_cases())
+def test_an_attribute_the_drivers_use_exists(cls, attr):
+    assert _has(_serve_class(cls), attr), f"{cls}.{attr}"
+
+
+def test_the_menu_the_serve_driver_prints_exists():
+    from can_tpu.sched import ServeSched
+
+    assert "menu" in _uses(SERVE_DRIVER, "service.sched")
+    assert ServeSched(4, max_wait_s=0.005).menu[0] == 4
+
+
+@pytest.mark.parametrize("cls,method,keyword", _method_keyword_cases())
+def test_a_method_s_keyword_is_accepted(cls, method, keyword):
+    assert _accepts(getattr(_serve_class(cls), method), keyword), (
+        f"{cls}.{method}({keyword}=...)")
+
+
+@pytest.fixture(scope="module")
+def stats_of():
+    """``stats()`` of each service class over a stub engine: building a
+    service runs no program."""
+    from can_tpu.obs import Telemetry
+    from can_tpu.serve.service import CountService, GenerateService
+
+    engine = types.SimpleNamespace(telemetry=Telemetry(), compile_count=0,
+                                   ds=8, counters={})
+    services = {
+        "CountService": CountService(engine, max_batch=4),
+        "GenerateService": GenerateService(engine, length_ladder=(16,),
+                                           max_batch=4)}
+    yield {name: s.stats() for name, s in services.items()}
+    for s in services.values():
+        s.close()
+
+
+@pytest.mark.parametrize("cls,key", _stats_cases())
+def test_a_stats_key_the_drivers_read_is_there(stats_of, cls, key):
+    assert key in stats_of[cls]

@@ -713,8 +713,9 @@ class TestDeviceWatchdog:
         assert "should never" not in proc.stdout
 
     def test_on_timeout_emits_before_exit(self):
-        # bench.py uses this to leave a machine-readable null result in
-        # the driver's artifact instead of a bare rc=3 (r5)
+        # the timing tools (tools/launch_cost_probe.py, ablate_mfu.py) use
+        # this to leave a machine-readable null result instead of a bare
+        # rc=3
         import subprocess
         import sys
 

@@ -814,46 +814,3 @@ class TestCollectCli:
             assert m["hosts"]["0"]["pending"] == 0
         finally:
             pr.kill()
-
-
-# --- obsplane bench tier plumbing ----------------------------------------
-class TestObsplaneBenchGate:
-    def test_mb_unit_gates_upward_only(self):
-        from tools.bench_compare import _direction, compare
-
-        assert _direction("mb") == -1
-        old = {"m": {"metric": "m", "value": 100.0, "unit": "mb",
-                     "spread_pct": 2.0}}
-        grew = {"m": {"metric": "m", "value": 150.0, "unit": "mb",
-                      "spread_pct": 2.0}}
-        shrank = {"m": {"metric": "m", "value": 60.0, "unit": "mb",
-                        "spread_pct": 2.0}}
-        assert compare(old, grew)[0]["verdict"] == "regression"
-        assert compare(old, shrank)[0]["verdict"] == "improved"
-
-    def test_committed_artifact_schema(self):
-        with open(os.path.join(REPO, "BENCH_OBSPLANE_cpu_r16.json")) as f:
-            doc = json.load(f)
-        assert doc["metric"] == "obsplane"
-        assert doc["config"]["hosts"] == 4
-        recs = {r["metric"]: r for r in doc["results"]}
-        assert recs["obsplane_ingest_events_per_s"]["unit"] == "events/s"
-        assert recs["obsplane_rss_mb"]["unit"] == "mb"
-        assert recs["obsplane_scrape_ms"]["unit"] == "ms"
-        for r in recs.values():
-            assert r["value"] > 0 and "spread_pct" in r
-        # the tier exercised the engine, not just the parser
-        assert doc["config"]["evaluations"] > 0
-
-    def test_gate_self_compare(self):
-        """CI_BENCH_ONLY=obsplane compare-only mode: the committed
-        artifact vs itself exits 0 (the gate plumbing works end to
-        end, including the no-self-overwrite OUT routing)."""
-        baseline = os.path.join(REPO, "BENCH_OBSPLANE_cpu_r16.json")
-        env = dict(os.environ, CI_BENCH_ONLY="obsplane",
-                   CI_BENCH_SKIP_RUN="1", CI_BENCH_OUT=baseline,
-                   CI_MIN_OVERLAP="3")
-        r = subprocess.run(
-            [os.path.join(REPO, "tools", "ci_bench_gate.sh"), baseline],
-            capture_output=True, text=True, env=env, cwd=REPO)
-        assert r.returncode == 0, r.stdout + r.stderr

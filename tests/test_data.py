@@ -879,7 +879,7 @@ class TestScheduleOverhead:
 
 
 def _bench_like_shapes(n=64, seed=0):
-    """The bench_suite distribution: 40% at a dominant resolution, the rest
+    """A Part-A-like distribution: 40% at a dominant resolution, the rest
     uniformly wild — the histogram real crowd datasets have."""
     rng = np.random.default_rng(seed)
     shapes = []
@@ -1076,9 +1076,8 @@ class TestRemnantSubBatches:
         assert big == (4096,)
 
     def test_launch_cost_prefers_fewer_batches(self):
-        # the measured reality behind the knob (tools/diag_remnant.py r4):
-        # where a step launch costs ~50 ms, the pixel optimum (many small
-        # sub-batches) LOSES throughput.  High
+        # the reason for the knob: where a step launch costs ~50 ms, the
+        # pixel optimum (many small sub-batches) LOSES throughput.  High
         # launch cost must recover exactly the legacy launch count; low
         # cost buys fewer dead slots with more launches.
         sizes = _bench_like_shapes()

@@ -3,7 +3,7 @@
 The REAL 2-process choreography — seeded SIGTERM kill, agreement,
 shrink checkpoint, re-rendezvous at dp', bit-identical continuation —
 lives in tests/test_multiprocess.py::test_elastic_shrink_and_continue
-(slow-marked; the CI_BENCH_ONLY=elastic gate runs it).  Here every
+(slow-marked).  Here every
 component is pinned in isolation:
 
 * signal files + elastic manifest (atomic, torn-safe, liveness rule);

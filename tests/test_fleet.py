@@ -672,84 +672,3 @@ class TestFleetCLI:
         with pytest.raises(SystemExit, match="replicas"):
             build_service(args)
 
-
-# --- committed artifacts + CI gate --------------------------------------
-import os  # noqa: E402
-import subprocess  # noqa: E402
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-class TestFleetArtifactsAndGate:
-    TIER = os.path.join(REPO, "BENCH_FLEET_cpu_r11.json")
-
-    def test_fleet_tier_artifact_schema(self):
-        doc = json.load(open(self.TIER))
-        assert doc["metric"] == "serve_fleet"
-        assert doc["config"]["replicas"] >= 2
-        metrics = {r["metric"]: r for r in doc["results"]}
-        for mode in ("f32", "bf16", "int8"):
-            p99 = metrics[f"serve_fleet_p99_{mode}"]
-            rps = metrics[f"serve_fleet_rps_{mode}"]
-            assert p99["unit"] == "ms" and p99["value"] > 0
-            assert rps["unit"] == "req/s" and rps["value"] > 0
-            assert p99["spread_pct"] is not None  # the gate's noise floor
-            assert p99["rejects"] == 0
-            assert p99["compiles_bounded"] is True
-            # work stealing: both replicas executed batches
-            assert all(b > 0 for b in p99["replica_batches"].values())
-            if mode != "f32":
-                assert p99["parity_grade"] in ("exact", "tight", "serve")
-        # the quantization receipt: int8 < bf16 < f32 resident bytes
-        assert (metrics["serve_fleet_p99_int8"]["param_bytes"]
-                < metrics["serve_fleet_p99_bf16"]["param_bytes"]
-                < metrics["serve_fleet_p99_f32"]["param_bytes"])
-
-    def test_bench_serve_fleet_artifacts_per_mode(self):
-        for mode in ("f32", "bf16", "int8"):
-            path = os.path.join(REPO, f"BENCH_SERVE_FLEET_cpu_{mode}.json")
-            doc = json.load(open(path))
-            assert doc["config"]["replicas"] >= 2
-            assert doc["config"]["serve_dtype"] == mode
-            assert doc["compiles_bounded"] is True
-            assert doc["open_loop"]["p99_ms"] > 0
-            assert doc["live_replicas"] >= 2
-            if mode == "f32":
-                assert "parity_vs_f32" not in doc
-            else:
-                par = doc["parity_vs_f32"]
-                assert par["grade"] != "fail"
-                assert [r["rung"] for r in par["ladder"]] == [
-                    "exact", "tight", "serve", "loose"]
-
-    def test_ci_gate_compare_only_self_compare_passes(self):
-        """The committed fleet baseline gates through
-        tools/ci_bench_gate.sh compare-only mode: self-compare = zero
-        regressions with full overlap (p99 rows gate upward-only on the
-        recorded spread floors, rps rows downward)."""
-        gate = os.path.join(REPO, "tools", "ci_bench_gate.sh")
-        r = subprocess.run(
-            ["sh", gate, self.TIER],
-            capture_output=True, text=True, cwd=REPO,
-            env=dict(os.environ, CI_BENCH_SKIP_RUN="1",
-                     CI_BENCH_OUT=self.TIER, CI_BENCH_ONLY="fleet",
-                     CI_MIN_OVERLAP="4", JAX_PLATFORMS="cpu"))
-        assert r.returncode == 0, r.stdout + r.stderr
-        assert "no regressions" in r.stdout
-
-    def test_ms_unit_gates_upward_only(self):
-        """Latency regresses UP: a p99 drop is an improvement, never a
-        trip; a rise beyond the recorded spread floor trips."""
-        from tools.bench_compare import compare
-
-        old = {"m": {"metric": "m", "value": 100.0, "unit": "ms",
-                     "spread_pct": 20.0}}
-        up = {"m": {"metric": "m", "value": 150.0, "unit": "ms",
-                    "spread_pct": 20.0}}
-        down = {"m": {"metric": "m", "value": 50.0, "unit": "ms",
-                      "spread_pct": 20.0}}
-        inside = {"m": {"metric": "m", "value": 115.0, "unit": "ms",
-                        "spread_pct": 20.0}}
-        assert compare(old, up)[0]["verdict"] == "regression"
-        assert compare(old, down)[0]["verdict"] == "improved"
-        assert compare(old, inside)[0]["verdict"] == "ok"

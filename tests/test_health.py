@@ -1,6 +1,5 @@
 """Run-health layer tests: detectors, monitor wiring in the train loop,
-cross-host run_monitor, the /metrics exporter, and the bench regression
-gate.
+cross-host run_monitor, and the /metrics exporter.
 
 Tier-1 contracts pinned here:
 
@@ -13,9 +12,7 @@ Tier-1 contracts pinned here:
 * a synthesized 2-host run with one straggler and one dead host is
   flagged by ``tools/run_monitor.py``;
 * a live /metrics scrape parses as Prometheus text and carries the
-  step/loss/grad-norm gauges plus serve counters;
-* ``tools/bench_compare.py`` gates on regressions beyond the recorded
-  ``spread_pct`` noise floor and passes changes within it.
+  step/loss/grad-norm gauges plus serve counters.
 """
 
 import json
@@ -770,85 +767,3 @@ def suite(path, entries):
     with open(path, "w") as f:
         json.dump(doc, f)
     return str(path)
-
-
-class TestBenchCompare:
-    def test_verdicts_respect_the_spread_floor(self):
-        from tools.bench_compare import compare
-
-        old = {"a": {"metric": "a", "value": 100.0, "unit": "images/sec",
-                     "spread_pct": 20.0},
-               "b": {"metric": "b", "value": 100.0, "unit": "images/sec",
-                     "spread_pct": 5.0},
-               "c": {"metric": "c", "value": 10.0, "unit": "seconds"},
-               "gone": {"metric": "gone", "value": 1.0,
-                        "unit": "images/sec"}}
-        new = {"a": {"metric": "a", "value": 85.0, "unit": "images/sec",
-                     "spread_pct": 18.0},   # -15% inside the 20% spread
-               "b": {"metric": "b", "value": 80.0, "unit": "images/sec",
-                     "spread_pct": 6.0},    # -20% beyond max(5,6,10)
-               "c": {"metric": "c", "value": 13.0, "unit": "seconds"},
-               # +30% seconds beyond the 10% default floor: regression
-               "fresh": {"metric": "fresh", "value": 1.0,
-                         "unit": "images/sec"}}
-        rows = {r["metric"]: r for r in compare(old, new)}
-        assert rows["a"]["verdict"] == "ok"
-        assert rows["b"]["verdict"] == "regression"
-        assert rows["c"]["verdict"] == "regression"  # lower-better unit
-        assert rows["gone"]["verdict"] == "removed"
-        assert rows["fresh"]["verdict"] == "added"
-
-    def test_improvement_and_null_results(self):
-        from tools.bench_compare import compare
-
-        old = {"a": {"metric": "a", "value": 100.0, "unit": "images/sec"},
-               "n": {"metric": "n", "value": None, "unit": "images/sec"}}
-        new = {"a": {"metric": "a", "value": 150.0, "unit": "images/sec"},
-               "n": {"metric": "n", "value": 5.0, "unit": "images/sec"}}
-        rows = {r["metric"]: r for r in compare(old, new)}
-        assert rows["a"]["verdict"] == "improved"
-        # a watchdog null result never gates
-        assert rows["n"]["verdict"] == "incomparable"
-
-    def test_load_suite_accepts_every_artifact_shape(self, tmp_path):
-        from tools.bench_compare import load_suite
-
-        # suite doc with "results"
-        p1 = suite(tmp_path / "s.json",
-                   [{"metric": "m", "value": 1.0, "unit": "images/sec"}])
-        assert "m" in load_suite(p1)
-        # single-record dict (BENCH_r*.json shape) — no raw KeyError
-        p2 = str(tmp_path / "one.json")
-        with open(p2, "w") as f:
-            json.dump({"metric": "m", "value": 2.0,
-                       "unit": "images/sec"}, f)
-        assert load_suite(p2)["m"]["value"] == 2.0
-        # JSONL (bench stdout piped to a file)
-        p3 = str(tmp_path / "lines.jsonl")
-        with open(p3, "w") as f:
-            f.write('{"metric": "a", "value": 1.0, "unit": "images/sec"}\n'
-                    '{"metric": "b", "value": 2.0, "unit": "seconds"}\n')
-        assert set(load_suite(p3)) == {"a", "b"}
-        # a dict with neither results nor metric: the clean error
-        p4 = str(tmp_path / "junk.json")
-        with open(p4, "w") as f:
-            json.dump({"irrelevant": True}, f)
-        with pytest.raises(SystemExit, match="no result records"):
-            load_suite(p4)
-
-    def test_cli_exit_codes_and_real_artifact(self, tmp_path):
-        from tools.bench_compare import main
-
-        base = [{"metric": "host_pipeline_x", "value": 100.0,
-                 "unit": "images/sec", "spread_pct": 15.0}]
-        old = suite(tmp_path / "old.json", base)
-        same = suite(tmp_path / "same.json",
-                     [dict(base[0], value=95.0)])    # within spread
-        worse = suite(tmp_path / "worse.json",
-                      [dict(base[0], value=60.0)])   # way beyond
-        assert main([old, same]) == 0
-        assert main([old, worse]) == 1
-        # the committed r07 artifact loads and self-compares clean
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        r07 = os.path.join(repo, "BENCH_SUITE_r07.json")
-        assert main([r07, r07]) == 0

@@ -665,6 +665,9 @@ class TestGradeEvents:
 
 
 # --- slo_report CLI ------------------------------------------------------
+SLO_FIXTURE = os.path.join("tests", "fixtures", "SLO_FIXTURE_cpu_r15.jsonl")
+
+
 def run_slo_report(*argv):
     tool = os.path.join(REPO, "tools", "slo_report.py")
     return subprocess.run([sys.executable, tool, *argv],
@@ -674,11 +677,9 @@ def run_slo_report(*argv):
 
 class TestSloReportCLI:
     def test_committed_fixture_passes_committed_spec(self):
-        """Artifact pin: the committed fleet-bench-era fixture grades
-        green against the committed example spec — exactly what the CI
-        gate (CI_BENCH_ONLY=slo) runs."""
-        r = run_slo_report("SLO_FIXTURE_cpu_r15.jsonl",
-                           "--spec", "slo_spec.json")
+        """Artifact pin: the committed fixture grades green against the
+        committed example spec."""
+        r = run_slo_report(SLO_FIXTURE, "--spec", "slo_spec.json")
         assert r.returncode == 0, r.stdout + r.stderr
         assert "PASS" in r.stdout
         # every committed objective was exercised by the fixture
@@ -690,13 +691,13 @@ class TestSloReportCLI:
         spec["objectives"][0]["burn_alert"] = 2.0
         p = tmp_path / "tight.json"
         p.write_text(json.dumps(spec))
-        r = run_slo_report("SLO_FIXTURE_cpu_r15.jsonl", "--spec", str(p))
+        r = run_slo_report(SLO_FIXTURE, "--spec", str(p))
         assert r.returncode == 1
         assert "VIOLATION serve_p99_deadline" in r.stdout
         assert "window 60+300" in r.stdout
 
     def test_usage_errors_exit_2(self, tmp_path):
-        r = run_slo_report("SLO_FIXTURE_cpu_r15.jsonl",
+        r = run_slo_report(SLO_FIXTURE,
                            "--spec", str(tmp_path / "absent.json"))
         assert r.returncode == 2
         bad = tmp_path / "bad.json"
@@ -727,15 +728,6 @@ class TestSloReportCLI:
         assert r.returncode == 0, r.stderr
         doc = json.loads(r.stdout)
         assert doc["objectives"]["lat"]["samples"] == 20
-
-    def test_ci_gate_slo_mode(self):
-        r = subprocess.run(["sh", os.path.join(REPO, "tools",
-                                               "ci_bench_gate.sh")],
-                           capture_output=True, text=True, cwd=REPO,
-                           env=dict(os.environ, CI_BENCH_ONLY="slo",
-                                    JAX_PLATFORMS="cpu"))
-        assert r.returncode == 0, r.stdout + r.stderr
-        assert "PASS" in r.stdout
 
 
 # --- run_monitor incident correlation ------------------------------------

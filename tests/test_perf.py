@@ -7,9 +7,7 @@ honest:
   is declared in ``obs/bus.py::EVENT_KINDS`` and vice versa (trace.span /
   perf.summary made this a recurring hazard);
 * default runs produce a byte-identical lowered train step (no
-  instrumentation can leak into the compiled program);
-* the committed ``PERF_LEDGER_cpu_r09.json`` self-gates through
-  ``tools/ci_bench_gate.sh`` compare-only mode.
+  instrumentation can leak into the compiled program).
 """
 
 import json
@@ -533,47 +531,3 @@ class TestReportPerfSection:
         assert s0["perf_mfu_weighted"] is None and s0["trace_spans"] == 0
         t0 = obs.format_report(s0)
         assert "perf MFU" not in t0 and "trace spans" not in t0
-
-
-# --- the committed perf-ledger artifact + gate ---------------------------
-class TestPerfLedgerArtifact:
-    ARTIFACT = os.path.join(REPO, "PERF_LEDGER_cpu_r09.json")
-
-    def test_artifact_schema(self):
-        doc = json.load(open(self.ARTIFACT))
-        assert doc["metric"] == "perf_ledger"
-        assert doc["results"], "no gateable records"
-        for rec in doc["results"]:
-            assert rec["unit"] == "gflops" and rec["value"] > 0
-            assert rec["roofline"] in ("compute", "memory", "unknown")
-        assert doc["summary"]["perf_programs"] >= len(doc["results"])
-        # CPU artifact: the peak is the labelled-nominal one
-        assert doc["summary"]["peak_nominal"] == 1
-
-    def test_ci_gate_compare_only_self_compare_passes(self):
-        """The satellite contract: the committed ledger gates through
-        tools/ci_bench_gate.sh compare-only mode (a self-compare must be
-        0 regressions with full overlap)."""
-        gate = os.path.join(REPO, "tools", "ci_bench_gate.sh")
-        r = subprocess.run(
-            ["sh", gate, self.ARTIFACT],
-            capture_output=True, text=True, cwd=REPO,
-            env=dict(os.environ, CI_BENCH_SKIP_RUN="1",
-                     CI_BENCH_OUT=self.ARTIFACT, CI_BENCH_ONLY="perf",
-                     CI_MIN_OVERLAP="2", JAX_PLATFORMS="cpu"))
-        assert r.returncode == 0, r.stdout + r.stderr
-        assert "no regressions" in r.stdout
-
-    def test_gflops_unit_gates_two_sided(self):
-        """Compiled-program cost is deterministic, so ANY move beyond
-        the floor trips: up = the program bloated, down = it lost work
-        (a dropped layer is not an 'improvement')."""
-        from tools.bench_compare import compare
-
-        old = {"m": {"metric": "m", "value": 100.0, "unit": "gflops"}}
-        up = {"m": {"metric": "m", "value": 150.0, "unit": "gflops"}}
-        down = {"m": {"metric": "m", "value": 60.0, "unit": "gflops"}}
-        same = {"m": {"metric": "m", "value": 100.0, "unit": "gflops"}}
-        assert compare(old, up)[0]["verdict"] == "regression"
-        assert compare(old, down)[0]["verdict"] == "regression"
-        assert compare(old, same)[0]["verdict"] == "ok"

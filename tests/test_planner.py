@@ -13,8 +13,6 @@ from __future__ import annotations
 import itertools
 import json
 import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -46,7 +44,9 @@ class _ShapeDs:
 
 
 def bench_shapes(n=64, seed=0):
-    """bench_suite.SynthVarResDataset's histogram (same draws)."""
+    """A Part-A-like histogram: 40% at 768x1024, the rest uniform in
+    384..1024 per side, snapped to 8 (the draws behind
+    tests/fixtures/PLAN_ABLATION_r08.json)."""
     rng = np.random.default_rng(seed)
     out = []
     for _ in range(n):
@@ -299,20 +299,10 @@ class TestPlannerTelemetry:
 
 
 class TestPlanSpaceTier:
-    def test_bench_plan_space_records(self):
-        from bench_suite import bench_plan_space
-
-        recs = bench_plan_space(repeats=1, batches=(16,),
-                                launch_costs_mpx=(2.0, 0.05))
-        by = {r["metric"]: r for r in recs}
-        assert by["plan_space_varres_b16_legacy_L2p0"]["value"] == \
-            pytest.approx(0.3067, abs=5e-4)
-        assert by["plan_space_varres_b16_cost_L0p05"]["value"] <= 0.24
-        assert all(r["predicted_eq_realized"] for r in recs)
-        assert all(r["programs"] <= r["max_buckets"] for r in recs)
 
     def test_committed_ablation_artifact_consistent(self):
-        path = os.path.join(REPO, "PLAN_ABLATION_r08.json")
+        path = os.path.join(REPO, "tests", "fixtures",
+                            "PLAN_ABLATION_r08.json")
         doc = json.load(open(path))
         head = doc["headline"]
         assert head["baseline_legacy_2mpx_pricing"]["schedule_overhead"] \
@@ -322,53 +312,3 @@ class TestPlanSpaceTier:
         assert (head["cost_planner_device_pricing"]["padding_overhead"]
                 <= head["baseline_legacy_2mpx_pricing"]["padding_overhead"]
                 + 5e-4)
-
-
-class TestScalingModel:
-    def test_model_shape_and_monotonicity(self):
-        import bench_scaling
-
-        doc = bench_scaling.scaling_model(dps=(1, 4, 16), n_images=80)
-        rows = doc["results"]
-        assert rows[0]["dp"] == 1
-        assert rows[0]["predicted_efficiency"] == 1.0
-        effs = [r["predicted_efficiency"] for r in rows]
-        assert effs == sorted(effs, reverse=True)
-        assert all(0.0 < e <= 1.0 for e in effs)
-        assert doc["grad_bytes"] > 1e7  # the real model's parameters
-        for r in rows:
-            assert r["global_batch"] == 16 * r["dp"]
-            assert r["batch_quantum"] % r["dp"] == 0
-
-    def test_committed_scaling_artifact(self):
-        doc = json.load(open(os.path.join(REPO, "SCALING_MODEL_r08.json")))
-        dps = [r["dp"] for r in doc["results"]]
-        assert dps == [1, 2, 4, 8, 16, 32, 64]
-        assert doc["results"][0]["predicted_efficiency"] == 1.0
-        assert "PREDICTED" in doc["note"]  # honesty label
-
-
-class TestCiBenchGate:
-    def test_min_overlap_guards_vacuous_pass(self, tmp_path):
-        from tools.bench_compare import main as compare_main
-
-        a = tmp_path / "a.json"
-        b = tmp_path / "b.json"
-        a.write_text(json.dumps({"metric": "x", "value": 1.0,
-                                 "unit": "images/sec"}))
-        b.write_text(json.dumps({"metric": "y", "value": 1.0,
-                                 "unit": "images/sec"}))
-        # disjoint metrics: ok without the guard, FAIL with it
-        assert compare_main([str(a), str(b)]) == 0
-        assert compare_main([str(a), str(b), "--min-overlap", "1"]) == 1
-        assert compare_main([str(a), str(a), "--min-overlap", "1"]) == 0
-
-    def test_gate_script_self_compare(self):
-        env = dict(os.environ, CI_BENCH_SKIP_RUN="1",
-                   CI_BENCH_OUT=os.path.join(REPO, "BENCH_SUITE_r07.json"))
-        got = subprocess.run(
-            [os.path.join(REPO, "tools", "ci_bench_gate.sh"),
-             os.path.join(REPO, "BENCH_SUITE_r07.json")],
-            env=env, capture_output=True, text=True, cwd=REPO)
-        assert got.returncode == 0, got.stdout + got.stderr
-        assert "no regressions" in got.stdout

@@ -6,8 +6,8 @@ Covers the r14 acceptance set: menu selection vs brute force, the
 predicted==realized invariant, bit-identical offline plans under the
 extracted core, zero new compiles under mixed traffic with the menu
 warmed, AOT bundle staleness on a menu change, deadline-ordering
-starvation bounds, the audit's one-registry mutation teeth, the
-scheduler gauges/report row, and the sched bench tier's gate plumbing.
+starvation bounds, the audit's one-registry mutation teeth, and the
+scheduler gauges/report row.
 """
 
 from __future__ import annotations
@@ -15,8 +15,6 @@ from __future__ import annotations
 import itertools
 import json
 import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -322,11 +320,12 @@ class TestOfflineBitIdentical:
         assert schedule_coverage(sched) == {i: 1
                                             for i in range(len(shapes))}
 
-    def test_committed_plan_ablation_reproduces(self):
+    def test_committed_golden_plan_reproduces(self):
         """The r8 padding-floor headline must survive the refactor: the
         cost-mode plan at device pricing reproduces the committed
         0.0961 overhead bit-for-bit (the acceptance pin)."""
-        with open(os.path.join(REPO, "PLAN_ABLATION_r08.json")) as f:
+        with open(os.path.join(REPO, "tests", "fixtures",
+                               "PLAN_ABLATION_r08.json")) as f:
             doc = json.load(f)
         headline = doc["headline"]["cost_planner_device_pricing"]
         assert headline["schedule_overhead"] == 0.0961
@@ -655,52 +654,3 @@ class TestSchedObservability:
         assert s["sched_cost_mismatches"] == 0
         text = format_report(s)
         assert "scheduler" in text and "predicted==realized" in text
-
-
-# -- bench plumbing --------------------------------------------------------
-class TestSchedBenchGate:
-    def test_fill_pct_direction_downward_only(self):
-        from tools.bench_compare import _direction, compare
-
-        assert _direction("fill_pct") == +1
-        old = {"m": {"metric": "m", "value": 50.0, "unit": "fill_pct",
-                     "spread_pct": 2.0}}
-        worse = {"m": {"metric": "m", "value": 40.0, "unit": "fill_pct",
-                       "spread_pct": 2.0}}
-        better = {"m": {"metric": "m", "value": 99.0, "unit": "fill_pct",
-                        "spread_pct": 2.0}}
-        assert compare(old, worse)[0]["verdict"] == "regression"
-        assert compare(old, better)[0]["verdict"] == "improved"
-
-    def test_committed_artifact_receipts(self):
-        """BENCH_SCHED_cpu_r14.json: fill strictly improved vs the
-        legacy arm at BOTH loads, p99 no worse than the legacy arm, and
-        the predicted==realized receipt is clean."""
-        with open(os.path.join(REPO, "BENCH_SCHED_cpu_r14.json")) as f:
-            doc = json.load(f)
-        recs = {r["metric"]: r for r in doc["results"]}
-        for phase in ("low", "mixed"):
-            r = recs[f"serve_sched_fill_{phase}"]
-            assert r["unit"] == "fill_pct"
-            assert r["value"] > r["legacy_fill"], phase
-            assert r["cost_mismatches"] == 0
-        # p99 no worse than the legacy arm under the same offered load
-        # (within the recorded noise of this artifact's own spreads)
-        for phase in ("low", "mixed"):
-            r = recs[f"serve_sched_p99_{phase}"]
-            floor = 1.0 + max(r["spread_pct"], 10.0) / 100.0
-            assert r["value"] <= r["legacy_p99_ms"] * floor, phase
-
-    def test_gate_self_compare(self):
-        """CI_BENCH_ONLY=sched compare-only mode: the committed artifact
-        vs itself exits 0 (the gate plumbing works end to end)."""
-        env = dict(os.environ, CI_BENCH_ONLY="sched",
-                   CI_BENCH_SKIP_RUN="1",
-                   CI_BENCH_OUT=os.path.join(REPO,
-                                             "BENCH_SCHED_cpu_r14.json"),
-                   CI_MIN_OVERLAP="5")
-        r = subprocess.run(
-            [os.path.join(REPO, "tools", "ci_bench_gate.sh"),
-             os.path.join(REPO, "BENCH_SCHED_cpu_r14.json")],
-            capture_output=True, text=True, env=env, cwd=REPO)
-        assert r.returncode == 0, r.stdout + r.stderr

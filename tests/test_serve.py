@@ -1276,6 +1276,20 @@ class TestServeCLIValidation:
         with pytest.raises(Exception):
             parse_bucket_shapes("no")
 
+    @pytest.mark.parametrize("flag,value", [("--flush-policy", "timer"),
+                                            ("--menu-budget", "1"),
+                                            ("--dispatch-order", "fifo")])
+    def test_a_retired_selector_is_refused(self, flag, value, capsys):
+        """The serving path's A/B arms are chosen by the code (the request
+        kinds' ``cost_unit``), not by a user: the parser no longer knows
+        the three flags that selected them."""
+        from can_tpu.cli.serve import parse_args
+
+        with pytest.raises(SystemExit) as e:
+            parse_args([flag, value])
+        assert e.value.code == 2
+        assert f"unrecognized arguments: {flag}" in capsys.readouterr().err
+
     def test_checkpoint_dir_sentinel_conflicts(self, tmp_path):
         """An EXPLICIT --checkpoint-dir ./checkpoints alongside --torch-pth
         must now conflict (it used to slip through the literal-string
@@ -1297,34 +1311,3 @@ class TestServeCLIValidation:
         assert args.checkpoint_dir == "./checkpoints"  # default resolves
         args = parse_args(["--torch-pth", str(pth)])
         validate_params_source(args)  # torch-pth alone: fine
-
-
-@pytest.mark.slow
-def test_bench_serve_emits_json_report(tmp_path):
-    """bench_serve.py end to end (CPU-smoke scale): JSON report with
-    latency percentiles, throughput, batch fill, and reject rate."""
-    import subprocess
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               BENCH_SERVE_REQUESTS="24", BENCH_SERVE_CLIENTS="4",
-               BENCH_SERVE_MAX_BATCH="4", BENCH_SERVE_OUT="test",
-               BENCH_SERVE_SIZES="60x60,64x90")
-    out = subprocess.run([sys.executable,
-                          os.path.join(repo, "bench_serve.py")],
-                         capture_output=True, text=True, cwd=str(tmp_path),
-                         env=env, timeout=600)
-    assert out.returncode == 0, out.stderr
-    report = json.load(open(tmp_path / "BENCH_SERVE_test.json"))
-    for phase in ("closed_loop", "open_loop"):
-        for k in ("p50_ms", "p95_ms", "p99_ms", "throughput_rps",
-                  "reject_rate"):
-            assert k in report[phase]
-    assert report["compiles_bounded"]
-    assert 0 < report["mean_batch_fill"] <= 1.0
-    # zero hangs: every request accounted for
-    assert (report["closed_loop"]["completed"]
-            + report["closed_loop"]["rejected"]) == 24
-    assert (report["open_loop"]["completed"]
-            + report["open_loop"]["rejected"]) == 24

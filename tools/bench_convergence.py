@@ -17,8 +17,7 @@ Run (single process, real TPU):
 CPU smoke: add ``--platform cpu --scale 0.125`` (no golden check — the
 TPU goldens don't transfer across backends; the run must still converge).
 
-Output: one JSON line, merged into BENCH_SUITE_r{N}.json by the round
-notes.  The quality bar this stands in for is the reference's
+Output: one JSON line.  The quality bar this stands in for is the reference's
 checkpoint-backed dataset claim (reference README.md:37, test.py:69).
 """
 

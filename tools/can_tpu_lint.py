@@ -39,8 +39,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         description="JAX/concurrency-aware linter for the can_tpu tree")
     ap.add_argument("paths", nargs="*",
-                    help="files or directories (default: the library, "
-                         "bench entry points, and tools)")
+                    help="files or directories (default: the library "
+                         "and tools)")
     ap.add_argument("--rules", default=None,
                     help="comma-separated rule subset")
     ap.add_argument("--baseline", default=DEFAULT_BASELINE)

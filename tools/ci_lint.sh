@@ -1,6 +1,6 @@
 #!/usr/bin/env sh
 # CI static-analysis gate: the source linter + the program-contract audit,
-# beside tools/ci_bench_gate.sh in the tier-1 flow.  Exit 0 iff BOTH pass.
+# in the tier-1 flow.  Exit 0 iff BOTH pass.
 #
 #   tools/ci_lint.sh                 # lint + structure audit (fast, ~30s)
 #   CI_LINT_FULL=1 tools/ci_lint.sh  # + compile each program and check

@@ -27,7 +27,7 @@ Two violation classes (see ``obs.slo.grade_events``):
 * budget — the run's total bad fraction exceeded the error budget even
   though no single window alerted (slow leak).
 
-Exit codes (bench_compare discipline — CI gates on them):
+Exit codes (CI gates on them):
   0  every graded objective within budget, no fast burns
   1  at least one violation (each printed naming objective + window)
   2  usage error: missing/invalid spec, unreadable target, no events
