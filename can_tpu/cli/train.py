@@ -233,12 +233,6 @@ def parse_args(argv=None):
                         "of shapes actually compiled well under the "
                         "budget), and the persistent compilation cache "
                         "makes the one-time bill cheap")
-    p.add_argument("--s2d-stem", action="store_true",
-                   help="space-to-depth the VGG stem: fold the 3-channel "
-                        "first conv into (H/2, W/2, 12) packed space so its "
-                        "contraction uses 108 of the MXU's 128 K-lanes "
-                        "instead of 27 — numerically identical "
-                        "(ops/conv.py fold_stem_kernel); dp path only")
     p.add_argument("--no-remnant-batches", action="store_true",
                    help="disable remnant sub-batches: with --pad-multiple "
                         "auto, straggler groups normally run at a small "
@@ -941,13 +935,6 @@ def _run_elastic_generations(args, run_cfg, topo, *, supervisor,
                             dict(run_cfg, world_size=dp))
 
         apply_fn = cannet_apply
-        if args.s2d_stem:
-            if args.sp > 1:
-                raise SystemExit("--s2d-stem is dp-path only (the sp "
-                                 "step builds its own sharded apply)")
-            import functools
-
-            apply_fn = functools.partial(cannet_apply, s2d_stem=True)
         if bn_ops is not None and args.sp == 1:
             import functools
 
@@ -955,7 +942,7 @@ def _run_elastic_generations(args, run_cfg, topo, *, supervisor,
 
             # the BN-moments seam rides LocalOps beside context_fused;
             # dp-path only (the sp step takes bn_ops directly)
-            apply_fn = functools.partial(apply_fn,
+            apply_fn = functools.partial(cannet_apply,
                                          ops=LocalOps(bn_ops=bn_ops))
         remat_policy = make_remat_policy(args.remat,
                                          global_batch=args.batch_size * dp,

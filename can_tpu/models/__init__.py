@@ -9,6 +9,8 @@ from .cannet import (
     init_batch_stats,
     load_vgg16_frontend,
     param_count,
+    stage1_layout,
+    stage1_traced,
 )
 
 __all__ = [
@@ -22,6 +24,8 @@ __all__ = [
     "init_batch_stats",
     "load_vgg16_frontend",
     "param_count",
+    "stage1_layout",
+    "stage1_traced",
 ]
 
 from can_tpu.models.flax_module import CANNet as FlaxCANNet  # noqa: E402

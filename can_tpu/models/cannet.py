@@ -35,8 +35,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from can_tpu.ops.conv import conv1x1, conv2d
-from can_tpu.ops.pooling import adaptive_avg_pool2d, max_pool2d
+from can_tpu.ops.conv import conv1x1, conv2d, fold_w_pairs, fold_w_pairs_kernel
+from can_tpu.ops.pooling import adaptive_avg_pool2d, max_pool2d, max_pool2d_w_pairs
 from can_tpu.ops.resize import resize_bilinear_align_corners
 
 # Layer configs (reference: model/CANNet.py:11-13).
@@ -44,6 +44,9 @@ FRONTEND_CFG: Sequence = (64, 64, "M", 128, 128, "M", 256, 256, 256, "M", 512, 5
 BACKEND_CFG: Sequence[int] = (512, 512, 512, 256, 128, 64)
 CONTEXT_SCALES: Sequence[int] = (1, 2, 3, 6)
 _FEAT_CH = 512
+# A TPU tile's minor dimension: an activation with fewer channels is stored
+# and streamed padded to it (stage1_layout).
+_LANES = 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -57,6 +60,11 @@ class LocalOps:
 
     conv2d: Callable = conv2d
     max_pool: Callable = max_pool2d
+    # The 2x2 pool of a W-pair folded tensor (stage 1, stage1_layout); an
+    # ops whose primitives cannot take the folded form states None and the
+    # forward keeps stage 1 plain.  parallel/spatial.py shards H and pools
+    # locally, so the default serves it unchanged.
+    max_pool_pairs: Any = max_pool2d_w_pairs
     adaptive_pool: Callable = adaptive_avg_pool2d
     upsample: Callable = resize_bilinear_align_corners
     # Full (unsharded) feature H, W; None means "use local shape".
@@ -132,6 +140,49 @@ def has_batch_norm(params: Mapping) -> bool:
     return "bn" in params["frontend"][0]
 
 
+def stage1_layout(params: Mapping, image_shape, ops: LocalOps = LocalOps()) -> str:
+    """``"folded"`` or ``"plain"``: how ``cannet_apply`` carries the front
+    end's first stage (conv, conv, pool at full resolution) for these
+    parameters on an image batch of this shape.  The forward asks it while
+    it is traced and notes the answer for ``stage1_traced``.
+
+    Folded: the two 64-channel layers and pool1 run on W-pairs of 128
+    channels (ops/conv.py::fold_w_pairs_kernel), so no tensor of the stage
+    has its channels padded to twice their bytes and both convolutions
+    fill the MXU's columns.  Same dtype, same sums of the same products.
+    Plain, by what is observed and no flag: batch-norm parameters (the
+    moments are per channel and would need both halves summed), an odd
+    width, a stage whose two convolutions are not 3x3 with half a tile's
+    channels, an ``ops`` without ``max_pool_pairs``.
+    """
+    first = params["frontend"][:2]
+    stage_folds = (FRONTEND_CFG[2] == "M" and all(
+        p["w"].shape[:2] == (3, 3) and 2 * p["w"].shape[3] == _LANES
+        for p in first))
+    if (stage_folds and not has_batch_norm(params)
+            and image_shape[-2] % 2 == 0 and ops.max_pool_pairs is not None):
+        return "folded"
+    return "plain"
+
+
+# "BxHxW" of an image batch -> how the newest trace of ``cannet_apply`` on
+# such a batch carried stage 1.  Written while a program is traced, read
+# after its launch by whoever reports what the program does.
+_STAGE1_TRACED: dict = {}
+
+
+def program_key(image_shape) -> str:
+    return "x".join(map(str, image_shape[:3]))
+
+
+def stage1_traced(image_shape) -> Optional[str]:
+    """``"folded"`` / ``"plain"`` as the program traced in this process for
+    an image batch of this shape has it; None where none was traced (a
+    stub, a binary compiled elsewhere, a per-shard shape under
+    ``shard_map``)."""
+    return _STAGE1_TRACED.get(program_key(image_shape))
+
+
 def init_batch_stats(params: Mapping) -> Optional[dict]:
     """Running mean/var tree for a BN model (None for the plain model).
     Mirrors torch BatchNorm2d defaults: mean 0, var 1."""
@@ -159,7 +210,6 @@ def cannet_apply(
     batch_stats: Any = None,
     train: bool = False,
     bn_momentum: float = 0.1,
-    s2d_stem: bool = False,
     pixel_mask: Any = None,
     sample_mask: Any = None,
 ):
@@ -184,6 +234,10 @@ def cannet_apply(
     Valid regions are /8-snapped by the dataset, so the /8 mask upsampled
     by nearest is exact at every frontend resolution.  Both default to
     None = the original unmasked moments.
+
+    Stage 1 (the two full-resolution 64-channel layers and pool1) runs on
+    W-pairs of 128 channels where ``stage1_layout`` says so; pool1's output
+    is the plain path's, so nothing after it knows.
     """
     if compute_dtype is not None:
         x = x.astype(compute_dtype)
@@ -202,29 +256,17 @@ def cannet_apply(
         ds = x.shape[-3] // m8.shape[-3]  # 8 at input resolution
         bn_mask = jnp.repeat(jnp.repeat(m8, ds, axis=-3), ds, axis=-2)
 
-    def conv_block(x, group, i, dilation, mask=None):
+    def conv_block(x, group, i, dilation, mask=None, folded=False):
         p = params[group][i]
-        if s2d_stem and group == "frontend" and i == 0:
-            # space-to-depth stem (VERDICT r3 item 2): the 3-channel first
-            # conv contracts only K=27 of the MXU's 128 K-lanes; fold it
-            # into packed space (K=108, 1/4 the positions) — numerically
-            # identical (ops/conv.py fold_stem_kernel; pinned by
-            # tests/test_ops.py::TestSpaceToDepthStem).  The fold is linear
-            # in w, so gradients train the ORIGINAL stem weights.
-            from can_tpu.ops.conv import (
-                depth_to_space,
-                fold_stem_kernel,
-                space_to_depth,
-            )
-
-            wp, bp = fold_stem_kernel(p["w"].astype(x.dtype),
-                                      p["b"].astype(x.dtype))
-            y = ops.conv2d(space_to_depth(x), wp, bp, dilation=dilation,
-                           precision=precision)
-            y = depth_to_space(y)
-        else:
-            y = ops.conv2d(x, p["w"].astype(x.dtype), p["b"].astype(x.dtype),
-                           dilation=dilation, precision=precision)
+        w, b = p["w"], p["b"]
+        if folded:
+            # linear in w: training differentiates through the fold and
+            # updates the ORIGINAL kernel; serving folds inside the program.
+            # Folded in the parameters' dtype, so the two blocks that hold a
+            # tap hand back their gradients to be summed there, not in x's
+            w, b = fold_w_pairs_kernel(w, b)
+        y = ops.conv2d(x, w.astype(x.dtype), b.astype(x.dtype),
+                       dilation=dilation, precision=precision)
         if bn:
             stats = None if batch_stats is None else batch_stats[group][i]
             y, updated = _batch_norm(y, p["bn"], stats, train, bn_momentum,
@@ -244,18 +286,26 @@ def cannet_apply(
         return checkpoint_name(jax.nn.relu(y), f"{group}{i}")
 
     # --- VGG-16 frontend ---
+    layout = _STAGE1_TRACED[program_key(x.shape)] = stage1_layout(
+        params, x.shape, ops)
+    folded = layout == "folded"
+    if folded:
+        x = fold_w_pairs(x)
     i = 0
     n_pool = 0
     for v in FRONTEND_CFG:
         if v == "M":
             n_pool += 1
-            x = checkpoint_name(ops.max_pool(x), f"pool{n_pool}")
+            # the folded stage ends at its pool, whose output is the plain one
+            pool = ops.max_pool_pairs if folded else ops.max_pool
+            folded = False
+            x = checkpoint_name(pool(x), f"pool{n_pool}")
             if bn_mask is not None:
                 # stride-2 subsample tracks the pool; valid regions are
                 # /8-aligned so this is exact (no partial cells)
                 bn_mask = bn_mask[:, ::2, ::2, :]
         else:
-            x = conv_block(x, "frontend", i, 1, mask=bn_mask)
+            x = conv_block(x, "frontend", i, 1, mask=bn_mask, folded=folded)
             i += 1
     fv = x
 

@@ -450,7 +450,9 @@ def render_stats(stats: dict, *, prefix: str = "can_tpu_serve",
     or lagging a rollout generation, ``"flush_reasons"``, whose counts
     become ``can_tpu_serve_flushes_total{reason="full"}`` lines, and
     ``"staging"``: ``can_tpu_serve_staging_launches_total{assembled=
-    "reused"}`` lines and the ``can_tpu_serve_staging_bytes_held`` gauge, and
+    "reused"}`` lines and the ``can_tpu_serve_staging_bytes_held`` gauge,
+    ``"stage1"``: ``can_tpu_serve_stage1_folded{program="16x768x1024:float32"}``
+    0/1 gauges, and
     ``"lm"`` (a language model's engine): ``can_tpu_serve_lm_*_total`` counters
     and ``can_tpu_serve_lm_cache_bytes{kind=}``."""
     gauges: Dict[str, float] = {}
@@ -486,6 +488,14 @@ def render_stats(stats: dict, *, prefix: str = "can_tpu_serve",
                 else:
                     counters[(f"{prefix}_staging_launches_total",
                               (("assembled", str(how)),))] = n
+            continue
+        if k == "stage1" and isinstance(v, dict):
+            # per compiled program: whether CANNet's first stage runs on
+            # W-pairs of 128 channels (models/cannet.py::stage1_layout)
+            for program, mode in v.items():
+                labelled_gauges[(f"{prefix}_stage1_folded",
+                                 (("program", str(program)),))] = (
+                    mode == "folded")
             continue
         if k == "lm" and isinstance(v, dict):
             # the language model's engine: generated tokens, assignments

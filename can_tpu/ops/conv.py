@@ -48,66 +48,45 @@ def conv2d(x, w, b=None, *, dilation: int = 1, padding=None, precision=None):
     return out.astype(x.dtype)
 
 
-def space_to_depth(x, block: int = 2):
-    """(N, H, W, C) -> (N, H/b, W/b, b*b*C); packed channel index is
-    (di*b + dj)*C + c for sub-pixel (di, dj)."""
+def fold_w_pairs(x):
+    """(N, H, W, C) -> (N, H, W/2, 2C): columns 2j and 2j+1 become one
+    super-pixel whose channels are [column 2j's C, column 2j+1's C].  The
+    row-major bytes are the same, so this is a reshape and no transpose."""
     n, h, w, c = x.shape
-    x = x.reshape(n, h // block, block, w // block, block, c)
-    return x.transpose(0, 1, 3, 2, 4, 5).reshape(
-        n, h // block, w // block, block * block * c)
+    return x.reshape(n, h, w // 2, 2 * c)
 
 
-def depth_to_space(y, block: int = 2):
-    """Inverse of space_to_depth (same channel packing)."""
-    n, h, w, pc = y.shape
-    c = pc // (block * block)
-    y = y.reshape(n, h, w, block, block, c)
-    return y.transpose(0, 1, 3, 2, 4, 5).reshape(
-        n, h * block, w * block, c)
+def fold_w_pairs_kernel(w, b=None):
+    """A 3x3 stride-1 SAME kernel for W-pair folded activations:
+    ``fold_w_pairs(conv2d(x, w, b)) == conv2d(fold_w_pairs(x), w', b')``.
 
+    w: (3, 3, C, O) -> (3, 3, 2C, 2O); b: (O,) -> (2O,).  Output super-pixel
+    j holds original columns 2j + dp (dp in 0, 1); tap v in (-1, 0, 1) of
+    that column reads column 2j + dp + v, which is half ``rb`` of
+    super-pixel ``j + fb`` with ``fb, rb = divmod(dp + v, 2)``.  The six
+    (dp, v) blocks are disjoint and every other entry is an exact zero
+    (the taps that would reach columns 2j-2 and 2j+3), so each output is
+    the same sum of the same products; SAME padding by one super-pixel
+    pads two columns, the outer of which only meets those zeros.  The H
+    taps are untouched.  The result is placed, never computed (no product
+    that a TPU's default precision would round), and is linear in ``w``:
+    gradients reach the original kernel through the slices' transposes.
 
-def fold_stem_kernel(w, b=None, *, block: int = 2):
-    """Fold a 3x3 stride-1 SAME conv into space-to-depth space.
-
-    ``conv2d(x, w, b) == depth_to_space(conv2d(space_to_depth(x), w', b'))``
-    exactly (both sides zero-pad, and the packed canvas's zeros land where
-    SAME padding's zeros would).  Purpose: the VGG stem's 3-channel conv
-    contracts only K = 3*3*3 = 27 elements — a fraction of the MXU's
-    128-wide K lanes; the folded conv contracts K = 12*9 = 108 at 1/4 the
-    spatial positions (VERDICT r3 item 2, the MLPerf space-to-depth trick
-    adapted to stride 1: each output sub-pixel keeps its full 3x3 receptive
-    field, which spans <= 3 packed rows, so the folded kernel stays 3x3 —
-    at 4x nominal FLOPs, the bet being utilisation > 4x).
-
-    MEASURED NEGATIVE on TPU v5e (r4 ablation, 576x768 b16 bf16 train
-    step, interleaved reps, losses bit-identical): plain 94.4 img/s vs
-    folded 82.8 (-12%).  For a stride-1 stem the receptive-field overlap
-    makes the folded kernel 4x the FLOPs, and XLA's native handling of
-    the 27-element contraction beats 4x-at-full-lanes — consistent with
-    the maxpool and Pallas-context ablations (ops/pooling.py,
-    ops/pallas_context.py): XLA's default lowering keeps winning on this
-    model.  Kept behind --s2d-stem as a documented, parity-tested option
-    for hardware where the trade differs; OFF by default.
-
-    w: (3, 3, C, O) -> (3, 3, b*b*C, b*b*O); b: (O,) -> (b*b*O,).
+    Why: a 64-channel activation fills half of a TPU tile's 128 lanes, so
+    it is stored and streamed at twice its bytes and its convolution
+    drives half of the MXU's columns; the W-pair form of the same array
+    has 128 channels (models/cannet.py, stage 1).  The folded kernel does
+    twice the nominal multiply-adds (half of them against the zeros).
     """
-    assert block == 2 and w.shape[:2] == (3, 3), (
-        "fold derived for the 3x3 stride-1 block-2 case")
+    assert w.shape[:2] == (3, 3), "fold derived for the 3x3 stride-1 case"
     c, o = w.shape[2], w.shape[3]
-    wp = jnp.zeros((3, 3, 4 * c, 4 * o), w.dtype)
-    for do in (0, 1):
-        for dp in (0, 1):
-            out0 = (2 * do + dp) * o
-            for u in (-1, 0, 1):
-                fa, ra = (do + u) // 2, (do + u) % 2
-                for v in (-1, 0, 1):
-                    fb, rb = (dp + v) // 2, (dp + v) % 2
-                    in0 = (ra * 2 + rb) * c
-                    wp = wp.at[fa + 1, fb + 1,
-                               in0:in0 + c, out0:out0 + o].add(
-                        w[u + 1, v + 1])
-    bp = None if b is None else jnp.tile(b, 4)
-    return wp, bp
+    wp = jnp.zeros((3, 3, 2 * c, 2 * o), w.dtype)
+    for dp in (0, 1):
+        for v in (-1, 0, 1):
+            fb, rb = divmod(dp + v, 2)
+            wp = wp.at[:, fb + 1, rb * c:(rb + 1) * c,
+                       dp * o:(dp + 1) * o].set(w[:, v + 1])
+    return wp, None if b is None else jnp.tile(b, 2)
 
 
 def conv1x1(x, w, b=None, *, precision=None):

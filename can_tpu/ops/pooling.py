@@ -76,7 +76,9 @@ def max_pool2d(x, window: int = 2, stride: int = 2):
     matches torch.nn.MaxPool2d(kernel_size=2, stride=2), reference
     model/CANNet.py:112).
 
-    ABLATION (v5e-1, 576x768 b16 bf16 train step; VERDICT r2 item 5): the
+    ABLATION (rounds 2-3, timed through the device path PR 21 retired:
+    history, not evidence for the chip as it is reached today; v5e-1,
+    576x768 b16 bf16 train step; VERDICT r2 item 5): the
     step profile charges maxpool-backward (``select_and_scatter``) ~5% of
     device time, so two replacements were measured against this stock
     lowering's 95.0-95.2 img/s, interleaved in one process:
@@ -94,11 +96,29 @@ def max_pool2d(x, window: int = 2, stride: int = 2):
     with the surrounding conv fusions well enough that removing it from
     the op list does not remove its time from the step.
     """
+    return _max_window(x, (window, window), (stride, stride))
+
+
+def _max_window(x, window_hw, stride_hw):
     return lax.reduce_window(
         x,
         -jnp.inf if jnp.issubdtype(x.dtype, jnp.floating) else jnp.iinfo(x.dtype).min,
         lax.max,
-        window_dimensions=(1, window, window, 1),
-        window_strides=(1, stride, stride, 1),
+        window_dimensions=(1, *window_hw, 1),
+        window_strides=(1, *stride_hw, 1),
         padding="VALID",
     )
+
+
+def max_pool2d_w_pairs(x):
+    """``max_pool2d`` (2x2, stride 2) of a W-pair folded tensor
+    (ops/conv.py::fold_w_pairs): (N, H, W/2, 2C) -> (N, H/2, W/2, C), the
+    plain pool's output of the unfolded tensor.  Row pairs first, so the
+    full-resolution pass (and its ``select_and_scatter`` in the backward)
+    runs on all 2C lanes; then the two columns of a super-pixel, which are
+    its two channel halves.  Where both columns hold the maximum the left
+    one takes the gradient, as the plain pool's window order has it."""
+    c = x.shape[-1] // 2
+    y = _max_window(x, (2, 1), (2, 1))
+    left, right = y[..., :c], y[..., c:]
+    return jnp.where(left >= right, left, right)

@@ -37,7 +37,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +55,11 @@ def _batch_dict(batch: Batch) -> dict:
     return {"image": batch.image, "dmap": batch.dmap,
             "pixel_mask": batch.pixel_mask,
             "sample_mask": batch.sample_mask}
+
+
+def _program_key(batch: Batch) -> str:
+    return ("x".join(map(str, batch.image.shape[:3]))
+            + f":{batch.image.dtype}")
 
 
 def tree_signature(tree) -> tuple:
@@ -133,6 +138,13 @@ class ServeEngine:
         self._predict = RecompileTracker(jax.jit(predict), self.telemetry,
                                          name=name, batch_arg=1)
         self._signatures = self.telemetry.signature_registry[name]
+        # per program ("BxHxW:dtype" of its image batch): how it carries
+        # the network's first stage, "folded" or "plain", as its trace
+        # noted it (the program's ``stage1_traced``, where it has one); a
+        # binary loaded from an AOT bundle was traced where it was baked
+        # and has no entry
+        self._stage1_traced = getattr(predict, "stage1_traced", None)
+        self.stage1: Dict[str, str] = {}
         # per calling thread: whether its last predict_batch compiled
         self._call = threading.local()
 
@@ -204,6 +216,7 @@ class ServeEngine:
         with tr.span("serve.dispatch", aot=prog is not None) as sp:
             counts, density = self._launch(prog, batch)
             sp.attrs["compiled"] = self._call.compiled
+            sp.attrs["stage1"] = self.stage1.get(_program_key(batch))
         with tr.span("serve.fetch", density=bool(want_density)):
             return self._fetch(counts, density, want_density)
 
@@ -218,6 +231,10 @@ class ServeEngine:
                                             _batch_dict(batch),
                                             self.batch_stats)
             self._call.compiled = self._predict.last_first_call
+            mode = (self._stage1_traced(batch.image.shape)
+                    if self._call.compiled and self._stage1_traced else None)
+            if mode is not None:
+                self.stage1[_program_key(batch)] = mode
         return counts, density
 
     @staticmethod

@@ -377,6 +377,16 @@ class FleetEngine:
         (staging engines bill to their own per-generation registry)."""
         return sum(r.engine.compile_count for r in self.replicas)
 
+    @property
+    def stage1(self) -> dict:
+        """Every replica's programs by how they carry the network's first
+        stage (``ServeEngine.stage1``; the replicas run one set of
+        parameters, so they agree)."""
+        out: dict = {}
+        for r in self.replicas:
+            out.update(r.engine.stage1)
+        return out
+
     def live_replicas(self) -> int:
         return sum(1 for r in self.replicas if r.state == REPLICA_ACTIVE)
 

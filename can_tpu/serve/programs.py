@@ -29,7 +29,7 @@ from can_tpu.serve import cache as kv_cache
 
 def cannet_predict(serve_dtype: str, compute_dtype):
     """``predict(params, batch, batch_stats) -> (counts, masked density)``."""
-    from can_tpu.models import cannet_apply
+    from can_tpu.models import cannet_apply, stage1_traced
     from can_tpu.serve.quant import dequantize_tree
     from can_tpu.train.loss import density_counts
     from can_tpu.train.steps import _batch_image
@@ -56,6 +56,9 @@ def cannet_predict(serve_dtype: str, compute_dtype):
                 * batch["sample_mask"][:, None, None, None])
         return counts, pred.astype(jnp.float32) * mask
 
+    # (image shape) -> how the newest trace of this network on such a batch
+    # carried its first stage; the engine asks after a program's first launch
+    predict.stage1_traced = stage1_traced
     return predict
 
 

@@ -538,6 +538,13 @@ class CountService:
             # (of ``batches``): how often the overlap engages
             "launches_overlapped": self.batcher.launches_overlapped,
         }
+        stage1 = getattr(self.engine, "stage1", None)
+        if stage1 is not None:
+            # per compiled program ("BxHxW:dtype"), how it carries the
+            # network's first stage: "folded" (W-pairs of 128 channels)
+            # or "plain" (models/cannet.py::stage1_layout); an engine of
+            # another model has none
+            out["stage1"] = dict(stage1)
         if self._fleet is not None:
             # per-replica rows: service-side work counters joined with the
             # fleet's health snapshot — obs/exporter.py renders these as

@@ -27,6 +27,7 @@ from typing import Callable, Iterable, NamedTuple, Optional
 import jax
 import numpy as np
 
+from can_tpu.models.cannet import program_key, stage1_traced
 from can_tpu.obs.spans import active
 from can_tpu.parallel.elastic import ElasticInterrupt
 from can_tpu.train.steps import NonFiniteLossError
@@ -275,8 +276,11 @@ def train_one_epoch(train_step: Callable, state, batches: Iterable, *,
                                thread=lane)
                 with spans.span("train.dispatch", trace_id=trace_id,
                                 parent_id=root_id,
-                                program="x".join(map(str, shape[:3]))):
+                                program=program_key(shape)) as sp:
                     state, metrics = train_step(state, dev_batch)
+                    # as the program's trace noted it (a first call traces
+                    # inside the step); None: no such program was traced
+                    sp.attrs["stage1"] = stage1_traced(shape)
             if telemetry is not None:
                 # a first-call compile is attributed by its own compile
                 # event; recording it here too would poison the step
