@@ -28,23 +28,20 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from can_tpu.models.lm_blocks import (VocabSlice, count_shapes, embed,
+                                      init_from_shapes, lm_head, rms_norm,
+                                      routing_report)
+from can_tpu.models.lm_blocks import ffn as _ffn
 from can_tpu.ops import attention as attn_ops
-from can_tpu.ops import moe as moe_ops
+from can_tpu.ops import cache_layout as layout
 from can_tpu.ops.moe import ExpertShare
 
 WINDOW, FULL = "sliding_attention", "full_attention"
-
-
-class VocabSlice(ExpertShare):
-    """Rows ``first .. first + held - 1`` of the ``total`` vocabulary."""
-
-    __slots__ = ()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -162,51 +159,29 @@ def param_shapes(cfg: ExaoneMoeConfig) -> dict:
 
 
 def param_count(cfg: ExaoneMoeConfig) -> int:
-    return sum(math.prod(s) for s in
-               jax.tree.leaves(param_shapes(cfg),
-                               is_leaf=lambda x: isinstance(x, tuple)))
-
-
-def _leaf(key, name: str, shape, dtype):
-    if name in ("ln_in", "ln_post", "final_norm", "q_norm", "k_norm",
-                "ln_hidden", "ln_embed"):
-        return (1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
-    if name == "bias":      # the router's correction bias: a float32 buffer
-        return 0.05 * jax.random.normal(key, shape, jnp.float32)
-    if name == "embed":
-        return jax.random.normal(key, shape, dtype)
-    fan_in = shape[-2]
-    return jax.random.normal(key, shape, dtype) * jnp.asarray(fan_in ** -0.5, dtype)
+    return count_shapes(param_shapes(cfg))
 
 
 def init_params(key, cfg: ExaoneMoeConfig, dtype=jnp.bfloat16):
-    """Parameters from a key, leaf by leaf on the device (one jitted call a
-    leaf: no float32 copy of the whole tree is ever alive).  Projections
-    N(0, 1 / fan_in) so that activations stay of order one, norms near one,
-    embedding N(0, 1)."""
-    shapes = param_shapes(cfg)
-    flat, treedef = jax.tree_util.tree_flatten_with_path(
-        shapes, is_leaf=lambda x: isinstance(x, tuple))
-    make = jax.jit(_leaf, static_argnums=(1, 2, 3))
-    leaves = []
-    for i, (path, shape) in enumerate(flat):
-        leaves.append(make(jax.random.fold_in(key, i), str(path[-1].key), shape,
-                           dtype))
-    return jax.tree_util.tree_unflatten(treedef, leaves)
+    """Parameters from a key, leaf by leaf on the device
+    (``lm_blocks.init_from_shapes``: projections N(0, 1 / fan_in), norms
+    near one, embedding N(0, 1))."""
+    return init_from_shapes(key, param_shapes(cfg), dtype)
+
+
+def cache_layout(cfg: ExaoneMoeConfig) -> tuple:
+    """What each held layer keeps in a launch's cache
+    (``ops/cache_layout.py``): a ring of ``sliding_window`` positions in
+    window layers, the whole context in full ones, keys and values per
+    key/value head."""
+    return tuple(
+        layout.kv_layer(layout.RING if t == WINDOW else layout.FULL,
+                        kv_heads=cfg.num_key_value_heads,
+                        head_dim=cfg.head_dim, window=cfg.sliding_window)
+        for t in cfg.layer_types)
 
 
 # -- layers -------------------------------------------------------------
-def rms_norm(x, g, eps: float):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (y * g.astype(jnp.float32)).astype(x.dtype)
-
-
-def swiglu(x, p):
-    return jnp.dot(jax.nn.silu(jnp.dot(x, p["gate"])) * jnp.dot(x, p["up"]),
-                   p["down"])
-
-
 def _qkv(p, x, positions, layer_type, cfg: ExaoneMoeConfig):
     """``x`` (B, L, d) -> q (B, L, KV, G, D), k, v (B, L, KV, D)."""
     b, l, _ = x.shape
@@ -223,56 +198,10 @@ def _qkv(p, x, positions, layer_type, cfg: ExaoneMoeConfig):
     return q, k, v
 
 
-def expert_layer(p, x, cfg: ExaoneMoeConfig):
-    """``x`` (T, d) -> (this chip's part of the routed sum + the shared
-    expert (T, d), the experts each token chose (T, k))."""
-    with jax.named_scope("moe"):
-        idx, w = moe_ops.route(x, p["router"], p["bias"],
-                               top_k=cfg.num_experts_per_tok,
-                               scale=cfg.routed_scaling_factor,
-                               normalize=cfg.norm_topk_prob)
-        routed = moe_ops.share_apply(x, idx, w, p["experts"], cfg.share)
-        return routed + swiglu(x, p["shared"]), idx
-
-
-def _ffn(layer, h, cfg: ExaoneMoeConfig):
-    """The feed-forward half of a block on (B, L, d): -> (y, the experts
-    each token chose (B, L, k), or None for the dense layer)."""
-    b, l, d = h.shape
-    x = rms_norm(h, layer["ln_post"], cfg.rms_norm_eps) if cfg.pre_norm else h
-    if "mlp" in layer:
-        with jax.named_scope("dense_mlp"):
-            return h + swiglu(x, layer["mlp"]), None
-    y, idx = expert_layer(layer["moe"], x.reshape(b * l, d), cfg)
-    return h + y.reshape(b, l, d), idx.reshape(b, l, -1)
-
-
-def _routing(chosen, mask, pick, cfg: ExaoneMoeConfig) -> dict:
-    """What a program reports of its routing: ``counts`` (expert layers,
-    held) assignments of the tokens ``mask`` (B, L) marks that landed on
-    each held expert, and ``choices`` (expert layers, B, k) the experts
-    chosen at position ``pick`` (B,) of each sequence."""
-    chosen = [c for c in chosen if c is not None]
-    if not chosen:
-        return {"counts": jnp.zeros((0, cfg.share.held), jnp.int32),
-                "choices": jnp.zeros((0, pick.shape[0], cfg.num_experts_per_tok),
-                                     jnp.int32)}
-    counts = [moe_ops.held_counts(jnp.where(mask[..., None], c, -1), cfg.share)
-              for c in chosen]
-    at = [jnp.take_along_axis(c, pick[:, None, None], axis=1)[:, 0]
-          for c in chosen]
-    return {"counts": jnp.stack(counts), "choices": jnp.stack(at)}
-
-
-def _embed(params, tokens):
-    return params["embed"][tokens]
-
-
-def _head(params, h, cfg: ExaoneMoeConfig):
-    with jax.named_scope("head"):
-        x = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-        return jnp.dot(x, params["head"],
-                       preferred_element_type=jnp.float32)
+def ffn(layer, h, cfg: ExaoneMoeConfig):
+    """The feed-forward half of a block (``lm_blocks.ffn``), its norm where
+    the configuration puts norms before the sublayers."""
+    return _ffn(layer, h, cfg, norm=cfg.pre_norm)
 
 
 # -- prefill ------------------------------------------------------------
@@ -300,14 +229,14 @@ def _prefill_block(layer, layer_type, x, positions, cfg,
             else:
                 pad = ((0, 0), (0, 0), (0, cache_len - l), (0, 0))
                 entry = {"k": jnp.pad(kt, pad), "v": jnp.pad(vt, pad)}
-    y, chosen = _ffn(layer, h, cfg)
+    y, chosen = ffn(layer, h, cfg)
     return y, entry, chosen
 
 
 def prefill_hidden(params, tokens, lengths, cfg: ExaoneMoeConfig,
                    cache_len: Optional[int] = None, active=None):
     """Whole prompts through the blocks: -> (hidden (B, L, d) before the
-    final norm, cache or None, ``_routing`` of the valid tokens).
+    final norm, cache or None, ``routing_report`` of the valid tokens).
     ``tokens`` (B, L) right-padded, ``lengths`` (B,); ``active`` (B,) marks
     the sequences whose routing is counted (all when None).  Padded
     positions compute garbage no valid position ever sees (attention is
@@ -317,7 +246,7 @@ def prefill_hidden(params, tokens, lengths, cfg: ExaoneMoeConfig,
     mask = positions < lengths[:, None]
     if active is not None:
         mask &= active[:, None]
-    x = _embed(params, tokens)
+    x = embed(params, tokens)
     entries, chosen = [], []
     for layer, lt in zip(params["layers"], cfg.layer_types):
         x, entry, c = _prefill_block(layer, lt, x, positions, cfg, cache_len,
@@ -325,7 +254,7 @@ def prefill_hidden(params, tokens, lengths, cfg: ExaoneMoeConfig,
         entries.append(entry)
         chosen.append(c)
     cache = None if cache_len is None else {"layers": entries}
-    return x, cache, _routing(chosen, mask, lengths - 1, cfg)
+    return x, cache, routing_report(chosen, mask, lengths - 1, cfg)
 
 
 def prefill(params, tokens, lengths, cfg: ExaoneMoeConfig, cache_len: int,
@@ -336,7 +265,7 @@ def prefill(params, tokens, lengths, cfg: ExaoneMoeConfig, cache_len: int,
     h, cache, routing = prefill_hidden(params, tokens, lengths, cfg, cache_len,
                                        active)
     last = jnp.take_along_axis(h, (lengths - 1)[:, None, None], axis=1)[:, 0]
-    return _head(params, last, cfg), cache, routing
+    return lm_head(params, last, cfg), cache, routing
 
 
 # -- decode -------------------------------------------------------------
@@ -349,7 +278,7 @@ def decode_step(params, cache, tokens, positions, cfg: ExaoneMoeConfig,
     whose routing is counted (all when None)."""
     b = tokens.shape[0]
     pos2 = positions[:, None]
-    x = _embed(params, tokens)[:, None]                       # (B, 1, d)
+    x = embed(params, tokens)[:, None]                       # (B, 1, d)
     entries, chosen = [], []
     for layer, lt, entry in zip(params["layers"], cfg.layer_types,
                                 cache["layers"]):
@@ -369,11 +298,11 @@ def decode_step(params, cache, tokens, positions, cfg: ExaoneMoeConfig,
             o = attn_ops.decode(q[:, 0], kc, vc, valid)
             h = x + jnp.dot(o.reshape(b, 1, -1), layer["attn"]["wo"])
         entries.append({"k": kc, "v": vc})
-        x, c = _ffn(layer, h, cfg)
+        x, c = ffn(layer, h, cfg)
         chosen.append(c)
     mask = jnp.ones((b, 1), bool) if active is None else active[:, None]
-    return (_head(params, x[:, 0], cfg), {"layers": entries},
-            _routing(chosen, mask, jnp.zeros((b,), jnp.int32), cfg))
+    return (lm_head(params, x[:, 0], cfg), {"layers": entries},
+            routing_report(chosen, mask, jnp.zeros((b,), jnp.int32), cfg))
 
 
 # -- multi-token prediction -----------------------------------------------
@@ -388,7 +317,7 @@ def mtp_logits(params, hidden, next_tokens, cfg: ExaoneMoeConfig):
     with jax.named_scope("mtp"):
         x = jnp.concatenate(
             [rms_norm(hidden, m["ln_hidden"], cfg.rms_norm_eps),
-             rms_norm(_embed(params, next_tokens), m["ln_embed"],
+             rms_norm(embed(params, next_tokens), m["ln_embed"],
                       cfg.rms_norm_eps)], axis=-1)
         x = jnp.dot(x, m["proj"])
         positions = jnp.broadcast_to(jnp.arange(l)[None], (b, l))

@@ -1,0 +1,381 @@
+"""GLM-4.7-Flash (``model_type`` ``glm4_moe_lite``): a decoder with
+multi-head latent attention (MLA; DeepSeek-V2/V3, arXiv:2405.04434,
+2412.19437) in every layer, a dense first layer, sparse experts with one
+shared expert in the others, and a multi-token-prediction (MTP) module.
+
+Pure functions over a parameter tree, as ``models/exaone_moe.py``; the block
+around the attention (norm, SwiGLU, expert layer, head, routing report,
+initialiser) is ``models/lm_blocks.py``'s, which both models import:
+
+* ``prefill(params, tokens, lengths, cfg, cache_len)`` -> (logits at each
+  sequence's last position, cache, routing);
+* ``decode_step(params, cache, tokens, positions, cfg)`` -> (logits, cache,
+  routing);
+* ``mtp_logits(params, hidden, next_tokens, cfg)`` -> logits for ``t + 2``.
+
+The attention layer has ONE set of weights and two forms:
+
+* queries: ``c_q = RMSNorm(x W_qa)``; ``q = c_q W_qb`` -> heads of
+  ``[q_nope | q_rope]``; rotary embedding on ``q_rope``;
+* the latent: ``x W_kva`` -> ``[c_kv | k_rope]``; ``c_kv <- RMSNorm(c_kv)``;
+  rotary on ``k_rope``, one for all heads.  ``kv_lora_rank +
+  qk_rope_head_dim`` numbers a position: all the cache keeps;
+* expanded (prefill): per head ``[k_nope | v] = c_kv W_kvb``, ``k = [k_nope
+  | k_rope]``, causal softmax of ``q.k * scale``, ``o = concat_h(p v) W_o``;
+* absorbed (decode): with ``W_kvb`` split per head into ``W_uk`` and
+  ``W_uv``: ``q_lat = q_nope W_uk^T``; score ``= (q_lat . c_kv + q_rope .
+  k_rope) * scale``; ``o_lat = sum_j p_j c_kv_j``; ``o_h = o_lat W_uv``.  The
+  same mathematics, the cache read as it is stored, once for all heads.
+
+What the published ``config.json`` does not say is ONE choice each here,
+named in ``ASSUMED`` (a configuration file states the four under
+``assumed``, and ``from_dict`` refuses another value): ``scoring_func``
+(sigmoid: what ``topk_method`` ``noaux_tc`` implies), ``rope_pairing``
+(rotate-half; with seeded weights interleaved pairs are a permutation of
+columns), ``softmax_scale`` (``1 / sqrt(qk_nope + qk_rope)``, no
+long-context factor: ``rope_scaling`` is null) and ``mtp_layout``
+(DeepSeek-V3's).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import json
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from can_tpu.models.lm_blocks import (VocabSlice, count_shapes, embed, ffn,
+                                      init_from_shapes, lm_head, rms_norm,
+                                      routing_report)
+from can_tpu.ops import attention as attn_ops
+from can_tpu.ops import cache_layout as layout
+from can_tpu.ops.moe import ExpertShare
+
+# queries and keys of a prefill meet in blocks of this many positions
+# (``ops/attention.py::prefill_causal``): a float32 score block of 20 heads
+# is 84 MB, and a 16,384-token prompt is 136 block pairs.  NOT a measured
+# choice: 512 and 2,048 were never timed (PERF.md section 7)
+PREFILL_BLOCK = 1024
+
+# what config.json leaves open, and the one value of each this module
+# implements (module docstring)
+ASSUMED = {"scoring_func": "sigmoid", "rope_pairing": "rotate_half",
+           "softmax_scale": "1/sqrt(qk_head_dim)",
+           "mtp_layout": "deepseek_v3"}
+
+
+@dataclasses.dataclass(frozen=True)
+class Glm4MoeLiteConfig:
+    hidden_size: int
+    num_attention_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    num_experts_per_tok: int
+    num_shared_experts: int
+    routed_scaling_factor: float
+    norm_topk_prob: bool
+    rms_norm_eps: float
+    rope_theta: float
+    mlp_layer_types: Tuple[str, ...]    # of the layers held
+    share: ExpertShare
+    vocab: VocabSlice
+    mtp_layers: int = 0
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.mlp_layer_types)
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def scale(self) -> float:
+        return self.qk_head_dim ** -0.5
+
+    @classmethod
+    def from_dict(cls, d: dict) -> "Glm4MoeLiteConfig":
+        """From a configuration file: the published ``config.json`` keys
+        with the cut applied (``num_hidden_layers`` kept, ``n_routed_experts``
+        and ``vocab_size`` HELD), ``published`` for the uncut counts,
+        ``deployment`` for the rank, ``assumed`` for what the config leaves
+        open (``ASSUMED``)."""
+        pub = d.get("published", {})
+        rank = int(d.get("deployment", {}).get("rank", 0))
+        n = int(d["num_hidden_layers"])
+        dense = int(d["first_k_dense_replace"])
+        held_e = int(d["n_routed_experts"])
+        tot_e = int(pub.get("n_routed_experts", held_e))
+        held_v = int(d["vocab_size"])
+        tot_v = int(pub.get("vocab_size", held_v))
+        if int(d.get("n_group", 1)) != 1 or int(d.get("topk_group", 1)) != 1:
+            raise ValueError("group-limited routing is not implemented "
+                             "(n_group and topk_group must be 1)")
+        if d.get("rope_scaling") is not None:
+            raise ValueError("rotary scaling is not implemented "
+                             "(rope_scaling must be null)")
+        if float(d.get("partial_rotary_factor", 1)) != 1:
+            raise ValueError("partial_rotary_factor must be 1: the rotary "
+                             "embedding covers the whole of qk_rope_head_dim")
+        if d.get("attention_bias") or d.get("tie_word_embeddings"):
+            raise ValueError("attention biases and tied embeddings are not "
+                             "implemented")
+        ass = d.get("assumed", {})
+        for name, only in ASSUMED.items():
+            if ass.get(name, only) != only:
+                raise ValueError(f"{name} {ass[name]!r} is not implemented "
+                                 f"(only {only!r})")
+        return cls(
+            hidden_size=int(d["hidden_size"]),
+            num_attention_heads=int(d["num_attention_heads"]),
+            q_lora_rank=int(d["q_lora_rank"]),
+            kv_lora_rank=int(d["kv_lora_rank"]),
+            qk_nope_head_dim=int(d["qk_nope_head_dim"]),
+            qk_rope_head_dim=int(d["qk_rope_head_dim"]),
+            v_head_dim=int(d["v_head_dim"]),
+            intermediate_size=int(d["intermediate_size"]),
+            moe_intermediate_size=int(d["moe_intermediate_size"]),
+            num_experts_per_tok=int(d["num_experts_per_tok"]),
+            num_shared_experts=int(d["n_shared_experts"]),
+            routed_scaling_factor=float(d["routed_scaling_factor"]),
+            norm_topk_prob=bool(d["norm_topk_prob"]),
+            rms_norm_eps=float(d["rms_norm_eps"]),
+            rope_theta=float(d["rope_theta"]),
+            mlp_layer_types=tuple(["dense"] * min(dense, n)
+                                  + ["sparse"] * max(n - dense, 0)),
+            share=ExpertShare(rank * held_e, held_e, tot_e),
+            vocab=VocabSlice(rank * held_v, held_v, tot_v),
+            mtp_layers=int(d.get("num_nextn_predict_layers", 0)),
+        )
+
+    @classmethod
+    def from_file(cls, path: str) -> "Glm4MoeLiteConfig":
+        with open(path) as f:
+            return cls.from_dict(json.load(f))
+
+
+# -- parameters ---------------------------------------------------------
+def param_shapes(cfg: Glm4MoeLiteConfig) -> dict:
+    """The tree of shapes (tuples); ``bias`` leaves are float32 buffers.
+    ``x @ w`` everywhere; ``wkv_b``'s columns are head by head ``[k_nope |
+    v]``, ``wq_b``'s ``[q_nope | q_rope]``."""
+    d, h = cfg.hidden_size, cfg.num_attention_heads
+    r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+
+    def mlp(width):
+        return {"gate": (d, width), "up": (d, width), "down": (width, d)}
+
+    def block(mlp_type):
+        out = {"ln_in": (d,), "ln_post": (d,),
+               "attn": {"wq_a": (d, cfg.q_lora_rank),
+                        "q_norm": (cfg.q_lora_rank,),
+                        "wq_b": (cfg.q_lora_rank, h * cfg.qk_head_dim),
+                        "wkv_a": (d, r + dr), "kv_norm": (r,),
+                        "wkv_b": (r, h * (cfg.qk_nope_head_dim
+                                          + cfg.v_head_dim)),
+                        "wo": (h * cfg.v_head_dim, d)}}
+        if mlp_type == "dense":
+            out["mlp"] = mlp(cfg.intermediate_size)
+        else:
+            f, e = cfg.moe_intermediate_size, cfg.share.held
+            out["moe"] = {"router": (d, cfg.share.total),
+                          "bias": (cfg.share.total,),
+                          "experts": {"gate": (e, d, f), "up": (e, d, f),
+                                      "down": (e, f, d)},
+                          "shared": mlp(f * cfg.num_shared_experts)}
+        return out
+
+    tree = {"embed": (cfg.vocab.held, d),
+            "layers": [block(t) for t in cfg.mlp_layer_types],
+            "final_norm": (d,), "head": (d, cfg.vocab.held)}
+    if cfg.mtp_layers:
+        tree["mtp"] = {"ln_hidden": (d,), "ln_embed": (d,), "proj": (2 * d, d),
+                       "block": block("sparse"), "final_norm": (d,)}
+    return tree
+
+
+def param_count(cfg: Glm4MoeLiteConfig) -> int:
+    return count_shapes(param_shapes(cfg))
+
+
+def init_params(key, cfg: Glm4MoeLiteConfig, dtype=jnp.bfloat16):
+    """Parameters from a key, leaf by leaf on the device
+    (``lm_blocks.init_from_shapes``: projections N(0, 1 / fan_in), norms
+    near one, embedding N(0, 1))."""
+    return init_from_shapes(key, param_shapes(cfg), dtype)
+
+
+def cache_layout(cfg: Glm4MoeLiteConfig) -> tuple:
+    """What each held layer keeps in a launch's cache
+    (``ops/cache_layout.py``): the latent and the shared rotary key of every
+    position, no heads.  Two arrays and not one of ``rank + rope_dim``: the
+    issue's default, never timed against the other (PERF.md section 7)."""
+    return (layout.latent_layer(rank=cfg.kv_lora_rank,
+                                rope_dim=cfg.qk_rope_head_dim),
+            ) * cfg.num_layers
+
+
+# -- latent attention ---------------------------------------------------
+def _queries(p, xn, positions, cfg: Glm4MoeLiteConfig):
+    """``xn`` (B, L, d) -> q_nope (B, L, H, nope), q_rope (B, L, H, rope)."""
+    b, l, _ = xn.shape
+    cq = rms_norm(jnp.dot(xn, p["wq_a"]), p["q_norm"], cfg.rms_norm_eps)
+    q = jnp.dot(cq, p["wq_b"]).reshape(b, l, cfg.num_attention_heads,
+                                       cfg.qk_head_dim)
+    return (q[..., :cfg.qk_nope_head_dim],
+            attn_ops.rope(q[..., cfg.qk_nope_head_dim:], positions,
+                          cfg.rope_theta))
+
+
+def _latent(p, xn, positions, cfg: Glm4MoeLiteConfig):
+    """``xn`` (B, L, d) -> c_kv (B, L, rank) normalised, k_rope (B, L, rope)
+    rotated: what the cache keeps of these positions."""
+    kv = jnp.dot(xn, p["wkv_a"])
+    return (rms_norm(kv[..., :cfg.kv_lora_rank], p["kv_norm"],
+                     cfg.rms_norm_eps),
+            attn_ops.rope(kv[..., cfg.kv_lora_rank:], positions,
+                          cfg.rope_theta))
+
+
+def _up_projections(p, cfg: Glm4MoeLiteConfig):
+    """``W_kvb`` per head: W_uk (rank, H, nope), W_uv (rank, H, v)."""
+    w = p["wkv_b"].reshape(cfg.kv_lora_rank, cfg.num_attention_heads,
+                           cfg.qk_nope_head_dim + cfg.v_head_dim)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+def attention_expanded(p, xn, positions, lengths, cfg: Glm4MoeLiteConfig):
+    """Whole prompts, keys and values rebuilt per head from the latent:
+    -> (the layer's output (B, L, d) before the residual, c_kv, k_rope)."""
+    b, l, _ = xn.shape
+    h = cfg.num_attention_heads
+    q_nope, q_rope = _queries(p, xn, positions, cfg)
+    ckv, krope = _latent(p, xn, positions, cfg)
+    kv = jnp.dot(ckv, p["wkv_b"]).reshape(b, l, h, -1)
+    q = jnp.concatenate([q_nope, q_rope], -1)
+    k = jnp.concatenate(
+        [kv[..., :cfg.qk_nope_head_dim],
+         jnp.broadcast_to(krope[:, :, None], (b, l, h, krope.shape[-1]))], -1)
+    o = attn_ops.prefill_causal(q, k, kv[..., cfg.qk_nope_head_dim:], lengths,
+                                scale=cfg.scale, block=PREFILL_BLOCK)
+    return jnp.dot(o.reshape(b, l, -1), p["wo"]), ckv, krope
+
+
+def attention_absorbed(p, xn, positions, entry, cfg: Glm4MoeLiteConfig):
+    """One token a sequence, in the latent space: ``xn`` (B, 1, d) at
+    ``positions`` (B,), its latent written into ``entry`` before it attends
+    -> (the layer's output (B, 1, d), the entry)."""
+    b = xn.shape[0]
+    q_nope, q_rope = _queries(p, xn, positions[:, None], cfg)
+    ckv, krope = _latent(p, xn, positions[:, None], cfg)
+    ckv_c = attn_ops.write_row(entry["ckv"], ckv[:, 0], positions)
+    krope_c = attn_ops.write_row(entry["krope"], krope[:, 0], positions)
+    w_uk, w_uv = _up_projections(p, cfg)
+    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_uk)
+    valid = jnp.arange(ckv_c.shape[1])[None, :] <= positions[:, None]
+    o_lat = attn_ops.decode_latent(q_lat, q_rope[:, 0], ckv_c, krope_c, valid,
+                                   scale=cfg.scale)
+    o = jnp.einsum("bhr,rhv->bhv", o_lat, w_uv)
+    return (jnp.dot(o.reshape(b, 1, -1), p["wo"]),
+            {"ckv": ckv_c, "krope": krope_c})
+
+
+# -- prefill ------------------------------------------------------------
+def _prefill_block(layer, x, positions, cfg, cache_len: Optional[int],
+                   lengths):
+    """One block over whole prompts; -> (y, cache entry or None, chosen)."""
+    with jax.named_scope("attn"):
+        xn = rms_norm(x, layer["ln_in"], cfg.rms_norm_eps)
+        o, ckv, krope = attention_expanded(layer["attn"], xn, positions,
+                                           lengths, cfg)
+        h = x + o
+        entry = None
+        if cache_len is not None:
+            pad = ((0, 0), (0, cache_len - x.shape[1]), (0, 0))
+            entry = {"ckv": jnp.pad(ckv, pad), "krope": jnp.pad(krope, pad)}
+    y, chosen = ffn(layer, h, cfg)
+    return y, entry, chosen
+
+
+def prefill_hidden(params, tokens, lengths, cfg: Glm4MoeLiteConfig,
+                   cache_len: Optional[int] = None, active=None):
+    """Whole prompts through the blocks: -> (hidden (B, L, d) before the
+    final norm, cache or None, ``routing_report`` of the valid tokens).
+    ``tokens`` (B, L) right-padded, ``lengths`` (B,); ``active`` (B,) marks
+    the sequences whose routing is counted (all when None).  Padded
+    positions compute garbage (or nothing: attention skips whole blocks of
+    them) that no valid position ever sees."""
+    b, l = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(l)[None], (b, l))
+    mask = positions < lengths[:, None]
+    if active is not None:
+        mask &= active[:, None]
+    x = embed(params, tokens)
+    entries, chosen = [], []
+    for layer in params["layers"]:
+        x, entry, c = _prefill_block(layer, x, positions, cfg, cache_len,
+                                     lengths)
+        entries.append(entry)
+        chosen.append(c)
+    cache = None if cache_len is None else {"layers": entries}
+    return x, cache, routing_report(chosen, mask, lengths - 1, cfg)
+
+
+def prefill(params, tokens, lengths, cfg: Glm4MoeLiteConfig, cache_len: int,
+            active=None):
+    """-> (float32 logits (B, V) at each sequence's last position, a cache
+    of ``cache_len`` positions, routing)."""
+    h, cache, routing = prefill_hidden(params, tokens, lengths, cfg, cache_len,
+                                       active)
+    last = jnp.take_along_axis(h, (lengths - 1)[:, None, None], axis=1)[:, 0]
+    return lm_head(params, last, cfg), cache, routing
+
+
+# -- decode -------------------------------------------------------------
+def decode_step(params, cache, tokens, positions, cfg: Glm4MoeLiteConfig,
+                active=None):
+    """One token per sequence: ``tokens`` (B,) at ``positions`` (B,) ->
+    (float32 logits (B, V) for the next position, cache, routing).
+    ``active`` (B,) marks the slots whose routing is counted."""
+    b = tokens.shape[0]
+    x = embed(params, tokens)[:, None]                        # (B, 1, d)
+    entries, chosen = [], []
+    for layer, entry in zip(params["layers"], cache["layers"]):
+        with jax.named_scope("attn"):
+            xn = rms_norm(x, layer["ln_in"], cfg.rms_norm_eps)
+            o, entry = attention_absorbed(layer["attn"], xn, positions, entry,
+                                          cfg)
+            h = x + o
+        entries.append(entry)
+        x, c = ffn(layer, h, cfg)
+        chosen.append(c)
+    mask = jnp.ones((b, 1), bool) if active is None else active[:, None]
+    return (lm_head(params, x[:, 0], cfg), {"layers": entries},
+            routing_report(chosen, mask, jnp.zeros((b,), jnp.int32), cfg))
+
+
+# -- multi-token prediction -----------------------------------------------
+def mtp_logits(params, hidden, next_tokens, cfg: Glm4MoeLiteConfig):
+    """The MTP module in DeepSeek-V3's form, over whole sequences:
+    ``h' = W_p [RMSNorm(h_t); RMSNorm(Emb(x_{t+1}))]``, one block of the
+    model's own kind (latent attention + expert layer), the module's norm
+    and the SHARED head: float32 logits (B, L, V) for position ``t + 2``."""
+    m = params["mtp"]
+    b, l, _ = hidden.shape
+    with jax.named_scope("mtp"):
+        x = jnp.concatenate(
+            [rms_norm(hidden, m["ln_hidden"], cfg.rms_norm_eps),
+             rms_norm(embed(params, next_tokens), m["ln_embed"],
+                      cfg.rms_norm_eps)], axis=-1)
+        x = jnp.dot(x, m["proj"])
+        positions = jnp.broadcast_to(jnp.arange(l)[None], (b, l))
+        x, _, _ = _prefill_block(m["block"], x, positions, cfg, None, None)
+        x = rms_norm(x, m["final_norm"], cfg.rms_norm_eps)
+        return jnp.dot(x, params["head"], preferred_element_type=jnp.float32)

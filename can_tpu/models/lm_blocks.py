@@ -1,0 +1,127 @@
+"""What the language models' decoder blocks have in common, as pure
+functions over a parameter tree: the norm, SwiGLU, the expert layer with
+its shared expert, the feed-forward half of a block, the head, the routing
+report a serving program returns, and the seeded initialiser.  A model's
+module (``exaone_moe.py``, ``glm_moe_lite.py``) brings its own attention and
+its own config class; the config offers ``rms_norm_eps``,
+``num_experts_per_tok``, ``routed_scaling_factor``, ``norm_topk_prob`` and
+``share`` (``ops.moe.ExpertShare``).
+
+Weights and activations follow the parameter tree's dtype; the router, the
+norms' statistics and the logits are float32.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+from can_tpu.ops import moe as moe_ops
+from can_tpu.ops.moe import ExpertShare
+
+
+class VocabSlice(ExpertShare):
+    """Rows ``first .. first + held - 1`` of the ``total`` vocabulary."""
+
+    __slots__ = ()
+
+
+# -- parameters ---------------------------------------------------------
+def count_shapes(shapes: dict) -> int:
+    """Numbers in a tree of shapes (tuples)."""
+    return sum(math.prod(s) for s in
+               jax.tree.leaves(shapes, is_leaf=lambda x: isinstance(x, tuple)))
+
+
+def _leaf(key, name: str, shape, dtype):
+    if name.startswith("ln_") or name.endswith("_norm"):
+        return (1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
+    if name == "bias":      # the router's correction bias: a float32 buffer
+        return 0.05 * jax.random.normal(key, shape, jnp.float32)
+    if name == "embed":
+        return jax.random.normal(key, shape, dtype)
+    fan_in = shape[-2]
+    return jax.random.normal(key, shape, dtype) * jnp.asarray(fan_in ** -0.5, dtype)
+
+
+def init_from_shapes(key, shapes: dict, dtype=jnp.bfloat16):
+    """Parameters from a key for a tree of shapes, leaf by leaf on the
+    device (one jitted call a leaf: no float32 copy of the whole tree is
+    ever alive).  By the leaf's name: ``ln_*`` / ``*_norm`` norms near one,
+    ``bias`` a float32 buffer, ``embed`` N(0, 1), every other a projection
+    N(0, 1 / fan_in) so that activations stay of order one."""
+    flat, treedef = jax.tree_util.tree_flatten_with_path(
+        shapes, is_leaf=lambda x: isinstance(x, tuple))
+    make = jax.jit(_leaf, static_argnums=(1, 2, 3))
+    leaves = []
+    for i, (path, shape) in enumerate(flat):
+        leaves.append(make(jax.random.fold_in(key, i), str(path[-1].key), shape,
+                           dtype))
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+# -- layers -------------------------------------------------------------
+def rms_norm(x, g, eps: float):
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (y * g.astype(jnp.float32)).astype(x.dtype)
+
+
+def swiglu(x, p):
+    return jnp.dot(jax.nn.silu(jnp.dot(x, p["gate"])) * jnp.dot(x, p["up"]),
+                   p["down"])
+
+
+def expert_layer(p, x, cfg):
+    """``x`` (T, d) -> (this chip's part of the routed sum + the shared
+    expert (T, d), the experts each token chose (T, k))."""
+    with jax.named_scope("moe"):
+        idx, w = moe_ops.route(x, p["router"], p["bias"],
+                               top_k=cfg.num_experts_per_tok,
+                               scale=cfg.routed_scaling_factor,
+                               normalize=cfg.norm_topk_prob)
+        routed = moe_ops.share_apply(x, idx, w, p["experts"], cfg.share)
+        return routed + swiglu(x, p["shared"]), idx
+
+
+def ffn(layer, h, cfg, *, norm: bool = True):
+    """The feed-forward half of a block on (B, L, d), ``h + F(RMSNorm(h))``
+    (``norm`` False: ``h + F(h)``): -> (y, the experts each token chose
+    (B, L, k), or None for the dense layer)."""
+    b, l, d = h.shape
+    x = rms_norm(h, layer["ln_post"], cfg.rms_norm_eps) if norm else h
+    if "mlp" in layer:
+        with jax.named_scope("dense_mlp"):
+            return h + swiglu(x, layer["mlp"]), None
+    y, idx = expert_layer(layer["moe"], x.reshape(b * l, d), cfg)
+    return h + y.reshape(b, l, d), idx.reshape(b, l, -1)
+
+
+def routing_report(chosen, mask, pick, cfg) -> dict:
+    """What a program reports of its routing: ``counts`` (expert layers,
+    held) assignments of the tokens ``mask`` (B, L) marks that landed on
+    each held expert, and ``choices`` (expert layers, B, k) the experts
+    chosen at position ``pick`` (B,) of each sequence."""
+    chosen = [c for c in chosen if c is not None]
+    if not chosen:
+        return {"counts": jnp.zeros((0, cfg.share.held), jnp.int32),
+                "choices": jnp.zeros((0, pick.shape[0], cfg.num_experts_per_tok),
+                                     jnp.int32)}
+    counts = [moe_ops.held_counts(jnp.where(mask[..., None], c, -1), cfg.share)
+              for c in chosen]
+    at = [jnp.take_along_axis(c, pick[:, None, None], axis=1)[:, 0]
+          for c in chosen]
+    return {"counts": jnp.stack(counts), "choices": jnp.stack(at)}
+
+
+def embed(params, tokens):
+    return params["embed"][tokens]
+
+
+def lm_head(params, h, cfg):
+    with jax.named_scope("head"):
+        x = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
+        return jnp.dot(x, params["head"],
+                       preferred_element_type=jnp.float32)

@@ -1,5 +1,7 @@
-"""Grouped-query attention for a decoder: prefill over a whole prompt in
-blocks, and one-token decode over a cache.
+"""Attention for a decoder: prefill over a whole prompt in blocks, and
+one-token decode over a cache.  Grouped-query attention first; latent
+attention (one compressed key/value latent a position for all heads) at the
+end of the module.
 
 Shapes: ``q`` (B, L, KV, G, D) with G query heads to each of the KV
 key/value heads; ``k``, ``v`` (B, L, KV, D).  Scores and the softmax are
@@ -13,6 +15,17 @@ against the keys up to its own end.  Decode reads a cache laid out
 (B, KV, S, D): the whole context for a full layer, a ring of ``window``
 slots for a window layer, in which slot ``r`` holds the newest position
 ``p <= pos`` with ``p % window == r``.
+
+Latent attention keeps (B, S, rank) latents and (B, S, rope_dim) rotary keys,
+no heads.  Its prefill rebuilds keys and values per head and runs
+``prefill_causal``: a loop over blocks of queries and, inside, over the
+blocks of keys up to the diagonal with a running softmax, ONE compiled body
+whatever the prompt's length (``prefill_full`` unrolls one pair of products
+per query block, each of another shape: 64 a layer at 16,384 positions), no
+score block larger than ``block x block`` a head, and no work for blocks
+past a sequence's own length.  Its decode (``decode_latent``) is the
+absorbed form: queries carried into the latent space, the cache read as it
+is stored.
 """
 
 from __future__ import annotations
@@ -117,3 +130,80 @@ def write_slot(cache, new, slot):
     (B,), one slot per sequence."""
     b = cache.shape[0]
     return cache.at[jnp.arange(b), :, slot].set(new.astype(cache.dtype))
+
+
+# -- latent attention -------------------------------------------------------
+def prefill_causal(q, k, v, lengths=None, *, scale=None, block: int = 1024):
+    """Causal attention over whole prompts with a running softmax.
+
+    ``q``, ``k`` (B, L, H, D), ``v`` (B, L, H, Dv) -> (B, L, H, Dv).  One
+    sequence at a time (``lax.map``); its queries in blocks of ``block``,
+    each against the key blocks before it (no mask) and its own (the causal
+    mask).  ``lengths`` (B,): blocks of queries wholly past a sequence's
+    length are not computed and stay zero (no valid position reads them)."""
+    b, l, h, d = q.shape
+    block = min(block, l)
+    if l % block:
+        raise ValueError(f"prompt bucket {l} is not a multiple of the "
+                         f"attention block {block}")
+    scale = d ** -0.5 if scale is None else scale
+    dv = v.shape[-1]
+    if lengths is None:
+        lengths = jnp.full((b,), l, jnp.int32)
+    causal = jnp.arange(block)[None, :] <= jnp.arange(block)[:, None]
+
+    def one(args):
+        qs, ks, vs, n = args                       # (L, H, D) of one sequence
+
+        def q_block(i, out):
+            qb = jax.lax.dynamic_slice_in_dim(qs, i * block, block, 0)
+
+            def k_block(j, carry, mask=None):
+                m, den, acc = carry
+                kb = jax.lax.dynamic_slice_in_dim(ks, j * block, block, 0)
+                vb = jax.lax.dynamic_slice_in_dim(vs, j * block, block, 0)
+                s = jnp.einsum("qhd,khd->hqk", qb, kb,
+                               preferred_element_type=jnp.float32) * scale
+                if mask is not None:
+                    s = jnp.where(mask, s, NEG)
+                m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+                p = jnp.exp(s - m_new[..., None])
+                fade = jnp.exp(m - m_new)
+                pv = jnp.einsum("hqk,khd->hqd", p.astype(vs.dtype), vb,
+                                preferred_element_type=jnp.float32)
+                return (m_new, den * fade + jnp.sum(p, axis=-1),
+                        acc * fade[..., None] + pv)
+
+            carry = (jnp.full((h, block), NEG, jnp.float32),
+                     jnp.zeros((h, block), jnp.float32),
+                     jnp.zeros((h, block, dv), jnp.float32))
+            carry = jax.lax.fori_loop(0, i, k_block, carry)
+            _, den, acc = k_block(i, carry, causal)
+            o = (acc / den[..., None]).astype(qs.dtype).transpose(1, 0, 2)
+            return jax.lax.dynamic_update_slice_in_dim(out, o, i * block, 0)
+
+        blocks = jnp.clip((n + block - 1) // block, 1, l // block)
+        return jax.lax.fori_loop(0, blocks, q_block,
+                                 jnp.zeros((l, h, dv), qs.dtype))
+
+    return jax.lax.map(one, (q, k, v, lengths.astype(jnp.int32)))
+
+
+def decode_latent(q_lat, q_rope, ckv, krope, valid, *, scale: float):
+    """One query per sequence against a latent cache, in the latent space.
+    ``q_lat`` (B, H, R): the queries' no-rotary part carried through the key
+    up-projection; ``q_rope`` (B, H, Dr); ``ckv`` (B, S, R), ``krope``
+    (B, S, Dr); ``valid`` (B, S) -> the attended latents (B, H, R), which the
+    value up-projection turns into the heads' outputs."""
+    s = (jnp.einsum("bhr,bsr->bhs", q_lat, ckv,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bhd,bsd->bhs", q_rope, krope,
+                      preferred_element_type=jnp.float32)) * scale
+    return _softmax_av(s, valid[:, None, :], ckv, "bhs,bsr->bhr", q_lat.dtype)
+
+
+def write_row(cache, new, pos):
+    """``cache`` (B, S, D) with ``new`` (B, D) written at position ``pos``
+    (B,), one row per sequence."""
+    b = cache.shape[0]
+    return cache.at[jnp.arange(b), pos].set(new.astype(cache.dtype))

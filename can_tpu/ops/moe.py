@@ -41,7 +41,11 @@ import jax.numpy as jnp
 # / sorted, ms: 64 tokens 1.75 / 3.92, 128 1.72 / 3.99, 256 2.01 / 4.10, 384
 # 2.89 / 4.21, 512 4.08 / 4.21, 768 5.61 / 4.71, 1024 7.80 / 4.97, 2048 16.0 /
 # 8.02: they cross near 550, where the tile arithmetic puts it (T * held =
-# T * k * held / total + held * 512, the grouped kernel's tile: T = 546)
+# T * k * held / total + held * 512, the grouped kernel's tile: T = 546).
+# NOT re-read where every expert is held (64 of 64, 2048 x 1536, top-4: a
+# decode step of 16 tokens takes the batched form and reads all 64 experts;
+# the same arithmetic would put the crossing at T = 546 there too, but
+# nobody timed it: PERF.md section 7)
 DENSE_MAX_TOKENS = 512
 
 

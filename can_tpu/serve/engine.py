@@ -476,12 +476,13 @@ class LMEngine:
                       slices=len(slices)) as sp:
                 cache = self._new_cache(slots, bucket)
                 outs = []
-                for lo, n in slices:
+                for i, (lo, n) in enumerate(slices):
                     part = {"tokens": batch.tokens[lo:lo + n],
                             "lengths": batch.lengths[lo:lo + n],
                             "active": live[lo:lo + n]}
-                    out, cache = self._prefill(self.params, part, cache,
-                                               np.int32(lo))
+                    with span("lm.prefill.dispatch", slice=i, start_slot=lo):
+                        out, cache = self._prefill(self.params, part, cache,
+                                                   np.int32(lo))
                     compiled |= self._prefill.last_first_call
                     outs.append(out)
                 state, pre = self._new_state(outs, batch.lengths, live)
@@ -532,7 +533,7 @@ class LMEngine:
                                      int((pre_counts + dec_counts).max(initial=0)))
         c["assignments_held"] += held
         c["assignments_all"] += every
-        c["cache_bytes"] = kv_cache.nbytes_by_kind(cache, p.layer_kinds)
+        c["cache_bytes"] = kv_cache.nbytes_by_kind(cache, p.cache_layout)
         self.last_launch = launch
 
     def warmup(self, buckets, max_batch: int, *, sizes=None) -> dict:
@@ -566,8 +567,8 @@ def _with_cache_signature(args) -> tuple:
     AND of the cache: the launch size and the bucket both choose the
     program."""
     flat = dict(args[1])
-    for i, entry in enumerate(args[2]["layers"]):
-        flat[f"cache{i}"] = entry["k"]
+    for i, leaf in enumerate(kv_cache.signature_leaves(args[2])):
+        flat[f"cache{i}"] = leaf
     return batch_signature(flat)
 
 

@@ -63,7 +63,12 @@ def cannet_predict(serve_dtype: str, compute_dtype):
 
 
 class LMPrograms:
-    """``models/exaone_moe.py``'s pure functions as serving programs.
+    """A language model's pure functions as serving programs.  The model is
+    GIVEN: ``model`` offers ``prefill(params, tokens, lengths, cfg,
+    cache_len, active=)``, ``decode_step(params, cache, tokens, positions,
+    cfg, active=)`` and ``cache_layout(cfg)`` (an
+    ``ops.cache_layout.LayerSpec`` for each layer held); ``cfg`` offers ``mlp_layer_types``, ``share``
+    (``ops.moe.ExpertShare``), ``vocab`` and ``num_experts_per_tok``.
 
     State between the programs of a launch, all on the device: the cache
     (``serve/cache.py``) and ``state``: ``tokens`` (slots,) the token each
@@ -75,25 +80,24 @@ class LMPrograms:
     expert.  Greedy: the next token is the argmax over the vocabulary slice.
     """
 
-    def __init__(self, cfg, *, max_new_tokens: int, dtype=jnp.bfloat16):
-        from can_tpu.models import exaone_moe
-
-        self._m = exaone_moe
+    def __init__(self, model, cfg, *, max_new_tokens: int,
+                 dtype=jnp.bfloat16):
+        self._m = model
         self.cfg = cfg
         self.max_new_tokens = int(max_new_tokens)
         self.dtype = dtype   # of the cache: the parameters' own
-        self.layer_kinds = tuple(
-            kv_cache.RING if t == exaone_moe.WINDOW else kv_cache.FULL
-            for t in cfg.layer_types)
+        self.cache_layout = tuple(model.cache_layout(cfg))
         self.expert_layers = sum(t != "dense" for t in cfg.mlp_layer_types)
         self.vocab_size = cfg.vocab.held   # ids and logits are over the slice
 
+    def positions(self, bucket: int) -> int:
+        """Positions of context a launch of ``bucket``-token prompts needs."""
+        return bucket + self.max_new_tokens
+
     def new_cache(self, slots: int, bucket: int):
-        cfg = self.cfg
-        return kv_cache.allocate(
-            self.layer_kinds, slots=slots, kv_heads=cfg.num_key_value_heads,
-            head_dim=cfg.head_dim, positions=bucket + self.max_new_tokens,
-            window=cfg.sliding_window, dtype=self.dtype)
+        return kv_cache.allocate(self.cache_layout, slots=slots,
+                                 positions=self.positions(bucket),
+                                 dtype=self.dtype)
 
     def new_state(self, outs, lengths, active):
         """The slices' outputs put together and the decode state after
@@ -115,10 +119,9 @@ class LMPrograms:
         """``batch``: {"tokens" (s, L), "lengths" (s,), "active" (s,)} for
         slots ``start .. start + s - 1`` -> ({"first", "logits", "choices",
         "counts"}, the cache with those slots' rows written)."""
-        cache_len = max(e["k"].shape[2] for e in cache["layers"])
         logits, part, routing = self._m.prefill(
-            params, batch["tokens"], batch["lengths"], self.cfg, cache_len,
-            active=batch["active"])
+            params, batch["tokens"], batch["lengths"], self.cfg,
+            self.positions(batch["tokens"].shape[1]), active=batch["active"])
         cache = jax.tree.map(
             lambda c, p: jax.lax.dynamic_update_slice_in_dim(
                 c, p.astype(c.dtype), start, axis=0), cache, part)
@@ -158,14 +161,30 @@ class ServingModel(NamedTuple):
     service: Callable
 
 
-def _exaone_moe_programs(config: dict, params, seed: int):
+def _lm_programs(model_of):
+    """``ServingModel.programs`` for a language model: ``model_of()`` ->
+    (the model's module, its config class), imported when first asked for."""
+    def programs(config: dict, params, seed: int):
+        model, config_class = model_of()
+        cfg = config_class.from_dict(config)
+        if params is None:
+            params = model.init_params(jax.random.key(seed), cfg)
+        return LMPrograms(model, cfg,
+                          max_new_tokens=int(config["max_new_tokens"]),
+                          dtype=params["embed"].dtype), params
+    return programs
+
+
+def _exaone_moe():
     from can_tpu.models import exaone_moe
 
-    cfg = exaone_moe.ExaoneMoeConfig.from_dict(config)
-    if params is None:
-        params = exaone_moe.init_params(jax.random.key(seed), cfg)
-    return LMPrograms(cfg, max_new_tokens=int(config["max_new_tokens"]),
-                      dtype=params["embed"].dtype), params
+    return exaone_moe, exaone_moe.ExaoneMoeConfig
+
+
+def _glm_moe_lite():
+    from can_tpu.models import glm_moe_lite
+
+    return glm_moe_lite, glm_moe_lite.Glm4MoeLiteConfig
 
 
 def _lm_engine(params, programs, config: dict, telemetry):
@@ -182,8 +201,10 @@ def _generate_service(engine, config: dict, **kw):
 
 
 MODEL_TYPES = {
-    "exaone_moe": ServingModel(_exaone_moe_programs, _lm_engine,
+    "exaone_moe": ServingModel(_lm_programs(_exaone_moe), _lm_engine,
                                _generate_service),
+    "glm4_moe_lite": ServingModel(_lm_programs(_glm_moe_lite), _lm_engine,
+                                  _generate_service),
 }
 
 

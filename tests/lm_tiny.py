@@ -37,3 +37,36 @@ def tiny_model(seed=0, dtype=jnp.float32, **kw):
     d = tiny_config(**kw)
     cfg = em.ExaoneMoeConfig.from_dict(d)
     return d, cfg, em.init_params(jax.random.key(seed), cfg, dtype)
+
+
+def tiny_glm_config(*, held=16, rank=0, mtp=1, **assumed) -> dict:
+    """The tiny GLM-4.7-Flash preset, as a configuration-file dict: latent
+    attention (query rank 24, latent 16 + 8 rotary, 4 heads of 8 + 8 / 16),
+    a dense first layer and two expert layers of 16 experts (top-4, one
+    shared), an MTP layer; ``held`` of the 16 experts live on rank ``rank``."""
+    return {
+        "model_type": "glm4_moe_lite", "attention_bias": False,
+        "first_k_dense_replace": 1, "hidden_size": 64,
+        "intermediate_size": 160, "moe_intermediate_size": 32,
+        "n_group": 1, "topk_group": 1, "topk_method": "noaux_tc",
+        "norm_topk_prob": True, "num_attention_heads": 4,
+        "n_routed_experts": held, "n_shared_experts": 1,
+        "num_experts_per_tok": 4, "num_hidden_layers": 3,
+        "num_nextn_predict_layers": mtp, "partial_rotary_factor": 1,
+        "rms_norm_eps": 1e-5, "rope_scaling": None, "rope_theta": 1000000,
+        "routed_scaling_factor": 1.8, "tie_word_embeddings": False,
+        "q_lora_rank": 24, "kv_lora_rank": 16, "qk_nope_head_dim": 8,
+        "qk_rope_head_dim": 8, "v_head_dim": 16, "vocab_size": 256,
+        "published": {"n_routed_experts": 16, "vocab_size": 256},
+        "deployment": {"rank": rank},
+        "assumed": assumed,
+    }
+
+
+def tiny_glm_model(seed=0, dtype=jnp.float32, **kw):
+    """-> (config dict, Glm4MoeLiteConfig, params)."""
+    from can_tpu.models import glm_moe_lite as gm
+
+    d = tiny_glm_config(**kw)
+    cfg = gm.Glm4MoeLiteConfig.from_dict(d)
+    return d, cfg, gm.init_params(jax.random.key(seed), cfg, dtype)

@@ -27,6 +27,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "benchmark")
 SERVE_DRIVER = "harness/drive_serve.py"
 LM_DRIVER = "harness/drive_lm_serve.py"
+GLM_DRIVER = "harness/drive_glm_serve.py"   # EngineProbe is LM_DRIVER's
 
 
 def _sources():
@@ -84,8 +85,11 @@ def _uses(rel, holder):
 def _probe_own(rel):
     """What a driver's ``EngineProbe`` defines itself (``engine.launches``
     and the like are the probe's, not the engine's)."""
-    cls = next(n for n in ast.walk(SOURCES[rel])
-               if isinstance(n, ast.ClassDef) and n.name == "EngineProbe")
+    cls = next((n for n in ast.walk(SOURCES[rel])
+                if isinstance(n, ast.ClassDef) and n.name == "EngineProbe"),
+               None)
+    if cls is None:          # imported from the driver that defines it
+        return _probe_own(LM_DRIVER)
     own = {m.name for m in cls.body if isinstance(m, ast.FunctionDef)}
     own |= {c.args[1].value for c in ast.walk(cls)
             if isinstance(c, ast.Call)
@@ -112,7 +116,8 @@ def _stats_keys(rel):
 # names its own; the language-model driver is handed what
 # ``build_model_service`` builds for ``exaone_moe``
 ROLES = {SERVE_DRIVER: ("CountService", "ServeEngine"),
-         LM_DRIVER: ("GenerateService", "LMEngine")}
+         LM_DRIVER: ("GenerateService", "LMEngine"),
+         GLM_DRIVER: ("GenerateService", "LMEngine")}
 # read THROUGH the probe by the service (``EngineProbe.__getattr__`` forwards
 # to the engine), so no benchmark file spells them
 FORWARDED = ("last_batch_compiled", "launches_in_flight")
@@ -129,8 +134,10 @@ def _attribute_cases():
 
 
 def _method_keyword_cases():
+    # ``self._service``: the door ``drive_glm_serve`` puts before the service
     return sorted({(ROLES[rel][0], m, k) for rel in ROLES
-                   for m, k in _method_keywords(rel, "service")})
+                   for holder in ("service", "self._service")
+                   for m, k in _method_keywords(rel, holder)})
 
 
 def _stats_cases():
@@ -168,8 +175,10 @@ def _accepts(fn, keyword):
 def test_the_collection_found_the_benchmark():
     # an empty collection would pass vacuously: the drivers are there and
     # each list holds what the drivers are known to use
-    assert {SERVE_DRIVER, LM_DRIVER, "harness/drive_train.py",
+    assert {SERVE_DRIVER, LM_DRIVER, GLM_DRIVER, "harness/drive_train.py",
             "run.py"} <= set(SOURCES)
+    assert ("can_tpu.serve.programs", "MODEL_TYPES") in _imported()
+    assert ("GenerateService", "submit", "want_logits") in _method_keyword_cases()
     assert ("can_tpu.serve", "RejectedError") in _imported()
     assert ("can_tpu.serve", "CountService", "max_batch") in _call_keywords()
     assert ("CountService", "warmup") in _attribute_cases()
@@ -230,3 +239,75 @@ def stats_of():
 @pytest.mark.parametrize("cls,key", _stats_cases())
 def test_a_stats_key_the_drivers_read_is_there(stats_of, cls, key):
     assert key in stats_of[cls]
+
+
+# -- what the language-model cells take beyond names and keywords ----------
+def _lm_configs():
+    import json
+
+    out = []
+    for f in sorted(os.listdir(os.path.join(BENCH, "configs"))):
+        with open(os.path.join(BENCH, "configs", f)) as fh:
+            config = json.load(fh)
+        if "model_type" in config:
+            out.append((f[:-len(".json")], config))
+    return out
+
+
+@pytest.mark.parametrize("name,config", _lm_configs(),
+                         ids=[n for n, _ in _lm_configs()])
+def test_a_configuration_s_model_type_is_served(name, config):
+    """``drive_glm_serve`` asks ``MODEL_TYPES`` before it opens a device; a
+    configuration file also names its driver's modules."""
+    from can_tpu.serve.programs import MODEL_TYPES, serving_model
+
+    assert config["model_type"] in MODEL_TYPES
+    assert callable(serving_model(config["model_type"]).programs)
+    for key in ("reference", "weights", "work"):
+        if key in config:
+            assert os.path.isfile(os.path.join(
+                REPO, *config[key].split(".")) + ".py"), config[key]
+
+
+@pytest.mark.parametrize("module,name", [
+    ("can_tpu.ops.attention", "write_slot"),   # calibrate_lm.late_write
+    ("can_tpu.ops.attention", "write_row"),    # calibrate_glm.late_write
+])
+def test_a_function_the_calibration_breaks_is_called_through_its_module(
+        module, name):
+    """The calibration replaces the module's attribute: the model has to
+    reach the function through the module, not through a name of its own."""
+    assert callable(_resolve(module, name))
+    models = os.path.join(REPO, "can_tpu", "models")
+    callers = [f for f in sorted(os.listdir(models)) if f.endswith(".py")
+               and f"attn_ops.{name}(" in open(os.path.join(models, f)).read()]
+    assert callers, f"no model calls attn_ops.{name}"
+
+
+@pytest.mark.parametrize("span,attrs", [
+    ("lm.prefill", ("tokens", "valid_tokens")),   # prefill_pad_token_pct.lm
+    ("lm.prefill.dispatch", ("slice", "start_slot")),      # the idle gaps' names
+    ("lm.decode.dispatch", ("decode_step",)),
+])
+def test_a_span_the_lm_readers_read_is_recorded(span, attrs):
+    from can_tpu.serve.engine import LMEngine
+
+    source = inspect.getsource(LMEngine.generate_batch)
+    call = source[source.index(f'span("{span}"'):]
+    call = call[:call.index(" as ")] if " as " in call[:400] else call[:400]
+    for a in attrs:
+        assert f"{a}=" in call, f"{span} lacks {a}"
+
+
+@pytest.mark.parametrize("key", ["cache_bytes", "generated_tokens", "launches"])
+def test_a_counter_the_lm_readers_read_is_kept(key):
+    """``stats()["lm"]`` is the engine's counters: ``latent_cache_bytes_per_pos.lm``
+    reads ``cache_bytes`` (by kind, from ``serve/cache.py``)."""
+    from can_tpu.ops import cache_layout as layout
+    from can_tpu.serve import cache as kv_cache
+    from can_tpu.serve.engine import LMEngine
+
+    assert f'"{key}"' in inspect.getsource(LMEngine)
+    spec = layout.latent_layer(rank=4, rope_dim=2)
+    made = kv_cache.allocate((spec,), slots=1, positions=3)
+    assert kv_cache.nbytes_by_kind(made, (spec,)) == {"latent": 3 * 6 * 2}

@@ -172,21 +172,27 @@ def test_dp2_sp2_train_step_compiles_on_the_2x2_mesh(v5e):
 
 
 # -- the language model's serving programs at the published widths --------
-def _lm_programs_and_shapes(v5e, slots, part):
-    """``serve/programs.py::LMPrograms`` of the benchmark's configuration,
-    its parameters, one launch's cache of ``slots`` and a prefill slice of
-    ``part`` prompts, as shapes on one described device."""
+def _lm_programs_and_shapes(v5e, slots, part,
+                            name="k-exaone-ep8-serve-bf16"):
+    """``serve/programs.py::LMPrograms`` of the benchmark's configuration
+    ``name``, its parameters, one launch's cache of ``slots`` and a prefill
+    slice of ``part`` prompts, as shapes on one described device."""
     import json
 
-    from can_tpu.models import exaone_moe as em
     from can_tpu.serve.programs import LMPrograms
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "k-exaone-ep8-serve-bf16.json")) as f:
+    with open(os.path.join(root, "benchmark", "configs", name + ".json")) as f:
         config = json.load(f)
-    cfg = em.ExaoneMoeConfig.from_dict(config)
-    programs = LMPrograms(cfg, max_new_tokens=int(config["max_new_tokens"]))
+    if config["model_type"] == "exaone_moe":
+        from can_tpu.models import exaone_moe as em
+
+        cfg = em.ExaoneMoeConfig.from_dict(config)
+    else:
+        from can_tpu.models import glm_moe_lite as em
+
+        cfg = em.Glm4MoeLiteConfig.from_dict(config)
+    programs = LMPrograms(em, cfg, max_new_tokens=int(config["max_new_tokens"]))
     one = SingleDeviceSharding(v5e[0])
     shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
     flat, treedef = jax.tree_util.tree_flatten_with_path(
@@ -233,3 +239,46 @@ def test_lm_prefill_slice_compiles_for_one_device(v5e):
         params, batch, cache, shape((), jnp.int32)).compile()
     assert "ragged-dot" in compiled.as_text()
     assert _fits_hbm(compiled) > 9 * 2**30
+
+
+# -- the latent-attention model at the published widths -------------------
+GLM = "glm-4.7-flash-pp8-serve-bf16"
+
+
+def test_glm_decode_step_compiles_for_one_device(v5e):
+    """One greedy step of 16 slots over a latent cache of 16,512 positions
+    (the absorbed form: no per-head keys anywhere in the program), the cache
+    updated in place (donated): 7.79 GB of weights + 1.83 GB of cache."""
+    programs, params, cache, _, shape = _lm_programs_and_shapes(v5e, 16, 2, GLM)
+    state = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(lambda: programs.new_state(
+            [{"first": jnp.zeros((16,), jnp.int32),
+              "logits": jnp.zeros((16, 8), jnp.float32),
+              "choices": jnp.zeros((5, 16, 4), jnp.int32),
+              "counts": jnp.zeros((5, 64), jnp.int32)}],
+            jnp.ones((16,), jnp.int32), jnp.ones((16,), bool))[0]))
+    compiled = jax.jit(programs.decode, donate_argnums=(1, 2)).lower(
+        params, state, cache).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" not in text
+    assert "bf16[16,16512,512]" in text          # the latent, as stored
+    assert "bf16[16,20,16512,192]" not in text   # no key rebuilt per head
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 16 * 16512 * 6912
+    assert _fits_hbm(compiled) > 9 * 2**30
+
+
+def test_glm_prefill_slice_compiles_for_one_device(v5e):
+    """2 prompts of 16,384 tokens into a 16-slot latent cache: the expanded
+    form in blocks with a running softmax (ONE loop body a layer, not 64
+    shapes), the sorted buffer of 131,072 rows, all beside the weights."""
+    programs, params, cache, batch, shape = _lm_programs_and_shapes(
+        v5e, 16, 2, GLM)
+    compiled = jax.jit(programs.prefill_slice, donate_argnums=(2,)).lower(
+        params, batch, cache, shape((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" in text
+    assert "f32[20,1024,1024]" in text           # a score block
+    assert "f32[20,1024,16384]" not in text and "f32[2,20,16384,16384]" not in text
+    assert 10 * 2**30 < _fits_hbm(compiled) < 14 * 2**30

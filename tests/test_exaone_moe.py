@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from can_tpu.models import exaone_moe as em
+from can_tpu.models import lm_blocks as lb
 from can_tpu.ops import attention as attn_ops
 from can_tpu.ops import moe as moe_ops
 from can_tpu.testing import exaone_moe_ref as ref
@@ -117,7 +118,7 @@ class TestExpertShare:
         lo = rank * held
         p["experts"] = {k: v[lo:lo + held] for k, v in p["experts"].items()}
         cfg = em.ExaoneMoeConfig.from_dict(tiny_config(held=held, rank=rank))
-        return em.expert_layer(p, x, cfg)[0], em.swiglu(x, p["shared"]), full
+        return lb.expert_layer(p, x, cfg)[0], lb.swiglu(x, p["shared"]), full
 
     @pytest.mark.parametrize("held", [1, 2, 4])
     def test_shares_of_all_ranks_add_up_to_the_uncut_layer(self, held):
@@ -156,7 +157,7 @@ class TestExpertShare:
         for e in range(2):
             one = {k: v[e] for k, v in p["experts"].items()}
             w_e = jnp.sum(jnp.where(idx == e, w, 0.0), -1)
-            want = want + w_e[:, None] * em.swiglu(x, one)
+            want = want + w_e[:, None] * lb.swiglu(x, one)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
 

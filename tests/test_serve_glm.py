@@ -1,0 +1,153 @@
+"""The latent-attention model through the ONE serving path (queue,
+``MicroBatcher``, ``LMEngine``, ``GenerateService``), built by the
+construction ``can_tpu.cli.serve --model-config`` uses: the seam of
+``serve/programs.py`` given a second model.  Tiny preset, CPU; sibling of
+``tests/test_serve_lm.py``."""
+
+import json
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from can_tpu.models import glm_moe_lite as gm
+from can_tpu.obs import Telemetry, spans
+from can_tpu.serve import GenerateService, build_model_service, lm_probe_steps
+from can_tpu.serve import programs as serve_programs
+from can_tpu.testing import glm_moe_lite_ref as ref
+
+from lm_tiny import tiny_glm_config
+
+NEW = 6
+
+
+def glm_config(**kw) -> dict:
+    d = tiny_glm_config(mtp=0)
+    d.update(max_new_tokens=NEW, prefill_slice=2, length_ladder=[16, 32],
+             max_batch=4, queue_capacity=16, max_wait_ms=5.0)
+    d.update(kw)
+    return d
+
+
+def _prompt(n, seed=0):
+    return np.random.default_rng(seed).integers(0, 256, n).astype(np.int32)
+
+
+@pytest.fixture(scope="module")
+def service():
+    block, gm.PREFILL_BLOCK = gm.PREFILL_BLOCK, 8   # buckets of 2 and 4 blocks
+    tracer = spans.SpanTracer()
+    tel = Telemetry()
+    tel.spans = tracer
+    cfg = gm.Glm4MoeLiteConfig.from_dict(glm_config())
+    params = gm.init_params(jax.random.key(3), cfg, jnp.float32)
+    svc = build_model_service(glm_config(), params=params, telemetry=tel)
+    report = svc.warmup()
+    svc.start()
+    yield svc, report, tracer
+    svc.close()
+    gm.PREFILL_BLOCK = block
+
+
+def test_the_table_builds_the_same_programs_class_for_both_models():
+    entry = serve_programs.serving_model("glm4_moe_lite")
+    made, params = entry.programs(glm_config(), None, 3)
+    assert isinstance(made, serve_programs.LMPrograms)
+    assert made.vocab_size == 256 and made.expert_layers == 2
+    assert [s.kind for s in made.cache_layout] == ["latent"] * 3
+    assert params["layers"][1]["attn"]["wkv_a"].shape == (64, 24)
+    assert params["embed"].dtype == jnp.bfloat16 == made.dtype
+
+
+def test_warmup_compiles_every_bucket(service):
+    svc, report, _ = service
+    assert isinstance(svc, GenerateService) and svc.sched is None
+    assert report["compiles"] == 2 * 2 == svc.engine.compile_count
+
+
+def test_generated_ids_and_probe_logits_match_the_reference(service):
+    """Prefill in the expanded form, then decode through the latent cache,
+    from another slot than 0 and a bucket of four blocks."""
+    svc, _, _ = service
+    before = svc.engine.compile_count
+    p = _prompt(27, 99)
+    other = svc.submit(_prompt(11, 98))
+    r = svc.submit(p, want_logits=True).result(120)
+    assert other.result(120).logits is None
+    assert svc.engine.compile_count == before
+    assert r.tokens.shape == (NEW,) and r.bucket_hw == (1, 32)
+    steps = lm_probe_steps(NEW)
+    assert sorted(r.logits) == sorted(["prefill"] + [f"step{s}" for s in steps])
+    spec = ref.spec_from_config(glm_config())
+    seq = np.concatenate([p, r.tokens])
+    full = ref.forward(svc.engine.params, seq, spec)
+    for name, at in [("prefill", len(p) - 1)] + [
+            (f"step{s}", len(p) - 1 + s) for s in steps]:
+        np.testing.assert_allclose(r.logits[name], np.asarray(full["logits"][at]),
+                                   atol=3e-5, rtol=3e-5)
+        for layer, chosen in enumerate(full["chosen"]):
+            assert (np.sort(r.routing[name][layer])
+                    == np.sort(np.asarray(chosen[at]))).all()
+    want = np.asarray(full["logits"])[len(p) - 1:len(p) - 1 + NEW]
+    margin = want[np.arange(NEW), r.tokens] - want.max(-1)
+    assert (margin > -1e-4).all(), margin
+
+
+def test_counters_say_the_cache_is_latent(service):
+    from can_tpu.obs.exporter import render_stats
+
+    svc, _, _ = service
+    svc.submit(_prompt(8, 1)).result(120)
+    lm = svc.stats()["lm"]
+    # 4 slots x (16 + 6) positions x 3 layers x (16 + 8) numbers x 4 bytes
+    assert lm["cache_bytes"] == {"latent": 4 * 22 * 3 * 24 * 4}
+    assert lm["assignments_held"] == lm["assignments_all"] > 0
+    last = svc.engine.last_launch
+    assert np.sum(last["decode_expert_tokens"]) == last["valid"] * last["steps"] * 4 * 2
+    text = render_stats(svc.stats(), prefix="can_tpu_serve")
+    assert 'can_tpu_serve_lm_cache_bytes{kind="latent"}' in text
+
+
+def test_prefill_spans_name_every_slice(service):
+    svc, _, tracer = service
+    ticket = svc.submit(_prompt(12, 5))
+    ticket.result(120)
+    want = ticket._request.batch_span.span_id
+    for _ in range(500):
+        ring = tracer.snapshot()
+        if any(s["span_id"] == want for s in ring):
+            break
+        time.sleep(0.01)
+    launch = next(s for s in ring if s["name"] == "serve.dispatch"
+                  and s.get("parent_id") == want)
+    inner = {s["name"]: s for s in ring if s.get("parent_id") == launch["span_id"]}
+    assert set(inner) == {"lm.prefill", "lm.decode"}
+    pre = inner["lm.prefill"]
+    assert pre["slices"] == 2
+    assert (pre["tokens"], pre["valid_tokens"]) == (64, 12)
+    slices = sorted((s for s in ring if s["name"] == "lm.prefill.dispatch"
+                     and s.get("parent_id") == pre["span_id"]),
+                    key=lambda s: s["slice"])
+    assert [(s["slice"], s["start_slot"]) for s in slices] == [(0, 0), (1, 2)]
+    steps = [s for s in ring if s["name"] == "lm.decode.dispatch"
+             and s.get("parent_id") == inner["lm.decode"]["span_id"]]
+    assert len(steps) == NEW
+
+
+def test_cli_serves_the_model_from_its_configuration_file(tmp_path, capsys):
+    from can_tpu.cli import serve as cli
+
+    path = tmp_path / "tiny-glm.json"
+    path.write_text(json.dumps(glm_config(length_ladder=[16], max_batch=2)))
+    svc = cli.build_service(cli.parse_args(["--model-config", str(path),
+                                            "--seed", "4"]))
+    try:
+        assert isinstance(svc.engine.programs.cfg, gm.Glm4MoeLiteConfig)
+        assert "[serve] warmup:" in capsys.readouterr().out
+        with svc:
+            r = svc.generate(_prompt(7), timeout=120)
+        assert r.tokens.shape == (NEW,)
+    finally:
+        svc.close()

@@ -24,6 +24,7 @@ from can_tpu.serve import (
     build_model_service,
     lm_probe_steps,
 )
+from can_tpu.ops import cache_layout as layout
 from can_tpu.serve import cache as kv_cache
 from can_tpu.serve.kinds import TOKENS, ImageKind, TokenKind
 from can_tpu.testing import exaone_moe_ref as ref
@@ -193,30 +194,63 @@ class TestImageKindIsWhatItWas:
 
 
 class TestCache:
+    KV = dict(kv_heads=2, head_dim=16)
+
+    def _exaone_layout(self):
+        ring = layout.kv_layer("ring", window=8, **self.KV)
+        return (ring, ring, ring, layout.kv_layer("full", **self.KV), ring)
+
     def test_allocation_by_kind_and_bytes(self):
-        kinds = ("ring", "ring", "ring", "full", "ring")
-        c = jax.jit(lambda: kv_cache.allocate(
-            kinds, slots=4, kv_heads=2, head_dim=16, positions=40, window=8))()
+        specs = self._exaone_layout()
+        c = jax.jit(lambda: kv_cache.allocate(specs, slots=4, positions=40))()
         shapes = [e["k"].shape for e in c["layers"]]
         assert shapes == [(4, 2, 8, 16)] * 3 + [(4, 2, 40, 16), (4, 2, 8, 16)]
-        assert kv_cache.nbytes_by_kind(c, kinds) == {
+        assert all(sorted(e) == ["k", "v"] for e in c["layers"])
+        assert kv_cache.nbytes_by_kind(c, specs) == {
             "full": 2 * 4 * 2 * 40 * 16 * 2, "ring": 4 * 2 * 4 * 2 * 8 * 16 * 2}
 
     def test_published_cell_cache_bytes(self):
         """64 sequences of 1,280 positions: 0.34 GB in the full layer, 0.13
-        in the four rings (ISSUE's arithmetic)."""
-        full = np.prod(kv_cache.entry_shape("full", slots=64, kv_heads=8,
-                                            head_dim=128, positions=1280,
-                                            window=128)) * 2 * 2
-        ring = np.prod(kv_cache.entry_shape("ring", slots=64, kv_heads=8,
-                                            head_dim=128, positions=1280,
-                                            window=128)) * 2 * 2 * 4
+        in the four rings (ISSUE 26's arithmetic)."""
+        kv = dict(kv_heads=8, head_dim=128)
+        nbytes = lambda spec: sum(  # noqa: E731
+            int(np.prod(s)) * 2 for s in spec.shapes(64, 1280).values())
+        full = nbytes(layout.kv_layer("full", **kv))
+        ring = nbytes(layout.kv_layer("ring", window=128, **kv)) * 4
         assert (full, ring) == (335_544_320, 134_217_728)
 
     def test_unknown_kind_refused(self):
-        with pytest.raises(ValueError):
-            kv_cache.entry_shape("paged", slots=1, kv_heads=1, head_dim=1,
-                                 positions=1, window=1)
+        with pytest.raises(ValueError, match="unknown cache layer kind"):
+            layout.kv_layer("paged", kv_heads=1, head_dim=1)
+        with pytest.raises(ValueError, match="needs its window"):
+            layout.kv_layer("ring", kv_heads=1, head_dim=1)
+
+    def test_three_kinds_live_in_one_cache(self):
+        """A ring, a full and a latent layer side by side: each entry has
+        its kind's leaves, the bytes are reported for every kind present,
+        and a compile signature tells two such caches apart."""
+        specs = (layout.kv_layer("ring", window=8, **self.KV),
+                 layout.kv_layer("full", **self.KV),
+                 layout.latent_layer(rank=16, rope_dim=8))
+        c = jax.jit(lambda: kv_cache.allocate(specs, slots=4, positions=40))()
+        assert {k: v.shape for k, v in c["layers"][2].items()} == {
+            "ckv": (4, 40, 16), "krope": (4, 40, 8)}
+        assert c["layers"][2]["ckv"].dtype == jnp.bfloat16
+        assert kv_cache.nbytes_by_kind(c, specs) == {
+            "ring": 2 * 4 * 2 * 8 * 16 * 2, "full": 2 * 4 * 2 * 40 * 16 * 2,
+            "latent": 4 * 40 * (16 + 8) * 2}
+        sig = [a.shape for a in kv_cache.signature_leaves(c)]
+        assert sig == [(4, 2, 8, 16), (4, 2, 40, 16), (4, 40, 16)]
+        other = kv_cache.allocate(specs, slots=2, positions=40)
+        assert [a.shape for a in kv_cache.signature_leaves(other)] != sig
+
+    def test_the_published_latent_cache(self):
+        """16 slots x 16,512 positions x 6 layers of 512 + 64 numbers: 6,912
+        B a position, 1.83 GB; per-head keys and values would be 32 GB."""
+        spec = layout.latent_layer(rank=512, rope_dim=64)
+        one = sum(int(np.prod(s)) * 2 for s in spec.shapes(16, 16512).values())
+        assert 6 * one == 16 * 16512 * 6912 == 1_826_095_104
+        assert spec.window is None and spec.kind == "latent"
 
 
 @pytest.fixture(scope="module")
@@ -401,7 +435,8 @@ def test_cli_builds_the_same_service(tmp_path, capsys):
 
 
 def test_unknown_model_type_refused():
-    with pytest.raises(ValueError, match="no serving programs"):
+    with pytest.raises(ValueError, match="no serving programs.*exaone_moe.*"
+                                         "glm4_moe_lite"):
         build_model_service({"model_type": "resnet"})
 
 
@@ -430,11 +465,24 @@ def test_serving_layers_import_no_network(name):
     assert not any(m.startswith("can_tpu.models") for m in imported), imported
 
 
+@pytest.mark.parametrize("name", ["exaone_moe", "glm_moe_lite", "lm_blocks"])
+def test_a_model_imports_neither_the_serving_path_nor_another_model(name):
+    """The other side of the seam: a model describes its cache with
+    ``ops/cache_layout.py`` and shares its block with ``models/lm_blocks.py``;
+    a change to one model's module cannot move another's programs."""
+    import importlib
+
+    imported = _imports(importlib.import_module(f"can_tpu.models.{name}"))
+    assert not any(m.startswith("can_tpu.serve") for m in imported), imported
+    others = {f"can_tpu.models.{m}" for m in ("exaone_moe", "glm_moe_lite")}
+    assert not (imported & others - {f"can_tpu.models.{name}"}), imported
+
+
 def test_the_model_table_holds_what_a_configuration_file_may_name():
     from can_tpu.serve import programs
 
     entry = programs.serving_model("exaone_moe")
-    assert set(programs.MODEL_TYPES) == {"exaone_moe"}
+    assert set(programs.MODEL_TYPES) == {"exaone_moe", "glm4_moe_lite"}
     made, params = entry.programs(lm_config(), None, 3)
     assert isinstance(made, programs.LMPrograms) and made.vocab_size == 256
     assert params["embed"].shape == (256, 64)
