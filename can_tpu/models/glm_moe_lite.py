@@ -51,12 +51,14 @@ from can_tpu.models.lm_blocks import (VocabSlice, count_shapes, embed, ffn,
                                       routing_report)
 from can_tpu.ops import attention as attn_ops
 from can_tpu.ops import cache_layout as layout
+from can_tpu.ops import pallas_attention as fused_attn
 from can_tpu.ops.moe import ExpertShare
 
-# queries and keys of a prefill meet in blocks of this many positions
-# (``ops/attention.py::prefill_causal``): a float32 score block of 20 heads
-# is 84 MB, and a 16,384-token prompt is 136 block pairs.  NOT a measured
-# choice: 512 and 2,048 were never timed (PERF.md section 7)
+# queries and keys of a prefill in the SCANNED form meet in blocks of this
+# many positions (``ops/attention.py::prefill_causal``): a float32 score
+# block of 20 heads is 84 MB, and a 16,384-token prompt is 136 block pairs.
+# The fused form has its own, timed, beside the kernel
+# (``ops/pallas_attention.py``)
 PREFILL_BLOCK = 1024
 
 # what config.json leaves open, and the one value of each this module
@@ -251,20 +253,46 @@ def _up_projections(p, cfg: Glm4MoeLiteConfig):
     return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
 
 
+# (B, L) of a prefill's prompts -> the form the newest trace of
+# ``attention_expanded`` on such prompts ran its attention in.  Written while
+# a program is traced, read after its launch by whoever reports what the
+# program does (as ``models/cannet.py::stage1_traced``).
+_ATTENTION_TRACED: dict = {}
+
+
+def attention_traced(tokens_shape) -> Optional[str]:
+    """``"fused"`` / ``"scanned"`` as the prefill traced in this process for
+    prompts of this (B, L) has it; None where none was traced."""
+    return _ATTENTION_TRACED.get(tuple(tokens_shape))
+
+
 def attention_expanded(p, xn, positions, lengths, cfg: Glm4MoeLiteConfig):
     """Whole prompts, keys and values rebuilt per head from the latent:
-    -> (the layer's output (B, L, d) before the residual, c_kv, k_rope)."""
+    -> (the layer's output (B, L, d) before the residual, c_kv, k_rope).
+
+    The causal attention itself has two forms, one algorithm: the fused
+    kernel (``ops/pallas_attention.py``) where its ``supports`` says it can
+    run (a TPU, head widths of whole lanes, a bucket of whole blocks), the
+    scanned ``prefill_causal`` everywhere else.  Nothing else chooses."""
     b, l, _ = xn.shape
     h = cfg.num_attention_heads
     q_nope, q_rope = _queries(p, xn, positions, cfg)
     ckv, krope = _latent(p, xn, positions, cfg)
-    kv = jnp.dot(ckv, p["wkv_b"]).reshape(b, l, h, -1)
+    # keys and values each from their own columns of ``W_kvb``: the
+    # products land where they are used, no slice of a joint result
+    w_uk, w_uv = _up_projections(p, cfg)
     q = jnp.concatenate([q_nope, q_rope], -1)
     k = jnp.concatenate(
-        [kv[..., :cfg.qk_nope_head_dim],
+        [jnp.einsum("blr,rhn->blhn", ckv, w_uk),
          jnp.broadcast_to(krope[:, :, None], (b, l, h, krope.shape[-1]))], -1)
-    o = attn_ops.prefill_causal(q, k, kv[..., cfg.qk_nope_head_dim:], lengths,
-                                scale=cfg.scale, block=PREFILL_BLOCK)
+    v = jnp.einsum("blr,rhv->blhv", ckv, w_uv)
+    fused = fused_attn.supports(q.shape, v.shape, q.dtype)
+    _ATTENTION_TRACED[(b, l)] = "fused" if fused else "scanned"
+    if fused:
+        o = fused_attn.fused_causal(q, k, v, lengths, scale=cfg.scale)
+    else:
+        o = attn_ops.prefill_causal(q, k, v, lengths, scale=cfg.scale,
+                                    block=PREFILL_BLOCK)
     return jnp.dot(o.reshape(b, l, -1), p["wo"]), ckv, krope
 
 

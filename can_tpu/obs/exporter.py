@@ -453,8 +453,9 @@ def render_stats(stats: dict, *, prefix: str = "can_tpu_serve",
     "reused"}`` lines and the ``can_tpu_serve_staging_bytes_held`` gauge,
     ``"stage1"``: ``can_tpu_serve_stage1_folded{program="16x768x1024:float32"}``
     0/1 gauges, and
-    ``"lm"`` (a language model's engine): ``can_tpu_serve_lm_*_total`` counters
-    and ``can_tpu_serve_lm_cache_bytes{kind=}``."""
+    ``"lm"`` (a language model's engine): ``can_tpu_serve_lm_*_total`` counters,
+    ``can_tpu_serve_lm_cache_bytes{kind=}`` and
+    ``can_tpu_serve_lm_prefill_launches_total{attention="fused"}``."""
     gauges: Dict[str, float] = {}
     counters: Dict[Tuple[str, tuple], float] = {}
     labelled_gauges: Dict[Tuple[str, tuple], float] = {}
@@ -505,6 +506,10 @@ def render_stats(stats: dict, *, prefix: str = "can_tpu_serve",
                     for kind, b in n.items():
                         labelled_gauges[(f"{prefix}_lm_cache_bytes",
                                          (("kind", str(kind)),))] = b
+                elif name == "prefill_attention":
+                    for form, launches in n.items():
+                        counters[(f"{prefix}_lm_prefill_launches_total",
+                                  (("attention", str(form)),))] = launches
                 elif name == "expert_tokens_max":
                     gauges[f"{prefix}_lm_{name}"] = n
                 else:

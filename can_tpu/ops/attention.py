@@ -17,15 +17,20 @@ slots for a window layer, in which slot ``r`` holds the newest position
 ``p <= pos`` with ``p % window == r``.
 
 Latent attention keeps (B, S, rank) latents and (B, S, rope_dim) rotary keys,
-no heads.  Its prefill rebuilds keys and values per head and runs
-``prefill_causal``: a loop over blocks of queries and, inside, over the
-blocks of keys up to the diagonal with a running softmax, ONE compiled body
-whatever the prompt's length (``prefill_full`` unrolls one pair of products
-per query block, each of another shape: 64 a layer at 16,384 positions), no
-score block larger than ``block x block`` a head, and no work for blocks
-past a sequence's own length.  Its decode (``decode_latent``) is the
-absorbed form: queries carried into the latent space, the cache read as it
-is stored.
+no heads.  Its prefill rebuilds keys and values per head and runs a causal
+attention with a running softmax over blocks of queries and, inside, the
+blocks of keys up to the diagonal, with no work for blocks past a
+sequence's own length.  That attention has two forms of one algorithm:
+``prefill_causal`` here, the SCANNED form (ONE compiled body whatever the
+prompt's length, where ``prefill_full`` unrolls one pair of products per
+query block, each of another shape: 64 a layer at 16,384 positions; no
+score block larger than ``block x block`` a head), which runs anywhere and
+is the other's oracle; and the FUSED Pallas kernel of
+``ops/pallas_attention.py``, in which a score block never leaves the chip.
+``pallas_attention.supports`` chooses from the backend and the shapes
+(``models/glm_moe_lite.py::attention_expanded`` asks it); nothing else
+does.  Its decode (``decode_latent``) is the absorbed form: queries carried
+into the latent space, the cache read as it is stored.
 """
 
 from __future__ import annotations

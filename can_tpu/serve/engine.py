@@ -411,13 +411,20 @@ class LMEngine:
                             tel.signature_registry[f"{name}_decode"])
         self._warm: set = set()   # (slots, bucket) launched to the end once
         self._last_compiled = False
+        # per prefill program ((prompts in a slice, bucket)): the form its
+        # trace ran the attention in, "fused" or "scanned", where the model
+        # notes one (``LMPrograms.attention_traced``)
+        self.prefill_attention: Dict[tuple, str] = {}
         # counters (the batcher thread writes, stats() reads a copy)
         self.counters = {"launches": 0, "generated_tokens": 0,
                          "prompt_tokens": 0, "decode_steps": 0,
                          # assignments_held: tokens over all held experts
                          "expert_tokens_max": 0,
                          "assignments_held": 0, "assignments_all": 0,
-                         "cache_bytes": {}}
+                         "cache_bytes": {},
+                         # launches by the form their prefill's attention
+                         # ran in (models that note none: empty)
+                         "prefill_attention": {}}
         self.last_launch: dict = {}
 
     @property
@@ -484,9 +491,16 @@ class LMEngine:
                         out, cache = self._prefill(self.params, part, cache,
                                                    np.int32(lo))
                     compiled |= self._prefill.last_first_call
+                    self._note_attention(self._prefill.last_first_call,
+                                         (n, bucket))
                     outs.append(out)
                 state, pre = self._new_state(outs, batch.lengths, live)
                 sp.attrs["compiled"] = compiled
+                forms = {self.prefill_attention.get((n, bucket))
+                         for _, n in slices}
+                attention = forms.pop() if len(forms) == 1 else "mixed"
+                if attention is not None:
+                    sp.attrs["attention"] = attention
             probes = {"prefill": {"logits": pre["logits"],
                                   "choices": pre["choices"]}}
             keep = set(lm_probe_steps(steps))
@@ -511,11 +525,20 @@ class LMEngine:
                 # can-tpu-lint: disable=HOSTSYNC(fetched only when a request asked for its logits)
                 fetched = jax.tree.map(np.asarray, probes)
         self._warm.add((slots, bucket))
-        self._count(cache, valid, valid_tokens, steps, pre_counts, dec_counts)
+        self._count(cache, valid, valid_tokens, steps, pre_counts, dec_counts,
+                    attention)
         return ids, fetched
 
+    def _note_attention(self, traced: bool, program: tuple) -> None:
+        """After a prefill program's first launch: the form its trace
+        noted (a program compiled elsewhere has no entry)."""
+        noted = self.programs.attention_traced
+        form = noted(program) if traced and noted is not None else None
+        if form is not None:
+            self.prefill_attention[program] = form
+
     def _count(self, cache, valid, valid_tokens, steps, pre_counts,
-               dec_counts) -> None:
+               dec_counts, attention) -> None:
         p = self.programs
         k = p.cfg.num_experts_per_tok
         held = int(pre_counts.sum() + dec_counts.sum())
@@ -534,6 +557,11 @@ class LMEngine:
         c["assignments_held"] += held
         c["assignments_all"] += every
         c["cache_bytes"] = kv_cache.nbytes_by_kind(cache, p.cache_layout)
+        if attention is not None:
+            by_form = c["prefill_attention"]
+            # a new dict: ``warmup`` restores a shallow copy of the counters
+            c["prefill_attention"] = {**by_form,
+                                      attention: by_form.get(attention, 0) + 1}
         self.last_launch = launch
 
     def warmup(self, buckets, max_batch: int, *, sizes=None) -> dict:

@@ -88,6 +88,10 @@ class LMPrograms:
         self.dtype = dtype   # of the cache: the parameters' own
         self.cache_layout = tuple(model.cache_layout(cfg))
         self.expert_layers = sum(t != "dense" for t in cfg.mlp_layer_types)
+        # ((B, L) of a prefill's prompts) -> "fused" / "scanned", as the
+        # newest trace of the model's prefill on such prompts ran its
+        # attention; None for a model with one form
+        self.attention_traced = getattr(model, "attention_traced", None)
         self.vocab_size = cfg.vocab.held   # ids and logits are over the slice
 
     def positions(self, bucket: int) -> int:

@@ -2,7 +2,8 @@
 """Chip smoke: train -> eval -> serve on the TPU, through the CLIs a user types.
 
     python chip_smoke.py              # one chip: data, train, eval, serve,
-                                      #           syncBN-pallas leg
+                                      #           syncBN-pallas leg, the
+                                      #           fused prefill attention
     python chip_smoke.py --chips 4    # four chips: dp=4 and dp=2 x sp=2 train
                                       #   steps against a one-device reference,
                                       #   cli.train on all four, cli.serve
@@ -456,6 +457,62 @@ def phase_pallas(device: dict, *, data: str) -> None:
             f"a bucket routed no BN layer to the kernel: {routes}")
 
 
+# --------------------------------------------- fused prefill attention --
+def attention_worker() -> None:
+    """CHILD process (``--attention-worker``): the fused causal attention
+    kernel (``ops/pallas_attention.py``) COMPILED for the chip (interpreted
+    only under ``--rehearse-cpu``) against the scanned ``prefill_causal`` at
+    a small aligned shape, the kernel's own blocks, uneven lengths: one
+    sequence ends inside its last block, the other leaves a block of
+    queries wholly past its length."""
+    import jax
+    import jax.numpy as jnp
+
+    from can_tpu.ops import attention as attn_ops
+    from can_tpu.ops import pallas_attention as fused_attn
+    from can_tpu.parallel import init_runtime
+    from can_tpu.utils import enable_compilation_cache
+
+    print(f"[runtime] {init_runtime()}")
+    print(f"[xla] persistent compilation cache at "
+          f"{enable_compilation_cache()}")
+    on_chip = jax.default_backend() == "tpu"
+    b, h, d, dv = 2, 3, 256, 128
+    l = 3 * max(fused_attn.BLOCK_Q, fused_attn.BLOCK_K)
+    lengths = jnp.asarray([l - 37, fused_attn.BLOCK_Q + 5], jnp.int32)
+    ks = jax.random.split(jax.random.key(SEED), 3)
+    q = jax.random.normal(ks[0], (b, l, h, d), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (b, l, h, d), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (b, l, h, dv), jnp.bfloat16)
+    assert fused_attn.supports(q.shape, v.shape, q.dtype,
+                               interpret=not on_chip), "supports() refused"
+    fused = jax.jit(lambda *a: fused_attn.fused_causal(
+        *a, interpret=not on_chip))(q, k, v, lengths)
+    plain = jax.jit(attn_ops.prefill_causal)(q, k, v, lengths)
+    valid = jnp.arange(l)[None] < lengths[:, None]
+    gap = float(jnp.where(valid[:, :, None, None], jnp.abs(
+        fused.astype(jnp.float32) - plain.astype(jnp.float32)), 0).max())
+    finite = bool(jnp.isfinite(fused.astype(jnp.float32)).all())
+    skipped = not bool(fused[1, 2 * fused_attn.BLOCK_Q:].any())
+    print(f"[attention] kernel {'compiled (not interpreted)' if on_chip else 'INTERPRETED'}"
+          f", platform {jax.default_backend()}; q {q.shape} v {v.shape} "
+          f"lengths {lengths.tolist()}: largest gap to prefill_causal over "
+          f"valid rows {gap:.3e}, every row finite {finite}, the block past "
+          f"a length left zero {skipped}")
+    # one bfloat16 step of an output of size about 1 is 2^-8; the two forms
+    # round the same products, summed in another order
+    assert gap < 2e-2 and finite and skipped
+    print("ATTENTION OK")
+
+
+def phase_attention(device: dict) -> None:
+    out = run_child("attention", [os.path.abspath(__file__),
+                                  "--attention-worker", "--seed", str(SEED)])
+    check_runtime("attention", out, device)
+    say(find(r"^\[attention\] .+$", out, "kernel line").group(0))
+    find(r"^ATTENTION OK$", out, "attention worker verdict")
+
+
 # ------------------------------------------------- four-chip mesh worker --
 def mesh_worker() -> None:
     """CHILD process (``--mesh-worker``): the only code here that imports
@@ -604,11 +661,16 @@ def main(argv=None) -> int:
                          "shapes; exits 3 and prints no result line")
     ap.add_argument("--mesh-worker", action="store_true",
                     help=argparse.SUPPRESS)
+    ap.add_argument("--attention-worker", action="store_true",
+                    help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
     global PLATFORM, CHIPS, SEED
     CHIPS, SEED = args.chips, args.seed
     if args.mesh_worker:
         mesh_worker()
+        return 0
+    if args.attention_worker:
+        attention_worker()
         return 0
     if args.rehearse_cpu:
         PLATFORM = "cpu"
@@ -648,6 +710,7 @@ def main(argv=None) -> int:
         phase_serve("serve", device, data=data, ckpt=ckpt,
                     buckets=shapes["serve_buckets"], replicas=1, port=8731)
         phase_pallas(device, data=one_size)
+        phase_attention(device)
     else:
         phase_data(one_size, 32, 8, bucket)
         phase_mesh_worker(device)

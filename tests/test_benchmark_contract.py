@@ -299,6 +299,22 @@ def test_a_span_the_lm_readers_read_is_recorded(span, attrs):
         assert f"{a}=" in call, f"{span} lacks {a}"
 
 
+def test_the_prefill_span_s_attention_is_written_where_its_reader_reads_it():
+    """``prefill_fused_attention_pct.lm`` reads ``attention`` off the
+    ``lm.prefill`` spans: the engine sets it inside that span, once the
+    slices' programs have run (and so have been traced)."""
+    from can_tpu.serve.engine import LMEngine
+
+    source = inspect.getsource(LMEngine.generate_batch)
+    inside = source[source.index('span("lm.prefill"'):
+                    source.index('span("lm.decode"')]
+    assert 'sp.attrs["attention"]' in inside
+    with open(os.path.join(BENCH, "metrics",
+                           "prefill_fused_attention_pct.lm.py")) as f:
+        reader = f.read()
+    assert '"lm.prefill"' in reader and 's["attention"] == "fused"' in reader
+
+
 @pytest.mark.parametrize("key", ["cache_bytes", "generated_tokens", "launches"])
 def test_a_counter_the_lm_readers_read_is_kept(key):
     """``stats()["lm"]`` is the engine's counters: ``latent_cache_bytes_per_pos.lm``

@@ -282,3 +282,48 @@ def test_glm_prefill_slice_compiles_for_one_device(v5e):
     assert "f32[20,1024,1024]" in text           # a score block
     assert "f32[20,1024,16384]" not in text and "f32[2,20,16384,16384]" not in text
     assert 10 * 2**30 < _fits_hbm(compiled) < 14 * 2**30
+
+
+# -- the fused prefill attention (ops/pallas_attention.py) ------------------
+@pytest.mark.parametrize("blocks", [(1024, 1024), (512, 1024)],
+                         ids=["1024x1024", "512x1024"])
+def test_fused_attention_kernel_compiles_at_the_cell_s_shape(v5e, blocks):
+    """A slice of the GLM cell: 2 x 16,384 positions x 20 heads of 256,
+    a head's keys and values whole in VMEM (32 MB, twice)."""
+    from can_tpu.ops import pallas_attention as fused_attn
+
+    one = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct((2, 16384, 20, 256), jnp.bfloat16, sharding=one)
+    n = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=one)
+    compiled = jax.jit(lambda q, k, v, n: fused_attn.fused_causal(
+        q, k, v, n, scale=1 / 16, block_q=blocks[0], block_k=blocks[1])
+        ).lower(x, x, x, n).compile()
+    assert "fused_causal_attention" in compiled.as_text()
+
+
+def test_glm_prefill_slice_compiles_with_the_fused_attention(v5e, monkeypatch):
+    """The same slice as the chip traces it (``supports`` asks the backend,
+    which is the CPU's during a compile for a described chip: steered
+    here): six kernel launches and no loop, no score block in HBM, and
+    q, k, v handed to the kernel as XLA leaves them (positions in the
+    lanes): a bitcast, never a transposing copy."""
+    import re
+
+    from can_tpu.models import glm_moe_lite as gm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    programs, params, cache, batch, shape = _lm_programs_and_shapes(
+        v5e, 16, 2, GLM)
+    compiled = jax.jit(programs.prefill_slice, donate_argnums=(2,)).lower(
+        params, batch, cache, shape((), jnp.int32)).compile()
+    assert gm.attention_traced((2, 16384)) == "fused"
+    text = compiled.as_text()
+    calls = re.findall(r"%fused_causal_attention[.\d]* = \S+ custom-call\(([^)]*)\)",
+                       text)
+    assert len(calls) == 6
+    assert " while(" not in text and "f32[20,1024,1024]" not in text
+    for operands in calls:
+        for name in operands.split(", ")[1:]:
+            made = re.search(rf"^\s*{re.escape(name)} = \S+ (\S+?)\(", text, re.M)
+            assert made and made.group(1) == "bitcast", (name, made and made.group(1))
+    assert 9 * 2**30 < _fits_hbm(compiled) < 13 * 2**30

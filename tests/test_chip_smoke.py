@@ -41,3 +41,19 @@ def test_parent_never_imports_jax():
             "bad = [m for m in ('jax', 'jaxlib', 'can_tpu') "
             "if m in sys.modules]; assert not bad, bad" % REPO)
     subprocess.run([sys.executable, "-c", code], check=True, timeout=60)
+
+
+def test_attention_worker_rehearses_on_the_cpu():
+    """The fused prefill attention leg's child (``--attention-worker``)
+    with JAX held to the CPU: the kernel interpreted, at the kernel's own
+    blocks, against ``prefill_causal``; its three lines are what
+    ``phase_attention`` looks for."""
+    out = subprocess.run([sys.executable, SMOKE, "--attention-worker"],
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=REPO,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stdout + out.stderr
+    lines = out.stdout.splitlines()
+    assert any(l.startswith("[runtime] {") for l in lines)
+    assert any(l.startswith("[attention] kernel INTERPRETED, platform cpu")
+               for l in lines)
+    assert lines[-1] == "ATTENTION OK"

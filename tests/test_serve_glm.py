@@ -110,9 +110,8 @@ def test_counters_say_the_cache_is_latent(service):
     assert 'can_tpu_serve_lm_cache_bytes{kind="latent"}' in text
 
 
-def test_prefill_spans_name_every_slice(service):
-    svc, _, tracer = service
-    ticket = svc.submit(_prompt(12, 5))
+def _launch_spans(tracer, ticket):
+    """-> (the ring, {name: span} directly under the ticket's launch)."""
     ticket.result(120)
     want = ticket._request.batch_span.span_id
     for _ in range(500):
@@ -122,7 +121,13 @@ def test_prefill_spans_name_every_slice(service):
         time.sleep(0.01)
     launch = next(s for s in ring if s["name"] == "serve.dispatch"
                   and s.get("parent_id") == want)
-    inner = {s["name"]: s for s in ring if s.get("parent_id") == launch["span_id"]}
+    return ring, {s["name"]: s for s in ring
+                  if s.get("parent_id") == launch["span_id"]}
+
+
+def test_prefill_spans_name_every_slice(service):
+    svc, _, tracer = service
+    ring, inner = _launch_spans(tracer, svc.submit(_prompt(12, 5)))
     assert set(inner) == {"lm.prefill", "lm.decode"}
     pre = inner["lm.prefill"]
     assert pre["slices"] == 2
@@ -134,6 +139,39 @@ def test_prefill_spans_name_every_slice(service):
     steps = [s for s in ring if s["name"] == "lm.decode.dispatch"
              and s.get("parent_id") == inner["lm.decode"]["span_id"]]
     assert len(steps) == NEW
+
+
+def test_the_prefill_says_which_form_its_attention_ran_in(service):
+    """On the CPU (and at heads of 16) ``supports`` refuses the fused
+    kernel: the span, the engine and the counters all say ``scanned``; the
+    warm-up's launches are not traffic and are not counted."""
+    from can_tpu.obs.exporter import render_stats
+
+    svc, _, tracer = service
+    before = dict(svc.stats()["lm"]["prefill_attention"])
+    pre = _launch_spans(tracer, svc.submit(_prompt(9, 7)))[1]["lm.prefill"]
+    assert pre["attention"] == "scanned"
+    # one program a (prompts in a slice, bucket): two buckets warmed up
+    assert svc.engine.prefill_attention == {(2, 16): "scanned",
+                                            (2, 32): "scanned"}
+    lm = svc.stats()["lm"]
+    assert set(lm["prefill_attention"]) == {"scanned"}
+    assert (lm["prefill_attention"]["scanned"]
+            == before.get("scanned", 0) + 1 <= lm["launches"])
+    text = render_stats(svc.stats(), prefix="can_tpu_serve")
+    assert ('can_tpu_serve_lm_prefill_launches_total{attention="scanned"} '
+            f'{lm["prefill_attention"]["scanned"]}') in text
+
+
+def test_the_span_says_fused_where_the_program_traced_the_kernel(service,
+                                                                 monkeypatch):
+    """What the span carries is what the program's trace noted, not what
+    the engine assumes: a program noted as ``fused`` reads so."""
+    svc, _, tracer = service
+    monkeypatch.setitem(svc.engine.prefill_attention, (2, 16), "fused")
+    pre = _launch_spans(tracer, svc.submit(_prompt(10, 8)))[1]["lm.prefill"]
+    assert pre["attention"] == "fused"
+    assert svc.stats()["lm"]["prefill_attention"]["fused"] == 1
 
 
 def test_cli_serves_the_model_from_its_configuration_file(tmp_path, capsys):

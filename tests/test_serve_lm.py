@@ -352,6 +352,17 @@ class TestGenerateService:
         last = svc.engine.last_launch
         assert np.sum(last["decode_expert_tokens"]) == last["valid"] * last["steps"] * 2 * 4
 
+    def test_a_model_with_one_attention_form_notes_none(self, service):
+        """K-EXAONE's prefill has one form: no ``attention`` on its spans,
+        no launches by form in its counters."""
+        svc, _, tracer = service
+        svc.submit(_prompt(6, 3)).result(120)
+        assert svc.engine.programs.attention_traced is None
+        assert svc.engine.prefill_attention == {}
+        assert svc.stats()["lm"]["prefill_attention"] == {}
+        assert all("attention" not in s for s in tracer.snapshot()
+                   if s["name"] == "lm.prefill")
+
     def test_spans_under_serve_batch(self, service):
         svc, _, tracer = service
         import time
