@@ -3,9 +3,11 @@ functions over a parameter tree: the norm, SwiGLU, the expert layer with
 its shared expert, the feed-forward half of a block, the head, the routing
 report a serving program returns, and the seeded initialiser.  A model's
 module (``exaone_moe.py``, ``glm_moe_lite.py``) brings its own attention and
-its own config class; the config offers ``rms_norm_eps``,
-``num_experts_per_tok``, ``routed_scaling_factor``, ``norm_topk_prob`` and
-``share`` (``ops.moe.ExpertShare``).
+its own config class; the config offers ``rms_norm_eps`` and, where the model
+has an expert layer, ``num_experts_per_tok``, ``routed_scaling_factor``,
+``norm_topk_prob`` and ``share`` (``ops.moe.ExpertShare``).  A dense model
+(``falcon_h1.py``) takes the norm, SwiGLU and the head with its
+configuration's multipliers, and ``no_routing``.
 
 Weights and activations follow the parameter tree's dtype; the router, the
 norms' statistics and the logits are float32.
@@ -69,9 +71,19 @@ def rms_norm(x, g, eps: float):
     return (y * g.astype(jnp.float32)).astype(x.dtype)
 
 
-def swiglu(x, p):
-    return jnp.dot(jax.nn.silu(jnp.dot(x, p["gate"])) * jnp.dot(x, p["up"]),
-                   p["down"])
+def swiglu(x, p, multipliers=None):
+    """``down(silu(gate(x)) * up(x))``; with ``multipliers`` (gate's, down's;
+    maximal-update parametrisation) ``down(up(x) * silu(gate(x) * m0)) *
+    m1``."""
+    if multipliers is None:
+        return jnp.dot(jax.nn.silu(jnp.dot(x, p["gate"])) * jnp.dot(x, p["up"]),
+                       p["down"])
+    # the products with a multiplier in float32: one rounded to bfloat16
+    # would be the same error on every number of the branch
+    m_gate, m_down = multipliers
+    gate = (jnp.dot(x, p["gate"]).astype(jnp.float32) * m_gate).astype(x.dtype)
+    y = jnp.dot(jax.nn.silu(gate) * jnp.dot(x, p["up"]), p["down"])
+    return (y.astype(jnp.float32) * m_down).astype(x.dtype)
 
 
 def expert_layer(p, x, cfg):
@@ -116,12 +128,21 @@ def routing_report(chosen, mask, pick, cfg) -> dict:
     return {"counts": jnp.stack(counts), "choices": jnp.stack(at)}
 
 
+def no_routing(batch: int) -> dict:
+    """``routing_report`` of a model without an expert layer: no rows."""
+    return {"counts": jnp.zeros((0, 0), jnp.int32),
+            "choices": jnp.zeros((0, batch, 0), jnp.int32)}
+
+
 def embed(params, tokens):
     return params["embed"][tokens]
 
 
-def lm_head(params, h, cfg):
+def lm_head(params, h, cfg, multiplier=None):
+    """Float32 logits of ``RMSNorm(h)``, times ``multiplier`` where the
+    configuration has one."""
     with jax.named_scope("head"):
         x = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-        return jnp.dot(x, params["head"],
-                       preferred_element_type=jnp.float32)
+        logits = jnp.dot(x, params["head"],
+                         preferred_element_type=jnp.float32)
+        return logits if multiplier is None else logits * multiplier

@@ -12,29 +12,51 @@ both import it, and neither imports the other for it.
   positions;
 * ``latent`` (latent attention): no heads; per position the compressed
   key/value latent and the one rotary key all heads share, ``{"ckv"
-  (slots, positions, rank), "krope" (slots, positions, rope_dim)}``.
+  (slots, positions, rank), "krope" (slots, positions, rope_dim)}``;
+* ``state`` (a recurrence): NO position axis, the size fixed whatever the
+  context: each leaf ``(slots, *dimensions)`` of whatever the layer carries
+  from one position to the next (a state-space mixer's state, its
+  convolution's last inputs).  A leaf may state its dtype (an accumulator
+  in float32 beside bfloat16 neighbours); the others take the cache's.
+
+A layer whose block keeps two kinds (attention and a recurrence side by
+side) is described by a tuple of ``LayerSpec``, one a kind; its leaves share
+the layer's one entry, so their names differ.  ``parts`` reads either form.
 """
 
 from __future__ import annotations
 
 from typing import Dict, NamedTuple, Optional, Tuple
 
-FULL, RING, LATENT = "full", "ring", "latent"
+FULL, RING, LATENT, STATE = "full", "ring", "latent", "state"
 
 
 class LayerSpec(NamedTuple):
-    """One layer's entry: its kind, its leaves as (name, dimensions before
-    the positions, width of a position) and, for a ring, how many positions
-    it holds (None: the whole context)."""
+    """One kind's part of a layer's entry: its kind, its leaves as (name,
+    dimensions before the positions, width of a position) and, for a ring,
+    how many positions it holds (None: the whole context).  A ``state``
+    leaf is (name, its dimensions but the last, the last) and has no
+    positions; ``dtypes`` names the leaves that do not take the cache's
+    dtype, as (name, dtype name)."""
 
     kind: str
     leaves: Tuple[Tuple[str, Tuple[int, ...], int], ...]
     window: Optional[int] = None
+    dtypes: Tuple[Tuple[str, str], ...] = ()
 
     def shapes(self, slots: int, positions: int) -> Dict[str, tuple]:
+        if self.kind == STATE:
+            return {name: (slots, *lead, width)
+                    for name, lead, width in self.leaves}
         held = positions if self.window is None else self.window
         return {name: (slots, *lead, held, width)
                 for name, lead, width in self.leaves}
+
+
+def parts(layer) -> Tuple[LayerSpec, ...]:
+    """A layer's description, one ``LayerSpec`` or a tuple of them, as the
+    tuple."""
+    return (layer,) if isinstance(layer, LayerSpec) else tuple(layer)
 
 
 def kv_layer(kind: str, *, kv_heads: int, head_dim: int,
@@ -52,3 +74,14 @@ def latent_layer(*, rank: int, rope_dim: int) -> LayerSpec:
     """``rank + rope_dim`` numbers a position, whatever the number of heads."""
     return LayerSpec(LATENT, (("ckv", (), int(rank)),
                               ("krope", (), int(rope_dim))))
+
+
+def state_layer(**leaves) -> LayerSpec:
+    """What a recurrence carries, no positions: ``name=(dimensions after the
+    slots, dtype name or None for the cache's own)``."""
+    return LayerSpec(
+        STATE,
+        tuple((n, tuple(int(d) for d in dims[:-1]), int(dims[-1]))
+              for n, (dims, _) in leaves.items()),
+        dtypes=tuple((n, str(dt)) for n, (_, dt) in leaves.items()
+                     if dt is not None))

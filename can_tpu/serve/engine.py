@@ -401,10 +401,10 @@ class LMEngine:
         tel = self.telemetry
         self._prefill = RecompileTracker(
             jax.jit(programs.prefill_slice, donate_argnums=(2,)), tel,
-            name=f"{name}_prefill", signature_of=_with_cache_signature)
+            name=f"{name}_prefill", signature_of=self._with_cache_signature)
         self._decode = RecompileTracker(
             jax.jit(programs.decode, donate_argnums=(1, 2)), tel,
-            name=f"{name}_decode", signature_of=_with_cache_signature)
+            name=f"{name}_decode", signature_of=self._with_cache_signature)
         self._new_cache = jax.jit(programs.new_cache, static_argnums=(0, 1))
         self._new_state = jax.jit(programs.new_state)
         self._signatures = (tel.signature_registry[f"{name}_prefill"],
@@ -415,6 +415,10 @@ class LMEngine:
         # trace ran the attention in, "fused" or "scanned", where the model
         # notes one (``LMPrograms.attention_traced``)
         self.prefill_attention: Dict[tuple, str] = {}
+        # per program ((prompts in a slice, bucket); (slots, 1) for decode):
+        # the form its trace ran the state-space recurrence in, where the
+        # model has one (``LMPrograms.ssm_traced``)
+        self.ssm_forms: Dict[tuple, str] = {}
         # counters (the batcher thread writes, stats() reads a copy)
         self.counters = {"launches": 0, "generated_tokens": 0,
                          "prompt_tokens": 0, "decode_steps": 0,
@@ -491,16 +495,18 @@ class LMEngine:
                         out, cache = self._prefill(self.params, part, cache,
                                                    np.int32(lo))
                     compiled |= self._prefill.last_first_call
-                    self._note_attention(self._prefill.last_first_call,
-                                         (n, bucket))
+                    self._note_forms(self._prefill.last_first_call,
+                                     (n, bucket))
                     outs.append(out)
                 state, pre = self._new_state(outs, batch.lengths, live)
                 sp.attrs["compiled"] = compiled
-                forms = {self.prefill_attention.get((n, bucket))
-                         for _, n in slices}
-                attention = forms.pop() if len(forms) == 1 else "mixed"
+                ran = [(n, bucket) for _, n in slices]
+                attention = _one_form(self.prefill_attention, ran)
                 if attention is not None:
                     sp.attrs["attention"] = attention
+                ssm = _one_form(self.ssm_forms, ran)
+                if ssm is not None:
+                    sp.attrs["ssm"] = ssm
             probes = {"prefill": {"logits": pre["logits"],
                                   "choices": pre["choices"]}}
             keep = set(lm_probe_steps(steps))
@@ -510,9 +516,12 @@ class LMEngine:
                         state, cache, out = self._decode(self.params, state,
                                                          cache)
                     compiled |= self._decode.last_first_call
+                    self._note_forms(self._decode.last_first_call, (slots, 1))
                     if step in keep:
                         probes[f"step{step}"] = out
                 sp.attrs["compiled"] = compiled
+                if (slots, 1) in self.ssm_forms:
+                    sp.attrs["ssm"] = self.ssm_forms[(slots, 1)]
             launch.attrs["compiled"] = compiled
         self._last_compiled = compiled
         with span("serve.fetch", logits=bool(want_logits)):
@@ -525,24 +534,42 @@ class LMEngine:
                 # can-tpu-lint: disable=HOSTSYNC(fetched only when a request asked for its logits)
                 fetched = jax.tree.map(np.asarray, probes)
         self._warm.add((slots, bucket))
+        # (expert layers, slots, k): none of either in a model without experts
+        expert_layers, _, k = pre["choices"].shape
         self._count(cache, valid, valid_tokens, steps, pre_counts, dec_counts,
-                    attention)
+                    attention, expert_layers * k)
         return ids, fetched
 
-    def _note_attention(self, traced: bool, program: tuple) -> None:
-        """After a prefill program's first launch: the form its trace
-        noted (a program compiled elsewhere has no entry)."""
-        noted = self.programs.attention_traced
-        form = noted(program) if traced and noted is not None else None
-        if form is not None:
-            self.prefill_attention[program] = form
+    def _note_forms(self, traced: bool, program: tuple) -> None:
+        """After a program's first launch: the forms its trace noted (a
+        program compiled elsewhere has no entry)."""
+        if not traced:
+            return
+        for noted, forms in ((self.programs.attention_traced,
+                              self.prefill_attention),
+                             (self.programs.ssm_traced, self.ssm_forms)):
+            form = noted(program) if noted is not None else None
+            if form is not None:
+                forms[program] = form
+
+    def _with_cache_signature(self, args) -> tuple:
+        """(params, dict of arrays, cache, ...) -> the signature of the dict
+        AND of the cache: the launch size and the bucket both choose the
+        program."""
+        flat = dict(args[1])
+        for i, leaf in enumerate(kv_cache.signature_leaves(
+                args[2], self.programs.cache_layout)):
+            flat[f"cache{i}"] = leaf
+        return batch_signature(flat)
 
     def _count(self, cache, valid, valid_tokens, steps, pre_counts,
-               dec_counts, attention) -> None:
+               dec_counts, attention, choices_per_token: int) -> None:
+        """``choices_per_token``: routing choices a token makes over all the
+        expert layers (0 for a model without one: every expert counter then
+        reads zero)."""
         p = self.programs
-        k = p.cfg.num_experts_per_tok
         held = int(pre_counts.sum() + dec_counts.sum())
-        every = (valid_tokens + valid * steps) * k * p.expert_layers
+        every = (valid_tokens + valid * steps) * choices_per_token
         launch = {"valid": valid, "steps": steps,
                   "prefill_expert_tokens": pre_counts.tolist(),
                   "decode_expert_tokens": dec_counts.tolist(),
@@ -590,14 +617,11 @@ class LMEngine:
         return report
 
 
-def _with_cache_signature(args) -> tuple:
-    """(params, dict of arrays, cache, ...) -> the signature of the dict
-    AND of the cache: the launch size and the bucket both choose the
-    program."""
-    flat = dict(args[1])
-    for i, leaf in enumerate(kv_cache.signature_leaves(args[2])):
-        flat[f"cache{i}"] = leaf
-    return batch_signature(flat)
+def _one_form(forms: dict, programs) -> Optional[str]:
+    """The one form the launch's programs ran in; "mixed" where they
+    differ, None where none noted one."""
+    found = {forms.get(p) for p in programs}
+    return found.pop() if len(found) == 1 else "mixed"
 
 
 class _NoSpan:

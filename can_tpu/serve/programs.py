@@ -64,11 +64,26 @@ def cannet_predict(serve_dtype: str, compute_dtype):
 
 class LMPrograms:
     """A language model's pure functions as serving programs.  The model is
-    GIVEN: ``model`` offers ``prefill(params, tokens, lengths, cfg,
-    cache_len, active=)``, ``decode_step(params, cache, tokens, positions,
-    cfg, active=)`` and ``cache_layout(cfg)`` (an
-    ``ops.cache_layout.LayerSpec`` for each layer held); ``cfg`` offers ``mlp_layer_types``, ``share``
-    (``ops.moe.ExpertShare``), ``vocab`` and ``num_experts_per_tok``.
+    GIVEN.  What is asked of it is what every language model has:
+
+    * ``model.prefill(params, tokens, lengths, cfg, cache_len, active=)`` ->
+      (float32 logits (B, V) at each prompt's last position, the cache rows
+      of those B sequences, routing); ``tokens`` are right-padded, and
+      whatever the model keeps of a prompt (keys, a latent, a recurrence's
+      state) is what it is AT EACH PROMPT'S OWN LENGTH;
+    * ``model.decode_step(params, cache, tokens, positions, cfg, active=)``
+      -> (logits, cache, routing);
+    * ``model.cache_layout(cfg)``: for each layer held an
+      ``ops.cache_layout.LayerSpec``, or a tuple of them where a block keeps
+      two kinds;
+    * ``routing``: {"counts" (expert layers, held experts) int32, "choices"
+      (expert layers, B, k) int32}; a model WITHOUT an expert layer returns
+      them with no rows (``lm_blocks.no_routing``) and is counted as any
+      other: its counters read zero, its answers' routing is empty;
+    * ``cfg.vocab`` (``lm_blocks.VocabSlice``): ids and logits are over the
+      slice held;
+    * optionally ``model.attention_traced`` / ``model.ssm_traced``: (B, L) of
+      a program's tokens -> the form its newest trace ran that layer in.
 
     State between the programs of a launch, all on the device: the cache
     (``serve/cache.py``) and ``state``: ``tokens`` (slots,) the token each
@@ -87,11 +102,14 @@ class LMPrograms:
         self.max_new_tokens = int(max_new_tokens)
         self.dtype = dtype   # of the cache: the parameters' own
         self.cache_layout = tuple(model.cache_layout(cfg))
-        self.expert_layers = sum(t != "dense" for t in cfg.mlp_layer_types)
         # ((B, L) of a prefill's prompts) -> "fused" / "scanned", as the
         # newest trace of the model's prefill on such prompts ran its
         # attention; None for a model with one form
         self.attention_traced = getattr(model, "attention_traced", None)
+        # ((B, L) of a program's tokens; L = 1: decode) -> "chunked" /
+        # "step" / a kernel's name, as its newest trace ran the state-space
+        # recurrence; None for a model without one
+        self.ssm_traced = getattr(model, "ssm_traced", None)
         self.vocab_size = cfg.vocab.held   # ids and logits are over the slice
 
     def positions(self, bucket: int) -> int:
@@ -115,8 +133,7 @@ class LMPrograms:
         state = {"tokens": first, "positions": lengths.astype(jnp.int32),
                  "active": active, "ids": ids.at[:, 0].set(first),
                  "step": jnp.ones((), jnp.int32),
-                 "counts": jnp.zeros((self.expert_layers, self.cfg.share.held),
-                                     jnp.int32)}
+                 "counts": jnp.zeros_like(pre["counts"])}
         return state, pre
 
     def prefill_slice(self, params, batch, cache, start):
@@ -191,6 +208,12 @@ def _glm_moe_lite():
     return glm_moe_lite, glm_moe_lite.Glm4MoeLiteConfig
 
 
+def _falcon_h1():
+    from can_tpu.models import falcon_h1
+
+    return falcon_h1, falcon_h1.FalconH1Config
+
+
 def _lm_engine(params, programs, config: dict, telemetry):
     from can_tpu.serve.engine import LMEngine
 
@@ -209,6 +232,8 @@ MODEL_TYPES = {
                                _generate_service),
     "glm4_moe_lite": ServingModel(_lm_programs(_glm_moe_lite), _lm_engine,
                                   _generate_service),
+    "falcon_h1": ServingModel(_lm_programs(_falcon_h1), _lm_engine,
+                              _generate_service),
 }
 
 

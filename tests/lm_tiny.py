@@ -70,3 +70,41 @@ def tiny_glm_model(seed=0, dtype=jnp.float32, **kw):
     d = tiny_glm_config(**kw)
     cfg = gm.Glm4MoeLiteConfig.from_dict(d)
     return d, cfg, gm.init_params(jax.random.key(seed), cfg, dtype)
+
+
+def tiny_falcon_config(**assumed) -> dict:
+    """The tiny Falcon-H1 preset, as a configuration-file dict: every
+    mechanism of the published model at sizes a CPU runs in milliseconds,
+    with every multiplier different from 1: 2 blocks of a Mamba-2 mixer (6
+    heads of 8, state 16, G = 2 groups, convolution of width 4, chunks of 8)
+    beside grouped-query attention (5 query heads to each of 2 key/value
+    heads) and a SwiGLU."""
+    return {
+        "model_type": "falcon_h1", "attention_bias": False,
+        "attention_in_multiplier": 0.9, "attention_out_multiplier": 0.0375,
+        "attn_layer_indices": None, "embedding_multiplier": 5.6,
+        "head_dim": 8, "hidden_size": 64, "intermediate_size": 96,
+        "key_multiplier": 0.011, "lm_head_multiplier": 0.0078,
+        "mamba_chunk_size": 8, "mamba_conv_bias": True, "mamba_d_conv": 4,
+        "mamba_d_head": 8, "mamba_d_ssm": 48, "mamba_d_state": 16,
+        "mamba_n_groups": 2, "mamba_n_heads": 6,
+        "mamba_norm_before_gate": False, "mamba_proj_bias": False,
+        "mamba_rms_norm": True, "mamba_use_mlp": True, "mlp_bias": False,
+        "mlp_multipliers": [0.18, 0.011], "num_attention_heads": 10,
+        "num_hidden_layers": 2, "num_key_value_heads": 2,
+        "projectors_bias": False, "rms_norm_eps": 1e-5, "rope_scaling": None,
+        "rope_theta": 100000000000, "ssm_in_multiplier": 0.25,
+        "ssm_multipliers": [0.35, 0.25, 0.18, 0.5, 0.35],
+        "ssm_out_multiplier": 0.088, "tie_word_embeddings": False,
+        "vocab_size": 256, "published": {"vocab_size": 256},
+        "assumed": assumed,
+    }
+
+
+def tiny_falcon_model(seed=0, dtype=jnp.float32, **kw):
+    """-> (config dict, FalconH1Config, params)."""
+    from can_tpu.models import falcon_h1 as fh
+
+    d = tiny_falcon_config(**kw)
+    cfg = fh.FalconH1Config.from_dict(d)
+    return d, cfg, fh.init_params(jax.random.key(seed), cfg, dtype)

@@ -28,6 +28,7 @@ BENCH = os.path.join(REPO, "benchmark")
 SERVE_DRIVER = "harness/drive_serve.py"
 LM_DRIVER = "harness/drive_lm_serve.py"
 GLM_DRIVER = "harness/drive_glm_serve.py"   # EngineProbe is LM_DRIVER's
+HYBRID_DRIVER = "harness/drive_hybrid_serve.py"   # a model without experts
 
 
 def _sources():
@@ -117,7 +118,8 @@ def _stats_keys(rel):
 # ``build_model_service`` builds for ``exaone_moe``
 ROLES = {SERVE_DRIVER: ("CountService", "ServeEngine"),
          LM_DRIVER: ("GenerateService", "LMEngine"),
-         GLM_DRIVER: ("GenerateService", "LMEngine")}
+         GLM_DRIVER: ("GenerateService", "LMEngine"),
+         HYBRID_DRIVER: ("GenerateService", "LMEngine")}
 # read THROUGH the probe by the service (``EngineProbe.__getattr__`` forwards
 # to the engine), so no benchmark file spells them
 FORWARDED = ("last_batch_compiled", "launches_in_flight")
@@ -175,8 +177,11 @@ def _accepts(fn, keyword):
 def test_the_collection_found_the_benchmark():
     # an empty collection would pass vacuously: the drivers are there and
     # each list holds what the drivers are known to use
-    assert {SERVE_DRIVER, LM_DRIVER, GLM_DRIVER, "harness/drive_train.py",
-            "run.py"} <= set(SOURCES)
+    assert {SERVE_DRIVER, LM_DRIVER, GLM_DRIVER, HYBRID_DRIVER,
+            "harness/drive_train.py", "run.py"} <= set(SOURCES)
+    assert "warmup" in _uses(HYBRID_DRIVER, "service")
+    assert "release_buffers" in _uses(HYBRID_DRIVER, "engine")
+    assert {"batch_slots", "batch_valid", "lm"} <= _stats_keys(HYBRID_DRIVER)
     assert ("can_tpu.serve.programs", "MODEL_TYPES") in _imported()
     assert ("GenerateService", "submit", "want_logits") in _method_keyword_cases()
     assert ("can_tpu.serve", "RejectedError") in _imported()
@@ -272,6 +277,8 @@ def test_a_configuration_s_model_type_is_served(name, config):
 @pytest.mark.parametrize("module,name", [
     ("can_tpu.ops.attention", "write_slot"),   # calibrate_lm.late_write
     ("can_tpu.ops.attention", "write_row"),    # calibrate_glm.late_write
+    ("can_tpu.ops.ssm", "ssd_chunked"),   # calibrate_falcon_h1.state_after_padding
+    ("can_tpu.ops.ssm", "conv_tail"),     # calibrate_falcon_h1.tail_late
 ])
 def test_a_function_the_calibration_breaks_is_called_through_its_module(
         module, name):
@@ -279,9 +286,10 @@ def test_a_function_the_calibration_breaks_is_called_through_its_module(
     reach the function through the module, not through a name of its own."""
     assert callable(_resolve(module, name))
     models = os.path.join(REPO, "can_tpu", "models")
+    alias = {"attention": "attn_ops", "ssm": "ssm_ops"}[module.rsplit(".", 1)[1]]
     callers = [f for f in sorted(os.listdir(models)) if f.endswith(".py")
-               and f"attn_ops.{name}(" in open(os.path.join(models, f)).read()]
-    assert callers, f"no model calls attn_ops.{name}"
+               and f"{alias}.{name}(" in open(os.path.join(models, f)).read()]
+    assert callers, f"no model calls {alias}.{name}"
 
 
 @pytest.mark.parametrize("span,attrs", [
@@ -313,6 +321,34 @@ def test_the_prefill_span_s_attention_is_written_where_its_reader_reads_it():
                            "prefill_fused_attention_pct.lm.py")) as f:
         reader = f.read()
     assert '"lm.prefill"' in reader and 's["attention"] == "fused"' in reader
+
+
+def test_the_spans_ssm_is_written_where_its_reader_reads_it():
+    """``prefill_ssm_chunked_pct.lm`` reads ``ssm`` off the ``lm.prefill``
+    spans; the decode span carries its form too."""
+    from can_tpu.serve.engine import LMEngine
+
+    source = inspect.getsource(LMEngine.generate_batch)
+    pre = source[source.index('span("lm.prefill"'):source.index('span("lm.decode"')]
+    assert 'sp.attrs["ssm"]' in pre
+    assert 'sp.attrs["ssm"]' in source[source.index('span("lm.decode"'):]
+    with open(os.path.join(BENCH, "metrics", "prefill_ssm_chunked_pct.lm.py")) as f:
+        reader = f.read()
+    assert '"lm.prefill"' in reader and 's["ssm"] == "chunked"' in reader
+
+
+def test_the_state_counter_its_reader_reads_is_kept_by_kind():
+    """``state_cache_bytes_per_slot.lm`` reads ``cache_bytes["state"]``."""
+    from can_tpu.ops import cache_layout as layout
+    from can_tpu.serve import cache as kv_cache
+
+    spec = layout.state_layer(ssm=((2, 3, 4), "float32"), conv=((5, 3), None))
+    made = kv_cache.allocate((spec,), slots=2, positions=9)
+    assert kv_cache.nbytes_by_kind(made, (spec,)) == {
+        "state": 2 * (24 * 4 + 15 * 2)}
+    with open(os.path.join(BENCH, "metrics",
+                           "state_cache_bytes_per_slot.lm.py")) as f:
+        assert '"state"' in f.read()
 
 
 @pytest.mark.parametrize("key", ["cache_bytes", "generated_tokens", "launches"])

@@ -188,6 +188,10 @@ def _lm_programs_and_shapes(v5e, slots, part,
         from can_tpu.models import exaone_moe as em
 
         cfg = em.ExaoneMoeConfig.from_dict(config)
+    elif config["model_type"] == "falcon_h1":
+        from can_tpu.models import falcon_h1 as em
+
+        cfg = em.FalconH1Config.from_dict(config)
     else:
         from can_tpu.models import glm_moe_lite as em
 
@@ -282,6 +286,47 @@ def test_glm_prefill_slice_compiles_for_one_device(v5e):
     assert "f32[20,1024,1024]" in text           # a score block
     assert "f32[20,1024,16384]" not in text and "f32[2,20,16384,16384]" not in text
     assert 10 * 2**30 < _fits_hbm(compiled) < 14 * 2**30
+
+
+# -- the state-space hybrid at the published widths -------------------------
+FALCON = "falcon-h1-34b-pp12-serve-bf16"
+
+
+def test_falcon_h1_decode_step_compiles_for_one_device(v5e):
+    """One greedy step of 64 slots: keys and values AND the float32
+    recurrent state (64 x 6 x 32 x 128 x 256) updated in place (both
+    donated), beside 10.5 GB of weights."""
+    programs, params, cache, _, shape = _lm_programs_and_shapes(
+        v5e, 64, 8, FALCON)
+    state = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(lambda: programs.new_state(
+            [{"first": jnp.zeros((64,), jnp.int32),
+              "logits": jnp.zeros((64, 8), jnp.float32),
+              "choices": jnp.zeros((0, 64, 0), jnp.int32),
+              "counts": jnp.zeros((0, 0), jnp.int32)}],
+            jnp.ones((64,), jnp.int32), jnp.ones((64,), bool))[0]))
+    compiled = jax.jit(programs.decode, donate_argnums=(1, 2)).lower(
+        params, state, cache).compile()
+    assert "f32[64,32,128,256]" in compiled.as_text()    # the state, as stored
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 64 * (25_350_144 + 1280 * 12_288)
+    assert 12 * 2**30 < _fits_hbm(compiled) < 15 * 2**30
+
+
+def test_falcon_h1_prefill_slice_compiles_for_one_device(v5e):
+    """8 prompts of 1,024 tokens into a 64-slot cache: the recurrence in
+    its chunked form (decay matrices of 128 x 128 a chunk and head, eight
+    carried states a sequence; no loop over 1,024 positions), the MLP's
+    21,504-wide intermediates, all beside the weights and the cache."""
+    programs, params, cache, batch, shape = _lm_programs_and_shapes(
+        v5e, 64, 8, FALCON)
+    compiled = jax.jit(programs.prefill_slice, donate_argnums=(2,)).lower(
+        params, batch, cache, shape((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "128,128]" in text                     # a chunk's decay matrix
+    assert "trip_count=1024" not in text and '"n":"1024"' not in text
+    assert 12 * 2**30 < _fits_hbm(compiled) < 15.5 * 2**30
 
 
 # -- the fused prefill attention (ops/pallas_attention.py) ------------------

@@ -55,7 +55,7 @@ def test_the_table_builds_the_same_programs_class_for_both_models():
     entry = serve_programs.serving_model("glm4_moe_lite")
     made, params = entry.programs(glm_config(), None, 3)
     assert isinstance(made, serve_programs.LMPrograms)
-    assert made.vocab_size == 256 and made.expert_layers == 2
+    assert made.vocab_size == 256 and made.ssm_traced is None
     assert [s.kind for s in made.cache_layout] == ["latent"] * 3
     assert params["layers"][1]["attn"]["wkv_a"].shape == (64, 24)
     assert params["embed"].dtype == jnp.bfloat16 == made.dtype

@@ -239,10 +239,10 @@ class TestCache:
         assert kv_cache.nbytes_by_kind(c, specs) == {
             "ring": 2 * 4 * 2 * 8 * 16 * 2, "full": 2 * 4 * 2 * 40 * 16 * 2,
             "latent": 4 * 40 * (16 + 8) * 2}
-        sig = [a.shape for a in kv_cache.signature_leaves(c)]
+        sig = [a.shape for a in kv_cache.signature_leaves(c, specs)]
         assert sig == [(4, 2, 8, 16), (4, 2, 40, 16), (4, 40, 16)]
         other = kv_cache.allocate(specs, slots=2, positions=40)
-        assert [a.shape for a in kv_cache.signature_leaves(other)] != sig
+        assert [a.shape for a in kv_cache.signature_leaves(other, specs)] != sig
 
     def test_the_published_latent_cache(self):
         """16 slots x 16,512 positions x 6 layers of 512 + 64 numbers: 6,912
@@ -476,7 +476,8 @@ def test_serving_layers_import_no_network(name):
     assert not any(m.startswith("can_tpu.models") for m in imported), imported
 
 
-@pytest.mark.parametrize("name", ["exaone_moe", "glm_moe_lite", "lm_blocks"])
+@pytest.mark.parametrize("name", ["exaone_moe", "glm_moe_lite", "falcon_h1",
+                                  "lm_blocks"])
 def test_a_model_imports_neither_the_serving_path_nor_another_model(name):
     """The other side of the seam: a model describes its cache with
     ``ops/cache_layout.py`` and shares its block with ``models/lm_blocks.py``;
@@ -485,7 +486,8 @@ def test_a_model_imports_neither_the_serving_path_nor_another_model(name):
 
     imported = _imports(importlib.import_module(f"can_tpu.models.{name}"))
     assert not any(m.startswith("can_tpu.serve") for m in imported), imported
-    others = {f"can_tpu.models.{m}" for m in ("exaone_moe", "glm_moe_lite")}
+    others = {f"can_tpu.models.{m}" for m in ("exaone_moe", "glm_moe_lite",
+                                              "falcon_h1")}
     assert not (imported & others - {f"can_tpu.models.{name}"}), imported
 
 
@@ -493,7 +495,8 @@ def test_the_model_table_holds_what_a_configuration_file_may_name():
     from can_tpu.serve import programs
 
     entry = programs.serving_model("exaone_moe")
-    assert set(programs.MODEL_TYPES) == {"exaone_moe", "glm4_moe_lite"}
+    assert set(programs.MODEL_TYPES) == {"exaone_moe", "glm4_moe_lite",
+                                         "falcon_h1"}
     made, params = entry.programs(lm_config(), None, 3)
     assert isinstance(made, programs.LMPrograms) and made.vocab_size == 256
     assert params["embed"].shape == (256, 64)
