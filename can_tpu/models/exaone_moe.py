@@ -33,6 +33,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from can_tpu.models.lm_blocks import experts_form  # noqa: F401  (the serving path asks the model for it)
 from can_tpu.models.lm_blocks import (VocabSlice, count_shapes, embed,
                                       init_from_shapes, lm_head, rms_norm,
                                       routing_report)

@@ -16,6 +16,7 @@ norms' statistics and the logits are float32.
 from __future__ import annotations
 
 import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -86,46 +87,73 @@ def swiglu(x, p, multipliers=None):
     return (y.astype(jnp.float32) * m_down).astype(x.dtype)
 
 
+class Routed(NamedTuple):
+    """What an expert layer says of one call: ``idx`` the experts each token
+    chose (..., k), ``read`` the number of held experts whose weights the
+    call read, () int32, where its form counts them, else None (every held
+    expert; ``ops.moe.share_apply``)."""
+
+    idx: jax.Array
+    read: Optional[jax.Array]
+
+
+def experts_form(cfg, tokens: int, dtype) -> str:
+    """The form (``ops.moe.share_form``) in which the expert layers of a
+    model of ``cfg`` run a program of ``tokens`` tokens."""
+    return moe_ops.share_form(tokens, cfg.num_experts_per_tok, cfg.share,
+                              cfg.hidden_size, cfg.moe_intermediate_size,
+                              dtype)
+
+
 def expert_layer(p, x, cfg):
     """``x`` (T, d) -> (this chip's part of the routed sum + the shared
-    expert (T, d), the experts each token chose (T, k))."""
+    expert (T, d), ``Routed``: the experts each token chose (T, k))."""
     with jax.named_scope("moe"):
         idx, w = moe_ops.route(x, p["router"], p["bias"],
                                top_k=cfg.num_experts_per_tok,
                                scale=cfg.routed_scaling_factor,
                                normalize=cfg.norm_topk_prob)
-        routed = moe_ops.share_apply(x, idx, w, p["experts"], cfg.share)
-        return routed + swiglu(x, p["shared"]), idx
+        routed, read = moe_ops.share_apply(x, idx, w, p["experts"], cfg.share)
+        return routed + swiglu(x, p["shared"]), Routed(idx, read)
 
 
 def ffn(layer, h, cfg, *, norm: bool = True):
     """The feed-forward half of a block on (B, L, d), ``h + F(RMSNorm(h))``
-    (``norm`` False: ``h + F(h)``): -> (y, the experts each token chose
-    (B, L, k), or None for the dense layer)."""
+    (``norm`` False: ``h + F(h)``): -> (y, ``Routed`` with the experts each
+    token chose (B, L, k), or None for the dense layer)."""
     b, l, d = h.shape
     x = rms_norm(h, layer["ln_post"], cfg.rms_norm_eps) if norm else h
     if "mlp" in layer:
         with jax.named_scope("dense_mlp"):
             return h + swiglu(x, layer["mlp"]), None
-    y, idx = expert_layer(layer["moe"], x.reshape(b * l, d), cfg)
-    return h + y.reshape(b, l, d), idx.reshape(b, l, -1)
+    y, routed = expert_layer(layer["moe"], x.reshape(b * l, d), cfg)
+    return h + y.reshape(b, l, d), routed._replace(
+        idx=routed.idx.reshape(b, l, -1))
 
 
 def routing_report(chosen, mask, pick, cfg) -> dict:
     """What a program reports of its routing: ``counts`` (expert layers,
     held) assignments of the tokens ``mask`` (B, L) marks that landed on
     each held expert, and ``choices`` (expert layers, B, k) the experts
-    chosen at position ``pick`` (B,) of each sequence."""
+    chosen at position ``pick`` (B,) of each sequence; where the layers'
+    form counts the experts it read (``Routed.read``), also
+    ``experts_read`` () int32, their sum over the layers (every row of the
+    batch reads, marked or not).  ``chosen``: each layer's ``Routed``, None
+    for a dense layer."""
     chosen = [c for c in chosen if c is not None]
     if not chosen:
         return {"counts": jnp.zeros((0, cfg.share.held), jnp.int32),
                 "choices": jnp.zeros((0, pick.shape[0], cfg.num_experts_per_tok),
                                      jnp.int32)}
-    counts = [moe_ops.held_counts(jnp.where(mask[..., None], c, -1), cfg.share)
-              for c in chosen]
-    at = [jnp.take_along_axis(c, pick[:, None, None], axis=1)[:, 0]
+    counts = [moe_ops.held_counts(jnp.where(mask[..., None], c.idx, -1),
+                                  cfg.share) for c in chosen]
+    at = [jnp.take_along_axis(c.idx, pick[:, None, None], axis=1)[:, 0]
           for c in chosen]
-    return {"counts": jnp.stack(counts), "choices": jnp.stack(at)}
+    report = {"counts": jnp.stack(counts), "choices": jnp.stack(at)}
+    read = [c.read for c in chosen if c.read is not None]
+    if read:
+        report["experts_read"] = sum(read)
+    return report
 
 
 def no_routing(batch: int) -> dict:

@@ -500,7 +500,9 @@ def render_stats(stats: dict, *, prefix: str = "can_tpu_serve",
             continue
         if k == "lm" and isinstance(v, dict):
             # the language model's engine: generated tokens, assignments
-            # that landed on held experts against all, cache bytes by kind
+            # that landed on held experts against all, held experts whose
+            # weights the decode steps read against those they could have
+            # (``decode_experts_read`` / ``_held``), cache bytes by kind
             for name, n in v.items():
                 if name == "cache_bytes":
                     for kind, b in n.items():

@@ -4,27 +4,38 @@
 keeps its published width) and picks its ``top_k``.  ``share_apply``
 computes what the experts held HERE add for the tokens routed to them.
 What the absent experts would have added is left out: that partial sum is
-the layer's routed output on this chip.  Two forms of the grouped product,
-chosen by the (static) number of tokens:
+the layer's routed output on this chip.  Three forms of the grouped product,
+chosen by ``share_form`` from the (static) shapes and the backend:
 
-* many tokens (a prefill): the assignments that landed on a held expert
-  are put in expert order (a counting sort: no comparison sort over T x k
-  keys), one grouped matrix product per projection runs over the sorted
-  rows (``jax.lax.ragged_dot``, which XLA:TPU lowers to its own
+* many tokens (a prefill), ``"sorted"``: the assignments that landed on a
+  held expert are put in expert order (a counting sort: no comparison sort
+  over T x k keys), one grouped matrix product per projection runs over the
+  sorted rows (``jax.lax.ragged_dot``, which XLA:TPU lowers to its own
   grouped-matmul kernel and XLA:CPU to a plain loop), and every token
   gathers its own rows back with its routing weights.  The sorted buffer has
   room for every assignment that CAN land here, ``T * min(top_k, held)``
   rows, and the grouped product visits only the rows in use;
-* few tokens (a decode step: ``T <= DENSE_MAX_TOKENS``): every held expert
-  takes every token in one batched product and the routing weights (zero
-  where a token did not choose the expert) sum the results.  A step of 64
-  tokens gives a held expert 4 rows: the grouped kernel pads each expert's
-  rows to a tile of 512 and its tiles' arithmetic, not the weights' bytes,
-  took the time (20.0 ms a step of which the three products' tiles about
-  13; my chip run, PR 26); batched, the arithmetic is 16 x T rows and each
-  weight is read once.
+* few tokens (a decode step: ``T <= DENSE_MAX_TOKENS``), ``"batched"``:
+  every held expert takes every token in one batched product and the
+  routing weights (zero where a token did not choose the expert) sum the
+  results.  A step of 64 tokens gives a held expert 4 rows: the grouped
+  kernel pads each expert's rows to a tile of 512 and its tiles'
+  arithmetic, not the weights' bytes, took the time (20.0 ms a step of
+  which the three products' tiles about 13; my chip run, PR 26); batched,
+  the arithmetic is 16 x T rows and each weight is read once.  The plain
+  form of the few-tokens branch, the CPU's, and the oracle of the next;
+* few tokens of which many held experts get none, ``"skipping"`` (PR 33):
+  the same sum over only the held experts that a token of the step chose,
+  one Pallas kernel (``ops/pallas_experts.py``) whose grid walks those
+  experts, their ids by scalar prefetch, and reads no other expert's
+  weights.  XLA cannot do that (static shapes: its batched product is over
+  all ``held``).  Taken where the EXPECTED share of held experts without a
+  token, ``(1 - top_k / total) ** T``, is at least ``SKIP_MIN_IDLE`` and the
+  kernel's ``supports`` takes the backend and the widths: 16 tokens' top-4
+  of 64 leave 36% idle, 64 tokens' top-8 of 128 leave 1.6% and stay
+  batched.  This form counts the experts it read.
 
-No token is dropped under any imbalance in either form.
+No token is dropped under any imbalance in any form.
 """
 
 from __future__ import annotations
@@ -42,11 +53,32 @@ import jax.numpy as jnp
 # 2.89 / 4.21, 512 4.08 / 4.21, 768 5.61 / 4.71, 1024 7.80 / 4.97, 2048 16.0 /
 # 8.02: they cross near 550, where the tile arithmetic puts it (T * held =
 # T * k * held / total + held * 512, the grouped kernel's tile: T = 546).
-# NOT re-read where every expert is held (64 of 64, 2048 x 1536, top-4: a
-# decode step of 16 tokens takes the batched form and reads all 64 experts;
-# the same arithmetic would put the crossing at T = 546 there too, but
-# nobody timed it: PERF.md section 7)
+# Where every expert is held (64 of 64, 2048 x 1536, top-4) the two
+# few-tokens forms were timed at PR 33 (below); sorted against batched was
+# not re-timed there (the same arithmetic puts that crossing at T = 546 too)
 DENSE_MAX_TOKENS = 512
+
+# the few-tokens branch skips (reads only the experts with a token) where the
+# EXPECTED share of held experts without one, (1 - top_k / total) ** T, is at
+# least this.  Timed on the v5e, one layer, bfloat16, uniform routing
+# (``benchmark/tools/expert_decode_forms.py``; my chip run, PR 33), batched /
+# skipping, ms (GB/s of the weights each read; experts read of held):
+#   64 of 64 held, 2048 x 1536, top-4 (GLM-4.7-Flash)
+#     T =   8, idle 0.597: 1.642 (736) / 0.591 (671; 21 of 64)
+#     T =  16, idle 0.356: 1.633 (740) / 1.055 (716; 40)
+#     T =  32, idle 0.127: 1.636 (739) / 1.403 (726; 54)
+#     T =  64, idle 0.016: 1.637 (738) / 1.660 (728; 64)
+#     T = 128, idle 0.000: 1.645 (735) / 1.657 (729; 64)
+#   16 of 128 held, 6144 x 2048, top-8 (K-EXAONE)
+#     T =  64, idle 0.016: 1.718 (703) / 1.663 (726; 16)
+# Both forms stream near 90% of the 819 GB/s (XLA's batched products too: it
+# is not slow, it cannot skip), so the kernel's time is 0.05 ms + 0.0251 ms
+# an expert READ and it wins by the share it skips: -36% at 0.356, -14% at
+# 0.127, a tie (+1.4%, -3.2%) at 0.016.  The line is drawn between the last
+# two, where the bytes skipped are a few times the kernel's fixed cost and
+# the spread of either form.  The kernel's tile of ``f`` (``TILE_F``), at
+# T = 16: 256 1.066, 512 1.057, 768 1.063, 1536 (whole) 1.054: no matter
+SKIP_MIN_IDLE = 0.05
 
 
 class ExpertShare(NamedTuple):
@@ -95,14 +127,61 @@ def held_counts(idx, share: ExpertShare):
     return hot.reshape(-1, share.held).sum(0)
 
 
+def share_form(tokens: int, top_k: int, share: ExpertShare, d: int, f: int,
+               dtype) -> str:
+    """``"sorted"`` / ``"batched"`` / ``"skipping"``: the form ``share_apply``
+    takes for ``tokens`` tokens of width ``d`` through experts of width
+    ``f``.  Shapes and the backend choose, nothing else."""
+    if tokens > DENSE_MAX_TOKENS:
+        return "sorted"
+    if (1.0 - top_k / share.total) ** tokens < SKIP_MIN_IDLE:
+        return "batched"
+    # imported here: ``jax.experimental.pallas`` takes over a second to
+    # load, paid only by a process one of whose shapes could skip
+    from can_tpu.ops import pallas_experts
+
+    return ("skipping" if pallas_experts.supports(tokens, d, f, dtype)
+            else "batched")
+
+
 def share_apply(x, idx, w, experts, share: ExpertShare):
-    """sum over the chosen experts held here of ``w_i E_i(x)``, (T, d).
+    """-> (sum over the chosen experts held here of ``w_i E_i(x)``, (T, d);
+    the number of held experts whose weights were read, () int32, where the
+    form counts it (``"skipping"``), else None: every held expert was).
 
     ``experts``: {"gate", "up": (held, d, f); "down": (held, f, d)};
     ``E(x) = (silu(x gate) * (x up)) down``."""
-    if idx.shape[0] <= DENSE_MAX_TOKENS:
-        return _share_apply_batched(x, idx, w, experts, share)
-    return _share_apply_sorted(x, idx, w, experts, share)
+    form = share_form(idx.shape[0], idx.shape[1], share,
+                      *experts["gate"].shape[1:], x.dtype)
+    if form == "skipping":
+        return _share_apply_skipping(x, idx, w, experts, share)
+    if form == "batched":
+        return _share_apply_batched(x, idx, w, experts, share), None
+    return _share_apply_sorted(x, idx, w, experts, share), None
+
+
+def _share_apply_skipping(x, idx, w, experts, share: ExpertShare, *,
+                          kernel=None):
+    """Only the held experts a token chose, their weights read once
+    (``kernel``: ``pallas_experts.skipping_experts`` unless given); -> (the
+    sum, how many experts that were)."""
+    if kernel is None:
+        from can_tpu.ops import pallas_experts
+
+        kernel = pallas_experts.skipping_experts
+    local, held = _held(idx, share)
+    slots = jnp.arange(share.held)
+    hot = held[..., None] & (local[..., None] == slots)       # (T, k, held)
+    w_te = jnp.sum(jnp.where(hot, w[..., None], 0.0), axis=1)
+    hit = jnp.any(hot, axis=(0, 1))
+    n_active = jnp.sum(hit.astype(jnp.int32))
+    # the hit experts' ids in ascending order, by their rank among the hit
+    # (a compare over held x held: no sort); the tail repeats the last
+    rank = jnp.cumsum(hit.astype(jnp.int32)) - 1
+    active = jnp.sum(jnp.where(hit[None, :] & (rank[None, :] == slots[:, None]),
+                               slots[None, :], 0), axis=1)
+    active = jnp.where(slots < n_active, active, jnp.max(active))
+    return kernel(x, w_te, active, n_active, experts), n_active
 
 
 def _share_apply_batched(x, idx, w, experts, share: ExpertShare):

@@ -425,6 +425,9 @@ class LMEngine:
                          # assignments_held: tokens over all held experts
                          "expert_tokens_max": 0,
                          "assignments_held": 0, "assignments_all": 0,
+                         # held experts whose weights the decode steps read
+                         # (layers x steps), of those they could have
+                         "decode_experts_read": 0, "decode_experts_held": 0,
                          "cache_bytes": {},
                          # launches by the form their prefill's attention
                          # ran in (models that note none: empty)
@@ -522,22 +525,34 @@ class LMEngine:
                 sp.attrs["compiled"] = compiled
                 if (slots, 1) in self.ssm_forms:
                     sp.attrs["ssm"] = self.ssm_forms[(slots, 1)]
+                # (expert layers, held): no rows in a model without experts
+                expert_layers, held = pre["counts"].shape
+                if expert_layers:
+                    sp.attrs["experts"] = self.programs.decode_experts(slots)
             launch.attrs["compiled"] = compiled
         self._last_compiled = compiled
-        with span("serve.fetch", logits=bool(want_logits)):
+        with span("serve.fetch", logits=bool(want_logits)) as sp:
             # can-tpu-lint: disable=HOSTSYNC(the fetch IS the product: the generated ids resolve the waiting requests)
             ids = np.asarray(state["ids"])[:, :steps]
             # can-tpu-lint: disable=HOSTSYNC(the launch's routing counters, reduced on the device, fetched with the answers)
             pre_counts, dec_counts = np.asarray(pre["counts"]), np.asarray(state["counts"])
+            # held experts whose weights the decode steps read, of those
+            # they could have: a form that does not count read them all
+            experts_held = expert_layers * held * steps
+            # can-tpu-lint: disable=HOSTSYNC(one more counter of the launch, fetched with the others)
+            experts_read = (int(np.asarray(state["experts_read"]))
+                            if "experts_read" in state else experts_held)
+            if expert_layers:
+                sp.attrs["experts_read"] = experts_read
+                sp.attrs["experts_held"] = experts_held
             fetched = None
             if want_logits:
                 # can-tpu-lint: disable=HOSTSYNC(fetched only when a request asked for its logits)
                 fetched = jax.tree.map(np.asarray, probes)
         self._warm.add((slots, bucket))
-        # (expert layers, slots, k): none of either in a model without experts
-        expert_layers, _, k = pre["choices"].shape
+        k = pre["choices"].shape[-1]   # (expert layers, slots, k)
         self._count(cache, valid, valid_tokens, steps, pre_counts, dec_counts,
-                    attention, expert_layers * k)
+                    attention, expert_layers * k, experts_read, experts_held)
         return ids, fetched
 
     def _note_forms(self, traced: bool, program: tuple) -> None:
@@ -563,7 +578,8 @@ class LMEngine:
         return batch_signature(flat)
 
     def _count(self, cache, valid, valid_tokens, steps, pre_counts,
-               dec_counts, attention, choices_per_token: int) -> None:
+               dec_counts, attention, choices_per_token: int,
+               experts_read: int, experts_held: int) -> None:
         """``choices_per_token``: routing choices a token makes over all the
         expert layers (0 for a model without one: every expert counter then
         reads zero)."""
@@ -583,6 +599,8 @@ class LMEngine:
                                      int((pre_counts + dec_counts).max(initial=0)))
         c["assignments_held"] += held
         c["assignments_all"] += every
+        c["decode_experts_read"] += experts_read
+        c["decode_experts_held"] += experts_held
         c["cache_bytes"] = kv_cache.nbytes_by_kind(cache, p.cache_layout)
         if attention is not None:
             by_form = c["prefill_attention"]

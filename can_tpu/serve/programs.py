@@ -83,7 +83,12 @@ class LMPrograms:
     * ``cfg.vocab`` (``lm_blocks.VocabSlice``): ids and logits are over the
       slice held;
     * optionally ``model.attention_traced`` / ``model.ssm_traced``: (B, L) of
-      a program's tokens -> the form its newest trace ran that layer in.
+      a program's tokens -> the form its newest trace ran that layer in;
+    * a model with an expert layer: ``model.experts_form(cfg, tokens, dtype)``
+      -> the form its expert layers take for a program of that many tokens
+      (``ops.moe.share_form``).  In the ``"skipping"`` form its ``routing``
+      also has ``experts_read`` () int32, the held experts whose weights the
+      step read, summed over the expert layers.
 
     State between the programs of a launch, all on the device: the cache
     (``serve/cache.py``) and ``state``: ``tokens`` (slots,) the token each
@@ -92,7 +97,9 @@ class LMPrograms:
     what each slot has generated (column 0 from prefill, column s from
     decode step s), ``step`` the next decode step (from 1), ``counts``
     (expert layers, held) decode assignments that landed on each held
-    expert.  Greedy: the next token is the argmax over the vocabulary slice.
+    expert; where decode's expert layers skip (``experts_form``), also
+    ``experts_read`` () the experts they read so far, summed over layers and
+    steps.  Greedy: the next token is the argmax over the vocabulary slice.
     """
 
     def __init__(self, model, cfg, *, max_new_tokens: int,
@@ -110,7 +117,17 @@ class LMPrograms:
         # "step" / a kernel's name, as its newest trace ran the state-space
         # recurrence; None for a model without one
         self.ssm_traced = getattr(model, "ssm_traced", None)
+        self._experts_form = getattr(model, "experts_form", None)
         self.vocab_size = cfg.vocab.held   # ids and logits are over the slice
+
+    def decode_experts(self, slots: int):
+        """``"skipping"`` / ``"batched"`` / ``"sorted"``: the form the expert
+        layers take in a decode step of ``slots`` tokens (shapes and the
+        backend say, as they do when the step is traced); None for a model
+        without an expert layer."""
+        if self._experts_form is None:
+            return None
+        return self._experts_form(self.cfg, slots, self.dtype)
 
     def positions(self, bucket: int) -> int:
         """Positions of context a launch of ``bucket``-token prompts needs."""
@@ -134,6 +151,8 @@ class LMPrograms:
                  "active": active, "ids": ids.at[:, 0].set(first),
                  "step": jnp.ones((), jnp.int32),
                  "counts": jnp.zeros_like(pre["counts"])}
+        if self.decode_experts(first.shape[0]) == "skipping":
+            state["experts_read"] = jnp.zeros((), jnp.int32)
         return state, pre
 
     def prefill_slice(self, params, batch, cache, start):
@@ -161,11 +180,14 @@ class LMPrograms:
         ids = jax.lax.dynamic_update_slice(
             state["ids"], nxt[:, None], (jnp.zeros((), jnp.int32),
                                          state["step"]))
-        state = {"tokens": nxt, "positions": state["positions"] + 1,
+        moved = {"tokens": nxt, "positions": state["positions"] + 1,
                  "active": state["active"], "ids": ids,
                  "step": state["step"] + 1,
                  "counts": state["counts"] + routing["counts"]}
-        return state, cache, {"logits": logits, "choices": routing["choices"]}
+        if "experts_read" in routing:
+            moved["experts_read"] = (state["experts_read"]
+                                     + routing["experts_read"])
+        return moved, cache, {"logits": logits, "choices": routing["choices"]}
 
 
 # -- the table of served models -------------------------------------------

@@ -3,7 +3,8 @@
 
     python chip_smoke.py              # one chip: data, train, eval, serve,
                                       #           syncBN-pallas leg, the
-                                      #           fused prefill attention
+                                      #           fused prefill attention,
+                                      #           the skipping experts
     python chip_smoke.py --chips 4    # four chips: dp=4 and dp=2 x sp=2 train
                                       #   steps against a one-device reference,
                                       #   cli.train on all four, cli.serve
@@ -513,6 +514,74 @@ def phase_attention(device: dict) -> None:
     find(r"^ATTENTION OK$", out, "attention worker verdict")
 
 
+# ------------------------------------------------- skipping experts --
+def experts_worker() -> None:
+    """CHILD process (``--experts-worker``): the skipping experts kernel
+    (``ops/pallas_experts.py``) COMPILED for the chip (interpreted only
+    where JAX is held to the CPU, with 2 tokens and a quarter of the
+    experts) against ``_share_apply_batched``, one layer at GLM-4.7-Flash's
+    published widths: 16 tokens, 64 of 64 experts held, 2048 x 1536, top-4,
+    bfloat16."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from can_tpu.ops import moe as moe_ops
+    from can_tpu.ops import pallas_experts
+    from can_tpu.parallel import init_runtime
+    from can_tpu.utils import enable_compilation_cache
+
+    print(f"[runtime] {init_runtime()}")
+    print(f"[xla] persistent compilation cache at "
+          f"{enable_compilation_cache()}")
+    on_chip = jax.default_backend() == "tpu"
+    d, f, k = 2048, 1536, 4
+    tokens, held = (16, 64) if on_chip else (2, 16)
+    share = moe_ops.ExpertShare(0, held, held)
+    ks = jax.random.split(jax.random.key(SEED), 6)
+    experts = {
+        "gate": jax.random.normal(ks[0], (held, d, f), jnp.bfloat16) * d ** -0.5,
+        "up": jax.random.normal(ks[1], (held, d, f), jnp.bfloat16) * d ** -0.5,
+        "down": jax.random.normal(ks[2], (held, f, d), jnp.bfloat16) * f ** -0.5}
+    x = jax.random.normal(ks[3], (tokens, d), jnp.bfloat16)
+    idx = jnp.argsort(jax.random.uniform(ks[4], (tokens, held)),
+                      axis=-1)[:, :k].astype(jnp.int32)
+    w = 1.8 * jax.nn.softmax(jax.random.normal(ks[5], (tokens, k)), axis=-1)
+    assert pallas_experts.supports(tokens, d, f, x.dtype,
+                                   interpret=not on_chip), "supports() refused"
+    if on_chip:
+        form = moe_ops.share_form(tokens, k, share, d, f, x.dtype)
+        assert form == "skipping", f"share_form says {form} on the chip"
+    kernel = functools.partial(pallas_experts.skipping_experts,
+                               interpret=not on_chip)
+    got, read = jax.jit(lambda *a: moe_ops._share_apply_skipping(
+        *a, share, kernel=kernel))(x, idx, w, experts)
+    plain = jax.jit(lambda *a: moe_ops._share_apply_batched(*a, share))(
+        x, idx, w, experts)
+    chosen = int((moe_ops.held_counts(idx, share) > 0).sum())
+    size = float(jnp.abs(plain.astype(jnp.float32)).max())
+    gap = float(jnp.abs(got.astype(jnp.float32)
+                        - plain.astype(jnp.float32)).max())
+    print(f"[experts] kernel {'compiled (not interpreted)' if on_chip else 'INTERPRETED'}"
+          f", platform {jax.default_backend()}; x {x.shape} experts "
+          f"{experts['gate'].shape}: read {int(read)} of {held} experts "
+          f"({chosen} have a token), largest gap to _share_apply_batched "
+          f"{gap:.3e} on answers up to {size:.3f}")
+    # the two forms round the same products; a bfloat16 step of the largest
+    # answer is size * 2^-8, and the sum over 4 experts is float32 in both
+    assert int(read) == chosen < held and gap <= size * 2 ** -6
+    print("EXPERTS OK")
+
+
+def phase_experts(device: dict) -> None:
+    out = run_child("experts", [os.path.abspath(__file__),
+                                "--experts-worker", "--seed", str(SEED)])
+    check_runtime("experts", out, device)
+    say(find(r"^\[experts\] .+$", out, "kernel line").group(0))
+    find(r"^EXPERTS OK$", out, "experts worker verdict")
+
+
 # ------------------------------------------------- four-chip mesh worker --
 def mesh_worker() -> None:
     """CHILD process (``--mesh-worker``): the only code here that imports
@@ -663,6 +732,8 @@ def main(argv=None) -> int:
                     help=argparse.SUPPRESS)
     ap.add_argument("--attention-worker", action="store_true",
                     help=argparse.SUPPRESS)
+    ap.add_argument("--experts-worker", action="store_true",
+                    help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
     global PLATFORM, CHIPS, SEED
     CHIPS, SEED = args.chips, args.seed
@@ -671,6 +742,9 @@ def main(argv=None) -> int:
         return 0
     if args.attention_worker:
         attention_worker()
+        return 0
+    if args.experts_worker:
+        experts_worker()
         return 0
     if args.rehearse_cpu:
         PLATFORM = "cpu"
@@ -711,6 +785,7 @@ def main(argv=None) -> int:
                     buckets=shapes["serve_buckets"], replicas=1, port=8731)
         phase_pallas(device, data=one_size)
         phase_attention(device)
+        phase_experts(device)
     else:
         phase_data(one_size, 32, 8, bucket)
         phase_mesh_worker(device)

@@ -108,3 +108,17 @@ def tiny_falcon_model(seed=0, dtype=jnp.float32, **kw):
     d = tiny_falcon_config(**kw)
     cfg = fh.FalconH1Config.from_dict(d)
     return d, cfg, fh.init_params(jax.random.key(seed), cfg, dtype)
+
+
+def interpret_skipping_experts(monkeypatch) -> None:
+    """The skipping experts kernel (``ops/pallas_experts.py``) interpreted
+    wherever its shapes fit: what a TPU backend turns on, steered here as
+    the compile tests steer it."""
+    import functools
+
+    from can_tpu.ops import pallas_experts
+
+    monkeypatch.setattr(pallas_experts, "supports", functools.partial(
+        pallas_experts.supports, interpret=True))
+    monkeypatch.setattr(pallas_experts, "skipping_experts", functools.partial(
+        pallas_experts.skipping_experts, interpret=True))

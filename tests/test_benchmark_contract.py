@@ -337,6 +337,66 @@ def test_the_spans_ssm_is_written_where_its_reader_reads_it():
     assert '"lm.prefill"' in reader and 's["ssm"] == "chunked"' in reader
 
 
+def test_the_fetch_span_s_expert_counters_are_written_where_their_reader_reads_them():
+    """``decode_experts_read_pct.lm`` reads ``experts_read`` / ``experts_held``
+    off the launches' ``serve.fetch`` spans: the engine sets both inside
+    that span, where the counters arrive from the device."""
+    from can_tpu.serve.engine import LMEngine
+
+    source = inspect.getsource(LMEngine.generate_batch)
+    inside = source[source.index('span("serve.fetch"'):]
+    assert 'sp.attrs["experts_read"]' in inside
+    assert 'sp.attrs["experts_held"]' in inside
+    with open(os.path.join(BENCH, "metrics",
+                           "decode_experts_read_pct.lm.py")) as f:
+        reader = f.read()
+    assert '"serve.fetch"' in reader
+    assert 'fetch["experts_read"]' in reader and 'fetch["experts_held"]' in reader
+
+
+def test_the_experts_reader_reads_a_recorded_window(monkeypatch):
+    """The reader over a ring as the program records it: two launches that
+    read 60 and 68 of 100 read 64; a ring whose fetch spans carry no
+    counter (the parent's program) reads nothing."""
+    import importlib.util
+
+    from benchmark.harness import program_spans
+    from can_tpu.obs import spans as recorder
+
+    spec = importlib.util.spec_from_file_location(
+        "decode_experts_read_pct_lm",
+        os.path.join(BENCH, "metrics", "decode_experts_read_pct.lm.py"))
+    reader = importlib.util.module_from_spec(spec)
+    # a reader's module arms a ring-only tracer when it is loaded: put back
+    # what was installed
+    monkeypatch.setattr(recorder, "_installed", recorder._installed)
+    spec.loader.exec_module(reader)
+
+    def ring(counters):
+        spans, sid = [], iter(range(1, 100))
+        for n, attrs in enumerate(counters):
+            batch = next(sid)
+            spans.append({"name": "serve.batch", "span_id": batch,
+                          "parent_id": 0, "trace_id": "lane", "valid": 16,
+                          "start_s": float(n), "duration_s": 0.9})
+            spans.append({"name": "serve.dispatch", "span_id": next(sid),
+                          "parent_id": batch, "trace_id": "lane",
+                          "compiled": False, "start_s": float(n),
+                          "duration_s": 0.5})
+            spans.append({"name": "serve.fetch", "span_id": next(sid),
+                          "parent_id": batch, "trace_id": "lane",
+                          "start_s": n + 0.5, "duration_s": 0.1, **attrs})
+        return program_spans.Ring(spans, lambda span, kids: 0.0)
+
+    ctx = {"counters": {"rate": {"rate": 32.0, "window_s": 1.0}}}
+    for counters, want in (
+            ([{"experts_read": 60, "experts_held": 100},
+              {"experts_read": 68, "experts_held": 100}], 64.0),
+            ([{}, {}], None)):
+        monkeypatch.setattr(program_spans, "read", lambda c=counters: ring(c))
+        assert reader.read(ctx) == want
+
+
 def test_the_state_counter_its_reader_reads_is_kept_by_kind():
     """``state_cache_bytes_per_slot.lm`` reads ``cache_bytes["state"]``."""
     from can_tpu.ops import cache_layout as layout

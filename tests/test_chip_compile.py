@@ -288,6 +288,70 @@ def test_glm_prefill_slice_compiles_for_one_device(v5e):
     assert 10 * 2**30 < _fits_hbm(compiled) < 14 * 2**30
 
 
+# -- the skipping experts kernel (ops/pallas_experts.py) ---------------------
+@pytest.mark.parametrize("tokens,held,d,f", [(16, 64, 2048, 1536),
+                                             (1, 64, 2048, 1536),
+                                             (64, 16, 6144, 2048)],
+                         ids=["glm-16", "glm-1", "k-exaone-64"])
+def test_skipping_experts_kernel_compiles_at_published_widths(v5e, tokens,
+                                                              held, d, f):
+    """A decode step's rows through one layer's held experts, the weights
+    in tiles of 512 of their ``f`` (three blocks of 2 to 6 MB, twice)."""
+    from can_tpu.ops import pallas_experts
+
+    one = SingleDeviceSharding(v5e[0])
+    shape = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
+    experts = {"gate": shape((held, d, f), jnp.bfloat16),
+               "up": shape((held, d, f), jnp.bfloat16),
+               "down": shape((held, f, d), jnp.bfloat16)}
+    compiled = jax.jit(pallas_experts.skipping_experts).lower(
+        shape((tokens, d), jnp.bfloat16), shape((tokens, held), jnp.float32),
+        shape((held,), jnp.int32), shape((), jnp.int32), experts).compile()
+    assert "skipping_experts" in compiled.as_text()
+
+
+def test_glm_decode_step_compiles_with_the_skipping_experts(v5e, monkeypatch):
+    """The GLM cell's decode step as the chip traces it (``supports`` asks
+    the backend, which is the CPU's during a compile for a described chip:
+    steered here): five kernel launches, none of the batched form's
+    products, the counter in the state, and the experts' weights handed to
+    the kernel AS STORED: the program's own parameters, never a copy or a
+    transpose of a (64, 2048, 1536) or (64, 1536, 2048) operand."""
+    import re
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    programs, params, cache, _, shape = _lm_programs_and_shapes(v5e, 16, 2, GLM)
+    assert programs.decode_experts(16) == "skipping"
+    state = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(lambda: programs.new_state(
+            [{"first": jnp.zeros((16,), jnp.int32),
+              "logits": jnp.zeros((16, 8), jnp.float32),
+              "choices": jnp.zeros((5, 16, 4), jnp.int32),
+              "counts": jnp.zeros((5, 64), jnp.int32)}],
+            jnp.ones((16,), jnp.int32), jnp.ones((16,), bool))[0]))
+    assert state["experts_read"].shape == ()
+    compiled = jax.jit(programs.decode, donate_argnums=(1, 2)).lower(
+        params, state, cache).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%skipping_experts[.\d]* = \S+ custom-call\(([^)]*)\)",
+                       text)
+    assert len(calls) == 5
+    assert "bf16[64,16,1536]" not in text         # no product over all 64
+    entry = text[text.index("ENTRY"):]
+    for operands in calls:
+        weights = re.sub(r"/\*.*?\*/", "", operands).split(", ")[-3:]  # gate, up, down
+        for name in weights:
+            made = re.search(rf"^\s*{re.escape(name)} = (\S+) (\S+?)\(", entry,
+                             re.M)
+            assert made and made.group(2) == "parameter", (name, made)
+            assert re.match(r"bf16\[64,(2048,1536|1536,2048)\]", made.group(1))
+    for line in entry.splitlines():
+        if re.search(r"= bf16\[64,(2048,1536|1536,2048)\]", line):
+            assert " parameter(" in line, line    # nothing else has the shape
+    assert _fits_hbm(compiled) > 9 * 2**30
+
+
 # -- the state-space hybrid at the published widths -------------------------
 FALCON = "falcon-h1-34b-pp12-serve-bf16"
 

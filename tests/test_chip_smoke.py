@@ -57,3 +57,19 @@ def test_attention_worker_rehearses_on_the_cpu():
     assert any(l.startswith("[attention] kernel INTERPRETED, platform cpu")
                for l in lines)
     assert lines[-1] == "ATTENTION OK"
+
+
+def test_experts_worker_rehearses_on_the_cpu():
+    """The skipping experts leg's child (``--experts-worker``) with JAX held
+    to the CPU: the kernel interpreted at GLM's widths against
+    ``_share_apply_batched``; its lines are what ``phase_experts`` looks
+    for."""
+    out = subprocess.run([sys.executable, SMOKE, "--experts-worker"],
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=REPO,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stdout + out.stderr
+    lines = out.stdout.splitlines()
+    assert any(l.startswith("[runtime] {") for l in lines)
+    assert any(l.startswith("[experts] kernel INTERPRETED, platform cpu")
+               for l in lines)
+    assert lines[-1] == "EXPERTS OK"

@@ -176,7 +176,7 @@ class TestExpertShare:
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
                                    rtol=2e-5)
         monkeypatch.setattr(moe_ops, "DENSE_MAX_TOKENS", 64)
-        c = moe_ops.share_apply(x, idx, w, experts, share)
+        c, _ = moe_ops.share_apply(x, idx, w, experts, share)
         np.testing.assert_allclose(np.asarray(c), np.asarray(a), atol=2e-5,
                                    rtol=2e-5)
 
@@ -188,7 +188,7 @@ class TestExpertShare:
         idx = jnp.zeros((9, 2), jnp.int32).at[:, 1].set(1)
         w = jnp.ones((9, 2))
         experts = {k: v[6:] for k, v in p["experts"].items()}
-        got = moe_ops.share_apply(x, idx, w, experts, share)
+        got, _ = moe_ops.share_apply(x, idx, w, experts, share)
         assert float(jnp.abs(got).max()) == 0.0
 
     def test_router_weights(self):
@@ -304,3 +304,154 @@ def test_published_configuration_counts():
                                                          False, True]
     assert cfg.mlp_layer_types == ("dense",) + ("sparse",) * 4
     assert cfg.mtp_layers == 0
+
+
+# -- which form the held experts' product takes (ops/moe.py::share_form) ----
+GLM_SHARE, EXAONE_SHARE = moe_ops.ExpertShare(0, 64, 64), moe_ops.ExpertShare(0, 16, 128)
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+@pytest.mark.parametrize("what,args,on_tpu", [
+    ("GLM's decode step: a third of the experts idle",
+     (16, 4, GLM_SHARE, 2048, 1536), "skipping"),
+    ("GLM, 8 tokens", (8, 4, GLM_SHARE, 2048, 1536), "skipping"),
+    ("GLM, 64 tokens: nobody idle", (64, 4, GLM_SHARE, 2048, 1536), "batched"),
+    ("K-EXAONE's decode step: nobody idle",
+     (64, 8, EXAONE_SHARE, 6144, 2048), "batched"),
+    ("K-EXAONE, 8 tokens", (8, 8, EXAONE_SHARE, 6144, 2048), "skipping"),
+    ("widths that are no whole lanes", (4, 2, moe_ops.ExpertShare(0, 8, 8), 64, 32),
+     "batched"),
+    ("a token over the few", (moe_ops.DENSE_MAX_TOKENS + 1, 4, GLM_SHARE, 2048,
+                              1536), "sorted"),
+    ("a prefill slice", (32768, 4, GLM_SHARE, 2048, 1536), "sorted"),
+], ids=lambda v: v if isinstance(v, str) else None)
+def test_the_form_is_chosen_by_backend_and_shapes(backend, what, args, on_tpu,
+                                                  monkeypatch):
+    """Off a TPU always a plain form; on one the kernel where the expected
+    share of idle experts, ``(1 - k / total) ** T``, is worth skipping and
+    the kernel's ``supports`` takes the widths."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    want = on_tpu if backend == "tpu" or on_tpu == "sorted" else "batched"
+    assert moe_ops.share_form(*args, jnp.bfloat16) == want
+
+
+@pytest.mark.parametrize("form", ["skipping", "batched", "sorted"])
+def test_share_apply_runs_the_form_chosen(form, monkeypatch):
+    ran = []
+    for name in ("skipping", "batched", "sorted"):
+        monkeypatch.setattr(
+            moe_ops, f"_share_apply_{name}",
+            lambda *a, name=name: (ran.append(name)
+                                   or (("y", 3) if name == "skipping" else "y")))
+    monkeypatch.setattr(moe_ops, "share_form", lambda *a: form)
+    x, idx = jnp.zeros((4, 128)), jnp.zeros((4, 2), jnp.int32)
+    experts = {"gate": jnp.zeros((8, 128, 256))}
+    got = moe_ops.share_apply(x, idx, None, experts, moe_ops.ExpertShare(0, 8, 8))
+    assert ran == [form]
+    assert got == (("y", 3) if form == "skipping" else ("y", None))
+
+
+# -- the serving programs of the models this change must not move -----------
+# sha256 of the StableHLO text of ``LMPrograms.decode`` / ``.prefill_slice``
+# at the benchmark's configurations, lowered on the CPU with jax 0.9.0 at
+# commit 0227fb7 (PR 32).  A PR that means to change one of these programs
+# prints the new value with this test's failure and replaces it here.
+PROGRAM_TEXT = {
+    ("k-exaone-ep8-serve-bf16", "decode"):
+        "be43ebf728ceeff7e972c249bae208143218bc4fb724638127cf8c95c0512622",
+    ("k-exaone-ep8-serve-bf16", "prefill_slice"):
+        "bb7d1d97fcf9ba32df5bb46cf815b1ecaab3ab6cc8d8f16461276278dfe9f6c0",
+    ("falcon-h1-34b-pp12-serve-bf16", "decode"):
+        "59e7ad47cb2d3d41d1833184c93ba29d34c6061137822c72923499b4a4510386",
+    ("falcon-h1-34b-pp12-serve-bf16", "prefill_slice"):
+        "7dbdc1aaac65afe9ff78235ea2ebc774d5fa7b43881686bb73b86555ca11ee7a",
+    ("glm-4.7-flash-pp8-serve-bf16", "decode"):
+        "9bafbae804c45ab05d80ff531d20944e158ad3719d5d49f1a1dfed610a8e1323",
+    ("glm-4.7-flash-pp8-serve-bf16", "prefill_slice"):
+        "e9f9fe50e6393695b08d2153b7db87cf10b93c4a3bad87fc68f1288515920756",
+}
+
+
+def _program_text(name: str, program: str) -> str:
+    import json
+
+    from can_tpu.serve import programs as serve_programs
+
+    with open(os.path.join(ROOT, "benchmark", "configs", name + ".json")) as f:
+        config = json.load(f)
+    slots, part = int(config["max_batch"]), int(config["prefill_slice"])
+    if config["model_type"] == "exaone_moe":
+        model, cfg = em, em.ExaoneMoeConfig.from_dict(config)
+    elif config["model_type"] == "falcon_h1":
+        from can_tpu.models import falcon_h1 as model
+
+        cfg = model.FalconH1Config.from_dict(config)
+    else:
+        from can_tpu.models import glm_moe_lite as model
+
+        cfg = model.Glm4MoeLiteConfig.from_dict(config)
+    programs = serve_programs.LMPrograms(
+        model, cfg, max_new_tokens=int(config["max_new_tokens"]))
+    shape = jax.ShapeDtypeStruct
+    flat, treedef = jax.tree_util.tree_flatten_with_path(
+        model.param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
+    params = jax.tree_util.tree_unflatten(treedef, [
+        shape(s, jnp.float32 if path[-1].key == "bias" else jnp.bfloat16)
+        for path, s in flat])
+    bucket = int(config["length_ladder"][-1])
+    cache = jax.eval_shape(lambda: programs.new_cache(slots, bucket))
+    batch = {"tokens": shape((part, bucket), jnp.int32),
+             "lengths": shape((part,), jnp.int32),
+             "active": shape((part,), jnp.bool_)}
+    start = shape((), jnp.int32)
+    if program == "prefill_slice":
+        return jax.jit(programs.prefill_slice, donate_argnums=(2,)).lower(
+            params, batch, cache, start).as_text()
+    out = jax.eval_shape(lambda *a: programs.prefill_slice(*a)[0], params,
+                         batch, cache, start)
+    state = jax.eval_shape(
+        lambda outs: programs.new_state(outs, jnp.ones((slots,), jnp.int32),
+                                        jnp.ones((slots,), bool))[0],
+        [out] * (slots // part))
+    return jax.jit(programs.decode, donate_argnums=(1, 2)).lower(
+        params, state, cache).as_text()
+
+
+@pytest.mark.parametrize("name,program,backend", [
+    (name, program, backend) for name, program in sorted(PROGRAM_TEXT)
+    for backend in ("cpu", "tpu")
+    # on a TPU GLM's two programs take the kernels, which only lower there
+    # (tests/test_chip_compile.py compiles both for a described v5e)
+    if not (backend == "tpu" and name.startswith("glm"))])
+def test_a_serving_program_lowers_to_the_text_it_had(name, program, backend,
+                                                     monkeypatch):
+    """K-EXAONE's and Falcon-H1's programs are the parent's, whatever the
+    backend says (K-EXAONE's shapes leave nothing to skip, Falcon-H1 has no
+    expert layer); GLM's are the parent's off a TPU."""
+    import hashlib
+
+    if jax.__version__ != "0.9.0":
+        pytest.skip(f"the texts were lowered with jax 0.9.0, not {jax.__version__}")
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    got = hashlib.sha256(_program_text(name, program).encode()).hexdigest()
+    assert got == PROGRAM_TEXT[name, program]
+
+
+def test_a_model_that_never_skips_does_not_load_pallas():
+    """``jax.experimental.pallas`` takes over a second to import
+    (``setup_s``): ``ops/moe.py`` loads the kernel's module only where a
+    shape could skip, so K-EXAONE's and Falcon-H1's processes never do."""
+    import subprocess
+    import sys
+
+    code = ("import sys, jax.numpy as jnp\n"
+            "from can_tpu.models import exaone_moe, falcon_h1\n"
+            "from can_tpu.ops import moe\n"
+            "import can_tpu.serve.programs\n"
+            "share = moe.ExpertShare(0, 16, 128)\n"
+            "assert moe.share_form(64, 8, share, 6144, 2048, jnp.bfloat16) == 'batched'\n"
+            "assert moe.share_form(8192, 8, share, 6144, 2048, jnp.bfloat16) == 'sorted'\n"
+            "bad = [m for m in sys.modules if 'pallas' in m]\n"
+            "assert not bad, bad\n")
+    subprocess.run([sys.executable, "-c", code], check=True, cwd=ROOT,
+                   env=dict(os.environ, JAX_PLATFORMS="cpu"), timeout=120)

@@ -17,7 +17,8 @@ from can_tpu.models import lm_blocks as lb
 from can_tpu.ops import attention as attn_ops
 from can_tpu.testing import glm_moe_lite_ref as ref
 
-from lm_tiny import tiny_glm_config, tiny_glm_model
+from lm_tiny import (interpret_skipping_experts, tiny_glm_config,
+                     tiny_glm_model)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BUCKET, NEW = 32, 12
@@ -318,3 +319,53 @@ def test_an_assumption_is_stated_and_only_what_is_implemented(name, value):
         assert json.load(f)["assumed"][name] == gm.ASSUMED[name]
     with pytest.raises(ValueError, match=name):
         gm.Glm4MoeLiteConfig.from_dict(tiny_glm_config(**{name: value}))
+
+
+# -- the decode step's expert layers on the skipping kernel ------------------
+class TestSkippingDecode:
+    """Hidden 128 and experts 128 wide (whole lanes), the kernel interpreted
+    where a TPU backend would compile it."""
+
+    @pytest.fixture()
+    def wide(self, monkeypatch):
+        interpret_skipping_experts(monkeypatch)
+        d = tiny_glm_config(mtp=0)
+        d.update(hidden_size=128, moe_intermediate_size=128)
+        cfg = gm.Glm4MoeLiteConfig.from_dict(d)
+        return d, cfg, gm.init_params(jax.random.key(1), cfg, jnp.float32)
+
+    def test_the_model_s_form_is_the_shapes(self, wide):
+        _, cfg, _ = wide
+        assert gm.experts_form(cfg, 3, jnp.float32) == "skipping"
+        assert gm.experts_form(cfg, 3 * BUCKET, jnp.float32) == "batched"
+        assert gm.experts_form(cfg, 1024, jnp.float32) == "sorted"
+        narrow = gm.Glm4MoeLiteConfig.from_dict(tiny_glm_config(mtp=0))
+        assert gm.experts_form(narrow, 3, jnp.float32) == "batched"
+
+    def test_decode_reports_what_it_read_and_matches_the_reference(self, wide):
+        """Three sequences' decode steps through the kernel: the reference's
+        logits at every step, and ``experts_read`` the distinct experts the
+        step's three rows chose, summed over the two expert layers; the
+        prefill (96 rows: nobody idle) reports none."""
+        d, cfg, params = wide
+        spec = ref.spec_from_config(d)
+        tokens, lengths = _prompts([32, 19, 9])
+        logits, cache, routing = jax.jit(gm.prefill, static_argnums=(3, 4))(
+            params, tokens, lengths, cfg, BUCKET + 3)
+        assert "experts_read" not in routing
+        step = jax.jit(gm.decode_step, static_argnums=(4,))
+        seqs = [list(tokens[i, :n]) for i, n in enumerate(lengths)]
+        pos = jnp.asarray(lengths)
+        for _ in range(3):
+            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            for i in range(3):
+                seqs[i].append(int(tok[i]))
+            logits, cache, routing = step(params, cache, tok, pos, cfg)
+            chosen = np.asarray(routing["choices"])          # (2, 3, 4)
+            assert int(routing["experts_read"]) == sum(
+                len(np.unique(layer)) for layer in chosen) <= 2 * 12
+            pos = pos + 1
+            for i, s in enumerate(seqs):
+                want = ref.forward(params, np.asarray(s), spec)["logits"][-1]
+                np.testing.assert_allclose(np.asarray(logits[i]), np.asarray(want),
+                                           atol=3e-5, rtol=3e-5)

@@ -154,6 +154,18 @@ def test_the_spans_say_which_form_the_recurrence_ran_in(service):
     assert svc.stats()["lm"]["prefill_attention"] == {}
 
 
+def test_a_model_without_experts_says_nothing_of_them(service):
+    svc, _, tracer = service
+    _launch_spans(tracer, svc.submit(_prompt(5, 9)))
+    assert svc.engine.programs.decode_experts(4) is None
+    ring = tracer.snapshot()
+    assert all("experts" not in s for s in ring if s["name"] == "lm.decode")
+    assert all("experts_read" not in s and "experts_held" not in s
+               for s in ring if s["name"] == "serve.fetch")
+    lm = svc.stats()["lm"]
+    assert lm["decode_experts_read"] == lm["decode_experts_held"] == 0
+
+
 def test_cli_builds_the_same_service(tmp_path, capsys):
     """``can_tpu.cli.serve --model-config`` takes a third language model."""
     from can_tpu.cli import serve as cli

@@ -18,7 +18,7 @@ from can_tpu.serve import GenerateService, build_model_service, lm_probe_steps
 from can_tpu.serve import programs as serve_programs
 from can_tpu.testing import glm_moe_lite_ref as ref
 
-from lm_tiny import tiny_glm_config
+from lm_tiny import interpret_skipping_experts, tiny_glm_config
 
 NEW = 6
 
@@ -189,3 +189,107 @@ def test_cli_serves_the_model_from_its_configuration_file(tmp_path, capsys):
         assert r.tokens.shape == (NEW,)
     finally:
         svc.close()
+
+
+# -- the decode step's expert layers: which form, and what it read ----------
+def test_the_batched_form_reads_every_held_expert(service):
+    """On the CPU the decode step's expert layers take the batched form:
+    the ``lm.decode`` span says so, and read = held = expert layers x held
+    experts x steps on the launch's ``serve.fetch`` span and in the
+    counters (the warm-up's launches are not traffic)."""
+    from can_tpu.obs.exporter import render_stats
+
+    svc, _, tracer = service
+    assert svc.engine.programs.decode_experts(4) == "batched"
+    before = svc.stats()["lm"]
+    ticket = svc.submit(_prompt(9, 21))
+    ring, inner = _launch_spans(tracer, ticket)
+    assert inner["lm.decode"]["experts"] == "batched"
+    batch = ticket._request.batch_span.span_id
+    fetch = next(s for s in ring if s["name"] == "serve.fetch"
+                 and s.get("parent_id") == batch)
+    assert fetch["experts_read"] == fetch["experts_held"] == 2 * 16 * NEW
+    lm = svc.stats()["lm"]
+    assert (lm["decode_experts_held"] - before["decode_experts_held"]
+            == lm["decode_experts_read"] - before["decode_experts_read"]
+            == 2 * 16 * NEW)
+    text = render_stats(svc.stats(), prefix="can_tpu_serve")
+    assert (f'can_tpu_serve_lm_decode_experts_read_total '
+            f'{lm["decode_experts_read"]}') in text
+    assert (f'can_tpu_serve_lm_decode_experts_held_total '
+            f'{lm["decode_experts_held"]}') in text
+
+
+@pytest.fixture()
+def skipping(monkeypatch):
+    interpret_skipping_experts(monkeypatch)
+
+
+def test_the_skipping_form_counts_the_experts_it_read(skipping):
+    """Widths of whole lanes (hidden 128, experts 128 wide), 4 slots of 16
+    experts top-4: the decode step's expert layers take the kernel; the
+    state carries the counter, the answers are the batched program's, and
+    ``experts_read`` is the number of distinct experts the step's rows chose,
+    layer by layer (every row reads, live or not)."""
+    from can_tpu.serve.engine import LMEngine
+    from can_tpu.serve.kinds import TokenBatch
+
+    config = glm_config(hidden_size=128, moe_intermediate_size=128)
+    cfg = gm.Glm4MoeLiteConfig.from_dict(config)
+    params = gm.init_params(jax.random.key(5), cfg, jnp.float32)
+    programs = serve_programs.LMPrograms(gm, cfg, max_new_tokens=NEW,
+                                         dtype=jnp.float32)
+    assert programs.decode_experts(4) == "skipping"
+    assert programs.decode_experts(64) == "batched"     # nobody idle
+    tracer = spans.SpanTracer()
+    tel = Telemetry()
+    tel.spans = tracer
+    engine = LMEngine(params, programs, prefill_slice=2, telemetry=tel)
+    tokens = np.stack([np.pad(_prompt(n, n), (0, 16 - n)) for n in (9, 16, 5, 1)])
+    batch = TokenBatch(tokens, np.asarray([9, 16, 5, 1], np.int32),
+                       np.asarray([1, 1, 1, 0], np.float32))
+    ids, probes = engine.generate_batch(batch, steps=1, want_logits=True)
+    chosen = probes["step1"]["choices"]              # (expert layers, slots, k)
+    read = sum(len(np.unique(layer)) for layer in chosen)
+    assert engine.counters["decode_experts_read"] == read < 2 * 16
+    assert engine.counters["decode_experts_held"] == 2 * 16
+    ring = tracer.snapshot()
+    assert next(s for s in ring if s["name"] == "lm.decode")["experts"] == "skipping"
+    fetch = next(s for s in ring if s["name"] == "serve.fetch")
+    assert (fetch["experts_read"], fetch["experts_held"]) == (read, 32)
+    # more steps: one program for all of them (the counter is in the state
+    # from the first), and the plain form's tokens
+    compiled = engine.compile_count
+    ids, _ = engine.generate_batch(batch, steps=NEW)
+    assert engine.compile_count == compiled
+    assert engine.counters["decode_experts_held"] == 32 * (1 + NEW)
+
+
+def test_the_skipping_program_answers_as_the_batched_one(skipping, monkeypatch):
+    from can_tpu.ops import moe as moe_ops
+    from can_tpu.serve.engine import LMEngine
+    from can_tpu.serve.kinds import TokenBatch
+
+    config = glm_config(hidden_size=128, moe_intermediate_size=128)
+    cfg = gm.Glm4MoeLiteConfig.from_dict(config)
+    params = gm.init_params(jax.random.key(6), cfg, jnp.float32)
+    tokens = np.stack([np.pad(_prompt(n, n), (0, 16 - n)) for n in (12, 16, 7, 3)])
+    batch = TokenBatch(tokens, np.asarray([12, 16, 7, 3], np.int32),
+                       np.ones((4,), np.float32))
+
+    def launch():
+        programs = serve_programs.LMPrograms(gm, cfg, max_new_tokens=NEW,
+                                             dtype=jnp.float32)
+        engine = LMEngine(params, programs, prefill_slice=2)
+        return engine, engine.generate_batch(batch, want_logits=True)
+
+    kernel, (ids, probes) = launch()
+    assert kernel.counters["decode_experts_read"] < kernel.counters["decode_experts_held"]
+    monkeypatch.setattr(moe_ops, "SKIP_MIN_IDLE", 2.0)   # nothing is that idle
+    plain, (want_ids, want) = launch()
+    assert plain.counters["decode_experts_read"] == plain.counters["decode_experts_held"]
+    assert (ids == want_ids).all()
+    for name in want:
+        np.testing.assert_allclose(probes[name]["logits"], want[name]["logits"],
+                                   atol=2e-5, rtol=2e-5)
+        assert (probes[name]["choices"] == want[name]["choices"]).all()

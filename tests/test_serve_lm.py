@@ -363,6 +363,23 @@ class TestGenerateService:
         assert all("attention" not in s for s in tracer.snapshot()
                    if s["name"] == "lm.prefill")
 
+    def test_the_batched_decode_reads_every_held_expert(self, service):
+        """K-EXAONE's decode on the CPU: the batched form, so ``experts`` on
+        the ``lm.decode`` span says ``batched``, the state carries no
+        counter, and read = held = 4 expert layers x 8 held x steps."""
+        svc, _, tracer = service
+        assert svc.engine.programs.decode_experts(4) == "batched"
+        before = dict(svc.stats()["lm"])
+        svc.submit(_prompt(7, 4), max_new_tokens=3).result(120)
+        lm = svc.stats()["lm"]
+        assert (lm["decode_experts_read"] - before["decode_experts_read"]
+                == lm["decode_experts_held"] - before["decode_experts_held"]
+                == 4 * 8 * 3)
+        ring = tracer.snapshot()
+        assert {s["experts"] for s in ring if s["name"] == "lm.decode"} == {"batched"}
+        fetch = [s for s in ring if s["name"] == "serve.fetch"][-1]
+        assert fetch["experts_read"] == fetch["experts_held"] == 4 * 8 * 3
+
     def test_spans_under_serve_batch(self, service):
         svc, _, tracer = service
         import time
