@@ -35,8 +35,8 @@ import jax.numpy as jnp
 
 from can_tpu.models.lm_blocks import experts_form  # noqa: F401  (the serving path asks the model for it)
 from can_tpu.models.lm_blocks import (VocabSlice, count_shapes, embed,
-                                      init_from_shapes, lm_head, rms_norm,
-                                      routing_report)
+                                      init_from_shapes, last_hidden, lm_head,
+                                      rms_norm, routing_report)
 from can_tpu.models.lm_blocks import ffn as _ffn
 from can_tpu.ops import attention as attn_ops
 from can_tpu.ops import cache_layout as layout
@@ -209,17 +209,20 @@ def ffn(layer, h, cfg: ExaoneMoeConfig):
 def _prefill_block(layer, layer_type, x, positions, cfg,
                    cache_len: Optional[int], lengths):
     """One block over whole prompts; -> (y, cache entry or None, chosen)."""
-    with jax.named_scope("attn"):
+    with jax.named_scope("attn.proj"):
         xn = rms_norm(x, layer["ln_in"], cfg.rms_norm_eps) if cfg.pre_norm else x
         q, k, v = _qkv(layer["attn"], xn, positions, layer_type, cfg)
+    with jax.named_scope("attn.core"):
         if layer_type == WINDOW:
             o = attn_ops.prefill_window(q, k, v, window=cfg.sliding_window)
         else:
             o = attn_ops.prefill_full(q, k, v)
+    with jax.named_scope("attn.out"):
         b, l = x.shape[:2]
         h = x + jnp.dot(o.reshape(b, l, -1), layer["attn"]["wo"])
-        entry = None
-        if cache_len is not None:
+    entry = None
+    if cache_len is not None:
+        with jax.named_scope("attn.cache"):
             kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
             if layer_type == WINDOW:
                 # slot r <- the newest position p < length with p % W == r
@@ -265,8 +268,7 @@ def prefill(params, tokens, lengths, cfg: ExaoneMoeConfig, cache_len: int,
     layers and ``cfg.sliding_window`` in window layers."""
     h, cache, routing = prefill_hidden(params, tokens, lengths, cfg, cache_len,
                                        active)
-    last = jnp.take_along_axis(h, (lengths - 1)[:, None, None], axis=1)[:, 0]
-    return lm_head(params, last, cfg), cache, routing
+    return lm_head(params, last_hidden(h, lengths), cfg), cache, routing
 
 
 # -- decode -------------------------------------------------------------
@@ -283,10 +285,11 @@ def decode_step(params, cache, tokens, positions, cfg: ExaoneMoeConfig,
     entries, chosen = [], []
     for layer, lt, entry in zip(params["layers"], cfg.layer_types,
                                 cache["layers"]):
-        with jax.named_scope("attn"):
+        with jax.named_scope("attn.proj"):
             xn = (rms_norm(x, layer["ln_in"], cfg.rms_norm_eps)
                   if cfg.pre_norm else x)
             q, k, v = _qkv(layer["attn"], xn, pos2, lt, cfg)
+        with jax.named_scope("attn.core"):
             if lt == WINDOW:
                 slot = jnp.mod(positions, cfg.sliding_window)
                 valid = attn_ops.ring_positions(positions,
@@ -294,9 +297,12 @@ def decode_step(params, cache, tokens, positions, cfg: ExaoneMoeConfig,
             else:
                 slot = positions
                 valid = jnp.arange(entry["k"].shape[2])[None, :] <= pos2
+        with jax.named_scope("attn.cache"):
             kc = attn_ops.write_slot(entry["k"], k[:, 0], slot)
             vc = attn_ops.write_slot(entry["v"], v[:, 0], slot)
+        with jax.named_scope("attn.core"):
             o = attn_ops.decode(q[:, 0], kc, vc, valid)
+        with jax.named_scope("attn.out"):
             h = x + jnp.dot(o.reshape(b, 1, -1), layer["attn"]["wo"])
         entries.append({"k": kc, "v": vc})
         x, c = ffn(layer, h, cfg)

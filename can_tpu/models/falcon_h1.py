@@ -59,8 +59,8 @@ import jax
 import jax.numpy as jnp
 
 from can_tpu.models.lm_blocks import (VocabSlice, count_shapes, embed,
-                                      init_from_shapes, lm_head, no_routing,
-                                      rms_norm, swiglu)
+                                      init_from_shapes, last_hidden, lm_head,
+                                      no_routing, rms_norm, swiglu)
 from can_tpu.ops import attention as attn_ops
 from can_tpu.ops import cache_layout as layout
 from can_tpu.ops import ssm as ssm_ops
@@ -363,30 +363,36 @@ def mixer_chunked(p, u, lengths, cfg: FalconH1Config):
     (B, heads, head_dim, d_state) float32 and the convolution's tail
     (B, conv_dim, d_conv - 1), both at each prompt's own length)."""
     b, l, _ = u.shape
-    z, xbc, dt = _mixer_inputs(p, u, cfg)
-    tail = ssm_ops.conv_tail(xbc, lengths, cfg.mamba_d_conv)
-    xbc = ssm_ops.conv1d_causal(xbc, p["conv_w"], p["conv_b"])
-    x, bm, cm, dt, a = _recurrence_inputs(p, xbc, dt, cfg)
+    with jax.named_scope("ssm.proj"):
+        z, xbc, dt = _mixer_inputs(p, u, cfg)
+        tail = ssm_ops.conv_tail(xbc, lengths, cfg.mamba_d_conv)
+        xbc = ssm_ops.conv1d_causal(xbc, p["conv_w"], p["conv_b"])
+        x, bm, cm, dt, a = _recurrence_inputs(p, xbc, dt, cfg)
     _SSM_TRACED[(b, l)] = "chunked"
-    y, state = ssm_ops.ssd_chunked(x, dt, a, bm, cm, p["D"], lengths,
-                                   chunk=cfg.mamba_chunk_size)
-    y = gated_norm(y.reshape(b, l, -1), z, p["gate_norm"], cfg)
-    return jnp.dot(y, p["out_proj"]), state, tail
+    with jax.named_scope("ssm.scan"):
+        y, state = ssm_ops.ssd_chunked(x, dt, a, bm, cm, p["D"], lengths,
+                                       chunk=cfg.mamba_chunk_size)
+    with jax.named_scope("ssm.out"):
+        y = gated_norm(y.reshape(b, l, -1), z, p["gate_norm"], cfg)
+        return jnp.dot(y, p["out_proj"]), state, tail
 
 
 def mixer_step(p, u, entry, active, cfg: FalconH1Config):
     """One token a sequence: ``u`` (B, d), the layer's ``ssm`` and ``conv``
     leaves -> (the mixer's output (B, d), the two leaves moved on by one)."""
     b = u.shape[0]
-    z, xbc, dt = _mixer_inputs(p, u, cfg)
-    xbc, tail = ssm_ops.conv1d_step(entry["conv"], xbc, p["conv_w"],
-                                    p["conv_b"])
-    x, bm, cm, dt, a = _recurrence_inputs(p, xbc, dt, cfg)
+    with jax.named_scope("ssm.proj"):
+        z, xbc, dt = _mixer_inputs(p, u, cfg)
+        xbc, tail = ssm_ops.conv1d_step(entry["conv"], xbc, p["conv_w"],
+                                        p["conv_b"])
+        x, bm, cm, dt, a = _recurrence_inputs(p, xbc, dt, cfg)
     _SSM_TRACED[(b, 1)] = "step"
-    y, state = ssm_ops.ssd_step(entry["ssm"], x, dt, a, bm, cm, p["D"],
-                                active)
-    y = gated_norm(y.reshape(b, -1), z, p["gate_norm"], cfg)
-    return jnp.dot(y, p["out_proj"]), state, tail
+    with jax.named_scope("ssm.scan"):
+        y, state = ssm_ops.ssd_step(entry["ssm"], x, dt, a, bm, cm, p["D"],
+                                    active)
+    with jax.named_scope("ssm.out"):
+        y = gated_norm(y.reshape(b, -1), z, p["gate_norm"], cfg)
+        return jnp.dot(y, p["out_proj"]), state, tail
 
 
 def _mixed(x, m, o, cfg: FalconH1Config):
@@ -408,22 +414,27 @@ def ffn(layer, h, cfg: FalconH1Config):
 def _prefill_block(layer, x, positions, lengths, cfg, cache_len):
     """One block over whole prompts; -> (y, cache entry or None)."""
     b, l = x.shape[:2]
-    u = rms_norm(x, layer["ln_in"], cfg.rms_norm_eps)
-    with jax.named_scope("attn"):
+    with jax.named_scope("attn.proj"):
+        # the block's one input norm: the mixer reads it too
+        u = rms_norm(x, layer["ln_in"], cfg.rms_norm_eps)
         q, k, v = _qkv(layer["attn"], _scaled(u, cfg.attention_in_multiplier),
                        positions, cfg)
+    with jax.named_scope("attn.core"):
         o = attn_ops.prefill_full(q, k, v)
+    with jax.named_scope("attn.out"):
         o = jnp.dot(o.reshape(b, l, -1), layer["attn"]["wo"])
-    with jax.named_scope("ssm"):
-        m, state, tail = mixer_chunked(
-            layer["mixer"], _scaled(u, cfg.ssm_in_multiplier), lengths, cfg)
-    h = _mixed(x, m, o, cfg)
+    with jax.named_scope("ssm.proj"):
+        u_ssm = _scaled(u, cfg.ssm_in_multiplier)
+    m, state, tail = mixer_chunked(layer["mixer"], u_ssm, lengths, cfg)
+    with jax.named_scope("attn.out"):
+        h = _mixed(x, m, o, cfg)   # the residual and both branches' sum
     entry = None
     if cache_len is not None:
-        pad = ((0, 0), (0, 0), (0, cache_len - l), (0, 0))
-        entry = {"k": jnp.pad(k.transpose(0, 2, 1, 3), pad),
-                 "v": jnp.pad(v.transpose(0, 2, 1, 3), pad),
-                 "ssm": state, "conv": tail}
+        with jax.named_scope("attn.cache"):
+            pad = ((0, 0), (0, 0), (0, cache_len - l), (0, 0))
+            entry = {"k": jnp.pad(k.transpose(0, 2, 1, 3), pad),
+                     "v": jnp.pad(v.transpose(0, 2, 1, 3), pad),
+                     "ssm": state, "conv": tail}
     return ffn(layer, h, cfg), entry
 
 
@@ -435,7 +446,8 @@ def prefill_hidden(params, tokens, lengths, cfg: FalconH1Config,
     attention is causal, and the recurrence does not advance over them."""
     b, l = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(l)[None], (b, l))
-    x = _scaled(embed(params, tokens), cfg.embedding_multiplier)
+    with jax.named_scope("embed"):
+        x = _scaled(embed(params, tokens), cfg.embedding_multiplier)
     entries = []
     for layer in params["layers"]:
         x, entry = _prefill_block(layer, x, positions, lengths, cfg, cache_len)
@@ -451,8 +463,8 @@ def prefill(params, tokens, lengths, cfg: FalconH1Config, cache_len: int,
     is the serving programs' (which slots hold a request): a dense model
     counts nothing by it."""
     h, cache = prefill_hidden(params, tokens, lengths, cfg, cache_len)
-    last = jnp.take_along_axis(h, (lengths - 1)[:, None, None], axis=1)[:, 0]
-    return (lm_head(params, last, cfg, cfg.lm_head_multiplier), cache,
+    return (lm_head(params, last_hidden(h, lengths), cfg,
+                    cfg.lm_head_multiplier), cache,
             no_routing(tokens.shape[0]))
 
 
@@ -466,23 +478,29 @@ def decode_step(params, cache, tokens, positions, cfg: FalconH1Config,
     ``active`` (B,) marks False, which keep their state."""
     b = tokens.shape[0]
     pos2 = positions[:, None]
-    x = _scaled(embed(params, tokens), cfg.embedding_multiplier)[:, None]
+    with jax.named_scope("embed"):
+        x = _scaled(embed(params, tokens), cfg.embedding_multiplier)[:, None]
     entries = []
     for layer, entry in zip(params["layers"], cache["layers"]):
-        u = rms_norm(x, layer["ln_in"], cfg.rms_norm_eps)
-        with jax.named_scope("attn"):
+        with jax.named_scope("attn.proj"):
+            # the block's one input norm: the mixer reads it too
+            u = rms_norm(x, layer["ln_in"], cfg.rms_norm_eps)
             q, k, v = _qkv(layer["attn"],
                            _scaled(u, cfg.attention_in_multiplier), pos2, cfg)
+        with jax.named_scope("attn.core"):
             valid = jnp.arange(entry["k"].shape[2])[None, :] <= pos2
+        with jax.named_scope("attn.cache"):
             kc = attn_ops.write_slot(entry["k"], k[:, 0], positions)
             vc = attn_ops.write_slot(entry["v"], v[:, 0], positions)
+        with jax.named_scope("attn.core"):
             o = attn_ops.decode(q[:, 0], kc, vc, valid)
+        with jax.named_scope("attn.out"):
             o = jnp.dot(o.reshape(b, 1, -1), layer["attn"]["wo"])
-        with jax.named_scope("ssm"):
-            m, state, tail = mixer_step(
-                layer["mixer"], _scaled(u[:, 0], cfg.ssm_in_multiplier), entry,
-                active, cfg)
-        h = _mixed(x, m[:, None], o, cfg)
+        with jax.named_scope("ssm.proj"):
+            u_ssm = _scaled(u[:, 0], cfg.ssm_in_multiplier)
+        m, state, tail = mixer_step(layer["mixer"], u_ssm, entry, active, cfg)
+        with jax.named_scope("attn.out"):
+            h = _mixed(x, m[:, None], o, cfg)   # the residual, both branches
         entries.append({"k": kc, "v": vc, "ssm": state, "conv": tail})
         x = ffn(layer, h, cfg)
     return (lm_head(params, x[:, 0], cfg, cfg.lm_head_multiplier),

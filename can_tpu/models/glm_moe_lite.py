@@ -48,8 +48,8 @@ import jax.numpy as jnp
 
 from can_tpu.models.lm_blocks import experts_form  # noqa: F401  (the serving path asks the model for it)
 from can_tpu.models.lm_blocks import (VocabSlice, count_shapes, embed, ffn,
-                                      init_from_shapes, lm_head, rms_norm,
-                                      routing_report)
+                                      init_from_shapes, last_hidden, lm_head,
+                                      rms_norm, routing_report)
 from can_tpu.ops import attention as attn_ops
 from can_tpu.ops import cache_layout as layout
 from can_tpu.ops import pallas_attention as fused_attn
@@ -277,24 +277,28 @@ def attention_expanded(p, xn, positions, lengths, cfg: Glm4MoeLiteConfig):
     scanned ``prefill_causal`` everywhere else.  Nothing else chooses."""
     b, l, _ = xn.shape
     h = cfg.num_attention_heads
-    q_nope, q_rope = _queries(p, xn, positions, cfg)
-    ckv, krope = _latent(p, xn, positions, cfg)
-    # keys and values each from their own columns of ``W_kvb``: the
-    # products land where they are used, no slice of a joint result
-    w_uk, w_uv = _up_projections(p, cfg)
-    q = jnp.concatenate([q_nope, q_rope], -1)
-    k = jnp.concatenate(
-        [jnp.einsum("blr,rhn->blhn", ckv, w_uk),
-         jnp.broadcast_to(krope[:, :, None], (b, l, h, krope.shape[-1]))], -1)
-    v = jnp.einsum("blr,rhv->blhv", ckv, w_uv)
+    with jax.named_scope("attn.proj"):
+        q_nope, q_rope = _queries(p, xn, positions, cfg)
+        ckv, krope = _latent(p, xn, positions, cfg)
+        # keys and values each from their own columns of ``W_kvb``: the
+        # products land where they are used, no slice of a joint result
+        w_uk, w_uv = _up_projections(p, cfg)
+        q = jnp.concatenate([q_nope, q_rope], -1)
+        k = jnp.concatenate(
+            [jnp.einsum("blr,rhn->blhn", ckv, w_uk),
+             jnp.broadcast_to(krope[:, :, None],
+                              (b, l, h, krope.shape[-1]))], -1)
+        v = jnp.einsum("blr,rhv->blhv", ckv, w_uv)
     fused = fused_attn.supports(q.shape, v.shape, q.dtype)
     _ATTENTION_TRACED[(b, l)] = "fused" if fused else "scanned"
-    if fused:
-        o = fused_attn.fused_causal(q, k, v, lengths, scale=cfg.scale)
-    else:
-        o = attn_ops.prefill_causal(q, k, v, lengths, scale=cfg.scale,
-                                    block=PREFILL_BLOCK)
-    return jnp.dot(o.reshape(b, l, -1), p["wo"]), ckv, krope
+    with jax.named_scope("attn.core"):
+        if fused:
+            o = fused_attn.fused_causal(q, k, v, lengths, scale=cfg.scale)
+        else:
+            o = attn_ops.prefill_causal(q, k, v, lengths, scale=cfg.scale,
+                                        block=PREFILL_BLOCK)
+    with jax.named_scope("attn.out"):
+        return jnp.dot(o.reshape(b, l, -1), p["wo"]), ckv, krope
 
 
 def attention_absorbed(p, xn, positions, entry, cfg: Glm4MoeLiteConfig):
@@ -302,31 +306,38 @@ def attention_absorbed(p, xn, positions, entry, cfg: Glm4MoeLiteConfig):
     ``positions`` (B,), its latent written into ``entry`` before it attends
     -> (the layer's output (B, 1, d), the entry)."""
     b = xn.shape[0]
-    q_nope, q_rope = _queries(p, xn, positions[:, None], cfg)
-    ckv, krope = _latent(p, xn, positions[:, None], cfg)
-    ckv_c = attn_ops.write_row(entry["ckv"], ckv[:, 0], positions)
-    krope_c = attn_ops.write_row(entry["krope"], krope[:, 0], positions)
-    w_uk, w_uv = _up_projections(p, cfg)
-    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_uk)
-    valid = jnp.arange(ckv_c.shape[1])[None, :] <= positions[:, None]
-    o_lat = attn_ops.decode_latent(q_lat, q_rope[:, 0], ckv_c, krope_c, valid,
-                                   scale=cfg.scale)
-    o = jnp.einsum("bhr,rhv->bhv", o_lat, w_uv)
-    return (jnp.dot(o.reshape(b, 1, -1), p["wo"]),
-            {"ckv": ckv_c, "krope": krope_c})
+    with jax.named_scope("attn.proj"):
+        q_nope, q_rope = _queries(p, xn, positions[:, None], cfg)
+        ckv, krope = _latent(p, xn, positions[:, None], cfg)
+    with jax.named_scope("attn.cache"):
+        ckv_c = attn_ops.write_row(entry["ckv"], ckv[:, 0], positions)
+        krope_c = attn_ops.write_row(entry["krope"], krope[:, 0], positions)
+    with jax.named_scope("attn.proj"):
+        w_uk, w_uv = _up_projections(p, cfg)
+        q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_uk)
+    with jax.named_scope("attn.core"):
+        valid = jnp.arange(ckv_c.shape[1])[None, :] <= positions[:, None]
+        o_lat = attn_ops.decode_latent(q_lat, q_rope[:, 0], ckv_c, krope_c,
+                                       valid, scale=cfg.scale)
+    with jax.named_scope("attn.out"):
+        o = jnp.einsum("bhr,rhv->bhv", o_lat, w_uv)
+        return (jnp.dot(o.reshape(b, 1, -1), p["wo"]),
+                {"ckv": ckv_c, "krope": krope_c})
 
 
 # -- prefill ------------------------------------------------------------
 def _prefill_block(layer, x, positions, cfg, cache_len: Optional[int],
                    lengths):
     """One block over whole prompts; -> (y, cache entry or None, chosen)."""
-    with jax.named_scope("attn"):
+    with jax.named_scope("attn.proj"):
         xn = rms_norm(x, layer["ln_in"], cfg.rms_norm_eps)
-        o, ckv, krope = attention_expanded(layer["attn"], xn, positions,
-                                           lengths, cfg)
+    o, ckv, krope = attention_expanded(layer["attn"], xn, positions, lengths,
+                                       cfg)
+    with jax.named_scope("attn.out"):
         h = x + o
-        entry = None
-        if cache_len is not None:
+    entry = None
+    if cache_len is not None:
+        with jax.named_scope("attn.cache"):
             pad = ((0, 0), (0, cache_len - x.shape[1]), (0, 0))
             entry = {"ckv": jnp.pad(ckv, pad), "krope": jnp.pad(krope, pad)}
     y, chosen = ffn(layer, h, cfg)
@@ -363,8 +374,7 @@ def prefill(params, tokens, lengths, cfg: Glm4MoeLiteConfig, cache_len: int,
     of ``cache_len`` positions, routing)."""
     h, cache, routing = prefill_hidden(params, tokens, lengths, cfg, cache_len,
                                        active)
-    last = jnp.take_along_axis(h, (lengths - 1)[:, None, None], axis=1)[:, 0]
-    return lm_head(params, last, cfg), cache, routing
+    return lm_head(params, last_hidden(h, lengths), cfg), cache, routing
 
 
 # -- decode -------------------------------------------------------------
@@ -377,10 +387,10 @@ def decode_step(params, cache, tokens, positions, cfg: Glm4MoeLiteConfig,
     x = embed(params, tokens)[:, None]                        # (B, 1, d)
     entries, chosen = [], []
     for layer, entry in zip(params["layers"], cache["layers"]):
-        with jax.named_scope("attn"):
+        with jax.named_scope("attn.proj"):
             xn = rms_norm(x, layer["ln_in"], cfg.rms_norm_eps)
-            o, entry = attention_absorbed(layer["attn"], xn, positions, entry,
-                                          cfg)
+        o, entry = attention_absorbed(layer["attn"], xn, positions, entry, cfg)
+        with jax.named_scope("attn.out"):
             h = x + o
         entries.append(entry)
         x, c = ffn(layer, h, cfg)

@@ -25,6 +25,44 @@ from can_tpu.ops import moe as moe_ops
 from can_tpu.ops.moe import ExpertShare
 
 
+# The parts of a language model, ONE vocabulary for the three models: every
+# ``jax.named_scope`` that the serving programs pass through (the models,
+# ``ops/moe.py``, ``serve/programs.py``) is one of these names, whole (the MTP
+# modules' ``mtp``, outside those programs, wraps them).  A scope is metadata: it names no op and adds
+# none (``tests/test_program_scopes.py`` holds the programs' text equal with
+# and without).  The compiled program carries the scopes as each
+# instruction's ``op_name``; ``obs/trace.py::part_of`` gives a device op the
+# INNERMOST name of its path, and ``LMEngine`` records the map when a tracer
+# is active (the span ``program.scopes``).
+PARTS = (
+    "embed",         # the token gather (and Falcon-H1's multiplier)
+    "attn.proj",     # input norm, query / key-value / latent projections, rotary, GLM's q_nope x W_uk
+    "attn.cache",    # the row written into the cache; a prefill slice's rows placed into the launch's cache
+    "attn.core",     # scores, softmax, values: against the cache (decode) or over the prompt (prefill)
+    "attn.out",      # GLM's W_uv, ``wo``, the residual
+    "moe.router",    # post-norm, router product, top-k, the weights by held expert
+    "moe.dispatch",  # the sorted form's sort, gather, scatter and combine (no other form has any)
+    "moe.experts",   # the routed experts' products, in all three forms
+    "moe.shared",    # the shared expert and the layer's residual
+    "dense_mlp",     # a dense layer's norm, SwiGLU and residual
+    "ssm.proj",      # ``in_proj``, the convolution, the recurrence's inputs
+    "ssm.scan",      # ``ssd_chunked`` / ``ssd_step``: the state
+    "ssm.out",       # the gated norm and ``out_proj``
+    "head",          # final norm and the head's product
+    "sample",        # argmax, the ``ids`` update, the decode state moved on
+    "routing",       # ``routing_report``'s counts and choices
+)
+
+# What the compiler renames: XLA:TPU rewrites ``jax.lax.ragged_dot`` into its
+# grouped-matmul kernels and stamps their ``op_name`` anew, dropping the scope
+# they were traced in (read in the compiled text of both sparse models'
+# prefill, PR 35).  ``obs.trace.part_of`` takes these names for the part too.
+RENAMED_BY_COMPILER = {
+    "ragged-dot-none": "moe.experts",      # the grouped product itself
+    "ragged-dot-metadata": "moe.experts",  # its groups' tiling, from the sizes
+}
+
+
 class VocabSlice(ExpertShare):
     """Rows ``first .. first + held - 1`` of the ``total`` vocabulary."""
 
@@ -108,12 +146,13 @@ def experts_form(cfg, tokens: int, dtype) -> str:
 def expert_layer(p, x, cfg):
     """``x`` (T, d) -> (this chip's part of the routed sum + the shared
     expert (T, d), ``Routed``: the experts each token chose (T, k))."""
-    with jax.named_scope("moe"):
+    with jax.named_scope("moe.router"):
         idx, w = moe_ops.route(x, p["router"], p["bias"],
                                top_k=cfg.num_experts_per_tok,
                                scale=cfg.routed_scaling_factor,
                                normalize=cfg.norm_topk_prob)
-        routed, read = moe_ops.share_apply(x, idx, w, p["experts"], cfg.share)
+    routed, read = moe_ops.share_apply(x, idx, w, p["experts"], cfg.share)
+    with jax.named_scope("moe.shared"):
         return routed + swiglu(x, p["shared"]), Routed(idx, read)
 
 
@@ -122,13 +161,15 @@ def ffn(layer, h, cfg, *, norm: bool = True):
     (``norm`` False: ``h + F(h)``): -> (y, ``Routed`` with the experts each
     token chose (B, L, k), or None for the dense layer)."""
     b, l, d = h.shape
-    x = rms_norm(h, layer["ln_post"], cfg.rms_norm_eps) if norm else h
-    if "mlp" in layer:
-        with jax.named_scope("dense_mlp"):
+    dense = "mlp" in layer
+    with jax.named_scope("dense_mlp" if dense else "moe.router"):
+        x = rms_norm(h, layer["ln_post"], cfg.rms_norm_eps) if norm else h
+        if dense:
             return h + swiglu(x, layer["mlp"]), None
     y, routed = expert_layer(layer["moe"], x.reshape(b * l, d), cfg)
-    return h + y.reshape(b, l, d), routed._replace(
-        idx=routed.idx.reshape(b, l, -1))
+    with jax.named_scope("moe.shared"):
+        return h + y.reshape(b, l, d), routed._replace(
+            idx=routed.idx.reshape(b, l, -1))
 
 
 def routing_report(chosen, mask, pick, cfg) -> dict:
@@ -145,15 +186,16 @@ def routing_report(chosen, mask, pick, cfg) -> dict:
         return {"counts": jnp.zeros((0, cfg.share.held), jnp.int32),
                 "choices": jnp.zeros((0, pick.shape[0], cfg.num_experts_per_tok),
                                      jnp.int32)}
-    counts = [moe_ops.held_counts(jnp.where(mask[..., None], c.idx, -1),
-                                  cfg.share) for c in chosen]
-    at = [jnp.take_along_axis(c.idx, pick[:, None, None], axis=1)[:, 0]
-          for c in chosen]
-    report = {"counts": jnp.stack(counts), "choices": jnp.stack(at)}
-    read = [c.read for c in chosen if c.read is not None]
-    if read:
-        report["experts_read"] = sum(read)
-    return report
+    with jax.named_scope("routing"):
+        counts = [moe_ops.held_counts(jnp.where(mask[..., None], c.idx, -1),
+                                      cfg.share) for c in chosen]
+        at = [jnp.take_along_axis(c.idx, pick[:, None, None], axis=1)[:, 0]
+              for c in chosen]
+        report = {"counts": jnp.stack(counts), "choices": jnp.stack(at)}
+        read = [c.read for c in chosen if c.read is not None]
+        if read:
+            report["experts_read"] = sum(read)
+        return report
 
 
 def no_routing(batch: int) -> dict:
@@ -163,7 +205,16 @@ def no_routing(batch: int) -> dict:
 
 
 def embed(params, tokens):
-    return params["embed"][tokens]
+    with jax.named_scope("embed"):
+        return params["embed"][tokens]
+
+
+def last_hidden(h, lengths):
+    """``h`` (B, L, d) -> (B, d) at each sequence's last position: what a
+    prefill hands the head."""
+    with jax.named_scope("head"):
+        return jnp.take_along_axis(h, (lengths - 1)[:, None, None],
+                                   axis=1)[:, 0]
 
 
 def lm_head(params, h, cfg, multiplier=None):

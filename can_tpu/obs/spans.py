@@ -15,12 +15,11 @@ inherit the bus's sinks, per-host files and crash semantics, and
 ``tools/trace_export.py`` converts them to Chrome/Perfetto JSON offline.
 
 One clock: every start and end is ``time.perf_counter()``, the clock the
-benchmark anchors onto the device trace.  A span opened with
-``tracer.span(name)`` also enters a ``jax.profiler.TraceAnnotation`` of
-the same name, so in an operator's profile it lies on the profiler's own
-timeline beside ``XLA Ops``.  Parents may be recorded after their
-children (a root span's duration isn't known until it ends); consumers
-must not assume order.
+benchmark anchors onto the device trace (an operator's profile holds the
+device alone, ``obs/trace.py::operator_profile_options``;
+``tools/trace_export.py --profile`` places the spans beside it by one
+anchor).  Parents may be recorded after their children (a root span's
+duration isn't known until it ends); consumers must not assume order.
 
 Arming: nothing installs a tracer by default.  A site asks
 ``active(telemetry)`` — ``telemetry.spans`` when a CLI consumer armed
@@ -81,8 +80,8 @@ class Span:
     """An open span: a context manager that stamps both ends.  ``attrs``
     may be filled while it is open (a count known only at the end)."""
 
-    __slots__ = ("_tracer", "_annotation", "_outer", "name", "trace_id",
-                 "span_id", "parent_id", "start", "thread", "attrs")
+    __slots__ = ("_tracer", "_outer", "name", "trace_id", "span_id",
+                 "parent_id", "start", "thread", "attrs")
 
     def __init__(self, tracer, name, trace_id, parent_id, attrs):
         self._tracer = tracer
@@ -109,14 +108,11 @@ class Span:
         self._outer = getattr(tr._local, "span", None)
         self._adopt(self._outer)
         tr._local.span = self
-        self._annotation = tr._annotate(self.name)
-        self._annotation.__enter__()
         self.start = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         end = time.perf_counter()
-        self._annotation.__exit__(exc_type, exc, tb)
         self._tracer._local.span = self._outer
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
@@ -126,8 +122,7 @@ class Span:
     def begin(self) -> "Span":
         """Stamp the start of a span that may END ON ANOTHER THREAD
         (``finish()`` there): a launch that the batcher thread assembles
-        and a lane runs.  It does not become this thread's open span, and
-        enters no profiler annotation (those cannot change threads);
+        and a lane runs.  It does not become this thread's open span;
         whoever works on it makes it the open one with ``span.under()``.
         It is recorded on the lane of the thread that began it."""
         self._adopt(self._tracer.current())
@@ -176,9 +171,6 @@ class SpanTracer:
         self._ids = itertools.count(1)
         self._ring: collections.deque = collections.deque(maxlen=capacity)
         self._local = threading.local()
-        from jax.profiler import TraceAnnotation
-
-        self._annotate = TraceAnnotation
 
     def new_trace_id(self, hint: str = "") -> str:
         tag = f"{hint}-" if hint else ""

@@ -1,4 +1,7 @@
-"""Step-range profiler trigger: trace a WINDOW instead of the whole run.
+"""The profiler's module: the step-range trigger, and the map from a
+compiled program's instructions to the parts of the model.
+
+**The trigger** traces a WINDOW instead of the whole run.
 
 ``profile_trace`` (utils/profiling.py) wraps the entire run — fine for a
 smoke run, useless for "steady-state steps 10..12 of a 10-hour job" where
@@ -7,12 +10,148 @@ a whole-run trace is gigabytes of mostly-identical timelines.  Here the
 ``--trace-steps A:B`` (python slice semantics: first traced step A,
 first untraced step B) starts the trace when step A begins and stops it
 when step B begins, so the artifact holds exactly ``B - A`` steps.
+
+**The map.**  The device trace names an op event by its HLO text
+(``%fusion.36 = bf16[16,64,2048]{...} fusion(...)``) and carries no
+``jax.named_scope``; the compiled program's own text does, on every
+instruction: ``metadata={op_name="jit(decode)/attn.core/dot_general"}``.
+The name before the ``=`` is the one the event carries, so ``scope_map``
+of ``compiled.as_text()`` joins the two, and ``part_of`` reads the part of
+the model (``models/lm_blocks.py::PARTS``) out of an ``op_name``.  A fusion
+carries its ROOT's ``op_name``: an op fused across a scope's edge is
+counted with the part its root belongs to.  ``program_scopes`` is what an
+engine records of one program (``LMEngine``, when a tracer is active: the
+span ``program.scopes``); ``tools/trace_export.py`` and the benchmark's
+``harness/program_scopes.py`` read it.
 """
 
 from __future__ import annotations
 
+import re
 import time
-from typing import Optional, Tuple
+from collections.abc import Mapping
+from typing import Dict, Optional, Tuple
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
+_OPCODE = re.compile(r"\s([a-z][\w\-]*)\(")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+# computations that run INSIDE the instruction that names them
+_INLINED = {"fusion": re.compile(r"\bcalls=%?([\w.\-]+)"),
+            "custom-call": re.compile(r"called_computations=\{([^}]*)\}")}
+_TO_APPLY = re.compile(r"\bto_apply=%?([\w.\-]+)")
+# instructions that never run as a device op of their own
+_NO_OP = frozenset({"parameter", "constant", "tuple", "get-tuple-element",
+                    "bitcast"})
+
+
+def _instructions(hlo_text: str):
+    """-> ([(computation, name, opcode, op_name, operand names)] in the
+    text's order, the computations that run inside an instruction)."""
+    inlined, rows, current = set(), [], None
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            if line.rstrip().endswith("{") and "->" in line:
+                current = line.split("(", 1)[0].split()[-1].lstrip("%")
+            continue
+        opcode = _OPCODE.search(line, m.end())
+        operands = ()
+        if opcode:   # operands hold no parenthesis: up to the first ")"
+            operands = _OPERAND.findall(
+                line, opcode.end(), max(line.find(")", opcode.end()), 0))
+        opcode = opcode.group(1) if opcode else ""
+        called = _INLINED.get(opcode, _TO_APPLY if opcode != "call" else None)
+        if called is not None:
+            for c in called.finditer(line):
+                inlined.update(n.strip().lstrip("%")
+                               for n in c.group(1).split(","))
+        name = _OP_NAME.search(line)
+        rows.append((current, m.group(1), opcode,
+                     name.group(1) if name else "", operands))
+    return rows, inlined
+
+
+def instruction_of(event_name: str) -> str:
+    """A device op event's name, which is its HLO text (``%fusion.4 =
+    bf16[16,64]{...} fusion(...)``), -> the instruction's (``fusion.4``)."""
+    m = _INSTRUCTION.match(event_name)
+    return m.group(1) if m else event_name
+
+
+def scope_map(hlo_text: str) -> Dict[str, str]:
+    """{instruction name: ``op_name``} of a compiled module's text
+    (``compiled.as_text()``), over every computation that RUNS: the entry,
+    the bodies and conditions of ``while`` loops (a scan's ops are events of
+    their own), the branches of conditionals, called computations.  Left
+    out: what runs inside another instruction (a fusion's body, a reduce's
+    or a sort's ``to_apply``) and what runs not at all (parameters,
+    constants, tuples, bitcasts).  An instruction without metadata (a copy
+    the compiler put in) maps to ``""``."""
+    rows, inlined = _instructions(hlo_text)
+    return {inst: op_name for comp, inst, opcode, op_name, _ in rows
+            if comp not in inlined and opcode not in _NO_OP}
+
+
+def part_of(op_name: str, parts) -> Optional[str]:
+    """The INNERMOST name of ``parts`` on the path ``op_name``
+    (``jit(decode)/attn.core/jit(_where)/select_n`` -> ``attn.core``), or
+    None where the path names none.  ``parts``: the names, or a mapping
+    {name on a path: the part it stands for} (``LMPrograms.parts``: the
+    compiler's own names for what it rewrote stand for a part too)."""
+    for scope in reversed(op_name.split("/")):
+        if scope in parts:
+            return parts[scope] if isinstance(parts, Mapping) else scope
+    return None
+
+
+def program_scopes(hlo_text: str, parts) -> dict:
+    """What a ``program.scopes`` span says of one compiled program:
+    ``parts`` {instruction name: its part, None where none is found} over
+    ``scope_map``'s instructions, ``instructions`` how many those are,
+    ``unscoped`` how many of them have no part, and ``inherited``: the
+    instructions whose own ``op_name`` names no part (the compiler's copies
+    and prefetches of an operand carry no metadata, or the argument's name)
+    and that took the part of the FIRST op that uses their result, through
+    tuples and bitcasts (the wait for an operand is charged to the op that
+    needs it), or, where nothing uses it (the program's output), of the op
+    that made their operand."""
+    rows, inlined = _instructions(hlo_text)
+    # tuples, bitcasts and the like have no part of their own: they hand on
+    # their neighbours'
+    own = {inst: None if opcode in _NO_OP else part_of(op_name, parts)
+           for _, inst, opcode, op_name, _ in rows}
+    users: Dict[str, list] = {}
+    for _, inst, _, _, operands in rows:
+        for operand in operands:
+            users.setdefault(operand, []).append(inst)
+
+    def handed(order, neighbours, never) -> dict:
+        """Each instruction's own part, else the first neighbour's that has
+        one; ``order`` visits the neighbours first, so one pass hands a part
+        along a whole chain of copies.  The two directions are kept apart:
+        nothing passes from one user of a value to another."""
+        got = dict(own)
+        for _, inst, opcode, _, operands in order:
+            if got[inst] is None and opcode not in never:
+                got[inst] = next((got[n] for n in neighbours(inst, operands)
+                                  if got.get(n) is not None), None)
+        return got
+
+    # users come later in the text, producers earlier.  An argument or a
+    # constant takes no part; a tuple hands on to its elements' producers
+    # but not from them (which element a user reads is not looked at)
+    down = handed(reversed(rows), lambda inst, _: users.get(inst, ()),
+                  ("parameter", "constant"))
+    up = handed(rows, lambda _, operands: operands,
+                ("parameter", "constant", "tuple"))
+    found = {inst: own[inst] or down[inst] or up[inst]
+             for comp, inst, opcode, _, _ in rows
+             if comp not in inlined and opcode not in _NO_OP}
+    return {"parts": found, "instructions": len(found),
+            "unscoped": sum(p is None for p in found.values()),
+            "inherited": [i for i, p in found.items()
+                          if p is not None and own[i] is None]}
 
 
 def operator_profile_options():

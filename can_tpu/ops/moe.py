@@ -36,6 +36,12 @@ chosen by ``share_form`` from the (static) shapes and the backend:
   batched.  This form counts the experts it read.
 
 No token is dropped under any imbalance in any form.
+
+Scopes (``models/lm_blocks.py::PARTS``): the grouped products of every form
+are ``moe.experts``; what the sorted form does around them (the counting
+sort, the gather of rows, the scatter back, the weighted combine) is
+``moe.dispatch``, which no other form has; the few-tokens forms' weights by
+held expert are the router's, ``moe.router``.
 """
 
 from __future__ import annotations
@@ -169,57 +175,68 @@ def _share_apply_skipping(x, idx, w, experts, share: ExpertShare, *,
         from can_tpu.ops import pallas_experts
 
         kernel = pallas_experts.skipping_experts
-    local, held = _held(idx, share)
-    slots = jnp.arange(share.held)
-    hot = held[..., None] & (local[..., None] == slots)       # (T, k, held)
-    w_te = jnp.sum(jnp.where(hot, w[..., None], 0.0), axis=1)
-    hit = jnp.any(hot, axis=(0, 1))
-    n_active = jnp.sum(hit.astype(jnp.int32))
-    # the hit experts' ids in ascending order, by their rank among the hit
-    # (a compare over held x held: no sort); the tail repeats the last
-    rank = jnp.cumsum(hit.astype(jnp.int32)) - 1
-    active = jnp.sum(jnp.where(hit[None, :] & (rank[None, :] == slots[:, None]),
-                               slots[None, :], 0), axis=1)
-    active = jnp.where(slots < n_active, active, jnp.max(active))
-    return kernel(x, w_te, active, n_active, experts), n_active
+    with jax.named_scope("moe.router"):
+        local, held = _held(idx, share)
+        slots = jnp.arange(share.held)
+        hot = held[..., None] & (local[..., None] == slots)   # (T, k, held)
+        w_te = jnp.sum(jnp.where(hot, w[..., None], 0.0), axis=1)
+        hit = jnp.any(hot, axis=(0, 1))
+        n_active = jnp.sum(hit.astype(jnp.int32))
+        # the hit experts' ids in ascending order, by their rank among the
+        # hit (a compare over held x held: no sort); the tail repeats the last
+        rank = jnp.cumsum(hit.astype(jnp.int32)) - 1
+        active = jnp.sum(jnp.where(hit[None, :]
+                                   & (rank[None, :] == slots[:, None]),
+                                   slots[None, :], 0), axis=1)
+        active = jnp.where(slots < n_active, active, jnp.max(active))
+    with jax.named_scope("moe.experts"):
+        return kernel(x, w_te, active, n_active, experts), n_active
 
 
 def _share_apply_batched(x, idx, w, experts, share: ExpertShare):
     """Every held expert on every token; the routing weights pick."""
-    local, held = _held(idx, share)
-    # (T, held): the weight with which token t chose held expert e, else 0
-    w_te = jnp.sum(jnp.where(held[..., None]
-                             & (local[..., None] == jnp.arange(share.held)),
-                             w[..., None], 0.0), axis=1)
-    g = jnp.einsum("td,edf->etf", x, experts["gate"])
-    u = jnp.einsum("td,edf->etf", x, experts["up"])
-    out = jnp.einsum("etf,efd->etd", jax.nn.silu(g) * u, experts["down"])
-    return jnp.sum(out.astype(jnp.float32) * w_te.T[..., None],
-                   axis=0).astype(x.dtype)
+    with jax.named_scope("moe.router"):
+        local, held = _held(idx, share)
+        # (T, held): the weight with which token t chose held expert e, else 0
+        w_te = jnp.sum(jnp.where(held[..., None]
+                                 & (local[..., None] == jnp.arange(share.held)),
+                                 w[..., None], 0.0), axis=1)
+    with jax.named_scope("moe.experts"):
+        g = jnp.einsum("td,edf->etf", x, experts["gate"])
+        u = jnp.einsum("td,edf->etf", x, experts["up"])
+        out = jnp.einsum("etf,efd->etd", jax.nn.silu(g) * u, experts["down"])
+        return jnp.sum(out.astype(jnp.float32) * w_te.T[..., None],
+                       axis=0).astype(x.dtype)
 
 
 def _share_apply_sorted(x, idx, w, experts, share: ExpertShare):
     """The tokens in expert order, one grouped product per projection."""
     t, k = idx.shape
     n_groups = share.held + 1                       # the last: held elsewhere
-    local, held = _held(idx, share)
-    group = jnp.where(held, local, share.held).reshape(-1)          # (T k,)
-    hot = (group[:, None] == jnp.arange(n_groups)).astype(jnp.int32)
-    sizes = hot.sum(0)
-    starts = jnp.cumsum(sizes) - sizes
-    rank = jnp.take_along_axis(jnp.cumsum(hot, axis=0), group[:, None],
-                               axis=1)[:, 0] - 1
-    dest = starts[group] + rank                     # row in expert order
-    rows = t * min(k, share.held)                   # every held assignment fits
-    token = jnp.arange(t * k, dtype=jnp.int32) // k
-    row_token = jnp.zeros((rows,), jnp.int32).at[dest].set(token, mode="drop")
-    in_use = jnp.arange(rows) < (t * k - sizes[-1])
-    xs = x[row_token]
-    held_sizes = sizes[:-1]
-    g = jax.lax.ragged_dot(xs, experts["gate"], held_sizes)
-    u = jax.lax.ragged_dot(xs, experts["up"], held_sizes)
-    out = jax.lax.ragged_dot(jax.nn.silu(g) * u, experts["down"], held_sizes)
-    out = jnp.where(in_use[:, None], out, 0)        # rows past the last group
-    back = out[jnp.minimum(dest, rows - 1).reshape(t, k)]           # (T, k, d)
-    wk = jnp.where(held, w, 0.0)
-    return jnp.sum(back.astype(jnp.float32) * wk[..., None], axis=1).astype(x.dtype)
+    with jax.named_scope("moe.dispatch"):
+        local, held = _held(idx, share)
+        group = jnp.where(held, local, share.held).reshape(-1)      # (T k,)
+        hot = (group[:, None] == jnp.arange(n_groups)).astype(jnp.int32)
+        sizes = hot.sum(0)
+        starts = jnp.cumsum(sizes) - sizes
+        rank = jnp.take_along_axis(jnp.cumsum(hot, axis=0), group[:, None],
+                                   axis=1)[:, 0] - 1
+        dest = starts[group] + rank                 # row in expert order
+        rows = t * min(k, share.held)               # every held assignment fits
+        token = jnp.arange(t * k, dtype=jnp.int32) // k
+        row_token = jnp.zeros((rows,), jnp.int32).at[dest].set(token,
+                                                               mode="drop")
+        in_use = jnp.arange(rows) < (t * k - sizes[-1])
+        xs = x[row_token]
+        held_sizes = sizes[:-1]
+    with jax.named_scope("moe.experts"):
+        g = jax.lax.ragged_dot(xs, experts["gate"], held_sizes)
+        u = jax.lax.ragged_dot(xs, experts["up"], held_sizes)
+        out = jax.lax.ragged_dot(jax.nn.silu(g) * u, experts["down"],
+                                 held_sizes)
+    with jax.named_scope("moe.dispatch"):
+        out = jnp.where(in_use[:, None], out, 0)    # rows past the last group
+        back = out[jnp.minimum(dest, rows - 1).reshape(t, k)]       # (T, k, d)
+        wk = jnp.where(held, w, 0.0)
+        return jnp.sum(back.astype(jnp.float32) * wk[..., None],
+                       axis=1).astype(x.dtype)

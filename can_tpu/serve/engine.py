@@ -500,6 +500,11 @@ class LMEngine:
                     compiled |= self._prefill.last_first_call
                     self._note_forms(self._prefill.last_first_call,
                                      (n, bucket))
+                    if tr is not None and self._prefill.last_first_call:
+                        self._note_scopes(tr, launch, self._prefill,
+                                          "jit_prefill_slice", (n, bucket),
+                                          (self.params, part, cache,
+                                           np.int32(lo)))
                     outs.append(out)
                 state, pre = self._new_state(outs, batch.lengths, live)
                 sp.attrs["compiled"] = compiled
@@ -520,6 +525,10 @@ class LMEngine:
                                                          cache)
                     compiled |= self._decode.last_first_call
                     self._note_forms(self._decode.last_first_call, (slots, 1))
+                    if tr is not None and self._decode.last_first_call:
+                        self._note_scopes(tr, launch, self._decode,
+                                          "jit_decode", (slots, bucket),
+                                          (self.params, state, cache))
                     if step in keep:
                         probes[f"step{step}"] = out
                 sp.attrs["compiled"] = compiled
@@ -566,6 +575,26 @@ class LMEngine:
             form = noted(program) if noted is not None else None
             if form is not None:
                 forms[program] = form
+
+    def _note_scopes(self, tr, launch, program, name: str, key: tuple,
+                     args) -> None:
+        """After a program's first launch WITH A TRACER ACTIVE: one
+        ``program.scopes`` span under the launch's ``serve.dispatch`` that
+        says which part of the model (``LMPrograms.parts``) each instruction
+        of the compiled program belongs to (``obs.trace.program_scopes``),
+        so that a device trace's op events can be summed by part.  The
+        compiled text is read from a second ``lower().compile()`` of the
+        same signature (``ServeEngine.compile_program``'s precedent: a hit
+        in the persistent cache); ``args`` are the launch's own or, where it
+        donated them, what it returned in their place (the same shapes).
+        ``name``: the program as the trace's ``XLA Modules`` line names it."""
+        from can_tpu.obs.costs import resolve_jit
+        from can_tpu.obs.trace import program_scopes
+
+        with tr.span("program.scopes", parent_id=launch.span_id,
+                     program=name, key=list(key)) as sp:
+            text = resolve_jit(program, args).lower(*args).compile().as_text()
+            sp.attrs.update(program_scopes(text, self.programs.parts))
 
     def _with_cache_signature(self, args) -> tuple:
         """(params, dict of arrays, cache, ...) -> the signature of the dict

@@ -119,6 +119,12 @@ class LMPrograms:
         self.ssm_traced = getattr(model, "ssm_traced", None)
         self._experts_form = getattr(model, "experts_form", None)
         self.vocab_size = cfg.vocab.held   # ids and logits are over the slice
+        # {a name on a compiled instruction's path: the part of the model it
+        # stands for}: the scopes the programs open, and what the compiler
+        # renames (imported here: a process that serves CANNet never loads it)
+        from can_tpu.models.lm_blocks import PARTS, RENAMED_BY_COMPILER
+
+        self.parts = {**{p: p for p in PARTS}, **RENAMED_BY_COMPILER}
 
     def decode_experts(self, slots: int):
         """``"skipping"`` / ``"batched"`` / ``"sorted"``: the form the expert
@@ -162,12 +168,15 @@ class LMPrograms:
         logits, part, routing = self._m.prefill(
             params, batch["tokens"], batch["lengths"], self.cfg,
             self.positions(batch["tokens"].shape[1]), active=batch["active"])
-        cache = jax.tree.map(
-            lambda c, p: jax.lax.dynamic_update_slice_in_dim(
-                c, p.astype(c.dtype), start, axis=0), cache, part)
-        out = {"first": jnp.argmax(logits, -1).astype(jnp.int32),
-               "logits": logits, "choices": routing["choices"],
-               "counts": routing["counts"]}
+        # the scopes are the models' vocabulary (``lm_blocks.PARTS``)
+        with jax.named_scope("attn.cache"):
+            cache = jax.tree.map(
+                lambda c, p: jax.lax.dynamic_update_slice_in_dim(
+                    c, p.astype(c.dtype), start, axis=0), cache, part)
+        with jax.named_scope("sample"):
+            first = jnp.argmax(logits, -1).astype(jnp.int32)
+        out = {"first": first, "logits": logits,
+               "choices": routing["choices"], "counts": routing["counts"]}
         return out, cache
 
     def decode(self, params, state, cache):
@@ -176,17 +185,18 @@ class LMPrograms:
         logits, cache, routing = self._m.decode_step(
             params, cache, state["tokens"], state["positions"], self.cfg,
             active=state["active"])
-        nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-        ids = jax.lax.dynamic_update_slice(
-            state["ids"], nxt[:, None], (jnp.zeros((), jnp.int32),
-                                         state["step"]))
-        moved = {"tokens": nxt, "positions": state["positions"] + 1,
-                 "active": state["active"], "ids": ids,
-                 "step": state["step"] + 1,
-                 "counts": state["counts"] + routing["counts"]}
-        if "experts_read" in routing:
-            moved["experts_read"] = (state["experts_read"]
-                                     + routing["experts_read"])
+        with jax.named_scope("sample"):
+            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+            ids = jax.lax.dynamic_update_slice(
+                state["ids"], nxt[:, None], (jnp.zeros((), jnp.int32),
+                                             state["step"]))
+            moved = {"tokens": nxt, "positions": state["positions"] + 1,
+                     "active": state["active"], "ids": ids,
+                     "step": state["step"] + 1,
+                     "counts": state["counts"] + routing["counts"]}
+            if "experts_read" in routing:
+                moved["experts_read"] = (state["experts_read"]
+                                         + routing["experts_read"])
         return moved, cache, {"logits": logits, "choices": routing["choices"]}
 
 

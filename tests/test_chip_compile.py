@@ -350,6 +350,27 @@ def test_glm_decode_step_compiles_with_the_skipping_experts(v5e, monkeypatch):
         if re.search(r"= bf16\[64,(2048,1536|1536,2048)\]", line):
             assert " parameter(" in line, line    # nothing else has the shape
     assert _fits_hbm(compiled) > 9 * 2**30
+    _parts_of_the_compiled(programs, text, "skipping_experts", "moe.experts", 5)
+
+
+def _parts_of_the_compiled(programs, text, kernel, part, launches):
+    """The chip's own compiled text, read as ``LMEngine`` reads it for its
+    ``program.scopes`` span: the kernel's launches belong to ``part``, every
+    part is of the vocabulary, and next to nothing is left without one (over
+    half of the instructions are the compiler's own prefetches and copies,
+    which carry no metadata and take their user's part: read at PR 35, 2 of
+    954 and 2 of 1,341 stay without)."""
+    from can_tpu.models.lm_blocks import PARTS
+    from can_tpu.obs.trace import program_scopes
+
+    got = program_scopes(text, programs.parts)
+    found = got["parts"]
+    mine = {i: p for i, p in found.items() if i.startswith(kernel)}
+    assert len(mine) == launches and set(mine.values()) == {part}, mine
+    assert set(found.values()) <= set(PARTS) | {None}
+    assert got["unscoped"] <= 0.01 * got["instructions"], got["unscoped"]
+    assert 0.4 < len(got["inherited"]) / got["instructions"] < 0.75
+    return found
 
 
 # -- the state-space hybrid at the published widths -------------------------
@@ -436,3 +457,10 @@ def test_glm_prefill_slice_compiles_with_the_fused_attention(v5e, monkeypatch):
             made = re.search(rf"^\s*{re.escape(name)} = \S+ (\S+?)\(", text, re.M)
             assert made and made.group(1) == "bitcast", (name, made and made.group(1))
     assert 9 * 2**30 < _fits_hbm(compiled) < 13 * 2**30
+    found = _parts_of_the_compiled(programs, text, "fused_causal_attention",
+                                   "attn.core", 6)
+    # XLA:TPU stamps its grouped-matmul kernels' op_name anew: the scope is
+    # lost and ``lm_blocks.RENAMED_BY_COMPILER`` gives it back
+    assert {p for i, p in found.items() if i.startswith("ragged-dot")} == {
+        "moe.experts"}
+    assert "moe.dispatch" in found.values()

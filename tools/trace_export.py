@@ -49,6 +49,15 @@ the run's ``profile.window`` span — the host has just seen a program
 complete — against the end of the last program on the device (a few
 milliseconds of error; gaps are tens of milliseconds).
 
+By model part: where the spans hold ``program.scopes`` (a language-model
+engine records one per compiled program when a tracer was active at its
+first launch: ``serve/engine.py``, ``obs/trace.py``), every ``XLA Ops``
+event of that program is written with the part of the model its
+instruction belongs to, in ``args`` and as the event's category (Perfetto
+colours by it), and ``--scopes`` prints the seconds by program and part
+(plain sums of the ops' durations; the benchmark's
+``harness/program_scopes.py`` counts overlapping ops once).
+
 Pure host-side file reading — no JAX import unless ``--profile`` is given
 (reading ``.xplane.pb`` takes ``jax.profiler.ProfileData``), safe anywhere
 the artifact was copied to (same contract as tools/telemetry_report.py).
@@ -57,6 +66,7 @@ the artifact was copied to (same contract as tools/telemetry_report.py).
 from __future__ import annotations
 
 import argparse
+import bisect
 import glob
 import json
 import os
@@ -69,6 +79,7 @@ from can_tpu.obs.join import (  # noqa: E402
     load_joined_events,
     resolve_telemetry_source,
 )
+from can_tpu.obs.trace import instruction_of  # noqa: E402
 
 # spans at whose end the host has just seen a device program complete
 _SEEN_COMPLETE = ("serve.fetch", "metric_flush")
@@ -98,6 +109,45 @@ def load_device_planes(profile_dir: str) -> dict:
     return planes
 
 
+def program_parts(events) -> dict:
+    """``program.scopes`` spans -> {program (``jit_decode``): {instruction
+    name: part or None}}; programs compiled under one name share a map."""
+    out: dict = {}
+    for e in events:
+        p = e.get("payload", {})
+        if e.get("kind") == "trace.span" and p.get("name") == "program.scopes":
+            out.setdefault(p["program"], {}).update(p["parts"])
+    return out
+
+
+def _ops_with_parts(lines: dict, parts: dict):
+    """A device plane's ``XLA Ops`` events as (name, start, duration,
+    program, part): the program is the ``XLA Modules`` execution the op
+    starts in, the part what that program's map says of the instruction
+    the event's HLO text begins with (None: the map has none, or there is
+    no map)."""
+    mods = sorted((s, s + d, n.split("(")[0])
+                  for n, s, d in lines.get("XLA Modules", []))
+    starts = [m[0] for m in mods]
+    for name, start, dur in lines.get("XLA Ops", []):
+        i = bisect.bisect_right(starts, start) - 1
+        program = mods[i][2] if i >= 0 and start < mods[i][1] else None
+        yield (name, start, dur, program,
+               parts.get(program, {}).get(instruction_of(name)))
+
+
+def seconds_by_part(planes: dict, parts: dict) -> dict:
+    """{program: {part: seconds}} over the programs that have a map; ops
+    without a part under ``"(none)"``."""
+    out: dict = {}
+    for lines in planes.values():
+        for _, _, dur, program, part in _ops_with_parts(lines, parts):
+            if program in parts:
+                by = out.setdefault(program, {})
+                by[part or "(none)"] = by.get(part or "(none)", 0.0) + dur * 1e-9
+    return out
+
+
 def _device_events(planes: dict, spans, to_ts) -> list:
     """The device planes as trace events, anchored (module docstring).
     ``to_ts(host, seconds)``: the document's microseconds for a host
@@ -123,6 +173,7 @@ def _device_events(planes: dict, spans, to_ts) -> list:
                          "serve.fetch / metric_flush span ends inside the "
                          "profile.window, or the profile holds no program")
     anchor = max(seen)
+    parts = program_parts(spans)
     out = []
     for i, (plane, lines) in enumerate(sorted(planes.items())):
         pid = _DEVICE_PID + i
@@ -132,13 +183,16 @@ def _device_events(planes: dict, spans, to_ts) -> list:
         for tid, line in enumerate(_DEVICE_LINES, start=1):
             out.append({"ph": "M", "name": "thread_name", "pid": pid,
                         "tid": tid, "args": {"name": line}})
-            for name, start, dur in lines.get(line, []):
+            rows = (_ops_with_parts(lines, parts) if line == "XLA Ops" else
+                    ((n, s, d, None, None) for n, s, d in lines.get(line, [])))
+            for name, start, dur, program, part in rows:
                 out.append({
-                    "name": name[:120], "cat": "device", "ph": "X",
+                    "name": name[:120], "cat": part or "device", "ph": "X",
                     "ts": round(to_ts(host, anchor + (start - last_end) * 1e-9),
                                 3),
                     "dur": round(dur * 1e-3, 3), "pid": pid, "tid": tid,
-                    "args": {}})
+                    "args": ({"part": part, "program": program}
+                             if part else {})})
     return out
 
 
@@ -237,20 +291,37 @@ def main(argv=None) -> int:
     p.add_argument("--profile", default="",
                    help="the run's --profile-dir: draw the device's XLA "
                         "Modules / XLA Ops beside the spans")
+    p.add_argument("--scopes", action="store_true",
+                   help="with --profile: print the device seconds by "
+                        "program and model part (needs program.scopes "
+                        "spans: a language-model engine warmed up with a "
+                        "tracer active)")
     args = p.parse_args(argv)
+    if args.scopes and not args.profile:
+        p.error("--scopes reads the device plane: give --profile DIR")
     # estimate=True: a flame view exists to compare timing across hosts,
     # so skew correction is always on (measured snapshot offsets win;
     # plain run dirs get the first-heartbeat estimate).  The events come
     # back already corrected — no offsets passed below.
     events, _, _ = load_joined_events(args.target, estimate=True)
     try:
-        doc = spans_to_trace_events(
-            events, trace_id=args.trace_id,
-            device_planes=(load_device_planes(args.profile)
-                           if args.profile else None))
+        planes = load_device_planes(args.profile) if args.profile else None
+        doc = spans_to_trace_events(events, trace_id=args.trace_id,
+                                    device_planes=planes)
     except (FileNotFoundError, ValueError) as e:
         print(f"trace_export: {e}", file=sys.stderr)
         return 1
+    if args.scopes:
+        table = seconds_by_part(planes, program_parts(events))
+        if not table:
+            print("trace_export: no program.scopes span names a program of "
+                  "this profile", file=sys.stderr)
+            return 1
+        for program, by in sorted(table.items()):
+            total = sum(by.values())
+            print(f"[scopes] {program}: {total:.6f} s of ops")
+            for part, sec in sorted(by.items(), key=lambda kv: -kv[1]):
+                print(f"  {part:<14}{sec:12.6f} s {100 * sec / total:6.2f}%")
     n = sum(1 for e in doc["traceEvents"]
             if e["ph"] == "X" and e["cat"] == "can_tpu")
     if not n:
