@@ -218,8 +218,11 @@ def init_params(key, cfg: Glm4MoeLiteConfig, dtype=jnp.bfloat16):
 def cache_layout(cfg: Glm4MoeLiteConfig) -> tuple:
     """What each held layer keeps in a launch's cache
     (``ops/cache_layout.py``): the latent and the shared rotary key of every
-    position, no heads.  Two arrays and not one of ``rank + rope_dim``: the
-    issue's default, never timed against the other (PERF.md section 7)."""
+    position, no heads.  Two arrays and not one of ``rank + rope_dim``: 576
+    is not a whole number of the 128 lanes, so that array would reach a
+    program with its positions minor and be copied whole around every
+    step's one-row write, as the 64-wide ``krope`` is today (compiled for a
+    described v5e, never timed: PERF.md section 7)."""
     return (layout.latent_layer(rank=cfg.kv_lora_rank,
                                 rope_dim=cfg.qk_rope_head_dim),
             ) * cfg.num_layers

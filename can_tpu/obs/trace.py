@@ -36,6 +36,7 @@ _INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
 _OPCODE = re.compile(r"\s([a-z][\w\-]*)\(")
 _OPERAND = re.compile(r"%([\w.\-]+)")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
+_RESULT = re.compile(r"(\w+\[[\d,]*\])")   # right after the ``name = ``
 # computations that run INSIDE the instruction that names them
 _INLINED = {"fusion": re.compile(r"\bcalls=%?([\w.\-]+)"),
             "custom-call": re.compile(r"called_computations=\{([^}]*)\}")}
@@ -46,8 +47,9 @@ _NO_OP = frozenset({"parameter", "constant", "tuple", "get-tuple-element",
 
 
 def _instructions(hlo_text: str):
-    """-> ([(computation, name, opcode, op_name, operand names)] in the
-    text's order, the computations that run inside an instruction)."""
+    """-> ([(computation, name, opcode, op_name, operand names, the
+    result's type ``bf16[4,8]`` or "" for a tuple)] in the text's order, the
+    computations that run inside an instruction)."""
     inlined, rows, current = set(), [], None
     for line in hlo_text.splitlines():
         m = _INSTRUCTION.match(line)
@@ -67,8 +69,10 @@ def _instructions(hlo_text: str):
                 inlined.update(n.strip().lstrip("%")
                                for n in c.group(1).split(","))
         name = _OP_NAME.search(line)
+        made = _RESULT.match(line, m.end())
         rows.append((current, m.group(1), opcode,
-                     name.group(1) if name else "", operands))
+                     name.group(1) if name else "", operands,
+                     made.group(1) if made else ""))
     return rows, inlined
 
 
@@ -89,7 +93,7 @@ def scope_map(hlo_text: str) -> Dict[str, str]:
     constants, tuples, bitcasts).  An instruction without metadata (a copy
     the compiler put in) maps to ``""``."""
     rows, inlined = _instructions(hlo_text)
-    return {inst: op_name for comp, inst, opcode, op_name, _ in rows
+    return {inst: op_name for comp, inst, opcode, op_name, _, _ in rows
             if comp not in inlined and opcode not in _NO_OP}
 
 
@@ -120,9 +124,9 @@ def program_scopes(hlo_text: str, parts) -> dict:
     # tuples, bitcasts and the like have no part of their own: they hand on
     # their neighbours'
     own = {inst: None if opcode in _NO_OP else part_of(op_name, parts)
-           for _, inst, opcode, op_name, _ in rows}
+           for _, inst, opcode, op_name, _, _ in rows}
     users: Dict[str, list] = {}
-    for _, inst, _, _, operands in rows:
+    for _, inst, _, _, operands, _ in rows:
         for operand in operands:
             users.setdefault(operand, []).append(inst)
 
@@ -132,7 +136,7 @@ def program_scopes(hlo_text: str, parts) -> dict:
         along a whole chain of copies.  The two directions are kept apart:
         nothing passes from one user of a value to another."""
         got = dict(own)
-        for _, inst, opcode, _, operands in order:
+        for _, inst, opcode, _, operands, _ in order:
             if got[inst] is None and opcode not in never:
                 got[inst] = next((got[n] for n in neighbours(inst, operands)
                                   if got.get(n) is not None), None)
@@ -146,12 +150,36 @@ def program_scopes(hlo_text: str, parts) -> dict:
     up = handed(rows, lambda _, operands: operands,
                 ("parameter", "constant", "tuple"))
     found = {inst: own[inst] or down[inst] or up[inst]
-             for comp, inst, opcode, _, _ in rows
+             for comp, inst, opcode, _, _, _ in rows
              if comp not in inlined and opcode not in _NO_OP}
     return {"parts": found, "instructions": len(found),
             "unscoped": sum(p is None for p in found.values()),
             "inherited": [i for i, p in found.items()
                           if p is not None and own[i] is None]}
+
+
+def hlo_type(shape, dtype) -> str:
+    """An array's type as a compiled program's text writes it:
+    ``(64, 4, 1280, 128)``, ``bfloat16`` -> ``bf16[64,4,1280,128]``."""
+    import numpy as np
+
+    dt = np.dtype(dtype)
+    head = ("bf16" if dt.name == "bfloat16" else "pred" if dt.kind == "b"
+            else {"f": "f", "i": "s", "u": "u"}[dt.kind] + str(8 * dt.itemsize))
+    return f"{head}[{','.join(str(int(n)) for n in shape)}]"
+
+
+def cache_copies(hlo_text: str, leaves) -> int:
+    """How many ``copy`` instructions of a compiled program's text, among
+    those that run as ops of their own (``scope_map``'s), have the shape and
+    dtype of one of ``leaves`` (the decoding cache's arrays, or their
+    ``ShapeDtypeStruct``s).  A donated cache that is written in place has
+    none; each one is a whole leaf moved into another layout, or back
+    (PERF.md section 6, PR 37)."""
+    types = {hlo_type(a.shape, a.dtype) for a in leaves}
+    rows, inlined = _instructions(hlo_text)
+    return sum(opcode == "copy" and comp not in inlined and made in types
+               for comp, _, opcode, _, _, made in rows)
 
 
 def operator_profile_options():

@@ -132,9 +132,24 @@ def ring_positions(pos, window: int):
 
 def write_slot(cache, new, slot):
     """``cache`` (B, KV, S, D) with ``new`` (B, KV, D) written at ``slot``
-    (B,), one slot per sequence."""
-    b = cache.shape[0]
-    return cache.at[jnp.arange(b), :, slot].set(new.astype(cache.dtype))
+    (B,), one slot per sequence.
+
+    The rows are written by a scatter over the MERGED (sequence x head)
+    axis, one indexed axis in front of the positions.  Indexed as
+    ``cache.at[arange(B), :, slot]`` the head axis is a window dimension
+    between two indexed ones, and XLA:TPU re-lays the whole cache with the
+    positions above the heads, scatters there and copies it back to the
+    layout the donated argument and ``decode`` have: two copies of every
+    layer's whole cache a step (PERF.md section 6, PR 37).  Merged, the
+    write is ``bitcast -> scatter -> bitcast`` on the argument's own buffer.
+    The merge is a bitcast where the positions are a whole number of the
+    leaf's tile rows (8 of float32, 16 of bfloat16: 1,280 and a window of
+    128 are); elsewhere it is a correct write that may cost a copy."""
+    b, kv, s, d = cache.shape
+    rows = cache.reshape(b * kv, s, d)
+    rows = rows.at[jnp.arange(b * kv), jnp.repeat(slot, kv)].set(
+        new.reshape(b * kv, d).astype(cache.dtype), unique_indices=True)
+    return rows.reshape(b, kv, s, d)
 
 
 # -- latent attention -------------------------------------------------------

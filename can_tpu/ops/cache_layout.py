@@ -22,6 +22,13 @@ both import it, and neither imports the other for it.
 A layer whose block keeps two kinds (attention and a recurrence side by
 side) is described by a tuple of ``LayerSpec``, one a kind; its leaves share
 the layer's one entry, so their names differ.  ``parts`` reads either form.
+
+A decode step writes ONE position of a leaf that has positions, in place:
+``write_slot`` merges the axes in front of the positions (slots x heads)
+into one before it scatters, because XLA:TPU's scatter takes its indexed
+axes adjacent and major, and with the heads between two indexed axes it
+copies the whole leaf into another layout and back around the row (PERF.md
+section 6, PR 37).  ``positioned_leaves`` names the arrays this holds for.
 """
 
 from __future__ import annotations
@@ -57,6 +64,15 @@ def parts(layer) -> Tuple[LayerSpec, ...]:
     """A layer's description, one ``LayerSpec`` or a tuple of them, as the
     tuple."""
     return (layer,) if isinstance(layer, LayerSpec) else tuple(layer)
+
+
+def positioned_leaves(specs, cache: dict) -> list:
+    """The arrays of ``cache`` (``serve/cache.py::allocate``'s, of the
+    layers ``specs``) that have a position axis: every kind's but
+    ``state``'s, which a step rewrites whole."""
+    return [entry[name] for layer, entry in zip(specs, cache["layers"])
+            for spec in parts(layer) if spec.kind != STATE
+            for name, _, _ in spec.leaves]
 
 
 def kv_layer(kind: str, *, kv_heads: int, head_dim: int,

@@ -586,15 +586,22 @@ class LMEngine:
         compiled text is read from a second ``lower().compile()`` of the
         same signature (``ServeEngine.compile_program``'s precedent: a hit
         in the persistent cache); ``args`` are the launch's own or, where it
-        donated them, what it returned in their place (the same shapes).
+        donated them, what it returned in their place (the same shapes);
+        ``args[2]`` is the cache in both programs, and ``cache_copies`` says
+        how many times the program copies whole one of its arrays that have
+        positions (``obs.trace.cache_copies``: none where a position is
+        written in place).
         ``name``: the program as the trace's ``XLA Modules`` line names it."""
         from can_tpu.obs.costs import resolve_jit
-        from can_tpu.obs.trace import program_scopes
+        from can_tpu.obs.trace import cache_copies, program_scopes
+        from can_tpu.ops.cache_layout import positioned_leaves
 
         with tr.span("program.scopes", parent_id=launch.span_id,
                      program=name, key=list(key)) as sp:
             text = resolve_jit(program, args).lower(*args).compile().as_text()
             sp.attrs.update(program_scopes(text, self.programs.parts))
+            sp.attrs["cache_copies"] = cache_copies(text, positioned_leaves(
+                self.programs.cache_layout, args[2]))
 
     def _with_cache_signature(self, args) -> tuple:
         """(params, dict of arrays, cache, ...) -> the signature of the dict

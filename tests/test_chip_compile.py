@@ -171,6 +171,57 @@ def test_dp2_sp2_train_step_compiles_on_the_2x2_mesh(v5e):
     _fits_hbm(compiled)
 
 
+def _cache_copies(compiled, programs, cache) -> dict:
+    """{type of a cache array that has positions: how many times the
+    compiled program copies such an array whole} (``obs.trace.cache_copies``
+    over ``positioned_leaves``: summed, what ``LMEngine`` writes on its
+    ``program.scopes`` span).  A donated cache written in place reads 0
+    throughout; each copy is a leaf moved into another layout or back."""
+    from can_tpu.obs.trace import cache_copies, hlo_type
+    from can_tpu.ops.cache_layout import positioned_leaves
+
+    text = compiled.as_text()
+    return {hlo_type(a.shape, a.dtype): cache_copies(text, [a])
+            for a in positioned_leaves(programs.cache_layout, cache)}
+
+
+def _plain_write_slot(cache, new, slot):
+    """``write_slot`` as it stood before PR 37."""
+    return cache.at[jnp.arange(cache.shape[0]), :, slot].set(
+        new.astype(cache.dtype))
+
+
+@pytest.mark.parametrize("shape,write,copies", [
+    ((64, 8, 1280, 128), None, 0), ((64, 8, 128, 128), None, 0),
+    ((64, 4, 1280, 128), None, 0), ((64, 4, 1280, 128), _plain_write_slot, 2),
+], ids=["k-exaone-full", "k-exaone-ring", "falcon-h1-full",
+        "falcon-h1-full-plain-indexed"])
+def test_write_slot_and_decode_leave_a_donated_cache_where_it_is(
+        v5e, shape, write, copies):
+    """One row written into a donated leaf of the cells' shapes and the leaf
+    read by ``decode``: the merged scatter compiles to no copy of the leaf;
+    the plain indexed write it replaced, kept here as the record of the
+    cause, to one copy into ``{3,1,2,0}`` and one back."""
+    from can_tpu.obs.trace import cache_copies
+    from can_tpu.ops import attention
+
+    write = write or attention.write_slot
+    b, kv, s, d = shape
+    one = SingleDeviceSharding(v5e[0])
+    arr = lambda sh, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        sh, dt, sharding=one)
+
+    def step(cache, q, new, slot):
+        cache = write(cache, new, slot)
+        return cache, attention.decode(q, cache, cache, jnp.ones((b, s), bool))
+
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        arr(shape), arr((b, kv, 2, d)), arr((b, kv, d)),
+        arr((b,), jnp.int32)).compile()
+    assert cache_copies(compiled.as_text(), [arr(shape)]) == copies
+    assert compiled.memory_analysis().alias_size_in_bytes == b * kv * s * d * 2
+
+
 # -- the language model's serving programs at the published widths --------
 def _lm_programs_and_shapes(v5e, slots, part,
                             name="k-exaone-ep8-serve-bf16"):
@@ -230,6 +281,10 @@ def test_lm_decode_step_compiles_for_one_device(v5e):
     compiled = jax.jit(programs.decode, donate_argnums=(1, 2)).lower(
         params, state, cache).compile()
     assert "ragged-dot" not in compiled.as_text()
+    # ``write_slot`` writes in place: neither the full layer's cache nor a
+    # window layer's ring is copied (a copy of either is 0.13-0.5 ms a step)
+    assert _cache_copies(compiled, programs, cache) == {
+        "bf16[64,8,1280,128]": 0, "bf16[64,8,128,128]": 0}
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= 64 * 8 * (1280 + 4 * 128) * 128 * 2 * 2
     assert _fits_hbm(compiled) > 7 * 2**30   # the weights alone are 7.4 GB
@@ -268,6 +323,13 @@ def test_glm_decode_step_compiles_for_one_device(v5e):
     assert "ragged-dot" not in text
     assert "bf16[16,16512,512]" in text          # the latent, as stored
     assert "bf16[16,20,16512,192]" not in text   # no key rebuilt per head
+    # ``write_row`` writes the latent in place; the rotary keys' leaf, 64
+    # wide (under the 128 lanes), arrives with the positions minor and is
+    # re-laid on the way in and out, twice a layer: a KNOWN DEBT, 1.78 ms of
+    # a 13.05 ms step (PERF.md section 7: the leaf's layout pinned across
+    # the launch's programs cures it, a merged or 2-D scatter does not)
+    assert _cache_copies(compiled, programs, cache) == {
+        "bf16[16,16512,512]": 0, "bf16[16,16512,64]": 12}
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= 16 * 16512 * 6912
     assert _fits_hbm(compiled) > 9 * 2**30
@@ -394,6 +456,10 @@ def test_falcon_h1_decode_step_compiles_for_one_device(v5e):
     compiled = jax.jit(programs.decode, donate_argnums=(1, 2)).lower(
         params, state, cache).compile()
     assert "f32[64,32,128,256]" in compiled.as_text()    # the state, as stored
+    # keys and values written in place (a copy of one is 0.12-0.26 ms a
+    # step); the state's leaves, rewritten whole, are not counted
+    assert _cache_copies(compiled, programs, cache) == {
+        "bf16[64,4,1280,128]": 0}
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= 64 * (25_350_144 + 1280 * 12_288)
     assert 12 * 2**30 < _fits_hbm(compiled) < 15 * 2**30

@@ -239,6 +239,53 @@ class TestAttention:
             np.asarray(attn_ops.prefill_window(q, k, v, window=window)),
             np.asarray(self._dense(q, k, v, window)), atol=1e-5)
 
+    # the cells' leaves cut small: (slots, kv heads, positions, head dim),
+    # the cache's dtype, ``new``'s dtype, the positions of the slots' tokens
+    @pytest.mark.parametrize("late", [False, True], ids=["sound", "late_write"])
+    @pytest.mark.parametrize("shape,window,dtype,new_dtype,positions", [
+        ((4, 2, 16, 8), None, jnp.bfloat16, jnp.bfloat16, [0, 5, 11, 15]),
+        ((4, 2, 8, 8), 8, jnp.bfloat16, jnp.bfloat16, [3, 8, 21, 47]),
+        ((3, 1, 16, 8), None, jnp.bfloat16, jnp.bfloat16, [2, 9, 15]),
+        ((4, 4, 16, 8), None, jnp.bfloat16, jnp.float32, [7, 7, 7, 1]),
+        ((4, 2, 8, 8), 8, jnp.float32, jnp.float32, [9, 1, 17, 25]),
+        ((1, 3, 12, 4), None, jnp.float32, jnp.bfloat16, [11]),
+    ], ids=["full", "ring", "one-head", "slot-repeated-f32-new",
+            "ring-slot-repeated-f32", "one-sequence-bf16-new"])
+    def test_write_slot_is_the_plain_indexed_write(self, shape, window, dtype,
+                                                   new_dtype, positions, late):
+        """The merged (slot x head) scatter against the indexed write it
+        replaced, bit for bit: a full layer writes at its position, a ring
+        at ``position % window``; alone and under
+        ``calibrate_lm.late_write``'s wrapper, whose late slot wraps at
+        ``cache.shape[2]``."""
+        from benchmark.tools import calibrate_lm
+
+        b, kv, s, d = shape
+        k1, k2 = jax.random.split(jax.random.key(sum(shape)))
+        cache = jax.random.normal(k1, shape).astype(dtype)
+        new = jax.random.normal(k2, (b, kv, d)).astype(new_dtype)
+        slot = jnp.asarray(positions, jnp.int32)
+        if window:
+            slot = slot % window
+        want_at = (slot + 1) % s if late else slot
+        want = cache.at[jnp.arange(b), :, want_at].set(new.astype(dtype))
+        if late:
+            calibrate_lm.late_write(None)
+        try:
+            got = jax.jit(attn_ops.write_slot)(cache, new, slot)
+        finally:
+            if late:
+                calibrate_lm.late_write.undo()
+        assert got.shape == shape and got.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+        # every other row is the cache's own
+        untouched = np.ones((b, s), bool)
+        untouched[np.arange(b), np.asarray(want_at)] = False
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32).transpose(0, 2, 1, 3)[untouched],
+            np.asarray(cache, np.float32).transpose(0, 2, 1, 3)[untouched])
+
     def test_rope_is_relative(self):
         """q.k after rotation depends on the distance alone."""
         q = jax.random.normal(jax.random.key(0), (1, 1, 1, 16))
@@ -355,14 +402,16 @@ def test_share_apply_runs_the_form_chosen(form, monkeypatch):
 # sha256 of the StableHLO text of ``LMPrograms.decode`` / ``.prefill_slice``
 # at the benchmark's configurations, lowered on the CPU with jax 0.9.0 at
 # commit 0227fb7 (PR 32).  A PR that means to change one of these programs
-# prints the new value with this test's failure and replaces it here.
+# prints the new value with this test's failure and replaces it here: PR 37
+# replaced the two ``decode`` texts of K-EXAONE and Falcon-H1 (``write_slot``
+# as a scatter over the merged slot x head axis); the other four stand.
 PROGRAM_TEXT = {
     ("k-exaone-ep8-serve-bf16", "decode"):
-        "be43ebf728ceeff7e972c249bae208143218bc4fb724638127cf8c95c0512622",
+        "2c53b1638274f93f0d4919dc5558a2e6378313e37e545c1402c69c3c3a01403d",
     ("k-exaone-ep8-serve-bf16", "prefill_slice"):
         "bb7d1d97fcf9ba32df5bb46cf815b1ecaab3ab6cc8d8f16461276278dfe9f6c0",
     ("falcon-h1-34b-pp12-serve-bf16", "decode"):
-        "59e7ad47cb2d3d41d1833184c93ba29d34c6061137822c72923499b4a4510386",
+        "06d5c406ee95631fb2d429365a24acb1623aef8e99a46f6cbc3d113e7c48c8a0",
     ("falcon-h1-34b-pp12-serve-bf16", "prefill_slice"):
         "7dbdc1aaac65afe9ff78235ea2ebc774d5fa7b43881686bb73b86555ca11ee7a",
     ("glm-4.7-flash-pp8-serve-bf16", "decode"):

@@ -12,7 +12,8 @@ import pytest
 import lm_tiny
 from can_tpu.models import exaone_moe, falcon_h1, glm_moe_lite, lm_blocks
 from can_tpu.obs import spans as recorder
-from can_tpu.obs.trace import part_of, program_scopes, scope_map
+from can_tpu.obs.trace import (cache_copies, hlo_type, part_of,
+                               program_scopes, scope_map)
 from can_tpu.serve.programs import LMPrograms
 
 PARTS = lm_blocks.PARTS
@@ -67,6 +68,60 @@ ENTRY %main.20 (x: bf16[4,8], w: bf16[64,8,8], cache: bf16[4,8]) -> (bf16[4,8], 
   ROOT %tuple.9 = (bf16[4,8]{1,0}, f32[], bf16[4,8]{0,1}) tuple(%bitcast.2, %reduce.5, %copy.40)
 }
 """
+
+
+# a decode step that writes its cache in place (XLA:TPU's text for
+# ``write_slot`` since PR 37, cut down by hand): the donated leaf is bitcast
+# to the merged (slot x head) axis, scattered into and bitcast back; a
+# ``copy`` INSIDE the fusion is no op of its own, one of another shape is
+# not the cache's
+HLO_IN_PLACE = r"""HloModule jit_decode, is_scheduled=true
+
+%fused_computation.2 (param_0.2: bf16[8,16,8], param_1.2: bf16[8,8]) -> bf16[8,16,8] {
+  %param_0.2 = bf16[8,16,8]{2,1,0} parameter(0)
+  %param_1.2 = bf16[8,8]{1,0} parameter(1)
+  %copy.7 = bf16[4,2,16,8]{3,2,1,0} copy(%param_0.2)
+  ROOT %scatter.1 = bf16[8,16,8]{2,1,0} scatter(%param_0.2, %param_1.2), metadata={op_name="jit(decode)/attn.cache/scatter"}
+}
+
+ENTRY %main.9 (cache: bf16[4,2,16,8], new: bf16[8,8]) -> (bf16[4,2,16,8], bf16[8,8]) {
+  %cache = bf16[4,2,16,8]{3,2,1,0:T(8,128)(2,1)} parameter(0), metadata={op_name="cache['layers'][0]['k']"}
+  %new = bf16[8,8]{1,0} parameter(1)
+  %bitcast.1 = bf16[8,16,8]{2,1,0:T(8,128)(2,1)} bitcast(%cache)
+  %fusion.2 = bf16[8,16,8]{2,1,0:T(8,128)(2,1)} fusion(%bitcast.1, %new), kind=kInput, calls=%fused_computation.2, metadata={op_name="jit(decode)/attn.cache/scatter"}
+  %bitcast.3 = bf16[4,2,16,8]{3,2,1,0:T(8,128)(2,1)} bitcast(%fusion.2)
+  %copy.8 = bf16[8,8]{0,1} copy(%new)
+  ROOT %tuple.2 = (bf16[4,2,16,8]{3,2,1,0}, bf16[8,8]{0,1}) tuple(%bitcast.3, %copy.8)
+}
+"""
+
+
+@pytest.mark.parametrize("text,leaves,want", [
+    # ``HLO`` above: its cache is copied in (copy.30), a result of its shape
+    # out (copy.31) and an argument of its shape out (copy.40)
+    (HLO, [((4, 8), "bfloat16")], 3),
+    (HLO, [((64, 8, 8), "bfloat16")], 0),    # the weights: never copied
+    (HLO, [((4, 8), "bfloat16"), ((), "float32"), ((4, 8), "bfloat16")], 3),
+    (HLO_IN_PLACE, [((4, 2, 16, 8), "bfloat16")], 0),
+    (HLO_IN_PLACE, [((8, 8), "bfloat16")], 1),   # counted where asked for
+    (HLO_IN_PLACE, [], 0),
+], ids=["copied-in-and-out", "another-shape", "two-leaves", "in-place",
+        "not-the-cache", "no-leaves"])
+def test_cache_copies_counts_the_copies_that_run_with_a_leaf_s_type(
+        text, leaves, want):
+    assert cache_copies(text, [jax.ShapeDtypeStruct(s, jnp.dtype(d))
+                               for s, d in leaves]) == want
+
+
+@pytest.mark.parametrize("shape,dtype,want", [
+    ((64, 4, 1280, 128), jnp.bfloat16, "bf16[64,4,1280,128]"),
+    ((16, 16512, 64), jnp.zeros((), jnp.bfloat16).dtype, "bf16[16,16512,64]"),
+    ((64, 32, 128, 256), jnp.float32, "f32[64,32,128,256]"),
+    ((64,), "int32", "s32[64]"), ((), jnp.uint8, "u8[]"),
+    ((2, 3), bool, "pred[2,3]"),
+])
+def test_hlo_type_writes_an_array_as_the_compiled_text_does(shape, dtype, want):
+    assert hlo_type(shape, dtype) == want
 
 
 class TestTheMap:
@@ -279,6 +334,8 @@ def test_with_a_tracer_the_engine_records_one_span_a_program(count_lowerings):
         assert s["unscoped"] == sum(p is None for p in s["parts"].values()) <= 3
         assert set(s["inherited"]) <= set(s["parts"])
         assert {"attn.core", "moe.experts", "head"} <= set(s["parts"].values())
+        # the CPU's compiler copies what it likes: an integer, whatever it is
+        assert type(s["cache_copies"]) is int and s["cache_copies"] >= 0
     assert {s["program"]: s["key"][0] for s in scopes} == {
         "jit_decode": slots, "jit_prefill_slice": engine._slices(slots)[0][1]}
 
@@ -312,6 +369,7 @@ def test_trace_export_writes_each_device_op_with_its_part(tmp_path, capsys):
               span("serve.fetch", 100.4, 0.1, thread="batcher"),
               span("program.scopes", 90.0, 0.5, program="jit_predict",
                    key=[2, 64], instructions=3, unscoped=1, inherited=[],
+                   cache_copies=4,
                    parts={"convert_bitcast_fusion": "embed",
                           "copy-done.14": "attn.cache", "copy.1": None})]
     tel = tmp_path / "telemetry.host0.jsonl"
@@ -331,7 +389,9 @@ def test_trace_export_writes_each_device_op_with_its_part(tmp_path, capsys):
     rest = [e for e in ops if e["cat"] == "device"]
     assert len(rest) == len(ops) - 6 and all(e["args"] == {} for e in rest)
     table = capsys.readouterr().out
-    assert "[scopes] jit_predict:" in table
+    head, = [l for l in table.splitlines() if l.startswith("[scopes]")]
+    assert head.startswith("[scopes] jit_predict: ")
+    assert head.endswith(" s of ops, cache_copies 4")
     rows = {l.split()[0]: float(l.split()[1]) for l in table.splitlines()
             if l.startswith("  ")}
     assert set(rows) == {"embed", "attn.cache", "(none)"}

@@ -56,7 +56,10 @@ event of that program is written with the part of the model its
 instruction belongs to, in ``args`` and as the event's category (Perfetto
 colours by it), and ``--scopes`` prints the seconds by program and part
 (plain sums of the ops' durations; the benchmark's
-``harness/program_scopes.py`` counts overlapping ops once).
+``harness/program_scopes.py`` counts overlapping ops once) and, beside a
+program's name, the span's ``cache_copies``: how many times the program
+copies an array of its decoding cache whole (0 where a step writes its
+position in place).
 
 Pure host-side file reading — no JAX import unless ``--profile`` is given
 (reading ``.xplane.pb`` takes ``jax.profiler.ProfileData``), safe anywhere
@@ -117,6 +120,21 @@ def program_parts(events) -> dict:
         p = e.get("payload", {})
         if e.get("kind") == "trace.span" and p.get("name") == "program.scopes":
             out.setdefault(p["program"], {}).update(p["parts"])
+    return out
+
+
+def program_cache_copies(events) -> dict:
+    """``program.scopes`` spans -> {program: ``cache_copies``}, how many
+    times the compiled program copies an array of its decoding cache whole
+    (0: every position is written in place); of programs compiled under one
+    name the most; nothing for a span from before the attribute."""
+    out: dict = {}
+    for e in events:
+        p = e.get("payload", {})
+        if (e.get("kind") == "trace.span" and p.get("name") == "program.scopes"
+                and "cache_copies" in p):
+            out[p["program"]] = max(out.get(p["program"], 0),
+                                    int(p["cache_copies"]))
     return out
 
 
@@ -317,9 +335,12 @@ def main(argv=None) -> int:
             print("trace_export: no program.scopes span names a program of "
                   "this profile", file=sys.stderr)
             return 1
+        copies = program_cache_copies(events)
         for program, by in sorted(table.items()):
             total = sum(by.values())
-            print(f"[scopes] {program}: {total:.6f} s of ops")
+            print(f"[scopes] {program}: {total:.6f} s of ops"
+                  + (f", cache_copies {copies[program]}"
+                     if program in copies else ""))
             for part, sec in sorted(by.items(), key=lambda kv: -kv[1]):
                 print(f"  {part:<14}{sec:12.6f} s {100 * sec / total:6.2f}%")
     n = sum(1 for e in doc["traceEvents"]
