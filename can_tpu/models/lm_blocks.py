@@ -2,10 +2,13 @@
 functions over a parameter tree: the norm, SwiGLU, the expert layer with
 its shared expert, the feed-forward half of a block, the head, the routing
 report a serving program returns, and the seeded initialiser.  A model's
-module (``exaone_moe.py``, ``glm_moe_lite.py``) brings its own attention and
-its own config class; the config offers ``rms_norm_eps`` and, where the model
-has an expert layer, ``num_experts_per_tok``, ``routed_scaling_factor``,
-``norm_topk_prob`` and ``share`` (``ops.moe.ExpertShare``).  A dense model
+module (``exaone_moe.py``, ``glm_moe_lite.py``, ``lfm2_moe.py``) brings its
+own mixers and its own config class; the config offers ``rms_norm_eps``
+and, where the model has an expert layer, ``num_experts_per_tok``, ``routed_scaling_factor``,
+``norm_topk_prob`` and ``share`` (``ops.moe.ExpertShare``).  An expert layer
+has a shared expert where its parameters hold one (``shared``; LFM2's hold
+none), and the head is the embedding's transpose where the tree has no
+``head`` (tied).  A dense model
 (``falcon_h1.py``) takes the norm, SwiGLU and the head with its
 configuration's multipliers, and ``no_routing``.
 
@@ -25,7 +28,7 @@ from can_tpu.ops import moe as moe_ops
 from can_tpu.ops.moe import ExpertShare
 
 
-# The parts of a language model, ONE vocabulary for the three models: every
+# The parts of a language model, ONE vocabulary for the four models: every
 # ``jax.named_scope`` that the serving programs pass through (the models,
 # ``ops/moe.py``, ``serve/programs.py``) is one of these names, whole (the MTP
 # modules' ``mtp``, outside those programs, wraps them).  A scope is metadata: it names no op and adds
@@ -48,6 +51,9 @@ PARTS = (
     "ssm.proj",      # ``in_proj``, the convolution, the recurrence's inputs
     "ssm.scan",      # ``ssd_chunked`` / ``ssd_step``: the state
     "ssm.out",       # the gated norm and ``out_proj``
+    "conv.proj",     # LFM2's gated short convolution: input norm and ``in_proj``
+    "conv.mix",      # the gates ``B * X`` and ``C * v`` around the convolution (a prompt / one step), the tail
+    "conv.out",      # ``out_proj`` and the residual
     "head",          # final norm and the head's product
     "sample",        # argmax, the ``ids`` update, the decode state moved on
     "routing",       # ``routing_report``'s counts and choices
@@ -83,6 +89,9 @@ def _leaf(key, name: str, shape, dtype):
         return 0.05 * jax.random.normal(key, shape, jnp.float32)
     if name == "embed":
         return jax.random.normal(key, shape, dtype)
+    if name == "conv_w":    # (channels, taps): N(0, 1 / taps)
+        return (jax.random.normal(key, shape, jnp.float32)
+                * shape[-1] ** -0.5).astype(dtype)
     fan_in = shape[-2]
     return jax.random.normal(key, shape, dtype) * jnp.asarray(fan_in ** -0.5, dtype)
 
@@ -91,8 +100,9 @@ def init_from_shapes(key, shapes: dict, dtype=jnp.bfloat16):
     """Parameters from a key for a tree of shapes, leaf by leaf on the
     device (one jitted call a leaf: no float32 copy of the whole tree is
     ever alive).  By the leaf's name: ``ln_*`` / ``*_norm`` norms near one,
-    ``bias`` a float32 buffer, ``embed`` N(0, 1), every other a projection
-    N(0, 1 / fan_in) so that activations stay of order one."""
+    ``bias`` a float32 buffer, ``embed`` N(0, 1), ``conv_w`` a depthwise
+    convolution N(0, 1 / taps), every other a projection N(0, 1 / fan_in)
+    so that activations stay of order one."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=lambda x: isinstance(x, tuple))
     make = jax.jit(_leaf, static_argnums=(1, 2, 3))
@@ -145,13 +155,16 @@ def experts_form(cfg, tokens: int, dtype) -> str:
 
 def expert_layer(p, x, cfg):
     """``x`` (T, d) -> (this chip's part of the routed sum + the shared
-    expert (T, d), ``Routed``: the experts each token chose (T, k))."""
+    expert where the layer has one (T, d), ``Routed``: the experts each
+    token chose (T, k))."""
     with jax.named_scope("moe.router"):
         idx, w = moe_ops.route(x, p["router"], p["bias"],
                                top_k=cfg.num_experts_per_tok,
                                scale=cfg.routed_scaling_factor,
                                normalize=cfg.norm_topk_prob)
     routed, read = moe_ops.share_apply(x, idx, w, p["experts"], cfg.share)
+    if "shared" not in p:
+        return routed, Routed(idx, read)
     with jax.named_scope("moe.shared"):
         return routed + swiglu(x, p["shared"]), Routed(idx, read)
 
@@ -219,9 +232,14 @@ def last_hidden(h, lengths):
 
 def lm_head(params, h, cfg, multiplier=None):
     """Float32 logits of ``RMSNorm(h)``, times ``multiplier`` where the
-    configuration has one."""
+    configuration has one.  A tree without ``head`` ties it to the
+    embedding: the product is against ``embed`` (V, d) as it is stored."""
     with jax.named_scope("head"):
         x = rms_norm(h, params["final_norm"], cfg.rms_norm_eps)
-        logits = jnp.dot(x, params["head"],
-                         preferred_element_type=jnp.float32)
+        if "head" in params:
+            logits = jnp.dot(x, params["head"],
+                             preferred_element_type=jnp.float32)
+        else:
+            logits = jnp.einsum("...d,vd->...v", x, params["embed"],
+                                preferred_element_type=jnp.float32)
         return logits if multiplier is None else logits * multiplier

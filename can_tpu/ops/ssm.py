@@ -1,7 +1,8 @@
 """The selective state-space recurrence of a Mamba-2 mixer (Dao & Gu,
 "Transformers are SSMs", arXiv:2405.21060) and the causal depthwise
 convolution in front of it: ONE layer in two forms, as latent attention has
-two.
+two.  The convolution is also, between two gates, the whole mixer of an
+LFM2 ``conv`` layer (``gated_conv_causal`` / ``gated_conv_step``).
 
 Per head, with a state ``S`` of (P, N) numbers that starts from zero:
 
@@ -36,15 +37,15 @@ import jax.numpy as jnp
 # -- the convolution ----------------------------------------------------
 def conv1d_causal(x, w, b):
     """Depthwise causal convolution over whole prompts: ``x`` (B, L, C),
-    ``w`` (C, K), ``b`` (C,) -> ``y[t] = b + sum_j w[:, j] x[t - K + 1 + j]``
-    (zeros before position 0), in ``x``'s dtype."""
+    ``w`` (C, K), ``b`` (C,) or None -> ``y[t] = b + sum_j w[:, j] x[t - K +
+    1 + j]`` (zeros before position 0), in ``x``'s dtype."""
     k = w.shape[-1]
     l = x.shape[1]
     xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
     w32 = w.astype(jnp.float32)
-    y = b.astype(jnp.float32) + sum(xp[:, j:j + l] * w32[:, j]
-                                    for j in range(k))
-    return y.astype(x.dtype)
+    bias = None if b is None else b.astype(jnp.float32)
+    y = sum(xp[:, j:j + l] * w32[:, j] for j in range(k))
+    return (y if bias is None else bias + y).astype(x.dtype)
 
 
 def conv_tail(x, lengths, width: int):
@@ -60,11 +61,36 @@ def conv_tail(x, lengths, width: int):
 
 def conv1d_step(tail, x, w, b):
     """One position: ``tail`` (B, C, K - 1) the inputs before it, ``x``
-    (B, C) -> (y (B, C) in ``x``'s dtype, the tail moved on by one)."""
+    (B, C), ``b`` (C,) or None -> (y (B, C) in ``x``'s dtype, the tail moved
+    on by one)."""
     window = jnp.concatenate([tail.astype(x.dtype), x[..., None]], -1)
-    y = b.astype(jnp.float32) + jnp.sum(
-        window.astype(jnp.float32) * w.astype(jnp.float32), -1)
-    return y.astype(x.dtype), window[..., 1:].astype(tail.dtype)
+    bias = None if b is None else b.astype(jnp.float32)
+    y = jnp.sum(window.astype(jnp.float32) * w.astype(jnp.float32), -1)
+    return ((y if bias is None else bias + y).astype(x.dtype),
+            window[..., 1:].astype(tail.dtype))
+
+
+# -- the gated short convolution (LFM2) ---------------------------------
+# The whole mixer of an LFM2 ``conv`` layer between its two projections:
+# ``[B | C | X]`` (..., 3 C) the input projection's columns, ``u = B * X``,
+# ``v`` the convolution of ``u`` (no bias, no activation), ``y = C * v``.
+# What a decode step needs of a prompt is ``u``'s tail, not ``x``'s.
+def gated_conv_causal(bcx, w, lengths):
+    """Whole prompts: ``bcx`` (B, L, 3 C), ``w`` (C, K) -> (``C * conv(B *
+    X)`` (B, L, C), ``conv_tail`` of ``B * X`` at each prompt's own length
+    (B, C, K - 1))."""
+    gate_in, gate_out, x = jnp.split(bcx, 3, axis=-1)
+    u = gate_in * x
+    return (gate_out * conv1d_causal(u, w, None),
+            conv_tail(u, lengths, w.shape[-1]))
+
+
+def gated_conv_step(tail, bcx, w):
+    """One position: ``tail`` (B, C, K - 1), ``bcx`` (B, 3 C) -> (``C *
+    conv(B * X)`` (B, C), the tail moved on by one)."""
+    gate_in, gate_out, x = jnp.split(bcx, 3, axis=-1)
+    v, tail = conv1d_step(tail, gate_in * x, w, None)
+    return gate_out * v, tail
 
 
 # -- the recurrence -----------------------------------------------------

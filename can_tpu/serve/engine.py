@@ -419,6 +419,9 @@ class LMEngine:
         # the form its trace ran the state-space recurrence in, where the
         # model has one (``LMPrograms.ssm_traced``)
         self.ssm_forms: Dict[tuple, str] = {}
+        # the same of the gated short convolution, where the model has one
+        # (``LMPrograms.conv_traced``)
+        self.conv_forms: Dict[tuple, str] = {}
         # counters (the batcher thread writes, stats() reads a copy)
         self.counters = {"launches": 0, "generated_tokens": 0,
                          "prompt_tokens": 0, "decode_steps": 0,
@@ -515,6 +518,9 @@ class LMEngine:
                 ssm = _one_form(self.ssm_forms, ran)
                 if ssm is not None:
                     sp.attrs["ssm"] = ssm
+                conv = _one_form(self.conv_forms, ran)
+                if conv is not None:
+                    sp.attrs["conv"] = conv
             probes = {"prefill": {"logits": pre["logits"],
                                   "choices": pre["choices"]}}
             keep = set(lm_probe_steps(steps))
@@ -534,6 +540,8 @@ class LMEngine:
                 sp.attrs["compiled"] = compiled
                 if (slots, 1) in self.ssm_forms:
                     sp.attrs["ssm"] = self.ssm_forms[(slots, 1)]
+                if (slots, 1) in self.conv_forms:
+                    sp.attrs["conv"] = self.conv_forms[(slots, 1)]
                 # (expert layers, held): no rows in a model without experts
                 expert_layers, held = pre["counts"].shape
                 if expert_layers:
@@ -571,7 +579,8 @@ class LMEngine:
             return
         for noted, forms in ((self.programs.attention_traced,
                               self.prefill_attention),
-                             (self.programs.ssm_traced, self.ssm_forms)):
+                             (self.programs.ssm_traced, self.ssm_forms),
+                             (self.programs.conv_traced, self.conv_forms)):
             form = noted(program) if noted is not None else None
             if form is not None:
                 forms[program] = form

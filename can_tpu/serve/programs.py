@@ -82,8 +82,9 @@ class LMPrograms:
       other: its counters read zero, its answers' routing is empty;
     * ``cfg.vocab`` (``lm_blocks.VocabSlice``): ids and logits are over the
       slice held;
-    * optionally ``model.attention_traced`` / ``model.ssm_traced``: (B, L) of
-      a program's tokens -> the form its newest trace ran that layer in;
+    * optionally ``model.attention_traced`` / ``model.ssm_traced`` /
+      ``model.conv_traced``: (B, L) of a program's tokens -> the form its
+      newest trace ran that layer in;
     * a model with an expert layer: ``model.experts_form(cfg, tokens, dtype)``
       -> the form its expert layers take for a program of that many tokens
       (``ops.moe.share_form``).  In the ``"skipping"`` form its ``routing``
@@ -117,6 +118,8 @@ class LMPrograms:
         # "step" / a kernel's name, as its newest trace ran the state-space
         # recurrence; None for a model without one
         self.ssm_traced = getattr(model, "ssm_traced", None)
+        # the same of a gated short convolution: "causal" / "step"
+        self.conv_traced = getattr(model, "conv_traced", None)
         self._experts_form = getattr(model, "experts_form", None)
         self.vocab_size = cfg.vocab.held   # ids and logits are over the slice
         # {a name on a compiled instruction's path: the part of the model it
@@ -246,6 +249,12 @@ def _falcon_h1():
     return falcon_h1, falcon_h1.FalconH1Config
 
 
+def _lfm2_moe():
+    from can_tpu.models import lfm2_moe
+
+    return lfm2_moe, lfm2_moe.Lfm2MoeConfig
+
+
 def _lm_engine(params, programs, config: dict, telemetry):
     from can_tpu.serve.engine import LMEngine
 
@@ -266,6 +275,8 @@ MODEL_TYPES = {
                                   _generate_service),
     "falcon_h1": ServingModel(_lm_programs(_falcon_h1), _lm_engine,
                               _generate_service),
+    "lfm2_moe": ServingModel(_lm_programs(_lfm2_moe), _lm_engine,
+                             _generate_service),
 }
 
 

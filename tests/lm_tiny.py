@@ -110,6 +110,39 @@ def tiny_falcon_model(seed=0, dtype=jnp.float32, **kw):
     return d, cfg, fh.init_params(jax.random.key(seed), cfg, dtype)
 
 
+def tiny_lfm2_config(*, held=16, rank=0, layer_types=None, **assumed) -> dict:
+    """The tiny LFM2-MoE preset, as a configuration-file dict: both kinds of
+    mixer (a gated short convolution of width 3, grouped-query attention of
+    4 heads of 16 with q/k norm) in the published order ``c c A c c c A c``,
+    both leading dense layers, 6 expert layers of 16 experts (top-4, a bias
+    on the choice, NO shared expert), a tied head over 512 ids; ``held`` of
+    the 16 experts live on rank ``rank``."""
+    kinds = list(layer_types or ["conv", "conv", "full_attention", "conv",
+                                 "conv", "conv", "full_attention", "conv"])
+    return {
+        "model_type": "lfm2_moe", "conv_L_cache": 3, "conv_bias": False,
+        "hidden_size": 64, "intermediate_size": 160, "layer_types": kinds,
+        "max_position_embeddings": 128000, "moe_intermediate_size": 32,
+        "norm_eps": 1e-5, "norm_topk_prob": True, "num_attention_heads": 4,
+        "num_dense_layers": 2, "num_experts": held, "num_experts_per_tok": 4,
+        "num_hidden_layers": len(kinds), "num_key_value_heads": 2,
+        "rope_parameters": {"rope_theta": 1000000, "rope_type": "default"},
+        "routed_scaling_factor": 1, "use_expert_bias": True,
+        "vocab_size": 512, "published": {"num_experts": 16},
+        "deployment": {"rank": rank},
+        "assumed": assumed,
+    }
+
+
+def tiny_lfm2_model(seed=0, dtype=jnp.float32, **kw):
+    """-> (config dict, Lfm2MoeConfig, params)."""
+    from can_tpu.models import lfm2_moe as lm
+
+    d = tiny_lfm2_config(**kw)
+    cfg = lm.Lfm2MoeConfig.from_dict(d)
+    return d, cfg, lm.init_params(jax.random.key(seed), cfg, dtype)
+
+
 def interpret_skipping_experts(monkeypatch) -> None:
     """The skipping experts kernel (``ops/pallas_experts.py``) interpreted
     wherever its shapes fit: what a TPU backend turns on, steered here as

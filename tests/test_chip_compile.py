@@ -224,10 +224,11 @@ def test_write_slot_and_decode_leave_a_donated_cache_where_it_is(
 
 # -- the language model's serving programs at the published widths --------
 def _lm_programs_and_shapes(v5e, slots, part,
-                            name="k-exaone-ep8-serve-bf16"):
+                            name="k-exaone-ep8-serve-bf16", edit=None):
     """``serve/programs.py::LMPrograms`` of the benchmark's configuration
-    ``name``, its parameters, one launch's cache of ``slots`` and a prefill
-    slice of ``part`` prompts, as shapes on one described device."""
+    ``name`` (``edit``: a change to the file's dict first), its parameters,
+    one launch's cache of ``slots`` and a prefill slice of ``part`` prompts,
+    as shapes on one described device."""
     import json
 
     from can_tpu.serve.programs import LMPrograms
@@ -235,6 +236,8 @@ def _lm_programs_and_shapes(v5e, slots, part,
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "configs", name + ".json")) as f:
         config = json.load(f)
+    if edit is not None:
+        edit(config)
     if config["model_type"] == "exaone_moe":
         from can_tpu.models import exaone_moe as em
 
@@ -243,6 +246,10 @@ def _lm_programs_and_shapes(v5e, slots, part,
         from can_tpu.models import falcon_h1 as em
 
         cfg = em.FalconH1Config.from_dict(config)
+    elif config["model_type"] == "lfm2_moe":
+        from can_tpu.models import lfm2_moe as em
+
+        cfg = em.Lfm2MoeConfig.from_dict(config)
     else:
         from can_tpu.models import glm_moe_lite as em
 
@@ -478,6 +485,76 @@ def test_falcon_h1_prefill_slice_compiles_for_one_device(v5e):
     assert "128,128]" in text                     # a chunk's decay matrix
     assert "trip_count=1024" not in text and '"n":"1024"' not in text
     assert 12 * 2**30 < _fits_hbm(compiled) < 15.5 * 2**30
+
+
+# -- the short-convolution / attention model at the published widths --------
+LFM2 = "lfm2-24b-a2b-ep8-serve-bf16"
+
+
+def _lfm2_depth(layers):
+    """The cell's file cut to its first ``layers`` layers (6: both dense
+    layers and one period ``A c c c``): a compile's size, not a cell."""
+    def edit(config):
+        config["num_hidden_layers"] = layers
+        config["layer_types"] = config["layer_types"][:layers]
+    return edit
+
+
+def _lfm2_programs(v5e, layers):
+    """-> (programs, decode's compiled program, prefill slice's, the cache's
+    shapes) at the published widths, 64 slots, slices of 8."""
+    programs, params, cache, batch, shape = _lm_programs_and_shapes(
+        v5e, 64, 8, LFM2, _lfm2_depth(layers))
+    sparse = layers - 2
+    state = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(lambda: programs.new_state(
+            [{"first": jnp.zeros((64,), jnp.int32),
+              "logits": jnp.zeros((64, 8), jnp.float32),
+              "choices": jnp.zeros((sparse, 64, 4), jnp.int32),
+              "counts": jnp.zeros((sparse, 8), jnp.int32)}],
+            jnp.ones((64,), jnp.int32), jnp.ones((64,), bool))[0]))
+    decode = jax.jit(programs.decode, donate_argnums=(1, 2)).lower(
+        params, state, cache).compile()
+    prefill = jax.jit(programs.prefill_slice, donate_argnums=(2,)).lower(
+        params, batch, cache, shape((), jnp.int32)).compile()
+    return programs, decode, prefill, cache
+
+
+def _lfm2_checks(programs, decode, prefill, cache, layers):
+    """What holds at any depth: the forms, the tails as stored, and the
+    NAMED DEBT of the 64-wide heads (PERF.md section 7): ``write_slot`` on a
+    leaf narrower than the 128 lanes keeps 2 whole-leaf copies a step (into
+    ``{2,3,1,0}`` and back), 4 an attention layer; a prefill slice, which
+    writes whole rows, keeps none."""
+    attention = len([s for s in programs.cache_layout if s.kind == "full"])
+    text = decode.as_text()
+    assert "ragged-dot" not in text and "ragged-dot" in prefill.as_text()
+    assert "bf16[64,2048,2]" in text                 # a tail, as stored
+    assert _cache_copies(decode, programs, cache) == {
+        "bf16[64,8,1280,64]": 4 * attention}
+    assert _cache_copies(prefill, programs, cache) == {"bf16[64,8,1280,64]": 0}
+    held = 64 * (1280 * 2048 * attention + 8192 * (layers - attention))
+    assert decode.memory_analysis().alias_size_in_bytes >= held
+
+
+def test_lfm2_decode_and_prefill_compile_on_a_six_layer_pattern(v5e):
+    """Both dense layers and one period (``c c A c c c``) at the published
+    widths, 8 of 64 experts held: decode's experts batched, the prefill's
+    sorted, one attention layer's keys and values copied 4 times a step."""
+    programs, decode, prefill, cache = _lfm2_programs(v5e, 6)
+    _lfm2_checks(programs, decode, prefill, cache, 6)
+    assert _fits_hbm(decode) < 2 * 2**30 and _fits_hbm(prefill) < 2.5 * 2**30
+
+
+@pytest.mark.slow
+def test_lfm2_decode_and_prefill_compile_at_full_depth(v5e):
+    """All 40 layers, as the cell runs them (25 s here): 9.2 GB of weights
+    and cache as arguments, 0.3 / 0.6 GB of temporaries, 40 whole-leaf
+    copies a decode step."""
+    programs, decode, prefill, cache = _lfm2_programs(v5e, 40)
+    _lfm2_checks(programs, decode, prefill, cache, 40)
+    assert 9e9 < _fits_hbm(decode) < _fits_hbm(prefill) < 11e9
 
 
 # -- the fused prefill attention (ops/pallas_attention.py) ------------------

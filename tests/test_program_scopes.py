@@ -10,7 +10,8 @@ import jax.numpy as jnp
 import pytest
 
 import lm_tiny
-from can_tpu.models import exaone_moe, falcon_h1, glm_moe_lite, lm_blocks
+from can_tpu.models import (exaone_moe, falcon_h1, glm_moe_lite, lfm2_moe,
+                            lm_blocks)
 from can_tpu.obs import spans as recorder
 from can_tpu.obs.trace import (cache_copies, hlo_type, part_of,
                                program_scopes, scope_map)
@@ -176,17 +177,19 @@ class TestTheMap:
                                          "add.40", "copy.31"}
 
     def test_the_vocabulary_is_the_issue_s(self):
-        assert len(PARTS) == len(set(PARTS)) == 16
+        # sixteen of ISSUE 35, and ISSUE 38's three of the short convolution
+        assert len(PARTS) == len(set(PARTS)) == 19
         assert {p.split(".")[0] for p in PARTS} == {
-            "embed", "attn", "moe", "dense_mlp", "ssm", "head", "sample",
-            "routing"}
+            "embed", "attn", "moe", "dense_mlp", "ssm", "conv", "head",
+            "sample", "routing"}
         assert set(lm_blocks.RENAMED_BY_COMPILER.values()) <= set(PARTS)
 
 
-# -- the three tiny models ---------------------------------------------------
+# -- the four tiny models ----------------------------------------------------
 MODELS = {"k-exaone": (exaone_moe, lambda: lm_tiny.tiny_model(mtp=0)),
           "glm": (glm_moe_lite, lambda: lm_tiny.tiny_glm_model(mtp=0)),
-          "falcon-h1": (falcon_h1, lm_tiny.tiny_falcon_model)}
+          "falcon-h1": (falcon_h1, lm_tiny.tiny_falcon_model),
+          "lfm2": (lfm2_moe, lm_tiny.tiny_lfm2_model)}
 SLOTS, PART, BUCKET = 4, 2, 16
 
 
@@ -235,6 +238,7 @@ def test_every_traced_instruction_of_a_tiny_model_has_a_part(tiny, program):
     families = {p.split(".")[0] for p in got["parts"].values() if p}
     assert {"attn", "head", "sample", "embed", "dense_mlp"} <= families
     assert ("ssm" in families) == (name == "falcon-h1")
+    assert ("conv" in families) == (name == "lfm2")
     assert ("moe" in families) == ("routing" in families) == (name != "falcon-h1")
     assert got["unscoped"] <= 3 and got["unscoped"] < 0.03 * got["instructions"]
     assert set(got["parts"].values()) <= set(PARTS) | {None}
