@@ -39,7 +39,10 @@ A launch's cache (``ops/cache_layout.py``) holds ONE kind a layer, and which
 depends on the layer: a ``conv`` layer a ``state`` without positions (``u``
 at the last ``conv_L_cache - 1`` positions of each sequence's OWN length,
 (slots, d, K - 1) in the cache's dtype: a copy of activations, nothing
-accumulates), a ``full_attention`` layer ``full`` keys and values.
+accumulates), a ``full_attention`` layer ``full`` keys and values, the heads
+of 64 two to a row of 128 lanes (``ops/cache_layout.py::kv_pack``: the
+prefill hands its entry over in that shape, ``write_slot`` and ``decode``
+read it from the leaf's).
 
 What the published config leaves open is ONE choice each, named in
 ``ASSUMED`` (a configuration file states them under ``assumed``;
@@ -191,16 +194,22 @@ def init_params(key, cfg: Lfm2MoeConfig, dtype=jnp.bfloat16):
     return init_from_shapes(key, param_shapes(cfg), dtype)
 
 
+def _kv_spec(cfg: Lfm2MoeConfig) -> layout.LayerSpec:
+    """A ``full_attention`` layer's keys and values: heads of 64 lie two to
+    a row of 128 lanes (``layout.kv_pack``)."""
+    return layout.kv_layer(layout.FULL, kv_heads=cfg.num_key_value_heads,
+                           head_dim=cfg.head_dim)
+
+
 def cache_layout(cfg: Lfm2MoeConfig) -> tuple:
     """What each held layer keeps in a launch's cache
     (``ops/cache_layout.py``), ONE kind a layer: a ``conv`` layer the
     convolution's last ``conv_L_cache - 1`` inputs (no positions, the
     cache's dtype), a ``full_attention`` layer keys and values of every
-    position."""
+    position, in rows of whole lanes."""
     tail = layout.state_layer(
         conv=((cfg.hidden_size, cfg.conv_L_cache - 1), None))
-    full = layout.kv_layer(layout.FULL, kv_heads=cfg.num_key_value_heads,
-                           head_dim=cfg.head_dim)
+    full = _kv_spec(cfg)
     return tuple(tail if kind == CONV else full for kind in cfg.layer_types)
 
 
@@ -275,9 +284,9 @@ def _attention_prefill(layer, x, positions, cfg, cache_len):
     if cache_len is None:
         return h, None
     with jax.named_scope("attn.cache"):
-        pad = ((0, 0), (0, 0), (0, cache_len - l), (0, 0))
-        return h, {"k": jnp.pad(k.transpose(0, 2, 1, 3), pad),
-                   "v": jnp.pad(v.transpose(0, 2, 1, 3), pad)}
+        shapes = _kv_spec(cfg).shapes(b, cache_len)
+        return h, {"k": attn_ops.as_leaf(k, shapes["k"]),
+                   "v": attn_ops.as_leaf(v, shapes["v"])}
 
 
 def prefill_hidden(params, tokens, lengths, cfg: Lfm2MoeConfig,

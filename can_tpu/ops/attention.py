@@ -14,7 +14,12 @@ before), and for a full layer forms it a block of queries at a time, each
 against the keys up to its own end.  Decode reads a cache laid out
 (B, KV, S, D): the whole context for a full layer, a ring of ``window``
 slots for a window layer, in which slot ``r`` holds the newest position
-``p <= pos`` with ``p % window == r``.
+``p <= pos`` with ``p % window == r``.  A position's row is a whole number
+of the 128 lanes: heads narrower than that lie ``pack`` side by side in one
+((B, KV / pack, S, pack * D), ``ops/cache_layout.py::kv_pack``), because a
+donated leaf whose last axis is under a lane row is not written in place
+(two whole-leaf copies a write); ``write_slot``, ``as_leaf`` and ``decode``
+read ``pack`` from the shapes they are given, 1 for every head of 128.
 
 Latent attention keeps (B, S, rank) latents and (B, S, rope_dim) rotary keys,
 no heads.  Its prefill rebuilds keys and values per head and runs a causal
@@ -114,13 +119,33 @@ def prefill_window(q, k, v, *, window: int):
 
 def decode(q, k_cache, v_cache, valid):
     """One query per sequence against its cache.  ``q`` (B, KV, G, D);
-    caches (B, KV, S, D); ``valid`` (B, S): which slots hold a position
-    this query may see."""
-    d = q.shape[-1]
+    caches (B, KV / pack, S, pack * D), ``pack`` heads side by side in a
+    row (``cache_layout.kv_pack``; read here from the shapes: 1 for heads
+    of whole lanes); ``valid`` (B, S): which slots hold a position this
+    query may see.
+
+    Over packed rows the group axis takes the queries of all the row's
+    heads (``pack * G`` a row), each zero outside its own head's ``D``
+    lanes, and each keeps its own head's ``D`` lanes of the result.  Exact:
+    the other heads' lanes add ``key * 0`` to a float32 score and their
+    half of the values is dropped; ``pack`` times the arithmetic of two
+    small products, in a step the cache's bytes bound."""
+    b, kv, g, d = q.shape
+    rows = k_cache.shape[1]
+    pack = kv // rows
+    if pack > 1:
+        own = jnp.eye(pack, dtype=q.dtype)     # head p of a row: lanes p
+        q = jnp.einsum("brpgd,pl->brpgld", q.reshape(b, rows, pack, g, d),
+                       own).reshape(b, rows, pack * g, pack * d)
     s = jnp.einsum("bkgd,bksd->bkgs", q, k_cache,
                    preferred_element_type=jnp.float32) * d ** -0.5
-    return _softmax_av(s, valid[:, None, None, :], v_cache,
-                       "bkgs,bksd->bkgd", q.dtype)
+    o = _softmax_av(s, valid[:, None, None, :], v_cache,
+                    "bkgs,bksd->bkgd", q.dtype)
+    if pack > 1:
+        o = jnp.einsum("brpgld,pl->brpgd",
+                       o.reshape(b, rows, pack, g, pack, d),
+                       own).reshape(b, kv, g, d)
+    return o
 
 
 def ring_positions(pos, window: int):
@@ -130,9 +155,23 @@ def ring_positions(pos, window: int):
     return pos[:, None] - jnp.mod(pos[:, None] - r, window)
 
 
+def as_leaf(x, shape):
+    """A prompt's keys or values ``x`` (B, L, KV, D) as a cache leaf of
+    ``shape`` (B, rows, positions, width) (``LayerSpec.shapes``): the heads
+    of a row side by side as ``write_slot`` writes them, the positions past
+    ``L`` zero."""
+    b, rows, positions, width = shape
+    l = x.shape[1]
+    x = x.reshape(b, l, rows, width).transpose(0, 2, 1, 3)
+    return jnp.pad(x, ((0, 0), (0, 0), (0, positions - l), (0, 0)))
+
+
 def write_slot(cache, new, slot):
     """``cache`` (B, KV, S, D) with ``new`` (B, KV, D) written at ``slot``
-    (B,), one slot per sequence.
+    (B,), one slot per sequence.  Over packed rows (``cache`` (B, KV /
+    pack, S, pack * D), ``cache_layout.kv_pack``) ``new`` is the same
+    (B, KV, D): the heads of a row are adjacent in it, so the reshape to the
+    cache's rows below places them side by side and moves nothing.
 
     The rows are written by a scatter over the MERGED (sequence x head)
     axis, one indexed axis in front of the positions.  Indexed as

@@ -1,12 +1,23 @@
 """How one layer's entry of a decoding cache is laid out: the description a
 model gives of each of its layers (``cache_layout(cfg)``) and
 ``serve/cache.py`` allocates from.  It sits beside ``ops/attention.py``,
-whose writes and reads (``write_slot``, ``write_row``, ``decode``,
-``decode_latent``) assume these leaves; the models and the serving path
-both import it, and neither imports the other for it.
+whose writes and reads (``write_slot``, ``as_leaf``, ``write_row``,
+``decode``, ``decode_latent``) assume these leaves; the models and the
+serving path both import it, and neither imports the other for it.
 
 * ``full``: keys and values of every position of the context,
-  ``{"k", "v"}`` of (slots, kv_heads, positions, head_dim);
+  ``{"k", "v"}`` of (slots, rows, positions, width).  **A position's row is
+  a whole number of lanes:** heads of 128 are a row each (rows = kv_heads,
+  width = head_dim); heads narrower than the 128 lanes lie ``pack = 128 //
+  head_dim`` side by side, heads ``pack * j .. pack * j + pack - 1`` in row
+  ``j`` (rows = kv_heads / pack, width = pack * head_dim: the key
+  projection's own output (B, L, kv_heads * head_dim) reshaped, no data
+  moves).  ``kv_pack`` says how many: 1 unless ``head_dim`` divides 128 and
+  ``pack`` divides ``kv_heads``.  Why: XLA:TPU hands a leaf whose last axis
+  is under a lane row on with the POSITIONS minor, so a donated leaf of
+  64-wide heads was copied whole into another layout and whole back around
+  every one-row write (PERF.md section 6, PR 39); a leaf of whole lane rows
+  is written where it lies.  The bytes are the same;
 * ``ring`` (window attention): the newest ``window`` positions, slot
   ``p % window`` for position ``p``, the same two leaves with ``window``
   positions;
@@ -36,6 +47,7 @@ from __future__ import annotations
 from typing import Dict, NamedTuple, Optional, Tuple
 
 FULL, RING, LATENT, STATE = "full", "ring", "latent", "state"
+_LANES = 128   # the minor axis of a TPU tile: a row a leaf is stored in
 
 
 class LayerSpec(NamedTuple):
@@ -75,14 +87,26 @@ def positioned_leaves(specs, cache: dict) -> list:
             for name, _, _ in spec.leaves]
 
 
+def kv_pack(kv_heads: int, head_dim: int) -> int:
+    """How many heads share a row of a key/value leaf: as many as fill the
+    128 lanes, where ``head_dim`` divides them and the heads come out in
+    whole rows; else 1 (whole lane rows or none: no partial packing)."""
+    pack = _LANES // int(head_dim)
+    whole = pack * int(head_dim) == _LANES and int(kv_heads) % pack == 0
+    return pack if whole else 1
+
+
 def kv_layer(kind: str, *, kv_heads: int, head_dim: int,
              window: Optional[int] = None) -> LayerSpec:
-    """Keys and values per head: ``full``, or ``ring`` over ``window``."""
+    """Keys and values per head: ``full``, or ``ring`` over ``window``; a
+    position's row holds ``kv_pack`` heads side by side."""
     if kind not in (FULL, RING):
         raise ValueError(f"unknown cache layer kind {kind!r}")
     if kind == RING and not window:
         raise ValueError("a ring layer needs its window")
-    leaves = tuple((n, (int(kv_heads),), int(head_dim)) for n in ("k", "v"))
+    pack = kv_pack(kv_heads, head_dim)
+    leaves = tuple((n, (int(kv_heads) // pack,), pack * int(head_dim))
+                   for n in ("k", "v"))
     return LayerSpec(kind, leaves, int(window) if kind == RING else None)
 
 

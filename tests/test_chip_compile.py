@@ -191,22 +191,29 @@ def _plain_write_slot(cache, new, slot):
         new.astype(cache.dtype))
 
 
-@pytest.mark.parametrize("shape,write,copies", [
-    ((64, 8, 1280, 128), None, 0), ((64, 8, 128, 128), None, 0),
-    ((64, 4, 1280, 128), None, 0), ((64, 4, 1280, 128), _plain_write_slot, 2),
+@pytest.mark.parametrize("shape,heads,write,copies", [
+    ((64, 8, 1280, 128), 8, None, 0), ((64, 8, 128, 128), 8, None, 0),
+    ((64, 4, 1280, 128), 4, None, 0),
+    ((64, 4, 1280, 128), 4, _plain_write_slot, 2),
+    ((64, 4, 1280, 128), 8, None, 0), ((64, 8, 1280, 64), 8, None, 2),
 ], ids=["k-exaone-full", "k-exaone-ring", "falcon-h1-full",
-        "falcon-h1-full-plain-indexed"])
+        "falcon-h1-full-plain-indexed", "lfm2-full-packed",
+        "lfm2-full-a-head-a-row"])
 def test_write_slot_and_decode_leave_a_donated_cache_where_it_is(
-        v5e, shape, write, copies):
+        v5e, shape, heads, write, copies):
     """One row written into a donated leaf of the cells' shapes and the leaf
     read by ``decode``: the merged scatter compiles to no copy of the leaf;
     the plain indexed write it replaced, kept here as the record of the
-    cause, to one copy into ``{3,1,2,0}`` and one back."""
+    cause, to one copy into ``{3,1,2,0}`` and one back.  LFM2's ``heads``
+    of 64 two to a row of 128 lanes (PR 39) likewise to none; a head a row,
+    as they were stored before and kept here as the record of THAT cause,
+    to one copy into ``{2,3,1,0}`` and one back whatever the write."""
     from can_tpu.obs.trace import cache_copies
     from can_tpu.ops import attention
 
     write = write or attention.write_slot
     b, kv, s, d = shape
+    head = kv * d // heads
     one = SingleDeviceSharding(v5e[0])
     arr = lambda sh, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
         sh, dt, sharding=one)
@@ -216,7 +223,7 @@ def test_write_slot_and_decode_leave_a_donated_cache_where_it_is(
         return cache, attention.decode(q, cache, cache, jnp.ones((b, s), bool))
 
     compiled = jax.jit(step, donate_argnums=(0,)).lower(
-        arr(shape), arr((b, kv, 2, d)), arr((b, kv, d)),
+        arr(shape), arr((b, heads, 2, head)), arr((b, heads, head)),
         arr((b,), jnp.int32)).compile()
     assert cache_copies(compiled.as_text(), [arr(shape)]) == copies
     assert compiled.memory_analysis().alias_size_in_bytes == b * kv * s * d * 2
@@ -521,19 +528,27 @@ def _lfm2_programs(v5e, layers):
     return programs, decode, prefill, cache
 
 
-def _lfm2_checks(programs, decode, prefill, cache, layers):
+def _lfm2_checks(programs, decode, prefill, cache, layers, through_vmem=0):
     """What holds at any depth: the forms, the tails as stored, and the
-    NAMED DEBT of the 64-wide heads (PERF.md section 7): ``write_slot`` on a
-    leaf narrower than the 128 lanes keeps 2 whole-leaf copies a step (into
-    ``{2,3,1,0}`` and back), 4 an attention layer; a prefill slice, which
-    writes whole rows, keeps none."""
+    keys and values of the 64-wide heads two to a row of 128 lanes
+    (``cache_layout.kv_pack``, PR 39), written in place by a decode step and
+    by a prefill slice: a head a row, the step kept 2 whole-leaf copies a
+    leaf (into ``{2,3,1,0}`` and back), 4 an attention layer (the toy case
+    ``lfm2-full-a-head-a-row`` above is the record).  ``through_vmem``: the
+    leaves the compiler's own memory assignment still moves whole, a NAMED
+    DEBT of another kind that ``cache_copies`` does not count (PERF.md
+    section 7): sliced into the chip's fast memory (``S(1)``), written and
+    read there, and sent back to HBM by one ``copy-start`` of the merged
+    leaf ``bf16[256,1280,128]``."""
     attention = len([s for s in programs.cache_layout if s.kind == "full"])
     text = decode.as_text()
     assert "ragged-dot" not in text and "ragged-dot" in prefill.as_text()
     assert "bf16[64,2048,2]" in text                 # a tail, as stored
-    assert _cache_copies(decode, programs, cache) == {
-        "bf16[64,8,1280,64]": 4 * attention}
-    assert _cache_copies(prefill, programs, cache) == {"bf16[64,8,1280,64]": 0}
+    assert _cache_copies(decode, programs, cache) == {"bf16[64,4,1280,128]": 0}
+    assert _cache_copies(prefill, programs, cache) == {
+        "bf16[64,4,1280,128]": 0}
+    assert len([line for line in text.splitlines() if " copy-start(" in line
+                and "= (bf16[256,1280,128]" in line]) == through_vmem
     held = 64 * (1280 * 2048 * attention + 8192 * (layers - attention))
     assert decode.memory_analysis().alias_size_in_bytes >= held
 
@@ -541,7 +556,7 @@ def _lfm2_checks(programs, decode, prefill, cache, layers):
 def test_lfm2_decode_and_prefill_compile_on_a_six_layer_pattern(v5e):
     """Both dense layers and one period (``c c A c c c``) at the published
     widths, 8 of 64 experts held: decode's experts batched, the prefill's
-    sorted, one attention layer's keys and values copied 4 times a step."""
+    sorted, the attention layer's keys and values written where they lie."""
     programs, decode, prefill, cache = _lfm2_programs(v5e, 6)
     _lfm2_checks(programs, decode, prefill, cache, 6)
     assert _fits_hbm(decode) < 2 * 2**30 and _fits_hbm(prefill) < 2.5 * 2**30
@@ -550,10 +565,11 @@ def test_lfm2_decode_and_prefill_compile_on_a_six_layer_pattern(v5e):
 @pytest.mark.slow
 def test_lfm2_decode_and_prefill_compile_at_full_depth(v5e):
     """All 40 layers, as the cell runs them (25 s here): 9.2 GB of weights
-    and cache as arguments, 0.3 / 0.6 GB of temporaries, 40 whole-leaf
-    copies a decode step."""
+    and cache as arguments, 0.3 / 0.6 GB of temporaries, no copy of a
+    cache leaf in either program (40 a decode step before PR 39); the keys
+    of nine attention layers pass through the fast memory and back."""
     programs, decode, prefill, cache = _lfm2_programs(v5e, 40)
-    _lfm2_checks(programs, decode, prefill, cache, 40)
+    _lfm2_checks(programs, decode, prefill, cache, 40, through_vmem=9)
     assert 9e9 < _fits_hbm(decode) < _fits_hbm(prefill) < 11e9
 
 

@@ -20,6 +20,8 @@ from benchmark.reference import lfm2_moe_ref as ref
 from can_tpu.models import lfm2_moe as lm
 from can_tpu.models import lm_blocks
 from can_tpu.obs import Telemetry, spans
+from can_tpu.ops import attention as attn_ops
+from can_tpu.ops import cache_layout as layout
 from can_tpu.ops import moe as moe_ops
 from can_tpu.ops import ssm as ssm_ops
 from can_tpu.serve import GenerateService, build_model_service, lm_probe_steps
@@ -78,18 +80,29 @@ def test_the_tiny_preset_has_every_mechanism(tiny):
     assert params["layers"][2]["moe"]["bias"].dtype == jnp.float32
 
 
-@pytest.mark.parametrize("held,rank", [(16, 0), (2, 3)], ids=["all", "share"])
-def test_prefill_then_12_decode_steps_match_the_reference(held, rank):
+@pytest.mark.parametrize("held,rank,head_dim,pack", [
+    (16, 0, 16, 1), (2, 3, 16, 1), (16, 0, 64, 2),
+], ids=["all", "share", "heads-of-64-two-to-a-row"])
+def test_prefill_then_12_decode_steps_match_the_reference(held, rank, head_dim,
+                                                          pack):
     """Prompts of unequal length right-padded into one bucket, then 12
     greedy steps through the cache (keys, values and the convolution's
     tails), against the reference's ONE full forward over prompt +
     generated tokens: logits at every position; with every expert held and
-    with rank 3's two of sixteen."""
-    d, cfg, params = tiny_lfm2_model(seed=5, held=held, rank=rank)
+    with rank 3's two of sixteen.  The preset's 2 key/value heads of 16
+    never share a row (``kv_pack`` 1): the third case gives them the cell's
+    width of 64, so the keys and values it writes and reads are packed two
+    to a row, and says so before it compares."""
+    d, cfg, params = tiny_lfm2_model(seed=5, held=held, rank=rank,
+                                     head_dim=head_dim)
+    assert cfg.head_dim == head_dim
+    assert layout.kv_pack(cfg.num_key_value_heads, cfg.head_dim) == pack
     spec = ref.spec_from_config(d)
     prompts = [_tokens(21, 6), _tokens(9, 7), _tokens(32, 8)]
     toks, lengths = _padded(prompts, 32)
     logits, cache, routing = PREFILL(params, toks, lengths, cfg, 32 + NEW)
+    assert cache["layers"][2]["k"].shape == (3, 2 // pack, 32 + NEW,
+                                             pack * head_dim)
     assert routing["counts"].shape == (6, held)
     assert routing["choices"].shape == (6, 3, 4)
     got, seqs = [np.asarray(logits)], [list(p) for p in prompts]
@@ -302,6 +315,124 @@ def test_rows_returns_the_rows_asked_for(tiny):
     assert [c.shape for c in some["chosen"]] == [(2, 4)] * 6
 
 
+# -- keys and values in rows of whole lanes --------------------------------
+@pytest.mark.parametrize("kv_heads,head_dim,pack", [
+    (8, 64, 2), (8, 128, 1), (8, 32, 4), (8, 96, 1), (3, 64, 1), (2, 16, 1),
+    (4, 256, 1),
+], ids=["lfm2-64", "whole-lanes-128", "four-of-32", "96-divides-no-row",
+        "3-heads-are-no-whole-rows", "the-tiny-presets", "wider-than-a-row"])
+def test_a_leaf_s_row_is_whole_lanes_or_one_head(kv_heads, head_dim, pack):
+    """``kv_layer``: heads that fill a row of 128 lanes exactly lie ``pack``
+    side by side, in whole rows or not at all; every other head keeps a row
+    of its own.  The bytes are the same either way."""
+    assert layout.kv_pack(kv_heads, head_dim) == pack
+    for kind, window, held in (("full", None, 40), ("ring", 8, 8)):
+        spec = layout.kv_layer(kind, kv_heads=kv_heads, head_dim=head_dim,
+                               window=window)
+        assert spec.shapes(5, 40) == {
+            n: (5, kv_heads // pack, held, pack * head_dim) for n in "kv"}
+
+
+def _a_head_a_row(leaf, pack):
+    """A packed leaf (B, rows, S, pack * D) as (B, rows * pack, S, D)."""
+    b, rows, s, width = leaf.shape
+    return leaf.reshape(b, rows, s, pack, width // pack).transpose(
+        0, 1, 3, 2, 4).reshape(b, rows * pack, s, width // pack)
+
+
+@pytest.mark.parametrize("late", [False, True], ids=["sound", "late_write"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("kv_heads,head_dim,window", [
+    (8, 64, None), (4, 64, 16), (8, 32, None),
+], ids=["full-two-to-a-row", "ring-two-to-a-row", "full-four-to-a-row"])
+def test_packed_write_and_decode_equal_a_head_a_row(
+        kv_heads, head_dim, window, dtype, late):
+    """``write_slot`` then ``decode`` over a leaf of packed rows against the
+    same two over a leaf with a row a head, on the same random keys, values,
+    queries, slots and ``valid``: the leaves equal row for row, and the
+    outputs BIT FOR BIT in float32 (the other heads' lanes meet zeros in
+    the score and are dropped from the values).  In bfloat16 the CPU's
+    product sums a row of 128 in another order than a row of 64 (the float32
+    scores differ by 4e-6), so one output in a thousand rounds to the
+    neighbouring bfloat16: equal but for those, each within one step.  A
+    ring of packed heads too, and under ``calibrate_lm.late_write``, which
+    patches ``write_slot`` by its signature and wraps the late slot at
+    ``cache.shape[2]``."""
+    from benchmark.tools import calibrate_lm
+
+    assert list(inspect.signature(attn_ops.write_slot).parameters) == [
+        "cache", "new", "slot"]
+    b, g, s = 5, 4, window or 48
+    pack = layout.kv_pack(kv_heads, head_dim)
+    assert pack == 128 // head_dim
+    ks = jax.random.split(jax.random.key(kv_heads + head_dim), 7)
+    packed = (b, kv_heads // pack, s, pack * head_dim)
+    kc, vc = (jax.random.normal(k, packed).astype(dtype) for k in ks[:2])
+    q = jax.random.normal(ks[2], (b, kv_heads, g, head_dim)).astype(dtype)
+    nk, nv = (jax.random.normal(k, (b, kv_heads, head_dim)).astype(dtype)
+              for k in ks[3:5])
+    pos = jax.random.randint(ks[5], (b,), 0, 3 * s)
+    if window:
+        slot = pos % window
+        valid = attn_ops.ring_positions(pos, window) >= 0
+    else:
+        slot = pos % s
+        valid = jnp.arange(s)[None] <= slot[:, None]
+    valid &= jax.random.bernoulli(ks[6], 0.8, (b, s))
+
+    @jax.jit
+    def step(kc, vc):
+        kc = attn_ops.write_slot(kc, nk, slot)
+        vc = attn_ops.write_slot(vc, nv, slot)
+        return kc, vc, attn_ops.decode(q, kc, vc, valid)
+
+    if late:
+        calibrate_lm.late_write(None)
+    try:
+        k2, v2, got = step(kc, vc)
+        k1, v1, want = step(_a_head_a_row(kc, pack), _a_head_a_row(vc, pack))
+    finally:
+        if late:
+            calibrate_lm.late_write.undo()
+    assert k2.shape == packed and k1.shape == (b, kv_heads, s, head_dim)
+    written = np.asarray(k1, np.float32)[np.arange(b), :,
+                                         (np.asarray(slot) + late) % s]
+    np.testing.assert_array_equal(written, np.asarray(nk, np.float32))
+    for mine, plain in ((k2, k1), (v2, v1)):
+        np.testing.assert_array_equal(
+            np.asarray(_a_head_a_row(mine, pack), np.float32),
+            np.asarray(plain, np.float32))
+    assert got.shape == want.shape == q.shape and got.dtype == dtype
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    if dtype == jnp.float32:
+        np.testing.assert_array_equal(got, want)
+    else:
+        assert (got != want).mean() < 0.01
+        np.testing.assert_allclose(got, want, rtol=2 ** -7, atol=2 ** -9)
+    assert np.abs(got).max() > 0.1
+
+
+@pytest.mark.parametrize("kv_heads,head_dim", [(8, 64), (2, 16), (4, 128)],
+                         ids=["two-to-a-row", "tiny", "whole-lanes"])
+def test_a_prefill_s_entry_is_write_slot_position_by_position(kv_heads,
+                                                              head_dim):
+    """``as_leaf`` (``_attention_prefill``'s cache entry) places a prompt's
+    keys where ``write_slot`` would have written them one position at a
+    time, in the shape ``kv_layer`` states; the positions behind are zero."""
+    b, l, s = 3, 7, 12
+    shape = layout.kv_layer("full", kv_heads=kv_heads,
+                            head_dim=head_dim).shapes(b, s)["k"]
+    k = jax.random.normal(jax.random.key(head_dim), (b, l, kv_heads, head_dim))
+    want = jnp.zeros(shape)
+    for p in range(l):
+        want = attn_ops.write_slot(want, k[:, p], jnp.full((b,), p))
+    got = attn_ops.as_leaf(k, shape)
+    assert got.shape == shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert (np.asarray(got)[:, :, l:] == 0).all() and np.asarray(got).any()
+
+
 # -- the cell's file ------------------------------------------------------
 def test_param_count_and_cache_of_the_cell_s_file():
     """The builder's own reckoning (the configuration's ``reduced_how``): 30
@@ -321,7 +452,7 @@ def test_param_count_and_cache_of_the_cell_s_file():
     assert kv_cache.nbytes_by_kind(cache, layout) == {
         "state": 64 * 245_760, "full": 64 * 1280 * 20_480}
     assert cache["layers"][0]["conv"].shape == (64, 2048, 2)
-    assert cache["layers"][2]["k"].shape == (64, 8, 1280, 64)
+    assert cache["layers"][2]["k"].shape == (64, 4, 1280, 128)   # 8 x 64
     assert cfg.share == moe_ops.ExpertShare(0, 8, 64) and cfg.head_dim == 64
     assert lm_blocks.experts_form(cfg, 64, jnp.bfloat16) == "batched"
     assert lm_blocks.experts_form(cfg, 8192, jnp.bfloat16) == "sorted"
