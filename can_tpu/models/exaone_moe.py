@@ -36,7 +36,7 @@ import jax.numpy as jnp
 from can_tpu.models.lm_blocks import experts_form  # noqa: F401  (the serving path asks the model for it)
 from can_tpu.models.lm_blocks import (VocabSlice, count_shapes, embed,
                                       init_from_shapes, last_hidden, lm_head,
-                                      rms_norm, routing_report)
+                                      qkv_heads, rms_norm, routing_report)
 from can_tpu.models.lm_blocks import ffn as _ffn
 from can_tpu.ops import attention as attn_ops
 from can_tpu.ops import cache_layout as layout
@@ -185,11 +185,8 @@ def cache_layout(cfg: ExaoneMoeConfig) -> tuple:
 # -- layers -------------------------------------------------------------
 def _qkv(p, x, positions, layer_type, cfg: ExaoneMoeConfig):
     """``x`` (B, L, d) -> q (B, L, KV, G, D), k, v (B, L, KV, D)."""
-    b, l, _ = x.shape
-    kv, g, hd = cfg.num_key_value_heads, cfg.groups, cfg.head_dim
-    q = jnp.dot(x, p["wq"]).reshape(b, l, kv, g, hd)
-    k = jnp.dot(x, p["wk"]).reshape(b, l, kv, hd)
-    v = jnp.dot(x, p["wv"]).reshape(b, l, kv, hd)
+    q, k, v = qkv_heads(p, x, cfg.num_key_value_heads, cfg.groups,
+                        cfg.head_dim)
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
@@ -223,14 +220,10 @@ def _prefill_block(layer, layer_type, x, positions, cfg,
     entry = None
     if cache_len is not None:
         with jax.named_scope("attn.cache"):
-            kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
             if layer_type == WINDOW:
-                # slot r <- the newest position p < length with p % W == r
-                held = attn_ops.ring_positions(lengths - 1, cfg.sliding_window)
-                take = jnp.clip(held, 0, l - 1)[:, None, :, None]
-                entry = {"k": jnp.take_along_axis(kt, take, axis=2),
-                         "v": jnp.take_along_axis(vt, take, axis=2)}
+                entry = attn_ops.ring_entry(k, v, lengths, cfg.sliding_window)
             else:
+                kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
                 pad = ((0, 0), (0, 0), (0, cache_len - l), (0, 0))
                 entry = {"k": jnp.pad(kt, pad), "v": jnp.pad(vt, pad)}
     y, chosen = ffn(layer, h, cfg)

@@ -61,7 +61,7 @@ import jax.numpy as jnp
 from can_tpu.models.lm_blocks import experts_form  # noqa: F401  (the serving path asks the model for it)
 from can_tpu.models.lm_blocks import (VocabSlice, count_shapes, embed, ffn,
                                       init_from_shapes, last_hidden, lm_head,
-                                      rms_norm, routing_report)
+                                      qkv_heads, rms_norm, routing_report)
 from can_tpu.ops import attention as attn_ops
 from can_tpu.ops import cache_layout as layout
 from can_tpu.ops import ssm as ssm_ops
@@ -228,11 +228,8 @@ def conv_traced(tokens_shape) -> Optional[str]:
 # -- layers -------------------------------------------------------------
 def _qkv(p, x, positions, cfg: Lfm2MoeConfig):
     """``x`` (B, L, d) -> q (B, L, KV, G, D), k, v (B, L, KV, D)."""
-    b, l, _ = x.shape
-    kv, g, hd = cfg.num_key_value_heads, cfg.groups, cfg.head_dim
-    q = jnp.dot(x, p["wq"]).reshape(b, l, kv, g, hd)
-    k = jnp.dot(x, p["wk"]).reshape(b, l, kv, hd)
-    v = jnp.dot(x, p["wv"]).reshape(b, l, kv, hd)
+    q, k, v = qkv_heads(p, x, cfg.num_key_value_heads, cfg.groups,
+                        cfg.head_dim)
     q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
     k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
     return (attn_ops.rope(q, positions, cfg.rope_theta),

@@ -2,8 +2,9 @@
 functions over a parameter tree: the norm, SwiGLU, the expert layer with
 its shared expert, the feed-forward half of a block, the head, the routing
 report a serving program returns, and the seeded initialiser.  A model's
-module (``exaone_moe.py``, ``glm_moe_lite.py``, ``lfm2_moe.py``) brings its
-own mixers and its own config class; the config offers ``rms_norm_eps``
+module (``exaone_moe.py``, ``glm_moe_lite.py``, ``lfm2_moe.py``,
+``mimo_v2_flash.py``) brings its own mixers and its own config class (the
+grouped-query ones share ``qkv_heads``, the three projections by head); the config offers ``rms_norm_eps``
 and, where the model has an expert layer, ``num_experts_per_tok``, ``routed_scaling_factor``,
 ``norm_topk_prob`` and ``share`` (``ops.moe.ExpertShare``).  An expert layer
 has a shared expert where its parameters hold one (``shared``; LFM2's hold
@@ -28,7 +29,7 @@ from can_tpu.ops import moe as moe_ops
 from can_tpu.ops.moe import ExpertShare
 
 
-# The parts of a language model, ONE vocabulary for the four models: every
+# The parts of a language model, ONE vocabulary for the five models: every
 # ``jax.named_scope`` that the serving programs pass through (the models,
 # ``ops/moe.py``, ``serve/programs.py``) is one of these names, whole (the MTP
 # modules' ``mtp``, outside those programs, wraps them).  A scope is metadata: it names no op and adds
@@ -42,6 +43,7 @@ PARTS = (
     "attn.proj",     # input norm, query / key-value / latent projections, rotary, GLM's q_nope x W_uk
     "attn.cache",    # the row written into the cache; a prefill slice's rows placed into the launch's cache
     "attn.core",     # scores, softmax, values: against the cache (decode) or over the prompt (prefill)
+    "attn.window",   # the same of a WINDOW layer (the ring; two blocks of a prompt; its sink), where a model opens it inside attn.core: attn.core is then its full layers' alone
     "attn.out",      # GLM's W_uv, ``wo``, the residual
     "moe.router",    # post-norm, router product, top-k, the weights by held expert
     "moe.dispatch",  # the sorted form's sort, gather, scatter and combine (no other form has any)
@@ -87,7 +89,7 @@ def _leaf(key, name: str, shape, dtype):
         return (1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
     if name == "bias":      # the router's correction bias: a float32 buffer
         return 0.05 * jax.random.normal(key, shape, jnp.float32)
-    if name == "embed":
+    if name in ("embed", "sink"):   # a sink: one logit a head; its model moves the mean
         return jax.random.normal(key, shape, dtype)
     if name == "conv_w":    # (channels, taps): N(0, 1 / taps)
         return (jax.random.normal(key, shape, jnp.float32)
@@ -100,9 +102,10 @@ def init_from_shapes(key, shapes: dict, dtype=jnp.bfloat16):
     """Parameters from a key for a tree of shapes, leaf by leaf on the
     device (one jitted call a leaf: no float32 copy of the whole tree is
     ever alive).  By the leaf's name: ``ln_*`` / ``*_norm`` norms near one,
-    ``bias`` a float32 buffer, ``embed`` N(0, 1), ``conv_w`` a depthwise
-    convolution N(0, 1 / taps), every other a projection N(0, 1 / fan_in)
-    so that activations stay of order one."""
+    ``bias`` a float32 buffer, ``embed`` and ``sink`` N(0, 1) (the model that
+    has sinks moves their mean: ``mimo_v2_flash.init_params``), ``conv_w`` a
+    depthwise convolution N(0, 1 / taps), every other a projection N(0, 1 /
+    fan_in) so that activations stay of order one."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=lambda x: isinstance(x, tuple))
     make = jax.jit(_leaf, static_argnums=(1, 2, 3))
@@ -133,6 +136,19 @@ def swiglu(x, p, multipliers=None):
     gate = (jnp.dot(x, p["gate"]).astype(jnp.float32) * m_gate).astype(x.dtype)
     y = jnp.dot(jax.nn.silu(gate) * jnp.dot(x, p["up"]), p["down"])
     return (y.astype(jnp.float32) * m_down).astype(x.dtype)
+
+
+def qkv_heads(p, x, kv_heads: int, groups: int, head_dim: int,
+              v_head_dim: Optional[int] = None):
+    """Grouped-query projections: ``x`` (B, L, d) -> q (B, L, KV, G, D), k
+    (B, L, KV, D), v (B, L, KV, Dv), from ``p``'s ``wq``, ``wk``, ``wv``
+    (``Dv`` = ``D`` where the model gives the values no width of their
+    own).  Norms, rotary and scales are the model's."""
+    b, l, _ = x.shape
+    q = jnp.dot(x, p["wq"]).reshape(b, l, kv_heads, groups, head_dim)
+    k = jnp.dot(x, p["wk"]).reshape(b, l, kv_heads, head_dim)
+    v = jnp.dot(x, p["wv"]).reshape(b, l, kv_heads, v_head_dim or head_dim)
+    return q, k, v
 
 
 class Routed(NamedTuple):

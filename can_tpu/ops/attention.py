@@ -4,8 +4,11 @@ attention (one compressed key/value latent a position for all heads) at the
 end of the module.
 
 Shapes: ``q`` (B, L, KV, G, D) with G query heads to each of the KV
-key/value heads; ``k``, ``v`` (B, L, KV, D).  Scores and the softmax are
-float32 whatever the inputs are; the output has ``q``'s dtype.
+key/value heads; ``k`` (B, L, KV, D), ``v`` (B, L, KV, Dv): the values' width
+is their own (MiMo-V2-Flash: keys 192, values 128), the output's is ``Dv``.
+Scores and the softmax are float32 whatever the inputs are; the output has
+``q``'s dtype.  A layer may carry a learned SINK, one logit a query head
+that joins the softmax's denominator and adds no value (``_softmax_av``).
 
 Two kinds of layer: *full* (every earlier position) and *window*
 (``i - j < window``).  Prefill never forms an L x L score matrix for a window
@@ -15,11 +18,13 @@ against the keys up to its own end.  Decode reads a cache laid out
 (B, KV, S, D): the whole context for a full layer, a ring of ``window``
 slots for a window layer, in which slot ``r`` holds the newest position
 ``p <= pos`` with ``p % window == r``.  A position's row is a whole number
-of the 128 lanes: heads narrower than that lie ``pack`` side by side in one
-((B, KV / pack, S, pack * D), ``ops/cache_layout.py::kv_pack``), because a
-donated leaf whose last axis is under a lane row is not written in place
-(two whole-leaf copies a write); ``write_slot``, ``as_leaf`` and ``decode``
-read ``pack`` from the shapes they are given, 1 for every head of 128.
+of the 128 lanes: heads whose width is not lie ``pack`` side by side in one
+((B, KV / pack, S, pack * D), ``ops/cache_layout.py::kv_pack``: two heads of
+64 in 128 lanes, two of 192 in 384), because a donated leaf whose last axis
+is not whole lane rows is not written in place (two whole-leaf copies a
+write); ``write_slot``, ``as_leaf``, ``ring_entry`` and ``decode`` read
+``pack`` from the shapes they are given, keys and values each their own, 1
+for every head of 128.
 
 Latent attention keeps (B, S, rank) latents and (B, S, rope_dim) rotary keys,
 no heads.  Its prefill rebuilds keys and values per head and runs a causal
@@ -61,9 +66,20 @@ def rope(x, positions, theta: float):
     return (x32 * cos + rot * sin).astype(x.dtype)
 
 
-def _softmax_av(scores, mask, v, eq, dtype):
+def _softmax_av(scores, mask, v, eq, dtype, sink=None):
+    """``softmax(scores) v`` over the last axis of ``scores``.  ``sink``
+    (broadcast against ``scores`` with a last axis of 1): a learned logit
+    per head that joins the softmax's DENOMINATOR and nothing else (it takes
+    probability mass and adds no value): ``p_j = exp(s_j - m) / (sum_j'
+    exp(s_j' - m) + exp(sink - m))``, ``m = max(max_j s_j, sink)``."""
     scores = jnp.where(mask, scores, NEG)
-    p = jax.nn.softmax(scores, axis=-1)
+    if sink is None:
+        p = jax.nn.softmax(scores, axis=-1)
+    else:
+        sink = sink.astype(jnp.float32)
+        m = jnp.maximum(jnp.max(scores, axis=-1, keepdims=True), sink)
+        e = jnp.exp(scores - m)
+        p = e / (jnp.sum(e, axis=-1, keepdims=True) + jnp.exp(sink - m))
     return jnp.einsum(eq, p.astype(dtype), v,
                       preferred_element_type=jnp.float32).astype(dtype)
 
@@ -89,9 +105,11 @@ def prefill_full(q, k, v, *, block: int = 256):
     return jnp.concatenate(outs, axis=1)
 
 
-def prefill_window(q, k, v, *, window: int):
+def prefill_window(q, k, v, *, window: int, sink=None):
     """Causal attention with ``i - j < window``: blocks of ``window``
-    queries, each against its own block of keys and the one before."""
+    queries, each against its own block of keys and the one before.
+    ``sink`` (KV, G): a learned logit a query head in the softmax's
+    denominator (``_softmax_av``); None: a plain softmax."""
     b, l, kv, g, d = q.shape
     if l % window:
         raise ValueError(f"prompt bucket {l} is not a multiple of the "
@@ -100,7 +118,7 @@ def prefill_window(q, k, v, *, window: int):
     qb = q.reshape(b, nb, window, kv, g, d)
 
     def two_blocks(x):
-        xb = x.reshape(b, nb, window, kv, d)
+        xb = x.reshape(b, nb, window, kv, x.shape[-1])
         prev = jnp.pad(xb[:, :-1], ((0, 0), (1, 0), (0, 0), (0, 0), (0, 0)))
         return jnp.concatenate([prev, xb], axis=2)   # (B, nb, 2W, KV, D)
 
@@ -113,20 +131,24 @@ def prefill_window(q, k, v, *, window: int):
     first = mask & (kj >= window)                     # block 0 has no "before"
     mask = jnp.where((jnp.arange(nb) == 0)[:, None, None], first, mask)
     out = _softmax_av(s, mask[None, :, None, None], vv,
-                      "bnkgqs,bnskd->bnqkgd", q.dtype)
-    return out.reshape(b, l, kv, g, d)
+                      "bnkgqs,bnskd->bnqkgd", q.dtype,
+                      None if sink is None else sink[:, :, None, None])
+    return out.reshape(b, l, kv, g, v.shape[-1])
 
 
-def decode(q, k_cache, v_cache, valid):
+def decode(q, k_cache, v_cache, valid, sink=None):
     """One query per sequence against its cache.  ``q`` (B, KV, G, D);
-    caches (B, KV / pack, S, pack * D), ``pack`` heads side by side in a
-    row (``cache_layout.kv_pack``; read here from the shapes: 1 for heads
-    of whole lanes); ``valid`` (B, S): which slots hold a position this
-    query may see.
+    keys (B, KV / pack, S, pack * D), ``pack`` heads side by side in a row
+    (``cache_layout.kv_pack``; read here from the shapes: 1 for heads of
+    whole lanes); values the same with their OWN width ``Dv`` and their own
+    ``pack`` (keys 192 wide lie two to a row of 384, their values of 128 a
+    head a row); ``valid`` (B, S): which slots hold a position this query
+    may see; ``sink`` (KV, G): a learned logit a query head in the
+    softmax's denominator, or None.
 
     Over packed rows the group axis takes the queries of all the row's
     heads (``pack * G`` a row), each zero outside its own head's ``D``
-    lanes, and each keeps its own head's ``D`` lanes of the result.  Exact:
+    lanes, and each keeps its own head's ``Dv`` lanes of the result.  Exact:
     the other heads' lanes add ``key * 0`` to a float32 score and their
     half of the values is dropped; ``pack`` times the arithmetic of two
     small products, in a step the cache's bytes bound."""
@@ -139,12 +161,21 @@ def decode(q, k_cache, v_cache, valid):
                        own).reshape(b, rows, pack * g, pack * d)
     s = jnp.einsum("bkgd,bksd->bkgs", q, k_cache,
                    preferred_element_type=jnp.float32) * d ** -0.5
+    rows_v = v_cache.shape[1]
+    pack_v = kv // rows_v
+    dv = v_cache.shape[-1] // pack_v
+    # the scores by the VALUES' rows (head = row * pack + p on both sides:
+    # nothing moves; the same shape where keys and values pack alike)
+    s = s.reshape(b, rows_v, pack_v * g, s.shape[-1])
+    if sink is not None:
+        sink = sink.reshape(rows_v, pack_v * g, 1)
     o = _softmax_av(s, valid[:, None, None, :], v_cache,
-                    "bkgs,bksd->bkgd", q.dtype)
-    if pack > 1:
+                    "bkgs,bksd->bkgd", q.dtype, sink)
+    if pack_v > 1:
+        own_v = own if pack_v == pack else jnp.eye(pack_v, dtype=q.dtype)
         o = jnp.einsum("brpgld,pl->brpgd",
-                       o.reshape(b, rows, pack, g, pack, d),
-                       own).reshape(b, kv, g, d)
+                       o.reshape(b, rows_v, pack_v, g, pack_v, dv),
+                       own_v).reshape(b, kv, g, dv)
     return o
 
 
@@ -164,6 +195,24 @@ def as_leaf(x, shape):
     l = x.shape[1]
     x = x.reshape(b, l, rows, width).transpose(0, 2, 1, 3)
     return jnp.pad(x, ((0, 0), (0, 0), (0, positions - l), (0, 0)))
+
+
+def ring_entry(k, v, lengths, window: int, shapes=None) -> dict:
+    """A prompt's keys and values (B, L, KV, D / Dv) as a window layer's
+    ring: slot ``r`` takes the newest position ``p < length`` with ``p %
+    window == r`` (a slot no position has reached yet holds position 0's
+    row, which ``decode``'s ``valid`` never admits).  ``shapes``
+    (``LayerSpec.shapes``): the leaves' (B, rows, window, width), for heads
+    that lie side by side in a row; None: a head a row."""
+    b, l = k.shape[:2]
+    if shapes is not None:
+        k = k.reshape(b, l, *shapes["k"][1::2])
+        v = v.reshape(b, l, *shapes["v"][1::2])
+    kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+    held = ring_positions(lengths - 1, window)
+    take = jnp.clip(held, 0, l - 1)[:, None, :, None]
+    return {"k": jnp.take_along_axis(kt, take, axis=2),
+            "v": jnp.take_along_axis(vt, take, axis=2)}
 
 
 def write_slot(cache, new, slot):
@@ -195,12 +244,16 @@ def write_slot(cache, new, slot):
 def prefill_causal(q, k, v, lengths=None, *, scale=None, block: int = 1024):
     """Causal attention over whole prompts with a running softmax.
 
-    ``q``, ``k`` (B, L, H, D), ``v`` (B, L, H, Dv) -> (B, L, H, Dv).  One
+    ``q`` (B, L, H, D), ``k`` (B, L, KV, D), ``v`` (B, L, KV, Dv) -> (B, L,
+    H, Dv); query heads ``c * G .. c * G + G - 1`` read key/value head ``c``
+    (G = H / KV; latent attention rebuilds a key a head: KV = H).  One
     sequence at a time (``lax.map``); its queries in blocks of ``block``,
     each against the key blocks before it (no mask) and its own (the causal
     mask).  ``lengths`` (B,): blocks of queries wholly past a sequence's
     length are not computed and stay zero (no valid position reads them)."""
     b, l, h, d = q.shape
+    kv = k.shape[2]
+    g = h // kv
     block = min(block, l)
     if l % block:
         raise ValueError(f"prompt bucket {l} is not a multiple of the "
@@ -221,15 +274,28 @@ def prefill_causal(q, k, v, lengths=None, *, scale=None, block: int = 1024):
                 m, den, acc = carry
                 kb = jax.lax.dynamic_slice_in_dim(ks, j * block, block, 0)
                 vb = jax.lax.dynamic_slice_in_dim(vs, j * block, block, 0)
-                s = jnp.einsum("qhd,khd->hqk", qb, kb,
-                               preferred_element_type=jnp.float32) * scale
+                if g == 1:
+                    s = jnp.einsum("qhd,khd->hqk", qb, kb,
+                                   preferred_element_type=jnp.float32) * scale
+                else:   # a group's queries against the one key head they share
+                    s = jnp.einsum(
+                        "qcgd,kcd->cgqk", qb.reshape(block, kv, g, d), kb,
+                        preferred_element_type=jnp.float32,
+                    ).reshape(h, block, block) * scale
                 if mask is not None:
                     s = jnp.where(mask, s, NEG)
                 m_new = jnp.maximum(m, jnp.max(s, axis=-1))
                 p = jnp.exp(s - m_new[..., None])
                 fade = jnp.exp(m - m_new)
-                pv = jnp.einsum("hqk,khd->hqd", p.astype(vs.dtype), vb,
-                                preferred_element_type=jnp.float32)
+                if g == 1:
+                    pv = jnp.einsum("hqk,khd->hqd", p.astype(vs.dtype), vb,
+                                    preferred_element_type=jnp.float32)
+                else:
+                    pv = jnp.einsum(
+                        "cgqk,kcd->cgqd",
+                        p.astype(vs.dtype).reshape(kv, g, block, block), vb,
+                        preferred_element_type=jnp.float32,
+                    ).reshape(h, block, dv)
                 return (m_new, den * fade + jnp.sum(p, axis=-1),
                         acc * fade[..., None] + pv)
 

@@ -6,15 +6,17 @@ whose writes and reads (``write_slot``, ``as_leaf``, ``write_row``,
 serving path both import it, and neither imports the other for it.
 
 * ``full``: keys and values of every position of the context,
-  ``{"k", "v"}`` of (slots, rows, positions, width).  **A position's row is
-  a whole number of lanes:** heads of 128 are a row each (rows = kv_heads,
-  width = head_dim); heads narrower than the 128 lanes lie ``pack = 128 //
-  head_dim`` side by side, heads ``pack * j .. pack * j + pack - 1`` in row
+  ``{"k", "v"}`` of (slots, rows, positions, width), each leaf with its own
+  width (keys ``head_dim``, values ``v_head_dim`` where the model gives
+  one).  **A position's row is a whole number of lanes:** heads of 128 are
+  a row each (rows = kv_heads, width = head_dim); heads of another width lie
+  ``pack`` side by side, the FEWEST whose row is whole lanes (two of 64 in
+  128, two of 192 in 384), heads ``pack * j .. pack * j + pack - 1`` in row
   ``j`` (rows = kv_heads / pack, width = pack * head_dim: the key
   projection's own output (B, L, kv_heads * head_dim) reshaped, no data
-  moves).  ``kv_pack`` says how many: 1 unless ``head_dim`` divides 128 and
-  ``pack`` divides ``kv_heads``.  Why: XLA:TPU hands a leaf whose last axis
-  is under a lane row on with the POSITIONS minor, so a donated leaf of
+  moves, no lane is padding).  ``kv_pack`` says how many: 1 where ``pack``
+  does not divide ``kv_heads``.  Why: XLA:TPU hands a leaf whose last axis
+  is not whole lane rows on with the POSITIONS minor, so a donated leaf of
   64-wide heads was copied whole into another layout and whole back around
   every one-row write (PERF.md section 6, PR 39); a leaf of whole lane rows
   is written where it lies.  The bytes are the same;
@@ -44,6 +46,7 @@ section 6, PR 37).  ``positioned_leaves`` names the arrays this holds for.
 
 from __future__ import annotations
 
+import math
 from typing import Dict, NamedTuple, Optional, Tuple
 
 FULL, RING, LATENT, STATE = "full", "ring", "latent", "state"
@@ -88,25 +91,30 @@ def positioned_leaves(specs, cache: dict) -> list:
 
 
 def kv_pack(kv_heads: int, head_dim: int) -> int:
-    """How many heads share a row of a key/value leaf: as many as fill the
-    128 lanes, where ``head_dim`` divides them and the heads come out in
-    whole rows; else 1 (whole lane rows or none: no partial packing)."""
-    pack = _LANES // int(head_dim)
-    whole = pack * int(head_dim) == _LANES and int(kv_heads) % pack == 0
-    return pack if whole else 1
+    """How many heads share a row of a key/value leaf: the fewest whose row
+    is a whole number of the 128 lanes (1 of 128, 2 of 64 or of 192, 4 of
+    32), where the heads come out in whole rows; else 1 (whole lane rows or
+    none: no partial packing)."""
+    pack = _LANES // math.gcd(int(head_dim), _LANES)
+    return pack if int(kv_heads) % pack == 0 else 1
 
 
 def kv_layer(kind: str, *, kv_heads: int, head_dim: int,
-             window: Optional[int] = None) -> LayerSpec:
-    """Keys and values per head: ``full``, or ``ring`` over ``window``; a
-    position's row holds ``kv_pack`` heads side by side."""
+             window: Optional[int] = None,
+             v_head_dim: Optional[int] = None) -> LayerSpec:
+    """Keys and values per head: ``full``, or ``ring`` over ``window``; keys
+    ``head_dim`` wide, values ``v_head_dim`` (None: as the keys); a
+    position's row of either leaf holds ITS ``kv_pack`` heads side by
+    side."""
     if kind not in (FULL, RING):
         raise ValueError(f"unknown cache layer kind {kind!r}")
     if kind == RING and not window:
         raise ValueError("a ring layer needs its window")
-    pack = kv_pack(kv_heads, head_dim)
-    leaves = tuple((n, (int(kv_heads) // pack,), pack * int(head_dim))
-                   for n in ("k", "v"))
+    def leaf(name, width):
+        pack = kv_pack(kv_heads, width)
+        return name, (int(kv_heads) // pack,), pack * int(width)
+
+    leaves = (leaf("k", head_dim), leaf("v", v_head_dim or head_dim))
     return LayerSpec(kind, leaves, int(window) if kind == RING else None)
 
 

@@ -255,6 +255,12 @@ def _lfm2_moe():
     return lfm2_moe, lfm2_moe.Lfm2MoeConfig
 
 
+def _mimo_v2_flash():
+    from can_tpu.models import mimo_v2_flash
+
+    return mimo_v2_flash, mimo_v2_flash.MimoV2FlashConfig
+
+
 def _lm_engine(params, programs, config: dict, telemetry):
     from can_tpu.serve.engine import LMEngine
 
@@ -277,6 +283,8 @@ MODEL_TYPES = {
                               _generate_service),
     "lfm2_moe": ServingModel(_lm_programs(_lfm2_moe), _lm_engine,
                              _generate_service),
+    "mimo_v2_flash": ServingModel(_lm_programs(_mimo_v2_flash), _lm_engine,
+                                  _generate_service),
 }
 
 

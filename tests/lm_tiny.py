@@ -143,6 +143,53 @@ def tiny_lfm2_model(seed=0, dtype=jnp.float32, **kw):
     return d, cfg, lm.init_params(jax.random.key(seed), cfg, dtype)
 
 
+def tiny_mimo_config(*, held=32, rank=0, layers=7, **edits) -> dict:
+    """The tiny MiMo-V2-Flash preset, as a configuration-file dict: every
+    mechanism of the published model at sizes a CPU runs in milliseconds: 8
+    query heads; full layers of 2 key/value heads (theta 5e6), window layers
+    of 4 (theta 1e4, a window of 8, a learned sink); keys 24 wide of which
+    the first 8 rotary, values 16; a value scale; the published pattern's
+    first seven layers ``F S S S S F S`` with layer 0 dense; 32 experts
+    (top-4, a bias on the choice, NO shared expert); an untied head over 512
+    ids.  ``held`` of the 32 experts live on rank ``rank``; ``edits``
+    replace keys (``assumed=...`` among them)."""
+    d = {
+        "model_type": "mimo_v2_flash", "attention_value_scale": 0.707,
+        "hidden_act": "silu", "hidden_size": 64, "intermediate_size": 160,
+        "max_position_embeddings": 262144, "num_attention_heads": 8,
+        "head_dim": 24, "num_hidden_layers": layers, "num_key_value_heads": 2,
+        "layernorm_epsilon": 1e-5, "rope_theta": 5000000,
+        "tie_word_embeddings": False, "vocab_size": 512,
+        "partial_rotary_factor": 0.334, "sliding_window": 8,
+        "swa_rope_theta": 10000, "attention_bias": False, "v_head_dim": 16,
+        "hybrid_layer_pattern": [0, 1, 1, 1, 1, 0, 1, 1, 1, 1, 1, 0],
+        "add_swa_attention_sink_bias": True,
+        "add_full_attention_sink_bias": False, "sliding_window_size": 8,
+        "attention_chunk_size": 8,
+        "moe_layer_freq": [0] + [1] * 11, "moe_intermediate_size": 32,
+        "n_routed_experts": held, "n_shared_experts": None,
+        "num_experts_per_tok": 4, "norm_topk_prob": True,
+        "scoring_func": "sigmoid", "n_group": 1, "topk_group": 1,
+        "topk_method": "noaux_tc", "routed_scaling_factor": None,
+        "swa_num_attention_heads": 8, "swa_num_key_value_heads": 4,
+        "swa_head_dim": 24, "swa_v_head_dim": 16,
+        "published": {"n_routed_experts": 32, "vocab_size": 512},
+        "deployment": {"rank": rank},
+        "assumed": {},
+    }
+    d.update(edits)
+    return d
+
+
+def tiny_mimo_model(seed=0, dtype=jnp.float32, **kw):
+    """-> (config dict, MimoV2FlashConfig, params)."""
+    from can_tpu.models import mimo_v2_flash as mv
+
+    d = tiny_mimo_config(**kw)
+    cfg = mv.MimoV2FlashConfig.from_dict(d)
+    return d, cfg, mv.init_params(jax.random.key(seed), cfg, dtype)
+
+
 def interpret_skipping_experts(monkeypatch) -> None:
     """The skipping experts kernel (``ops/pallas_experts.py``) interpreted
     wherever its shapes fit: what a TPU backend turns on, steered here as

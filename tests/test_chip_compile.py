@@ -257,6 +257,10 @@ def _lm_programs_and_shapes(v5e, slots, part,
         from can_tpu.models import lfm2_moe as em
 
         cfg = em.Lfm2MoeConfig.from_dict(config)
+    elif config["model_type"] == "mimo_v2_flash":
+        from can_tpu.models import mimo_v2_flash as em
+
+        cfg = em.MimoV2FlashConfig.from_dict(config)
     else:
         from can_tpu.models import glm_moe_lite as em
 
@@ -571,6 +575,71 @@ def test_lfm2_decode_and_prefill_compile_at_full_depth(v5e):
     programs, decode, prefill, cache = _lfm2_programs(v5e, 40)
     _lfm2_checks(programs, decode, prefill, cache, 40, through_vmem=9)
     assert 9e9 < _fits_hbm(decode) < _fits_hbm(prefill) < 11e9
+
+
+# -- MiMo-V2-Flash: two kinds of attention layer, keys wider than values -----
+MIMO = "mimo-v2-flash-ep16-serve-bf16"
+
+
+def _mimo_programs(v5e, monkeypatch):
+    """-> (programs, decode's compiled program, prefill slice's, the cache's
+    shapes) at the cell's widths: all 7 held layers, 16 slots of 8,448
+    positions, slices of 4; decode's experts in the skipping form, as on a
+    TPU (``share_form`` asks the backend, which is the CPU here)."""
+    from can_tpu.ops import pallas_experts
+
+    monkeypatch.setattr(pallas_experts, "supports", functools.partial(
+        pallas_experts._fits, tile_f=pallas_experts.TILE_F))
+    programs, params, cache, batch, shape = _lm_programs_and_shapes(
+        v5e, 16, 4, MIMO)
+    assert programs.decode_experts(16) == "skipping"
+    state = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(lambda: programs.new_state(
+            [{"first": jnp.zeros((16,), jnp.int32),
+              "logits": jnp.zeros((16, 8), jnp.float32),
+              "choices": jnp.zeros((6, 16, 8), jnp.int32),
+              "counts": jnp.zeros((6, 16), jnp.int32)}],
+            jnp.ones((16,), jnp.int32), jnp.ones((16,), bool))[0]))
+    decode = jax.jit(programs.decode, donate_argnums=(1, 2)).lower(
+        params, state, cache).compile()
+    prefill = jax.jit(programs.prefill_slice, donate_argnums=(2,)).lower(
+        params, batch, cache, shape((), jnp.int32)).compile()
+    return programs, decode, prefill, cache
+
+
+# every positioned leaf of the cell's cache: a full layer's keys (4 heads,
+# two to a row of 384 lanes) and values (a head a row of 128), a window
+# layer's ring of 128 (8 heads)
+MIMO_LEAVES = ("bf16[16,2,8448,384]", "bf16[16,4,8448,128]",
+               "bf16[16,4,128,384]", "bf16[16,8,128,128]")
+
+
+def test_mimo_decode_and_prefill_compile_for_one_device(v5e, monkeypatch):
+    """The cell's two programs at the published widths and all 7 held layers
+    (2 full, 5 window; 16 of 256 experts held): both fit, decode's experts
+    skip, the prefill's are sorted, the full layers' prefill is the scanned
+    form (a ``while`` in the text), and **no leaf of the cache is copied
+    whole in either program**: keys 192 wide two heads to a row of 384 lanes
+    are written where they lie, as values of 128 are.  **The named debt, in
+    bytes:** the prefill slice needs 14.47 GB, 6.87 of them temporaries,
+    because the sorted expert form's buffer at a sixteenth holds ``T x 8`` =
+    262,144 rows of 4,096 a layer for 16,384 in use (2.1 GB each in bfloat16
+    for the gathered rows, the products and the rows gathered back; PERF.md
+    section 7, debt (2)): it fits the chip's 17.18 GB, a slice of 8 would
+    not."""
+    programs, decode, prefill, cache = _mimo_programs(v5e, monkeypatch)
+    text = decode.as_text()
+    assert "ragged-dot" not in text and "ragged-dot" in prefill.as_text()
+    assert "skipping_experts" in text
+    assert _cache_copies(decode, programs, cache) == dict.fromkeys(MIMO_LEAVES, 0)
+    assert _cache_copies(prefill, programs, cache) == dict.fromkeys(
+        MIMO_LEAVES, 0)
+    # the cache is donated and handed back: 16 x (8,448 x 5,120 + 3,276,800) B
+    assert decode.memory_analysis().alias_size_in_bytes >= 744_488_960
+    assert 7.5e9 < _fits_hbm(decode) < 8.5e9
+    assert 14e9 < _fits_hbm(prefill) < 15e9
+    assert "bf16[262144,4096]" in prefill.as_text()      # the debt's buffer
 
 
 # -- the fused prefill attention (ops/pallas_attention.py) ------------------

@@ -404,7 +404,12 @@ def test_share_apply_runs_the_form_chosen(form, monkeypatch):
 # commit 0227fb7 (PR 32).  A PR that means to change one of these programs
 # prints the new value with this test's failure and replaces it here: PR 37
 # replaced the two ``decode`` texts of K-EXAONE and Falcon-H1 (``write_slot``
-# as a scatter over the merged slot x head axis); the other four stand.
+# as a scatter over the merged slot x head axis); the other four stand.  PR 40
+# (which gave ``ops/attention.py``'s grouped-query functions values of their
+# own width, a sink and keys and values that pack apart, and moved the three
+# projections by head and the ring's prefill entry into shared functions)
+# added LFM2's two, lowered at its parent 7a2007b: a ``sink`` of None, ``dv ==
+# d`` and one ``pack`` for both leaves trace to what stood, in all four models.
 PROGRAM_TEXT = {
     ("k-exaone-ep8-serve-bf16", "decode"):
         "2c53b1638274f93f0d4919dc5558a2e6378313e37e545c1402c69c3c3a01403d",
@@ -418,6 +423,10 @@ PROGRAM_TEXT = {
         "9bafbae804c45ab05d80ff531d20944e158ad3719d5d49f1a1dfed610a8e1323",
     ("glm-4.7-flash-pp8-serve-bf16", "prefill_slice"):
         "e9f9fe50e6393695b08d2153b7db87cf10b93c4a3bad87fc68f1288515920756",
+    ("lfm2-24b-a2b-ep8-serve-bf16", "decode"):
+        "8c7d19f0bdfbfe4b323aedbd4a0fb791f1abf2ac18a6bd3daa2cc9ccd4439952",
+    ("lfm2-24b-a2b-ep8-serve-bf16", "prefill_slice"):
+        "0bbecfd4ba4a60d18ca3ca046bca32461b42da999a1fa36f77bba0c8eb624b54",
 }
 
 
@@ -435,6 +444,10 @@ def _program_text(name: str, program: str) -> str:
         from can_tpu.models import falcon_h1 as model
 
         cfg = model.FalconH1Config.from_dict(config)
+    elif config["model_type"] == "lfm2_moe":
+        from can_tpu.models import lfm2_moe as model
+
+        cfg = model.Lfm2MoeConfig.from_dict(config)
     else:
         from can_tpu.models import glm_moe_lite as model
 
@@ -474,9 +487,9 @@ def _program_text(name: str, program: str) -> str:
     if not (backend == "tpu" and name.startswith("glm"))])
 def test_a_serving_program_lowers_to_the_text_it_had(name, program, backend,
                                                      monkeypatch):
-    """K-EXAONE's and Falcon-H1's programs are the parent's, whatever the
-    backend says (K-EXAONE's shapes leave nothing to skip, Falcon-H1 has no
-    expert layer); GLM's are the parent's off a TPU."""
+    """K-EXAONE's, Falcon-H1's and LFM2's programs are the parent's, whatever
+    the backend says (K-EXAONE's and LFM2's shapes leave nothing to skip,
+    Falcon-H1 has no expert layer); GLM's are the parent's off a TPU."""
     import hashlib
 
     if jax.__version__ != "0.9.0":

@@ -317,9 +317,9 @@ def test_rows_returns_the_rows_asked_for(tiny):
 
 # -- keys and values in rows of whole lanes --------------------------------
 @pytest.mark.parametrize("kv_heads,head_dim,pack", [
-    (8, 64, 2), (8, 128, 1), (8, 32, 4), (8, 96, 1), (3, 64, 1), (2, 16, 1),
+    (8, 64, 2), (8, 128, 1), (8, 32, 4), (8, 96, 4), (3, 64, 1), (2, 16, 1),
     (4, 256, 1),
-], ids=["lfm2-64", "whole-lanes-128", "four-of-32", "96-divides-no-row",
+], ids=["lfm2-64", "whole-lanes-128", "four-of-32", "four-of-96-in-three-lane-rows",
         "3-heads-are-no-whole-rows", "the-tiny-presets", "wider-than-a-row"])
 def test_a_leaf_s_row_is_whole_lanes_or_one_head(kv_heads, head_dim, pack):
     """``kv_layer``: heads that fill a row of 128 lanes exactly lie ``pack``

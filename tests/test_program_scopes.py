@@ -11,7 +11,7 @@ import pytest
 
 import lm_tiny
 from can_tpu.models import (exaone_moe, falcon_h1, glm_moe_lite, lfm2_moe,
-                            lm_blocks)
+                            lm_blocks, mimo_v2_flash)
 from can_tpu.obs import spans as recorder
 from can_tpu.obs.trace import (cache_copies, hlo_type, part_of,
                                program_scopes, scope_map)
@@ -177,19 +177,21 @@ class TestTheMap:
                                          "add.40", "copy.31"}
 
     def test_the_vocabulary_is_the_issue_s(self):
-        # sixteen of ISSUE 35, and ISSUE 38's three of the short convolution
-        assert len(PARTS) == len(set(PARTS)) == 19
+        # sixteen of ISSUE 35, ISSUE 38's three of the short convolution, and
+        # ISSUE 40's ``attn.window``
+        assert len(PARTS) == len(set(PARTS)) == 20
         assert {p.split(".")[0] for p in PARTS} == {
             "embed", "attn", "moe", "dense_mlp", "ssm", "conv", "head",
             "sample", "routing"}
         assert set(lm_blocks.RENAMED_BY_COMPILER.values()) <= set(PARTS)
 
 
-# -- the four tiny models ----------------------------------------------------
+# -- the five tiny models ----------------------------------------------------
 MODELS = {"k-exaone": (exaone_moe, lambda: lm_tiny.tiny_model(mtp=0)),
           "glm": (glm_moe_lite, lambda: lm_tiny.tiny_glm_model(mtp=0)),
           "falcon-h1": (falcon_h1, lm_tiny.tiny_falcon_model),
-          "lfm2": (lfm2_moe, lm_tiny.tiny_lfm2_model)}
+          "lfm2": (lfm2_moe, lm_tiny.tiny_lfm2_model),
+          "mimo": (mimo_v2_flash, lm_tiny.tiny_mimo_model)}
 SLOTS, PART, BUCKET = 4, 2, 16
 
 
@@ -239,6 +241,11 @@ def test_every_traced_instruction_of_a_tiny_model_has_a_part(tiny, program):
     assert {"attn", "head", "sample", "embed", "dense_mlp"} <= families
     assert ("ssm" in families) == (name == "falcon-h1")
     assert ("conv" in families) == (name == "lfm2")
+    # a window layer's core is a part of its own in the one model that opens
+    # it (inside ``attn.core``, which is then the full layers' alone);
+    # K-EXAONE's window layers stay ``attn.core``
+    assert ("attn.window" in got["parts"].values()) == (name == "mimo")
+    assert "attn.core" in got["parts"].values()
     assert ("moe" in families) == ("routing" in families) == (name != "falcon-h1")
     assert got["unscoped"] <= 3 and got["unscoped"] < 0.03 * got["instructions"]
     assert set(got["parts"].values()) <= set(PARTS) | {None}
