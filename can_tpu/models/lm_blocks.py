@@ -155,10 +155,12 @@ class Routed(NamedTuple):
     """What an expert layer says of one call: ``idx`` the experts each token
     chose (..., k), ``read`` the number of held experts whose weights the
     call read, () int32, where its form counts them, else None (every held
-    expert; ``ops.moe.share_apply``)."""
+    expert), ``passes`` the passes it took over its buffer, () int32, where
+    its form has one, else None (``ops.moe.share_apply``)."""
 
     idx: jax.Array
     read: Optional[jax.Array]
+    passes: Optional[jax.Array]
 
 
 def experts_form(cfg, tokens: int, dtype) -> str:
@@ -178,11 +180,12 @@ def expert_layer(p, x, cfg):
                                top_k=cfg.num_experts_per_tok,
                                scale=cfg.routed_scaling_factor,
                                normalize=cfg.norm_topk_prob)
-    routed, read = moe_ops.share_apply(x, idx, w, p["experts"], cfg.share)
+    routed, read, passes = moe_ops.share_apply(x, idx, w, p["experts"],
+                                               cfg.share)
     if "shared" not in p:
-        return routed, Routed(idx, read)
+        return routed, Routed(idx, read, passes)
     with jax.named_scope("moe.shared"):
-        return routed + swiglu(x, p["shared"]), Routed(idx, read)
+        return routed + swiglu(x, p["shared"]), Routed(idx, read, passes)
 
 
 def ffn(layer, h, cfg, *, norm: bool = True):
@@ -208,8 +211,9 @@ def routing_report(chosen, mask, pick, cfg) -> dict:
     chosen at position ``pick`` (B,) of each sequence; where the layers'
     form counts the experts it read (``Routed.read``), also
     ``experts_read`` () int32, their sum over the layers (every row of the
-    batch reads, marked or not).  ``chosen``: each layer's ``Routed``, None
-    for a dense layer."""
+    batch reads, marked or not), and where it counts its passes
+    (``Routed.passes``), ``dispatch_passes`` () int32, theirs.  ``chosen``:
+    each layer's ``Routed``, None for a dense layer."""
     chosen = [c for c in chosen if c is not None]
     if not chosen:
         return {"counts": jnp.zeros((0, cfg.share.held), jnp.int32),
@@ -224,6 +228,9 @@ def routing_report(chosen, mask, pick, cfg) -> dict:
         read = [c.read for c in chosen if c.read is not None]
         if read:
             report["experts_read"] = sum(read)
+        passes = [c.passes for c in chosen if c.passes is not None]
+        if passes:
+            report["dispatch_passes"] = sum(passes)
         return report
 
 

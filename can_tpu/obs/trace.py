@@ -41,6 +41,9 @@ _RESULT = re.compile(r"(\w+\[[\d,]*\])")   # right after the ``name = ``
 _INLINED = {"fusion": re.compile(r"\bcalls=%?([\w.\-]+)"),
             "custom-call": re.compile(r"called_computations=\{([^}]*)\}")}
 _TO_APPLY = re.compile(r"\bto_apply=%?([\w.\-]+)")
+# a loop: the instruction, and the two computations it runs
+_LOOP = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = [^\n]*? while\([^\n]*?"
+                   r"condition=%?([\w.\-]+), body=%?([\w.\-]+)", re.M)
 # instructions that never run as a device op of their own
 _NO_OP = frozenset({"parameter", "constant", "tuple", "get-tuple-element",
                     "bitcast"})
@@ -119,7 +122,9 @@ def program_scopes(hlo_text: str, parts) -> dict:
     and that took the part of the FIRST op that uses their result, through
     tuples and bitcasts (the wait for an operand is charged to the op that
     needs it), or, where nothing uses it (the program's output), of the op
-    that made their operand."""
+    that made their operand, or, where that has none either and they run
+    in a loop's body or condition (the compiler's copies of a loop's carried
+    state between memory spaces), of the ``while`` that runs them."""
     rows, inlined = _instructions(hlo_text)
     # tuples, bitcasts and the like have no part of their own: they hand on
     # their neighbours'
@@ -152,6 +157,13 @@ def program_scopes(hlo_text: str, parts) -> dict:
     found = {inst: own[inst] or down[inst] or up[inst]
              for comp, inst, opcode, _, _, _ in rows
              if comp not in inlined and opcode not in _NO_OP}
+    # callers come after their computations in the text: the outermost loop
+    # first, so that a loop without a part of its own has its caller's
+    ran_by = {comp: loop for loop, *ran in _LOOP.findall(hlo_text)
+              for comp in ran}
+    for comp, inst, _, _, _, _ in reversed(rows):
+        if found.get(inst, "") is None and comp in ran_by:
+            found[inst] = found.get(ran_by[comp])
     return {"parts": found, "instructions": len(found),
             "unscoped": sum(p is None for p in found.values()),
             "inherited": [i for i, p in found.items()

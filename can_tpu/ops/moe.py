@@ -11,10 +11,17 @@ chosen by ``share_form`` from the (static) shapes and the backend:
   held expert are put in expert order (a counting sort: no comparison sort
   over T x k keys), one grouped matrix product per projection runs over the
   sorted rows (``jax.lax.ragged_dot``, which XLA:TPU lowers to its own
-  grouped-matmul kernel and XLA:CPU to a plain loop), and every token
-  gathers its own rows back with its routing weights.  The sorted buffer has
-  room for every assignment that CAN land here, ``T * min(top_k, held)``
-  rows, and the grouped product visits only the rows in use;
+  grouped-matmul kernel and XLA:CPU to a plain loop), and every row's
+  output, times its routing weight, is added to its token's sum.  The
+  sorted buffer holds a BOUND on the rows in use, ``sorted_rows``: the
+  ``T * top_k * held / total`` rows that even routing lands here times
+  ``SORTED_MARGIN``, in whole tiles of the grouped kernel, never more than
+  the ``T * min(top_k, held)`` assignments that CAN land here.  Rows past
+  the bound, if the routing sends any, take further passes over the same
+  buffer (a ``while`` over the sorted order), and each call says how many
+  passes it took.  Where the bound is the whole, as with every expert
+  held, there is one pass and no loop, and every token gathers its rows
+  back instead (over all the rows the cheaper combine);
 * few tokens (a decode step: ``T <= DENSE_MAX_TOKENS``), ``"batched"``:
   every held expert takes every token in one batched product and the
   routing weights (zero where a token did not choose the expert) sum the
@@ -46,6 +53,8 @@ held expert are the router's, ``moe.router``.
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import NamedTuple
 
 import jax
@@ -85,6 +94,40 @@ DENSE_MAX_TOKENS = 512
 # the spread of either form.  The kernel's tile of ``f`` (``TILE_F``), at
 # T = 16: 256 1.066, 512 1.057, 768 1.063, 1536 (whole) 1.054: no matter
 SKIP_MIN_IDLE = 0.05
+
+# the sorted form's buffer holds this many times the rows that even routing
+# lands on the held experts (``sorted_rows``); a call whose routing lands
+# more takes a further pass.  Timed on the v5e, one layer and prefill slice,
+# bfloat16 (``benchmark/tools/expert_prefill_forms.py``; my chip run, PR 43),
+# ms a call under a seeded router of the cells' kind (its rows in use over
+# the even share) | under one whose bias lifts the held experts; (2): the
+# call took two passes:
+#   8 of 64 held, 2048 x 1536, top-4, 8,192 tokens (LFM2; 1.52 | 1.99)
+#     T x min(k, held) rows, gathered back (PR 40's form)  5.05 | 5.25
+#     the same 32,768 rows, added to the sums               5.78 | 5.97
+#     margin 1.25 (5,120 rows) 4.02 (2) | 4.20 (2);  1.5 (6,144) 4.15 (2) |
+#     4.32 (2);  2 (8,192) 3.41 | 3.60
+#   16 of 256 held, 4096 x 2048, top-8, 32,768 tokens (MiMo; 1.26 | 1.96)
+#     262,144 rows gathered back 60.84 | 65.10;  added 94.35 | 98.63
+#     1.25 (20,480) 31.83 (2) | 35.98 (2);  1.5 (24,576) 25.49 | 38.00 (2);
+#     2 (32,768) 28.08 | 32.40
+#   16 of 128 held, 6144 x 2048, top-8, 8,192 tokens (K-EXAONE; 1.15 | 1.57)
+#     65,536 rows gathered back 22.26 | 24.18;  added 34.45 | 36.38
+#     1.25 (10,240) 16.51 | 23.94 (2);  1.5 (12,288) 17.14 | 25.22 (2);
+#     2 (16,384) 18.52 | 20.34
+#   64 of 64 held, 2048 x 1536, top-4, 32,768 tokens (GLM): the bound is the
+#     whole at any margin: 131,072 rows gathered back 42.44, added 46.22
+# Over 16 more such routers a shape the most rows in use read 1.30 / 1.22 /
+# 1.19 times the even share (one call of 16 past 1.25 at LFM2's shape, none
+# past 1.5; the timed router above read 1.52).  One pass at 1.5 is 7-9% under
+# one pass at 2, a second pass 47-49% over it, and trained routers skew more
+# than seeded ones: 2, which no call of the four cells passed (``prefill_
+# dispatch_passes_per_call.lm`` 1.0).  Adding a row to its token's sum costs
+# more than a token's gathering it back, row for row, so the bound pays by
+# the rows it leaves out (it keeps a quarter to an eighth), and where it
+# leaves none out (the last shape) the rows are gathered back
+SORTED_MARGIN = 2.0
+SORTED_TILE = 512    # the grouped kernel's tile of rows: the buffer is whole tiles
 
 
 class ExpertShare(NamedTuple):
@@ -150,20 +193,32 @@ def share_form(tokens: int, top_k: int, share: ExpertShare, d: int, f: int,
             else "batched")
 
 
+def sorted_rows(tokens: int, top_k: int, share: ExpertShare) -> int:
+    """Rows of the sorted form's buffer for ``tokens`` tokens: what even
+    routing lands on the held experts times ``SORTED_MARGIN``, in whole
+    tiles, and never more than every assignment that CAN land here."""
+    expected = tokens * top_k * share.held / share.total
+    tiles = math.ceil(expected * SORTED_MARGIN / SORTED_TILE)
+    return min(tiles * SORTED_TILE, tokens * min(top_k, share.held))
+
+
 def share_apply(x, idx, w, experts, share: ExpertShare):
     """-> (sum over the chosen experts held here of ``w_i E_i(x)``, (T, d);
     the number of held experts whose weights were read, () int32, where the
-    form counts it (``"skipping"``), else None: every held expert was).
+    form counts it (``"skipping"``), else None: every held expert was; the
+    passes over its buffer that the call took, () int32, where the form has
+    a buffer (``"sorted"``), else None).
 
     ``experts``: {"gate", "up": (held, d, f); "down": (held, f, d)};
     ``E(x) = (silu(x gate) * (x up)) down``."""
     form = share_form(idx.shape[0], idx.shape[1], share,
                       *experts["gate"].shape[1:], x.dtype)
     if form == "skipping":
-        return _share_apply_skipping(x, idx, w, experts, share)
+        return (*_share_apply_skipping(x, idx, w, experts, share), None)
     if form == "batched":
-        return _share_apply_batched(x, idx, w, experts, share), None
-    return _share_apply_sorted(x, idx, w, experts, share), None
+        return _share_apply_batched(x, idx, w, experts, share), None, None
+    out, passes = _sorted_in_passes(x, idx, w, experts, share)
+    return out, None, passes
 
 
 def _share_apply_skipping(x, idx, w, experts, share: ExpertShare, *,
@@ -211,32 +266,82 @@ def _share_apply_batched(x, idx, w, experts, share: ExpertShare):
 
 def _share_apply_sorted(x, idx, w, experts, share: ExpertShare):
     """The tokens in expert order, one grouped product per projection."""
+    return _sorted_in_passes(x, idx, w, experts, share)[0]
+
+
+# jitted with ``share`` static: a model's expert layers have one signature,
+# so a program of 38 of them traces and lowers this function once and calls
+# it 38 times (XLA inlines the calls: the compiled program is the one
+# without them)
+@functools.partial(jax.jit, static_argnames="share")
+def _sorted_in_passes(x, idx, w, experts, share: ExpertShare):
+    """-> (the sum (T, d), the passes over the buffer that it took, ()
+    int32).  The counting sort runs once; pass ``p`` takes rows ``p cap ..
+    (p + 1) cap - 1`` of the sorted order (``cap``: ``sorted_rows``) through
+    the grouped products and adds them, weighted, to their tokens' sums in
+    float32, while rows in use are left.  Where the bound is the whole
+    buffer (every expert held) there is one pass and no loop, and every
+    token gathers its ``k`` rows back instead: over ALL the rows that is
+    the cheaper combine (the table above ``SORTED_MARGIN``)."""
     t, k = idx.shape
-    n_groups = share.held + 1                       # the last: held elsewhere
+    cap = sorted_rows(t, k, share)
+    rows = t * min(k, share.held)                   # every held assignment fits
     with jax.named_scope("moe.dispatch"):
         local, held = _held(idx, share)
         group = jnp.where(held, local, share.held).reshape(-1)      # (T k,)
+        n_groups = share.held + 1                   # the last: held elsewhere
         hot = (group[:, None] == jnp.arange(n_groups)).astype(jnp.int32)
         sizes = hot.sum(0)
         starts = jnp.cumsum(sizes) - sizes
         rank = jnp.take_along_axis(jnp.cumsum(hot, axis=0), group[:, None],
                                    axis=1)[:, 0] - 1
         dest = starts[group] + rank                 # row in expert order
-        rows = t * min(k, share.held)               # every held assignment fits
+        n = t * k - sizes[-1]                       # rows in use
+        room = -(-rows // cap) * cap                # whole passes
         token = jnp.arange(t * k, dtype=jnp.int32) // k
-        row_token = jnp.zeros((rows,), jnp.int32).at[dest].set(token,
+        row_token = jnp.zeros((room,), jnp.int32).at[dest].set(token,
                                                                mode="drop")
-        in_use = jnp.arange(rows) < (t * k - sizes[-1])
-        xs = x[row_token]
-        held_sizes = sizes[:-1]
-    with jax.named_scope("moe.experts"):
-        g = jax.lax.ragged_dot(xs, experts["gate"], held_sizes)
-        u = jax.lax.ragged_dot(xs, experts["up"], held_sizes)
-        out = jax.lax.ragged_dot(jax.nn.silu(g) * u, experts["down"],
-                                 held_sizes)
+
+    def products(xs, sizes_p):
+        with jax.named_scope("moe.experts"):
+            g = jax.lax.ragged_dot(xs, experts["gate"], sizes_p)
+            u = jax.lax.ragged_dot(xs, experts["up"], sizes_p)
+            return jax.lax.ragged_dot(jax.nn.silu(g) * u, experts["down"],
+                                      sizes_p)
+
+    if cap == rows:                                 # the bound is the whole
+        with jax.named_scope("moe.dispatch"):
+            xs = x[row_token]
+        out = products(xs, sizes[:-1])
+        with jax.named_scope("moe.dispatch"):
+            out = jnp.where((jnp.arange(rows) < n)[:, None], out, 0)
+            back = out[jnp.minimum(dest, rows - 1).reshape(t, k)]   # (T, k, d)
+            wk = jnp.where(held, w, 0.0)
+            return (jnp.sum(back.astype(jnp.float32) * wk[..., None],
+                            axis=1).astype(x.dtype), jnp.ones((), jnp.int32))
+
     with jax.named_scope("moe.dispatch"):
-        out = jnp.where(in_use[:, None], out, 0)    # rows past the last group
-        back = out[jnp.minimum(dest, rows - 1).reshape(t, k)]       # (T, k, d)
-        wk = jnp.where(held, w, 0.0)
-        return jnp.sum(back.astype(jnp.float32) * wk[..., None],
-                       axis=1).astype(x.dtype)
+        row_w = jnp.zeros((room,), jnp.float32).at[dest].set(w.reshape(-1),
+                                                             mode="drop")
+        first, last = starts[:-1], starts[:-1] + sizes[:-1]
+
+    def one_pass(p, acc):
+        lo = p * cap
+        with jax.named_scope("moe.dispatch"):
+            tok = jax.lax.dynamic_slice(row_token, (lo,), (cap,))
+            xs = x[tok]
+            sizes_p = (jnp.clip(last - lo, 0, cap)
+                       - jnp.clip(first - lo, 0, cap))   # the groups' rows here
+        out = products(xs, sizes_p)
+        with jax.named_scope("moe.dispatch"):
+            wr = jax.lax.dynamic_slice(row_w, (lo,), (cap,))
+            in_use = lo + jnp.arange(cap) < n       # rows past the last group
+            return acc.at[tok].add(jnp.where(
+                in_use[:, None], out.astype(jnp.float32) * wr[:, None], 0.0))
+
+    with jax.named_scope("moe.dispatch"):
+        passes, acc = jax.lax.while_loop(
+            lambda c: c[0] * cap < n,
+            lambda c: (c[0] + 1, one_pass(c[0], c[1])),
+            (jnp.zeros((), jnp.int32), jnp.zeros((t, x.shape[1]), jnp.float32)))
+        return acc.astype(x.dtype), jnp.maximum(passes, 1)

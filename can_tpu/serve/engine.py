@@ -431,6 +431,10 @@ class LMEngine:
                          # held experts whose weights the decode steps read
                          # (layers x steps), of those they could have
                          "decode_experts_read": 0, "decode_experts_held": 0,
+                         # passes the prefill slices' expert layers took
+                         # over their sorted buffers, of their calls (one a
+                         # layer and slice; a form without a buffer: 0 of 0)
+                         "dispatch_passes": 0, "dispatch_calls": 0,
                          "cache_bytes": {},
                          # launches by the form their prefill's attention
                          # ran in (models that note none: empty)
@@ -562,6 +566,15 @@ class LMEngine:
             if expert_layers:
                 sp.attrs["experts_read"] = experts_read
                 sp.attrs["experts_held"] = experts_held
+            # the prefill's passes are known only now: ``lm.prefill`` closed
+            # when its slices were queued, before the device ran them
+            passes = calls = 0
+            if "dispatch_passes" in pre:
+                # can-tpu-lint: disable=HOSTSYNC(one more counter of the launch, fetched with the others)
+                passes = int(np.asarray(pre["dispatch_passes"]))
+                calls = expert_layers * len(slices)
+                sp.attrs["dispatch_passes"] = passes
+                sp.attrs["dispatch_calls"] = calls
             fetched = None
             if want_logits:
                 # can-tpu-lint: disable=HOSTSYNC(fetched only when a request asked for its logits)
@@ -569,7 +582,8 @@ class LMEngine:
         self._warm.add((slots, bucket))
         k = pre["choices"].shape[-1]   # (expert layers, slots, k)
         self._count(cache, valid, valid_tokens, steps, pre_counts, dec_counts,
-                    attention, expert_layers * k, experts_read, experts_held)
+                    attention, expert_layers * k, experts_read, experts_held,
+                    passes, calls)
         return ids, fetched
 
     def _note_forms(self, traced: bool, program: tuple) -> None:
@@ -624,7 +638,8 @@ class LMEngine:
 
     def _count(self, cache, valid, valid_tokens, steps, pre_counts,
                dec_counts, attention, choices_per_token: int,
-               experts_read: int, experts_held: int) -> None:
+               experts_read: int, experts_held: int, passes: int,
+               calls: int) -> None:
         """``choices_per_token``: routing choices a token makes over all the
         expert layers (0 for a model without one: every expert counter then
         reads zero)."""
@@ -646,6 +661,8 @@ class LMEngine:
         c["assignments_all"] += every
         c["decode_experts_read"] += experts_read
         c["decode_experts_held"] += experts_held
+        c["dispatch_passes"] += passes
+        c["dispatch_calls"] += calls
         c["cache_bytes"] = kv_cache.nbytes_by_kind(cache, p.cache_layout)
         if attention is not None:
             by_form = c["prefill_attention"]

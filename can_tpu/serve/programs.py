@@ -89,7 +89,9 @@ class LMPrograms:
       -> the form its expert layers take for a program of that many tokens
       (``ops.moe.share_form``).  In the ``"skipping"`` form its ``routing``
       also has ``experts_read`` () int32, the held experts whose weights the
-      step read, summed over the expert layers.
+      step read, summed over the expert layers; in the ``"sorted"`` form
+      ``dispatch_passes`` () int32, the passes over the sorted buffer that
+      the expert layers took, summed over them.
 
     State between the programs of a launch, all on the device: the cache
     (``serve/cache.py``) and ``state``: ``tokens`` (slots,) the token each
@@ -150,11 +152,14 @@ class LMPrograms:
     def new_state(self, outs, lengths, active):
         """The slices' outputs put together and the decode state after
         prefill: -> (state, {"logits" (slots, V), "choices", "counts"} of
-        the whole launch's prefill)."""
+        the whole launch's prefill; where the slices' expert layers count
+        their passes, also "dispatch_passes" (), their sum)."""
         cat = lambda name, axis=0: jnp.concatenate([o[name] for o in outs], axis)
         first = cat("first")
         pre = {"logits": cat("logits"), "choices": cat("choices", 1),
                "counts": sum(o["counts"] for o in outs)}
+        if "dispatch_passes" in outs[0]:
+            pre["dispatch_passes"] = sum(o["dispatch_passes"] for o in outs)
         ids = jnp.zeros((first.shape[0], self.max_new_tokens + 1), jnp.int32)
         state = {"tokens": first, "positions": lengths.astype(jnp.int32),
                  "active": active, "ids": ids.at[:, 0].set(first),
@@ -167,7 +172,8 @@ class LMPrograms:
     def prefill_slice(self, params, batch, cache, start):
         """``batch``: {"tokens" (s, L), "lengths" (s,), "active" (s,)} for
         slots ``start .. start + s - 1`` -> ({"first", "logits", "choices",
-        "counts"}, the cache with those slots' rows written)."""
+        "counts"; "dispatch_passes" where the expert layers count them}, the
+        cache with those slots' rows written)."""
         logits, part, routing = self._m.prefill(
             params, batch["tokens"], batch["lengths"], self.cfg,
             self.positions(batch["tokens"].shape[1]), active=batch["active"])
@@ -180,6 +186,8 @@ class LMPrograms:
             first = jnp.argmax(logits, -1).astype(jnp.int32)
         out = {"first": first, "logits": logits,
                "choices": routing["choices"], "counts": routing["counts"]}
+        if "dispatch_passes" in routing:
+            out["dispatch_passes"] = routing["dispatch_passes"]
         return out, cache
 
     def decode(self, params, state, cache):

@@ -309,13 +309,18 @@ def test_lm_decode_step_compiles_for_one_device(v5e):
 
 
 def test_lm_prefill_slice_compiles_for_one_device(v5e):
-    """8 prompts of 1,024 tokens into a 64-slot cache: the sorted buffer's
-    worst case (8 x tokens rows) fits beside the weights."""
+    """8 prompts of 1,024 tokens into a 64-slot cache: the sorted buffer
+    holds twice the even share, 16,384 rows (``moe.sorted_rows``; every
+    assignment that can land here would be 65,536), one loop over its passes
+    an expert layer, beside the weights."""
     programs, params, cache, batch, shape = _lm_programs_and_shapes(v5e, 64, 8)
     compiled = jax.jit(programs.prefill_slice, donate_argnums=(2,)).lower(
         params, batch, cache, shape((), jnp.int32)).compile()
-    assert "ragged-dot" in compiled.as_text()
-    assert _fits_hbm(compiled) > 9 * 2**30
+    text = compiled.as_text()
+    assert "ragged-dot" in text
+    assert "bf16[16384,6144]" in text and "bf16[65536,6144]" not in text
+    assert _dispatch_loops(programs, text) == 4
+    assert 8.5 * 2**30 < _fits_hbm(compiled) < 9.5 * 2**30
 
 
 # -- the latent-attention model at the published widths -------------------
@@ -365,6 +370,10 @@ def test_glm_prefill_slice_compiles_for_one_device(v5e):
     assert "ragged-dot" in text
     assert "f32[20,1024,1024]" in text           # a score block
     assert "f32[20,1024,16384]" not in text and "f32[2,20,16384,16384]" not in text
+    # every expert is held: the bound is the whole buffer, one pass, no loop
+    # of the sorted form's (the scanned attention's loops are ``attn.core``'s)
+    assert "bf16[131072,2048]" in text
+    assert _dispatch_loops(programs, text) == 0
     assert 10 * 2**30 < _fits_hbm(compiled) < 14 * 2**30
 
 
@@ -451,6 +460,32 @@ def _parts_of_the_compiled(programs, text, kernel, part, launches):
     assert got["unscoped"] <= 0.01 * got["instructions"], got["unscoped"]
     assert 0.4 < len(got["inherited"]) / got["instructions"] < 0.75
     return found
+
+
+def _dispatch_loops(programs, text) -> int:
+    """The sorted expert form's loops over its buffer in a compiled prefill
+    slice (``ops/moe.py::_sorted_in_passes``): -> how many ``while``
+    instructions the part ``moe.dispatch`` owns.  Every op of their bodies
+    and conditions belongs to ``moe.dispatch`` or ``moe.experts``; the inner
+    ``jit`` that traces the form once a program left no ``call`` behind; and
+    next to nothing of the program is left without a part
+    (``prefill_unscoped_pct.lm`` stays under 0.1)."""
+    import re
+
+    from can_tpu.obs.trace import _instructions, part_of, program_scopes
+
+    got = program_scopes(text, programs.parts)
+    assert " call(" not in text
+    assert got["unscoped"] <= 0.005 * got["instructions"], got["unscoped"]
+    loops = [m for m in re.finditer(
+        r" while\(.*?condition=%([\w.\-]+), body=%([\w.\-]+).*?op_name=\"([^\"]*)\"",
+        text) if part_of(m.group(3), programs.parts) == "moe.dispatch"]
+    inside = {name for m in loops for name in m.group(1, 2)}
+    ops = [got["parts"][inst] for comp, inst, *_ in _instructions(text)[0]
+           if comp in inside and inst in got["parts"]]
+    assert set(ops) <= {"moe.dispatch", "moe.experts"}, set(ops)
+    assert not loops or ops.count("moe.experts") >= 3 * len(loops)
+    return len(loops)
 
 
 # -- the state-space hybrid at the published widths -------------------------
@@ -555,26 +590,65 @@ def _lfm2_checks(programs, decode, prefill, cache, layers, through_vmem=0):
                 and "= (bf16[256,1280,128]" in line]) == through_vmem
     held = 64 * (1280 * 2048 * attention + 8192 * (layers - attention))
     assert decode.memory_analysis().alias_size_in_bytes >= held
+    # the sorted form's buffer: twice the even share of a slice's 8,192
+    # tokens' top-4 at an eighth, 8,192 rows (every assignment that can land
+    # here would be 32,768), and one loop over its passes an expert layer
+    text = prefill.as_text()
+    assert "bf16[8192,1536]" in text and "bf16[32768,1536]" not in text
+    assert _dispatch_loops(programs, text) == layers - 2
 
 
-def test_lfm2_decode_and_prefill_compile_on_a_six_layer_pattern(v5e):
+def test_lfm2_decode_and_prefill_compile_on_a_six_layer_pattern(v5e,
+                                                                monkeypatch):
     """Both dense layers and one period (``c c A c c c``) at the published
     widths, 8 of 64 experts held: decode's experts batched, the prefill's
-    sorted, the attention layer's keys and values written where they lie."""
+    sorted, the attention layer's keys and values written where they lie.
+    **The inner ``jit`` of the sorted form changes what the host does and
+    next to nothing the chip does:** the same slice compiled with the
+    function traced in line, layer by layer, runs opcode for opcode the same
+    instructions but for ONE small fusion an expert layer (the scatters'
+    index clamp over ``s32[32768,1]``, one multi-output fusion in line, two
+    fusions and a reshape behind the call)."""
+    import collections
+
+    from can_tpu.obs.trace import _NO_OP, _instructions
+    from can_tpu.ops import moe as moe_ops
+
     programs, decode, prefill, cache = _lfm2_programs(v5e, 6)
     _lfm2_checks(programs, decode, prefill, cache, 6)
     assert _fits_hbm(decode) < 2 * 2**30 and _fits_hbm(prefill) < 2.5 * 2**30
+    monkeypatch.setattr(moe_ops, "_sorted_in_passes",
+                        moe_ops._sorted_in_passes.__wrapped__)
+    programs, params, cache, batch, shape = _lm_programs_and_shapes(
+        v5e, 64, 8, LFM2, _lfm2_depth(6))
+    lowered = jax.jit(programs.prefill_slice, donate_argnums=(2,)).lower(
+        params, batch, cache, shape((), jnp.int32))
+    assert "_sorted_in_passes" not in lowered.as_text()
+
+    def ran(text):
+        rows, inlined = _instructions(text)
+        return collections.Counter(opcode for comp, _, opcode, *_ in rows
+                                   if comp not in inlined and opcode not in _NO_OP)
+
+    called, in_line = ran(prefill.as_text()), ran(lowered.compile().as_text())
+    assert {op for op in called | in_line if called[op] != in_line[op]} <= {
+        "fusion", "reshape"}
+    assert 0 <= called["fusion"] - in_line["fusion"] <= 4   # 4 expert layers
 
 
 @pytest.mark.slow
 def test_lfm2_decode_and_prefill_compile_at_full_depth(v5e):
-    """All 40 layers, as the cell runs them (25 s here): 9.2 GB of weights
-    and cache as arguments, 0.3 / 0.6 GB of temporaries, no copy of a
-    cache leaf in either program (40 a decode step before PR 39); the keys
-    of nine attention layers pass through the fast memory and back."""
+    """All 40 layers, as the cell runs them (45 s here): 9.2 GB of weights
+    and cache as arguments, 0.3 / 1.8 GB of temporaries (0.8 in the prefill
+    before PR 43: around the 38 loops the scheduler leaves the 30 mixers'
+    tail gathers to the program's end, and each holds its layer's ``u``,
+    33.5 MB, until then), no copy of a cache leaf in either program (40 a
+    decode step before PR 39); the keys of nine attention layers pass
+    through the fast memory and back; 38 loops of the sorted form, one an
+    expert layer."""
     programs, decode, prefill, cache = _lfm2_programs(v5e, 40)
     _lfm2_checks(programs, decode, prefill, cache, 40, through_vmem=9)
-    assert 9e9 < _fits_hbm(decode) < _fits_hbm(prefill) < 11e9
+    assert 9e9 < _fits_hbm(decode) < _fits_hbm(prefill) < 11.5e9
 
 
 # -- MiMo-V2-Flash: two kinds of attention layer, keys wider than values -----
@@ -621,13 +695,15 @@ def test_mimo_decode_and_prefill_compile_for_one_device(v5e, monkeypatch):
     skip, the prefill's are sorted, the full layers' prefill is the scanned
     form (a ``while`` in the text), and **no leaf of the cache is copied
     whole in either program**: keys 192 wide two heads to a row of 384 lanes
-    are written where they lie, as values of 128 are.  **The named debt, in
-    bytes:** the prefill slice needs 14.47 GB, 6.87 of them temporaries,
-    because the sorted expert form's buffer at a sixteenth holds ``T x 8`` =
-    262,144 rows of 4,096 a layer for 16,384 in use (2.1 GB each in bfloat16
-    for the gathered rows, the products and the rows gathered back; PERF.md
-    section 7, debt (2)): it fits the chip's 17.18 GB, a slice of 8 would
-    not."""
+    are written where they lie, as values of 128 are.  **The prefill slice's
+    memory:** 11.92 GB, 4.32 of them temporaries (14.47 and 6.87 before
+    PR 43, when the sorted expert form's buffer held ``T x 8`` = 262,144
+    rows of 4,096 a layer for 16,384 in use, 2.1 GB each for the gathered
+    rows, the products and the rows gathered back).  The buffer now holds
+    twice the even share, 32,768 rows (``moe.sorted_rows``), with one loop
+    over its passes an expert layer, and what sets the peak is a window
+    layer's attention (``f32[4,64,8,8,128,256]``, 2.1 GB of scores with
+    their mask and their bfloat16 copy), not an expert layer."""
     programs, decode, prefill, cache = _mimo_programs(v5e, monkeypatch)
     text = decode.as_text()
     assert "ragged-dot" not in text and "ragged-dot" in prefill.as_text()
@@ -638,8 +714,11 @@ def test_mimo_decode_and_prefill_compile_for_one_device(v5e, monkeypatch):
     # the cache is donated and handed back: 16 x (8,448 x 5,120 + 3,276,800) B
     assert decode.memory_analysis().alias_size_in_bytes >= 744_488_960
     assert 7.5e9 < _fits_hbm(decode) < 8.5e9
-    assert 14e9 < _fits_hbm(prefill) < 15e9
-    assert "bf16[262144,4096]" in prefill.as_text()      # the debt's buffer
+    assert 11.5e9 < _fits_hbm(prefill) < 12.4e9
+    assert prefill.memory_analysis().temp_size_in_bytes < 4.5e9
+    text = prefill.as_text()
+    assert "bf16[32768,2048]" in text and "bf16[262144," not in text
+    assert _dispatch_loops(programs, text) == 6
 
 
 # -- the fused prefill attention (ops/pallas_attention.py) ------------------

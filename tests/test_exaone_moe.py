@@ -176,7 +176,8 @@ class TestExpertShare:
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
                                    rtol=2e-5)
         monkeypatch.setattr(moe_ops, "DENSE_MAX_TOKENS", 64)
-        c, _ = moe_ops.share_apply(x, idx, w, experts, share)
+        c, _, passes = moe_ops.share_apply(x, idx, w, experts, share)
+        assert (passes is None) == (tokens <= 64)
         np.testing.assert_allclose(np.asarray(c), np.asarray(a), atol=2e-5,
                                    rtol=2e-5)
 
@@ -188,7 +189,7 @@ class TestExpertShare:
         idx = jnp.zeros((9, 2), jnp.int32).at[:, 1].set(1)
         w = jnp.ones((9, 2))
         experts = {k: v[6:] for k, v in p["experts"].items()}
-        got, _ = moe_ops.share_apply(x, idx, w, experts, share)
+        got, _, _ = moe_ops.share_apply(x, idx, w, experts, share)
         assert float(jnp.abs(got).max()) == 0.0
 
     def test_router_weights(self):
@@ -384,18 +385,22 @@ def test_the_form_is_chosen_by_backend_and_shapes(backend, what, args, on_tpu,
 
 @pytest.mark.parametrize("form", ["skipping", "batched", "sorted"])
 def test_share_apply_runs_the_form_chosen(form, monkeypatch):
+    """-> (the sum, the experts read where the form counts them, the passes
+    it took where the form has a buffer)."""
     ran = []
-    for name in ("skipping", "batched", "sorted"):
-        monkeypatch.setattr(
-            moe_ops, f"_share_apply_{name}",
-            lambda *a, name=name: (ran.append(name)
-                                   or (("y", 3) if name == "skipping" else "y")))
+    says = {"skipping": ("y", 3), "batched": "y", "sorted": ("y", 2)}
+    for name, fn in (("skipping", "_share_apply_skipping"),
+                     ("batched", "_share_apply_batched"),
+                     ("sorted", "_sorted_in_passes")):
+        monkeypatch.setattr(moe_ops, fn, lambda *a, name=name: (
+            ran.append(name) or says[name]))
     monkeypatch.setattr(moe_ops, "share_form", lambda *a: form)
     x, idx = jnp.zeros((4, 128)), jnp.zeros((4, 2), jnp.int32)
     experts = {"gate": jnp.zeros((8, 128, 256))}
     got = moe_ops.share_apply(x, idx, None, experts, moe_ops.ExpertShare(0, 8, 8))
     assert ran == [form]
-    assert got == (("y", 3) if form == "skipping" else ("y", None))
+    assert got == {"skipping": ("y", 3, None), "batched": ("y", None, None),
+                   "sorted": ("y", None, 2)}[form]
 
 
 # -- the serving programs of the models this change must not move -----------
@@ -410,11 +415,14 @@ def test_share_apply_runs_the_form_chosen(form, monkeypatch):
 # projections by head and the ring's prefill entry into shared functions)
 # added LFM2's two, lowered at its parent 7a2007b: a ``sink`` of None, ``dv ==
 # d`` and one ``pack`` for both leaves trace to what stood, in all four models.
+# PR 43 replaced the three ``prefill_slice`` texts of the models with experts
+# (``ops/moe.py``'s sorted form over a bound on the rows in use, an inner
+# ``jit``); Falcon-H1's and every ``decode`` text stand.
 PROGRAM_TEXT = {
     ("k-exaone-ep8-serve-bf16", "decode"):
         "2c53b1638274f93f0d4919dc5558a2e6378313e37e545c1402c69c3c3a01403d",
     ("k-exaone-ep8-serve-bf16", "prefill_slice"):
-        "bb7d1d97fcf9ba32df5bb46cf815b1ecaab3ab6cc8d8f16461276278dfe9f6c0",
+        "beaa82325d5187ef59abc47b9044c9f75a4e80a2f62096daaf60ad3e70813cd6",
     ("falcon-h1-34b-pp12-serve-bf16", "decode"):
         "06d5c406ee95631fb2d429365a24acb1623aef8e99a46f6cbc3d113e7c48c8a0",
     ("falcon-h1-34b-pp12-serve-bf16", "prefill_slice"):
@@ -422,11 +430,11 @@ PROGRAM_TEXT = {
     ("glm-4.7-flash-pp8-serve-bf16", "decode"):
         "9bafbae804c45ab05d80ff531d20944e158ad3719d5d49f1a1dfed610a8e1323",
     ("glm-4.7-flash-pp8-serve-bf16", "prefill_slice"):
-        "e9f9fe50e6393695b08d2153b7db87cf10b93c4a3bad87fc68f1288515920756",
+        "c559b2a514ec802e648d8cb975be7456086ac7ffd849785acfef383446aa68a7",
     ("lfm2-24b-a2b-ep8-serve-bf16", "decode"):
         "8c7d19f0bdfbfe4b323aedbd4a0fb791f1abf2ac18a6bd3daa2cc9ccd4439952",
     ("lfm2-24b-a2b-ep8-serve-bf16", "prefill_slice"):
-        "0bbecfd4ba4a60d18ca3ca046bca32461b42da999a1fa36f77bba0c8eb624b54",
+        "48d2b79687d2ca3c0b80e047ae1116f0406ab531e79760cd4938c5a5fe4bcdb3",
 }
 
 
@@ -487,9 +495,9 @@ def _program_text(name: str, program: str) -> str:
     if not (backend == "tpu" and name.startswith("glm"))])
 def test_a_serving_program_lowers_to_the_text_it_had(name, program, backend,
                                                      monkeypatch):
-    """K-EXAONE's, Falcon-H1's and LFM2's programs are the parent's, whatever
-    the backend says (K-EXAONE's and LFM2's shapes leave nothing to skip,
-    Falcon-H1 has no expert layer); GLM's are the parent's off a TPU."""
+    """K-EXAONE's, Falcon-H1's and LFM2's programs are the pinned ones,
+    whatever the backend says (K-EXAONE's and LFM2's shapes leave nothing to
+    skip, Falcon-H1 has no expert layer); GLM's are off a TPU."""
     import hashlib
 
     if jax.__version__ != "0.9.0":

@@ -97,6 +97,67 @@ ENTRY %main.9 (cache: bf16[4,2,16,8], new: bf16[8,8]) -> (bf16[4,2,16,8], bf16[8
 """
 
 
+# a loop whose body the compiler gave copies of its carried state (XLA:TPU's
+# text for the sorted expert form's passes, cut down by hand): they carry no
+# metadata, nothing but the body's own result uses them and an argument made
+# them, so neither neighbour has a part to hand on
+HLO_LOOP = r"""HloModule jit_prefill_slice, is_scheduled=true
+
+%inner_body.3 (c.2: (s32[], bf16[4,8])) -> (s32[], bf16[4,8]) {
+  %c.2 = (s32[], bf16[4,8]{1,0}) parameter(0)
+  %get-tuple-element.21 = bf16[4,8]{1,0} get-tuple-element(%c.2), index=1
+  %copy.22 = bf16[4,8]{0,1} copy(%get-tuple-element.21)
+  %constant.23 = s32[] constant(1)
+  ROOT %tuple.24 = (s32[], bf16[4,8]{0,1}) tuple(%constant.23, %copy.22)
+}
+
+%inner_cond.4 (c.3: (s32[], bf16[4,8])) -> pred[] {
+  %c.3 = (s32[], bf16[4,8]{1,0}) parameter(0)
+  ROOT %constant.25 = pred[] constant(false)
+}
+
+%body.1 (c: (s32[], bf16[4,8])) -> (s32[], bf16[4,8]) {
+  %c = (s32[], bf16[4,8]{1,0}) parameter(0)
+  %get-tuple-element.11 = bf16[4,8]{1,0} get-tuple-element(%c), index=1
+  %copy-start.12 = (bf16[4,8]{1,0:S(1)}, bf16[4,8]{1,0}, u32[]{:S(2)}) copy-start(%get-tuple-element.11)
+  %copy-done.12 = bf16[4,8]{1,0:S(1)} copy-done(%copy-start.12)
+  %while.13 = (s32[], bf16[4,8]{1,0}) while(%c), condition=%inner_cond.4, body=%inner_body.3
+  %add.14 = s32[] add(%constant.15, %constant.15), metadata={op_name="jit(prefill_slice)/jit(_sorted_in_passes)/moe.dispatch/while/body/add"}
+  ROOT %tuple.16 = (s32[], bf16[4,8]{1,0:S(1)}) tuple(%add.14, %copy-done.12)
+}
+
+%cond.2 (c.1: (s32[], bf16[4,8])) -> pred[] {
+  %c.1 = (s32[], bf16[4,8]{1,0}) parameter(0)
+  %get-tuple-element.17 = s32[] get-tuple-element(%c.1), index=0
+  ROOT %compare.18 = pred[] compare(%get-tuple-element.17, %get-tuple-element.17), direction=LT
+}
+
+ENTRY %main.5 (x: bf16[4,8]) -> bf16[4,8] {
+  %x = bf16[4,8]{1,0} parameter(0)
+  %tuple.6 = (s32[], bf16[4,8]{1,0}) tuple(%constant.7, %x)
+  %while.8 = (s32[], bf16[4,8]{1,0}) while(%tuple.6), condition=%cond.2, body=%body.1, metadata={op_name="jit(prefill_slice)/jit(_sorted_in_passes)/moe.dispatch/while"}
+  %copy.9 = bf16[4,8]{0,1} copy(%x)
+  ROOT %get-tuple-element.10 = bf16[4,8]{1,0} get-tuple-element(%while.8), index=1
+}
+"""
+
+
+def test_what_runs_in_a_loop_and_names_no_part_is_the_loop_s():
+    """The compiler's copies of a loop's carried state, a condition that
+    kept no metadata and a loop inside the loop that has none belong to the
+    ``while`` that runs them (through the inner loop, to its body too);
+    outside a loop nothing changes."""
+    got = program_scopes(HLO_LOOP, {p: p for p in PARTS})
+    p = got["parts"]
+    assert p["while.8"] == p["add.14"] == "moe.dispatch"
+    for inst in ("copy-start.12", "copy-done.12", "compare.18", "while.13",
+                 "copy.22"):
+        assert p[inst] == "moe.dispatch", inst
+        assert inst in got["inherited"]
+    assert p["copy.9"] is None and got["unscoped"] == 1
+    assert got["instructions"] == 8
+
+
 @pytest.mark.parametrize("text,leaves,want", [
     # ``HLO`` above: its cache is copied in (copy.30), a result of its shape
     # out (copy.31) and an argument of its shape out (copy.40)

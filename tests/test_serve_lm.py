@@ -637,3 +637,54 @@ def test_arrivals_during_a_launch_join_their_group_before_the_timer():
     for _ in range(5):
         queue.offer(request())
     assert b.run_once(0.0) == 2 and launches == [4, 4, 4, 1]
+
+
+# -- the sorted expert form's passes, counted by the engine ------------------
+@pytest.mark.parametrize("held,loop", [(8, False), (2, True)],
+                         ids=["every-expert-held", "a-quarter-held"])
+def test_the_engine_counts_the_prefill_s_passes_over_the_sorted_buffer(held,
+                                                                       loop):
+    """A prefill slice of 4 x 256 tokens takes the sorted form: each of the
+    4 expert layers of each slice is one call and says how many passes over
+    its buffer it took (``ops.moe.share_apply``); the launch's sum comes
+    back with its answers, into ``stats()["lm"]`` and onto ``serve.fetch``.
+    With every expert held the buffer is the whole: one pass a call, no
+    loop.  A launch whose prefill is in another form (the module's
+    ``service``: slices of 32 tokens) counts 0 of 0."""
+    from can_tpu.ops import moe as moe_ops
+
+    config = lm_config(num_experts=held, prefill_slice=4, max_batch=8,
+                       length_ladder=[256], max_new_tokens=2)
+    cfg = em.ExaoneMoeConfig.from_dict(config)
+    assert (moe_ops.sorted_rows(1024, 2, cfg.share) < 1024 * 2) == loop
+    tracer = spans.SpanTracer()
+    tel = Telemetry()
+    tel.spans = tracer
+    svc = build_model_service(config, telemetry=tel, seed=5)
+    svc.warmup()
+    assert svc.stats()["lm"]["dispatch_calls"] == 0   # warm-up is no traffic
+    svc.start()
+    try:
+        for t in [svc.submit(_prompt(200 + n, n)) for n in range(8)]:
+            t.result(300)
+        lm = svc.stats()["lm"]
+    finally:
+        svc.close()
+    calls = 4 * 2 * lm["launches"]         # expert layers x slices of 4 of 8
+    assert lm["dispatch_calls"] == calls > 0
+    if loop:
+        assert calls <= lm["dispatch_passes"] <= 2 * calls
+    else:
+        assert lm["dispatch_passes"] == calls
+    fetches = [s for s in tracer.snapshot() if s["name"] == "serve.fetch"
+               and "dispatch_calls" in s]
+    assert sum(s["dispatch_passes"] for s in fetches[-lm["launches"]:]) \
+        == lm["dispatch_passes"]
+
+
+def test_a_prefill_in_another_form_counts_no_passes(service):
+    svc, _, tracer = service
+    svc.submit(_prompt(9, 11)).result(120)
+    lm = svc.stats()["lm"]
+    assert lm["dispatch_calls"] == lm["dispatch_passes"] == 0
+    assert not [s for s in tracer.snapshot() if "dispatch_passes" in s]
