@@ -31,7 +31,6 @@ import os
 from typing import Sequence
 
 import numpy as np
-from scipy.spatial import cKDTree
 
 
 def _gaussian_kernel_1d(sigma: float, radius: int) -> np.ndarray:
@@ -124,6 +123,11 @@ def gaussian_density_map(points: np.ndarray, shape: Sequence[int], *,
         return density.astype(np.float32)
 
     if n > 1:
+        # imported here, as ``scipy.io`` below: ``scipy.spatial`` takes a
+        # second to load, and every process that serves (``serve/kinds.py``
+        # imports this package) paid it at start-up for maps it never stamps
+        from scipy.spatial import cKDTree
+
         tree = cKDTree(points, leafsize=2048)
         # k+1 neighbours: the nearest is the point itself at distance 0.
         distances, _ = tree.query(points, k=min(k + 1, n))

@@ -44,9 +44,12 @@ of 384 = 3 lane rows beside values of 128 a head a row (``kv_pack``: each
 leaf's row is whole lanes, no lane is padding; ``write_slot``,
 ``as_leaf`` / ``ring_entry`` and ``decode`` read it from the leaves' shapes).
 
-A full layer's prefill is the scanned ``prefill_causal`` over groups at
-every bucket (it clamps its block to the bucket).  No fused kernel takes
-heads of 192 (``pallas_attention.supports``: not whole lanes).
+A full layer's prefill is one causal attention over groups in two forms of
+one algorithm: the fused kernel ``ops/pallas_attention.py::fused_causal``
+(keys of 192 beside values of 128, 16 query heads to a key head) where its
+``supports`` says it can run (a TPU, a bucket of whole blocks), the scanned
+``prefill_causal`` everywhere else (it clamps its block to the bucket).
+Nothing else chooses; ``attention_traced`` says which a traced prefill took.
 
 What the published config leaves open is ONE choice each, named in
 ``ASSUMED`` (a configuration file states them under ``assumed``;
@@ -69,6 +72,7 @@ from can_tpu.models.lm_blocks import (VocabSlice, count_shapes, embed, ffn,
                                       qkv_heads, rms_norm, routing_report)
 from can_tpu.ops import attention as attn_ops
 from can_tpu.ops import cache_layout as layout
+from can_tpu.ops import pallas_attention as fused_attn
 from can_tpu.ops.moe import ExpertShare
 
 ASSUMED = {"norm": "rmsnorm", "qk_norm": False, "rope_pairing": "rotate_half",
@@ -299,12 +303,29 @@ def _core_scope(window: bool):
 
 
 # -- prefill ------------------------------------------------------------
+# (B, L) of a prefill's prompts -> the form the newest trace of a full
+# layer on such prompts ran its attention in (as
+# ``glm_moe_lite._ATTENTION_TRACED``: written while a program is traced, read
+# after its launch by whoever reports what the program does)
+_ATTENTION_TRACED: dict = {}
+
+
+def attention_traced(tokens_shape) -> Optional[str]:
+    """``"fused"`` / ``"scanned"`` as the prefill traced in this process for
+    prompts of this (B, L) has it; None where none was traced."""
+    return _ATTENTION_TRACED.get(tuple(tokens_shape))
+
+
 def _full_prefill(q, k, v, lengths):
-    """A full layer's causal attention over whole prompts: the scanned
-    ``prefill_causal``, a group's queries against the key head they share."""
+    """A full layer's causal attention over whole prompts, a group's queries
+    against the key head they share: the fused kernel where its ``supports``
+    says it can run, the scanned ``prefill_causal`` elsewhere."""
     b, l, kv, g, d = q.shape
-    o = attn_ops.prefill_causal(q.reshape(b, l, kv * g, d), k, v, lengths)
-    return o.reshape(b, l, kv, g, v.shape[-1])
+    q = q.reshape(b, l, kv * g, d)
+    fused = fused_attn.supports(q.shape, v.shape, q.dtype)
+    _ATTENTION_TRACED[(b, l)] = "fused" if fused else "scanned"
+    causal = fused_attn.fused_causal if fused else attn_ops.prefill_causal
+    return causal(q, k, v, lengths).reshape(b, l, kv, g, v.shape[-1])
 
 
 def _prefill_block(layer, window, x, positions, cfg, cache_len, lengths):

@@ -37,10 +37,16 @@ query block, each of another shape: 64 a layer at 16,384 positions; no
 score block larger than ``block x block`` a head), which runs anywhere and
 is the other's oracle; and the FUSED Pallas kernel of
 ``ops/pallas_attention.py``, in which a score block never leaves the chip.
-``pallas_attention.supports`` chooses from the backend and the shapes
-(``models/glm_moe_lite.py::attention_expanded`` asks it); nothing else
-does.  Its decode (``decode_latent``) is the absorbed form: queries carried
-into the latent space, the cache read as it is stored.
+``pallas_attention.supports`` chooses from the backend and the shapes;
+nothing else does.  Two models ask it: ``models/glm_moe_lite.py::
+attention_expanded`` (a key a head, 256 wide) and ``models/mimo_v2_flash.py::
+_full_prefill`` (the full layers of a grouped-query model: 64 query heads of
+192 over 4 key heads, values 128: ``prefill_causal`` over GROUPS is that
+kernel's oracle too).  K-EXAONE's, Falcon-H1's and LFM2's full layers and
+every window layer run ``prefill_full`` / ``prefill_window`` (plain
+``jax.numpy``).  Latent attention's decode (``decode_latent``) is the
+absorbed form: queries carried into the latent space, the cache read as it
+is stored.
 """
 
 from __future__ import annotations

@@ -463,9 +463,10 @@ def attention_worker() -> None:
     """CHILD process (``--attention-worker``): the fused causal attention
     kernel (``ops/pallas_attention.py``) COMPILED for the chip (interpreted
     only under ``--rehearse-cpu``) against the scanned ``prefill_causal`` at
-    a small aligned shape, the kernel's own blocks, uneven lengths: one
-    sequence ends inside its last block, the other leaves a block of
-    queries wholly past its length."""
+    two small shapes (a key a head of whole lanes; groups of query heads over
+    keys of 192 beside values of 128), the kernel's own blocks, uneven
+    lengths: one sequence ends inside its last block, the other leaves a
+    block of queries wholly past its length."""
     import jax
     import jax.numpy as jnp
 
@@ -478,31 +479,33 @@ def attention_worker() -> None:
     print(f"[xla] persistent compilation cache at "
           f"{enable_compilation_cache()}")
     on_chip = jax.default_backend() == "tpu"
-    b, h, d, dv = 2, 3, 256, 128
     l = 3 * max(fused_attn.BLOCK_Q, fused_attn.BLOCK_K)
     lengths = jnp.asarray([l - 37, fused_attn.BLOCK_Q + 5], jnp.int32)
-    ks = jax.random.split(jax.random.key(SEED), 3)
-    q = jax.random.normal(ks[0], (b, l, h, d), jnp.bfloat16)
-    k = jax.random.normal(ks[1], (b, l, h, d), jnp.bfloat16)
-    v = jax.random.normal(ks[2], (b, l, h, dv), jnp.bfloat16)
-    assert fused_attn.supports(q.shape, v.shape, q.dtype,
-                               interpret=not on_chip), "supports() refused"
-    fused = jax.jit(lambda *a: fused_attn.fused_causal(
-        *a, interpret=not on_chip))(q, k, v, lengths)
-    plain = jax.jit(attn_ops.prefill_causal)(q, k, v, lengths)
-    valid = jnp.arange(l)[None] < lengths[:, None]
-    gap = float(jnp.where(valid[:, :, None, None], jnp.abs(
-        fused.astype(jnp.float32) - plain.astype(jnp.float32)), 0).max())
-    finite = bool(jnp.isfinite(fused.astype(jnp.float32)).all())
-    skipped = not bool(fused[1, 2 * fused_attn.BLOCK_Q:].any())
-    print(f"[attention] kernel {'compiled (not interpreted)' if on_chip else 'INTERPRETED'}"
-          f", platform {jax.default_backend()}; q {q.shape} v {v.shape} "
-          f"lengths {lengths.tolist()}: largest gap to prefill_causal over "
-          f"valid rows {gap:.3e}, every row finite {finite}, the block past "
-          f"a length left zero {skipped}")
-    # one bfloat16 step of an output of size about 1 is 2^-8; the two forms
-    # round the same products, summed in another order
-    assert gap < 2e-2 and finite and skipped
+    # a key a head of whole lanes (GLM's kind), then groups of four query
+    # heads over keys of 192 beside values of 128 (MiMo's full layers' kind)
+    for b, h, kv, d, dv in ((2, 3, 3, 256, 128), (2, 8, 2, 192, 128)):
+        ks = jax.random.split(jax.random.key(SEED), 3)
+        q = jax.random.normal(ks[0], (b, l, h, d), jnp.bfloat16)
+        k = jax.random.normal(ks[1], (b, l, kv, d), jnp.bfloat16)
+        v = jax.random.normal(ks[2], (b, l, kv, dv), jnp.bfloat16)
+        assert fused_attn.supports(q.shape, v.shape, q.dtype,
+                                   interpret=not on_chip), "supports() refused"
+        fused = jax.jit(lambda *a: fused_attn.fused_causal(
+            *a, interpret=not on_chip))(q, k, v, lengths)
+        plain = jax.jit(attn_ops.prefill_causal)(q, k, v, lengths)
+        valid = jnp.arange(l)[None] < lengths[:, None]
+        gap = float(jnp.where(valid[:, :, None, None], jnp.abs(
+            fused.astype(jnp.float32) - plain.astype(jnp.float32)), 0).max())
+        finite = bool(jnp.isfinite(fused.astype(jnp.float32)).all())
+        skipped = not bool(fused[1, 2 * fused_attn.BLOCK_Q:].any())
+        print(f"[attention] kernel {'compiled (not interpreted)' if on_chip else 'INTERPRETED'}"
+              f", platform {jax.default_backend()}; q {q.shape} k {k.shape} "
+              f"v {v.shape} lengths {lengths.tolist()}: largest gap to "
+              f"prefill_causal over valid rows {gap:.3e}, every row finite "
+              f"{finite}, the block past a length left zero {skipped}")
+        # one bfloat16 step of an output of size about 1 is 2^-8; the two
+        # forms round the same products, summed in another order
+        assert gap < 2e-2 and finite and skipped
     print("ATTENTION OK")
 
 

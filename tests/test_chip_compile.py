@@ -658,12 +658,11 @@ MIMO = "mimo-v2-flash-ep16-serve-bf16"
 def _mimo_programs(v5e, monkeypatch):
     """-> (programs, decode's compiled program, prefill slice's, the cache's
     shapes) at the cell's widths: all 7 held layers, 16 slots of 8,448
-    positions, slices of 4; decode's experts in the skipping form, as on a
-    TPU (``share_form`` asks the backend, which is the CPU here)."""
-    from can_tpu.ops import pallas_experts
-
-    monkeypatch.setattr(pallas_experts, "supports", functools.partial(
-        pallas_experts._fits, tile_f=pallas_experts.TILE_F))
+    positions, slices of 4, as a TPU traces them: decode's experts in the
+    skipping form and the full layers' prefill in the fused kernel (both
+    ``supports`` ask the backend, which is the CPU's during a compile for a
+    described chip: steered here)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     programs, params, cache, batch, shape = _lm_programs_and_shapes(
         v5e, 16, 4, MIMO)
     assert programs.decode_experts(16) == "skipping"
@@ -679,6 +678,7 @@ def _mimo_programs(v5e, monkeypatch):
         params, state, cache).compile()
     prefill = jax.jit(programs.prefill_slice, donate_argnums=(2,)).lower(
         params, batch, cache, shape((), jnp.int32)).compile()
+    assert programs.attention_traced((4, 8192)) == "fused"
     return programs, decode, prefill, cache
 
 
@@ -689,21 +689,37 @@ MIMO_LEAVES = ("bf16[16,2,8448,384]", "bf16[16,4,8448,128]",
                "bf16[16,4,128,384]", "bf16[16,8,128,128]")
 
 
+def _kernel_operands(text, kernel="fused_causal_attention"):
+    """Per launch of ``kernel`` in a compiled program's text: the op that
+    made each of its array operands (the scalar prefetch left out)."""
+    import re
+
+    calls = re.findall(rf"%{kernel}[.\d]* = \S+ custom-call\(([^)]*)\)", text)
+    return [[re.search(rf"^\s*{re.escape(name)} = \S+ (\S+?)\(", text,
+                       re.M).group(1) for name in operands.split(", ")[1:]]
+            for operands in calls]
+
+
 def test_mimo_decode_and_prefill_compile_for_one_device(v5e, monkeypatch):
     """The cell's two programs at the published widths and all 7 held layers
     (2 full, 5 window; 16 of 256 experts held): both fit, decode's experts
-    skip, the prefill's are sorted, the full layers' prefill is the scanned
-    form (a ``while`` in the text), and **no leaf of the cache is copied
-    whole in either program**: keys 192 wide two heads to a row of 384 lanes
-    are written where they lie, as values of 128 are.  **The prefill slice's
-    memory:** 11.92 GB, 4.32 of them temporaries (14.47 and 6.87 before
-    PR 43, when the sorted expert form's buffer held ``T x 8`` = 262,144
-    rows of 4,096 a layer for 16,384 in use, 2.1 GB each for the gathered
-    rows, the products and the rows gathered back).  The buffer now holds
-    twice the even share, 32,768 rows (``moe.sorted_rows``), with one loop
-    over its passes an expert layer, and what sets the peak is a window
-    layer's attention (``f32[4,64,8,8,128,256]``, 2.1 GB of scores with
-    their mask and their bfloat16 copy), not an expert layer."""
+    skip, the prefill's are sorted, **the full layers' prefill is two
+    launches of the fused kernel** (64 query heads of 192 over 4 key heads,
+    values 128: no scanned loop is left, the only ``while``s are the sorted
+    expert form's six, and no float32 score block of the full layers goes to
+    HBM), **q, k and v reach the kernel by bitcast** (the rotary part is the
+    first 64 of 192 and XLA keeps the positions minor all the same, as in
+    GLM's program: no transposing copy of 805 MB a slice and layer), and **no
+    leaf of the cache is copied whole in either program**: keys 192 wide two
+    heads to a row of 384 lanes are written where they lie, as values of 128
+    are.  **The prefill slice's memory:** 11.92 GB, 4.32 of them temporaries,
+    as with the scanned form (14.47 and 6.87 before PR 43, when the sorted
+    expert form's buffer held ``T x 8`` = 262,144 rows of 4,096 a layer for
+    16,384 in use).  The buffer now holds twice the even share, 32,768 rows
+    (``moe.sorted_rows``), with one loop over its passes an expert layer, and
+    what sets the peak is a window layer's attention
+    (``f32[4,64,8,8,128,256]``, 2.1 GB of scores with their mask and their
+    bfloat16 copy), neither an expert layer nor a full layer."""
     programs, decode, prefill, cache = _mimo_programs(v5e, monkeypatch)
     text = decode.as_text()
     assert "ragged-dot" not in text and "ragged-dot" in prefill.as_text()
@@ -718,23 +734,46 @@ def test_mimo_decode_and_prefill_compile_for_one_device(v5e, monkeypatch):
     assert prefill.memory_analysis().temp_size_in_bytes < 4.5e9
     text = prefill.as_text()
     assert "bf16[32768,2048]" in text and "bf16[262144," not in text
-    assert _dispatch_loops(programs, text) == 6
+    assert _dispatch_loops(programs, text) == 6 == text.count(" while(")
+    assert "f32[64,1024,1024]" not in text       # the scanned form's score block
+    assert _kernel_operands(text) == [["bitcast"] * 3] * 2
+    _parts_of_the_compiled(programs, text, "fused_causal_attention",
+                           "attn.core", 2)
 
 
 # -- the fused prefill attention (ops/pallas_attention.py) ------------------
-@pytest.mark.parametrize("blocks", [(1024, 1024), (512, 1024)],
-                         ids=["1024x1024", "512x1024"])
-def test_fused_attention_kernel_compiles_at_the_cell_s_shape(v5e, blocks):
+@pytest.mark.parametrize("q,kv,blocks", [
+    ((2, 16384, 20, 256), (20, 256), (1024, 1024)),
+    ((2, 16384, 20, 256), (20, 256), (512, 1024)),
+    ((4, 8192, 64, 192), (4, 128), (1024, 1024)),
+    ((4, 8192, 64, 192), (4, 128), (512, 1024)),
+    ((4, 8192, 64, 192), (4, 128), (1024, 512)),
+    # the narrowest and a wide key ``supports`` admits in bfloat16: one
+    # sublane tile, and two lane rows and a half
+    ((2, 2048, 8, 16), (2, 128), (1024, 1024)),
+    ((2, 2048, 8, 320), (2, 128), (1024, 1024)),
+], ids=["1024x1024", "512x1024", "mimo-1024x1024", "mimo-512x1024",
+        "mimo-1024x512", "keys-16", "keys-320"])
+def test_fused_attention_kernel_compiles_at_the_cell_s_shape(v5e, q, kv, blocks):
     """A slice of the GLM cell: 2 x 16,384 positions x 20 heads of 256,
-    a head's keys and values whole in VMEM (32 MB, twice)."""
+    a head's keys and values whole in VMEM (32 MB, twice); and of MiMo's:
+    4 x 8,192 x 64 query heads of 192 over 4 key heads, values of 128 (a
+    head's rows are no whole number of lanes once turned: Mosaic takes the
+    contraction over 192 as it stands).  What ``supports`` admits compiles."""
     from can_tpu.ops import pallas_attention as fused_attn
 
     one = SingleDeviceSharding(v5e[0])
-    x = jax.ShapeDtypeStruct((2, 16384, 20, 256), jnp.bfloat16, sharding=one)
-    n = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=one)
+    b, l, _, d = q
+    assert fused_attn.supports(q, (b, l) + kv, jnp.bfloat16, block_q=blocks[0],
+                               block_k=blocks[1], interpret=True)
+
+    def arr(*s):
+        return jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one)
+
+    n = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one)
     compiled = jax.jit(lambda q, k, v, n: fused_attn.fused_causal(
-        q, k, v, n, scale=1 / 16, block_q=blocks[0], block_k=blocks[1])
-        ).lower(x, x, x, n).compile()
+        q, k, v, n, block_q=blocks[0], block_k=blocks[1])).lower(
+            arr(*q), arr(b, l, kv[0], d), arr(b, l, *kv), n).compile()
     assert "fused_causal_attention" in compiled.as_text()
 
 
@@ -744,8 +783,6 @@ def test_glm_prefill_slice_compiles_with_the_fused_attention(v5e, monkeypatch):
     here): six kernel launches and no loop, no score block in HBM, and
     q, k, v handed to the kernel as XLA leaves them (positions in the
     lanes): a bitcast, never a transposing copy."""
-    import re
-
     from can_tpu.models import glm_moe_lite as gm
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -755,14 +792,8 @@ def test_glm_prefill_slice_compiles_with_the_fused_attention(v5e, monkeypatch):
         params, batch, cache, shape((), jnp.int32)).compile()
     assert gm.attention_traced((2, 16384)) == "fused"
     text = compiled.as_text()
-    calls = re.findall(r"%fused_causal_attention[.\d]* = \S+ custom-call\(([^)]*)\)",
-                       text)
-    assert len(calls) == 6
     assert " while(" not in text and "f32[20,1024,1024]" not in text
-    for operands in calls:
-        for name in operands.split(", ")[1:]:
-            made = re.search(rf"^\s*{re.escape(name)} = \S+ (\S+?)\(", text, re.M)
-            assert made and made.group(1) == "bitcast", (name, made and made.group(1))
+    assert _kernel_operands(text) == [["bitcast"] * 3] * 6
     assert 9 * 2**30 < _fits_hbm(compiled) < 13 * 2**30
     found = _parts_of_the_compiled(programs, text, "fused_causal_attention",
                                    "attn.core", 6)

@@ -8,10 +8,12 @@ seeded weights, alone and through the ONE serving path.  Tiny preset: hidden
 values 16, window 8, 7 layers ``F S S S S F S`` with layer 0 dense, 32
 experts top-4, vocabulary 512, float32, CPU."""
 
+import functools
 import inspect
 import json
 import os
 import time
+import types
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +27,7 @@ from can_tpu.obs import Telemetry, spans
 from can_tpu.ops import attention as attn_ops
 from can_tpu.ops import cache_layout as layout
 from can_tpu.ops import moe as moe_ops
+from can_tpu.ops import pallas_attention as fused_attn
 from can_tpu.serve import GenerateService, build_model_service, lm_probe_steps
 from can_tpu.serve import cache as kv_cache
 from can_tpu.serve import programs as serve_programs
@@ -206,6 +209,69 @@ def test_a_full_layer_s_prefill_forms_take_values_narrower_than_keys(tiny):
                                     jnp.asarray([13]), block=8)
     np.testing.assert_allclose(_out(short, p)[:13], want[:13], **TOL)
     assert not np.asarray(short)[0, 16:].any()      # blocks past the length
+
+
+@pytest.mark.parametrize("heads,form", [(None, "scanned"),
+                                        (PUBLISHED_HEADS, "fused")],
+                         ids=["tiny-scanned", "keys-192-values-128-fused"])
+def test_a_full_layer_s_prefill_asks_the_kernel_s_supports(tiny, monkeypatch,
+                                                           heads, form):
+    """``_full_prefill`` runs the fused kernel where ``supports`` says yes
+    (here: interpreted, blocks of 8; keys of 192 beside values of 128, four
+    query heads to a key head) and the scanned form where it refuses (the
+    tiny preset's values of 16 are no whole lanes); either way the layer's
+    output is the reference's, with a length that stops short of the bucket
+    too, and the model notes the form its trace took.  On the CPU backend
+    nothing is steered and the form is the scanned one."""
+    p, cfg, x, q, k, v, want = _layer_io(tiny, False, heads=heads)
+    lengths = jnp.asarray([32], jnp.int32)
+    plain = mv._full_prefill(q, k, v, lengths)
+    assert mv.attention_traced((1, 32)) == "scanned"
+    monkeypatch.setattr(mv, "fused_attn", types.SimpleNamespace(
+        supports=functools.partial(fused_attn.supports, block_q=8, block_k=8,
+                                   interpret=True),
+        fused_causal=functools.partial(fused_attn.fused_causal, block_q=8,
+                                       block_k=8, interpret=True)))
+    o = mv._full_prefill(q, k, v, lengths)
+    assert mv.attention_traced((1, 32)) == form
+    assert o.shape == plain.shape == q.shape[:-1] + v.shape[-1:]
+    np.testing.assert_allclose(_out(o, p), want, **TOL)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(plain), **TOL)
+    short = mv._full_prefill(q, k, v, jnp.asarray([13]))
+    np.testing.assert_allclose(_out(short, p)[:13], want[:13], **TOL)
+    if form == "fused":         # blocks of 8 past the length: zeros, unread
+        assert not np.asarray(short)[0, 16:].any()
+
+
+def test_the_whole_prefill_through_the_fused_kernel_is_the_scanned_one(
+        monkeypatch):
+    """Both full layers of the tiny model at the published head widths
+    through the interpreted kernel: the logits, the cache and the routing of
+    the whole prefill are the scanned form's."""
+    d, cfg, params = tiny_mimo_model(seed=5, **PUBLISHED_HEADS)
+    tokens, lengths = _padded([_tokens(32, 1), _tokens(13, 2)], 32)
+    want, want_cache, want_routing = mv.prefill(params, tokens, lengths, cfg, 40)
+    assert mv.attention_traced((2, 32)) == "scanned"
+    monkeypatch.setattr(mv, "fused_attn", types.SimpleNamespace(
+        supports=lambda *a, **kw: True,
+        fused_causal=functools.partial(fused_attn.fused_causal, block_q=16,
+                                       block_k=16, interpret=True)))
+    got, got_cache, got_routing = mv.prefill(params, tokens, lengths, cfg, 40)
+    assert mv.attention_traced((2, 32)) == "fused"
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
+    np.testing.assert_array_equal(np.asarray(got_routing["choices"]),
+                                  np.asarray(want_routing["choices"]))
+    for a, b, spec in zip(got_cache["layers"], want_cache["layers"],
+                          mv.cache_layout(cfg)):
+        for name in a:
+            if spec.kind == "ring":
+                np.testing.assert_allclose(np.asarray(a[name]),
+                                           np.asarray(b[name]), **TOL)
+            else:       # positions past a length hold what nobody reads
+                valid = (np.arange(40)[None] < np.asarray(lengths)[:, None])
+                valid = valid[:, None, :, None]
+                np.testing.assert_allclose(np.where(valid, a[name], 0),
+                                           np.where(valid, b[name], 0), **TOL)
 
 
 def test_prefill_causal_without_groups_is_what_it_was():
@@ -498,7 +564,8 @@ def test_the_table_builds_the_same_programs_class():
     made, params = entry.programs(mimo_config(), None, 3)
     assert isinstance(made, serve_programs.LMPrograms)
     assert made.vocab_size == 512
-    assert made.attention_traced is None      # one form: nothing to note
+    # two forms of the full layers' prefill: the model notes which it traced
+    assert made.attention_traced is mv.attention_traced
     assert made.ssm_traced is None and made.conv_traced is None
     assert [s.kind for s in made.cache_layout] == [
         "full", "ring", "ring", "ring", "ring", "full", "ring"]
@@ -551,7 +618,11 @@ def test_the_counters_read_the_share_and_both_kinds_of_cache(service):
     assert stats["cache_bytes"] == {"full": 4 * 30 * 2 * 2 * 40 * 4,
                                     "ring": 4 * 5 * 4 * 40 * 8 * 4}
     assert stats["decode_experts_read"] == stats["decode_experts_held"] > 0
-    assert stats["prefill_attention"] == {}        # one form: none is noted
+    # on the CPU every prefill's full layers ran the scanned form
+    assert set(stats["prefill_attention"]) == {"scanned"}
+    assert 0 < stats["prefill_attention"]["scanned"] <= stats["launches"]
+    assert svc.engine.prefill_attention == {(2, 16): "scanned",
+                                            (2, 32): "scanned"}
 
 
 def test_the_spans_name_the_experts_form_and_both_kinds_of_attention(service):
@@ -567,7 +638,9 @@ def test_the_spans_name_the_experts_form_and_both_kinds_of_attention(service):
     launch = next(s for s in ring if s["name"] == "serve.dispatch"
                   and s.get("parent_id") == want)
     inner = {s["name"]: s for s in ring if s.get("parent_id") == launch["span_id"]}
-    assert "attention" not in inner["lm.prefill"]  # the scanned form, always
+    # the full layers' form as the slices' programs traced it: on the CPU
+    # ``pallas_attention.supports`` says no
+    assert inner["lm.prefill"]["attention"] == "scanned"
     assert inner["lm.decode"]["experts"] == "batched"
     scopes = [s for s in ring if s["name"] == "program.scopes"]
     assert {s["program"] for s in scopes} == {"jit_prefill_slice", "jit_decode"}

@@ -24,11 +24,21 @@ from lm_tiny import tiny_glm_config
 L = 64
 
 
-def _qkv(h, d, dv, seed=0, b=2, dtype=jnp.float32):
+def _qkv(h, d, dv, seed=0, b=2, dtype=jnp.float32, kv=None):
+    """``kv`` key/value heads under ``h`` query heads (as many when None)."""
+    kv = h if kv is None else kv
     ks = jax.random.split(jax.random.key(seed), 3)
     return (jax.random.normal(ks[0], (b, L, h, d), dtype),
-            jax.random.normal(ks[1], (b, L, h, d), dtype),
-            jax.random.normal(ks[2], (b, L, h, dv), dtype))
+            jax.random.normal(ks[1], (b, L, kv, d), dtype),
+            jax.random.normal(ks[2], (b, L, kv, dv), dtype))
+
+
+def _plain(q, k, v, scale):
+    """The plain softmax of ``test_glm_moe_lite``, a group's query heads
+    reading the key/value head they share."""
+    g = q.shape[2] // k.shape[2]
+    return glm_tests.TestPrefillCausal()._plain(
+        q, jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2), scale)
 
 
 def _valid(lengths):
@@ -37,18 +47,26 @@ def _valid(lengths):
 
 @pytest.mark.parametrize("blocks", [(16, 16), (32, 16)],
                          ids=["16x16", "32x16"])
-@pytest.mark.parametrize("d,dv", [(128, 128), (128, 256)],
-                         ids=["D=Dv", "D!=Dv"])
+@pytest.mark.parametrize("d,dv,h,kv", [
+    (128, 128, 3, 3), (128, 256, 3, 3),
+    # keys that are no whole number of lanes beside values that are, and
+    # groups of G = H / KV query heads to a key/value head (MiMo's full
+    # layers: 192 beside 128, G = 16)
+    (192, 128, 3, 3), (192, 128, 8, 2), (192, 128, 16, 1), (64, 128, 4, 2),
+    (128, 128, 8, 2), (16, 256, 6, 3),
+], ids=["D=Dv", "D!=Dv", "192-G1", "192-G4", "192-G16", "64-G2", "128-G4",
+        "16-G2"])
 @pytest.mark.parametrize("lengths", [[64, 64], [64, 37], [48, 32], [16, 5]],
                          ids=["full", "inside-a-block", "at-an-edge",
                               "one-block"])
 def test_the_kernel_against_the_scanned_form_and_the_plain_softmax(
-        lengths, d, dv, blocks):
-    """Three heads (not a power of two).  Where both forms cut the queries
-    into the same blocks they agree on every row, the zeros of the blocks
-    past a length included; rows of valid positions agree always."""
+        lengths, d, dv, h, kv, blocks):
+    """Three heads (not a power of two) a key head each, and groups of query
+    heads over fewer key heads.  Where both forms cut the queries into the
+    same blocks they agree on every row, the zeros of the blocks past a
+    length included; rows of valid positions agree always."""
     bq, bk = blocks
-    q, k, v = _qkv(3, d, dv)
+    q, k, v = _qkv(h, d, dv, kv=kv)
     n = jnp.asarray(lengths, jnp.int32)
     got = np.asarray(fused_attn.fused_causal(q, k, v, n, scale=0.1,
                                              block_q=bq, block_k=bk,
@@ -56,7 +74,7 @@ def test_the_kernel_against_the_scanned_form_and_the_plain_softmax(
     scanned = np.asarray(attn_ops.prefill_causal(q, k, v, n, scale=0.1,
                                                  block=bq))
     np.testing.assert_allclose(got, scanned, atol=2e-5, rtol=2e-5)
-    plain = np.asarray(glm_tests.TestPrefillCausal()._plain(q, k, v, 0.1))
+    plain = np.asarray(_plain(q, k, v, 0.1))
     valid = _valid(lengths)
     np.testing.assert_allclose(np.where(valid, got, 0),
                                np.where(valid, plain, 0), atol=2e-5, rtol=2e-5)
@@ -130,19 +148,53 @@ class TestSupports:
         assert fused_attn.supports(self.Q, self.V, jnp.bfloat16)
 
     @pytest.mark.parametrize("q,v", [
-        ((2, 2048, 3, 192), (2, 2048, 3, 128)),      # D not whole lanes
+        ((2, 2048, 3, 200), (2, 2048, 3, 128)),      # D not whole sublane tiles
         ((2, 2048, 3, 256), (2, 2048, 3, 64)),       # Dv not whole lanes
+        ((2, 2048, 3, 192), (2, 2048, 3, 192)),      # Dv 192: D may be, Dv not
         ((2, 1536, 3, 256), (2, 1536, 3, 128)),      # L not whole blocks
+        ((2, 512, 64, 192), (2, 512, 4, 128)),       # a bucket under a block
+        ((2, 2048, 6, 192), (2, 2048, 4, 128)),      # H no whole groups of KV
         ((2, 65536, 3, 256), (2, 65536, 3, 256)),    # a head over the VMEM
-    ], ids=["D", "Dv", "L", "vmem"])
+        # 100 MiB to the byte without the turned queries' scratch of
+        # (1,024, 256): counted (192 lies in 256 lanes), it is over
+        ((1, 66560, 16, 192), (1, 66560, 1, 128)),
+    ], ids=["D", "Dv", "Dv-192", "L", "L-under-a-block", "groups", "vmem",
+            "vmem-by-the-padded-scratch"])
     def test_a_shape_it_cannot_take(self, q, v):
         assert not fused_attn.supports(q, v, jnp.bfloat16, interpret=True)
+
+    @pytest.mark.parametrize("q,v", [
+        ((2, 2048, 3, 192), (2, 2048, 3, 128)),      # D of 192: whole tiles
+        ((2, 2048, 64, 192), (2, 2048, 4, 128)),     # 16 query heads a key head
+        ((1, 65536, 16, 192), (1, 65536, 1, 128)),   # a block under the budget
+        ((4, 8192, 64, 192), (4, 8192, 4, 128)),     # MiMo's slice
+    ], ids=["D-192", "groups", "vmem", "mimo"])
+    def test_a_shape_it_takes(self, q, v):
+        assert fused_attn.supports(q, v, jnp.bfloat16, interpret=True)
+
+    def test_the_scratch_is_counted_in_whole_lanes(self):
+        """A head of 192 turned lies in 256 lanes: its scratch costs what a
+        head of 256's does; the resident keys and blocks cost 192."""
+        at = functools.partial(fused_attn._vmem_bytes, 8192, dv=128,
+                               itemsize=2, block_q=1024, block_k=1024)
+        assert at(d=256) - at(d=192) == (2 * 8192 + 2 * 1024) * 64 * 2
+        assert at(d=192) - at(d=128) == ((2 * 8192 + 2 * 1024) * 64 * 2
+                                         + 1024 * 128 * 2)
 
     def test_the_kernel_refuses_what_supports_refuses(self):
         x = jnp.zeros((1, 32, 1, 96))
         with pytest.raises(ValueError, match="cannot take"):
             fused_attn.fused_causal(x, x, x, block_q=16, block_k=16,
                                     interpret=True)
+
+    def test_the_kernel_refuses_keys_of_another_shape_than_the_queries_say(self):
+        """``supports`` reads ``q`` and ``v``; ``k`` has to be ``v``'s heads
+        of ``q``'s width."""
+        q, v = jnp.zeros((1, 32, 4, 128)), jnp.zeros((1, 32, 2, 128))
+        for k in (jnp.zeros((1, 32, 4, 128)), jnp.zeros((1, 32, 2, 64))):
+            with pytest.raises(ValueError, match="cannot take"):
+                fused_attn.fused_causal(q, k, v, block_q=16, block_k=16,
+                                        interpret=True)
 
     def test_the_cell_s_shape_fits(self):
         assert fused_attn.supports((2, 16384, 20, 256), (2, 16384, 20, 256),
