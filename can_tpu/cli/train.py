@@ -940,7 +940,7 @@ def _run_elastic_generations(args, run_cfg, topo, *, supervisor,
 
             from can_tpu.models.cannet import LocalOps
 
-            # the BN-moments seam rides LocalOps beside context_fused;
+            # the BN-moments seam rides LocalOps;
             # dp-path only (the sp step takes bn_ops directly)
             apply_fn = functools.partial(cannet_apply,
                                          ops=LocalOps(bn_ops=bn_ops))

@@ -27,6 +27,3 @@ __all__ = [
     "stage1_layout",
     "stage1_traced",
 ]
-
-from can_tpu.models.flax_module import CANNet as FlaxCANNet  # noqa: E402
-__all__.append("FlaxCANNet")

@@ -69,9 +69,6 @@ class LocalOps:
     upsample: Callable = resize_bilinear_align_corners
     # Full (unsharded) feature H, W; None means "use local shape".
     global_hw: Any = None
-    # Optional fused context tail: (fv, [ave_k], [W_k], hw) -> fi
-    # (ops/pallas_context.py provides the TPU kernel).
-    context_fused: Any = None
     # Optional BN-moments implementation (ops/bn_moments.py BNOps): the
     # train-mode batch moments of every BN layer route through it —
     # "onepass" reads the feature map once and issues ONE packed psum per
@@ -330,11 +327,6 @@ def context_block(cparams: Mapping, fv: jax.Array, *,
     """Multi-scale context fusion (reference model/CANNet.py:39-84):
     fi = (sum_k w_k * sm_k) / (sum_k w_k + 1e-12) with
     sm_k = upsample(1x1(adaptive_pool(fv, k))), w_k = sigmoid(1x1(sm_k - fv)).
-
-    ``ops.context_fused`` (e.g. the Pallas kernel in ops/pallas_context.py)
-    replaces the fusion tail — everything after the per-scale pooled
-    projections — with a single HBM pass; the pooling itself is tiny and
-    stays outside.
     """
     hw = ops.global_hw or (fv.shape[-3], fv.shape[-2])
     aves = []
@@ -345,9 +337,6 @@ def context_block(cparams: Mapping, fv: jax.Array, *,
                             precision=precision))
     weights = [cparams[f"s{s}"]["weight"].astype(fv.dtype)
                for s in CONTEXT_SCALES]
-    if ops.context_fused is not None:
-        return ops.context_fused(fv, aves, weights, hw)
-
     num = 0.0
     den = 0.0
     for ave, wmat in zip(aves, weights):

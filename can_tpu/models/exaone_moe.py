@@ -33,10 +33,12 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from can_tpu.models import lm_blocks
 from can_tpu.models.lm_blocks import experts_form  # noqa: F401  (the serving path asks the model for it)
-from can_tpu.models.lm_blocks import (VocabSlice, count_shapes, embed,
-                                      init_from_shapes, last_hidden, lm_head,
-                                      qkv_heads, rms_norm, routing_report)
+from can_tpu.models.lm_blocks import (VocabSlice, count_shapes,
+                                      init_from_shapes, kv_decode, kv_entry,
+                                      last_hidden, lm_head, qkv_heads,
+                                      rms_norm)
 from can_tpu.models.lm_blocks import ffn as _ffn
 from can_tpu.ops import attention as attn_ops
 from can_tpu.ops import cache_layout as layout
@@ -170,16 +172,19 @@ def init_params(key, cfg: ExaoneMoeConfig, dtype=jnp.bfloat16):
     return init_from_shapes(key, param_shapes(cfg), dtype)
 
 
+def _kv_spec(cfg: ExaoneMoeConfig, layer_type: str) -> layout.LayerSpec:
+    return layout.kv_layer(
+        layout.RING if layer_type == WINDOW else layout.FULL,
+        kv_heads=cfg.num_key_value_heads, head_dim=cfg.head_dim,
+        window=cfg.sliding_window)
+
+
 def cache_layout(cfg: ExaoneMoeConfig) -> tuple:
     """What each held layer keeps in a launch's cache
     (``ops/cache_layout.py``): a ring of ``sliding_window`` positions in
     window layers, the whole context in full ones, keys and values per
     key/value head."""
-    return tuple(
-        layout.kv_layer(layout.RING if t == WINDOW else layout.FULL,
-                        kv_heads=cfg.num_key_value_heads,
-                        head_dim=cfg.head_dim, window=cfg.sliding_window)
-        for t in cfg.layer_types)
+    return tuple(_kv_spec(cfg, t) for t in cfg.layer_types)
 
 
 # -- layers -------------------------------------------------------------
@@ -203,8 +208,8 @@ def ffn(layer, h, cfg: ExaoneMoeConfig):
 
 
 # -- prefill ------------------------------------------------------------
-def _prefill_block(layer, layer_type, x, positions, cfg,
-                   cache_len: Optional[int], lengths):
+def _prefill_block(layer, layer_type, x, positions, lengths, cfg,
+                   cache_len: Optional[int]):
     """One block over whole prompts; -> (y, cache entry or None, chosen)."""
     with jax.named_scope("attn.proj"):
         xn = rms_norm(x, layer["ln_in"], cfg.rms_norm_eps) if cfg.pre_norm else x
@@ -219,39 +224,18 @@ def _prefill_block(layer, layer_type, x, positions, cfg,
         h = x + jnp.dot(o.reshape(b, l, -1), layer["attn"]["wo"])
     entry = None
     if cache_len is not None:
-        with jax.named_scope("attn.cache"):
-            if layer_type == WINDOW:
-                entry = attn_ops.ring_entry(k, v, lengths, cfg.sliding_window)
-            else:
-                kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-                pad = ((0, 0), (0, 0), (0, cache_len - l), (0, 0))
-                entry = {"k": jnp.pad(kt, pad), "v": jnp.pad(vt, pad)}
+        entry = kv_entry(_kv_spec(cfg, layer_type), k, v, lengths, cache_len)
     y, chosen = ffn(layer, h, cfg)
     return y, entry, chosen
 
 
 def prefill_hidden(params, tokens, lengths, cfg: ExaoneMoeConfig,
                    cache_len: Optional[int] = None, active=None):
-    """Whole prompts through the blocks: -> (hidden (B, L, d) before the
-    final norm, cache or None, ``routing_report`` of the valid tokens).
-    ``tokens`` (B, L) right-padded, ``lengths`` (B,); ``active`` (B,) marks
-    the sequences whose routing is counted (all when None).  Padded
-    positions compute garbage no valid position ever sees (attention is
-    causal)."""
-    b, l = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(l)[None], (b, l))
-    mask = positions < lengths[:, None]
-    if active is not None:
-        mask &= active[:, None]
-    x = embed(params, tokens)
-    entries, chosen = [], []
-    for layer, lt in zip(params["layers"], cfg.layer_types):
-        x, entry, c = _prefill_block(layer, lt, x, positions, cfg, cache_len,
-                                     lengths)
-        entries.append(entry)
-        chosen.append(c)
-    cache = None if cache_len is None else {"layers": entries}
-    return x, cache, routing_report(chosen, mask, lengths - 1, cfg)
+    """``lm_blocks.prefill_stack`` over ``_prefill_block``: -> (hidden (B,
+    L, d) before the final norm, cache or None, routing).  Padded positions
+    compute garbage no valid position ever sees (attention is causal)."""
+    return lm_blocks.prefill_stack(params, tokens, lengths, cfg.layer_types,
+                                   _prefill_block, cfg, cache_len, active)
 
 
 def prefill(params, tokens, lengths, cfg: ExaoneMoeConfig, cache_len: int,
@@ -265,63 +249,33 @@ def prefill(params, tokens, lengths, cfg: ExaoneMoeConfig, cache_len: int,
 
 
 # -- decode -------------------------------------------------------------
+def _decode_block(layer, layer_type, x, entry, positions, column, cfg):
+    """One block over one token a sequence, its key and value written into
+    ``entry`` before it attends; -> (y, the entry, chosen)."""
+    with jax.named_scope("attn.proj"):
+        xn = rms_norm(x, layer["ln_in"], cfg.rms_norm_eps) if cfg.pre_norm else x
+        q, k, v = _qkv(layer["attn"], xn, column, layer_type, cfg)
+    o, entry = kv_decode(_kv_spec(cfg, layer_type), q, k, v, entry, positions,
+                         column, ("attn.core",))
+    with jax.named_scope("attn.out"):
+        h = x + jnp.dot(o.reshape(x.shape[0], 1, -1), layer["attn"]["wo"])
+    y, chosen = ffn(layer, h, cfg)
+    return y, entry, chosen
+
+
 def decode_step(params, cache, tokens, positions, cfg: ExaoneMoeConfig,
                 active=None):
-    """One token per sequence: ``tokens`` (B,) at ``positions`` (B,) ->
-    (float32 logits (B, V) for the next position, cache, routing).  The
-    token's key and value are written at its position (its ring slot in
-    window layers) before it attends.  ``active`` (B,) marks the slots
-    whose routing is counted (all when None)."""
-    b = tokens.shape[0]
-    pos2 = positions[:, None]
-    x = embed(params, tokens)[:, None]                       # (B, 1, d)
-    entries, chosen = [], []
-    for layer, lt, entry in zip(params["layers"], cfg.layer_types,
-                                cache["layers"]):
-        with jax.named_scope("attn.proj"):
-            xn = (rms_norm(x, layer["ln_in"], cfg.rms_norm_eps)
-                  if cfg.pre_norm else x)
-            q, k, v = _qkv(layer["attn"], xn, pos2, lt, cfg)
-        with jax.named_scope("attn.core"):
-            if lt == WINDOW:
-                slot = jnp.mod(positions, cfg.sliding_window)
-                valid = attn_ops.ring_positions(positions,
-                                                cfg.sliding_window) >= 0
-            else:
-                slot = positions
-                valid = jnp.arange(entry["k"].shape[2])[None, :] <= pos2
-        with jax.named_scope("attn.cache"):
-            kc = attn_ops.write_slot(entry["k"], k[:, 0], slot)
-            vc = attn_ops.write_slot(entry["v"], v[:, 0], slot)
-        with jax.named_scope("attn.core"):
-            o = attn_ops.decode(q[:, 0], kc, vc, valid)
-        with jax.named_scope("attn.out"):
-            h = x + jnp.dot(o.reshape(b, 1, -1), layer["attn"]["wo"])
-        entries.append({"k": kc, "v": vc})
-        x, c = ffn(layer, h, cfg)
-        chosen.append(c)
-    mask = jnp.ones((b, 1), bool) if active is None else active[:, None]
-    return (lm_head(params, x[:, 0], cfg), {"layers": entries},
-            routing_report(chosen, mask, jnp.zeros((b,), jnp.int32), cfg))
+    """``lm_blocks.decode_stack`` over ``_decode_block``: ``tokens`` (B,) at
+    ``positions`` (B,) -> (float32 logits (B, V) for the next position,
+    cache, routing).  The token's key and value are written at its position
+    (its ring slot in window layers) before it attends."""
+    return lm_blocks.decode_stack(params, cache, tokens, positions,
+                                  cfg.layer_types, _decode_block, cfg, active)
 
 
 # -- multi-token prediction -----------------------------------------------
 def mtp_logits(params, hidden, next_tokens, cfg: ExaoneMoeConfig):
-    """The MTP module in DeepSeek-V3's form, over whole sequences:
-    ``h' = W_p [RMSNorm(h_t); RMSNorm(Emb(x_{t+1}))]``, one full-attention
-    block with an expert layer, the module's norm and the SHARED head:
-    float32 logits (B, L, V) for position ``t + 2``.  ``hidden`` (B, L, d)
-    is ``prefill_hidden``'s, ``next_tokens`` (B, L) the ids at ``t + 1``."""
-    m = params["mtp"]
-    b, l, _ = hidden.shape
-    with jax.named_scope("mtp"):
-        x = jnp.concatenate(
-            [rms_norm(hidden, m["ln_hidden"], cfg.rms_norm_eps),
-             rms_norm(embed(params, next_tokens), m["ln_embed"],
-                      cfg.rms_norm_eps)], axis=-1)
-        x = jnp.dot(x, m["proj"])
-        positions = jnp.broadcast_to(jnp.arange(l)[None], (b, l))
-        x, _, _ = _prefill_block(m["block"], FULL, x, positions, cfg, None,
-                                 None)
-        x = rms_norm(x, m["final_norm"], cfg.rms_norm_eps)
-        return jnp.dot(x, params["head"], preferred_element_type=jnp.float32)
+    """``lm_blocks.mtp_logits`` with one full-attention block with an expert
+    layer: float32 logits (B, L, V) for position ``t + 2``."""
+    return lm_blocks.mtp_logits(params, hidden, next_tokens, cfg,
+                                _prefill_block, FULL)

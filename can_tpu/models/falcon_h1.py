@@ -59,8 +59,9 @@ import jax
 import jax.numpy as jnp
 
 from can_tpu.models.lm_blocks import (VocabSlice, count_shapes, embed,
-                                      init_from_shapes, last_hidden, lm_head,
-                                      no_routing, rms_norm, swiglu)
+                                      init_from_shapes, kv_decode, kv_entry,
+                                      last_hidden, lm_head, no_routing,
+                                      rms_norm, swiglu)
 from can_tpu.ops import attention as attn_ops
 from can_tpu.ops import cache_layout as layout
 from can_tpu.ops import ssm as ssm_ops
@@ -275,13 +276,17 @@ def init_params(key, cfg: FalconH1Config, dtype=jnp.bfloat16):
     return params
 
 
+def _kv_spec(cfg: FalconH1Config) -> layout.LayerSpec:
+    return layout.kv_layer(layout.FULL, kv_heads=cfg.num_key_value_heads,
+                           head_dim=cfg.head_dim)
+
+
 def cache_layout(cfg: FalconH1Config) -> tuple:
     """What each held layer keeps in a launch's cache
     (``ops/cache_layout.py``), two kinds in ONE layer: keys and values of
     every position, and the mixer's state (float32) with the convolution's
     last inputs (the cache's dtype), neither of which has positions."""
-    block = (layout.kv_layer(layout.FULL, kv_heads=cfg.num_key_value_heads,
-                             head_dim=cfg.head_dim),
+    block = (_kv_spec(cfg),
              layout.state_layer(
                  ssm=((cfg.mamba_n_heads, cfg.mamba_d_head,
                        cfg.mamba_d_state), ASSUMED["state_dtype"]),
@@ -430,11 +435,8 @@ def _prefill_block(layer, x, positions, lengths, cfg, cache_len):
         h = _mixed(x, m, o, cfg)   # the residual and both branches' sum
     entry = None
     if cache_len is not None:
-        with jax.named_scope("attn.cache"):
-            pad = ((0, 0), (0, 0), (0, cache_len - l), (0, 0))
-            entry = {"k": jnp.pad(k.transpose(0, 2, 1, 3), pad),
-                     "v": jnp.pad(v.transpose(0, 2, 1, 3), pad),
-                     "ssm": state, "conv": tail}
+        entry = {**kv_entry(_kv_spec(cfg), k, v, lengths, cache_len),
+                 "ssm": state, "conv": tail}
     return ffn(layer, h, cfg), entry
 
 
@@ -487,13 +489,8 @@ def decode_step(params, cache, tokens, positions, cfg: FalconH1Config,
             u = rms_norm(x, layer["ln_in"], cfg.rms_norm_eps)
             q, k, v = _qkv(layer["attn"],
                            _scaled(u, cfg.attention_in_multiplier), pos2, cfg)
-        with jax.named_scope("attn.core"):
-            valid = jnp.arange(entry["k"].shape[2])[None, :] <= pos2
-        with jax.named_scope("attn.cache"):
-            kc = attn_ops.write_slot(entry["k"], k[:, 0], positions)
-            vc = attn_ops.write_slot(entry["v"], v[:, 0], positions)
-        with jax.named_scope("attn.core"):
-            o = attn_ops.decode(q[:, 0], kc, vc, valid)
+        o, kv = kv_decode(_kv_spec(cfg), q, k, v, entry, positions, pos2,
+                          ("attn.core",))
         with jax.named_scope("attn.out"):
             o = jnp.dot(o.reshape(b, 1, -1), layer["attn"]["wo"])
         with jax.named_scope("ssm.proj"):
@@ -501,7 +498,7 @@ def decode_step(params, cache, tokens, positions, cfg: FalconH1Config,
         m, state, tail = mixer_step(layer["mixer"], u_ssm, entry, active, cfg)
         with jax.named_scope("attn.out"):
             h = _mixed(x, m[:, None], o, cfg)   # the residual, both branches
-        entries.append({"k": kc, "v": vc, "ssm": state, "conv": tail})
+        entries.append({**kv, "ssm": state, "conv": tail})
         x = ffn(layer, h, cfg)
     return (lm_head(params, x[:, 0], cfg, cfg.lm_head_multiplier),
             {"layers": entries}, no_routing(b))

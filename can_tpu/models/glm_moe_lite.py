@@ -47,9 +47,10 @@ import jax
 import jax.numpy as jnp
 
 from can_tpu.models.lm_blocks import experts_form  # noqa: F401  (the serving path asks the model for it)
-from can_tpu.models.lm_blocks import (VocabSlice, count_shapes, embed, ffn,
+from can_tpu.models import lm_blocks
+from can_tpu.models.lm_blocks import (VocabSlice, count_shapes, ffn,
                                       init_from_shapes, last_hidden, lm_head,
-                                      rms_norm, routing_report)
+                                      rms_norm)
 from can_tpu.ops import attention as attn_ops
 from can_tpu.ops import cache_layout as layout
 from can_tpu.ops import pallas_attention as fused_attn
@@ -329,8 +330,12 @@ def attention_absorbed(p, xn, positions, entry, cfg: Glm4MoeLiteConfig):
 
 
 # -- prefill ------------------------------------------------------------
-def _prefill_block(layer, x, positions, cfg, cache_len: Optional[int],
-                   lengths):
+# ``lm_blocks``' stacks hand a block its layer's label (here its
+# ``LayerSpec``) and a step's positions as a column too: every layer is the
+# one latent kind and ``attention_absorbed`` takes ``positions`` as they
+# come, so neither is read below
+def _prefill_block(layer, kind, x, positions, lengths, cfg,
+                   cache_len: Optional[int]):
     """One block over whole prompts; -> (y, cache entry or None, chosen)."""
     with jax.named_scope("attn.proj"):
         xn = rms_norm(x, layer["ln_in"], cfg.rms_norm_eps)
@@ -349,26 +354,12 @@ def _prefill_block(layer, x, positions, cfg, cache_len: Optional[int],
 
 def prefill_hidden(params, tokens, lengths, cfg: Glm4MoeLiteConfig,
                    cache_len: Optional[int] = None, active=None):
-    """Whole prompts through the blocks: -> (hidden (B, L, d) before the
-    final norm, cache or None, ``routing_report`` of the valid tokens).
-    ``tokens`` (B, L) right-padded, ``lengths`` (B,); ``active`` (B,) marks
-    the sequences whose routing is counted (all when None).  Padded
-    positions compute garbage (or nothing: attention skips whole blocks of
-    them) that no valid position ever sees."""
-    b, l = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(l)[None], (b, l))
-    mask = positions < lengths[:, None]
-    if active is not None:
-        mask &= active[:, None]
-    x = embed(params, tokens)
-    entries, chosen = [], []
-    for layer in params["layers"]:
-        x, entry, c = _prefill_block(layer, x, positions, cfg, cache_len,
-                                     lengths)
-        entries.append(entry)
-        chosen.append(c)
-    cache = None if cache_len is None else {"layers": entries}
-    return x, cache, routing_report(chosen, mask, lengths - 1, cfg)
+    """``lm_blocks.prefill_stack`` over ``_prefill_block``: -> (hidden (B,
+    L, d) before the final norm, cache or None, routing).  Padded positions
+    compute garbage (or nothing: attention skips whole blocks of them) that
+    no valid position ever sees."""
+    return lm_blocks.prefill_stack(params, tokens, lengths, cache_layout(cfg),
+                                   _prefill_block, cfg, cache_len, active)
 
 
 def prefill(params, tokens, lengths, cfg: Glm4MoeLiteConfig, cache_len: int,
@@ -381,43 +372,31 @@ def prefill(params, tokens, lengths, cfg: Glm4MoeLiteConfig, cache_len: int,
 
 
 # -- decode -------------------------------------------------------------
+def _decode_block(layer, kind, x, entry, positions, column, cfg):
+    """One block over one token a sequence, its latent written into
+    ``entry`` before it attends; -> (y, the entry, chosen)."""
+    with jax.named_scope("attn.proj"):
+        xn = rms_norm(x, layer["ln_in"], cfg.rms_norm_eps)
+    o, entry = attention_absorbed(layer["attn"], xn, positions, entry, cfg)
+    with jax.named_scope("attn.out"):
+        h = x + o
+    y, chosen = ffn(layer, h, cfg)
+    return y, entry, chosen
+
+
 def decode_step(params, cache, tokens, positions, cfg: Glm4MoeLiteConfig,
                 active=None):
-    """One token per sequence: ``tokens`` (B,) at ``positions`` (B,) ->
-    (float32 logits (B, V) for the next position, cache, routing).
-    ``active`` (B,) marks the slots whose routing is counted."""
-    b = tokens.shape[0]
-    x = embed(params, tokens)[:, None]                        # (B, 1, d)
-    entries, chosen = [], []
-    for layer, entry in zip(params["layers"], cache["layers"]):
-        with jax.named_scope("attn.proj"):
-            xn = rms_norm(x, layer["ln_in"], cfg.rms_norm_eps)
-        o, entry = attention_absorbed(layer["attn"], xn, positions, entry, cfg)
-        with jax.named_scope("attn.out"):
-            h = x + o
-        entries.append(entry)
-        x, c = ffn(layer, h, cfg)
-        chosen.append(c)
-    mask = jnp.ones((b, 1), bool) if active is None else active[:, None]
-    return (lm_head(params, x[:, 0], cfg), {"layers": entries},
-            routing_report(chosen, mask, jnp.zeros((b,), jnp.int32), cfg))
+    """``lm_blocks.decode_stack`` over ``_decode_block``: ``tokens`` (B,) at
+    ``positions`` (B,) -> (float32 logits (B, V) for the next position,
+    cache, routing)."""
+    return lm_blocks.decode_stack(params, cache, tokens, positions,
+                                  cache_layout(cfg), _decode_block, cfg, active)
 
 
 # -- multi-token prediction -----------------------------------------------
 def mtp_logits(params, hidden, next_tokens, cfg: Glm4MoeLiteConfig):
-    """The MTP module in DeepSeek-V3's form, over whole sequences:
-    ``h' = W_p [RMSNorm(h_t); RMSNorm(Emb(x_{t+1}))]``, one block of the
-    model's own kind (latent attention + expert layer), the module's norm
-    and the SHARED head: float32 logits (B, L, V) for position ``t + 2``."""
-    m = params["mtp"]
-    b, l, _ = hidden.shape
-    with jax.named_scope("mtp"):
-        x = jnp.concatenate(
-            [rms_norm(hidden, m["ln_hidden"], cfg.rms_norm_eps),
-             rms_norm(embed(params, next_tokens), m["ln_embed"],
-                      cfg.rms_norm_eps)], axis=-1)
-        x = jnp.dot(x, m["proj"])
-        positions = jnp.broadcast_to(jnp.arange(l)[None], (b, l))
-        x, _, _ = _prefill_block(m["block"], x, positions, cfg, None, None)
-        x = rms_norm(x, m["final_norm"], cfg.rms_norm_eps)
-        return jnp.dot(x, params["head"], preferred_element_type=jnp.float32)
+    """``lm_blocks.mtp_logits`` with one block of the model's own kind
+    (latent attention + expert layer): float32 logits (B, L, V) for
+    position ``t + 2``."""
+    return lm_blocks.mtp_logits(params, hidden, next_tokens, cfg,
+                                _prefill_block, None)

@@ -58,10 +58,12 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from can_tpu.models import lm_blocks
 from can_tpu.models.lm_blocks import experts_form  # noqa: F401  (the serving path asks the model for it)
-from can_tpu.models.lm_blocks import (VocabSlice, count_shapes, embed, ffn,
-                                      init_from_shapes, last_hidden, lm_head,
-                                      qkv_heads, rms_norm, routing_report)
+from can_tpu.models.lm_blocks import (VocabSlice, count_shapes, ffn,
+                                      init_from_shapes, kv_decode, kv_entry,
+                                      last_hidden, lm_head, qkv_heads,
+                                      rms_norm)
 from can_tpu.ops import attention as attn_ops
 from can_tpu.ops import cache_layout as layout
 from can_tpu.ops import ssm as ssm_ops
@@ -266,52 +268,38 @@ def conv_mixer_step(layer, x, tail, cfg: Lfm2MoeConfig):
 
 
 # -- prefill ------------------------------------------------------------
-def _attention_prefill(layer, x, positions, cfg, cache_len):
-    """A ``full_attention`` layer's first half over whole prompts; -> (h,
-    cache entry or None)."""
-    b, l = x.shape[:2]
-    with jax.named_scope("attn.proj"):
-        q, k, v = _qkv(layer["attn"],
-                       rms_norm(x, layer["ln_in"], cfg.rms_norm_eps),
-                       positions, cfg)
-    with jax.named_scope("attn.core"):
-        o = attn_ops.prefill_full(q, k, v)
-    with jax.named_scope("attn.out"):
-        h = x + jnp.dot(o.reshape(b, l, -1), layer["attn"]["wo"])
-    if cache_len is None:
-        return h, None
-    with jax.named_scope("attn.cache"):
-        shapes = _kv_spec(cfg).shapes(b, cache_len)
-        return h, {"k": attn_ops.as_leaf(k, shapes["k"]),
-                   "v": attn_ops.as_leaf(v, shapes["v"])}
+def _prefill_block(layer, kind, x, positions, lengths, cfg,
+                   cache_len: Optional[int]):
+    """One block over whole prompts, its mixer a gated short convolution or
+    attention; -> (y, cache entry, chosen)."""
+    if kind == CONV:
+        h, tail = conv_mixer_causal(layer, x, lengths, cfg)
+        entry = {"conv": tail}
+    else:
+        b, l = x.shape[:2]
+        with jax.named_scope("attn.proj"):
+            q, k, v = _qkv(layer["attn"],
+                           rms_norm(x, layer["ln_in"], cfg.rms_norm_eps),
+                           positions, cfg)
+        with jax.named_scope("attn.core"):
+            o = attn_ops.prefill_full(q, k, v)
+        with jax.named_scope("attn.out"):
+            h = x + jnp.dot(o.reshape(b, l, -1), layer["attn"]["wo"])
+        entry = None
+        if cache_len is not None:
+            entry = kv_entry(_kv_spec(cfg), k, v, lengths, cache_len)
+    y, chosen = ffn(layer, h, cfg)
+    return y, entry, chosen
 
 
 def prefill_hidden(params, tokens, lengths, cfg: Lfm2MoeConfig,
                    cache_len: Optional[int] = None, active=None):
-    """Whole prompts through the blocks: -> (hidden (B, L, d) before the
-    final norm, cache or None, ``routing_report`` of the valid tokens).
-    ``tokens`` (B, L) right-padded, ``lengths`` (B,); ``active`` (B,) marks
-    the sequences whose routing is counted (all when None).  Padded
-    positions compute garbage no valid position ever sees: attention and
-    the convolution are causal, and the tail is taken at ``lengths``."""
-    b, l = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(l)[None], (b, l))
-    mask = positions < lengths[:, None]
-    if active is not None:
-        mask &= active[:, None]
-    x = embed(params, tokens)
-    entries, chosen = [], []
-    for layer, kind in zip(params["layers"], cfg.layer_types):
-        if kind == CONV:
-            h, tail = conv_mixer_causal(layer, x, lengths, cfg)
-            entry = {"conv": tail}
-        else:
-            h, entry = _attention_prefill(layer, x, positions, cfg, cache_len)
-        entries.append(entry)
-        x, c = ffn(layer, h, cfg)
-        chosen.append(c)
-    cache = None if cache_len is None else {"layers": entries}
-    return x, cache, routing_report(chosen, mask, lengths - 1, cfg)
+    """``lm_blocks.prefill_stack`` over ``_prefill_block``: -> (hidden (B,
+    L, d) before the final norm, cache or None, routing).  Padded positions
+    compute garbage no valid position ever sees: attention and the
+    convolution are causal, and the tail is taken at ``lengths``."""
+    return lm_blocks.prefill_stack(params, tokens, lengths, cfg.layer_types,
+                                   _prefill_block, cfg, cache_len, active)
 
 
 def prefill(params, tokens, lengths, cfg: Lfm2MoeConfig, cache_len: int,
@@ -325,40 +313,31 @@ def prefill(params, tokens, lengths, cfg: Lfm2MoeConfig, cache_len: int,
 
 
 # -- decode -------------------------------------------------------------
+def _decode_block(layer, kind, x, entry, positions, column, cfg):
+    """One block over one token a sequence: an attention layer writes the
+    token's key and value at its position before it attends; a ``conv``
+    layer reads its tail, writes the token's ``u`` behind it.  -> (y, the
+    entry, chosen)."""
+    if kind == CONV:
+        h, tail = conv_mixer_step(layer, x, entry["conv"], cfg)
+        entry = {"conv": tail}
+    else:
+        with jax.named_scope("attn.proj"):
+            q, k, v = _qkv(layer["attn"],
+                           rms_norm(x, layer["ln_in"], cfg.rms_norm_eps),
+                           column, cfg)
+        o, entry = kv_decode(_kv_spec(cfg), q, k, v, entry, positions, column,
+                             ("attn.core",))
+        with jax.named_scope("attn.out"):
+            h = x + jnp.dot(o.reshape(x.shape[0], 1, -1), layer["attn"]["wo"])
+    y, chosen = ffn(layer, h, cfg)
+    return y, entry, chosen
+
+
 def decode_step(params, cache, tokens, positions, cfg: Lfm2MoeConfig,
                 active=None):
-    """One token per sequence: ``tokens`` (B,) at ``positions`` (B,) ->
-    (float32 logits (B, V) for the next position, cache, routing).  An
-    attention layer writes the token's key and value at its position before
-    it attends; a ``conv`` layer reads its tail, writes the token's ``u``
-    behind it.  ``active`` (B,) marks the slots whose routing is counted
-    (all when None)."""
-    b = tokens.shape[0]
-    pos2 = positions[:, None]
-    x = embed(params, tokens)[:, None]                       # (B, 1, d)
-    entries, chosen = [], []
-    for layer, kind, entry in zip(params["layers"], cfg.layer_types,
-                                  cache["layers"]):
-        if kind == CONV:
-            h, tail = conv_mixer_step(layer, x, entry["conv"], cfg)
-            entries.append({"conv": tail})
-        else:
-            with jax.named_scope("attn.proj"):
-                q, k, v = _qkv(layer["attn"],
-                               rms_norm(x, layer["ln_in"], cfg.rms_norm_eps),
-                               pos2, cfg)
-            with jax.named_scope("attn.core"):
-                valid = jnp.arange(entry["k"].shape[2])[None, :] <= pos2
-            with jax.named_scope("attn.cache"):
-                kc = attn_ops.write_slot(entry["k"], k[:, 0], positions)
-                vc = attn_ops.write_slot(entry["v"], v[:, 0], positions)
-            with jax.named_scope("attn.core"):
-                o = attn_ops.decode(q[:, 0], kc, vc, valid)
-            with jax.named_scope("attn.out"):
-                h = x + jnp.dot(o.reshape(b, 1, -1), layer["attn"]["wo"])
-            entries.append({"k": kc, "v": vc})
-        x, c = ffn(layer, h, cfg)
-        chosen.append(c)
-    mask = jnp.ones((b, 1), bool) if active is None else active[:, None]
-    return (lm_head(params, x[:, 0], cfg), {"layers": entries},
-            routing_report(chosen, mask, jnp.zeros((b,), jnp.int32), cfg))
+    """``lm_blocks.decode_stack`` over ``_decode_block``: ``tokens`` (B,) at
+    ``positions`` (B,) -> (float32 logits (B, V) for the next position,
+    cache, routing)."""
+    return lm_blocks.decode_stack(params, cache, tokens, positions,
+                                  cfg.layer_types, _decode_block, cfg, active)

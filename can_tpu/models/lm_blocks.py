@@ -1,17 +1,26 @@
 """What the language models' decoder blocks have in common, as pure
 functions over a parameter tree: the norm, SwiGLU, the expert layer with
 its shared expert, the feed-forward half of a block, the head, the routing
-report a serving program returns, and the seeded initialiser.  A model's
-module (``exaone_moe.py``, ``glm_moe_lite.py``, ``lfm2_moe.py``,
-``mimo_v2_flash.py``) brings its own mixers and its own config class (the
-grouped-query ones share ``qkv_heads``, the three projections by head); the config offers ``rms_norm_eps``
-and, where the model has an expert layer, ``num_experts_per_tok``, ``routed_scaling_factor``,
-``norm_topk_prob`` and ``share`` (``ops.moe.ExpertShare``).  An expert layer
-has a shared expert where its parameters hold one (``shared``; LFM2's hold
-none), and the head is the embedding's transpose where the tree has no
-``head`` (tied).  A dense model
-(``falcon_h1.py``) takes the norm, SwiGLU and the head with its
-configuration's multipliers, and ``no_routing``.
+report a serving program returns, the seeded initialiser, and the two
+things every model's stack does the same way: **how a key/value layer
+writes and reads its cache** (``kv_entry``, ``kv_decode``: from the
+``LayerSpec`` the model states in its ``cache_layout``, nothing else) and
+**the stack's skeleton** (``prefill_stack``, ``decode_stack``,
+``mtp_logits``: positions and mask, the embedding, the loop over the layers,
+the cache, the head, the routing report).  A model's module
+(``exaone_moe.py``, ``glm_moe_lite.py``, ``lfm2_moe.py``,
+``mimo_v2_flash.py``) brings its config class, its own mixers as one function
+a layer for a prompt and one for a step, and three thin entry points over
+the skeleton (the grouped-query ones share ``qkv_heads``, the three
+projections by head); the config offers ``rms_norm_eps`` and, where the
+model has an expert layer, ``num_experts_per_tok``,
+``routed_scaling_factor``, ``norm_topk_prob`` and ``share``
+(``ops.moe.ExpertShare``).  An expert layer has a shared expert where its
+parameters hold one (``shared``; LFM2's hold none), and the head is the
+embedding's transpose where the tree has no ``head`` (tied).  A dense model
+(``falcon_h1.py``) takes the norm, SwiGLU, the head with its configuration's
+multipliers, ``kv_entry`` / ``kv_decode`` and ``no_routing``; its embedding
+and head carry multipliers, so its shell is its own.
 
 Weights and activations follow the parameter tree's dtype; the router, the
 norms' statistics and the logits are float32.
@@ -19,12 +28,15 @@ norms' statistics and the logits are float32.
 
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
+from can_tpu.ops import attention as attn_ops
+from can_tpu.ops import cache_layout as layout
 from can_tpu.ops import moe as moe_ops
 from can_tpu.ops.moe import ExpertShare
 
@@ -151,6 +163,62 @@ def qkv_heads(p, x, kv_heads: int, groups: int, head_dim: int,
     return q, k, v
 
 
+def scoped(*names: str):
+    """``jax.named_scope`` of each of ``names``, the first outermost: a
+    window layer's core is ``("attn.core", "attn.window")`` where its model
+    tells the two kinds of layer apart, ``("attn.core",)`` elsewhere."""
+    stack = contextlib.ExitStack()
+    for name in names:
+        stack.enter_context(jax.named_scope(name))
+    return stack
+
+
+# -- a key/value layer's cache ------------------------------------------
+# THE place where keys and values meet the leaves ``cache_layout.kv_layer``
+# describes: what a model states there (full or a ring, the heads' widths)
+# is all these two read.  ``ops/attention.py``'s writes and reads are called
+# through the module: the benchmark's calibration breaks the timed path by
+# replacing them there (``write_slot``, ``ring_entry``).
+def kv_entry(spec: layout.LayerSpec, k, v, lengths, cache_len: int) -> dict:
+    """A prompt's keys and values (B, L, KV, D / Dv) as the layer's entry
+    ``{"k", "v"}`` in ``spec.shapes(B, cache_len)``: every position of a
+    ``full`` layer (those past ``L`` zero), the newest ``spec.window`` before
+    each prompt's own length in a ``ring``."""
+    with jax.named_scope("attn.cache"):
+        shapes = spec.shapes(k.shape[0], cache_len)
+        if spec.kind == layout.RING:
+            return attn_ops.ring_entry(k, v, lengths, spec.window, shapes)
+        return {"k": attn_ops.as_leaf(k, shapes["k"]),
+                "v": attn_ops.as_leaf(v, shapes["v"])}
+
+
+def kv_decode(spec: layout.LayerSpec, q, k, v, entry, positions, column,
+              core, sink=None):
+    """One token a sequence against the layer's cache: ``q`` (B, 1, KV, G,
+    D) and the token's own ``k`` (B, 1, KV, D), ``v`` (B, 1, KV, Dv) at
+    ``positions`` (B,) (``column``: the same as (B, 1), made once a step),
+    written into ``entry`` before the token attends: at its position in a
+    ``full`` layer, at slot ``position % spec.window`` in a ``ring`` -> (the
+    attended values (B, KV, G, Dv), the entry).  ``core``: the scope names
+    of the layer's attention (``scoped``), which the per-layer metrics read
+    from the compiled program; ``sink`` (H,): a learned logit a query head
+    in the softmax's denominator, as the layer's parameters hold it."""
+    with scoped(*core):
+        if spec.kind == layout.RING:
+            slot = jnp.mod(positions, spec.window)
+            valid = attn_ops.ring_positions(positions, spec.window) >= 0
+        else:
+            slot = positions
+            valid = jnp.arange(entry["k"].shape[2])[None, :] <= column
+    with jax.named_scope("attn.cache"):
+        kc = attn_ops.write_slot(entry["k"], k[:, 0], slot)
+        vc = attn_ops.write_slot(entry["v"], v[:, 0], slot)
+    with scoped(*core):
+        o = attn_ops.decode(q[:, 0], kc, vc, valid,
+                            None if sink is None else sink.reshape(q.shape[2:4]))
+    return o, {"k": kc, "v": vc}
+
+
 class Routed(NamedTuple):
     """What an expert layer says of one call: ``idx`` the experts each token
     chose (..., k), ``read`` the number of held experts whose weights the
@@ -266,3 +334,75 @@ def lm_head(params, h, cfg, multiplier=None):
             logits = jnp.einsum("...d,vd->...v", x, params["embed"],
                                 preferred_element_type=jnp.float32)
         return logits if multiplier is None else logits * multiplier
+
+
+# -- the stack's skeleton -------------------------------------------------
+# What a model's ``prefill_hidden`` / ``decode_step`` / ``mtp_logits`` run
+# around its own layers.  ``kinds``: one label a layer, the model's own (its
+# layer types, or its ``cache_layout``'s specs), handed to the layer function
+# with the layer's parameters.
+def prefill_stack(params, tokens, lengths, kinds, block, cfg,
+                  cache_len: Optional[int], active):
+    """Whole prompts through the layers: -> (hidden (B, L, d) before the
+    final norm, cache or None, ``routing_report`` of the valid tokens).
+    ``tokens`` (B, L) right-padded, ``lengths`` (B,); ``active`` (B,) marks
+    the sequences whose routing is counted (all when None).  ``block(layer,
+    kind, x, positions, lengths, cfg, cache_len)`` -> (y, the layer's cache
+    entry or None, its ``Routed`` or None) is the model's layer over ``x``
+    (B, L, d) at ``positions`` (B, L)."""
+    b, l = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(l)[None], (b, l))
+    mask = positions < lengths[:, None]
+    if active is not None:
+        mask &= active[:, None]
+    x = embed(params, tokens)
+    entries, chosen = [], []
+    for layer, kind in zip(params["layers"], kinds):
+        x, entry, c = block(layer, kind, x, positions, lengths, cfg, cache_len)
+        entries.append(entry)
+        chosen.append(c)
+    cache = None if cache_len is None else {"layers": entries}
+    return x, cache, routing_report(chosen, mask, lengths - 1, cfg)
+
+
+def decode_stack(params, cache, tokens, positions, kinds, block, cfg, active):
+    """One token per sequence through the layers: ``tokens`` (B,) at
+    ``positions`` (B,) -> (float32 logits (B, V) for the next position,
+    cache, routing).  ``active`` (B,) marks the slots whose routing is
+    counted (all when None).  ``block(layer, kind, x, entry, positions,
+    column, cfg)`` -> (y, the entry written, its ``Routed`` or None) is the
+    model's layer over ``x`` (B, 1, d); ``column`` is ``positions`` as (B,
+    1), made once a step for the rotary and the masks of all layers."""
+    b = tokens.shape[0]
+    column = positions[:, None]
+    x = embed(params, tokens)[:, None]                       # (B, 1, d)
+    entries, chosen = [], []
+    for layer, kind, entry in zip(params["layers"], kinds, cache["layers"]):
+        x, entry, c = block(layer, kind, x, entry, positions, column, cfg)
+        entries.append(entry)
+        chosen.append(c)
+    mask = jnp.ones((b, 1), bool) if active is None else active[:, None]
+    return (lm_head(params, x[:, 0], cfg), {"layers": entries},
+            routing_report(chosen, mask, jnp.zeros((b,), jnp.int32), cfg))
+
+
+def mtp_logits(params, hidden, next_tokens, cfg, block, kind):
+    """The MTP module in DeepSeek-V3's form, over whole sequences:
+    ``h' = W_p [RMSNorm(h_t); RMSNorm(Emb(x_{t+1}))]``, one block of the
+    model's own (``block`` as ``prefill_stack`` calls it, of ``kind``: its
+    attention + an expert layer, nothing cached), the module's norm and the
+    SHARED head: float32 logits (B, L, V) for position ``t + 2``.
+    ``hidden`` (B, L, d) is ``prefill_hidden``'s, ``next_tokens`` (B, L) the
+    ids at ``t + 1``."""
+    m = params["mtp"]
+    b, l, _ = hidden.shape
+    with jax.named_scope("mtp"):
+        x = jnp.concatenate(
+            [rms_norm(hidden, m["ln_hidden"], cfg.rms_norm_eps),
+             rms_norm(embed(params, next_tokens), m["ln_embed"],
+                      cfg.rms_norm_eps)], axis=-1)
+        x = jnp.dot(x, m["proj"])
+        positions = jnp.broadcast_to(jnp.arange(l)[None], (b, l))
+        x = block(m["block"], kind, x, positions, None, cfg, None)[0]
+        x = rms_norm(x, m["final_norm"], cfg.rms_norm_eps)
+        return jnp.dot(x, params["head"], preferred_element_type=jnp.float32)

@@ -58,7 +58,6 @@ What the published config leaves open is ONE choice each, named in
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import math
 from typing import Optional, Tuple
@@ -66,10 +65,12 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from can_tpu.models import lm_blocks
 from can_tpu.models.lm_blocks import experts_form  # noqa: F401  (the serving path asks the model for it)
-from can_tpu.models.lm_blocks import (VocabSlice, count_shapes, embed, ffn,
-                                      init_from_shapes, last_hidden, lm_head,
-                                      qkv_heads, rms_norm, routing_report)
+from can_tpu.models.lm_blocks import (VocabSlice, count_shapes, ffn,
+                                      init_from_shapes, kv_decode, kv_entry,
+                                      last_hidden, lm_head, qkv_heads,
+                                      rms_norm, scoped)
 from can_tpu.ops import attention as attn_ops
 from can_tpu.ops import cache_layout as layout
 from can_tpu.ops import pallas_attention as fused_attn
@@ -292,14 +293,11 @@ def _sink(p, window: bool, cfg: MimoV2FlashConfig):
     return p["sink"].reshape(kv, cfg.num_attention_heads // kv)
 
 
-def _core_scope(window: bool):
-    """``attn.core`` and, for a window layer, ``attn.window`` inside it: the
+def _core(window: bool) -> tuple:
+    """The scopes of a layer's attention (``lm_blocks.scoped``):
+    ``attn.core`` and, for a window layer, ``attn.window`` inside it: the
     family ``attn.`` holds both, ``attn.core`` alone is the full layers'."""
-    stack = contextlib.ExitStack()
-    stack.enter_context(jax.named_scope("attn.core"))
-    if window:
-        stack.enter_context(jax.named_scope("attn.window"))
-    return stack
+    return ("attn.core", "attn.window") if window else ("attn.core",)
 
 
 # -- prefill ------------------------------------------------------------
@@ -328,14 +326,15 @@ def _full_prefill(q, k, v, lengths):
     return causal(q, k, v, lengths).reshape(b, l, kv, g, v.shape[-1])
 
 
-def _prefill_block(layer, window, x, positions, cfg, cache_len, lengths):
+def _prefill_block(layer, window, x, positions, lengths, cfg,
+                   cache_len: Optional[int]):
     """One block over whole prompts; -> (y, cache entry or None, chosen)."""
     b, l = x.shape[:2]
     p = layer["attn"]
     with jax.named_scope("attn.proj"):
         q, k, v = _qkv(p, rms_norm(x, layer["ln_in"], cfg.rms_norm_eps),
                        positions, window, cfg)
-    with _core_scope(window):
+    with scoped(*_core(window)):
         if window:
             o = attn_ops.prefill_window(q, k, v, window=cfg.sliding_window,
                                         sink=_sink(p, window, cfg))
@@ -345,40 +344,18 @@ def _prefill_block(layer, window, x, positions, cfg, cache_len, lengths):
         h = x + jnp.dot(o.reshape(b, l, -1), p["wo"])
     entry = None
     if cache_len is not None:
-        with jax.named_scope("attn.cache"):
-            shapes = _kv_spec(cfg, window).shapes(b, cache_len)
-            if window:
-                entry = attn_ops.ring_entry(k, v, lengths, cfg.sliding_window,
-                                            shapes)
-            else:
-                entry = {"k": attn_ops.as_leaf(k, shapes["k"]),
-                         "v": attn_ops.as_leaf(v, shapes["v"])}
+        entry = kv_entry(_kv_spec(cfg, window), k, v, lengths, cache_len)
     y, chosen = ffn(layer, h, cfg)
     return y, entry, chosen
 
 
 def prefill_hidden(params, tokens, lengths, cfg: MimoV2FlashConfig,
                    cache_len: Optional[int] = None, active=None):
-    """Whole prompts through the blocks: -> (hidden (B, L, d) before the
-    final norm, cache or None, ``routing_report`` of the valid tokens).
-    ``tokens`` (B, L) right-padded, ``lengths`` (B,); ``active`` (B,) marks
-    the sequences whose routing is counted (all when None).  Padded
-    positions compute garbage no valid position ever sees (attention is
-    causal)."""
-    b, l = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(l)[None], (b, l))
-    mask = positions < lengths[:, None]
-    if active is not None:
-        mask &= active[:, None]
-    x = embed(params, tokens)
-    entries, chosen = [], []
-    for layer, window in zip(params["layers"], cfg.window_layers):
-        x, entry, c = _prefill_block(layer, window, x, positions, cfg,
-                                     cache_len, lengths)
-        entries.append(entry)
-        chosen.append(c)
-    cache = None if cache_len is None else {"layers": entries}
-    return x, cache, routing_report(chosen, mask, lengths - 1, cfg)
+    """``lm_blocks.prefill_stack`` over ``_prefill_block``: -> (hidden (B,
+    L, d) before the final norm, cache or None, routing).  Padded positions
+    compute garbage no valid position ever sees (attention is causal)."""
+    return lm_blocks.prefill_stack(params, tokens, lengths, cfg.window_layers,
+                                   _prefill_block, cfg, cache_len, active)
 
 
 def prefill(params, tokens, lengths, cfg: MimoV2FlashConfig, cache_len: int,
@@ -392,42 +369,27 @@ def prefill(params, tokens, lengths, cfg: MimoV2FlashConfig, cache_len: int,
 
 
 # -- decode -------------------------------------------------------------
+def _decode_block(layer, window, x, entry, positions, column, cfg):
+    """One block over one token a sequence, its key and value written into
+    ``entry`` (its ring slot in a window layer) before it attends; -> (y,
+    the entry, chosen)."""
+    p = layer["attn"]
+    with jax.named_scope("attn.proj"):
+        q, k, v = _qkv(p, rms_norm(x, layer["ln_in"], cfg.rms_norm_eps),
+                       column, window, cfg)
+    o, entry = kv_decode(_kv_spec(cfg, window), q, k, v, entry, positions,
+                         column, _core(window), p.get("sink"))
+    with jax.named_scope("attn.out"):
+        h = x + jnp.dot(o.reshape(x.shape[0], 1, -1), p["wo"])
+    y, chosen = ffn(layer, h, cfg)
+    return y, entry, chosen
+
+
 def decode_step(params, cache, tokens, positions, cfg: MimoV2FlashConfig,
                 active=None):
-    """One token per sequence: ``tokens`` (B,) at ``positions`` (B,) ->
-    (float32 logits (B, V) for the next position, cache, routing).  The
-    token's key and value are written at its position (its ring slot in
-    window layers) before it attends.  ``active`` (B,) marks the slots
-    whose routing is counted (all when None)."""
-    b = tokens.shape[0]
-    pos2 = positions[:, None]
-    x = embed(params, tokens)[:, None]                       # (B, 1, d)
-    entries, chosen = [], []
-    for layer, window, entry in zip(params["layers"], cfg.window_layers,
-                                    cache["layers"]):
-        p = layer["attn"]
-        with jax.named_scope("attn.proj"):
-            q, k, v = _qkv(p, rms_norm(x, layer["ln_in"], cfg.rms_norm_eps),
-                           pos2, window, cfg)
-        with _core_scope(window):
-            if window:
-                slot = jnp.mod(positions, cfg.sliding_window)
-                valid = attn_ops.ring_positions(positions,
-                                                cfg.sliding_window) >= 0
-            else:
-                slot = positions
-                valid = jnp.arange(entry["v"].shape[2])[None, :] <= pos2
-        with jax.named_scope("attn.cache"):
-            kc = attn_ops.write_slot(entry["k"], k[:, 0], slot)
-            vc = attn_ops.write_slot(entry["v"], v[:, 0], slot)
-        with _core_scope(window):
-            o = attn_ops.decode(q[:, 0], kc, vc, valid,
-                                _sink(p, window, cfg))
-        with jax.named_scope("attn.out"):
-            h = x + jnp.dot(o.reshape(b, 1, -1), p["wo"])
-        entries.append({"k": kc, "v": vc})
-        x, c = ffn(layer, h, cfg)
-        chosen.append(c)
-    mask = jnp.ones((b, 1), bool) if active is None else active[:, None]
-    return (lm_head(params, x[:, 0], cfg), {"layers": entries},
-            routing_report(chosen, mask, jnp.zeros((b,), jnp.int32), cfg))
+    """``lm_blocks.decode_stack`` over ``_decode_block``: ``tokens`` (B,) at
+    ``positions`` (B,) -> (float32 logits (B, V) for the next position,
+    cache, routing)."""
+    return lm_blocks.decode_stack(params, cache, tokens, positions,
+                                  cfg.window_layers, _decode_block, cfg,
+                                  active)

@@ -24,7 +24,9 @@ of the 128 lanes: heads whose width is not lie ``pack`` side by side in one
 is not whole lane rows is not written in place (two whole-leaf copies a
 write); ``write_slot``, ``as_leaf``, ``ring_entry`` and ``decode`` read
 ``pack`` from the shapes they are given, keys and values each their own, 1
-for every head of 128.
+for every head of 128.  ONE caller fills and reads a layer's leaves with
+them, for every model: ``models/lm_blocks.py::kv_entry`` (a prompt) and
+``kv_decode`` (a step), from the ``LayerSpec`` the model states.
 
 Latent attention keeps (B, S, rank) latents and (B, S, rope_dim) rotary keys,
 no heads.  Its prefill rebuilds keys and values per head and runs a causal
@@ -203,17 +205,16 @@ def as_leaf(x, shape):
     return jnp.pad(x, ((0, 0), (0, 0), (0, positions - l), (0, 0)))
 
 
-def ring_entry(k, v, lengths, window: int, shapes=None) -> dict:
+def ring_entry(k, v, lengths, window: int, shapes) -> dict:
     """A prompt's keys and values (B, L, KV, D / Dv) as a window layer's
     ring: slot ``r`` takes the newest position ``p < length`` with ``p %
     window == r`` (a slot no position has reached yet holds position 0's
     row, which ``decode``'s ``valid`` never admits).  ``shapes``
-    (``LayerSpec.shapes``): the leaves' (B, rows, window, width), for heads
-    that lie side by side in a row; None: a head a row."""
+    (``LayerSpec.shapes``): the leaves' (B, rows, window, width), the heads
+    of a row side by side as ``write_slot`` writes them."""
     b, l = k.shape[:2]
-    if shapes is not None:
-        k = k.reshape(b, l, *shapes["k"][1::2])
-        v = v.reshape(b, l, *shapes["v"][1::2])
+    k = k.reshape(b, l, *shapes["k"][1::2])
+    v = v.reshape(b, l, *shapes["v"][1::2])
     kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
     held = ring_positions(lengths - 1, window)
     take = jnp.clip(held, 0, l - 1)[:, None, :, None]

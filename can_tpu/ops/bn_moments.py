@@ -30,8 +30,8 @@ The f32 accumulator dtype is pinned across every implementation: callers
 hand in ``yf = y.astype(float32)`` and masks are f32, so bf16 compute
 changes only the values entering the reduction, never the accumulation.
 
-Selection rides ``LocalOps.bn_ops`` (models/cannet.py) — the same
-injection seam as ``context_fused`` — and ``--bn-impl`` on the train CLI.
+Selection rides ``LocalOps.bn_ops`` (models/cannet.py) and ``--bn-impl``
+on the train CLI.
 ``None``/default keeps the twopass math bit-for-bit.
 """
 
@@ -141,7 +141,7 @@ def global_moments_onepass(yf, axes) -> Tuple:
 
 @dataclasses.dataclass(frozen=True)
 class BNOps:
-    """The BN-moments seam on ``LocalOps`` (beside ``context_fused``).
+    """The BN-moments seam on ``LocalOps``.
 
     ``masked_moments(yf, m, axes) -> (mean, biased_var, global_s0)`` and
     ``global_moments(yf, axes) -> (mean, biased_var)`` — both f32 in/out.

@@ -3,7 +3,10 @@ model gives of each of its layers (``cache_layout(cfg)``) and
 ``serve/cache.py`` allocates from.  It sits beside ``ops/attention.py``,
 whose writes and reads (``write_slot``, ``as_leaf``, ``write_row``,
 ``decode``, ``decode_latent``) assume these leaves; the models and the
-serving path both import it, and neither imports the other for it.
+serving path both import it, and neither imports the other for it.  A
+key/value layer's leaves are filled and read in one place for every model,
+``models/lm_blocks.py::kv_entry`` / ``kv_decode``, which ask the layer's
+``LayerSpec`` for a leaf's shape and its window, never a config.
 
 * ``full``: keys and values of every position of the context,
   ``{"k", "v"}`` of (slots, rows, positions, width), each leaf with its own

@@ -23,8 +23,7 @@ already fuses that chain into the consumer (verified per-program via the
 PR-6 cost ledger: bytes do not move when the affine is pulled in by
 hand).  Gradients come from a custom VJP that re-differentiates the
 jnp twin (``masked_moment_sums``) — the residuals
-are just the kernel inputs, no extra HBM, exactly the
-``ops/pallas_context.py`` fallback discipline.
+are just the kernel inputs, no extra HBM.
 
 Constraints (else callers fall back to the jnp one-pass): C a multiple of
 128 lanes (the C=128+ frontend/backend layers; the C=64 stem layers fall

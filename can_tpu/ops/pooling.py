@@ -91,8 +91,7 @@ def max_pool2d(x, window: int = 2, stride: int = 2):
       full-resolution mask/count intermediates are pure HBM traffic,
       ~3x the 5% it tried to reclaim.
 
-    Like the Pallas context kernel (ops/pallas_context.py), the honest
-    conclusion is that XLA's lowering wins: select_and_scatter overlaps
+    The honest conclusion is that XLA's lowering wins: select_and_scatter overlaps
     with the surrounding conv fusions well enough that removing it from
     the op list does not remove its time from the step.
     """

@@ -1,6 +1,6 @@
 """GLM-4.7-Flash (``models/glm_moe_lite.py``; latent attention in
 ``ops/attention.py``, the expert layer in ``ops/moe.py``) against the plain
-reference (``testing/glm_moe_lite_ref.py``) at the tiny preset of
+reference (``benchmark/reference/glm_moe_lite_ref.py``) at the tiny preset of
 ``tests/lm_tiny.py``, float32 on the CPU so that the comparison is tight
 enough to see a wrong index, and once in bfloat16."""
 
@@ -12,10 +12,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmark.reference import glm_moe_lite_ref as ref
 from can_tpu.models import glm_moe_lite as gm
 from can_tpu.models import lm_blocks as lb
 from can_tpu.ops import attention as attn_ops
-from can_tpu.testing import glm_moe_lite_ref as ref
 
 from lm_tiny import (interpret_skipping_experts, tiny_glm_config,
                      tiny_glm_model)
@@ -243,19 +243,6 @@ def test_mtp_module_against_reference():
     want = ref.mtp_forward(params, r["hidden"], nxt[0], spec)
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want), atol=3e-5,
                                rtol=3e-5)
-
-
-def test_reference_copies_are_the_same_text():
-    """``can_tpu/testing/`` and ``benchmark/reference/`` hold one reference:
-    the same text below their docstrings; it imports nothing of the program."""
-    def body(path):
-        text = open(os.path.join(ROOT, path)).read()
-        assert text.startswith('"""')
-        return text[text.index('"""', 3) + 3:]
-
-    mine = body("can_tpu/testing/glm_moe_lite_ref.py")
-    assert mine == body("benchmark/reference/glm_moe_lite_ref.py")
-    assert "import can_tpu" not in mine and "from can_tpu" not in mine
 
 
 @pytest.mark.parametrize("variant", VARIANTS)

@@ -417,7 +417,7 @@ def test_packed_write_and_decode_equal_a_head_a_row(
                          ids=["two-to-a-row", "tiny", "whole-lanes"])
 def test_a_prefill_s_entry_is_write_slot_position_by_position(kv_heads,
                                                               head_dim):
-    """``as_leaf`` (``_attention_prefill``'s cache entry) places a prompt's
+    """``as_leaf`` (``lm_blocks.kv_entry``'s full-layer entry) places a prompt's
     keys where ``write_slot`` would have written them one position at a
     time, in the shape ``kv_layer`` states; the positions behind are zero."""
     b, l, s = 3, 7, 12

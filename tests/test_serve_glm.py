@@ -12,11 +12,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmark.reference import glm_moe_lite_ref as ref
 from can_tpu.models import glm_moe_lite as gm
 from can_tpu.obs import Telemetry, spans
 from can_tpu.serve import GenerateService, build_model_service, lm_probe_steps
 from can_tpu.serve import programs as serve_programs
-from can_tpu.testing import glm_moe_lite_ref as ref
 
 from lm_tiny import interpret_skipping_experts, tiny_glm_config
 

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmark.reference import exaone_moe_ref as ref
 from can_tpu.data.batching import pad_batch
 from can_tpu.models import exaone_moe as em
 from can_tpu.obs import Telemetry, spans
@@ -27,7 +28,6 @@ from can_tpu.serve import (
 from can_tpu.ops import cache_layout as layout
 from can_tpu.serve import cache as kv_cache
 from can_tpu.serve.kinds import TOKENS, ImageKind, TokenKind
-from can_tpu.testing import exaone_moe_ref as ref
 
 from lm_tiny import tiny_config
 
