@@ -9,7 +9,7 @@ writes and reads its cache** (``kv_entry``, ``kv_decode``: from the
 ``mtp_logits``: positions and mask, the embedding, the loop over the layers,
 the cache, the head, the routing report).  A model's module
 (``exaone_moe.py``, ``glm_moe_lite.py``, ``lfm2_moe.py``,
-``mimo_v2_flash.py``) brings its config class, its own mixers as one function
+``mimo_v2_flash.py``, ``brumby.py``) brings its config class, its own mixers as one function
 a layer for a prompt and one for a step, and three thin entry points over
 the skeleton (the grouped-query ones share ``qkv_heads``, the three
 projections by head); the config offers ``rms_norm_eps`` and, where the
@@ -41,7 +41,7 @@ from can_tpu.ops import moe as moe_ops
 from can_tpu.ops.moe import ExpertShare
 
 
-# The parts of a language model, ONE vocabulary for the five models: every
+# The parts of a language model, ONE vocabulary for the six models: every
 # ``jax.named_scope`` that the serving programs pass through (the models,
 # ``ops/moe.py``, ``serve/programs.py``) is one of these names, whole (the MTP
 # modules' ``mtp``, outside those programs, wraps them).  A scope is metadata: it names no op and adds
@@ -68,6 +68,10 @@ PARTS = (
     "conv.proj",     # LFM2's gated short convolution: input norm and ``in_proj``
     "conv.mix",      # the gates ``B * X`` and ``C * v`` around the convolution (a prompt / one step), the tail
     "conv.out",      # ``out_proj`` and the residual
+    "ret.proj",      # power retention (Brumby): input norm, ``wq`` / ``wk`` / ``wv`` / ``wg``, the head norms, rotary, ``log_sigmoid``
+    "ret.core",      # a prompt's scores inside a chunk: the power, the decay, the weighted values
+    "ret.state",     # ``phi``; the state decayed, updated, queried, its read and write; a prefill slice's states placed into the launch's cache
+    "ret.out",       # the division by the normaliser, ``wo``, the residual
     "head",          # final norm and the head's product
     "sample",        # argmax, the ``ids`` update, the decode state moved on
     "routing",       # ``routing_report``'s counts and choices
@@ -276,17 +280,16 @@ def routing_report(chosen, mask, pick, cfg) -> dict:
     """What a program reports of its routing: ``counts`` (expert layers,
     held) assignments of the tokens ``mask`` (B, L) marks that landed on
     each held expert, and ``choices`` (expert layers, B, k) the experts
-    chosen at position ``pick`` (B,) of each sequence; where the layers'
+    chosen at position ``pick`` (B,) of each sequence (``no_routing`` where no
+    layer is an expert layer); where the layers'
     form counts the experts it read (``Routed.read``), also
     ``experts_read`` () int32, their sum over the layers (every row of the
     batch reads, marked or not), and where it counts its passes
     (``Routed.passes``), ``dispatch_passes`` () int32, theirs.  ``chosen``:
     each layer's ``Routed``, None for a dense layer."""
     chosen = [c for c in chosen if c is not None]
-    if not chosen:
-        return {"counts": jnp.zeros((0, cfg.share.held), jnp.int32),
-                "choices": jnp.zeros((0, pick.shape[0], cfg.num_experts_per_tok),
-                                     jnp.int32)}
+    if not chosen:      # no expert layer among them: no rows, of any config
+        return no_routing(pick.shape[0])
     with jax.named_scope("routing"):
         counts = [moe_ops.held_counts(jnp.where(mask[..., None], c.idx, -1),
                                       cfg.share) for c in chosen]

@@ -84,13 +84,24 @@ def parts(layer) -> Tuple[LayerSpec, ...]:
     return (layer,) if isinstance(layer, LayerSpec) else tuple(layer)
 
 
+def _leaves(specs, cache: dict, keep) -> list:
+    return [entry[name] for layer, entry in zip(specs, cache["layers"])
+            for spec in parts(layer) if keep(spec)
+            for name, _, _ in spec.leaves]
+
+
 def positioned_leaves(specs, cache: dict) -> list:
     """The arrays of ``cache`` (``serve/cache.py::allocate``'s, of the
     layers ``specs``) that have a position axis: every kind's but
     ``state``'s, which a step rewrites whole."""
-    return [entry[name] for layer, entry in zip(specs, cache["layers"])
-            for spec in parts(layer) if spec.kind != STATE
-            for name, _, _ in spec.leaves]
+    return _leaves(specs, cache, lambda spec: spec.kind != STATE)
+
+
+def state_leaves(specs, cache: dict) -> list:
+    """The arrays of ``cache`` that have NO position axis (the ``state``
+    kind's): a step reads and rewrites each whole, where it lies if the
+    program is sound (PERF.md section 6, PR 47)."""
+    return _leaves(specs, cache, lambda spec: spec.kind == STATE)
 
 
 def kv_pack(kv_heads: int, head_dim: int) -> int:

@@ -422,6 +422,8 @@ class LMEngine:
         # the same of the gated short convolution, where the model has one
         # (``LMPrograms.conv_traced``)
         self.conv_forms: Dict[tuple, str] = {}
+        # the same of a retention layer (``LMPrograms.retention_traced``)
+        self.retention_forms: Dict[tuple, str] = {}
         # counters (the batcher thread writes, stats() reads a copy)
         self.counters = {"launches": 0, "generated_tokens": 0,
                          "prompt_tokens": 0, "decode_steps": 0,
@@ -525,6 +527,9 @@ class LMEngine:
                 conv = _one_form(self.conv_forms, ran)
                 if conv is not None:
                     sp.attrs["conv"] = conv
+                retention = _one_form(self.retention_forms, ran)
+                if retention is not None:
+                    sp.attrs["retention"] = retention
             probes = {"prefill": {"logits": pre["logits"],
                                   "choices": pre["choices"]}}
             keep = set(lm_probe_steps(steps))
@@ -546,6 +551,8 @@ class LMEngine:
                     sp.attrs["ssm"] = self.ssm_forms[(slots, 1)]
                 if (slots, 1) in self.conv_forms:
                     sp.attrs["conv"] = self.conv_forms[(slots, 1)]
+                if (slots, 1) in self.retention_forms:
+                    sp.attrs["retention"] = self.retention_forms[(slots, 1)]
                 # (expert layers, held): no rows in a model without experts
                 expert_layers, held = pre["counts"].shape
                 if expert_layers:
@@ -594,7 +601,9 @@ class LMEngine:
         for noted, forms in ((self.programs.attention_traced,
                               self.prefill_attention),
                              (self.programs.ssm_traced, self.ssm_forms),
-                             (self.programs.conv_traced, self.conv_forms)):
+                             (self.programs.conv_traced, self.conv_forms),
+                             (self.programs.retention_traced,
+                              self.retention_forms)):
             form = noted(program) if noted is not None else None
             if form is not None:
                 forms[program] = form
@@ -613,17 +622,21 @@ class LMEngine:
         ``args[2]`` is the cache in both programs, and ``cache_copies`` says
         how many times the program copies whole one of its arrays that have
         positions (``obs.trace.cache_copies``: none where a position is
-        written in place).
+        written in place), ``state_copies`` the same of its arrays WITHOUT
+        positions, which a step rewrites whole where they lie (a model
+        without a ``state`` kind: 0).
         ``name``: the program as the trace's ``XLA Modules`` line names it."""
         from can_tpu.obs.costs import resolve_jit
         from can_tpu.obs.trace import cache_copies, program_scopes
-        from can_tpu.ops.cache_layout import positioned_leaves
+        from can_tpu.ops.cache_layout import positioned_leaves, state_leaves
 
         with tr.span("program.scopes", parent_id=launch.span_id,
                      program=name, key=list(key)) as sp:
             text = resolve_jit(program, args).lower(*args).compile().as_text()
             sp.attrs.update(program_scopes(text, self.programs.parts))
             sp.attrs["cache_copies"] = cache_copies(text, positioned_leaves(
+                self.programs.cache_layout, args[2]))
+            sp.attrs["state_copies"] = cache_copies(text, state_leaves(
                 self.programs.cache_layout, args[2]))
 
     def _with_cache_signature(self, args) -> tuple:

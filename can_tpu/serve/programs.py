@@ -83,8 +83,11 @@ class LMPrograms:
     * ``cfg.vocab`` (``lm_blocks.VocabSlice``): ids and logits are over the
       slice held;
     * optionally ``model.attention_traced`` / ``model.ssm_traced`` /
-      ``model.conv_traced``: (B, L) of a program's tokens -> the form its
-      newest trace ran that layer in;
+      ``model.conv_traced`` / ``model.retention_traced``: (B, L) of a
+      program's tokens -> the form its newest trace ran that layer in;
+    * optionally ``model.CACHE_PART``: the part of the model (a name of
+      ``lm_blocks.PARTS``) that placing a prefill slice's rows into the
+      launch's cache belongs to; ``attn.cache`` where the model names none;
     * a model with an expert layer: ``model.experts_form(cfg, tokens, dtype)``
       -> the form its expert layers take for a program of that many tokens
       (``ops.moe.share_form``).  In the ``"skipping"`` form its ``routing``
@@ -122,6 +125,9 @@ class LMPrograms:
         self.ssm_traced = getattr(model, "ssm_traced", None)
         # the same of a gated short convolution: "causal" / "step"
         self.conv_traced = getattr(model, "conv_traced", None)
+        # the same of a retention layer: "chunked" / "step"
+        self.retention_traced = getattr(model, "retention_traced", None)
+        self._cache_part = getattr(model, "CACHE_PART", "attn.cache")
         self._experts_form = getattr(model, "experts_form", None)
         self.vocab_size = cfg.vocab.held   # ids and logits are over the slice
         # {a name on a compiled instruction's path: the part of the model it
@@ -178,7 +184,7 @@ class LMPrograms:
             params, batch["tokens"], batch["lengths"], self.cfg,
             self.positions(batch["tokens"].shape[1]), active=batch["active"])
         # the scopes are the models' vocabulary (``lm_blocks.PARTS``)
-        with jax.named_scope("attn.cache"):
+        with jax.named_scope(self._cache_part):
             cache = jax.tree.map(
                 lambda c, p: jax.lax.dynamic_update_slice_in_dim(
                     c, p.astype(c.dtype), start, axis=0), cache, part)
@@ -269,6 +275,12 @@ def _mimo_v2_flash():
     return mimo_v2_flash, mimo_v2_flash.MimoV2FlashConfig
 
 
+def _brumby():
+    from can_tpu.models import brumby
+
+    return brumby, brumby.BrumbyConfig
+
+
 def _lm_engine(params, programs, config: dict, telemetry):
     from can_tpu.serve.engine import LMEngine
 
@@ -293,6 +305,8 @@ MODEL_TYPES = {
                              _generate_service),
     "mimo_v2_flash": ServingModel(_lm_programs(_mimo_v2_flash), _lm_engine,
                                   _generate_service),
+    "brumby": ServingModel(_lm_programs(_brumby), _lm_engine,
+                           _generate_service),
 }
 
 

@@ -190,6 +190,38 @@ def tiny_mimo_model(seed=0, dtype=jnp.float32, **kw):
     return d, cfg, mv.init_params(jax.random.key(seed), cfg, dtype)
 
 
+def tiny_brumby_config(**edits) -> dict:
+    """The tiny Brumby preset, as a configuration-file dict: every mechanism
+    of the published model at sizes a CPU runs in milliseconds: 3 layers of
+    power retention (6 query heads over 2 key heads of 8, so groups of 3 and
+    a state of 40 rows a key head, per-head norms, rotary, a gate a key
+    head) and a SwiGLU, an untied head over 256 ids.  ``edits`` replace keys
+    (``assumed=...`` among them)."""
+    d = {
+        "model_type": "brumby", "attention_bias": False, "head_dim": 8,
+        "hidden_act": "silu", "hidden_size": 64, "intermediate_size": 96,
+        "max_position_embeddings": 32768, "max_window_layers": 3,
+        "num_attention_heads": 6, "num_hidden_layers": 3,
+        "num_key_value_heads": 2, "rms_norm_eps": 1e-6, "rope_scaling": None,
+        "rope_theta": 1000000, "sliding_window": None,
+        "tie_word_embeddings": False, "use_sliding_window": False,
+        "vocab_size": 256, "published": {"num_hidden_layers": 3,
+                                         "vocab_size": 256},
+        "assumed": {},
+    }
+    d.update(edits)
+    return d
+
+
+def tiny_brumby_model(seed=0, dtype=jnp.float32, **kw):
+    """-> (config dict, BrumbyConfig, params)."""
+    from can_tpu.models import brumby as bm
+
+    d = tiny_brumby_config(**kw)
+    cfg = bm.BrumbyConfig.from_dict(d)
+    return d, cfg, bm.init_params(jax.random.key(seed), cfg, dtype)
+
+
 def interpret_skipping_experts(monkeypatch) -> None:
     """The skipping experts kernel (``ops/pallas_experts.py``) interpreted
     wherever its shapes fit: what a TPU backend turns on, steered here as

@@ -171,18 +171,21 @@ def test_dp2_sp2_train_step_compiles_on_the_2x2_mesh(v5e):
     _fits_hbm(compiled)
 
 
-def _cache_copies(compiled, programs, cache) -> dict:
+def _cache_copies(compiled, programs, cache, positioned=True) -> dict:
     """{type of a cache array that has positions: how many times the
     compiled program copies such an array whole} (``obs.trace.cache_copies``
     over ``positioned_leaves``: summed, what ``LMEngine`` writes on its
-    ``program.scopes`` span).  A donated cache written in place reads 0
-    throughout; each copy is a leaf moved into another layout or back."""
+    ``program.scopes`` span as ``cache_copies``; ``positioned`` False: over
+    ``state_leaves``, the arrays WITHOUT positions, its ``state_copies``).  A
+    donated cache written in place reads 0 throughout; each copy is a leaf
+    moved into another layout or back."""
     from can_tpu.obs.trace import cache_copies, hlo_type
-    from can_tpu.ops.cache_layout import positioned_leaves
+    from can_tpu.ops.cache_layout import positioned_leaves, state_leaves
 
     text = compiled.as_text()
+    leaves = positioned_leaves if positioned else state_leaves
     return {hlo_type(a.shape, a.dtype): cache_copies(text, [a])
-            for a in positioned_leaves(programs.cache_layout, cache)}
+            for a in leaves(programs.cache_layout, cache)}
 
 
 def _plain_write_slot(cache, new, slot):
@@ -261,6 +264,10 @@ def _lm_programs_and_shapes(v5e, slots, part,
         from can_tpu.models import mimo_v2_flash as em
 
         cfg = em.MimoV2FlashConfig.from_dict(config)
+    elif config["model_type"] == "brumby":
+        from can_tpu.models import brumby as em
+
+        cfg = em.BrumbyConfig.from_dict(config)
     else:
         from can_tpu.models import glm_moe_lite as em
 
@@ -802,3 +809,70 @@ def test_glm_prefill_slice_compiles_with_the_fused_attention(v5e, monkeypatch):
     assert {p for i, p in found.items() if i.startswith("ragged-dot")} == {
         "moe.experts"}
     assert "moe.dispatch" in found.values()
+
+
+# -- power retention at the published widths ------------------------------------
+BRUMBY = "brumby-14b-pp5-serve-bf16"
+
+
+def _brumby_parts(programs, text) -> set:
+    """The parts the compiled program's instructions belong to, as
+    ``LMEngine`` reads them for its ``program.scopes`` span; all of the
+    vocabulary, next to nothing without one."""
+    from can_tpu.models.lm_blocks import PARTS
+    from can_tpu.obs.trace import program_scopes
+
+    got = program_scopes(text, programs.parts)
+    assert set(got["parts"].values()) <= set(PARTS) | {None}
+    assert got["unscoped"] <= 0.01 * got["instructions"], got["unscoped"]
+    return set(got["parts"].values())
+
+
+def test_brumby_decode_step_writes_its_state_where_it_lies(v5e):
+    """One greedy step of 16 slots: every layer's float32 matrix state (16 x
+    8 x 8,320 x 128: 545 MB a layer, 4.4 GB in all) read, moved on and
+    written IN PLACE (donated): no whole copy of a state leaf, no second
+    state alive, beside 8.4 GB of weights."""
+    programs, params, cache, _, shape = _lm_programs_and_shapes(
+        v5e, 16, 4, BRUMBY)
+    state = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(lambda: programs.new_state(
+            [{"first": jnp.zeros((16,), jnp.int32),
+              "logits": jnp.zeros((16, 8), jnp.float32),
+              "choices": jnp.zeros((0, 16, 0), jnp.int32),
+              "counts": jnp.zeros((0, 0), jnp.int32)}],
+            jnp.ones((16,), jnp.int32), jnp.ones((16,), bool))[0]))
+    compiled = jax.jit(programs.decode, donate_argnums=(1, 2)).lower(
+        params, state, cache).compile()
+    assert _cache_copies(compiled, programs, cache) == {}   # nothing has positions
+    assert _cache_copies(compiled, programs, cache, positioned=False) == {
+        "f32[16,8,8320,128]": 0, "f32[16,8,8320]": 0}
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 16 * 274_759_680
+    # weights + state, and temporaries under ONE layer's state
+    assert m.temp_size_in_bytes < 16 * 274_759_680 // 8
+    assert 11.5 * 2**30 < _fits_hbm(compiled) < 12.5 * 2**30
+    assert {"ret.proj", "ret.state", "ret.out"} <= _brumby_parts(
+        programs, compiled.as_text())
+
+
+def test_brumby_prefill_slice_compiles_for_one_device(v5e):
+    """4 prompts of 1,024 tokens into a 16-slot cache: the bucket is one
+    chunk, so the quadratic weights (4 x 40 x 1,024 x 1,024 float32) and one
+    state at the end, no loop over chunks; the slice's states placed into
+    the donated cache without a whole copy of a leaf."""
+    from can_tpu.models import brumby as bm
+
+    programs, params, cache, batch, shape = _lm_programs_and_shapes(
+        v5e, 16, 4, BRUMBY)
+    compiled = jax.jit(programs.prefill_slice, donate_argnums=(2,)).lower(
+        params, batch, cache, shape((), jnp.int32)).compile()
+    assert bm.retention_traced((4, 1024)) == "chunked"
+    text = compiled.as_text()
+    assert " while(" not in text
+    assert _cache_copies(compiled, programs, cache, positioned=False) == {
+        "f32[16,8,8320,128]": 0, "f32[16,8,8320]": 0}
+    assert 12 * 2**30 < _fits_hbm(compiled) < 15.5 * 2**30
+    assert {"ret.proj", "ret.core", "ret.state", "ret.out"} <= _brumby_parts(
+        programs, text)

@@ -410,7 +410,9 @@ def test_share_apply_runs_the_form_chosen(form, monkeypatch):
 # ``prefill_slice`` text and no other: its full layer's entry is ``as_leaf``'s
 # now, the same two transposes and two pads with the keys' pad before the
 # values' transpose; compiled for the described v5e the program is the
-# parent's, instruction for instruction (PERF.md section 6, PR 45).
+# parent's, instruction for instruction (PERF.md section 6, PR 45).  PR 47
+# added Brumby's two (power retention: the backend changes neither) and moved
+# none of the eight.
 PROGRAM_TEXT = {
     ("k-exaone-ep8-serve-bf16", "decode"):
         "2c53b1638274f93f0d4919dc5558a2e6378313e37e545c1402c69c3c3a01403d",
@@ -428,6 +430,10 @@ PROGRAM_TEXT = {
         "8c7d19f0bdfbfe4b323aedbd4a0fb791f1abf2ac18a6bd3daa2cc9ccd4439952",
     ("lfm2-24b-a2b-ep8-serve-bf16", "prefill_slice"):
         "48d2b79687d2ca3c0b80e047ae1116f0406ab531e79760cd4938c5a5fe4bcdb3",
+    ("brumby-14b-pp5-serve-bf16", "decode"):
+        "894291e8ab323dd6053e9e353676c0ad6be8140e8cc4a79f71f63360748487df",
+    ("brumby-14b-pp5-serve-bf16", "prefill_slice"):
+        "788d5a019663505aa9b93a8949c8288396a883f60e9f498594977a494fae80e0",
 }
 
 
@@ -449,6 +455,10 @@ def _program_text(name: str, program: str) -> str:
         from can_tpu.models import lfm2_moe as model
 
         cfg = model.Lfm2MoeConfig.from_dict(config)
+    elif config["model_type"] == "brumby":
+        from can_tpu.models import brumby as model
+
+        cfg = model.BrumbyConfig.from_dict(config)
     else:
         from can_tpu.models import glm_moe_lite as model
 

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import pytest
 
 import lm_tiny
-from can_tpu.models import (exaone_moe, falcon_h1, glm_moe_lite, lfm2_moe,
+from can_tpu.models import (brumby, exaone_moe, falcon_h1, glm_moe_lite, lfm2_moe,
                             lm_blocks, mimo_v2_flash)
 from can_tpu.obs import spans as recorder
 from can_tpu.obs.trace import (cache_copies, hlo_type, part_of,
@@ -238,21 +238,24 @@ class TestTheMap:
                                          "add.40", "copy.31"}
 
     def test_the_vocabulary_is_the_issue_s(self):
-        # sixteen of ISSUE 35, ISSUE 38's three of the short convolution, and
-        # ISSUE 40's ``attn.window``
-        assert len(PARTS) == len(set(PARTS)) == 20
+        # sixteen of ISSUE 35, ISSUE 38's three of the short convolution,
+        # ISSUE 40's ``attn.window`` and ISSUE 47's four of power retention
+        assert len(PARTS) == len(set(PARTS)) == 24
         assert {p.split(".")[0] for p in PARTS} == {
-            "embed", "attn", "moe", "dense_mlp", "ssm", "conv", "head",
+            "embed", "attn", "moe", "dense_mlp", "ssm", "conv", "ret", "head",
             "sample", "routing"}
+        assert {p for p in PARTS if p.startswith("ret.")} == {
+            "ret.proj", "ret.core", "ret.state", "ret.out"}
         assert set(lm_blocks.RENAMED_BY_COMPILER.values()) <= set(PARTS)
 
 
-# -- the five tiny models ----------------------------------------------------
+# -- the six tiny models -----------------------------------------------------
 MODELS = {"k-exaone": (exaone_moe, lambda: lm_tiny.tiny_model(mtp=0)),
           "glm": (glm_moe_lite, lambda: lm_tiny.tiny_glm_model(mtp=0)),
           "falcon-h1": (falcon_h1, lm_tiny.tiny_falcon_model),
           "lfm2": (lfm2_moe, lm_tiny.tiny_lfm2_model),
-          "mimo": (mimo_v2_flash, lm_tiny.tiny_mimo_model)}
+          "mimo": (mimo_v2_flash, lm_tiny.tiny_mimo_model),
+          "brumby": (brumby, lm_tiny.tiny_brumby_model)}
 SLOTS, PART, BUCKET = 4, 2, 16
 
 
@@ -299,7 +302,16 @@ def test_every_traced_instruction_of_a_tiny_model_has_a_part(tiny, program):
     assert not lost, lost
     got = program_scopes(text, programs.parts)
     families = {p.split(".")[0] for p in got["parts"].values() if p}
-    assert {"attn", "head", "sample", "embed", "dense_mlp"} <= families
+    assert {"head", "sample", "embed", "dense_mlp"} <= families
+    # retention INSTEAD of attention: its model has no ``attn.`` part at all,
+    # and its slices' states are placed into the cache as ``ret.state``
+    assert ("ret" in families) == (name == "brumby") == ("attn" not in families)
+    if name == "brumby":
+        want = {"ret.proj", "ret.state", "ret.out"} | (
+            {"ret.core"} if program == "prefill_slice" else set())
+        assert {p for p in got["parts"].values()
+                if p and p.startswith("ret.")} == want
+        return
     assert ("ssm" in families) == (name == "falcon-h1")
     assert ("conv" in families) == (name == "lfm2")
     # a window layer's core is a part of its own in the one model that opens
@@ -328,14 +340,15 @@ def test_the_scopes_change_no_program(tiny, program, monkeypatch):
     assert module.sub("", with_scopes) == module.sub("", without)
     # the patch took: traced again, the locations name no scope
     bare = jax.jit(lambda *a: fn(*a)).lower(*args).as_text(debug_info=True)
-    assert "attn.core" not in bare
+    assert "attn.core" not in bare and "ret.state" not in bare
 
 
 def test_the_scopes_are_in_the_lowering_s_locations(tiny):
     _, _, progs = tiny
     fn, args = progs["decode"]
     named = jax.jit(fn).lower(*args).as_text(debug_info=True)
-    assert "attn.core" in named and "sample" in named
+    core = "ret.state" if tiny[0] == "brumby" else "attn.core"
+    assert core in named and "sample" in named
 
 
 # -- the engine ----------------------------------------------------------

@@ -494,7 +494,8 @@ def test_serving_layers_import_no_network(name):
 
 
 @pytest.mark.parametrize("name", ["exaone_moe", "glm_moe_lite", "falcon_h1",
-                                  "lfm2_moe", "mimo_v2_flash", "lm_blocks"])
+                                  "lfm2_moe", "mimo_v2_flash", "brumby",
+                                  "lm_blocks"])
 def test_a_model_imports_neither_the_serving_path_nor_another_model(name):
     """The other side of the seam: a model describes its cache with
     ``ops/cache_layout.py`` and shares its block with ``models/lm_blocks.py``;
@@ -505,7 +506,7 @@ def test_a_model_imports_neither_the_serving_path_nor_another_model(name):
     assert not any(m.startswith("can_tpu.serve") for m in imported), imported
     others = {f"can_tpu.models.{m}" for m in ("exaone_moe", "glm_moe_lite",
                                               "falcon_h1", "lfm2_moe",
-                                              "mimo_v2_flash")}
+                                              "mimo_v2_flash", "brumby")}
     assert not (imported & others - {f"can_tpu.models.{name}"}), imported
 
 
@@ -515,7 +516,7 @@ def test_the_model_table_holds_what_a_configuration_file_may_name():
     entry = programs.serving_model("exaone_moe")
     assert set(programs.MODEL_TYPES) == {"exaone_moe", "glm4_moe_lite",
                                          "falcon_h1", "lfm2_moe",
-                                         "mimo_v2_flash"}
+                                         "mimo_v2_flash", "brumby"}
     made, params = entry.programs(lm_config(), None, 3)
     assert isinstance(made, programs.LMPrograms) and made.vocab_size == 256
     assert params["embed"].shape == (256, 64)
