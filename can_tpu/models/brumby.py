@@ -24,11 +24,16 @@ The block (``h`` (L, d), ``rms`` with ``rms_norm_eps`` in float32; key head
     h1 = x + concat_h(y_h) Wo;    out = h1 + swiglu(rms(h1, ln_post))
 
 Two forms of one layer: chunked over whole prompts (prefill), one step from
-the cached state (decode).  A launch's cache (``ops/cache_layout.py``) holds
-ONE kind, ``state``, in every layer: ``S`` (slots, key heads, rows, head_dim)
-and ``z`` (slots, key heads, rows), both float32, ``rows`` =
-``retention.state_rows(head_dim)`` (8,320 for heads of 128), written as they
-stand at each prompt's own length.
+the cached state (decode; on a TPU at the published head width the step is
+ONE Pallas kernel a layer, ``ops/pallas_retention.py``, that reads and writes
+each (slot, key head) state once, where it lies; elsewhere the plain form,
+its oracle: ``ops/retention.py::step_form`` says which, from the backend and
+the shapes, and ``retention_traced`` reports it).  A launch's cache
+(``ops/cache_layout.py``) holds ONE kind, ``state``, in every layer: ``S``
+(slots, key heads, head_dim, rows) and ``z`` (slots, key heads, rows), both
+float32, ``rows`` = ``retention.state_rows(head_dim)`` (8,320 for heads of
+128) in the LANES (the layout the step's kernel streams: ``ops/retention.py``),
+written as they stand at each prompt's own length.
 
 The published ``config.json`` carries Qwen3's keys only.  What it leaves
 open is ONE choice each, named in ``ASSUMED`` (a configuration file states
@@ -181,7 +186,7 @@ def cache_layout(cfg: BrumbyConfig) -> tuple:
     rows = ret_ops.state_rows(cfg.head_dim)
     kv = cfg.num_key_value_heads
     return (layout.state_layer(
-        S=((kv, rows, cfg.head_dim), ASSUMED["state_dtype"]),
+        S=((kv, cfg.head_dim, rows), ASSUMED["state_dtype"]),
         z=((kv, rows), ASSUMED["state_dtype"])),) * cfg.num_hidden_layers
 
 
@@ -192,8 +197,9 @@ _RETENTION_TRACED: dict = {}
 
 
 def retention_traced(tokens_shape) -> Optional[str]:
-    """``"chunked"`` / ``"step"`` as the program traced in this process for
-    tokens of this (B, L) has it; None where none was traced."""
+    """``"chunked"`` / ``"step"`` / ``"fused"`` (the step as one kernel:
+    ``ops/retention.py::step_form``) as the program traced in this process
+    for tokens of this (B, L) has it; None where none was traced."""
     return _RETENTION_TRACED.get(tuple(tokens_shape))
 
 
@@ -240,7 +246,7 @@ def _decode_block(layer, kind, x, entry, positions, column, cfg, *, active):
     b = x.shape[0]
     with jax.named_scope("ret.proj"):
         q, k, v, log_g = _inputs(layer, x, column, cfg)
-    _RETENTION_TRACED[(b, 1)] = "step"
+    _RETENTION_TRACED[(b, 1)] = ret_ops.step_form(entry["S"], q[:, 0])
     y, S, z = ret_ops.power_retention_step(entry["S"], entry["z"], q[:, 0],
                                            k[:, 0], v[:, 0], log_g[:, 0],
                                            active)

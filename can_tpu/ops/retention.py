@@ -18,8 +18,15 @@ and the same numbers as a recurrence over a MATRIX state a key head, with
 
 Shapes: ``q`` (B, L, KV, G, d) with G query heads to each of the KV key
 heads, ``k`` (B, L, KV, d), ``v`` (B, L, KV, dv), ``log_g`` (B, L, KV) float32;
-the state ``S`` (B, KV, ROWS, dv) and ``z`` (B, KV, ROWS), float32 in both forms
-and never rounded on the way: accumulators over every position seen.
+the state ``S`` (B, KV, dv, ROWS) and ``z`` (B, KV, ROWS), float32 in both forms
+and never rounded on the way: accumulators over every position seen.  ``S`` is
+kept TRANSPOSED, ``S^T`` of the recurrence above, the rows in the LANES: the
+layout in which the step's kernel streams it (``phi(k)`` and ``phi(q)`` are
+then lane-dense rows that broadcast over the sublanes for free and ``v`` is
+one column; rows in the sublanes would need ``phi(k)`` as a column, 65
+transposed broadcasts a tile, or an operand padded 128-fold in HBM).  The
+prompt form writes it so (its product's own output orientation: no extra
+pass) and the bytes a slot keeps are the same.
 
 **The state's rows** (``phi``, ``state_rows``).  The ``d (d + 1) / 2``
 distinct monomials ``a_i a_j`` (8,256 at d = 128) are laid out BY OFFSET:
@@ -37,9 +44,16 @@ gather), ``state_rows(d)`` = ``(d / 2 + 1) d`` rows (8,320: 65 whole rows of
   (a scan: L / chunk steps).  A chunk as long as the prompt bucket is the
   quadratic form plus one state at the end, with no product against a state.
 * ``power_retention_step`` (decode): the state read, decayed, updated,
-  queried, written; float32 throughout: the update on the vector unit, the
-  query a product at the HIGHEST precision (at the default precision a TPU
-  would round the state it reads to bfloat16).
+  queried, written; float32 throughout.  ONE step, two forms with the same
+  answers (``step_form``: the backend, the shapes and the state's dtype
+  choose, nothing else): on a TPU at heads of 128 the Pallas kernel
+  ``ops/pallas_retention.py::fused_step``, in which a (slot, key head) state
+  goes through the chip ONCE and is written where it lay; everywhere else
+  (every CPU run, the tiny presets, a state that is not float32) the plain
+  form ``_step_plain``, the kernel's oracle: the update on the vector unit,
+  the query a product at the HIGHEST precision (at the default precision a
+  TPU would round the state it reads to bfloat16), two XLA fusions of the
+  state, three passes over it.
 
 Prompts are right-padded.  Padding advances nothing: at a position at or
 past a sequence's length ``log g`` is 0 and ``k`` is 0 (so ``phi(k)`` is), as
@@ -81,15 +95,19 @@ def state_rows(d: int) -> int:
     return (d // 2 + 1) * d
 
 
-def _weights(d: int):
-    """(``state_rows(d)``,) float32: what each monomial ``a_i a_(i + o)`` (row
-    ``o d + i``) is weighted by so that ``phi(a) . phi(b) = (a . b)^2 / d``:
+def _weights_of(row, d: int):
+    """float32, ``row``'s shape: what the monomial ``a_i a_(i + o)`` of row
+    ``o d + i`` is weighted by so that ``phi(a) . phi(b) = (a . b)^2 / d``:
     the squares once, every other pair twice, and nothing for the second
     half of the last block, whose pairs are its first half's again."""
-    row = jnp.arange(state_rows(d))
     return jnp.where(row < d, 1.0 / math.sqrt(d),
                      jnp.where(row < d * d // 2 + d // 2, math.sqrt(2.0 / d),
                                0.0)).astype(jnp.float32)
+
+
+def _weights(d: int):
+    """(``state_rows(d)``,): ``_weights_of`` every row."""
+    return _weights_of(jnp.arange(state_rows(d)), d)
 
 
 def _turns(d: int, dtype):
@@ -143,7 +161,7 @@ def _chunk(q, k, v, a, S, z):
             lead = jnp.exp(a_cum)
             pq = phi(q, dtype)
             num = num + lead[..., None, None] * jnp.einsum(
-                "bikgm,bkmv->bikgv", pq, S.astype(dtype),
+                "bikgm,bkvm->bikgv", pq, S.astype(dtype),
                 preferred_element_type=jnp.float32)
             den = den + lead[..., None] * jnp.einsum(
                 "bikgm,bkm->bikg", pq, z.astype(dtype),
@@ -151,10 +169,22 @@ def _chunk(q, k, v, a, S, z):
         # the chunk's own part of the state at its end
         to_end = jnp.exp(a_end[:, None] - a_cum)                 # (B, c, KV)
         pk = phi(k)
-        own_S = jnp.einsum(
-            "bjkm,bjkv->bkmv", pk.astype(dtype),
-            (to_end[..., None] * v.astype(jnp.float32)).astype(dtype),
-            preferred_element_type=jnp.float32)
+        # rows by dv, the orientation this product writes by itself (``phi(k)``
+        # has its positions minor, as the product that made it left it),
+        # then turned into the state's: asked for as "bkvm" the COMPILER
+        # turns ``phi(k)`` instead, a copy of 1.09 GB a layer of the cell's
+        # prefill slice where the state's part is 136 MB (compiled for a
+        # described v5e: the slice's temporaries 2.34 GB against 1.38; on
+        # the chip ``ret.state`` 17.75 ms per 1,000 tokens against 9.64, my
+        # chip run, PR 48); the barrier keeps the two apart.  The inputs
+        # are rounded to ``dtype`` and multiplied as float32 (the same
+        # numbers as a ``dtype`` product accumulated in float32, one pass of
+        # the matrix unit at the default precision): XLA:CPU has no
+        # bfloat16 x bfloat16 = float32 product whose result is turned
+        rounded = lambda x: x.astype(dtype).astype(jnp.float32)   # noqa: E731
+        own_S = jnp.swapaxes(jax.lax.optimization_barrier(jnp.einsum(
+            "bjkm,bjkv->bkmv", rounded(pk),
+            rounded(to_end[..., None] * v.astype(jnp.float32)))), -1, -2)
         own_z = jnp.sum(pk * to_end[..., None], axis=1)
         if S is not None:
             fade = jnp.exp(a_end)
@@ -203,23 +233,65 @@ def power_retention_chunked(q, k, v, log_g, lengths, *, chunk: int = CHUNK):
         return (S, z), _normalised(num, den, q.dtype)
 
     rows = state_rows(d)
-    zero = (jnp.zeros((b, kv, rows, v.shape[-1]), jnp.float32),
+    zero = (jnp.zeros((b, kv, v.shape[-1], rows), jnp.float32),
             jnp.zeros((b, kv, rows), jnp.float32))
     (S, z), y = jax.lax.scan(carry, zero, tuple(map(chunks, (q, k, v, a))))
     return jnp.moveaxis(y, 0, 1).reshape(b, l, kv, g, v.shape[-1]), S, z
 
 
+def step_form(S, q) -> str:
+    """``"fused"`` / ``"step"``: the form ``power_retention_step`` takes for
+    a state ``S`` (B, KV, dv, ROWS) and queries ``q`` (B, KV, G, d) (arrays
+    or their shapes with a dtype).  The backend, the shapes and the state's
+    dtype choose, nothing else."""
+    # One layer's step at the cell's shape (16 slots x 8 key heads x 5 query
+    # heads of 128, 1.10 GB of state read and written once each way, the state
+    # donated) on the v5e, ms and GB/s of that (my chip run, PR 48,
+    # ``tools/retention_forms.py``; ``y_gap`` to the plain form 0.001 in
+    # bfloat16 for every row):
+    #   fused 1.698 (647 GB/s, 79% of the peak) | plain 2.412 (456, 56%)
+    # and the four forms XLA was given on the layout the state had until
+    # PR 48, rows in the sublanes (kept in the tool): the query a float32
+    # product at the highest precision 2.419 | a multiply and a sum over the
+    # rows on the vector unit 3.298 | that BEFORE the update 3.301 | the
+    # group's heads side by side in the lanes 3.299.  The compiler makes two
+    # fusions of a layer's state in every one of them: three passes.
+    # (imported here: ``jax.experimental.pallas`` takes over a second to
+    # load, paid only by a process that traces a retention layer's step)
+    from can_tpu.ops import pallas_retention
+
+    return ("fused" if pallas_retention.supports(S.shape, q.shape, S.dtype)
+            else "step")
+
+
 def power_retention_step(S, z, q, k, v, log_g, active=None):
-    """One position: ``S`` (B, KV, ROWS, dv), ``z`` (B, KV, ROWS) float32,
+    """One position: ``S`` (B, KV, dv, ROWS), ``z`` (B, KV, ROWS) float32,
     ``q`` (B, KV, G, d), ``k`` (B, KV, d), ``v`` (B, KV, dv), ``log_g``
     (B, KV) -> (y (B, KV, G, dv) in ``q``'s dtype, the state moved on by
-    one).  Slots ``active`` (B,) marks False keep their state (and are
-    answered from it: their ``y`` is nobody's)."""
+    one).  Slots ``active`` (B,) marks False keep their state (their ``y``
+    is nobody's: the plain form answers them from the state they keep, the
+    kernel with zeros)."""
+    if step_form(S, q) != "fused":
+        return _step_plain(S, z, q, k, v, log_g, active)
+    from can_tpu.ops import pallas_retention
+
+    with jax.named_scope("ret.state"):
+        if active is None:
+            active = jnp.ones(S.shape[:1], bool)
+        num, den, S, z = pallas_retention.fused_step(
+            S, z, q, k, v, jnp.exp(log_g.astype(jnp.float32)), active)
+    return _normalised(num, den, q.dtype), S, z
+
+
+def _step_plain(S, z, q, k, v, log_g, active=None):
+    """``power_retention_step`` in plain ``jax.numpy``: the form of every
+    shape the kernel does not take (the CPU, heads that are not one row of
+    lanes, a state that is not float32) and the kernel's oracle."""
     with jax.named_scope("ret.state"):
         fade = jnp.exp(log_g.astype(jnp.float32))
         pk = phi(k)                                              # (B, KV, R)
         new_S = (fade[..., None, None] * S
-                 + pk[..., None] * v.astype(jnp.float32)[..., None, :])
+                 + v.astype(jnp.float32)[..., :, None] * pk[..., None, :])
         new_z = fade[..., None] * z + pk
         if active is not None:
             new_S = jnp.where(active[:, None, None, None], new_S, S)
@@ -229,12 +301,8 @@ def power_retention_step(S, z, q, k, v, log_g, active=None):
         pq = phi(q)                                              # (B, KV, G, R)
         # float32 against float32 at the HIGHEST precision: at the default a
         # TPU rounds both to bfloat16, and the float32 state would be read as
-        # a bfloat16 one.  On the v5e this product reads a layer's 545 MB in
-        # 0.78 ms where a multiply and a sum over the rows take 1.66 (my chip
-        # run, PR 47, ``tools/retention_forms.py``: 2.43 | 3.31 ms a layer's
-        # step with the update; querying BEFORE the update, or the group's
-        # heads side by side in the lanes, 3.30 | 3.31)
-        num = jnp.einsum("bkgm,bkmv->bkgv", pq, new_S.astype(jnp.float32),
+        # a bfloat16 one
+        num = jnp.einsum("bkgm,bkvm->bkgv", pq, new_S.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
         den = jnp.sum(pq * new_z[:, :, None], axis=-1, dtype=jnp.float32)
     return _normalised(num, den, q.dtype), new_S, new_z

@@ -125,7 +125,7 @@ class LMPrograms:
         self.ssm_traced = getattr(model, "ssm_traced", None)
         # the same of a gated short convolution: "causal" / "step"
         self.conv_traced = getattr(model, "conv_traced", None)
-        # the same of a retention layer: "chunked" / "step"
+        # the same of a retention layer: "chunked" / "step" / "fused"
         self.retention_traced = getattr(model, "retention_traced", None)
         self._cache_part = getattr(model, "CACHE_PART", "attn.cache")
         self._experts_form = getattr(model, "experts_form", None)

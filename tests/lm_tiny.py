@@ -234,3 +234,17 @@ def interpret_skipping_experts(monkeypatch) -> None:
         pallas_experts.supports, interpret=True))
     monkeypatch.setattr(pallas_experts, "skipping_experts", functools.partial(
         pallas_experts.skipping_experts, interpret=True))
+
+
+def interpret_fused_retention(monkeypatch) -> None:
+    """The fused retention step (``ops/pallas_retention.py``) interpreted
+    wherever its shapes fit: what a TPU backend turns on, steered here as
+    the compile tests steer it."""
+    import functools
+
+    from can_tpu.ops import pallas_retention
+
+    monkeypatch.setattr(pallas_retention, "supports", functools.partial(
+        pallas_retention.supports, interpret=True))
+    monkeypatch.setattr(pallas_retention, "fused_step", functools.partial(
+        pallas_retention.fused_step, interpret=True))

@@ -23,7 +23,8 @@ from can_tpu.serve import GenerateService, build_model_service, lm_probe_steps
 from can_tpu.serve import cache as kv_cache
 from can_tpu.serve import programs as serve_programs
 
-from lm_tiny import tiny_brumby_config, tiny_brumby_model
+from lm_tiny import (interpret_fused_retention, tiny_brumby_config,
+                     tiny_brumby_model)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CELL_FILE = os.path.join(REPO, "benchmark", "configs",
@@ -57,7 +58,7 @@ def test_the_tiny_preset_has_every_mechanism(tiny):
     assert "head" in params                      # untied
     (spec,) = set(bm.cache_layout(cfg))
     assert spec.kind == "state" and spec.shapes(4, 99) == {
-        "S": (4, 2, 40, 8), "z": (4, 2, 40)}     # no positions
+        "S": (4, 2, 8, 40), "z": (4, 2, 40)}     # no positions; rows in the lanes
     assert dict(spec.dtypes) == {"S": "float32", "z": "float32"}
     # the seeded gates lie near one: a state that forgets in two positions
     # would hide every fault of the state from the comparison
@@ -150,6 +151,52 @@ def test_an_inactive_slot_keeps_its_state(tiny):
             assert (np.asarray(after[leaf])[0] != np.asarray(before[leaf])[0]).any()
 
 
+def test_heads_of_128_decode_in_the_fused_kernel(monkeypatch):
+    """The published head width at a toy depth: prefill, then 4 greedy steps
+    with the step as ONE kernel a layer (``ops/pallas_retention.py``,
+    interpreted here; what a TPU backend turns on), one slot inactive, against
+    the reference's full forward; and the same steps in the plain form."""
+    d, cfg, params = tiny_brumby_model(seed=6, head_dim=128,
+                                       num_hidden_layers=2)
+    spec = ref.spec_from_config(d)
+    prompts = [_tokens(11, 1), _tokens(5, 2), _tokens(16, 3)]
+    toks, lengths = _padded(prompts, 16)
+    logits, cache, _ = bm.prefill(params, toks, lengths, cfg, 16 + 4)
+    assert cache["layers"][0]["S"].shape == (3, 2, 128, 8320)
+    active = jnp.asarray([True, False, True])
+    first = jnp.argmax(logits, -1).astype(jnp.int32)
+
+    def steps(form):
+        # (a function of its own: a second ``jit`` of ``bm.decode_step``
+        # itself would find the first one's trace)
+        step = jax.jit(lambda c, tok, pos: bm.decode_step(
+            params, c, tok, pos, cfg, active))
+        got, c, tok, pos = [], cache, first, lengths
+        for _ in range(4):
+            out, c, _ = step(c, tok, pos)
+            got.append(np.asarray(out))
+            tok, pos = jnp.argmax(out, -1).astype(jnp.int32), pos + 1
+        assert bm.retention_traced((3, 1)) == form
+        return np.stack(got, 1), c
+
+    plain, plain_cache = steps("step")
+    interpret_fused_retention(monkeypatch)
+    fused, fused_cache = steps("fused")
+    for i in (0, 2):
+        seq = list(prompts[i]) + [int(first[i])] + [
+            int(t) for t in fused[i, :3].argmax(-1)]
+        want = np.asarray(ref.forward(params, np.asarray(seq, np.int32),
+                                      spec)["logits"])[len(prompts[i]):]
+        np.testing.assert_allclose(fused[i], want, **TOL)
+        np.testing.assert_allclose(fused[i], plain[i], **TOL)
+    for kept, a, b in zip(cache["layers"], fused_cache["layers"],
+                          plain_cache["layers"]):
+        for leaf in ("S", "z"):
+            assert np.array_equal(np.asarray(a[leaf])[1], np.asarray(kept[leaf])[1])
+            np.testing.assert_allclose(np.asarray(a[leaf]), np.asarray(b[leaf]),
+                                       rtol=1e-4, atol=2e-5)
+
+
 @pytest.mark.parametrize("variant,moves", [
     ("no_gate", True), ("no_normaliser", True), ("no_head_norm", True),
     ("no_rope", True), ("no_scale", False)])
@@ -219,8 +266,11 @@ def test_the_cell_s_cache_is_state_alone_at_the_issue_s_size():
         bm.cache_layout(cfg), slots=16, positions=1536))
     rows = ret_ops.state_rows(128)
     assert rows == 8320        # 65 whole rows of lanes; 8,256 distinct monomials
-    per_slot = 8 * (8 * rows * 128 * 4 + 8 * rows * 4)
+    per_slot = 8 * (8 * 128 * rows * 4 + 8 * rows * 4)
     assert per_slot == 274_759_680
+    # the rows in the lanes (the layout the step's kernel streams): the bytes
+    # a slot keeps are the same
+    assert made["layers"][0]["S"].shape == (16, 8, 128, rows)
     assert kv_cache.nbytes_by_kind(made, bm.cache_layout(cfg)) == {
         "state": 16 * per_slot}
     # the same whatever the context: the context bounds nothing
@@ -355,8 +405,31 @@ def test_the_cache_s_bytes_hold_state_alone(service):
     assert 'kind="full"' not in text
 
 
-def test_the_spans_say_which_form_the_retention_ran_in(service):
-    svc, _, tracer = service
+@pytest.fixture
+def kernel_service(monkeypatch):
+    """A service of its own at the published head width (one layer), the
+    step's kernel interpreted: what a TPU backend turns on."""
+    interpret_fused_retention(monkeypatch)
+    config = brumby_config(head_dim=128, num_hidden_layers=1, max_batch=2,
+                           published={"num_hidden_layers": 1,
+                                      "vocab_size": 256})
+    tracer = spans.SpanTracer()
+    tel = Telemetry()
+    tel.spans = tracer
+    cfg = bm.BrumbyConfig.from_dict(config)
+    params = bm.init_params(jax.random.key(3), cfg, jnp.float32)
+    svc = build_model_service(config, params=params, telemetry=tel)
+    report = svc.warmup()
+    svc.start()
+    yield svc, report, tracer
+    svc.close()
+
+
+@pytest.mark.parametrize("which,slots,form", [("service", 4, "step"),
+                                              ("kernel_service", 2, "fused")])
+def test_the_spans_say_which_form_the_retention_ran_in(request, which, slots,
+                                                       form):
+    svc, _, tracer = request.getfixturevalue(which)
     ticket = svc.submit(_tokens(9, 7))
     ticket.result(120)
     want = ticket._request.batch_span.span_id
@@ -369,10 +442,10 @@ def test_the_spans_say_which_form_the_retention_ran_in(service):
                   and s.get("parent_id") == want)
     inner = {s["name"]: s for s in ring if s.get("parent_id") == launch["span_id"]}
     assert inner["lm.prefill"]["retention"] == "chunked"
-    assert inner["lm.decode"]["retention"] == "step"
+    assert inner["lm.decode"]["retention"] == form
     assert "ssm" not in inner["lm.prefill"] and "conv" not in inner["lm.decode"]
     assert svc.engine.retention_forms[(2, 16)] == "chunked"
-    assert svc.engine.retention_forms[(4, 1)] == "step"
+    assert svc.engine.retention_forms[(slots, 1)] == form
     # the programs' maps were recorded with both counts of whole copies
     scopes = [s for s in ring if s["name"] == "program.scopes"]
     assert scopes and all(s["cache_copies"] == 0 and s["state_copies"] >= 0

@@ -828,11 +828,18 @@ def _brumby_parts(programs, text) -> set:
     return set(got["parts"].values())
 
 
-def test_brumby_decode_step_writes_its_state_where_it_lies(v5e):
+def test_brumby_decode_step_writes_its_state_where_it_lies(v5e, monkeypatch):
     """One greedy step of 16 slots: every layer's float32 matrix state (16 x
-    8 x 8,320 x 128: 545 MB a layer, 4.4 GB in all) read, moved on and
-    written IN PLACE (donated): no whole copy of a state leaf, no second
-    state alive, beside 8.4 GB of weights."""
+    8 x 128 x 8,320: 545 MB a layer, 4.4 GB in all) read, moved on and
+    written IN PLACE (donated) by ONE launch a layer of the fused kernel
+    (``ops/pallas_retention.py``, PR 48): no whole copy of a state leaf, no
+    second state alive, no XLA fusion the size of a layer's state (the update
+    and the query were two until PR 48), beside 8.4 GB of weights."""
+    import re
+
+    from can_tpu.models import brumby as bm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     programs, params, cache, _, shape = _lm_programs_and_shapes(
         v5e, 16, 4, BRUMBY)
     state = jax.tree.map(
@@ -845,16 +852,31 @@ def test_brumby_decode_step_writes_its_state_where_it_lies(v5e):
             jnp.ones((16,), jnp.int32), jnp.ones((16,), bool))[0]))
     compiled = jax.jit(programs.decode, donate_argnums=(1, 2)).lower(
         params, state, cache).compile()
+    assert bm.retention_traced((16, 1)) == "fused"
     assert _cache_copies(compiled, programs, cache) == {}   # nothing has positions
     assert _cache_copies(compiled, programs, cache, positioned=False) == {
-        "f32[16,8,8320,128]": 0, "f32[16,8,8320]": 0}
+        "f32[16,8,128,8320]": 0, "f32[16,8,8320]": 0}
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= 16 * 274_759_680
     # weights + state, and temporaries under ONE layer's state
     assert m.temp_size_in_bytes < 16 * 274_759_680 // 8
     assert 11.5 * 2**30 < _fits_hbm(compiled) < 12.5 * 2**30
-    assert {"ret.proj", "ret.state", "ret.out"} <= _brumby_parts(
-        programs, compiled.as_text())
+    text = compiled.as_text()
+    # one launch a layer, its state operand the cache's own leaf (a parameter
+    # or the tuple element of one: no copy, no transpose before it)
+    calls = re.findall(r"^\s*%fused_retention_step[.\d]* = .* custom-call\("
+                       r"[^)]*(%cache__layers___\d___S__[.\d]*)", text, re.M)
+    assert len(calls) == len(set(calls)) == 8, calls
+    # nothing else in the program is the size of a layer's state: the
+    # ``add_select_fusion`` that updated it and the product that read it
+    # again are gone (the kernel's own result apart)
+    whole = [l for l in text.splitlines()
+             if re.search(r"= f32\[16,8,(128,8320|8320,128)\]", l)
+             and "parameter(" not in l and "get-tuple-element(" not in l]
+    assert whole == [], whole[:3]
+    found = _parts_of_the_compiled(programs, text, "fused_retention_step",
+                                   "ret.state", 8)
+    assert {"ret.proj", "ret.state", "ret.out"} <= set(found.values())
 
 
 def test_brumby_prefill_slice_compiles_for_one_device(v5e):
@@ -872,7 +894,7 @@ def test_brumby_prefill_slice_compiles_for_one_device(v5e):
     text = compiled.as_text()
     assert " while(" not in text
     assert _cache_copies(compiled, programs, cache, positioned=False) == {
-        "f32[16,8,8320,128]": 0, "f32[16,8,8320]": 0}
+        "f32[16,8,128,8320]": 0, "f32[16,8,8320]": 0}
     assert 12 * 2**30 < _fits_hbm(compiled) < 15.5 * 2**30
     assert {"ret.proj", "ret.core", "ret.state", "ret.out"} <= _brumby_parts(
         programs, text)

@@ -38,7 +38,7 @@ def quadratic(q, k, v, log_g):
 def stepped(q, k, v, log_g, lengths):
     """``power_retention_step`` from a zero state, position by position,
     each sequence active up to its own length: -> (y, S, z)."""
-    S = jnp.zeros((B, KV, ret.state_rows(D), DV), jnp.float32)
+    S = jnp.zeros((B, KV, DV, ret.state_rows(D)), jnp.float32)
     z = jnp.zeros((B, KV, ret.state_rows(D)), jnp.float32)
     ys = []
     for t in range(q.shape[1]):

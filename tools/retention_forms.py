@@ -7,13 +7,19 @@ bfloat16 activations, a float32 state of 8,320 rows a key head):
   by chunk: 128, 256, 512 and 1,024 (the bucket: the quadratic form plus one
   state at the end), each form's answer beside the last one's;
 * the step ``power_retention_step`` at a launch's 16 slots (545 MB of state
-  read and written), the module's form (the state queried after its update
-  by a float32 product at the highest precision) beside three that the
-  module does NOT take, kept here so that the readings in its comments can
-  be made again: ``rows`` (the query as a multiply and a sum over the rows on
-  the vector unit), ``old`` (that, on the state BEFORE its update with the
-  step's own term added, so that query and update read the same array) and
-  ``lanes`` (that, with the group's heads laid side by side in the lanes).
+  read and written), every form WITH THE STATE DONATED and handed on from
+  call to call, so that the write in place is what is timed: ``fused`` (the
+  module's form on the chip: ``ops/pallas_retention.py::fused_step``, the
+  state in the cache's layout, rows in the lanes, through the chip once),
+  ``plain`` (the module's plain form, the kernel's oracle: two XLA fusions of
+  the state) and four forms of the layout the state had until PR 48 (rows in
+  the sublanes, ``(slots, heads, rows, dv)``), kept here so that the readings
+  in the module's comments can be made again: ``product`` (PR 47's module
+  form: the state queried after its update by a float32 product at the
+  highest precision), ``rows`` (the query as a multiply and a sum over the
+  rows on the vector unit), ``old`` (that, on the state BEFORE its update with
+  the step's own term added, so that query and update read the same array)
+  and ``lanes`` (that, with the group's heads laid side by side in the lanes).
 
     chiprun --chips 1 -- python3 tools/retention_forms.py
     JAX_PLATFORMS=cpu python3 tools/retention_forms.py --rehearse
@@ -38,6 +44,7 @@ HBM_GBS = 819.0
 
 
 def _update(S, z, k, v, log_g, active):
+    """The update on the layout ``(slots, heads, rows, dv)``."""
     fade = jnp.exp(log_g.astype(jnp.float32))
     pk = phi(k)
     new_S = (fade[..., None, None] * S
@@ -45,6 +52,15 @@ def _update(S, z, k, v, log_g, active):
     new_z = fade[..., None] * z + pk
     return (jnp.where(active[:, None, None, None], new_S, S),
             jnp.where(active[:, None, None], new_z, z), fade, pk)
+
+
+def step_product(S, z, q, k, v, log_g, active):
+    new_S, new_z, _, _ = _update(S, z, k, v, log_g, active)
+    pq = phi(q)
+    num = jnp.einsum("bkgm,bkmv->bkgv", pq, new_S,
+                     precision=jax.lax.Precision.HIGHEST)
+    den = jnp.sum(pq * new_z[:, :, None], axis=-1)
+    return _normalised(num, den, q.dtype), new_S, new_z
 
 
 def step_rows(S, z, q, k, v, log_g, active):
@@ -77,10 +93,22 @@ def step_lanes(S, z, q, k, v, log_g, active):
     return _normalised(num, den, q.dtype), new_S, new_z
 
 
-STEPS = {"module": ret.power_retention_step, "rows": step_rows,
-         "old": step_old, "lanes": step_lanes}
+def step_fused(interpret):
+    """``fused_step`` as ``power_retention_step`` calls it."""
+    from can_tpu.ops import pallas_retention
+
+    def run(S, z, q, k, v, log_g, active):
+        num, den, S, z = pallas_retention.fused_step(
+            S, z, q, k, v, jnp.exp(log_g.astype(jnp.float32)), active,
+            interpret=interpret)
+        return _normalised(num, den, q.dtype), S, z
+
+    return run
 
 
+# the forms of the state's layout until PR 48: rows in the sublanes
+ROWS_IN_SUBLANES = {"product": step_product, "rows": step_rows,
+                    "old": step_old, "lanes": step_lanes}
 def _time(run, args, reps):
     out = run(*args)
     jax.block_until_ready(out)
@@ -91,6 +119,21 @@ def _time(run, args, reps):
     return 1e3 * (time.perf_counter() - t0) / reps, out
 
 
+def _time_step(fn, S, z, one, reps):
+    """A step with its state donated and handed on: -> (ms a step, the first
+    step's ``y``).  ``S`` and ``z`` are copied first: the caller keeps its
+    own."""
+    run = jax.jit(fn, donate_argnums=(0, 1))
+    first, S, z = run(S + 0.0, z + 0.0, *one)
+    first = np.asarray(first, np.float32)
+    jax.block_until_ready(S)
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        _, S, z = run(S, z, *one)
+    jax.block_until_ready(S)
+    return 1e3 * (time.perf_counter() - t0) / reps, first
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--rehearse", action="store_true")
@@ -99,9 +142,10 @@ def main():
     if dev.platform != "tpu" and not args.rehearse:
         print("retention_forms: no TPU (use --rehearse on the CPU)", file=sys.stderr)
         return 2
-    b, l, kv, g, d, slots, reps = ((2, 64, 2, 3, 8, 4, 2) if args.rehearse
+    # (the rehearsal keeps heads of 128: the kernel takes no other)
+    b, l, kv, g, d, slots, reps = ((2, 16, 2, 3, 128, 2, 1) if args.rehearse
                                    else (4, 1024, 8, 5, 128, 16, 10))
-    chunks = (8, 16, 32, 64) if args.rehearse else (128, 256, 512, 1024)
+    chunks = (4, 8, 16) if args.rehearse else (128, 256, 512, 1024)
     # (XLA:CPU has no bfloat16 product inside a scan: the rehearsal is float32)
     dtype = jnp.float32 if args.rehearse else jnp.bfloat16
     ks = jax.random.split(jax.random.key(47), 8)
@@ -142,16 +186,24 @@ def main():
     one = tuple(jnp.tile(x[:, 0], (slots // b,) + (1,) * (x.ndim - 2))
                 for x in (q, k, v, log_g)) + (active,)
     want = None
-    for name, fn in STEPS.items():
-        run = jax.jit(fn)
-        ms, out = _time(run, (S, z, *one), 5 * reps)
-        y = np.asarray(out[0], np.float32)
+    # the oracle first: the others' ``y_gap`` is against its answer
+    forms = [("plain", ret._step_plain, (S, z)),
+             ("fused", step_fused(args.rehearse), (S, z))]
+    forms += [(name, fn, (jnp.swapaxes(S, -1, -2), z))
+              for name, fn in ROWS_IN_SUBLANES.items()]
+    for name, fn, state in forms:
+        try:
+            ms, y = _time_step(fn, *state, one, 5 * reps)
+        except Exception as e:   # a variant the compiler refuses says so
+            rows.append({"form": f"step {name}", "error": str(e)[:300]})
+            print(json.dumps(rows[-1]), flush=True)
+            continue
         row = {"form": f"step {name}", "ms": ms,
                "state_read_and_written_GBs": state_gb / (ms * 1e-3),
                "of_peak_pct": 100.0 * state_gb / (ms * 1e-3) / HBM_GBS}
         if want is not None:
             row["y_gap"] = float(np.abs(y - want).max())
-        else:
+        elif name == "plain":
             want = y
         rows.append(row)
         print(json.dumps(row), flush=True)
