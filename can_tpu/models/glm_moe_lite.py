@@ -54,6 +54,7 @@ from can_tpu.models.lm_blocks import (VocabSlice, count_shapes, ffn,
 from can_tpu.ops import attention as attn_ops
 from can_tpu.ops import cache_layout as layout
 from can_tpu.ops import pallas_attention as fused_attn
+from can_tpu.ops import pallas_latent as fused_latent
 from can_tpu.ops.moe import ExpertShare
 
 # queries and keys of a prefill in the SCANNED form meet in blocks of this
@@ -221,9 +222,9 @@ def cache_layout(cfg: Glm4MoeLiteConfig) -> tuple:
     (``ops/cache_layout.py``): the latent and the shared rotary key of every
     position, no heads.  Two arrays and not one of ``rank + rope_dim``: 576
     is not a whole number of the 128 lanes, so that array would reach a
-    program with its positions minor and be copied whole around every
-    step's one-row write, as the 64-wide ``krope`` is today (compiled for a
-    described v5e, never timed: PERF.md section 7)."""
+    program with its positions minor, which the decode kernel cannot read
+    as rows of latent (the 64-wide ``krope`` does arrive so, and is read and
+    written so: ``ops/pallas_latent.py``, ``attn_ops.write_row``)."""
     return (layout.latent_layer(rank=cfg.kv_lora_rank,
                                 rope_dim=cfg.qk_rope_head_dim),
             ) * cfg.num_layers
@@ -271,6 +272,17 @@ def attention_traced(tokens_shape) -> Optional[str]:
     return _ATTENTION_TRACED.get(tuple(tokens_shape))
 
 
+# (B, 1) of a decode step -> the form the newest trace of
+# ``attention_absorbed`` for B sequences read the latent cache in
+_LATENT_TRACED: dict = {}
+
+
+def latent_traced(tokens_shape) -> Optional[str]:
+    """``"fused"`` / ``"plain"`` as the decode step traced in this process
+    for tokens of this (B, 1) has it; None where none was traced."""
+    return _LATENT_TRACED.get(tuple(tokens_shape))
+
+
 def attention_expanded(p, xn, positions, lengths, cfg: Glm4MoeLiteConfig):
     """Whole prompts, keys and values rebuilt per head from the latent:
     -> (the layer's output (B, L, d) before the residual, c_kv, k_rope).
@@ -308,7 +320,12 @@ def attention_expanded(p, xn, positions, lengths, cfg: Glm4MoeLiteConfig):
 def attention_absorbed(p, xn, positions, entry, cfg: Glm4MoeLiteConfig):
     """One token a sequence, in the latent space: ``xn`` (B, 1, d) at
     ``positions`` (B,), its latent written into ``entry`` before it attends
-    -> (the layer's output (B, 1, d), the entry)."""
+    -> (the layer's output (B, 1, d), the entry).  The cache is read in one
+    of two forms of one algorithm: the fused kernel over each sequence's own
+    context (``ops/pallas_latent.py``) where its ``supports`` says it can run
+    (a TPU, a rank of whole lanes, a cache of at least one block), the plain
+    ``decode_latent`` over every allocated position everywhere else.  Nothing
+    else chooses."""
     b = xn.shape[0]
     with jax.named_scope("attn.proj"):
         q_nope, q_rope = _queries(p, xn, positions[:, None], cfg)
@@ -319,10 +336,18 @@ def attention_absorbed(p, xn, positions, entry, cfg: Glm4MoeLiteConfig):
     with jax.named_scope("attn.proj"):
         w_uk, w_uv = _up_projections(p, cfg)
         q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_uk)
+    fused = fused_latent.supports(q_lat.shape, ckv_c.shape, krope_c.shape[-1],
+                                  ckv_c.dtype)
+    _LATENT_TRACED[(b, 1)] = "fused" if fused else "plain"
     with jax.named_scope("attn.core"):
-        valid = jnp.arange(ckv_c.shape[1])[None, :] <= positions[:, None]
-        o_lat = attn_ops.decode_latent(q_lat, q_rope[:, 0], ckv_c, krope_c,
-                                       valid, scale=cfg.scale)
+        if fused:
+            o_lat = fused_latent.fused_latent_decode(
+                q_lat, q_rope[:, 0], ckv_c, krope_c, positions,
+                scale=cfg.scale)
+        else:
+            valid = jnp.arange(ckv_c.shape[1])[None, :] <= positions[:, None]
+            o_lat = attn_ops.decode_latent(q_lat, q_rope[:, 0], ckv_c,
+                                           krope_c, valid, scale=cfg.scale)
     with jax.named_scope("attn.out"):
         o = jnp.einsum("bhr,rhv->bhv", o_lat, w_uv)
         return (jnp.dot(o.reshape(b, 1, -1), p["wo"]),

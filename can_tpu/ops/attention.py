@@ -46,9 +46,14 @@ _full_prefill`` (the full layers of a grouped-query model: 64 query heads of
 192 over 4 key heads, values 128: ``prefill_causal`` over GROUPS is that
 kernel's oracle too).  K-EXAONE's, Falcon-H1's and LFM2's full layers and
 every window layer run ``prefill_full`` / ``prefill_window`` (plain
-``jax.numpy``).  Latent attention's decode (``decode_latent``) is the
-absorbed form: queries carried into the latent space, the cache read as it
-is stored.
+``jax.numpy``).  Latent attention's decode is the absorbed form: queries
+carried into the latent space, the cache read as it is stored.  It has two
+forms of one algorithm too: ``decode_latent`` here (two products around a
+softmax, every allocated position), which runs anywhere and is the other's
+oracle, and ``ops/pallas_latent.py::fused_latent_decode``, one launch that
+reads each sequence's own context once; ``pallas_latent.supports`` chooses
+(``models/glm_moe_lite.py::attention_absorbed`` asks it).  ``write_row``
+writes both of its leaves, each where it lies.
 """
 
 from __future__ import annotations
@@ -57,6 +62,7 @@ import jax
 import jax.numpy as jnp
 
 NEG = -1e30  # finite: a fully masked row (a padded slot) must not make NaN
+_LANES = 128   # the minor axis of a TPU tile
 
 
 def rope(x, positions, theta: float):
@@ -322,7 +328,12 @@ def prefill_causal(q, k, v, lengths=None, *, scale=None, block: int = 1024):
 
 
 def decode_latent(q_lat, q_rope, ckv, krope, valid, *, scale: float):
-    """One query per sequence against a latent cache, in the latent space.
+    """One query per sequence against a latent cache, in the latent space:
+    the PLAIN form (two products around a softmax whose float32 scores go
+    through HBM, every allocated position read twice), which runs anywhere
+    and is the oracle of the fused kernel (``ops/pallas_latent.py``: one
+    launch that reads each sequence's own context once, where its
+    ``supports`` says it can run).
     ``q_lat`` (B, H, R): the queries' no-rotary part carried through the key
     up-projection; ``q_rope`` (B, H, Dr); ``ckv`` (B, S, R), ``krope``
     (B, S, Dr); ``valid`` (B, S) -> the attended latents (B, H, R), which the
@@ -336,6 +347,25 @@ def decode_latent(q_lat, q_rope, ckv, krope, valid, *, scale: float):
 
 def write_row(cache, new, pos):
     """``cache`` (B, S, D) with ``new`` (B, D) written at position ``pos``
-    (B,), one row per sequence."""
-    b = cache.shape[0]
-    return cache.at[jnp.arange(b), pos].set(new.astype(cache.dtype))
+    (B,), one row per sequence, where the leaf lies.
+
+    A leaf of whole lane rows (the latent, 512) is row-major on the chip and
+    one scatter over its two leading axes writes it in place.  A leaf
+    NARROWER than the 128 lanes (the rotary keys, 64) XLA:TPU keeps with the
+    POSITIONS minor (``bf16[16,16512,64]{1,2,0}``: how a donated argument
+    arrives and has to leave, and how ``ops/pallas_latent.py`` reads it); the
+    scatter wants it row-major and re-laid the whole leaf on the way in and
+    back on the way out, twice a layer and step (PERF.md section 6, PR 49).
+    A ``dynamic_update_slice`` takes a leaf in whatever layout it has: one a
+    sequence there, no copy.  Off a TPU no layout is at stake and the
+    scatter stands."""
+    b, _, d = cache.shape
+    new = new.astype(cache.dtype)
+    if d % _LANES == 0 or jax.default_backend() != "tpu":
+        return cache.at[jnp.arange(b), pos].set(new)
+    # (a position the leaf does not hold: the scatter drops it, this clamps
+    # it to the leaf's last row; a step never asks for one)
+    for i in range(b):
+        cache = jax.lax.dynamic_update_slice(cache, new[i][None, None],
+                                             (i, pos[i], 0))
+    return cache

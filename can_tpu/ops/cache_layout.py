@@ -2,7 +2,8 @@
 model gives of each of its layers (``cache_layout(cfg)``) and
 ``serve/cache.py`` allocates from.  It sits beside ``ops/attention.py``,
 whose writes and reads (``write_slot``, ``as_leaf``, ``write_row``,
-``decode``, ``decode_latent``) assume these leaves; the models and the
+``decode``, ``decode_latent``; ``ops/pallas_latent.py``'s fused form of the
+last) assume these leaves; the models and the
 serving path both import it, and neither imports the other for it.  A
 key/value layer's leaves are filled and read in one place for every model,
 ``models/lm_blocks.py::kv_entry`` / ``kv_decode``, which ask the layer's
@@ -28,7 +29,13 @@ key/value layer's leaves are filled and read in one place for every model,
   positions;
 * ``latent`` (latent attention): no heads; per position the compressed
   key/value latent and the one rotary key all heads share, ``{"ckv"
-  (slots, positions, rank), "krope" (slots, positions, rope_dim)}``;
+  (slots, positions, rank), "krope" (slots, positions, rope_dim)}``.  The
+  latent's rows are whole lanes and row-major on the chip.  The rotary keys
+  (64 wide) are NOT, and have no second head to lie beside: XLA:TPU keeps
+  that leaf with the positions minor, and both its users take it so
+  (``attention.write_row`` writes it a ``dynamic_update_slice`` a slot, not
+  a scatter; ``ops/pallas_latent.py`` reads it as (slots, rope_dim,
+  positions), a bitcast: PERF.md section 6, PR 49);
 * ``state`` (a recurrence): NO position axis, the size fixed whatever the
   context: each leaf ``(slots, *dimensions)`` of whatever the layer carries
   from one position to the next (a state-space mixer's state, its

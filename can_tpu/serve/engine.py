@@ -424,6 +424,9 @@ class LMEngine:
         self.conv_forms: Dict[tuple, str] = {}
         # the same of a retention layer (``LMPrograms.retention_traced``)
         self.retention_forms: Dict[tuple, str] = {}
+        # per decode program ((slots, 1)): the form its trace read a latent
+        # cache in, "fused" or "plain" (``LMPrograms.latent_traced``)
+        self.latent_forms: Dict[tuple, str] = {}
         # counters (the batcher thread writes, stats() reads a copy)
         self.counters = {"launches": 0, "generated_tokens": 0,
                          "prompt_tokens": 0, "decode_steps": 0,
@@ -553,6 +556,8 @@ class LMEngine:
                     sp.attrs["conv"] = self.conv_forms[(slots, 1)]
                 if (slots, 1) in self.retention_forms:
                     sp.attrs["retention"] = self.retention_forms[(slots, 1)]
+                if (slots, 1) in self.latent_forms:
+                    sp.attrs["latent"] = self.latent_forms[(slots, 1)]
                 # (expert layers, held): no rows in a model without experts
                 expert_layers, held = pre["counts"].shape
                 if expert_layers:
@@ -603,7 +608,9 @@ class LMEngine:
                              (self.programs.ssm_traced, self.ssm_forms),
                              (self.programs.conv_traced, self.conv_forms),
                              (self.programs.retention_traced,
-                              self.retention_forms)):
+                              self.retention_forms),
+                             (self.programs.latent_traced,
+                              self.latent_forms)):
             form = noted(program) if noted is not None else None
             if form is not None:
                 forms[program] = form

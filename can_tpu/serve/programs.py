@@ -83,8 +83,9 @@ class LMPrograms:
     * ``cfg.vocab`` (``lm_blocks.VocabSlice``): ids and logits are over the
       slice held;
     * optionally ``model.attention_traced`` / ``model.ssm_traced`` /
-      ``model.conv_traced`` / ``model.retention_traced``: (B, L) of a
-      program's tokens -> the form its newest trace ran that layer in;
+      ``model.conv_traced`` / ``model.retention_traced`` /
+      ``model.latent_traced``: (B, L) of a program's tokens -> the form its
+      newest trace ran that layer in;
     * optionally ``model.CACHE_PART``: the part of the model (a name of
       ``lm_blocks.PARTS``) that placing a prefill slice's rows into the
       launch's cache belongs to; ``attn.cache`` where the model names none;
@@ -127,6 +128,9 @@ class LMPrograms:
         self.conv_traced = getattr(model, "conv_traced", None)
         # the same of a retention layer: "chunked" / "step" / "fused"
         self.retention_traced = getattr(model, "retention_traced", None)
+        # ((B, 1) of a decode step) -> "fused" / "plain", as its newest
+        # trace read a latent cache; None for a model without one
+        self.latent_traced = getattr(model, "latent_traced", None)
         self._cache_part = getattr(model, "CACHE_PART", "attn.cache")
         self._experts_form = getattr(model, "experts_form", None)
         self.vocab_size = cfg.vocab.held   # ids and logits are over the slice

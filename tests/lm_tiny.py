@@ -248,3 +248,17 @@ def interpret_fused_retention(monkeypatch) -> None:
         pallas_retention.supports, interpret=True))
     monkeypatch.setattr(pallas_retention, "fused_step", functools.partial(
         pallas_retention.fused_step, interpret=True))
+
+
+def interpret_fused_latent(monkeypatch, block: int = 128) -> None:
+    """The fused latent decode (``ops/pallas_latent.py``) interpreted
+    wherever its shapes fit, in blocks of ``block`` positions: what a TPU
+    backend turns on, steered here as the compile tests steer it."""
+    import functools
+
+    from can_tpu.ops import pallas_latent
+
+    monkeypatch.setattr(pallas_latent, "supports", functools.partial(
+        pallas_latent.supports, block=block, interpret=True))
+    monkeypatch.setattr(pallas_latent, "fused_latent_decode", functools.partial(
+        pallas_latent.fused_latent_decode, block=block, interpret=True))

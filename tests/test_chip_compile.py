@@ -334,12 +334,10 @@ def test_lm_prefill_slice_compiles_for_one_device(v5e):
 GLM = "glm-4.7-flash-pp8-serve-bf16"
 
 
-def test_glm_decode_step_compiles_for_one_device(v5e):
-    """One greedy step of 16 slots over a latent cache of 16,512 positions
-    (the absorbed form: no per-head keys anywhere in the program), the cache
-    updated in place (donated): 7.79 GB of weights + 1.83 GB of cache."""
-    programs, params, cache, _, shape = _lm_programs_and_shapes(v5e, 16, 2, GLM)
-    state = jax.tree.map(
+def _glm_step_state(programs, shape):
+    """The decode state of a launch of 16 slots after its prefill, as shapes
+    on the described device."""
+    return jax.tree.map(
         lambda a: shape(a.shape, a.dtype),
         jax.eval_shape(lambda: programs.new_state(
             [{"first": jnp.zeros((16,), jnp.int32),
@@ -347,21 +345,73 @@ def test_glm_decode_step_compiles_for_one_device(v5e):
               "choices": jnp.zeros((5, 16, 4), jnp.int32),
               "counts": jnp.zeros((5, 64), jnp.int32)}],
             jnp.ones((16,), jnp.int32), jnp.ones((16,), bool))[0]))
+
+
+def test_glm_decode_step_compiles_for_one_device(v5e, monkeypatch):
+    """One greedy step of 16 slots over a latent cache of 16,512 positions
+    as the chip traces it (``supports`` and ``write_row`` ask the backend,
+    which is the CPU's during a compile for a described chip: steered here;
+    the absorbed form: no per-head keys anywhere in the program), the cache
+    updated in place (donated): 7.79 GB of weights + 1.83 GB of cache.  The
+    attention is the fused kernel, six launches that read both leaves WHERE
+    THEY LIE: the latent from the scatter that wrote its row in place, the
+    rotary keys as a bitcast of the leaf in the layout it arrives in
+    (positions minor), written a ``dynamic_update_slice`` a slot; no score
+    array is left in HBM."""
+    import re
+
+    from can_tpu.models import glm_moe_lite as gm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    programs, params, cache, _, shape = _lm_programs_and_shapes(v5e, 16, 2, GLM)
+    state = _glm_step_state(programs, shape)
     compiled = jax.jit(programs.decode, donate_argnums=(1, 2)).lower(
         params, state, cache).compile()
+    assert gm.latent_traced((16, 1)) == "fused"
     text = compiled.as_text()
     assert "ragged-dot" not in text
     assert "bf16[16,16512,512]" in text          # the latent, as stored
     assert "bf16[16,20,16512,192]" not in text   # no key rebuilt per head
-    # ``write_row`` writes the latent in place; the rotary keys' leaf, 64
-    # wide (under the 128 lanes), arrives with the positions minor and is
-    # re-laid on the way in and out, twice a layer: a KNOWN DEBT, 1.78 ms of
-    # a 13.05 ms step (PERF.md section 7: the leaf's layout pinned across
-    # the launch's programs cures it, a merged or 2-D scatter does not)
+    assert "f32[16,20,16512]" not in text        # no score array in HBM
+    assert "f32[16,32,16512]" not in text
+    # ``write_row`` writes both leaves in place (until PR 49 the rotary
+    # keys' leaf, 64 wide, was re-laid on the way in and out of a scatter,
+    # twice a layer: 12 copies, 1.78 ms of a 13.05 ms step)
     assert _cache_copies(compiled, programs, cache) == {
-        "bf16[16,16512,512]": 0, "bf16[16,16512,64]": 12}
+        "bf16[16,16512,512]": 0, "bf16[16,16512,64]": 0}
+    # the kernel's operands: the latent from the instruction that wrote its
+    # row (a scatter fusion on the parameter's own buffer), the rotary keys
+    # a bitcast of the leaf after its sixteen updates; never a copy
+    launches = _kernel_operands(text, "fused_latent_decode")
+    assert [made[-2:] for made in launches] == [["fusion", "bitcast"]] * 6
+    assert re.search(r"bf16\[16,64,16512\]\{2,1,0[^}]*\} bitcast\(", text)
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes >= 16 * 16512 * 6912
+    # (8.98 GiB: without the scores and the re-laid leaves the step's
+    # temporaries are 0.1 GB less than the plain form's)
+    assert 8.9 * 2**30 < _fits_hbm(compiled) < 9 * 2**30
+    _parts_of_the_compiled(programs, text, "fused_latent_decode", "attn.core",
+                           6)
+
+
+def test_glm_decode_step_in_the_plain_form_keeps_its_known_copies(v5e):
+    """The same step where ``supports`` says no (the backend is the CPU's
+    here and nothing steers it): ``decode_latent``'s two products around
+    float32 scores in HBM and ``write_row`` as a scatter, as until PR 49: the
+    rotary keys' leaf arrives with the positions minor and the scatter re-lays
+    it on the way in and out, twice a layer.  What the kernel's path has to
+    stay clear of, pinned so that a change to either form shows."""
+    from can_tpu.models import glm_moe_lite as gm
+
+    programs, params, cache, _, shape = _lm_programs_and_shapes(v5e, 16, 2, GLM)
+    state = _glm_step_state(programs, shape)
+    compiled = jax.jit(programs.decode, donate_argnums=(1, 2)).lower(
+        params, state, cache).compile()
+    assert gm.latent_traced((16, 1)) == "plain"
+    text = compiled.as_text()
+    assert "fused_latent_decode" not in text and "f32[16,20,16512]" in text
+    assert _cache_copies(compiled, programs, cache) == {
+        "bf16[16,16512,512]": 0, "bf16[16,16512,64]": 12}
     assert _fits_hbm(compiled) > 9 * 2**30
 
 
@@ -418,14 +468,7 @@ def test_glm_decode_step_compiles_with_the_skipping_experts(v5e, monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     programs, params, cache, _, shape = _lm_programs_and_shapes(v5e, 16, 2, GLM)
     assert programs.decode_experts(16) == "skipping"
-    state = jax.tree.map(
-        lambda a: shape(a.shape, a.dtype),
-        jax.eval_shape(lambda: programs.new_state(
-            [{"first": jnp.zeros((16,), jnp.int32),
-              "logits": jnp.zeros((16, 8), jnp.float32),
-              "choices": jnp.zeros((5, 16, 4), jnp.int32),
-              "counts": jnp.zeros((5, 64), jnp.int32)}],
-            jnp.ones((16,), jnp.int32), jnp.ones((16,), bool))[0]))
+    state = _glm_step_state(programs, shape)
     assert state["experts_read"].shape == ()
     compiled = jax.jit(programs.decode, donate_argnums=(1, 2)).lower(
         params, state, cache).compile()
@@ -445,7 +488,7 @@ def test_glm_decode_step_compiles_with_the_skipping_experts(v5e, monkeypatch):
     for line in entry.splitlines():
         if re.search(r"= bf16\[64,(2048,1536|1536,2048)\]", line):
             assert " parameter(" in line, line    # nothing else has the shape
-    assert _fits_hbm(compiled) > 9 * 2**30
+    assert _fits_hbm(compiled) > 8.9 * 2**30
     _parts_of_the_compiled(programs, text, "skipping_experts", "moe.experts", 5)
 
 

@@ -18,7 +18,8 @@ from can_tpu.obs import Telemetry, spans
 from can_tpu.serve import GenerateService, build_model_service, lm_probe_steps
 from can_tpu.serve import programs as serve_programs
 
-from lm_tiny import interpret_skipping_experts, tiny_glm_config
+from lm_tiny import (interpret_fused_latent, interpret_skipping_experts,
+                     tiny_glm_config)
 
 NEW = 6
 
@@ -172,6 +173,46 @@ def test_the_span_says_fused_where_the_program_traced_the_kernel(service,
     pre = _launch_spans(tracer, svc.submit(_prompt(10, 8)))[1]["lm.prefill"]
     assert pre["attention"] == "fused"
     assert svc.stats()["lm"]["prefill_attention"]["fused"] == 1
+
+
+@pytest.fixture
+def kernel_service(monkeypatch):
+    """A service of its own with a latent of whole lanes (rank 128, rotary
+    keys 16 wide) over a context of 134 positions, the decode step's kernel
+    interpreted in blocks of 128: what a TPU backend turns on."""
+    interpret_fused_latent(monkeypatch)
+    config = glm_config(kv_lora_rank=128, qk_rope_head_dim=16,
+                        length_ladder=[128], max_batch=2)
+    tracer = spans.SpanTracer()
+    tel = Telemetry()
+    tel.spans = tracer
+    cfg = gm.Glm4MoeLiteConfig.from_dict(config)
+    params = gm.init_params(jax.random.key(3), cfg, jnp.float32)
+    svc = build_model_service(config, params=params, telemetry=tel)
+    svc.warmup()
+    svc.start()
+    yield svc, None, tracer
+    svc.close()
+
+
+@pytest.mark.parametrize("which,slots,form", [("service", 4, "plain"),
+                                              ("kernel_service", 2, "fused")])
+def test_the_decode_says_which_form_read_the_latent_cache(request, which,
+                                                          slots, form):
+    """``lm.decode`` carries ``latent`` = what the step's trace noted
+    (``glm_moe_lite.latent_traced``): ``plain`` on the CPU and at a rank of
+    16, ``fused`` where ``pallas_latent.supports`` says yes; the program's
+    map says how many whole copies of a cache leaf the step makes."""
+    svc, _, tracer = request.getfixturevalue(which)
+    ring, inner = _launch_spans(tracer, svc.submit(_prompt(11, 9)))
+    assert inner["lm.decode"]["latent"] == form
+    assert "latent" not in inner["lm.prefill"]
+    assert "retention" not in inner["lm.decode"]
+    assert svc.engine.latent_forms == {(slots, 1): form}
+    scopes = {s["program"]: s for s in ring if s["name"] == "program.scopes"}
+    # (recorded at the program's first launch under the tracer: the warm-up's)
+    assert scopes["jit_decode"]["cache_copies"] == 0
+    assert "attn.core" in scopes["jit_decode"]["parts"].values()
 
 
 def test_cli_serves_the_model_from_its_configuration_file(tmp_path, capsys):
