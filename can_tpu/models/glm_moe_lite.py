@@ -231,10 +231,24 @@ def cache_layout(cfg: Glm4MoeLiteConfig) -> tuple:
 
 
 # -- latent attention ---------------------------------------------------
+# The layer below is every latent-attention model's (``longcat_flash.py``
+# runs two of it a block): ``cfg`` is any configuration with the widths it
+# reads and, where the model scales its normed latents (LongCat-Flash's
+# ``mla_scale_q_lora`` / ``mla_scale_kv_lora``), ``q_lora_scale`` /
+# ``kv_lora_scale``; ``leaves`` names the sublayer's two leaves in its
+# layer's cache entry (``cache_layout.latent_leaves``).
+def _gain(g, cfg, name: str):
+    """A latent's norm weight, times the configuration's factor ``name``
+    where it has one (in float32: one rounding, the norm's own)."""
+    scale = getattr(cfg, name, None)
+    return g if scale is None else g.astype(jnp.float32) * scale
+
+
 def _queries(p, xn, positions, cfg: Glm4MoeLiteConfig):
     """``xn`` (B, L, d) -> q_nope (B, L, H, nope), q_rope (B, L, H, rope)."""
     b, l, _ = xn.shape
-    cq = rms_norm(jnp.dot(xn, p["wq_a"]), p["q_norm"], cfg.rms_norm_eps)
+    cq = rms_norm(jnp.dot(xn, p["wq_a"]), _gain(p["q_norm"], cfg, "q_lora_scale"),
+                  cfg.rms_norm_eps)
     q = jnp.dot(cq, p["wq_b"]).reshape(b, l, cfg.num_attention_heads,
                                        cfg.qk_head_dim)
     return (q[..., :cfg.qk_nope_head_dim],
@@ -246,7 +260,8 @@ def _latent(p, xn, positions, cfg: Glm4MoeLiteConfig):
     """``xn`` (B, L, d) -> c_kv (B, L, rank) normalised, k_rope (B, L, rope)
     rotated: what the cache keeps of these positions."""
     kv = jnp.dot(xn, p["wkv_a"])
-    return (rms_norm(kv[..., :cfg.kv_lora_rank], p["kv_norm"],
+    return (rms_norm(kv[..., :cfg.kv_lora_rank],
+                     _gain(p["kv_norm"], cfg, "kv_lora_scale"),
                      cfg.rms_norm_eps),
             attn_ops.rope(kv[..., cfg.kv_lora_rank:], positions,
                           cfg.rope_theta))
@@ -317,22 +332,25 @@ def attention_expanded(p, xn, positions, lengths, cfg: Glm4MoeLiteConfig):
         return jnp.dot(o.reshape(b, l, -1), p["wo"]), ckv, krope
 
 
-def attention_absorbed(p, xn, positions, entry, cfg: Glm4MoeLiteConfig):
+def attention_absorbed(p, xn, positions, entry, cfg: Glm4MoeLiteConfig,
+                       leaves=layout.latent_leaves()):
     """One token a sequence, in the latent space: ``xn`` (B, 1, d) at
-    ``positions`` (B,), its latent written into ``entry`` before it attends
-    -> (the layer's output (B, 1, d), the entry).  The cache is read in one
+    ``positions`` (B,), its latent written into ``entry``'s two ``leaves``
+    before it attends -> (the layer's output (B, 1, d), those leaves
+    written).  The cache is read in one
     of two forms of one algorithm: the fused kernel over each sequence's own
     context (``ops/pallas_latent.py``) where its ``supports`` says it can run
     (a TPU, a rank of whole lanes, a cache of at least one block), the plain
     ``decode_latent`` over every allocated position everywhere else.  Nothing
     else chooses."""
     b = xn.shape[0]
+    ckv_leaf, krope_leaf = leaves
     with jax.named_scope("attn.proj"):
         q_nope, q_rope = _queries(p, xn, positions[:, None], cfg)
         ckv, krope = _latent(p, xn, positions[:, None], cfg)
     with jax.named_scope("attn.cache"):
-        ckv_c = attn_ops.write_row(entry["ckv"], ckv[:, 0], positions)
-        krope_c = attn_ops.write_row(entry["krope"], krope[:, 0], positions)
+        ckv_c = attn_ops.write_row(entry[ckv_leaf], ckv[:, 0], positions)
+        krope_c = attn_ops.write_row(entry[krope_leaf], krope[:, 0], positions)
     with jax.named_scope("attn.proj"):
         w_uk, w_uv = _up_projections(p, cfg)
         q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_uk)
@@ -351,7 +369,7 @@ def attention_absorbed(p, xn, positions, entry, cfg: Glm4MoeLiteConfig):
     with jax.named_scope("attn.out"):
         o = jnp.einsum("bhr,rhv->bhv", o_lat, w_uv)
         return (jnp.dot(o.reshape(b, 1, -1), p["wo"]),
-                {"ckv": ckv_c, "krope": krope_c})
+                {ckv_leaf: ckv_c, krope_leaf: krope_c})
 
 
 # -- prefill ------------------------------------------------------------

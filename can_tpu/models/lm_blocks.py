@@ -9,7 +9,7 @@ writes and reads its cache** (``kv_entry``, ``kv_decode``: from the
 ``mtp_logits``: positions and mask, the embedding, the loop over the layers,
 the cache, the head, the routing report).  A model's module
 (``exaone_moe.py``, ``glm_moe_lite.py``, ``lfm2_moe.py``,
-``mimo_v2_flash.py``, ``brumby.py``) brings its config class, its own mixers as one function
+``mimo_v2_flash.py``, ``brumby.py``, ``longcat_flash.py``) brings its config class, its own mixers as one function
 a layer for a prompt and one for a step, and three thin entry points over
 the skeleton (the grouped-query ones share ``qkv_heads``, the three
 projections by head); the config offers ``rms_norm_eps`` and, where the
@@ -41,7 +41,7 @@ from can_tpu.ops import moe as moe_ops
 from can_tpu.ops.moe import ExpertShare
 
 
-# The parts of a language model, ONE vocabulary for the six models: every
+# The parts of a language model, ONE vocabulary for the seven models: every
 # ``jax.named_scope`` that the serving programs pass through (the models,
 # ``ops/moe.py``, ``serve/programs.py``) is one of these names, whole (the MTP
 # modules' ``mtp``, outside those programs, wraps them).  A scope is metadata: it names no op and adds
@@ -87,10 +87,12 @@ RENAMED_BY_COMPILER = {
 }
 
 
-class VocabSlice(ExpertShare):
+class VocabSlice(NamedTuple):
     """Rows ``first .. first + held - 1`` of the ``total`` vocabulary."""
 
-    __slots__ = ()
+    first: int
+    held: int
+    total: int
 
 
 # -- parameters ---------------------------------------------------------
@@ -246,14 +248,25 @@ def experts_form(cfg, tokens: int, dtype) -> str:
 def expert_layer(p, x, cfg):
     """``x`` (T, d) -> (this chip's part of the routed sum + the shared
     expert where the layer has one (T, d), ``Routed``: the experts each
-    token chose (T, k))."""
+    token chose (T, k)).  Where the router has identity experts behind the
+    routed ones (``cfg.share.zero``) their term, ``(sum of a token's weights
+    on them) x``, is added whole: every chip computes it alike.  The
+    router's scores are ``cfg.scoring_func`` where the configuration names
+    one, sigmoid where it does not."""
     with jax.named_scope("moe.router"):
         idx, w = moe_ops.route(x, p["router"], p["bias"],
                                top_k=cfg.num_experts_per_tok,
                                scale=cfg.routed_scaling_factor,
-                               normalize=cfg.norm_topk_prob)
+                               normalize=cfg.norm_topk_prob,
+                               scoring=getattr(cfg, "scoring_func", "sigmoid"))
     routed, read, passes = moe_ops.share_apply(x, idx, w, p["experts"],
                                                cfg.share)
+    if cfg.share.zero:
+        with jax.named_scope("moe.router"):
+            w0 = moe_ops.zero_weight(idx, w, cfg.share)
+        with jax.named_scope("moe.experts"):
+            routed = (routed.astype(jnp.float32)
+                      + w0[:, None] * x.astype(jnp.float32)).astype(x.dtype)
     if "shared" not in p:
         return routed, Routed(idx, read, passes)
     with jax.named_scope("moe.shared"):
@@ -296,6 +309,10 @@ def routing_report(chosen, mask, pick, cfg) -> dict:
         at = [jnp.take_along_axis(c.idx, pick[:, None, None], axis=1)[:, 0]
               for c in chosen]
         report = {"counts": jnp.stack(counts), "choices": jnp.stack(at)}
+        if cfg.share.zero:
+            report["zero"] = jnp.stack([moe_ops.zero_counts(
+                jnp.where(mask[..., None], c.idx, -1), cfg.share)
+                for c in chosen])
         read = [c.read for c in chosen if c.read is not None]
         if read:
             report["experts_read"] = sum(read)

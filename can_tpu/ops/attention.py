@@ -345,6 +345,21 @@ def decode_latent(q_lat, q_rope, ckv, krope, valid, *, scale: float):
     return _softmax_av(s, valid[:, None, :], ckv, "bhs,bsr->bhr", q_lat.dtype)
 
 
+# A narrow leaf of at most this many positions a slot is written by a select
+# over the whole leaf, a longer one by an update a slot (``write_row``): an
+# update is about 1.04 us whatever the leaf (PR 49's reading), the select
+# 2 x positions x 64 x 2 B a slot at an elementwise pass's 590-750 GB/s, so
+# they cross near 2,400-3,000 positions.  TIMED at the two cells' shapes by
+# ``tools/latent_decode_forms.py --shape glm|longcat`` (one layer's two writes
+# + attention, ms, updates | select; my chip run, PR 50): 256 slots x 1,280
+# positions 1.503 | 1.210; 16 slots x 16,512 positions 0.418 | 0.565.  In
+# LongCat's whole step the 2,048 updates hid behind the weights' prefetches
+# (``req_per_s`` the same); what they cost was a compiled step of 3,994
+# instructions against 1,065 and a device trace that held a third of three
+# launches (PERF.md section 6, PR 50)
+SELECT_MAX_POSITIONS = 2048
+
+
 def write_row(cache, new, pos):
     """``cache`` (B, S, D) with ``new`` (B, D) written at position ``pos``
     (B,), one row per sequence, where the leaf lies.
@@ -357,12 +372,24 @@ def write_row(cache, new, pos):
     scatter wants it row-major and re-laid the whole leaf on the way in and
     back on the way out, twice a layer and step (PERF.md section 6, PR 49).
     A ``dynamic_update_slice`` takes a leaf in whatever layout it has: one a
-    sequence there, no copy.  Off a TPU no layout is at stake and the
-    scatter stands."""
-    b, _, d = cache.shape
+    sequence there, no copy.  Each is a small program of its own, about a
+    microsecond (96 a GLM step read 0.10 ms, PR 49), so their cost is the
+    SLOTS' count; a select over the whole leaf (one elementwise pass in the
+    leaf's own layout, in place where it is donated) costs the leaf's bytes
+    twice.  Both grow with the slots, so what chooses is the positions a
+    slot holds: at or under ``SELECT_MAX_POSITIONS`` the select (LongCat's
+    256 slots of 1,280: 8 leaves x 256 updates would be 2,048 programs a
+    step), over it an update a slot (GLM's 16 of 16,512: 34 MB a leaf to
+    rewrite for 16 rows).  Off a TPU no layout is at stake and the scatter
+    stands."""
+    b, s, d = cache.shape
     new = new.astype(cache.dtype)
     if d % _LANES == 0 or jax.default_backend() != "tpu":
         return cache.at[jnp.arange(b), pos].set(new)
+    if s <= SELECT_MAX_POSITIONS:
+        # (a position the leaf does not hold writes nothing, as the scatter)
+        hit = jnp.arange(s)[None, :, None] == pos[:, None, None]
+        return jnp.where(hit, new[:, None, :], cache)
     # (a position the leaf does not hold: the scatter drops it, this clamps
     # it to the leaf's last row; a step never asks for one)
     for i in range(b):

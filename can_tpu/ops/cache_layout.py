@@ -43,8 +43,10 @@ key/value layer's leaves are filled and read in one place for every model,
   in float32 beside bfloat16 neighbours); the others take the cache's.
 
 A layer whose block keeps two kinds (attention and a recurrence side by
-side) is described by a tuple of ``LayerSpec``, one a kind; its leaves share
-the layer's one entry, so their names differ.  ``parts`` reads either form.
+side), or one kind twice (two latent attention sublayers: ``latent_layer``'s
+``index``), is described by a tuple of ``LayerSpec``, one a part; its leaves
+share the layer's one entry, so their names differ.  ``parts`` reads either
+form.
 
 A decode step writes ONE position of a leaf that has positions, in place:
 ``write_slot`` merges the axes in front of the positions (slots x heads)
@@ -139,10 +141,21 @@ def kv_layer(kind: str, *, kv_heads: int, head_dim: int,
     return LayerSpec(kind, leaves, int(window) if kind == RING else None)
 
 
-def latent_layer(*, rank: int, rope_dim: int) -> LayerSpec:
-    """``rank + rope_dim`` numbers a position, whatever the number of heads."""
-    return LayerSpec(LATENT, (("ckv", (), int(rank)),
-                              ("krope", (), int(rope_dim))))
+def latent_layer(*, rank: int, rope_dim: int,
+                 index: Optional[int] = None) -> LayerSpec:
+    """``rank + rope_dim`` numbers a position, whatever the number of heads.
+    ``index``: which of a layer's latent sublayers this is, where a block
+    has more than one: its leaves are ``ckv<index>`` / ``krope<index>``
+    (``latent_leaves``), so that two specs share one entry."""
+    ckv, krope = latent_leaves(index)
+    return LayerSpec(LATENT, ((ckv, (), int(rank)),
+                              (krope, (), int(rope_dim))))
+
+
+def latent_leaves(index: Optional[int] = None) -> Tuple[str, str]:
+    """The names of a latent sublayer's two leaves in its layer's entry."""
+    tail = "" if index is None else str(int(index))
+    return "ckv" + tail, "krope" + tail
 
 
 def state_layer(**leaves) -> LayerSpec:

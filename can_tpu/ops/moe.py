@@ -4,7 +4,13 @@
 keeps its published width) and picks its ``top_k``.  ``share_apply``
 computes what the experts held HERE add for the tokens routed to them.
 What the absent experts would have added is left out: that partial sum is
-the layer's routed output on this chip.  Three forms of the grouped product,
+the layer's routed output on this chip.  A router may be wider than its
+routed experts: outputs ``total .. total + zero - 1`` (``ExpertShare.zero``;
+LongCat-Flash's zero-compute experts) are IDENTITY experts, which return
+their input: a token that chose some gets ``(sum of their weights) x``
+(``zero_weight``, added by the layer: ``lm_blocks.expert_layer``), reads no
+weight and is held nowhere, so the three forms below see such a choice as
+one held elsewhere, and ``zero_counts`` counts it.  Three forms of the grouped product,
 chosen by ``share_form`` from the (static) shapes and the backend:
 
 * many tokens (a prefill), ``"sorted"``: the assignments that landed on a
@@ -37,7 +43,7 @@ chosen by ``share_form`` from the (static) shapes and the backend:
   experts, their ids by scalar prefetch, and reads no other expert's
   weights.  XLA cannot do that (static shapes: its batched product is over
   all ``held``).  Taken where the EXPECTED share of held experts without a
-  token, ``(1 - top_k / total) ** T``, is at least ``SKIP_MIN_IDLE`` and the
+  token, ``(1 - top_k / width) ** T`` (``width``: the router's outputs), is at least ``SKIP_MIN_IDLE`` and the
   kernel's ``supports`` takes the backend and the widths: 16 tokens' top-4
   of 64 leave 36% idle, 64 tokens' top-8 of 128 leave 1.6% and stay
   batched.  This form counts the experts it read.
@@ -131,11 +137,19 @@ SORTED_TILE = 512    # the grouped kernel's tile of rows: the buffer is whole ti
 
 
 class ExpertShare(NamedTuple):
-    """Experts ``first .. first + held - 1`` of ``total`` live on this chip."""
+    """Experts ``first .. first + held - 1`` of ``total`` live on this chip;
+    the router has ``zero`` more outputs behind them, ids ``total .. total +
+    zero - 1``: identity experts, which every chip computes and none holds."""
 
     first: int
     held: int
     total: int
+    zero: int = 0
+
+    @property
+    def width(self) -> int:
+        """The router's outputs: what a token's ``top_k`` are chosen among."""
+        return self.total + self.zero
 
     @classmethod
     def of_rank(cls, rank: int, ranks: int, total: int) -> "ExpertShare":
@@ -146,16 +160,22 @@ class ExpertShare(NamedTuple):
 
 
 def route(x, router_w, bias, *, top_k: int, scale: float,
-          normalize: bool = True):
+          normalize: bool = True, scoring: str = "sigmoid"):
     """-> (chosen experts (T, k) int32, their weights (T, k) float32).
 
-    Sigmoid scores in float32 over all experts; the choice is by
+    Scores in float32 over all the router's outputs, each output's own
+    ``sigmoid`` or a ``softmax`` over them (``scoring``); the choice is by
     ``score + bias`` (the correction bias moves the choice, never the
     weight), the weights are the chosen scores, normalised to sum to one
-    and scaled."""
+    where ``normalize``, and scaled."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"unknown router scoring {scoring!r}")
     _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
     w = jnp.take_along_axis(scores, idx, axis=-1)
     if normalize:
@@ -176,6 +196,18 @@ def held_counts(idx, share: ExpertShare):
     return hot.reshape(-1, share.held).sum(0)
 
 
+def zero_weight(idx, w, share: ExpertShare):
+    """(T,) float32: the sum of each token's weights on the identity experts
+    it chose (ids ``>= share.total``); the layer adds that times the token."""
+    return jnp.sum(jnp.where(idx >= share.total, w, 0.0), axis=-1)
+
+
+def zero_counts(idx, share: ExpertShare):
+    """() int32: the choices that are identity experts (as ``held_counts``,
+    a choice masked to -1 is nobody's)."""
+    return jnp.sum((idx >= share.total).astype(jnp.int32))
+
+
 def share_form(tokens: int, top_k: int, share: ExpertShare, d: int, f: int,
                dtype) -> str:
     """``"sorted"`` / ``"batched"`` / ``"skipping"``: the form ``share_apply``
@@ -183,7 +215,7 @@ def share_form(tokens: int, top_k: int, share: ExpertShare, d: int, f: int,
     ``f``.  Shapes and the backend choose, nothing else."""
     if tokens > DENSE_MAX_TOKENS:
         return "sorted"
-    if (1.0 - top_k / share.total) ** tokens < SKIP_MIN_IDLE:
+    if (1.0 - top_k / share.width) ** tokens < SKIP_MIN_IDLE:
         return "batched"
     # imported here: ``jax.experimental.pallas`` takes over a second to
     # load, paid only by a process one of whose shapes could skip
@@ -195,9 +227,10 @@ def share_form(tokens: int, top_k: int, share: ExpertShare, d: int, f: int,
 
 def sorted_rows(tokens: int, top_k: int, share: ExpertShare) -> int:
     """Rows of the sorted form's buffer for ``tokens`` tokens: what even
-    routing lands on the held experts times ``SORTED_MARGIN``, in whole
-    tiles, and never more than every assignment that CAN land here."""
-    expected = tokens * top_k * share.held / share.total
+    routing (over all the router's outputs) lands on the held experts times
+    ``SORTED_MARGIN``, in whole tiles, and never more than every assignment
+    that CAN land here."""
+    expected = tokens * top_k * share.held / share.width
     tiles = math.ceil(expected * SORTED_MARGIN / SORTED_TILE)
     return min(tiles * SORTED_TILE, tokens * min(top_k, share.held))
 

@@ -76,7 +76,14 @@ from can_tpu.ops.attention import NEG
 # values so 0.483, both 0.718: each pays a transpose on the way and the
 # softmax then runs on scores 32 lanes wide.  In the cell the six launches
 # read 2.086 ms a step (0.348 a layer) where the two fusions they replace read
-# 5.135
+# 5.135.  At the second shape the kernel met (PR 50, LongCat-Flash's cell: 256
+# slots over 1,280 positions, 64 heads, contexts of 200 to 1,200; the same
+# tool, ``--shape longcat``) a layer reads 1.209 ms in blocks of 1,024, 1.131
+# in 512 and 1.070 in 384: with 64 rows of heads a block costs 3.3 us where
+# its bytes stream in 1.4 (43% of the bandwidth in the cell), and a context
+# under one block computes the whole block.  Not taken there: one constant
+# serves both cells until a block chosen from the cache's length is timed in
+# both (PERF.md section 7)
 BLOCK = 1024
 
 _LANES = 128

@@ -430,9 +430,12 @@ class LMEngine:
         # counters (the batcher thread writes, stats() reads a copy)
         self.counters = {"launches": 0, "generated_tokens": 0,
                          "prompt_tokens": 0, "decode_steps": 0,
-                         # assignments_held: tokens over all held experts
+                         # assignments_held: tokens over all held experts;
+                         # _zero: choices that were identity experts (a
+                         # router with none: 0); _all: every choice a token
                          "expert_tokens_max": 0,
                          "assignments_held": 0, "assignments_all": 0,
+                         "assignments_zero": 0,
                          # held experts whose weights the decode steps read
                          # (layers x steps), of those they could have
                          "decode_experts_read": 0, "decode_experts_held": 0,
@@ -578,6 +581,11 @@ class LMEngine:
             if expert_layers:
                 sp.attrs["experts_read"] = experts_read
                 sp.attrs["experts_held"] = experts_held
+            # the choices that were identity experts, where the router has any
+            zero = 0
+            if "zero" in pre:
+                # can-tpu-lint: disable=HOSTSYNC(one more counter of the launch, fetched with the others)
+                zero = int(np.asarray(pre["zero"] + state["zero"]).sum())
             # the prefill's passes are known only now: ``lm.prefill`` closed
             # when its slices were queued, before the device ran them
             passes = calls = 0
@@ -595,7 +603,7 @@ class LMEngine:
         k = pre["choices"].shape[-1]   # (expert layers, slots, k)
         self._count(cache, valid, valid_tokens, steps, pre_counts, dec_counts,
                     attention, expert_layers * k, experts_read, experts_held,
-                    passes, calls)
+                    passes, calls, zero)
         return ids, fetched
 
     def _note_forms(self, traced: bool, program: tuple) -> None:
@@ -659,17 +667,19 @@ class LMEngine:
     def _count(self, cache, valid, valid_tokens, steps, pre_counts,
                dec_counts, attention, choices_per_token: int,
                experts_read: int, experts_held: int, passes: int,
-               calls: int) -> None:
+               calls: int, zero: int) -> None:
         """``choices_per_token``: routing choices a token makes over all the
         expert layers (0 for a model without one: every expert counter then
-        reads zero)."""
+        reads zero); ``zero``: those of the launch's valid tokens that were
+        identity experts."""
         p = self.programs
         held = int(pre_counts.sum() + dec_counts.sum())
         every = (valid_tokens + valid * steps) * choices_per_token
         launch = {"valid": valid, "steps": steps,
                   "prefill_expert_tokens": pre_counts.tolist(),
                   "decode_expert_tokens": dec_counts.tolist(),
-                  "assignments_held": held, "assignments_all": every}
+                  "assignments_held": held, "assignments_all": every,
+                  "assignments_zero": zero}
         c = self.counters
         c["launches"] += 1
         c["generated_tokens"] += valid * steps
@@ -679,6 +689,7 @@ class LMEngine:
                                      int((pre_counts + dec_counts).max(initial=0)))
         c["assignments_held"] += held
         c["assignments_all"] += every
+        c["assignments_zero"] += zero
         c["decode_experts_read"] += experts_read
         c["decode_experts_held"] += experts_held
         c["dispatch_passes"] += passes

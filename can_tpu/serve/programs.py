@@ -95,7 +95,10 @@ class LMPrograms:
       also has ``experts_read`` () int32, the held experts whose weights the
       step read, summed over the expert layers; in the ``"sorted"`` form
       ``dispatch_passes`` () int32, the passes over the sorted buffer that
-      the expert layers took, summed over them.
+      the expert layers took, summed over them.  A model whose router has
+      identity experts (``ops.moe.ExpertShare.zero``) also returns ``zero``
+      (expert layers,) int32: the counted tokens' choices that are identity
+      experts, held nowhere and reading nothing.
 
     State between the programs of a launch, all on the device: the cache
     (``serve/cache.py``) and ``state``: ``tokens`` (slots,) the token each
@@ -106,7 +109,8 @@ class LMPrograms:
     (expert layers, held) decode assignments that landed on each held
     expert; where decode's expert layers skip (``experts_form``), also
     ``experts_read`` () the experts they read so far, summed over layers and
-    steps.  Greedy: the next token is the argmax over the vocabulary slice.
+    steps; where the router has identity experts, ``zero`` (expert layers,)
+    decode choices that were identity experts.  Greedy: the next token is the argmax over the vocabulary slice.
     """
 
     def __init__(self, model, cfg, *, max_new_tokens: int,
@@ -163,13 +167,15 @@ class LMPrograms:
         """The slices' outputs put together and the decode state after
         prefill: -> (state, {"logits" (slots, V), "choices", "counts"} of
         the whole launch's prefill; where the slices' expert layers count
-        their passes, also "dispatch_passes" (), their sum)."""
+        their passes, also "dispatch_passes" (), their sum; where the
+        router has identity experts, "zero" (expert layers,), theirs)."""
         cat = lambda name, axis=0: jnp.concatenate([o[name] for o in outs], axis)
         first = cat("first")
         pre = {"logits": cat("logits"), "choices": cat("choices", 1),
                "counts": sum(o["counts"] for o in outs)}
-        if "dispatch_passes" in outs[0]:
-            pre["dispatch_passes"] = sum(o["dispatch_passes"] for o in outs)
+        for name in ("dispatch_passes", "zero"):
+            if name in outs[0]:
+                pre[name] = sum(o[name] for o in outs)
         ids = jnp.zeros((first.shape[0], self.max_new_tokens + 1), jnp.int32)
         state = {"tokens": first, "positions": lengths.astype(jnp.int32),
                  "active": active, "ids": ids.at[:, 0].set(first),
@@ -177,13 +183,16 @@ class LMPrograms:
                  "counts": jnp.zeros_like(pre["counts"])}
         if self.decode_experts(first.shape[0]) == "skipping":
             state["experts_read"] = jnp.zeros((), jnp.int32)
+        if "zero" in pre:
+            state["zero"] = jnp.zeros_like(pre["zero"])
         return state, pre
 
     def prefill_slice(self, params, batch, cache, start):
         """``batch``: {"tokens" (s, L), "lengths" (s,), "active" (s,)} for
         slots ``start .. start + s - 1`` -> ({"first", "logits", "choices",
-        "counts"; "dispatch_passes" where the expert layers count them}, the
-        cache with those slots' rows written)."""
+        "counts"; "dispatch_passes" where the expert layers count them,
+        "zero" where the router has identity experts}, the cache with those
+        slots' rows written)."""
         logits, part, routing = self._m.prefill(
             params, batch["tokens"], batch["lengths"], self.cfg,
             self.positions(batch["tokens"].shape[1]), active=batch["active"])
@@ -196,8 +205,9 @@ class LMPrograms:
             first = jnp.argmax(logits, -1).astype(jnp.int32)
         out = {"first": first, "logits": logits,
                "choices": routing["choices"], "counts": routing["counts"]}
-        if "dispatch_passes" in routing:
-            out["dispatch_passes"] = routing["dispatch_passes"]
+        for name in ("dispatch_passes", "zero"):
+            if name in routing:
+                out[name] = routing[name]
         return out, cache
 
     def decode(self, params, state, cache):
@@ -215,9 +225,9 @@ class LMPrograms:
                      "active": state["active"], "ids": ids,
                      "step": state["step"] + 1,
                      "counts": state["counts"] + routing["counts"]}
-            if "experts_read" in routing:
-                moved["experts_read"] = (state["experts_read"]
-                                         + routing["experts_read"])
+            for name in ("experts_read", "zero"):
+                if name in routing:
+                    moved[name] = state[name] + routing[name]
         return moved, cache, {"logits": logits, "choices": routing["choices"]}
 
 
@@ -285,6 +295,12 @@ def _brumby():
     return brumby, brumby.BrumbyConfig
 
 
+def _longcat_flash():
+    from can_tpu.models import longcat_flash
+
+    return longcat_flash, longcat_flash.LongcatFlashConfig
+
+
 def _lm_engine(params, programs, config: dict, telemetry):
     from can_tpu.serve.engine import LMEngine
 
@@ -311,6 +327,8 @@ MODEL_TYPES = {
                                   _generate_service),
     "brumby": ServingModel(_lm_programs(_brumby), _lm_engine,
                            _generate_service),
+    "longcat_flash": ServingModel(_lm_programs(_longcat_flash), _lm_engine,
+                                  _generate_service),
 }
 
 

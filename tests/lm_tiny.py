@@ -222,6 +222,43 @@ def tiny_brumby_model(seed=0, dtype=jnp.float32, **kw):
     return d, cfg, bm.init_params(jax.random.key(seed), cfg, dtype)
 
 
+def tiny_longcat_config(*, held=2, rank=0, **edits) -> dict:
+    """The tiny LongCat-Flash preset, as a configuration-file dict: every
+    mechanism of the published model at sizes a CPU runs in milliseconds: 2
+    layers of two latent-attention sublayers (query rank 24, latent 16 + 8
+    rotary, 4 heads of 8 + 8 / 16, both latents scaled), two dense SwiGLUs
+    and one expert layer on the shortcut: a softmax router of 8 experts + 4
+    identity experts, top-3, not normalised, times 6, a bias on the choice;
+    an untied head over 256 ids.  ``held`` of the 8 experts live on rank
+    ``rank``; ``edits`` replace keys (``assumed=...`` among them)."""
+    d = {
+        "model_type": "longcat_flash", "attention_bias": False,
+        "vocab_size": 256, "hidden_size": 64, "ffn_hidden_size": 160,
+        "expert_ffn_hidden_size": 32, "num_layers": 2,
+        "num_attention_heads": 4, "kv_lora_rank": 16, "q_lora_rank": 24,
+        "qk_rope_head_dim": 8, "v_head_dim": 16, "qk_nope_head_dim": 8,
+        "mla_scale_q_lora": True, "mla_scale_kv_lora": True,
+        "routed_scaling_factor": 6, "n_routed_experts": held,
+        "max_position_embeddings": 131072, "rms_norm_eps": 1e-5,
+        "rope_theta": 10000000, "attention_method": "MLA",
+        "zero_expert_num": 4, "zero_expert_type": "identity", "moe_topk": 3,
+        "published": {"n_routed_experts": 8, "vocab_size": 256},
+        "deployment": {"rank": rank},
+        "assumed": {},
+    }
+    d.update(edits)
+    return d
+
+
+def tiny_longcat_model(seed=0, dtype=jnp.float32, **kw):
+    """-> (config dict, LongcatFlashConfig, params)."""
+    from can_tpu.models import longcat_flash as lf
+
+    d = tiny_longcat_config(**kw)
+    cfg = lf.LongcatFlashConfig.from_dict(d)
+    return d, cfg, lf.init_params(jax.random.key(seed), cfg, dtype)
+
+
 def interpret_skipping_experts(monkeypatch) -> None:
     """The skipping experts kernel (``ops/pallas_experts.py``) interpreted
     wherever its shapes fit: what a TPU backend turns on, steered here as

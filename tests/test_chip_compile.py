@@ -268,6 +268,10 @@ def _lm_programs_and_shapes(v5e, slots, part,
         from can_tpu.models import brumby as em
 
         cfg = em.BrumbyConfig.from_dict(config)
+    elif config["model_type"] == "longcat_flash":
+        from can_tpu.models import longcat_flash as em
+
+        cfg = em.LongcatFlashConfig.from_dict(config)
     else:
         from can_tpu.models import glm_moe_lite as em
 
@@ -941,3 +945,99 @@ def test_brumby_prefill_slice_compiles_for_one_device(v5e):
     assert 12 * 2**30 < _fits_hbm(compiled) < 15.5 * 2**30
     assert {"ret.proj", "ret.core", "ret.state", "ret.out"} <= _brumby_parts(
         programs, text)
+
+
+# -- LongCat-Flash: two latent sublayers a layer, 256 slots ---------------------
+LONGCAT = "longcat-flash-omni-ep32-serve-bf16"
+# every positioned leaf of the cell's cache: a sublayer's latent and its
+# rotary keys (four of each a layer: two sublayers)
+LONGCAT_LEAVES = ("bf16[256,1280,512]", "bf16[256,1280,64]")
+
+
+def test_longcat_decode_step_compiles_for_one_device(v5e, monkeypatch):
+    """One greedy step of 256 slots over 1,280 positions as the chip traces
+    it (``supports`` asks the backend: steered here), at the published
+    widths: 10.35 GB of weights + 3.02 GB of latent cache (8 leaves a
+    layer's two sublayers, 16 of each kind).  **Latent attention's decode
+    kernel takes the second shape it has met** (64 heads, rank 512, rope 64,
+    blocks of 1,024 over 1,280 positions: eight launches, two a layer), each
+    sublayer reading ITS leaves where they lie; the experts are the batched
+    form (256 tokens' top-12 of 768 leave a held expert idle 1.8% of the
+    time); no leaf of the cache is copied whole; the peak is named and under
+    16 GiB; the identity experts' counter rides in the state."""
+    import re
+
+    from can_tpu.models import longcat_flash as lf
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    programs, params, cache, _, shape = _lm_programs_and_shapes(
+        v5e, 256, 32, LONGCAT)
+    assert programs.decode_experts(256) == "batched"
+    state = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(lambda: programs.new_state(
+            [{"first": jnp.zeros((256,), jnp.int32),
+              "logits": jnp.zeros((256, 8), jnp.float32),
+              "choices": jnp.zeros((4, 256, 12), jnp.int32),
+              "counts": jnp.zeros((4, 16), jnp.int32),
+              "zero": jnp.zeros((4,), jnp.int32)}],
+            jnp.ones((256,), jnp.int32), jnp.ones((256,), bool))[0]))
+    assert state["zero"].shape == (4,) and "experts_read" not in state
+    compiled = jax.jit(programs.decode, donate_argnums=(1, 2)).lower(
+        params, state, cache).compile()
+    assert lf.latent_traced((256, 1)) == "fused"
+    text = compiled.as_text()
+    assert "ragged-dot" not in text and "skipping_experts" not in text
+    assert "f32[256,64,1280]" not in text        # no score array in HBM
+    assert _cache_copies(compiled, programs, cache) == dict.fromkeys(
+        LONGCAT_LEAVES, 0)
+    # the kernel's operands: the latent from the scatter that wrote its row
+    # in place; the rotary keys from the ONE fusion that writes their row by
+    # a select over the leaf where it lies, positions minor
+    # (``attention.write_row`` at or under ``SELECT_MAX_POSITIONS``: an
+    # update a slot would be 256 x 8 = 2,048 small programs a step), the
+    # kernel's own turn of the leaf folded into it: never a copy
+    launches = _kernel_operands(text, "fused_latent_decode")
+    assert [made[-2:] for made in launches] == [["fusion", "fusion"]] * 8
+    assert text.count("attn.cache/dynamic_update_slice") == 0
+    assert re.search(r"bf16\[256,64,1280\]\{2,1,0[^}]*\} fusion\(", text)
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 256 * 1280 * 9216
+    # weights 10.35 GB + cache 3.02 GB + a step's temporaries
+    assert 13.3e9 < _fits_hbm(compiled) < 14.2e9
+    # (``_parts_of_the_compiled``'s checks but its last: of this step's
+    # instructions far fewer are the compiler's own copies)
+    from can_tpu.models.lm_blocks import PARTS
+    from can_tpu.obs.trace import program_scopes
+
+    got = program_scopes(text, programs.parts)
+    mine = {i: p for i, p in got["parts"].items()
+            if i.startswith("fused_latent_decode")}
+    assert len(mine) == 8 and set(mine.values()) == {"attn.core"}, mine
+    assert set(got["parts"].values()) <= set(PARTS) | {None}
+    assert got["unscoped"] <= 0.01 * got["instructions"], got["unscoped"]
+
+
+def test_longcat_prefill_slice_compiles_for_one_device(v5e, monkeypatch):
+    """32 prompts of 256 tokens into the 256-slot cache as the chip traces
+    it: the bucket is under the fused attention's block of 1,024, so both
+    sublayers take the scanned ``prefill_causal`` (one block: 64 heads x 256 x
+    256 scores a prompt); the experts are sorted, the buffer twice the even
+    share over the router's WIDTH (8,192 x 12 x 16 / 768 = 2,048: 4,096
+    rows, where 16 / 512 would have made it 6,144), one loop an expert
+    layer; no leaf of the cache is copied whole; the peak is under 16 GiB."""
+    from can_tpu.models import glm_moe_lite as gm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    programs, params, cache, batch, shape = _lm_programs_and_shapes(
+        v5e, 256, 32, LONGCAT)
+    compiled = jax.jit(programs.prefill_slice, donate_argnums=(2,)).lower(
+        params, batch, cache, shape((), jnp.int32)).compile()
+    assert gm.attention_traced((32, 256)) == "scanned"
+    text = compiled.as_text()
+    assert "ragged-dot" in text and "fused_causal_attention" not in text
+    assert "bf16[4096,6144]" in text and "bf16[6144,6144]" not in text
+    assert _dispatch_loops(programs, text) == 4
+    assert _cache_copies(compiled, programs, cache) == dict.fromkeys(
+        LONGCAT_LEAVES, 0)
+    assert 13.3e9 < _fits_hbm(compiled) < 16.5e9

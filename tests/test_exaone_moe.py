@@ -335,7 +335,7 @@ def test_published_configuration_counts():
     cfg = em.ExaoneMoeConfig.from_file(os.path.join(
         ROOT, "benchmark", "configs", "k-exaone-ep8-serve-bf16.json"))
     assert em.param_count(cfg) == 3_712_028_416
-    assert cfg.share == (0, 16, 128) and cfg.vocab == (0, 19200, 153600)
+    assert cfg.share == moe_ops.ExpertShare(0, 16, 128) and cfg.vocab == (0, 19200, 153600)
     assert [t == em.WINDOW for t in cfg.layer_types] == [True, True, True,
                                                          False, True]
     assert cfg.mlp_layer_types == ("dense",) + ("sparse",) * 4
@@ -412,7 +412,12 @@ def test_share_apply_runs_the_form_chosen(form, monkeypatch):
 # values' transpose; compiled for the described v5e the program is the
 # parent's, instruction for instruction (PERF.md section 6, PR 45).  PR 47
 # added Brumby's two (power retention: the backend changes neither) and moved
-# none of the eight.
+# none of the eight.  PR 50 (a router's scoring and identity experts in
+# ``ops/moe.py`` and ``lm_blocks.expert_layer``, latent attention's optional
+# factors and leaf names, ``VocabSlice`` a tuple of its own) added
+# LongCat-Flash's two and moved none of the ten: sigmoid scoring, a share
+# without identity experts, a configuration without factors and the leaves
+# ``ckv`` / ``krope`` trace to what stood.
 PROGRAM_TEXT = {
     ("k-exaone-ep8-serve-bf16", "decode"):
         "2c53b1638274f93f0d4919dc5558a2e6378313e37e545c1402c69c3c3a01403d",
@@ -434,6 +439,10 @@ PROGRAM_TEXT = {
         "72fe0af25f4e4e352c5a2bd6dffec3ae6035a4cd35f2d7770ca7ac4d50d0eedc",
     ("brumby-14b-pp5-serve-bf16", "prefill_slice"):
         "b8fb8818bfc0b42cf7584453a6983ddfb733910cdf519ac636388753f40f2399",
+    ("longcat-flash-omni-ep32-serve-bf16", "decode"): 
+        "c82827d0a738bb8d9d2928e321768308845f8389621b9236b9a5592866c04167",
+    ("longcat-flash-omni-ep32-serve-bf16", "prefill_slice"): 
+        "a04405232c03c35f5a0878383fe5d787778d694b693b510bb900d9bf141ecf19",
 }
 
 
@@ -459,6 +468,10 @@ def _program_text(name: str, program: str) -> str:
         from can_tpu.models import brumby as model
 
         cfg = model.BrumbyConfig.from_dict(config)
+    elif config["model_type"] == "longcat_flash":
+        from can_tpu.models import longcat_flash as model
+
+        cfg = model.LongcatFlashConfig.from_dict(config)
     else:
         from can_tpu.models import glm_moe_lite as model
 
@@ -495,7 +508,8 @@ def _program_text(name: str, program: str) -> str:
     for backend in ("cpu", "tpu")
     # on a TPU GLM's two programs take the kernels, which only lower there
     # (tests/test_chip_compile.py compiles both for a described v5e)
-    if not (backend == "tpu" and name.startswith("glm"))])
+    # and LongCat-Flash's decode step takes GLM's decode kernel
+    if not (backend == "tpu" and name.startswith(("glm", "longcat")))])
 def test_a_serving_program_lowers_to_the_text_it_had(name, program, backend,
                                                      monkeypatch):
     """K-EXAONE's, Falcon-H1's and LFM2's programs are the pinned ones,

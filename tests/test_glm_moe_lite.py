@@ -16,6 +16,7 @@ from benchmark.reference import glm_moe_lite_ref as ref
 from can_tpu.models import glm_moe_lite as gm
 from can_tpu.models import lm_blocks as lb
 from can_tpu.ops import attention as attn_ops
+from can_tpu.ops import moe as moe_ops
 
 from lm_tiny import (interpret_skipping_experts, tiny_glm_config,
                      tiny_glm_model)
@@ -174,7 +175,7 @@ class TestExpertShare:
             p["experts"] = {k: v[lo:lo + held] for k, v in moe["experts"].items()}
             cfg = gm.Glm4MoeLiteConfig.from_dict(
                 tiny_glm_config(held=held, rank=rank))
-            assert cfg.share == (lo, held, 16)
+            assert cfg.share == moe_ops.ExpertShare(lo, held, 16)
             total = total + (lb.expert_layer(p, x, cfg)[0] - shared)
         spec = ref.spec_from_config(d)
         routed, _ = ref._experts(moe, x, spec, "f32", None)
@@ -185,7 +186,7 @@ class TestExpertShare:
 
     def test_the_cell_holds_every_expert(self):
         cfg = gm.Glm4MoeLiteConfig.from_dict(tiny_glm_config())
-        assert cfg.share == (0, 16, 16) and cfg.num_experts_per_tok == 4
+        assert cfg.share == moe_ops.ExpertShare(0, 16, 16) and cfg.num_experts_per_tok == 4
 
 
 class TestPrefillCausal:
@@ -274,7 +275,7 @@ def test_published_configuration_counts():
     cfg = gm.Glm4MoeLiteConfig.from_file(os.path.join(
         ROOT, "benchmark", "configs", "glm-4.7-flash-pp8-serve-bf16.json"))
     assert gm.param_count(cfg) == 3_895_625_536
-    assert cfg.share == (0, 64, 64) and cfg.vocab == (0, 154880, 154880)
+    assert cfg.share == moe_ops.ExpertShare(0, 64, 64) and cfg.vocab == (0, 154880, 154880)
     assert cfg.mlp_layer_types == ("dense",) + ("sparse",) * 5
     assert cfg.mtp_layers == 0 and cfg.scale == 1 / 16
     shapes = gm.param_shapes(cfg)["layers"][1]

@@ -253,38 +253,48 @@ def _scatter_write(cache, new, pos):
 
 
 class TestWriteRow:
-    """A leaf narrower than the 128 lanes is written one
-    ``dynamic_update_slice`` a sequence on a TPU (no scatter: it would re-lay
-    the leaf); a leaf of whole lanes, and every leaf off a TPU, by the
-    scatter."""
+    """A leaf narrower than the 128 lanes is written on a TPU without a
+    scatter (it would re-lay the leaf): by a select over the whole leaf where
+    a slot holds at most ``SELECT_MAX_POSITIONS`` positions, by one
+    ``dynamic_update_slice`` a sequence over that; a leaf of whole lanes, and
+    every leaf off a TPU, by the scatter."""
 
     @pytest.mark.parametrize("width", [64, 8, 512], ids=str)
+    @pytest.mark.parametrize("positions", [40, 2 * 2048], ids=str)
     @pytest.mark.parametrize("backend", ["cpu", "tpu"])
     def test_position_by_position_it_is_the_scatter(self, monkeypatch, width,
-                                                    backend):
+                                                    positions, backend):
         monkeypatch.setattr(jax, "default_backend", lambda: backend)
-        cache = jax.random.normal(jax.random.key(0), (3, 40, width),
+        cache = jax.random.normal(jax.random.key(0), (3, positions, width),
                                   jnp.bfloat16)
         new = jax.random.normal(jax.random.key(1), (3, width), jnp.float32)
-        for pos in ([0, 39, 17], [5, 5, 5]):
+        for pos in ([0, positions - 1, 17], [5, 5, 5]):
             pos = jnp.asarray(pos, jnp.int32)
             np.testing.assert_array_equal(
                 np.asarray(attn_ops.write_row(cache, new, pos), np.float32),
                 np.asarray(_scatter_write(cache, new, pos), np.float32))
 
-    @pytest.mark.parametrize("width,backend,updates", [
-        (64, "tpu", 3), (64, "cpu", 0), (512, "tpu", 0)],
-        ids=["narrow-on-a-tpu", "narrow-on-the-cpu", "whole-lanes-on-a-tpu"])
-    def test_which_form_the_program_has(self, monkeypatch, width, backend,
-                                        updates):
+    @pytest.mark.parametrize("width,positions,backend,form", [
+        (64, 2 * 2048, "tpu", "updates"), (64, 2048, "tpu", "select"),
+        (64, 40, "cpu", "scatter"), (512, 40, "tpu", "scatter"),
+        (512, 2 * 2048, "tpu", "scatter")],
+        ids=["narrow-and-long-on-a-tpu", "narrow-and-short-on-a-tpu",
+             "narrow-on-the-cpu", "whole-lanes-on-a-tpu",
+             "whole-lanes-and-long-on-a-tpu"])
+    def test_which_form_the_program_has(self, monkeypatch, width, positions,
+                                        backend, form):
+        assert attn_ops.SELECT_MAX_POSITIONS == 2048
         monkeypatch.setattr(jax, "default_backend", lambda: backend)
         # (a function of its own: a ``jit`` of ``write_row`` itself would
         # hand back the trace of whichever backend asked first)
         text = jax.jit(lambda *a: attn_ops.write_row(*a)).lower(
-            jnp.zeros((3, 40, width), jnp.bfloat16), jnp.zeros((3, width)),
-            jnp.zeros((3,), jnp.int32)).as_text()
-        assert text.count("dynamic_update_slice") == updates
-        assert ("scatter" in text) == (updates == 0)
+            jnp.zeros((3, positions, width), jnp.bfloat16),
+            jnp.zeros((3, width)), jnp.zeros((3,), jnp.int32)).as_text()
+        assert text.count("dynamic_update_slice") == (3 if form == "updates"
+                                                      else 0)
+        assert ("scatter" in text) == (form == "scatter")
+        if form == "select":     # one elementwise pass over the leaf
+            assert "stablehlo.select" in text and "stablehlo.compare" in text
 
     @pytest.mark.parametrize("backend", ["cpu", "tpu"])
     def test_a_prompt_s_fill_is_the_step_by_step_writes(self, monkeypatch,

@@ -11,6 +11,7 @@ import pytest
 
 import lm_tiny
 from can_tpu.models import (brumby, exaone_moe, falcon_h1, glm_moe_lite, lfm2_moe,
+                            longcat_flash,
                             lm_blocks, mimo_v2_flash)
 from can_tpu.obs import spans as recorder
 from can_tpu.obs.trace import (cache_copies, hlo_type, part_of,
@@ -249,13 +250,14 @@ class TestTheMap:
         assert set(lm_blocks.RENAMED_BY_COMPILER.values()) <= set(PARTS)
 
 
-# -- the six tiny models -----------------------------------------------------
+# -- the seven tiny models -----------------------------------------------------
 MODELS = {"k-exaone": (exaone_moe, lambda: lm_tiny.tiny_model(mtp=0)),
           "glm": (glm_moe_lite, lambda: lm_tiny.tiny_glm_model(mtp=0)),
           "falcon-h1": (falcon_h1, lm_tiny.tiny_falcon_model),
           "lfm2": (lfm2_moe, lm_tiny.tiny_lfm2_model),
           "mimo": (mimo_v2_flash, lm_tiny.tiny_mimo_model),
-          "brumby": (brumby, lm_tiny.tiny_brumby_model)}
+          "brumby": (brumby, lm_tiny.tiny_brumby_model),
+          "longcat": (longcat_flash, lm_tiny.tiny_longcat_model)}
 SLOTS, PART, BUCKET = 4, 2, 16
 
 

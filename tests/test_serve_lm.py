@@ -510,13 +510,27 @@ def test_a_model_imports_neither_the_serving_path_nor_another_model(name):
     assert not (imported & others - {f"can_tpu.models.{name}"}), imported
 
 
+def test_the_second_latent_model_takes_the_layer_from_the_first():
+    """The one exception to the rule above, on purpose: LongCat-Flash's two
+    sublayers ARE GLM's latent attention layer (``attention_expanded``,
+    ``attention_absorbed``), used from one place; it imports no other model
+    and nothing of the serving path."""
+    import importlib
+
+    imported = _imports(importlib.import_module("can_tpu.models.longcat_flash"))
+    assert not any(m.startswith("can_tpu.serve") for m in imported), imported
+    assert {m for m in imported if m.startswith("can_tpu.models.")} == {
+        "can_tpu.models.glm_moe_lite", "can_tpu.models.lm_blocks"}
+
+
 def test_the_model_table_holds_what_a_configuration_file_may_name():
     from can_tpu.serve import programs
 
     entry = programs.serving_model("exaone_moe")
     assert set(programs.MODEL_TYPES) == {"exaone_moe", "glm4_moe_lite",
                                          "falcon_h1", "lfm2_moe",
-                                         "mimo_v2_flash", "brumby"}
+                                         "mimo_v2_flash", "brumby",
+                                         "longcat_flash"}
     made, params = entry.programs(lm_config(), None, 3)
     assert isinstance(made, programs.LMPrograms) and made.vocab_size == 256
     assert params["embed"].shape == (256, 64)
@@ -689,3 +703,86 @@ def test_a_prefill_in_another_form_counts_no_passes(service):
     lm = svc.stats()["lm"]
     assert lm["dispatch_calls"] == lm["dispatch_passes"] == 0
     assert not [s for s in tracer.snapshot() if "dispatch_passes" in s]
+
+
+# -- a launch of 256 slots, a router with identity experts ---------------------
+def _longcat_config(**kw) -> dict:
+    from lm_tiny import tiny_longcat_config
+
+    d = tiny_longcat_config()
+    d.update(max_new_tokens=3, prefill_slice=32, length_ladder=[16],
+             max_batch=256, queue_capacity=1024, max_wait_ms=20.0)
+    d.update(kw)
+    return d
+
+
+@pytest.fixture(scope="module")
+def wide_service():
+    """The tiny LongCat-Flash preset behind the cell's own launch: 256 slots
+    a launch in slices of 32, a queue of 1,024."""
+    tracer = spans.SpanTracer()
+    tel = Telemetry()
+    tel.spans = tracer
+    svc = build_model_service(_longcat_config(), telemetry=tel, seed=11)
+    report = svc.warmup()
+    svc.start()
+    yield svc, report, tracer
+    svc.close()
+
+
+def test_a_launch_of_256_slots_fills_runs_and_answers_everyone(wide_service):
+    """600 requests at once (the queue takes 1,024): two full launches of 256
+    and one of 88, eight prefill slices a full launch; every request is
+    answered with its own tokens (the same prompt twice: the same ids,
+    whatever slot and launch it sat in)."""
+    svc, report, _ = wide_service
+    assert report["compiles"] == 2 == svc.engine.compile_count
+    prompts = [_prompt(4 + i % 12, i % 40) for i in range(600)]
+    tickets = [svc.submit(p) for p in prompts]
+    results = [t.result(600) for t in tickets]
+    assert all(r.tokens.shape == (3,) for r in results)
+    for i in (0, 7, 39):
+        for j in range(i + 40, 600, 40):
+            if len(prompts[i]) == len(prompts[j]):
+                np.testing.assert_array_equal(results[i].tokens,
+                                              results[j].tokens)
+    stats = svc.stats()
+    assert stats["completed"] == 600 and stats["rejected"] == 0
+    assert svc.engine.compile_count == 2       # nothing compiled in traffic
+    assert max(r.batch_fill for r in results) == 1.0     # a launch of 256 of 256
+    assert stats["lm"]["launches"] >= 3
+
+
+def test_the_engine_counts_the_choices_that_were_identity_experts(wide_service):
+    """``assignments_zero`` beside ``assignments_held`` / ``_all``: every
+    choice of a valid token (prefill and decode, 2 layers x top-3) is held
+    here, held elsewhere or an identity expert's; a third of the router's 12
+    outputs are identity experts; the counter is on ``/metrics``."""
+    from can_tpu.obs.exporter import render_stats
+
+    svc, _, tracer = wide_service
+    before = svc.stats()["lm"]
+    for t in [svc.submit(_prompt(9, 100 + n)) for n in range(40)]:
+        t.result(600)
+    lm = svc.stats()["lm"]
+    tokens = (lm["prompt_tokens"] - before["prompt_tokens"]
+              + lm["generated_tokens"] - before["generated_tokens"])
+    every = lm["assignments_all"] - before["assignments_all"]
+    zero = lm["assignments_zero"] - before["assignments_zero"]
+    held = lm["assignments_held"] - before["assignments_held"]
+    assert every == tokens * 2 * 3 and tokens == 40 * (9 + 3)
+    assert 0 < zero < every and 0 < held and held + zero < every
+    assert 0.15 < zero / every < 0.55          # 4 of the router's 12 outputs
+    # 256 slots x (16 + 3) positions x 2 layers x 2 sublayers x 24 numbers x 2 B
+    assert lm["cache_bytes"] == {"latent": 256 * 19 * 2 * 2 * 24 * 2}
+    text = render_stats(svc.stats(), prefix="can_tpu_serve")
+    assert f"can_tpu_serve_lm_assignments_zero_total {lm['assignments_zero']}" in text
+    decode = [s for s in tracer.snapshot() if s["name"] == "lm.decode"][-1]
+    assert decode["experts"] == "batched" and decode["latent"] == "plain"
+
+
+def test_a_model_without_identity_experts_counts_none(service):
+    svc, _, _ = service
+    svc.submit(_prompt(9, 5)).result(120)
+    lm = svc.stats()["lm"]
+    assert lm["assignments_zero"] == 0 < lm["assignments_all"]

@@ -17,6 +17,12 @@ roofline counts: ``rank + rope`` numbers a position up to each slot's own):
 * ``fused scatter``: the kernel (``ops/pallas_latent.py``) with the scatter's
   two copies of the rotary keys' leaf left in;
 * ``fused``: the kernel and the writes as they stand, the module's form;
+* ``fused updates`` / ``fused select`` (PR 50): the kernel with the rotary
+  keys' row written an update a SLOT (16 in GLM's cell, 256 in LongCat's)
+  or by a select over the whole leaf (one elementwise pass, in place):
+  ``write_row`` takes the first over ``SELECT_MAX_POSITIONS`` positions a
+  slot and the second at or under it, so ``fused`` is one of the two
+  (``--shape longcat``: 256 slots x 1,280 positions, 64 heads);
 * ``fused`` in blocks of 384 / 512 / 1,024 / 1,536 / 2,048 positions.
   (The other orientation of either product, the queries held in the matrix
   unit and the cache block streaming, was a parameter of the kernel in this
@@ -52,6 +58,23 @@ SCALE = 192 ** -0.5
 def _scatter(cache, new, pos):
     """``write_row`` until PR 49, whatever the leaf's width."""
     return cache.at[jnp.arange(cache.shape[0]), pos].set(new.astype(cache.dtype))
+
+
+def _updates(cache, new, pos):
+    """The row written by a ``dynamic_update_slice`` a slot, whatever the
+    leaf's length (``write_row``'s form over ``SELECT_MAX_POSITIONS``)."""
+    for i in range(cache.shape[0]):
+        cache = jax.lax.dynamic_update_slice(
+            cache, new[i][None, None].astype(cache.dtype), (i, pos[i], 0))
+    return cache
+
+
+def _select(cache, new, pos):
+    """The row written by a select over the whole leaf: one elementwise
+    pass in whatever layout the leaf has, in place where it is donated; its
+    cost is the leaf's bytes, not the slots' count."""
+    hit = jnp.arange(cache.shape[1])[None, :, None] == pos[:, None, None]
+    return jnp.where(hit, new[:, None, :].astype(cache.dtype), cache)
 
 
 def _step(write_krope, attend, ckv, krope, q_lat, q_rope, new_ckv, new_krope,
@@ -93,6 +116,9 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--steps", action="store_true")
+    ap.add_argument("--shape", choices=("glm", "longcat"), default="glm",
+                    help="glm: 16 slots x 16,512 positions, 20 heads; longcat: "
+                         "256 slots x 1,280 positions, 64 heads, 1,024 new")
     args = ap.parse_args()
     dev = jax.devices()[0]
     if dev.platform != "tpu" and not args.rehearse:
@@ -101,6 +127,10 @@ def main():
         return 2
     b, h, s, r, dr, reps = ((2, 20, 1152, 128, 16, 1) if args.rehearse
                             else (16, 20, 16512, 512, 64, 50))
+    new_tokens = 128   # of the cell: a launch's contexts grow by as many
+    if args.shape == "longcat":
+        new_tokens = 8 if args.rehearse else 1024
+        b, h, s = (8, 20, 1152) if args.rehearse else (256, 64, 1280)
     blocks = (128, 256) if args.rehearse else (384, 512, 1024, 1536, 2048)
     dtype = jnp.float32 if args.rehearse else jnp.bfloat16
     ks = jax.random.split(jax.random.key(49), 6)
@@ -111,7 +141,6 @@ def main():
     new = (jax.random.normal(ks[4], (b, r), dtype),
            jax.random.normal(ks[5], (b, dr), dtype))
     rng = np.random.default_rng(20261004)
-    new_tokens = 128   # of the cell: a launch's contexts grow by as many
     prompts = rng.integers((s - new_tokens) // 2, s - new_tokens + 1, b)
     at = {"middle": prompts + new_tokens // 2}
     if args.steps:
@@ -120,7 +149,9 @@ def main():
     forms = [("plain scatter", _scatter, _plain),
              ("plain", attn_ops.write_row, _plain),
              ("fused scatter", _scatter, fused),
-             ("fused", attn_ops.write_row, fused)]
+             ("fused", attn_ops.write_row, fused),
+             ("fused updates", _updates, fused),
+             ("fused select", _select, fused)]
     forms += [(f"fused block {n}", attn_ops.write_row,
                _fused(args.rehearse, block=n)) for n in blocks]
     rows = []
@@ -147,7 +178,9 @@ def main():
             print(json.dumps(row), flush=True)
     out_dir = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)
-    with open(os.path.join(out_dir, "latent_decode_forms.json"), "w") as f:
+    name = ("latent_decode_forms.json" if args.shape == "glm"
+            else f"latent_decode_forms.{args.shape}.json")
+    with open(os.path.join(out_dir, name), "w") as f:
         json.dump({"device": {"platform": dev.platform, "kind": dev.device_kind},
                    "shape": {"slots": b, "heads": h, "positions": s, "rank": r,
                              "rope": dr},
